@@ -133,13 +133,16 @@ def main() -> None:
                     help="append rows to benchmarks/results/")
     args = ap.parse_args()
 
+    import jax
+
     from metaopt_tpu.utils.provenance import provenance
 
     rows = []
     for pool in args.pools:
         row = run_batch_eval(pool, reps=args.reps, task_name=args.task,
                              dim=args.dim)
-        row.update(provenance())
+        # one process, on whatever device it owns: every row says which
+        row.update(provenance(backend=jax.default_backend()))
         print(json.dumps(row), flush=True)
         rows.append(row)
     if args.save:

@@ -61,7 +61,9 @@ def build_parser() -> argparse.ArgumentParser:
                       help="abort after this many broken trials")
     hunt.add_argument("--working-dir")
     hunt.add_argument("--n-chips", type=int, default=None,
-                      help="TPU chips per trial (enables the TPU executor)")
+                      help="TPU chips per trial: each trial subprocess is "
+                           "pinned to its own chips and fails if it cannot "
+                           "get them (on-chip sweeps always pass this)")
     hunt.add_argument("--timeout-s", type=float, default=None,
                       help="per-trial wall-clock timeout")
     hunt.add_argument("--warm-start", dest="warm_start", default=None,
@@ -104,10 +106,10 @@ def build_parser() -> argparse.ArgumentParser:
                            "(scripts resolve it via "
                            "client.checkpoint_paths())")
     hunt.add_argument("--jax-cache", dest="jax_cache", default=None,
-                      help="persistent XLA compilation cache dir shared by "
-                           "all trials: trial N reuses trial 1's compile "
-                           "(don't share the dir across heterogeneous "
-                           "hosts)")
+                      help="persistent XLA compilation cache dir for this "
+                           "process and every trial (default "
+                           "<checkout>/.cache/xla); an ambient "
+                           "JAX_COMPILATION_CACHE_DIR outranks it")
     hunt.add_argument("--batch-size", dest="batch_size", default=None,
                       help="evaluate pools of this many trials as ONE "
                            "jitted vmap program (needs --vector-objective; "
@@ -823,6 +825,19 @@ def _cmd_hunt(args, cfg: Dict[str, Any]) -> int:
     batch_size = getattr(args, "batch_size", None) or cfg.get("batch_size")
     vector_name = (getattr(args, "vector_objective", None)
                    or cfg.get("vector_objective"))
+    import jax
+
+    from metaopt_tpu.utils.procs import use_xla_cache
+
+    jax_cache = use_xla_cache(args.jax_cache or cfg.get("jax_cache"))
+    if not vector_name:
+        # Trials are child processes and a chip belongs to one process at
+        # a time: this process (producer, suggest kernels) must never
+        # initialise a non-CPU backend, or no trial could get the device.
+        # Through the live config, not os.environ, which children inherit.
+        # With --vector-objective the trials run in THIS process, which
+        # is then the one owner and keeps the default backend.
+        jax.config.update("jax_platforms", "cpu")
     if batch_size not in (None, 1, "1") and not vector_name:
         raise SystemExit(
             "--batch-size needs --vector-objective NAME: pools evaluate "
@@ -849,6 +864,12 @@ def _cmd_hunt(args, cfg: Dict[str, Any]) -> int:
     n_chips = args.n_chips if args.n_chips is not None else (
         (cfg.get("executor") or {}).get("n_chips")
     )
+    total_chips = None
+    if n_chips and vector_fn is None:
+        from metaopt_tpu.executor.topology import detect_slice_size
+
+        # once, before any trial (or --n-workers thread) starts
+        total_chips = detect_slice_size()
 
     def make_executor(tmpl):
         if vector_fn is not None:
@@ -870,7 +891,8 @@ def _cmd_hunt(args, cfg: Dict[str, Any]) -> int:
         if n_chips:
             from metaopt_tpu.executor.tpu import TPUExecutor
 
-            return TPUExecutor(tmpl, n_chips=int(n_chips), **kwargs)
+            return TPUExecutor(tmpl, n_chips=int(n_chips),
+                               total_chips=total_chips, **kwargs)
         return SubprocessExecutor(tmpl, **kwargs)
 
     workon_kwargs = dict(
@@ -986,11 +1008,16 @@ def _cmd_hunt(args, cfg: Dict[str, Any]) -> int:
         "experiment": exp.name,
         "worker": worker_id,
         "n_workers": n_workers,
+        # set-up facts: where this process ran its own jax work, how many
+        # chips the host has (with --n-chips), which ledger engine it got
+        "platform": jax.default_backend(),
+        "host_chips": total_chips,
+        "ledger": type(exp.ledger).__name__,
+        "jax_cache": jax_cache,
         "failed_workers": n_workers - len(all_stats),
         "completed_by_worker": sum(st.completed for st in all_stats),
         "broken_by_worker": sum(st.broken for st in all_stats),
         "pruned_by_worker": sum(st.pruned for st in all_stats),
-        "requeued_by_worker": sum(st.requeued for st in all_stats),
         "producer_timings": timings,
         "total": s["by_status"],
         "best": s["best"],

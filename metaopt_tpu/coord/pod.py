@@ -63,6 +63,11 @@ def start_pod_coordinator(
     hosting process, which must keep it alive and ``stop()`` it at exit.
     Single-process runs degenerate to a local server, so the same call works
     in tests, on one chip, and on a pod.
+
+    ``jax.process_count()`` initialises the backend: call this only from
+    the process that owns this host's chips (a ``jax.distributed`` program
+    whose trials run in-process), never from a hunt process that launches
+    chip-bound trial subprocesses.
     """
     import jax
 

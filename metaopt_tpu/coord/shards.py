@@ -1001,6 +1001,10 @@ class ShardSupervisor:
             root + os.pathsep + env["PYTHONPATH"]
             if env.get("PYTHONPATH") else root
         )
+        # N sibling shard processes host algorithms on one machine and a
+        # chip belongs to one process: suggest runs on the host CPU in
+        # every shard unless the operator chose a platform explicitly
+        env.setdefault("JAX_PLATFORMS", "cpu")
         if env_extra:
             env.update(env_extra)
         if disarm:

@@ -22,10 +22,6 @@ class ExecutionResult:
     results: List[Dict[str, Any]] = field(default_factory=list)
     exit_code: Optional[int] = None
     note: str = ""
-    #: infrastructure (not script) failure: the worker releases the trial
-    #: back to 'new' so the hunt retries it once the device recovers,
-    #: instead of leaving it for a manual `mtpu resume`
-    requeue: bool = False
 
 
 class Executor:

@@ -216,31 +216,55 @@ class ChipRegistry:
         return None
 
 
-def detect_slice_size(default: int = 1) -> int:
-    """Chips visible to this host (env override > jax > default)."""
+def detect_slice_size() -> int:
+    """Chips on this host: ``MTPU_SLICE_CHIPS``, else one short-lived child.
+
+    Never ``jax.devices()`` here: this runs in the hunt process, which
+    must not claim the chips its trial children need. The probe child
+    has exited before the first trial starts; its failure (no TPU, or
+    the chips held by another process) raises instead of defaulting.
+    Set ``MTPU_SLICE_CHIPS`` when several hunts share one host, since a
+    probe cannot count chips that a running trial holds.
+    """
     env = os.environ.get("MTPU_SLICE_CHIPS")
     if env:
         return int(env)
-    try:
-        import jax
+    from metaopt_tpu.utils.procs import probe_devices
 
-        return max(1, len(jax.devices()))
-    except Exception:
-        return default
+    return int(probe_devices()["count"])
+
+
+#: TPU_CHIPS_PER_PROCESS_BOUNDS for a block of 1, 2 or 4 chips on a 2x2 v5e
+#: host. Checked on such a host (jax 0.9.0, libtpu 0.0.34, PR 21): four
+#: 1-chip blocks, two 2-chip blocks, a 1- beside a 2-chip block and one
+#: 4-chip block all initialise side by side, collectives work inside each
+#: block, and every process opens only its own /dev/vfio/N. No per-process
+#: port, address or libtpu-lock variable was needed for that.
+_BLOCK_BOUNDS = {1: "1,1,1", 2: "1,2,1", 4: "2,2,1"}
 
 
 def chip_env(block: SubSlice) -> Dict[str, str]:
-    """Env vars pinning a trial subprocess to its sub-slice.
+    """Env vars pinning a trial subprocess to its sub-slice of a host.
 
-    ``TPU_VISIBLE_CHIPS``/``TPU_PROCESS_BOUNDS`` is the TPU analogue of the
-    reference's `CUDA_VISIBLE_DEVICES` story; `MTPU_ASSIGNED_CHIPS` is the
-    framework-level contract (read by `client.get_trial_info` users and the
-    demo models) and works on any backend.
+    ``JAX_PLATFORMS=tpu`` makes a trial that cannot get its chips raise
+    with the runtime's message (and end ``broken``); with the variable
+    unset, jax falls back to the CPU quietly and the trial would be
+    recorded ``completed``. ``TPU_VISIBLE_CHIPS`` and the bounds are the
+    TPU analogue of the reference's ``CUDA_VISIBLE_DEVICES`` story: the
+    process sees only its block, renumbered from device id 0.
+    ``MTPU_ASSIGNED_CHIPS`` is the framework-level contract
+    (``parallel.mesh.trial_devices``) and works on any backend.
     """
+    if block.size not in _BLOCK_BOUNDS:
+        raise ValueError(
+            f"no per-process bounds known for a {block.size}-chip block "
+            f"(known: {sorted(_BLOCK_BOUNDS)})"
+        )
     ids = ",".join(str(c) for c in block.chips)
     return {
+        "JAX_PLATFORMS": "tpu",
         "MTPU_ASSIGNED_CHIPS": ids,
         "TPU_VISIBLE_CHIPS": ids,
-        "TPU_CHIPS_PER_PROCESS_BOUNDS": f"1,1,{block.size}",
+        "TPU_CHIPS_PER_PROCESS_BOUNDS": _BLOCK_BOUNDS[block.size],
         "TPU_PROCESS_BOUNDS": "1,1,1",
     }

@@ -41,12 +41,10 @@ class TPUExecutor(SubprocessExecutor):
         allocate_poll_s: float = 0.5,
         **kwargs,
     ):
-        # the device circuit breaker (park at a wedged backend) lives in
-        # SubprocessExecutor — un-pinned relay hunts hit the identical
-        # failure mode; its knobs (park_max_s, probe_fn, ...) pass
-        # through **kwargs
         super().__init__(template, **kwargs)
         self.n_chips = int(n_chips)
+        # `hunt --n-workers` counts the chips once and passes the answer
+        # in: N executors probing at once would race each other for them
         total = total_chips or detect_slice_size()
         # round the slice size down to a power of two for the buddy allocator
         p = 1
@@ -79,15 +77,17 @@ class TPUExecutor(SubprocessExecutor):
                 note=f"no {self.n_chips}-chip sub-slice became available "
                 f"within {self.allocate_timeout_s}s",
             )
-        # MERGE the chip assignment — never replace the dict: the worker
-        # loop persists its per-trial requeue budget in this same dict
-        # (worker/loop.py), and clobbering it makes the budget infinite
-        # (the exact wedge-convergence failure the breaker exists to stop)
+        env = chip_env(block)
+        if os.environ.get("JAX_PLATFORMS"):
+            # an explicit platform choice in the environment (the CPU test
+            # harness) outranks the pin; only an UNSET variable lets jax
+            # fall back quietly, and chip_env never leaves it unset
+            del env["JAX_PLATFORMS"]
         trial.resources.update(
             {
                 "chips": block.chips,
                 "slice": {"start": block.start, "size": block.size},
-                "env": chip_env(block),
+                "env": env,
             }
         )
         log.debug("trial %s pinned to chips %s", trial.id[:8], block.chips)
@@ -97,10 +97,6 @@ class TPUExecutor(SubprocessExecutor):
             return heartbeat() if heartbeat else True
 
         try:
-            # the inherited breaker parks/arms inside (while holding the
-            # sub-slice — nothing else can use it during a wedge anyway,
-            # and `beating` keeps both the reservation and the registry
-            # lease alive)
             return super().execute(trial, heartbeat=beating, judge=judge)
         finally:
             self.registry.free(block)  # every exit path returns the sub-slice

@@ -10,7 +10,7 @@ module adds the lineage's branching story on top, re-based onto the ledger:
 - the child's first produce() replays the parent's completed trials through
   a :class:`TrialAdapter` so its algorithm starts informed (the ledger-side
   analogue of the lineage's adapter chain);
-- adaptation rules mirror the lineage's adapter taxonomy:
+- adaptation rules mirror the lineage's adapter classes:
   * dimension unchanged        → pass the value through,
   * prior/range changed        → keep the trial iff the value still fits,
   * dimension added in child   → fill from an explicit default

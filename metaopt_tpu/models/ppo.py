@@ -103,8 +103,7 @@ def train(
     # every scalar hyperparameter is a TRACED value, not a baked-in Python
     # constant: all trials of a sweep (same hidden width) then share ONE
     # XLA program, so the persistent compile cache turns a per-trial
-    # remote compile (~2-3 min through the relay) into a per-sweep one —
-    # the difference between evolution_ppo timing out and finishing
+    # compile into a per-sweep one
     hp = {
         "clip_eps": jnp.float32(hparams.get("clip_eps", 0.2)),
         "ent_coef": jnp.float32(hparams.get("ent_coef", 0.01)),

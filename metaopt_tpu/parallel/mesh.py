@@ -41,11 +41,9 @@ def active_mesh() -> Optional[Mesh]:
     mesh = _ACTIVE_MESH.get()
     if mesh is not None:
         return mesh
-    get_abstract = getattr(jax.sharding, "get_abstract_mesh", None)
-    if get_abstract is not None:
-        abstract = get_abstract()
-        if abstract is not None and not abstract.empty:
-            return abstract
+    abstract = jax.sharding.get_abstract_mesh()
+    if abstract is not None and not abstract.empty:
+        return abstract
     return None
 
 
@@ -53,10 +51,12 @@ def trial_devices() -> List[jax.Device]:
     """The devices this trial process may use.
 
     The TPU executor pins trials via ``MTPU_ASSIGNED_CHIPS`` (see
-    executor/topology.py). When the runtime actually hides other chips
-    (TPU_VISIBLE_CHIPS honored by the plugin) the id list matches
-    ``jax.devices()`` directly; when it doesn't (CPU test meshes), the ids
-    index into the visible device list — both cases resolve here.
+    executor/topology.py). On the chip the runtime honours
+    ``TPU_VISIBLE_CHIPS``: the process sees exactly its block, renumbered
+    from device id 0 with coordinates relative to the block (checked on a
+    2x2 v5e host, PR 21: chips "2,3" appear as ids [0, 1]), so the visible
+    set IS the assignment. Where nothing is hidden (CPU test meshes) the
+    ids index into the visible device list — both cases resolve here.
     """
     devices = jax.devices()
     spec = os.environ.get("MTPU_ASSIGNED_CHIPS")

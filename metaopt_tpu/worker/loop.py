@@ -33,9 +33,6 @@ class WorkerStats:
     interrupted: int = 0
     pruned: int = 0
     suspended: int = 0
-    #: trials bounced back to 'new' after an infrastructure failure
-    #: (executor set ExecutionResult.requeue) — retried, not lost
-    requeued: int = 0
     idle_cycles: int = 0
     events: List[Dict[str, Any]] = field(default_factory=list)
     #: producer timing aggregates (observe/suggest latency, SURVEY.md §5)
@@ -113,12 +110,6 @@ def workon(
             max_idle_cycles, stop_event, stale_sweep_interval_s, batch_size,
         )
     stats = WorkerStats()
-    # per-trial requeue budget: a wedge-attributed infrastructure failure
-    # releases the trial (ExecutionResult.requeue), but only this many
-    # times — a permanently dead backend must converge to interrupted.
-    # The count persists on the trial document (resources), so N workers
-    # (or a restarted worker) share ONE budget instead of multiplying it.
-    max_requeues = 3
     # first loop iteration always sweeps (resuming after a crash must
     # free the dead predecessor's reservations before producing)
     last_sweep = 0.0
@@ -338,7 +329,6 @@ def workon(
                 raise
 
             trial.exit_code = res.exit_code
-            requeue_budget_spent = False
             if res.status == "completed":
                 if fused:
                     # defer the terminal update: it rides the next worker_cycle
@@ -362,40 +352,7 @@ def workon(
                             "%s lost reservation of %s before result push",
                             worker_id, trial.id,
                         )
-            elif (res.requeue
-                  and int(trial.resources.get("requeues", 0)) < max_requeues):
-                # infrastructure failure (device wedge/park budget): release
-                # the trial back to 'new' so this or another worker retries it
-                # once the device recovers; bounded per trial so a permanently
-                # dead backend still converges to interrupted
-                n_req = int(trial.resources.get("requeues", 0)) + 1
-                trial.reset_to_new()
-                # AFTER reset_to_new, which clears resources — the counter
-                # must survive into the ledger or the budget never binds
-                trial.resources["requeues"] = n_req
-                ok = experiment.ledger.update_trial(
-                    trial, expected_status="reserved", expected_worker=worker_id
-                )
-                if ok:
-                    stats.requeued += 1
-                    _settle("new")
-                    log.warning(
-                        "%s requeued trial %s (%d/%d): %s", worker_id,
-                        trial.id[:8], n_req, max_requeues, res.note,
-                    )
-                else:
-                    log.warning(
-                        "%s lost reservation of %s before requeue write-back",
-                        worker_id, trial.id,
-                    )
             else:
-                if res.requeue:
-                    # the executor flagged a retry, but the shared budget is
-                    # spent — the stored outcome must say what actually
-                    # happens (nothing, until a human resumes it)
-                    res.note += (" (requeue budget exhausted — "
-                                 "see `mtpu resume`)")
-                    requeue_budget_spent = True
                 trial.transition(res.status)
                 experiment.ledger.update_trial(
                     trial, expected_status="reserved", expected_worker=worker_id
@@ -422,25 +379,6 @@ def workon(
                     "note": res.note,
                 }
             )
-            if requeue_budget_spent:
-                # the backend stayed dead through every park + retry this
-                # trial was entitled to (~3 park budgets of wall clock) and
-                # the final attempt just went terminal — continuing would
-                # have the producer mint replacement trials forever, each
-                # doomed to the same grind. Stop THIS worker; the interrupted
-                # trials resume with `mtpu resume` once the device returns.
-                # (A terminal-interrupted trial satisfies no stop condition:
-                # it is neither completed nor broken.) NOTE: this must key on
-                # the budget-exhausted branch having actually run, not on the
-                # stored counter — right after the LAST successful requeue
-                # the counter already reads max_requeues, and breaking there
-                # would strand the trial in 'new' instead of interrupted.
-                log.error(
-                    "%s: TPU backend did not recover within trial %s's requeue "
-                    "budget — stopping worker (state preserved; `mtpu resume` "
-                    "when the device returns)", worker_id, trial.id[:8],
-                )
-                break
 
     except BaseException:
         # error exits (coordinator unavailable, executor blow-ups, the
@@ -711,9 +649,9 @@ def _workon_batched(
                                 "push", worker_id, trial.id,
                             )
                 else:
-                    # broken / interrupted (the batched executor never
-                    # requeues: a pool-level infrastructure failure surfaces
-                    # as broken notes, the worker guard handles persistence)
+                    # broken / interrupted: a pool-level infrastructure
+                    # failure surfaces as broken notes, the worker guard
+                    # handles persistence
                     trial.transition(res.status)
                     experiment.ledger.update_trial(
                         trial, expected_status="reserved",

@@ -1,8 +1,8 @@
 """run_with_deadline streaming: a killed child must leave a visible tail.
 
-MULTICHIP_r02 went red because the dryrun child's output was buffered in a
-temp file and only flushed after exit — a driver-side kill left an empty
-tail. stream=True tees output as it is produced, so these tests pin that a
+A dryrun child's output used to be buffered in a temp file and only
+flushed after exit — a driver-side kill left an empty tail. stream=True
+tees output as it is produced, so these tests pin that a
 deadline kill still surfaces everything printed before the kill.
 """
 

@@ -19,8 +19,8 @@ class TestRunSwept:
     def test_markers_accumulate_across_nesting(self, monkeypatch):
         """An outer sweep marker must survive into children launched by
         an inner run_swept — overwriting it would leave the outer
-        caller's deadline sweep nothing to match (watch_tpu → run.py →
-        trial trees)."""
+        caller's deadline sweep nothing to match (an outer sweep → run.py
+        → trial trees)."""
         monkeypatch.setenv("MTPU_SWEEP_MARKER", "outer-abc")
         rc, out, _ = run_swept(
             [sys.executable, "-c",

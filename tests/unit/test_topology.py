@@ -117,10 +117,27 @@ class TestChipRegistryFile:
 
 class TestChipEnv:
     def test_pinning_env(self):
-        env = chip_env(SubSlice(4, 4))
-        assert env["MTPU_ASSIGNED_CHIPS"] == "4,5,6,7"
-        assert env["TPU_VISIBLE_CHIPS"] == "4,5,6,7"
-        assert env["TPU_CHIPS_PER_PROCESS_BOUNDS"] == "1,1,4"
+        env = chip_env(SubSlice(2, 2))
+        assert env["MTPU_ASSIGNED_CHIPS"] == "2,3"
+        assert env["TPU_VISIBLE_CHIPS"] == "2,3"
+        assert env["TPU_PROCESS_BOUNDS"] == "1,1,1"
+
+    def test_chip_bound_trial_is_pinned_to_the_tpu(self):
+        """Only an UNSET JAX_PLATFORMS lets jax fall back to the CPU
+        quietly; the pin makes a trial without its chip raise."""
+        for block in (SubSlice(0, 1), SubSlice(3, 1), SubSlice(0, 4)):
+            assert chip_env(block)["JAX_PLATFORMS"] == "tpu"
+
+    @pytest.mark.parametrize("size,bounds",
+                             [(1, "1,1,1"), (2, "1,2,1"), (4, "2,2,1")])
+    def test_per_block_bounds(self, size, bounds):
+        """The shapes a 2x2 v5e host accepted side by side (PR 21)."""
+        env = chip_env(SubSlice(0, size))
+        assert env["TPU_CHIPS_PER_PROCESS_BOUNDS"] == bounds
+
+    def test_unknown_block_shape_raises(self):
+        with pytest.raises(ValueError, match="8-chip block"):
+            chip_env(SubSlice(0, 8))
 
     def test_next_pow2(self):
         assert [next_pow2(n) for n in (1, 2, 3, 5, 8)] == [1, 2, 4, 8, 8]

@@ -3,8 +3,6 @@
 ref coverage model: Producer/worker unit tests with DumbAlgo (SURVEY.md §4).
 """
 
-import os
-
 import pytest
 
 from metaopt_tpu.executor import InProcessExecutor
@@ -58,29 +56,6 @@ class TestProducer:
         exp.push_results(t, [{"name": "o", "type": "objective", "value": 1.0}])
         prod.produce()
         assert algo.n_observed == 1
-
-    def test_jax_cache_env_injection(self, tmp_path):
-        from metaopt_tpu.executor import SubprocessExecutor
-        from metaopt_tpu.ledger import Trial
-        from metaopt_tpu.space.builder import SpaceBuilder
-
-        _, template = SpaceBuilder().build(["t.py", "-x~uniform(0, 1)"])
-        cache = str(tmp_path / "jc")
-        ex = SubprocessExecutor(template, jax_cache_dir=cache)
-        _, env, _ = ex._prepare(
-            Trial(params={"x": 0.5}, experiment="e"), str(tmp_path)
-        )
-        assert env["JAX_COMPILATION_CACHE_DIR"] == cache
-        assert os.path.isdir(cache)
-        # opt-in: no flag, no injection
-        ex2 = SubprocessExecutor(template)
-        _, env2, _ = ex2._prepare(
-            Trial(params={"x": 0.5}, experiment="e"), str(tmp_path)
-        )
-        if "JAX_COMPILATION_CACHE_DIR" in env2:  # only via ambient env
-            assert env2["JAX_COMPILATION_CACHE_DIR"] == os.environ.get(
-                "JAX_COMPILATION_CACHE_DIR"
-            )
 
     def test_parent_key_strips_into_trial_lineage(self, exp, space):
         # PBT continuations carry the reserved _parent key; it must become
