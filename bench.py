@@ -367,50 +367,6 @@ def bench_resnet() -> dict:
     return out
 
 
-def bench_profile_transformer(seq: int = 256) -> dict:
-    """A jax.profiler trace of the flagship train step, for MFU forensics.
-
-    The record carries the profiler evidence of where the step time
-    goes. The trace directory is written
-    under benchmarks/results/ (left out of git — binary, tens of MB) and
-    its path rides in the bench record.
-    """
-    import glob as _glob
-
-    import jax
-
-    results = os.path.join(
-        os.path.dirname(os.path.abspath(__file__)), "benchmarks", "results")
-    # prune older traces first (tens of MB each) — keep the newest one,
-    # plus this run
-    old = sorted(
-        d for d in _glob.glob(os.path.join(results, f"trace_seq{seq}_*"))
-        if os.path.isdir(d)
-    )
-    for d in old[:-1]:
-        import shutil
-
-        shutil.rmtree(d, ignore_errors=True)
-    # unique dir per run: a shared per-day dir would let a run that
-    # captured nothing inherit an earlier run's files as "its" trace
-    stamp = time.strftime("%Y-%m-%dT%H%M%S", time.gmtime())
-    out_dir = os.path.join(results, f"trace_seq{seq}_{stamp}")
-    os.makedirs(out_dir, exist_ok=True)
-    jax.profiler.start_trace(out_dir)
-    try:
-        stats = bench_transformer(seq=seq, batch=16384 // seq)
-    finally:
-        jax.profiler.stop_trace()
-    traced = _glob.glob(os.path.join(out_dir, "**", "*.trace.json.gz"),
-                        recursive=True) + _glob.glob(
-        os.path.join(out_dir, "**", "*.xplane.pb"), recursive=True)
-    rel = os.path.relpath(out_dir, os.path.dirname(os.path.abspath(__file__)))
-    return {
-        f"profile_seq{seq}_trace": rel if traced else "no trace captured",
-        f"profile_seq{seq}_step_ms": stats[f"transformer_step_ms_seq{seq}"],
-    }
-
-
 def bench_flash_pallas() -> dict:
     """Compile-and-run the REAL Pallas flash kernel (not a trivial probe).
 
@@ -824,7 +780,7 @@ STAGES = (
     ("transformer-256", 600.0), ("transformer-512", 600.0),
     ("transformer-1024", 600.0),
     ("xent-256", 600.0), ("xent-512", 600.0), ("xent-1024", 600.0),
-    ("resnet", 600.0), ("flash", 600.0), ("profile-256", 240.0),
+    ("resnet", 600.0), ("flash", 600.0),
     ("batch", 600.0), ("coord", 1800.0),
     # last: three cold fits at 10k observations (566 s for one on the v5e,
     # PERF.md) do not fit any sane deadline; S0/D4 resize or remove it
@@ -958,8 +914,6 @@ def stage_main(name: str) -> None:
         seq = int(name.split("-")[1])
         stats = bench_transformer(seq=seq, batch=16384 // seq,
                                   force_xent="blocked")
-    elif name.startswith("profile-"):
-        stats = bench_profile_transformer(seq=int(name.split("-")[1]))
     elif name == "resnet":
         stats = bench_resnet()
     elif name == "flash":

@@ -1,0 +1,10 @@
+"""Device milliseconds a step under the scope ``optimizer`` (``tx.update``
+and ``apply_updates`` of ``make_train_step``): union of the traced slice's
+operations whose ``op_name`` has that scope, over its steps
+(chipbench/program_trace.py)."""
+
+from chipbench import program_trace
+
+
+def read(records):
+    return program_trace.scope_ms_a_step(records, "optimizer", "train_step")

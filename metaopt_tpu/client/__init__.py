@@ -18,6 +18,9 @@ import json
 import os
 from typing import Any, Dict, List, Mapping, Optional
 
+# first: a trial child's ``trial.start`` span ends where this import runs
+from metaopt_tpu.utils import trace
+
 RESULTS_PATH_ENV = "METAOPT_TPU_RESULTS_PATH"
 TRIAL_INFO_ENV = "METAOPT_TPU_TRIAL_INFO"
 STOP_PATH_ENV = "METAOPT_TPU_STOP_PATH"
@@ -62,12 +65,13 @@ def report_results(data: List[Mapping[str, Any]]) -> None:
             raise ReportError(f"malformed result entry {d!r}")
     path = _results_path()
     tmp = path + ".tmp"
-    with open(tmp, "w") as f:
-        json.dump(data, f)
-    # atomic, deliberately not durable: same-host IPC with the executor
-    # that spawned us — if the HOST crashes the trial is re-run anyway,
-    # so atomicity (never a torn read) is the whole contract here
-    os.replace(tmp, path)  # mtpu: lint-ok MTP001 same-host IPC, atomicity-only
+    with trace.span("trial.report"):
+        with open(tmp, "w") as f:
+            json.dump(data, f)
+        # atomic, deliberately not durable: same-host IPC with the executor
+        # that spawned us — if the HOST crashes the trial is re-run anyway,
+        # so atomicity (never a torn read) is the whole contract here
+        os.replace(tmp, path)  # mtpu: lint-ok MTP001 same-host IPC, atomicity-only
 
 
 def report_objective(value: float, name: str = "objective") -> None:
@@ -151,16 +155,18 @@ def checkpoint_paths(root: Optional[str] = None):
     return own, parent_dir
 
 
-PROFILE_DIR_ENV = "METAOPT_TPU_PROFILE_DIR"
+PROFILE_DIR_ENV = trace.PROFILE_DIR_ENV
 
 
 class profiled:
     """Context manager: capture a ``jax.profiler`` trace of this trial.
 
     No-op unless the executor injected ``METAOPT_TPU_PROFILE_DIR`` (set
-    ``profile_dir=`` on the executor / ``--profile-dir`` on the CLI). Traces
-    land in ``<profile_dir>/<trial_id>/`` for TensorBoard's profile plugin —
-    the per-trial on-chip observability SURVEY.md §5 calls for.
+    ``profile_dir=`` on the executor / ``--profile-dir`` on the CLI). The
+    device trace lands in ``<profile_dir>/<trial_id>/`` for TensorBoard's
+    profile plugin, beside the ``spans.jsonl`` every process of such a sweep
+    leaves there (utils/trace.py); the spans show in the trace as host
+    annotations.
 
     Usage inside a user script::
 
@@ -171,24 +177,16 @@ class profiled:
 
     def __init__(self) -> None:
         base = os.environ.get(PROFILE_DIR_ENV)
-        self._dir: Optional[str] = None
-        if base:
-            info = get_trial_info() or {}
-            self._dir = os.path.join(base, str(info.get("id", os.getpid())))
+        self._trace = trace.profile(trace.trial_dir(base)) if base else None
 
     def __enter__(self) -> "profiled":
-        if self._dir:
-            import jax
-
-            os.makedirs(self._dir, exist_ok=True)
-            jax.profiler.start_trace(self._dir)
+        if self._trace:
+            self._trace.__enter__()
         return self
 
     def __exit__(self, *exc) -> None:
-        if self._dir:
-            import jax
-
-            jax.profiler.stop_trace()
+        if self._trace:
+            self._trace.__exit__(*exc)
 
 
 #: the library-first flow (ref: the lineage's client API —

@@ -33,6 +33,7 @@ from metaopt_tpu.client import (
 )
 from metaopt_tpu.executor.base import ExecutionResult, Executor, HeartbeatFn, JudgeFn
 from metaopt_tpu.executor.faults import faults
+from metaopt_tpu.utils import trace
 from metaopt_tpu.utils.procs import xla_cache_dir
 
 log = logging.getLogger(__name__)
@@ -74,8 +75,12 @@ class SubprocessExecutor(Executor):
         self.timeout_s = timeout_s
         self.prune_grace_s = prune_grace_s
         self.extra_env = dict(extra_env or {})
-        if profile_dir:  # opt-in per-trial jax.profiler traces (client.profiled)
-            self.extra_env["METAOPT_TPU_PROFILE_DIR"] = profile_dir
+        if profile_dir:
+            # every trial leaves its spans there (utils/trace.py), this
+            # process too; device traces are the script's to ask for
+            # (client.profiled)
+            self.extra_env[trace.PROFILE_DIR_ENV] = profile_dir
+            trace.dump_under(profile_dir)
         if ckpt_root:  # PBT weight handoff root (client.checkpoint_paths)
             self.extra_env["METAOPT_TPU_CKPT_ROOT"] = ckpt_root
         # Persistent XLA compilation cache, always on: every trial is a
@@ -115,6 +120,7 @@ class SubprocessExecutor(Executor):
                 "params": trial.params,
                 "parent": trial.parent,
                 "resources": {k: v for k, v in trial.resources.items() if k != "env"},
+                **trace.spawn_info(),
             }
         )
         return argv, env, results_path
@@ -144,25 +150,30 @@ class SubprocessExecutor(Executor):
         judge: Optional[JudgeFn] = None,
     ) -> ExecutionResult:
         with tempfile.TemporaryDirectory(prefix="mtpu_trial_") as tmpdir:
-            argv, env, results_path = self._prepare(trial, tmpdir)
             # stdout/stderr go to files, not PIPEs: an undrained PIPE deadlocks
             # a chatty script once the ~64KB buffer fills
             stdout_path = os.path.join(tmpdir, "stdout")
             stderr_path = os.path.join(tmpdir, "stderr")
             if faults.fire("spawn_fail"):
                 return ExecutionResult("broken", note="spawn failed: injected")
-            try:
-                with open(stdout_path, "wb") as so, open(stderr_path, "wb") as se:
-                    proc = subprocess.Popen(
-                        argv,
-                        env=env,
-                        cwd=self.working_dir,
-                        stdout=so,
-                        stderr=se,
-                        start_new_session=True,  # isolate signals (we kill the group)
-                    )
-            except OSError as e:
-                return ExecutionResult("broken", note=f"spawn failed: {e}")
+            # the span's id and stamp ride to the child in its trial info,
+            # so the environment is made inside the span
+            with trace.span("executor.spawn", trial=trial.id):
+                argv, env, results_path = self._prepare(trial, tmpdir)
+                try:
+                    with open(stdout_path, "wb") as so, \
+                            open(stderr_path, "wb") as se:
+                        proc = subprocess.Popen(
+                            argv,
+                            env=env,
+                            cwd=self.working_dir,
+                            stdout=so,
+                            stderr=se,
+                            start_new_session=True,  # isolate signals (we kill the group)
+                        )
+                except OSError as e:
+                    return ExecutionResult(
+                        "broken", note=f"spawn failed: {e}")
 
             if faults.fire("kill_trial"):  # simulate mid-run preemption
                 self._kill(proc)
@@ -171,6 +182,7 @@ class SubprocessExecutor(Executor):
             started = time.time()
             last_beat = started
             pruned = False
+            spawned = json.loads(env[TRIAL_INFO_ENV])  # stamp, and wait's id
             try:
                 while True:
                     rc = proc.poll()
@@ -228,9 +240,14 @@ class SubprocessExecutor(Executor):
             except KeyboardInterrupt:
                 self._kill(proc)
                 return ExecutionResult("interrupted", note="SIGINT")
+            finally:  # spawned -> the poll that saw the exit, or the kill
+                trace.record("executor.wait", spawned["spawn_ns"],
+                             time.time_ns(), id=spawned["under"],
+                             trial=trial.id)
 
             rc = proc.returncode if not pruned else 0
-            results = self._collect(results_path, partial, pruned)
+            with trace.span("executor.collect", trial=trial.id):
+                results = self._collect(results_path, partial, pruned)
             if results is None:
                 try:
                     with open(stderr_path, "rb") as f:

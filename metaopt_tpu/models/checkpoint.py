@@ -24,6 +24,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from metaopt_tpu.utils import trace
+
 log = logging.getLogger(__name__)
 
 
@@ -33,6 +35,7 @@ def _checkpointer():
     return ocp.PyTreeCheckpointer()
 
 
+@trace.span("trial.save")
 def save_state(path: str, tree: Any) -> None:
     """Save any pytree of arrays under ``path`` (overwrites)."""
     leaves = jax.tree.leaves(tree)
@@ -43,6 +46,7 @@ def save_state(path: str, tree: Any) -> None:
     _checkpointer().save(os.path.abspath(path), payload, force=True)
 
 
+@trace.span("trial.restore")
 def restore_state(path: str, like: Any, shardings: Optional[Any] = None) -> Any:
     """Restore a pytree shaped like ``like``; re-shard when given.
 

@@ -13,7 +13,10 @@ from typing import Iterator, Tuple
 import jax
 import jax.numpy as jnp
 
+from metaopt_tpu.utils import trace
 
+
+@trace.span("trial.data")
 def synthetic_images(
     key: jax.Array,
     n: int,
@@ -36,6 +39,7 @@ def synthetic_images(
     return x, y
 
 
+@trace.span("trial.data")
 def synthetic_seq2seq(
     key: jax.Array,
     n: int,
@@ -55,6 +59,7 @@ def synthetic_seq2seq(
     return src, tgt
 
 
+@trace.span("trial.data")
 def synthetic_lm(
     key: jax.Array,
     n: int,

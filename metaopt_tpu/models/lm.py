@@ -39,6 +39,7 @@ from metaopt_tpu.models.transformer import (
     readout_xent,
     sharded_init,
 )
+from metaopt_tpu.utils import trace
 
 
 class DecoderOnlyLM(nn.Module):
@@ -82,7 +83,8 @@ class DecoderOnlyLM(nn.Module):
         mask = causal & pad
         block_cls = (nn.remat(EncoderLayer, static_argnums=(3,))
                      if self.remat else EncoderLayer)
-        x = emb(tokens) + pos[None, :t_len].astype(jnp.bfloat16)
+        with trace.scope("embed"):
+            x = emb(tokens) + pos[None, :t_len].astype(jnp.bfloat16)
         for i in range(self.n_layers):
             x = block_cls(self.d_model, self.n_heads, self.d_ff,
                           self.dropout, self.n_experts,
@@ -93,10 +95,11 @@ class DecoderOnlyLM(nn.Module):
             # pre-readout features for the blocked xent: the (B, T, V)
             # logits tensor never materializes (see readout_xent)
             return x
-        logits = jnp.einsum(
-            "btd,vd->btv", x.astype(jnp.bfloat16), emb.embedding
-        )
-        return logits.astype(jnp.float32)
+        with trace.scope("readout_xent"):
+            logits = jnp.einsum(
+                "btd,vd->btv", x.astype(jnp.bfloat16), emb.embedding
+            )
+            return logits.astype(jnp.float32)
 
 
 def make_lm(hparams: Optional[Dict[str, Any]] = None,
@@ -143,8 +146,9 @@ def make_lm_train_step(model, tx):
         loss, grads = jax.value_and_grad(
             lambda p: lm_loss_fn(model, p, tokens, step_key)
         )(params)
-        updates, opt_state = tx.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with trace.scope("optimizer"):
+            updates, opt_state = tx.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return params, opt_state, loss
 
     return train_step
@@ -219,12 +223,20 @@ def train_lm(
             donate_argnums=(0, 1),
         )
         loss = None
-        for i in range(steps):
-            lo = (i * batch_size) % (n_train - batch_size + 1)
-            batch = shard_batch(mesh, toks[lo:lo + batch_size])
-            params, opt_state, loss = step_fn(
-                params, opt_state, batch, jax.random.fold_in(kstep, i)
-            )
+        with trace.span("trial.train", steps=steps):
+            for i in range(steps):
+                with trace.span("slice_and_shard_batch"):
+                    lo = (i * batch_size) % (n_train - batch_size + 1)
+                    batch = shard_batch(mesh, toks[lo:lo + batch_size])
+                with trace.span("dispatch_step"):
+                    params, opt_state, loss = step_fn(
+                        params, opt_state, batch,
+                        jax.random.fold_in(kstep, i)
+                    )
+            if loss is not None:
+                # the loop runs ahead of the device; the save and the
+                # float(loss) below would wait for it anyway, once
+                loss.block_until_ready()
     if save_dir:
         from metaopt_tpu.models.checkpoint import save_state
 

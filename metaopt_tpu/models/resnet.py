@@ -18,6 +18,7 @@ import optax
 from flax import linen as nn
 
 from metaopt_tpu.models.data import synthetic_images
+from metaopt_tpu.utils import trace
 
 #: layers-per-stage tables for the classic depths
 STAGES = {18: (2, 2, 2, 2), 34: (3, 4, 6, 3), 50: (3, 4, 6, 3),
@@ -121,13 +122,14 @@ def train_and_eval(
     x, y = synthetic_images(kd, n_train, hw=hw, channels=3)
     xv, yv = synthetic_images(kv, n_val, hw=hw, channels=3)
 
-    variables = model.init(ki, x[:1], train=False)
-    params, batch_stats = variables["params"], variables["batch_stats"]
-    tx = optax.chain(
-        optax.add_decayed_weights(weight_decay),
-        optax.sgd(lr, momentum=momentum, nesterov=True),
-    )
-    opt_state = tx.init(params)
+    with trace.span("trial.init"):
+        variables = model.init(ki, x[:1], train=False)
+        params, batch_stats = variables["params"], variables["batch_stats"]
+        tx = optax.chain(
+            optax.add_decayed_weights(weight_decay),
+            optax.sgd(lr, momentum=momentum, nesterov=True),
+        )
+        opt_state = tx.init(params)
     steps = max(1, n_train // batch_size)
 
     def loss_fn(p, bs, xb, yb):
@@ -147,8 +149,9 @@ def train_and_eval(
             (loss, bs), grads = jax.value_and_grad(loss_fn, has_aux=True)(
                 p, bs, x[idx], y[idx]
             )
-            updates, o = tx.update(grads, o, p)
-            p = optax.apply_updates(p, updates)
+            with trace.scope("optimizer"):
+                updates, o = tx.update(grads, o, p)
+                p = optax.apply_updates(p, updates)
             return (p, bs, o, k), loss
 
         (p, bs, o, _), losses = jax.lax.scan(
@@ -157,19 +160,30 @@ def train_and_eval(
         return (p, bs, o), losses.mean()
 
     @jax.jit
+    @trace.scope("eval")
     def val_error(p, bs):
         logits = model.apply({"params": p, "batch_stats": bs}, xv, train=False)
         return 1.0 - jnp.mean(jnp.argmax(logits, -1) == yv)
 
+    def evaluate(carry) -> float:
+        with trace.span("trial.eval"):
+            return float(val_error(carry[0], carry[1]))
+
     carry = (params, batch_stats, opt_state)
     err = 1.0
     for e in range(int(epochs)):
-        carry, _ = epoch(carry, jax.random.fold_in(key, 1000 + e))
+        with trace.span("trial.train", epoch=e + 1, steps=steps):
+            carry, loss = epoch(carry, jax.random.fold_in(key, 1000 + e))
+            if on_epoch is not None or e + 1 == int(epochs):
+                # the evaluation that follows would wait for the device
+                # anyway: its wait belongs to training. Epochs that nothing
+                # follows stay queued, as ever
+                loss.block_until_ready()
         if on_epoch is not None:
-            err = float(val_error(carry[0], carry[1]))
+            err = evaluate(carry)
             on_epoch(e + 1, err)
     if on_epoch is None:
-        err = float(val_error(carry[0], carry[1]))
+        err = evaluate(carry)
     return err
 
 

@@ -28,6 +28,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from metaopt_tpu.models.data import synthetic_seq2seq
 from metaopt_tpu.parallel.sharding import shard_batch, with_mesh_partitioning
+from metaopt_tpu.utils import trace
 
 
 def _pinit(partitioned: bool, axes):
@@ -49,6 +50,7 @@ class MHA(nn.Module):
     partitioned: bool = True
 
     @nn.compact
+    @trace.scope("attention")
     def __call__(self, q_in, kv_in, mask=None, *, train: bool = False):
         d_head = self.d_model // self.n_heads
         dense = lambda name: nn.DenseGeneral(  # noqa: E731
@@ -139,6 +141,7 @@ class FeedForward(nn.Module):
     partitioned: bool = True
 
     @nn.compact
+    @trace.scope("ffn")
     def __call__(self, x, *, train: bool):
         wi = nn.Dense(
             self.d_ff, dtype=jnp.bfloat16, name="wi",
@@ -271,7 +274,8 @@ class Transformer(nn.Module):
                    if self.remat else EncoderLayer)
         dec_cls = (nn.remat(DecoderLayer, static_argnums=(5,))
                    if self.remat else DecoderLayer)
-        x = emb(src) + pos[None, :s_len].astype(jnp.bfloat16)
+        with trace.scope("embed"):
+            x = emb(src) + pos[None, :s_len].astype(jnp.bfloat16)
         for i in range(self.n_layers):
             x = enc_cls(self.d_model, self.n_heads, self.d_ff,
                         self.dropout, self.n_experts,
@@ -279,7 +283,8 @@ class Transformer(nn.Module):
                         name=f"enc{i}")(x, src_pad, train)
         enc = nn.LayerNorm(dtype=jnp.float32, name="enc_ln")(x).astype(jnp.bfloat16)
 
-        y = emb(tgt_in) + pos[None, :t_len].astype(jnp.bfloat16)
+        with trace.scope("embed"):
+            y = emb(tgt_in) + pos[None, :t_len].astype(jnp.bfloat16)
         for i in range(self.n_layers):
             y = dec_cls(self.d_model, self.n_heads, self.d_ff,
                         self.dropout, self.n_experts,
@@ -294,10 +299,11 @@ class Transformer(nn.Module):
             # the (B, T, V) logits tensor never exists
             return y
         # weight-tied readout against the (bf16) embedding table
-        logits = jnp.einsum(
-            "btd,vd->btv", y.astype(jnp.bfloat16), emb.embedding
-        )
-        return logits.astype(jnp.float32)
+        with trace.scope("readout_xent"):
+            logits = jnp.einsum(
+                "btd,vd->btv", y.astype(jnp.bfloat16), emb.embedding
+            )
+            return logits.astype(jnp.float32)
 
 
 # ---------------------------------------------------------------------------
@@ -362,6 +368,7 @@ def blocked_xent_enabled(
     return per_device >= _BLOCKED_XENT_MIN_LOGITS_BYTES
 
 
+@trace.scope("readout_xent")
 def readout_xent(out, params, labels, vocab, blocked):
     """Per-token xent from the model output against the tied embedding.
 
@@ -419,8 +426,9 @@ def make_train_step(model, tx):
         loss, grads = jax.value_and_grad(
             lambda p: loss_fn(model, p, batch, step_key)
         )(params)
-        updates, opt_state = tx.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        with trace.scope("optimizer"):
+            updates, opt_state = tx.update(grads, opt_state, params)
+            params = optax.apply_updates(params, updates)
         return params, opt_state, loss
 
     return train_step
@@ -466,6 +474,7 @@ def _prune_spec(spec, mesh):
     return P(*cleaned)
 
 
+@trace.span("trial.init")
 def sharded_init(init_fn, mesh: Mesh, seed: int = 0):
     """Run ``init_fn(key)`` with outputs materialized directly sharded.
 
@@ -482,6 +491,7 @@ def sharded_init(init_fn, mesh: Mesh, seed: int = 0):
     return (*out, shardings)
 
 
+@trace.span("trial.setup")  # the first jax.devices() of a trial, as a rule
 def trial_setup(hparams: Dict[str, Any], mesh: Optional[Mesh],
                 tp: int, sp: int, ep: int, steps: int):
     """The shared trial-harness preamble: mesh assembly + optimizer.
@@ -574,13 +584,21 @@ def train_and_eval(
             donate_argnums=(0, 1),
         )
         loss = None
-        for i in range(steps):
-            lo = (i * batch_size) % (n_train - batch_size + 1)
-            sl = slice(lo, lo + batch_size)
-            batch = shard_batch(mesh, (src[sl], tgt[sl]))
-            params, opt_state, loss = step_fn(
-                params, opt_state, batch, jax.random.fold_in(kstep, i)
-            )
+        with trace.span("trial.train", steps=steps):
+            for i in range(steps):
+                with trace.span("slice_and_shard_batch"):
+                    lo = (i * batch_size) % (n_train - batch_size + 1)
+                    sl = slice(lo, lo + batch_size)
+                    batch = shard_batch(mesh, (src[sl], tgt[sl]))
+                with trace.span("dispatch_step"):
+                    params, opt_state, loss = step_fn(
+                        params, opt_state, batch,
+                        jax.random.fold_in(kstep, i)
+                    )
+            if loss is not None:
+                # the loop runs ahead of the device; the save and the
+                # float(loss) below would wait for it anyway, once
+                loss.block_until_ready()
     if save_dir:
         from metaopt_tpu.models.checkpoint import save_state
 

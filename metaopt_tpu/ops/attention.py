@@ -51,6 +51,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from metaopt_tpu.utils import trace
+
 _NEG_BIG = -1e30
 _SUBLANE = 8  # pad granularity for sequences shorter than a block
 
@@ -521,6 +523,7 @@ def _flash(q, k, v, mask, key, dropout_rate, block_q, block_k, impl,
     return out
 
 
+@trace.scope("attention.core")
 def _flash_fwd_rule(q, k, v, mask, key, dropout_rate, block_q, block_k, impl,
                     interpret):
     if impl == "pallas":
@@ -530,6 +533,7 @@ def _flash_fwd_rule(q, k, v, mask, key, dropout_rate, block_q, block_k, impl,
     return out, (q, k, v, mask, key, out, lse)
 
 
+@trace.scope("attention.core")  # a backward rule has no forward name stack
 def _flash_bwd_rule(dropout_rate, block_q, block_k, impl, interpret,
                     residuals, g):
     q, k, v, mask, key, out, lse = residuals
@@ -549,6 +553,7 @@ def _flash_bwd_rule(dropout_rate, block_q, block_k, impl, interpret,
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
+@trace.scope("attention.core")
 def _reference_attention(q, k, v, mask, dropout_rate=0.0, dropout_key=None):
     """Plain XLA attention (f32 softmax) — the O(S²)-HBM fallback/oracle."""
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)

@@ -27,6 +27,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from metaopt_tpu.utils import trace
+
 
 def pick_block_v(vocab: int, target: int = 4096) -> int:
     """Largest divisor of ``vocab`` ≤ target (the scan's tile width).
@@ -97,6 +99,7 @@ def blocked_softmax_xent(y, emb, labels, block_v: int = 2048):
     return loss
 
 
+@trace.scope("readout_xent")
 def _xent_fwd_impl(y, emb, labels, block_v):
     v = emb.shape[0]
     assert v % block_v == 0, (v, block_v)
@@ -111,6 +114,7 @@ def _xent_fwd(y, emb, labels, block_v):
     return loss, (y, emb, labels, lse)
 
 
+@trace.scope("readout_xent")  # a backward rule has no forward name stack
 def _xent_bwd(block_v, res, g):
     """dY, dEmb from recomputed per-block probabilities.
 

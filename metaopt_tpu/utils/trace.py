@@ -1,0 +1,347 @@
+"""The program's one trace layer: host spans, compile records and device
+scope names, on one clock and in one vocabulary.
+
+A span is ``(name, start_ns, end_ns, id, parent, trial, pid, attrs)`` on
+``time.time_ns()``, kept in a bounded ring in this process, always. While a
+profiler trace runs, the same span is a ``jax.profiler.TraceAnnotation`` on
+the ``/host:CPU`` plane, which counts from the file's ``profile_start_time``
+on that same clock (measured on the v5e: the two agree to 0.01 ms), so
+worker, executor, trial child and the child's device trace lie on one
+axis. Importing this module never imports jax: spans annotate only where jax is already loaded, so the hunt
+parent and the launchers stay off it. The ring is written at exit to
+``<METAOPT_TPU_PROFILE_DIR>/<trial id or worker id>/spans.jsonl`` when that
+variable is set (``hunt --profile-dir``), and never otherwise.
+
+``python -m metaopt_tpu.utils.trace DIR`` reads what a sweep left under DIR.
+"""
+
+from __future__ import annotations
+
+import atexit
+import collections
+import contextlib
+import itertools
+import json
+import os
+import statistics
+import sys
+import threading
+import time
+from typing import Any, Dict, Iterator, List, Optional
+
+#: every host span there is, by the layer that records it
+SPANS = (
+    "worker.trial", "worker.reserve", "worker.report",
+    "producer.observe", "producer.suggest",
+    "executor.spawn", "executor.wait", "executor.collect",
+    "trial.start", "trial.setup", "trial.data", "trial.init",
+    "trial.restore", "trial.train", "trial.eval", "trial.save",
+    "trial.report", "slice_and_shard_batch", "dispatch_step",
+    "compile", "profiler.trace",
+)
+#: every device scope (``jax.named_scope``) of the train steps
+SCOPES = ("embed", "attention", "attention.core", "ffn", "readout_xent",
+          "optimizer", "eval")
+#: spans a train loop makes every step. The ring keeps one whole only if it
+#: has a child (the step that compiled); the others are summed into the
+#: enclosing span's ``attrs["per_step"]`` as ``{name: [count, seconds]}``, so a
+#: trial of thousands of steps does not push its own phases out of the ring
+PER_STEP = ("slice_and_shard_batch", "dispatch_step")
+PROFILE_DIR_ENV = "METAOPT_TPU_PROFILE_DIR"
+RING = 8192
+
+_ring: collections.deque = collections.deque(maxlen=RING)
+_ids = itertools.count(1)
+_local = threading.local()  # .stack: open span ids; .compile: pending phases
+_info = json.loads(os.environ.get("METAOPT_TPU_TRIAL_INFO") or "{}")
+#: what a trial child inherits from its executor: its id, and the span that
+#: covers its life there (``executor.wait``) as the parent of its top-level
+#: spans
+_trial: Optional[str] = _info.get("id")
+_root: Optional[str] = _info.get("under")
+_owner: Optional[str] = _trial
+_dump_dir: Optional[str] = None
+_watching = False
+
+
+def new_id() -> str:
+    return f"{os.getpid():x}.{next(_ids):x}"
+
+
+def _new(name: str, id: Optional[str], trial: Optional[str],
+         attrs: Dict[str, Any]) -> dict:
+    """A span under the innermost open one of this thread, not yet timed."""
+    assert name in SPANS, name
+    stack = getattr(_local, "stack", None)
+    over = stack[-1] if stack else {"id": _root, "trial": _trial}
+    return {"name": name, "start_ns": None, "end_ns": None,
+            "id": id or new_id(), "parent": over["id"],
+            "trial": trial or over["trial"], "pid": os.getpid(),
+            "attrs": attrs}
+
+
+def record(name: str, start_ns: int, end_ns: int, *, id: Optional[str] = None,
+           parent: Optional[str] = None, trial: Optional[str] = None,
+           **attrs: Any) -> dict:
+    """One finished span into the ring."""
+    rec = _new(name, id, trial, attrs)
+    rec.update(start_ns=int(start_ns), end_ns=int(end_ns),
+               parent=parent or rec["parent"])
+    _ring.append(rec)
+    return rec
+
+
+@contextlib.contextmanager
+def span(name: str, *, id: Optional[str] = None, trial: Optional[str] = None,
+         **attrs: Any) -> Iterator[dict]:
+    """Time the block as span ``name``; yields the record, whose times are
+    set and which joins the ring when the block ends (one of ``PER_STEP``
+    without a child is summed into the span around it instead)."""
+    rec = _new(name, id, trial, attrs)
+    jax = sys.modules.get("jax")
+    note = contextlib.nullcontext()
+    if jax is not None and hasattr(jax, "profiler"):
+        watch_compiles()
+        note = jax.profiler.TraceAnnotation(name)
+    stack = _local.__dict__.setdefault("stack", [])
+    stack.append(rec)
+    rec["start_ns"] = time.time_ns()
+    try:
+        with note:
+            yield rec
+    finally:
+        rec["end_ns"] = time.time_ns()
+        stack.pop()
+        if name in PER_STEP and stack and not (
+                _ring and _ring[-1]["parent"] == rec["id"]):
+            summed = stack[-1]["attrs"].setdefault("per_step", {}).setdefault(
+                name, [0, 0.0])
+            summed[0] += 1
+            summed[1] += seconds(rec)
+        else:
+            _ring.append(rec)
+
+
+def seconds(rec: dict) -> float:
+    return (rec["end_ns"] - rec["start_ns"]) * 1e-9
+
+
+def scope(name: str):
+    """``jax.named_scope(name)`` for a name of ``SCOPES``: a context manager
+    or a decorator; the name shows in every traced op's ``op_name``."""
+    assert name in SCOPES, name
+    import jax
+
+    return jax.named_scope(name)
+
+
+def spans(name: Optional[str] = None) -> List[dict]:
+    return [s for s in list(_ring) if name is None or s["name"] == name]
+
+
+def owner(worker_id: str) -> None:
+    """Name this process's dump after ``worker_id`` (a trial's id wins)."""
+    global _owner
+    _owner = _owner or worker_id
+
+
+def spawn_info() -> dict:
+    """What rides to a child in ``METAOPT_TPU_TRIAL_INFO`` from under an
+    ``executor.spawn`` span: that span's id and the stamp, from which the
+    child closes ``trial.start`` at its import, and the id ``under`` which
+    the executor will record the child's life (``executor.wait``), the
+    parent of the child's other top-level spans."""
+    stack = getattr(_local, "stack", None)
+    return {"span": stack[-1]["id"] if stack else None, "under": new_id(),
+            "spawn_ns": time.time_ns()}
+
+
+# -- compile records --------------------------------------------------------
+
+_PHASES = {"/jax/core/compile/jaxpr_trace_duration": "trace_s",
+           "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+           "/jax/compilation_cache/cache_retrieval_time_sec": "retrieval_s",
+           "/jax/core/compile/backend_compile_duration": "backend_s"}
+
+
+def _fn(fun_name: Any) -> str:
+    """``train_step`` from jax 0.9.0's ``jit(train_step)``, ``jit_train_step``."""
+    name = str(fun_name or "")
+    if name.startswith("jit(") and name.endswith(")"):
+        return name[4:-1]
+    return name.removeprefix("jit_")
+
+
+def _heard_duration(event: str, duration_secs: float, **kw: Any) -> None:
+    """Phases of this thread's compile requests, kept by function name until
+    the backend phase closes one: inner jits are traced on the way and
+    ``eval_shape`` traces what never compiles, so only the name tells which
+    trace and lowering were the request's own."""
+    phase = _PHASES.get(event)
+    if phase is None:
+        return
+    now = time.time_ns()
+    fn = _fn(kw.get("fun_name"))
+    pending = _local.__dict__.setdefault("compile", {})
+    mine = pending.setdefault(fn, {})
+    # heard twice before the close (eval_shape traces, then jit finds that
+    # trace again): the earlier start, both durations
+    start, secs = mine.get(phase, (now, 0.0))
+    mine[phase] = (min(start, now - int(duration_secs * 1e9)),
+                   round(secs + duration_secs, 6))
+    if phase == "backend_s":
+        _local.compile = {}
+        mine = {**pending.get("", {}), **pending[fn]}  # retrieval is unnamed
+        phases = {k: v for k, v in mine.items() if k != "hit"}
+        record("compile", min(s for s, _ in phases.values()), now, fn=fn,
+               cache_hit="hit" in mine,
+               **{k: d for k, (_, d) in phases.items()})
+
+
+def _heard_event(event: str, **_: Any) -> None:
+    if event == "/jax/compilation_cache/cache_hits":
+        _local.__dict__.setdefault("compile", {}).setdefault(
+            "", {})["hit"] = True
+
+
+def watch_compiles() -> None:
+    """Register the one ``jax.monitoring`` listener (needs jax; idempotent)."""
+    global _watching
+    if not _watching:
+        _watching = True
+        from jax import monitoring
+
+        monitoring.register_event_duration_secs_listener(_heard_duration)
+        monitoring.register_event_listener(_heard_event)
+
+
+@contextlib.contextmanager
+def profile(trace_dir: str) -> Iterator[None]:
+    """A ``jax.profiler`` trace of the block into ``trace_dir``, inside a
+    span ``profiler.trace``. The planes of the file count from its
+    ``profile_start_time`` (``Task Environment`` plane; nanoseconds on this
+    same wall clock), which falls inside ``start_trace``."""
+    import jax
+
+    os.makedirs(trace_dir, exist_ok=True)
+    with span("profiler.trace", dir=trace_dir):
+        jax.profiler.start_trace(trace_dir)
+        try:
+            yield
+        finally:
+            jax.profiler.stop_trace()
+
+
+# -- the dump, and its reader -----------------------------------------------
+
+def dump(path: str) -> None:
+    """The ring as JSON lines."""
+    os.makedirs(os.path.dirname(os.path.abspath(path)), exist_ok=True)
+    with open(path, "w") as f:
+        for rec in spans():
+            f.write(json.dumps(rec, default=str) + "\n")
+
+
+def trial_dir(base: str) -> str:
+    return os.path.join(base, str(_owner or f"pid{os.getpid()}"))
+
+
+def dump_under(base: Optional[str]) -> None:
+    """Dump at exit to ``<base>/<trial or worker id>/spans.jsonl``."""
+    global _dump_dir
+    if base and _dump_dir is None:
+        _dump_dir = base
+
+        def _at_exit():
+            try:
+                dump(os.path.join(trial_dir(base), "spans.jsonl"))
+            except OSError:
+                pass  # the directory went away: nothing to tell anyone
+
+        atexit.register(_at_exit)
+
+
+def load(base: str) -> List[dict]:
+    """Every span dumped under ``base``. A full ring has lost its process's
+    earliest spans, whose time then reads as their parent's own: said on
+    stderr."""
+    out = []
+    for root, _, files in os.walk(base):
+        if "spans.jsonl" in files:
+            with open(os.path.join(root, "spans.jsonl")) as f:
+                recs = list(map(json.loads, f))
+            if len(recs) >= RING:
+                print(f"warning: {root}/spans.jsonl is a full ring ({RING}): "
+                      "its earliest spans are lost", file=sys.stderr)
+            out += recs
+    return out
+
+
+def self_ns(rec: dict, by_parent: Dict[str, List[dict]]) -> int:
+    """A span's time less what its children cover of it (their union)."""
+    at, end, covered = rec["start_ns"], rec["end_ns"], 0
+    for s, e in sorted((c["start_ns"], min(c["end_ns"], end))
+                       for c in by_parent.get(rec["id"], ())):
+        if e > at:
+            covered += e - max(s, at)
+            at = e
+    return end - rec["start_ns"] - covered
+
+
+def table(recs: List[dict]) -> List[dict]:
+    """Per phase over the trials: median and largest seconds, self time,
+    share of ``worker.trial``; and the gap between one ``worker.trial`` and
+    the next of the same worker process as ``(hand-off)``."""
+    by_parent: Dict[str, List[dict]] = collections.defaultdict(list)
+    for r in recs:
+        by_parent[r["parent"]].append(r)
+    per: Dict[str, Dict[Any, List[float]]] = collections.defaultdict(
+        lambda: collections.defaultdict(lambda: [0.0, 0.0]))
+    for r in recs:
+        own = self_ns(r, by_parent) * 1e-9
+        for name, (_, secs) in r["attrs"].get("per_step", {}).items():
+            step = per[name][r["trial"]]  # the steps summed at the source
+            step[0] += secs
+            step[1] += secs
+            own -= secs
+        cell = per[r["name"]][r["trial"]]
+        cell[0] += seconds(r)
+        cell[1] += own
+    roots = sorted((r for r in recs if r["name"] == "worker.trial"),
+                   key=lambda r: (r["pid"], r["start_ns"]))
+    for a, b in zip(roots, roots[1:]):
+        if a["pid"] == b["pid"]:
+            gap = (b["start_ns"] - a["end_ns"]) * 1e-9
+            per["(hand-off)"][b["trial"]] = [gap, gap]
+    whole = statistics.median(
+        [v[0] for v in per.get("worker.trial", {}).values()] or [0.0])
+    rows = []
+    for name, cells in per.items():
+        tot, own = zip(*cells.values())
+        rows.append({"phase": name, "trials": len(cells),
+                     "median_s": statistics.median(tot), "max_s": max(tot),
+                     "self_median_s": statistics.median(own),
+                     "share": statistics.median(own) / whole if whole else None})
+    return sorted(rows, key=lambda r: -r["self_median_s"])
+
+
+def main(argv: List[str]) -> int:
+    recs = load(argv[0])
+    print(f"{len(recs)} spans under {argv[0]}; self = a span less its "
+          "children; share = median self / median worker.trial")
+    print(f"{'phase':<24}{'trials':>7}{'median s':>10}{'max s':>10}"
+          f"{'self s':>10}{'share':>8}")
+    for r in table(recs):
+        share = "" if r["share"] is None else f"{100 * r['share']:.1f}%"
+        print(f"{r['phase']:<24}{r['trials']:>7}{r['median_s']:>10.3f}"
+              f"{r['max_s']:>10.3f}{r['self_median_s']:>10.3f}{share:>8}")
+    return 0
+
+
+dump_under(os.environ.get(PROFILE_DIR_ENV))
+if "spawn_ns" in _info:  # a trial child: from the executor's Popen to here
+    record("trial.start", _info["spawn_ns"], time.time_ns(),
+           parent=_info.get("span"))
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv[1:]))

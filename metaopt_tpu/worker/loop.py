@@ -20,6 +20,7 @@ from metaopt_tpu.algo.base import BaseAlgorithm, make_algorithm
 from metaopt_tpu.executor.base import Executor
 from metaopt_tpu.ledger.experiment import Experiment
 from metaopt_tpu.ledger.trial import Trial
+from metaopt_tpu.utils import trace
 from metaopt_tpu.worker.producer import Producer, RemoteProducer
 
 log = logging.getLogger(__name__)
@@ -97,6 +98,7 @@ def workon(
         # lives server-side) fall back to the experiment's suggest pool
         cohort = algo.cohort_size if algo is not None else None
         batch_size = cohort or max(int(experiment.pool_size or 1), 8)
+    trace.owner(worker_id)  # names this process's spans.jsonl, if one is due
     batch_size = int(batch_size)
     if batch_size > 1:
         if not hasattr(executor, "execute_batch"):
@@ -249,22 +251,24 @@ def workon(
                         "expected_status": "reserved",
                         "expected_worker": worker_id,
                     }
-                last_cycle = producer.cycle(
-                    stale_timeout_s=heartbeat_timeout_s if sweep else None,
-                    produce=produce_cycle,
-                    complete=complete,
-                )
+                with trace.span("worker.reserve", fused=True):
+                    last_cycle = producer.cycle(
+                        stale_timeout_s=heartbeat_timeout_s if sweep else None,
+                        produce=produce_cycle,
+                        complete=complete,
+                    )
                 if complete is not None:
                     _resolve_push(bool(last_cycle.get("completed_ok")))
                 produced = last_cycle["registered"]
                 trial = last_cycle["trial"]
             else:
-                if sweep:
-                    experiment.ledger.release_stale(
-                        experiment.name, heartbeat_timeout_s
-                    )
-                produced = producer.produce()
-                trial = experiment.reserve_trial(worker_id)
+                with trace.span("worker.reserve"):
+                    if sweep:
+                        experiment.ledger.release_stale(
+                            experiment.name, heartbeat_timeout_s
+                        )
+                    produced = producer.produce()
+                    trial = experiment.reserve_trial(worker_id)
             if sweep:
                 last_sweep = now
 
@@ -304,81 +308,87 @@ def workon(
                 stats.suspended += 1
                 _settle("suspended")
                 continue
-            log.debug("%s running trial %s %s", worker_id, trial.id[:8], trial.params)
-            t0 = time.time()
-            try:
-                res = executor.execute(
-                    trial,
-                    heartbeat=heartbeat_for(
+            # the root of this trial on this worker: the executor's and the
+            # child's spans hang under it by the trial's id
+            with trace.span("worker.trial", id=trial.id, trial=trial.id):
+                log.debug("%s running trial %s %s", worker_id, trial.id[:8], trial.params)
+                t0 = time.time()
+                try:
+                    res = executor.execute(
                         trial,
-                        # safe to answer the executor's immediate first beat
-                        # locally: the fused reply just told us this fresh
-                        # reservation has no pending signal
-                        primed=(last_cycle is not None
-                                and last_cycle.get("fused", False)
-                                and last_cycle.get("signal") is None),
-                    ),
-                    judge=judge_fn,
-                )
-            except KeyboardInterrupt:
-                trial.transition("interrupted")
-                experiment.ledger.update_trial(
-                    trial, expected_status="reserved", expected_worker=worker_id
-                )
-                stats.interrupted += 1
-                raise
+                        heartbeat=heartbeat_for(
+                            trial,
+                            # safe to answer the executor's immediate first beat
+                            # locally: the fused reply just told us this fresh
+                            # reservation has no pending signal
+                            primed=(last_cycle is not None
+                                    and last_cycle.get("fused", False)
+                                    and last_cycle.get("signal") is None),
+                        ),
+                        judge=judge_fn,
+                    )
+                except KeyboardInterrupt:
+                    trial.transition("interrupted")
+                    experiment.ledger.update_trial(
+                        trial, expected_status="reserved", expected_worker=worker_id
+                    )
+                    stats.interrupted += 1
+                    raise
 
-            trial.exit_code = res.exit_code
-            if res.status == "completed":
-                if fused:
-                    # defer the terminal update: it rides the next worker_cycle
-                    # (the cycle is due immediately anyway), so the steady-state
-                    # coord cost is ~1 RPC per trial instead of 2. The server
-                    # applies it before its produce/reserve legs — same order
-                    # as push-then-cycle — and the reply's counts/doneness
-                    # already include it, so no _settle here.
-                    trial.attach_results(res.results)
-                    trial.transition("completed")
-                    pending_push = (trial, int("pruned" in res.note))
-                else:
-                    ok = experiment.push_results(trial, res.results)
-                    if ok:
-                        stats.completed += 1
-                        _settle("completed")
-                        if "pruned" in res.note:
-                            stats.pruned += 1
+                trial.exit_code = res.exit_code
+                if res.status == "completed":
+                    if fused:
+                        # defer the terminal update: it rides the next worker_cycle
+                        # (the cycle is due immediately anyway), so the steady-state
+                        # coord cost is ~1 RPC per trial instead of 2. The server
+                        # applies it before its produce/reserve legs — same order
+                        # as push-then-cycle — and the reply's counts/doneness
+                        # already include it, so no _settle here.
+                        trial.attach_results(res.results)
+                        trial.transition("completed")
+                        pending_push = (trial, int("pruned" in res.note))
                     else:
-                        log.warning(
-                            "%s lost reservation of %s before result push",
-                            worker_id, trial.id,
+                        with trace.span("worker.report"):
+                            ok = experiment.push_results(trial, res.results)
+                        if ok:
+                            stats.completed += 1
+                            _settle("completed")
+                            if "pruned" in res.note:
+                                stats.pruned += 1
+                        else:
+                            log.warning(
+                                "%s lost reservation of %s before result push",
+                                worker_id, trial.id,
+                            )
+                else:
+                    trial.transition(res.status)
+                    with trace.span("worker.report", status=res.status):
+                        experiment.ledger.update_trial(
+                            trial, expected_status="reserved",
+                            expected_worker=worker_id
                         )
-            else:
-                trial.transition(res.status)
-                experiment.ledger.update_trial(
-                    trial, expected_status="reserved", expected_worker=worker_id
+                    _settle(res.status)
+                    stats.broken += res.status == "broken"
+                    stats.interrupted += res.status == "interrupted"
+                    if res.status == "broken":
+                        # the note carries the evidence (exit code + stderr tail);
+                        # at INFO it is invisible under the default CLI level and
+                        # the eventual max_broken ERROR reads as evidence-free
+                        last_broken_note = res.note
+                        if res.note:
+                            log.warning(
+                                "%s: trial %s broken: %s",
+                                worker_id, trial.id[:8], res.note)
+                    elif res.note:
+                        log.info("trial %s %s: %s", trial.id[:8], res.status, res.note)
+                stats.events.append(
+                    {
+                        "trial": trial.id,
+                        "status": res.status,
+                        "runtime_s": round(time.time() - t0, 4),
+                        "note": res.note,
+                    }
                 )
-                _settle(res.status)
-                stats.broken += res.status == "broken"
-                stats.interrupted += res.status == "interrupted"
-                if res.status == "broken":
-                    # the note carries the evidence (exit code + stderr tail);
-                    # at INFO it is invisible under the default CLI level and
-                    # the eventual max_broken ERROR reads as evidence-free
-                    last_broken_note = res.note
-                    if res.note:
-                        log.warning(
-                            "%s: trial %s broken: %s",
-                            worker_id, trial.id[:8], res.note)
-                elif res.note:
-                    log.info("trial %s %s: %s", trial.id[:8], res.status, res.note)
-            stats.events.append(
-                {
-                    "trial": trial.id,
-                    "status": res.status,
-                    "runtime_s": round(time.time() - t0, 4),
-                    "note": res.note,
-                }
-            )
 
     except BaseException:
         # error exits (coordinator unavailable, executor blow-ups, the

@@ -13,6 +13,7 @@ from typing import Any, Dict, Optional
 
 from metaopt_tpu.algo.base import BaseAlgorithm
 from metaopt_tpu.ledger.experiment import Experiment
+from metaopt_tpu.utils import trace
 
 log = logging.getLogger(__name__)
 
@@ -36,7 +37,50 @@ class Producer:
     def produce(self, pool_size: Optional[int] = None) -> int:
         """One observe→suggest→register cycle; returns #trials registered."""
         exp = self.experiment
-        t0 = time.perf_counter()
+        with trace.span("producer.observe") as observed:
+            self._observe(exp)
+        self.timings["observe_s"] += trace.seconds(observed)
+        self.timings["cycles"] += 1
+
+        if self.algorithm.is_done:
+            self.algo_done = True
+            exp.mark_algo_done()
+            return 0
+
+        # don't flood the ledger past max_trials with pending work
+        pending = exp.count(("new", "reserved"))
+        completed = exp.count("completed")
+        budget_left = exp.max_trials - completed - pending
+        want = min(pool_size or exp.pool_size, max(0, budget_left))
+        if want <= 0:
+            return 0
+
+        with trace.span("producer.suggest", want=want) as suggested:
+            points = self.algorithm.suggest(want)
+        self.timings["suggest_s"] += trace.seconds(suggested)
+        self.timings["suggested"] += len(points)
+        if not points:
+            return 0
+        # PBT-style algorithms mark continuations with the reserved
+        # ``_parent`` key: the trial whose checkpoint the new one resumes
+        trials = [
+            exp.make_trial(
+                {k: v for k, v in p.items() if k != "_parent"},
+                parent=p.get("_parent"),
+            )
+            for p in points
+        ]
+        kept = exp.register_trials(trials)
+        if len(kept) < len(trials):
+            log.debug(
+                "producer: %d/%d suggestions were duplicates",
+                len(trials) - len(kept), len(trials),
+            )
+        return len(kept)
+
+    def _observe(self, exp: Experiment) -> None:
+        """The observe half of a cycle: warm start once, then the trials
+        completed since the last cycle, then the in-flight ones."""
         if not self._warm_started:
             # warm start (lineage EVC role): replay another experiment's
             # completions into the algorithm once, before first suggest —
@@ -87,44 +131,6 @@ class Producer:
             # the fit with a lie objective so N racing workers don't pile
             # suggestions onto points already being evaluated
             self.algorithm.set_pending(exp.fetch_trials("reserved"))
-        self.timings["observe_s"] += time.perf_counter() - t0
-        self.timings["cycles"] += 1
-
-        if self.algorithm.is_done:
-            self.algo_done = True
-            exp.mark_algo_done()
-            return 0
-
-        # don't flood the ledger past max_trials with pending work
-        pending = exp.count(("new", "reserved"))
-        completed = exp.count("completed")
-        budget_left = exp.max_trials - completed - pending
-        want = min(pool_size or exp.pool_size, max(0, budget_left))
-        if want <= 0:
-            return 0
-
-        t1 = time.perf_counter()
-        points = self.algorithm.suggest(want)
-        self.timings["suggest_s"] += time.perf_counter() - t1
-        self.timings["suggested"] += len(points)
-        if not points:
-            return 0
-        # PBT-style algorithms mark continuations with the reserved
-        # ``_parent`` key: the trial whose checkpoint the new one resumes
-        trials = [
-            exp.make_trial(
-                {k: v for k, v in p.items() if k != "_parent"},
-                parent=p.get("_parent"),
-            )
-            for p in points
-        ]
-        kept = exp.register_trials(trials)
-        if len(kept) < len(trials):
-            log.debug(
-                "producer: %d/%d suggestions were duplicates",
-                len(trials) - len(kept), len(trials),
-            )
-        return len(kept)
 
     def _seed_transfer_priors(self, transfer, meta) -> None:
         """Seed the algorithm from EVC-admissible ancestors (ISSUE 16c).
