@@ -183,8 +183,9 @@ def bench_transformer(seq: int = 256, batch: int = 64,
     Shapes are Transformer-base (BASELINE config 4) at realistic
     sequence lengths — MFU at seq 64 measured mostly fixed overhead, which
     is not the number behind BASELINE's trials/hour north star. Attention
-    rides the chunked flash path (the TPU default in
-    ops/attention.attention_impl) so the O(S²) logits tensor never exists.
+    rides the route ops/attention.attention_route picks (on TPU the Pallas
+    kernels, the chunked twin under dropout) so the O(S²) logits tensor
+    never exists.
 
     ``force_xent``: the A/B control — ``"materializing"`` disables the
     blocked online-softmax xent (ops/xent.py) so the f32 (B, T, V) logits
@@ -372,9 +373,9 @@ def bench_flash_pallas() -> dict:
 
     Runs ``ops/attention._pallas_forward`` through ``flash_attention(
     impl='pallas', interpret=False)`` at Transformer-base attention shapes,
-    checks numerics against the chunked twin, and times the forward. This
-    is the record ``attention_impl()``'s docstring points at before anyone
-    flips the Pallas path to default-on.
+    checks numerics against the chunked twin, and times the forward from
+    the host (PERF.md has the per-call device times that made the Pallas
+    route ``attention_impl()``'s TPU default).
     """
     import jax
     import jax.numpy as jnp

@@ -1,11 +1,11 @@
 """Flash-vs-chunked attention sweep: seq × block shapes, fwd + bwd.
 
-The Pallas kernel has never been timed against the chunked twin where
-flash matters. This sweep times forward and full-grad steps for both
-impls at seq 4096→256 (descending — the crossover data first),
-causal-masked by default, over a small grid of (block_q, block_k), and
-records per-seq ratios plus the crossover — the data that decides
-attention_impl()'s TPU default. ``--unmasked`` adds the unmasked study,
+This sweep times, from the host, forward and full-grad steps of the Pallas
+kernels and the chunked twin at seq 4096→256 (descending — the crossover
+data first), causal-masked by default, over a small grid of (block_q,
+block_k), and records per-seq ratios plus the crossover. (PERF.md holds
+the per-call device times at seq 256/512/1024 behind attention_impl()'s
+TPU default.) ``--unmasked`` adds the unmasked study,
 ``--grid`` the full block grid.
 
 One process, on the chip (without a TPU it exits non-zero):

@@ -22,7 +22,7 @@ chip while it runs.
   sweep    5-trial TPE hunt over examples/transformer_wmt.py; every trial
            completed on the TPU with a finite loss, the hunt process
            itself on the CPU
-  kernels  the Pallas flash forward and both backward kernels, masked and
+  kernels  the Pallas flash forward and backward kernels, masked and
            unmasked, bf16, interpret=False, against the float32 reference;
            one TPE suggest at 10k observations and one GP-BO suggest at 1k
            on the chip
@@ -481,7 +481,7 @@ def child_kernels(cfg, rehearsal):
                    "interpret": rehearsal, "rel_err": errs,
                    "compile_and_first_run_s": round(t1 - t0, 2),
                    "run_s": round(t2 - t1, 4)}
-            say(f"kernels: pallas fwd+dkv+dq {row}")
+            say(f"kernels: pallas fwd+bwd {row}")
             check(max(errs.values()) <= tol,
                   f"error {errs} over {tol} at {row['shape']}")
             out["kernels"].append(row)

@@ -62,7 +62,7 @@ class MHA(nn.Module):
         v = dense("v")(kv_in)
         from metaopt_tpu.ops.attention import (
             _reference_attention,
-            attention_impl,
+            attention_route,
             flash_attention,
             sharded_flash_attention,
         )
@@ -113,9 +113,7 @@ class MHA(nn.Module):
                 dropout_rate=rate, dropout_key=key,
             ))
 
-        impl = attention_impl()
-        if impl == "pallas" and rate > 0.0:
-            impl = "chunked"  # the Pallas forward carries no dropout RNG
+        impl = attention_route(rate)
         if impl is None:
             out = _reference_attention(q, k, v, m3, rate, key)
         else:
@@ -491,7 +489,6 @@ def sharded_init(init_fn, mesh: Mesh, seed: int = 0):
     return (*out, shardings)
 
 
-@trace.span("trial.setup")  # the first jax.devices() of a trial, as a rule
 def trial_setup(hparams: Dict[str, Any], mesh: Optional[Mesh],
                 tp: int, sp: int, ep: int, steps: int):
     """The shared trial-harness preamble: mesh assembly + optimizer.
@@ -500,15 +497,31 @@ def trial_setup(hparams: Dict[str, Any], mesh: Optional[Mesh],
     carves an expert axis for MoE FFNs (n_experts hparam). Used by both
     zoo training harnesses (seq2seq below, decoder-only LM in lm.py) so
     mesh/scheduler behavior cannot drift between families.
+
+    Its span says which attention route the trial's steps take
+    (``attrs["attention"]``): the dropout rate decides between the Pallas
+    kernels and the chunked twin (ops/attention.attention_route).
     """
+    from metaopt_tpu.ops.attention import attention_route
     from metaopt_tpu.parallel.mesh import trial_mesh
 
-    extra = []
-    if sp > 1:
-        extra.append(("sp", sp))
-    if ep > 1:
-        extra.append(("ep", ep))
-    mesh = mesh or trial_mesh(tp=tp, extra_axes=tuple(extra))
+    # the span holds the first jax.devices() of a trial, as a rule
+    with trace.span("trial.setup") as setup:
+        extra = []
+        if sp > 1:
+            extra.append(("sp", sp))
+        if ep > 1:
+            extra.append(("ep", ep))
+        mesh = mesh or trial_mesh(tp=tp, extra_axes=tuple(extra))
+        dropout = float(hparams.get("dropout", 0.1))
+        if sp > 1:  # MHA's sequence-parallel branch comes first
+            from metaopt_tpu.ops.ulysses import sp_impl
+
+            route = lambda rate: sp_impl()  # noqa: E731
+        else:
+            route = lambda rate: attention_route(rate) or "reference"  # noqa: E731
+        setup["attrs"]["attention"] = {
+            "dropout": dropout, "train": route(dropout), "eval": route(0.0)}
     lr = float(hparams.get("lr", 1e-3))
     warmup = int(hparams.get("warmup", 10))
     sched = optax.warmup_cosine_decay_schedule(

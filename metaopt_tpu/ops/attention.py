@@ -6,37 +6,41 @@ The plain XLA path materializes the (B, H, Sq, Sk) logits tensor in HBM —
 O(S²) memory traffic, the classic attention bottleneck. Two memory-efficient
 implementations share one custom-VJP wrapper:
 
-- ``impl="pallas"`` — a Pallas TPU kernel: blocked **online-softmax**
-  attention that keeps Q·Kᵀ tiles in VMEM, carries running (max,
-  denominator, accumulator) statistics across K blocks, and never writes the
-  quadratic logits to HBM. MXU does the two matmuls per tile; the VPU
-  handles the rescaling. Compiles via Mosaic on the TPU; tests off the
-  chip pass ``interpret=True``.
+- ``impl="pallas"`` — Pallas TPU kernels: blocked **online-softmax**
+  attention whose score tiles live and die in VMEM. A program is one batch
+  row, all its heads and one tile of the sequence (up to 512 long, the
+  whole sequence where it is no longer); the MXU takes the operands at the
+  width they arrive in (bfloat16 from the models) and accumulates in
+  float32, the VPU does the softmax in float32. Compiles via Mosaic on the
+  TPU; tests off the chip pass ``interpret=True``.
 - ``impl="chunked"`` — the same blocked online-softmax as a ``lax.scan``
   over K blocks in plain XLA. Live tiles are O(Sq·block_k), never
-  O(Sq·Sk). This twin compiles on ANY backend and supports
-  attention-probability dropout, reproduced bit-exactly in the backward
-  from the same ``fold_in`` counter stream.
+  O(Sq·Sk), but each scan step's float32 tile goes through HBM. This twin
+  compiles on ANY backend and supports attention-probability dropout,
+  reproduced bit-exactly in the backward from the same ``fold_in`` counter
+  stream.
 
 Backward is blockwise recompute from the saved (q, k, v, mask, lse) — the
 forward emits the per-row logsumexp for exactly this — so peak memory
 stays O(Sq·block_k) per step and the forward's HBM saving is preserved
-through training. Two formulations: ``impl="pallas"`` (dropout-free)
-runs the two-pass Pallas kernels (``_flash_bwd_dkv_kernel`` parallel
-over K blocks + ``_flash_bwd_dq_kernel`` parallel over Q blocks — TPU
-has no cross-program atomics, so each pass owns its outputs exclusively);
-everything else uses the chunked ``lax.scan`` formulation, which also
-replays dropout bit-exactly from the same ``fold_in`` counter stream.
+through training. ``impl="pallas"`` (dropout-free) runs one Pallas kernel,
+``_flash_bwd_kernel``: a program owns a K tile's dK and dV and adds its
+share of dQ to a float32 scratch that the K tiles of a batch row, run in
+order, sum (TPU has no cross-program atomics); everything else uses the
+chunked ``lax.scan`` formulation, which also replays dropout.
 
 Irregular sequence lengths are padded up to block multiples with masked
-tails (``_block_and_pad``); block sizes never exceed the requested
-block_q/block_k.
+tails; block sizes follow the shapes (``_derived_block`` for the kernels,
+128-wide K blocks for the scan) unless a caller names them, and then never
+exceed what it names (``_block_and_pad``).
 
 ``MHA`` in metaopt_tpu.models.transformer routes here by default on TPU
-backends (chunked twin; see :func:`attention_impl` for the selection table
-and why the Pallas kernel stays opt-in), and wraps
-the call in ``shard_map`` over the trial mesh (batch on "dp", heads on
-"tp") via :func:`sharded_flash_attention` — attention is embarrassingly
+backends: a call without dropout (every evaluation step, every training
+step at dropout 0) takes the Pallas kernels, a call with dropout the
+chunked twin (:func:`attention_route`; :func:`attention_impl` has the
+``METAOPT_TPU_FLASH`` table). On a trial mesh the call is wrapped in
+``shard_map`` (batch on "dp", heads on "tp") via
+:func:`sharded_flash_attention` — attention is embarrassingly
 parallel over (batch, head), so each shard runs the kernel locally and the
 Megatron head split survives instead of GSPMD all-gathering q/k/v.
 """
@@ -44,12 +48,14 @@ Megatron head split survives instead of GSPMD all-gathering q/k/v.
 from __future__ import annotations
 
 import functools
+import math
 import os
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from metaopt_tpu.utils import trace
 
@@ -71,302 +77,329 @@ def _block_and_pad(size: int, target: int) -> tuple:
     return target, -(-size // target) * target
 
 
-# ---------------------------------------------------------------------------
-# Pallas forward kernel
+def _derived_block(size: int) -> tuple:
+    """(block, padded_size) for the Pallas kernels when the caller names none.
 
-
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref,
-                      *, block_k: int):
-    """One (batch·head, q-block) program: online softmax over K blocks.
-
-    Shapes in VMEM: q (1, Bq, D); k/v (1, Sk, D); mask (1, Bq, Sk) int8 or
-    None; o (1, Bq, D); lse (1, Bq, 1) — the trailing singleton keeps the
-    block's last two dims (Bq, 1) legal under Mosaic's (÷8, ÷128-or-equal)
-    tiling rule; a (1, Bq) block over a (B·H, Sq) array is rejected.
+    A tile is on the lane axis of the score tile in one place and on its
+    sublane axis in another, so it is a multiple of 128, or the whole
+    (32-aligned: the int8 mask's sublane tile) axis when that is shorter.
+    The largest of 512, 256, 128 that divides the length: at seq 512 and
+    1024 a 512-tile halves the kernels' time against 256 (PERF.md), and a
+    (512, 512) float32 score tile with its temporaries still sits well
+    inside VMEM.
     """
-    q = q_ref[0].astype(jnp.float32)                      # (Bq, D)
-    bq, d = q.shape
-    sk = k_ref.shape[1]
-    n_blocks = sk // block_k
+    if size < 128:
+        p = -(-size // 32) * 32
+        return p, p
+    block = next(t for t in (512, 256, 128) if size % t == 0 or t == 128)
+    return block, -(-size // block) * block
 
-    m0 = jnp.full((bq, 1), -jnp.inf, jnp.float32)
-    l0 = jnp.zeros((bq, 1), jnp.float32)
-    acc0 = jnp.zeros((bq, d), jnp.float32)
 
-    def body(i, carry):
-        m, l, acc = carry
-        kb = k_ref[0, pl.ds(i * block_k, block_k), :].astype(jnp.float32)
-        vb = v_ref[0, pl.ds(i * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(                           # (Bq, Bk) on MXU
-            q, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        if mask_ref is not None:
-            # int8 (not i1): Mosaic's sub-byte bool tiling is a pitfall
-            mb = mask_ref[0, :, pl.ds(i * block_k, block_k)]
-            s = jnp.where(mb != 0, s, _NEG_BIG)
-        # floor the running max above the mask fill: a fully-masked block
-        # would otherwise get exp(s - m) = exp(0) = 1 (uniform attention)
+# ---------------------------------------------------------------------------
+# Pallas kernels: forward and backward
+#
+# One layout for both: FEATURE-MAJOR, ``(B, H*D, S)``. q, k, v, dO
+# and the outputs are the projections' ``(B, S, H*D)`` with the last two
+# axes swapped, which is how XLA's TPU matmuls leave and take a
+# ``(B, S, 512)`` activation anyway (sequence on the lanes: the swap is a
+# bitcast there, and no copy stands around a call); a head is an aligned
+# D-row slice. A program is one batch row, ALL its heads and one tile of
+# the sequence; the other sequence is resident in VMEM and walked tile by
+# tile. Matmul operands keep the dtype they arrive in (bfloat16 from the
+# models: one MXU pass, as XLA's default precision gives the chunked twin's
+# float32 einsums) and accumulate in float32; running max, denominator,
+# lse, delta, exp and the accumulators are float32, and p / ds are cast
+# only as matmul operands.
+#
+# Both kernels work on the TRANSPOSED score tile ``sT = k @ q.T`` (keys on
+# sublanes, queries on lanes). The softmax's reductions over keys then run
+# down the sublanes, elementwise between vregs, and its row statistics
+# (running max, denominator, lse, delta) are lane-dense ``(1, Bq)`` rows.
+# With feature-major operands O.T = v.T @ pT, dQ.T = k.T @ dsT, dV.T =
+# dO.T @ pT.T and dK.T = q.T @ dsT.T are the MXU's own forms, their (D, S)
+# results are stored as they come, and only a (D, Bk) slice of k or v is
+# ever transposed, never an (S, S) tile. The mask arrives transposed too,
+# int8 ``(B, Sk, Sq)``, and becomes an additive float32 bias once a
+# program, shared by its heads; lse and delta travel as ``(B, H, Sq)``.
+
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T
+_NN = (((1,), (0,)), ((), ()))  # a @ b
+_VMEM_FLOOR = 32 << 20          # Mosaic's default scoped limit is 16 MiB
+_VMEM_CEIL = 100 << 20          # of a v5e core's 128 MiB
+
+
+def _dot(a, b, dims=_NN):
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+def _walk(lo, n, body, init):
+    """The loop over tiles lo..n-1 of the resident sequence.
+
+    One tile is no loop: its offset is then the static 0, which a tile
+    narrower than a lane tile (a short sequence) needs under Mosaic. A few
+    are unrolled, many are a fori_loop.
+    """
+    if n - lo == 1:
+        return body(lo, init)
+    return jax.lax.fori_loop(lo, n, body, init, unroll=n <= 4)
+
+
+def _tile(i, block):
+    start = i * block if isinstance(i, int) else pl.multiple_of(i * block,
+                                                                block)
+    return pl.ds(start, block)
+
+
+def _mask_bias(mask_ref):
+    """int8 0/1 mask block -> additive float32 bias, 0 or the mask fill.
+
+    int8 (not i1) in memory, and arithmetic rather than a select on
+    ``mask != 0``: Mosaic's sub-byte bool tiling and its i1 relayouts are
+    both pitfalls.
+    """
+    return (mask_ref[0].astype(jnp.float32) - 1.0) * -_NEG_BIG
+
+
+def _flash_fwd_kernel(*refs, n_heads: int, block_k: int, masked: bool):
+    """One (batch row, q tile) program: online softmax over K tiles.
+
+    Shapes in VMEM: q, o (1, H*D, Bq); k, v (1, H*D, Sk); mask (1, Sk, Bq)
+    int8; lse (1, H, Bq) float32; bias scratch (Sk, Bq) float32.
+    """
+    if masked:
+        q_ref, k_ref, v_ref, mask_ref, o_ref, lse_ref, bias_ref = refs
+        bias_ref[...] = _mask_bias(mask_ref)
+    else:
+        q_ref, k_ref, v_ref, o_ref, lse_ref = refs
+    bq = q_ref.shape[2]
+    d = q_ref.shape[1] // n_heads
+    n_k = k_ref.shape[2] // block_k
+
+    def head(hh):
+        return slice(hh * d, (hh + 1) * d)
+
+    def scores(hh, i):
+        """Head hh's masked sT against K tile i: (Bk, Bq)."""
+        ks = _tile(i, block_k)
+        st = _dot(k_ref[0, head(hh), ks].T, q_ref[0, head(hh), :])
+        return st + bias_ref[ks, :] if masked else st
+
+    def fold(hh, i, st, carry):
+        m, l, acc = carry                                   # (1, Bq) x2, (D, Bq)
+        # floor the running max above the mask fill: a fully-masked tile
+        # would otherwise get exp(s - m) = exp(0) = 1
         m_new = jnp.maximum(
-            jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True)), 0.5 * _NEG_BIG
-        )
-        alpha = jnp.exp(m - m_new)                         # rescale old stats
-        p = jnp.exp(s - m_new)
-        l_new = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
-        acc_new = alpha * acc + jax.lax.dot_general(
-            p, vb, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        return m_new, l_new, acc_new
+            jnp.maximum(m, jnp.max(st, axis=0, keepdims=True)),
+            0.5 * _NEG_BIG)
+        alpha = jnp.exp(m - m_new)                          # rescale old stats
+        pt = jnp.exp(st - m_new)
+        l_new = alpha * l + jnp.sum(pt, axis=0, keepdims=True)
+        vt = v_ref[0, head(hh), _tile(i, block_k)]          # (D, Bk)
+        return m_new, l_new, alpha * acc + _dot(vt, pt.astype(vt.dtype))
 
-    m, l, acc = jax.lax.fori_loop(0, n_blocks, body, (m0, l0, acc0))
-    # fully-masked rows have l == 0; emit zeros rather than NaNs, and an
-    # lse of +inf so the blockwise backward recomputes p == 0 for them
-    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
-    lse_ref[0] = jnp.where(
-        l > 0, m + jnp.log(jnp.maximum(l, 1e-30)), jnp.inf
-    )
+    first = scores(0, 0)
+    for hh in range(n_heads):
+        st = first
+        if hh + 1 < n_heads:
+            # the next head's first scores are issued BEFORE this head's
+            # softmax: the two are independent, and in this order the MXU
+            # works under the VPU's exp (42 % off the kernel at seq 256)
+            first = scores(hh + 1, 0)
+        carry = fold(hh, 0, st, (jnp.full((1, bq), -jnp.inf, jnp.float32),
+                                 jnp.zeros((1, bq), jnp.float32),
+                                 jnp.zeros((d, bq), jnp.float32)))
+        if n_k > 1:
+            carry = _walk(
+                1, n_k, lambda i, c, hh=hh: fold(hh, i, scores(hh, i), c),
+                carry)
+        m, l, acc = carry
+        # fully-masked rows have l == 0; emit zeros rather than NaNs, and
+        # an lse of +inf so the backward recomputes p == 0 for them
+        o_ref[0, head(hh), :] = (
+            acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        lse_ref[0, hh:hh + 1, :] = jnp.where(
+            l > 0, m + jnp.log(jnp.maximum(l, 1e-30)), jnp.inf)
 
 
+def _flash_bwd_kernel(*refs, n_heads: int, block_q: int, masked: bool):
+    """One (batch row, k tile) program: the tile's dK and dV, and its share
+    of dQ.
+
+    p is recomputed from the saved lse (p = exp(s - lse)), the same
+    normalized-probability recomputation the chunked twin uses; ds =
+    p * (dO @ V.T - delta) with delta = rowsum(dO * O) made in XLA. One
+    pass: each score tile is made once and feeds all three gradients. TPU
+    has no cross-program atomics, so dQ is summed in a float32 scratch
+    over the K tiles of a batch row, which run in order on one core (the
+    grid's second axis is "arbitrary"), and written after the last.
+
+    Shapes in VMEM: k, v, dk, dv (1, H*D, Bk); q, dO, dq (1, H*D, Sq); mask
+    (1, Bk, Sq) int8; lse, delta (1, H, Sq); dq scratch (H*D, Sq) and bias
+    scratch (Bk, Sq) float32.
+    """
+    if masked:
+        (q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref, mask_ref,
+         dq_ref, dk_ref, dv_ref, acc_ref, bias_ref) = refs
+        bias_ref[...] = _mask_bias(mask_ref)
+    else:
+        (q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref,
+         dq_ref, dk_ref, dv_ref, acc_ref) = refs
+    bk = k_ref.shape[2]
+    d = k_ref.shape[1] // n_heads
+    n_q = q_ref.shape[2] // block_q
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    for hh in range(n_heads):
+        head = slice(hh * d, (hh + 1) * d)
+        kt = k_ref[0, head, :]                              # (D, Bk)
+        kb = kt.T
+        vb = v_ref[0, head, :].T
+
+        def body(i, carry, kt=kt, kb=kb, vb=vb, hh=hh, head=head):
+            dk, dv = carry
+            qs = _tile(i, block_q)
+            qt = q_ref[0, head, qs]                         # (D, Bq)
+            gt = g_ref[0, head, qs]
+            st = _dot(kb, qt)                               # (Bk, Bq)
+            if masked:
+                st = st + bias_ref[:, qs]
+            # fully-masked rows carry lse = +inf from the forward -> p = 0
+            pt = jnp.exp(st - lse_ref[0, hh:hh + 1, qs])
+            dst = (pt * (_dot(vb, gt) - delta_ref[0, hh:hh + 1, qs])
+                   ).astype(qt.dtype)
+            acc_ref[head, qs] += _dot(kt, dst)              # dQ.T (D, Bq)
+            return (dk + _dot(qt, dst, _NT),                # dK.T (D, Bk)
+                    dv + _dot(gt, pt.astype(gt.dtype), _NT))
+
+        dk, dv = _walk(0, n_q, body, (jnp.zeros((d, bk), jnp.float32),
+                                   jnp.zeros((d, bk), jnp.float32)))
+        dk_ref[0, head, :] = dk.astype(dk_ref.dtype)
+        dv_ref[0, head, :] = dv.astype(dv_ref.dtype)
+
+    @pl.when(pl.program_id(1) == pl.num_programs(1) - 1)
+    def _():
+        dq_ref[0] = acc_ref[...].astype(dq_ref.dtype)
+
+
+def _call(kernel, name, grid, semantics, in_specs, out_specs, out_shape,
+          scratch, operands, interpret, **static):
+    """pallas_call with the VMEM limit its blocks (double-buffered) and
+    float32 scratch need."""
+    blocks = [(sp.block_shape, x.dtype) for sp, x in zip(in_specs, operands)]
+    blocks += [(sp.block_shape, o.dtype) for sp, o in zip(out_specs, out_shape)]
+    need = 2 * sum(math.prod(shp) * jnp.dtype(dt).itemsize
+                   for shp, dt in blocks)
+    need += sum(4 * math.prod(shp) for shp in scratch)
+    # the score tiles and their temporaries live beside the blocks
+    limit = min(max(2 * need, _VMEM_FLOOR), _VMEM_CEIL)
+    return pl.pallas_call(
+        functools.partial(kernel, **static),
+        out_shape=out_shape, grid=grid, in_specs=in_specs,
+        out_specs=out_specs, name=name,
+        scratch_shapes=[pltpu.VMEM(shp, jnp.float32) for shp in scratch],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=semantics, vmem_limit_bytes=limit),
+        interpret=interpret,
+    )(*operands)
+
+
+# jitted, so that a model's call sites share one trace and one lowering:
+# jax lowers a jitted callee once a module (18 call sites of a 6-layer
+# Transformer: 2 Mosaic kernels lowered at every retrace, not 36), and XLA
+# still names each inlined copy's ops after its own call site.
+_shared = functools.partial(
+    jax.jit, static_argnames=("block_q", "block_k", "interpret"))
+
+_rows = lambda i, j: (i, j, 0)   # noqa: E731  a tile of rows, every lane
+_lanes = lambda i, j: (i, 0, j)  # noqa: E731  every row, a tile of lanes
+_whole = lambda i, j: (i, 0, 0)  # noqa: E731
+
+
+def _feature_major(x):
+    """(B, S, H, D) -> (B, H*D, S)."""
+    b, s, h, d = x.shape
+    return x.reshape(b, s, h * d).transpose(0, 2, 1)
+
+
+def _mask_t(mask):
+    """(B, Sq, Sk) bool -> (B, Sk, Sq) int8, as the kernels read it."""
+    return mask.astype(jnp.int8).transpose(0, 2, 1)
+
+
+def _heads_last(xt, h):
+    """(B, H*D, S) -> (B, S, H, D)."""
+    b, hd, s = xt.shape
+    return xt.transpose(0, 2, 1).reshape(b, s, h, hd // h)
+
+
+@_shared
 def _pallas_forward(q, k, v, mask, block_q, block_k, interpret):
     """(out, lse) via the Pallas kernel. Shapes pre-padded to block multiples."""
     b, sq, h, d = q.shape
     sk = k.shape[1]
+    hd = h * d
 
-    # head-major flattening: one grid row per (batch, head)
-    qf = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-    kf = k.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
-    vf = v.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
-
-    grid = (b * h, sq // block_q)
-    in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
-        pl.BlockSpec((1, sk, d), lambda bh, qi: (bh, 0, 0)),
-        pl.BlockSpec((1, sk, d), lambda bh, qi: (bh, 0, 0)),
-    ]
-    operands = [qf, kf, vf]
+    in_specs = [pl.BlockSpec((1, hd, block_q), _lanes),
+                pl.BlockSpec((1, hd, sk), _whole),
+                pl.BlockSpec((1, hd, sk), _whole)]
+    operands = [_feature_major(q), _feature_major(k), _feature_major(v)]
+    scratch = []
     if mask is not None:
-        in_specs.append(
-            # mask is per-batch (heads share it): index by bh // h
-            pl.BlockSpec((1, block_q, sk), lambda bh, qi, h=h: (bh // h, qi, 0))
-        )
-        operands.append(mask.astype(jnp.int8))
-        kernel = functools.partial(_flash_fwd_kernel, block_k=block_k)
-    else:
-        def kernel(q_ref, k_ref, v_ref, o_ref, lse_ref):
-            _flash_fwd_kernel(q_ref, k_ref, v_ref, None, o_ref, lse_ref,
-                              block_k=block_k)
-
-    out, lse = pl.pallas_call(
-        kernel,
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h, sq, 1), jnp.float32),
-        ],
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
-            pl.BlockSpec((1, block_q, 1), lambda bh, qi: (bh, qi, 0)),
-        ],
-        interpret=interpret,
-    )(*operands)
-    return (out.reshape(b, h, sq, d).transpose(0, 2, 1, 3),
-            lse.reshape(b, h, sq))
+        in_specs.append(pl.BlockSpec((1, sk, block_q), _lanes))
+        operands.append(_mask_t(mask))
+        scratch.append((sk, block_q))
+    out, lse = _call(
+        _flash_fwd_kernel, "flash_fwd", (b, sq // block_q),
+        ("parallel", "parallel"), in_specs,
+        [pl.BlockSpec((1, hd, block_q), _lanes),
+         pl.BlockSpec((1, h, block_q), _lanes)],
+        [jax.ShapeDtypeStruct((b, hd, sq), q.dtype),
+         jax.ShapeDtypeStruct((b, h, sq), jnp.float32)],
+        scratch, operands, interpret, n_heads=h, block_k=block_k,
+        masked=mask is not None)
+    return _heads_last(out, h), lse
 
 
-# ---------------------------------------------------------------------------
-# Pallas backward kernels (FlashAttention-2 style: two passes, no atomics)
-
-
-def _flash_bwd_dkv_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref,
-                          mask_ref, dk_ref, dv_ref, *, block_q: int):
-    """One (batch·head, k-block) program: dK/dV over all Q blocks.
-
-    TPU has no cross-program atomics, so the backward splits into a dKV
-    pass (this kernel, parallel over K blocks) and a dQ pass (below,
-    parallel over Q blocks) — each output is owned by exactly one
-    program. Shapes in VMEM: q/g (1, Sq, D) full; k/v (1, Bk, D) block;
-    lse/delta (1, Sq, 1) full; mask (1, Sq, Bk) int8 block or None.
-    p is recomputed from the saved lse (p = exp(s − lse)), the same
-    normalized-probability recomputation the chunked twin uses; ds =
-    p ⊙ (dO·Vᵀ − delta) with delta = rowsum(dO ⊙ O) precomputed in XLA.
-    """
-    kb = k_ref[0].astype(jnp.float32)                      # (Bk, D)
-    vb = v_ref[0].astype(jnp.float32)
-    bk, d = kb.shape
-    sq = q_ref.shape[1]
-    n_blocks = sq // block_q
-
-    dk0 = jnp.zeros((bk, d), jnp.float32)
-    dv0 = jnp.zeros((bk, d), jnp.float32)
-
-    def body(i, carry):
-        dk, dv = carry
-        qb = q_ref[0, pl.ds(i * block_q, block_q), :].astype(jnp.float32)
-        gb = g_ref[0, pl.ds(i * block_q, block_q), :].astype(jnp.float32)
-        lse_b = lse_ref[0, pl.ds(i * block_q, block_q), :]  # (Bq, 1) f32
-        delta_b = delta_ref[0, pl.ds(i * block_q, block_q), :]
-        s = jax.lax.dot_general(                            # (Bq, Bk)
-            qb, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        if mask_ref is not None:
-            mb = mask_ref[0, pl.ds(i * block_q, block_q), :]
-            s = jnp.where(mb != 0, s, _NEG_BIG)
-        # fully-masked rows carry lse = +inf from the forward → p = 0
-        p = jnp.exp(s - lse_b)
-        gp = jax.lax.dot_general(                           # dO·Vᵀ (Bq, Bk)
-            gb, vb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (gp - delta_b)
-        dv_new = dv + jax.lax.dot_general(                  # pᵀ·dO (Bk, D)
-            p, gb, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        dk_new = dk + jax.lax.dot_general(                  # dsᵀ·q (Bk, D)
-            ds, qb, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        return dk_new, dv_new
-
-    dk, dv = jax.lax.fori_loop(0, n_blocks, body, (dk0, dv0))
-    dk_ref[0] = dk.astype(dk_ref.dtype)
-    dv_ref[0] = dv.astype(dv_ref.dtype)
-
-
-def _flash_bwd_dq_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref,
-                         mask_ref, dq_ref, *, block_k: int):
-    """One (batch·head, q-block) program: dQ over all K blocks."""
-    qb = q_ref[0].astype(jnp.float32)                      # (Bq, D)
-    gb = g_ref[0].astype(jnp.float32)
-    lse_b = lse_ref[0]                                     # (Bq, 1) f32
-    delta_b = delta_ref[0]
-    bq, d = qb.shape
-    sk = k_ref.shape[1]
-    n_blocks = sk // block_k
-
-    def body(i, dq):
-        kb = k_ref[0, pl.ds(i * block_k, block_k), :].astype(jnp.float32)
-        vb = v_ref[0, pl.ds(i * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            qb, kb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        if mask_ref is not None:
-            mb = mask_ref[0, :, pl.ds(i * block_k, block_k)]
-            s = jnp.where(mb != 0, s, _NEG_BIG)
-        p = jnp.exp(s - lse_b)
-        gp = jax.lax.dot_general(
-            gb, vb, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        ds = p * (gp - delta_b)
-        return dq + jax.lax.dot_general(                    # ds·K (Bq, D)
-            ds, kb, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-
-    dq = jax.lax.fori_loop(0, n_blocks, body, jnp.zeros((bq, d), jnp.float32))
-    dq_ref[0] = dq.astype(dq_ref.dtype)
-
-
+@_shared
 def _pallas_backward(q, k, v, mask, out, lse, g, block_q, block_k, interpret):
-    """(dq, dk, dv) via the two Pallas passes. Shapes pre-padded."""
+    """(dq, dk, dv) via the Pallas kernel. Shapes pre-padded."""
     b, sq, h, d = q.shape
     sk = k.shape[1]
+    hd = h * d
 
-    qf = q.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-    kf = k.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
-    vf = v.transpose(0, 2, 1, 3).reshape(b * h, sk, d)
-    gf = g.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-    of = out.transpose(0, 2, 1, 3).reshape(b * h, sq, d)
-    # delta = rowsum(dO ⊙ O): one fused elementwise+reduce, cheaper in XLA
-    # than re-deriving O inside the kernels
-    delta = jnp.sum(gf.astype(jnp.float32) * of.astype(jnp.float32),
-                    axis=-1, keepdims=True)                # (B·H, Sq, 1)
-    lsef = lse.reshape(b * h, sq, 1)
-    m8 = mask.astype(jnp.int8) if mask is not None else None
-
-    full_q = [
-        pl.BlockSpec((1, sq, d), lambda bh, i: (bh, 0, 0)),      # q
-        pl.BlockSpec((1, sq, d), lambda bh, i: (bh, 0, 0)),      # g
-    ]
-    stats = [
-        pl.BlockSpec((1, sq, 1), lambda bh, i: (bh, 0, 0)),      # lse
-        pl.BlockSpec((1, sq, 1), lambda bh, i: (bh, 0, 0)),      # delta
-    ]
-
-    # pass 1: dK/dV, one program per K block
-    in_specs = full_q + [
-        pl.BlockSpec((1, block_k, d), lambda bh, ki: (bh, ki, 0)),  # k
-        pl.BlockSpec((1, block_k, d), lambda bh, ki: (bh, ki, 0)),  # v
-    ] + stats
-    operands = [qf, gf, kf, vf, lsef, delta]
-    if m8 is not None:
-        in_specs.append(
-            pl.BlockSpec((1, sq, block_k),
-                         lambda bh, ki, h=h: (bh // h, 0, ki))
-        )
-        operands.append(m8)
-        dkv_kernel = functools.partial(_flash_bwd_dkv_kernel,
-                                       block_q=block_q)
-    else:
-        def dkv_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref,
-                       dk_ref, dv_ref):
-            _flash_bwd_dkv_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref,
-                                  delta_ref, None, dk_ref, dv_ref,
-                                  block_q=block_q)
-    # the dKV pass reorders q/g/k/v operands: q/g are the full arrays
-    dk, dv = pl.pallas_call(
-        dkv_kernel,
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h, sk, d), k.dtype),
-            jax.ShapeDtypeStruct((b * h, sk, d), v.dtype),
-        ],
-        grid=(b * h, sk // block_k),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, block_k, d), lambda bh, ki: (bh, ki, 0)),
-            pl.BlockSpec((1, block_k, d), lambda bh, ki: (bh, ki, 0)),
-        ],
-        interpret=interpret,
-    )(*operands)
-
-    # pass 2: dQ, one program per Q block
-    in_specs = [
-        pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),  # q
-        pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),  # g
-        pl.BlockSpec((1, sk, d), lambda bh, qi: (bh, 0, 0)),        # k
-        pl.BlockSpec((1, sk, d), lambda bh, qi: (bh, 0, 0)),        # v
-        pl.BlockSpec((1, block_q, 1), lambda bh, qi: (bh, qi, 0)),  # lse
-        pl.BlockSpec((1, block_q, 1), lambda bh, qi: (bh, qi, 0)),  # delta
-    ]
-    operands = [qf, gf, kf, vf, lsef, delta]
-    if m8 is not None:
-        in_specs.append(
-            pl.BlockSpec((1, block_q, sk),
-                         lambda bh, qi, h=h: (bh // h, qi, 0))
-        )
-        operands.append(m8)
-        dq_kernel = functools.partial(_flash_bwd_dq_kernel, block_k=block_k)
-    else:
-        def dq_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref,
-                      dq_ref):
-            _flash_bwd_dq_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref,
-                                 delta_ref, None, dq_ref, block_k=block_k)
-    dq = pl.pallas_call(
-        dq_kernel,
-        out_shape=jax.ShapeDtypeStruct((b * h, sq, d), q.dtype),
-        grid=(b * h, sq // block_q),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, block_q, d), lambda bh, qi: (bh, qi, 0)),
-        interpret=interpret,
-    )(*operands)
-
-    unflat = lambda t, s: t.reshape(b, h, s, d).transpose(0, 2, 1, 3)  # noqa: E731
-    return unflat(dq, sq), unflat(dk, sk), unflat(dv, sk)
+    # delta = rowsum(dO * O): one fused elementwise+reduce, cheaper in XLA
+    # than re-deriving O inside the kernel
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1).transpose(0, 2, 1)             # (B, H, Sq)
+    in_specs = [pl.BlockSpec((1, hd, sq), _whole),          # q
+                pl.BlockSpec((1, hd, sq), _whole),          # dO
+                pl.BlockSpec((1, hd, block_k), _lanes),     # k
+                pl.BlockSpec((1, hd, block_k), _lanes),     # v
+                pl.BlockSpec((1, h, sq), _whole),           # lse
+                pl.BlockSpec((1, h, sq), _whole)]           # delta
+    operands = [_feature_major(q), _feature_major(g), _feature_major(k),
+                _feature_major(v), lse, delta]
+    scratch = [(hd, sq)]
+    if mask is not None:
+        in_specs.append(pl.BlockSpec((1, block_k, sq), _rows))
+        operands.append(_mask_t(mask))
+        scratch.append((block_k, sq))
+    dq, dk, dv = _call(
+        _flash_bwd_kernel, "flash_bwd", (b, sk // block_k),
+        ("parallel", "arbitrary"), in_specs,
+        [pl.BlockSpec((1, hd, sq), _whole),
+         pl.BlockSpec((1, hd, block_k), _lanes),
+         pl.BlockSpec((1, hd, block_k), _lanes)],
+        [jax.ShapeDtypeStruct((b, hd, sq), q.dtype),
+         jax.ShapeDtypeStruct((b, hd, sk), k.dtype),
+         jax.ShapeDtypeStruct((b, hd, sk), v.dtype)],
+        scratch, operands, interpret, n_heads=h, block_q=block_q,
+        masked=mask is not None)
+    return _heads_last(dq, h), _heads_last(dk, h), _heads_last(dv, h)
 
 
 # ---------------------------------------------------------------------------
@@ -583,8 +616,8 @@ def flash_attention(
     *,
     dropout_rate: float = 0.0,
     dropout_key: Optional[jnp.ndarray] = None,
-    block_q: int = 128,
-    block_k: int = 128,
+    block_q: Optional[int] = None,
+    block_k: Optional[int] = None,
     impl: Optional[str] = None,
     interpret: bool = False,
 ) -> jnp.ndarray:
@@ -594,7 +627,8 @@ def flash_attention(
     k, v: (B, Sk, H, D); mask: optional (B, Sq, Sk) bool, True = attend
     (shared across heads); dropout_rate applies to attention probabilities
     (chunked impl only) with dropout_key. Irregular Sq/Sk are padded to
-    block multiples with masked tails. Returns (B, Sq, H, D) in q's dtype.
+    block multiples with masked tails; ``block_q`` / ``block_k`` left at
+    None follow the shapes. Returns (B, Sq, H, D) in q's dtype.
 
     ``interpret=True`` runs the Pallas kernels in the interpreter (tests
     off the chip). It is never chosen for the caller: ``impl="pallas"``
@@ -609,8 +643,15 @@ def flash_attention(
 
     b, sq, h, d = q.shape
     sk = k.shape[1]
-    bq, sq_p = _block_and_pad(sq, block_q)
-    bk, sk_p = _block_and_pad(sk, block_k)
+    # blocks follow the shapes unless the caller names them: the Pallas
+    # kernels take the largest tile the lengths allow, the chunked scan
+    # its 128-wide K blocks
+    derived = _derived_block if impl == "pallas" else (
+        lambda size: _block_and_pad(size, 128))
+    bq, sq_p = (derived(sq) if block_q is None
+                else _block_and_pad(sq, block_q))
+    bk, sk_p = (derived(sk) if block_k is None
+                else _block_and_pad(sk, block_k))
     if sq_p != sq or sk_p != sk:
         q = jnp.pad(q, ((0, 0), (0, sq_p - sq), (0, 0), (0, 0)))
         k = jnp.pad(k, ((0, 0), (0, sk_p - sk), (0, 0), (0, 0)))
@@ -623,9 +664,16 @@ def flash_attention(
             mask = jnp.broadcast_to(
                 (jnp.arange(sk_p) < sk)[None, None, :], (b, sq_p, sk_p)
             )
+    out_dtype = q.dtype
+    if impl == "pallas":
+        # the kernels' matmul operands are as wide as the narrowest of
+        # q, k, v (MHA's scaled q is float32 beside bfloat16 k and v)
+        narrow = min((q.dtype, k.dtype, v.dtype),
+                     key=lambda t: jnp.dtype(t).itemsize)
+        q, k, v = q.astype(narrow), k.astype(narrow), v.astype(narrow)
     out = _flash(q, k, v, mask, dropout_key, float(dropout_rate), bq, bk,
                  impl, bool(interpret))
-    return out[:, :sq]
+    return out[:, :sq].astype(out_dtype)
 
 
 def sharded_flash_attention(
@@ -676,22 +724,22 @@ def sharded_flash_attention(
 def attention_impl() -> Optional[str]:
     """Which implementation MHA routes through, from ``METAOPT_TPU_FLASH``.
 
-    - unset → backend default: **``chunked`` on TPU** (keeps live
-      attention tiles O(Sq·block_k) instead of the reference path's O(S²)
-      HBM logits tensor, and covers dropout), ``None`` (plain XLA
-      reference) on CPU, where the O(S²) path is faster at test shapes
+    - unset → backend default: **``pallas`` on TPU**, where the kernels
+      keep every score tile in VMEM (PERF.md has the per-call device times
+      against the chunked twin at seq 256, 512 and 1024), ``None`` (plain
+      XLA reference) on CPU, where the O(S²) path is faster at test shapes
       and numerically the oracle.
     - ``0``/``off`` → ``None``: force the plain XLA reference attention.
-    - ``1``/``pallas`` → the Pallas kernel, compiled through Mosaic (TPU
-      only; it raises elsewhere). Attention dropout still routes those
-      calls to the chunked twin. Pallas against chunked has no kernel
-      timing on the current runtime (PERF.md); ``chip_smoke.py`` checks
-      that the kernels compile and match the float32 reference.
+    - ``1``/``pallas`` → the Pallas kernels, compiled through Mosaic (TPU
+      only; they raise elsewhere).
     - ``chunked``/``scan`` → force the lax.scan twin on any backend.
+
+    Whatever this answers, a call WITH attention dropout takes the chunked
+    twin (:func:`attention_route`): the kernels carry no dropout RNG.
     """
     env = (os.environ.get("METAOPT_TPU_FLASH") or "").strip().lower()
     if env in ("", None):
-        return "chunked" if jax.default_backend() == "tpu" else None
+        return "pallas" if jax.default_backend() == "tpu" else None
     if env in ("0", "false", "no", "off"):
         return None
     if env in ("chunked", "scan", "2"):
@@ -702,6 +750,18 @@ def attention_impl() -> Optional[str]:
     raise ValueError(
         f"METAOPT_TPU_FLASH={env!r}: expected off/pallas/chunked"
     )
+
+
+def attention_route(dropout_rate: float) -> Optional[str]:
+    """The implementation a call with this dropout rate takes.
+
+    The one rule the program follows is in its input: no dropout (every
+    evaluation step, every training step at dropout 0) → what
+    :func:`attention_impl` answers; dropout → never Pallas but the chunked
+    twin, whose masks replay bit-exactly in the backward.
+    """
+    impl = attention_impl()
+    return "chunked" if impl == "pallas" and dropout_rate > 0.0 else impl
 
 
 def use_flash_attention() -> bool:

@@ -335,7 +335,20 @@ def main(argv: List[str]) -> int:
         share = "" if r["share"] is None else f"{100 * r['share']:.1f}%"
         print(f"{r['phase']:<24}{r['trials']:>7}{r['median_s']:>10.3f}"
               f"{r['max_s']:>10.3f}{r['self_median_s']:>10.3f}{share:>8}")
+    print_routes(recs)
     return 0
+
+
+def print_routes(recs: List[dict]) -> None:
+    """A line a trial: which attention route its steps took, as its
+    ``trial.setup`` span has it (ops/attention.attention_route)."""
+    for r in recs:
+        route = r["attrs"].get("attention") if r["name"] == "trial.setup" \
+            else None
+        if route:
+            print(f"trial {r['trial']}: attention {route['train']} in "
+                  f"training (dropout {route['dropout']}), {route['eval']} "
+                  "in evaluation")
 
 
 dump_under(os.environ.get(PROFILE_DIR_ENV))

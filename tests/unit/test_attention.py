@@ -13,8 +13,11 @@ import pytest
 
 from metaopt_tpu.ops.attention import (
     _block_and_pad,
+    _derived_block,
+    _pallas_forward,
     _reference_attention,
     attention_impl,
+    attention_route,
     flash_attention,
     sharded_flash_attention,
     use_flash_attention,
@@ -306,6 +309,30 @@ class TestSharded:
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        atol=1e-4, rtol=1e-4)
 
+    def test_sharded_pallas_grads_match(self):
+        """The kernels under shard_map: each shard's (B/dp, S, H/tp, D)
+        slab is a smaller call of the same program."""
+        from metaopt_tpu.parallel.mesh import make_mesh
+
+        mesh = make_mesh([("dp", 2), ("tp", 4)])
+        q, k, v = rand_qkv(jax.random.PRNGKey(44), b=2, sq=16, sk=24, h=4,
+                           d=8)
+        mask = jax.random.bernoulli(jax.random.PRNGKey(45), 0.7, (2, 16, 24))
+        mask = mask.at[:, :, 0].set(True)
+
+        def loss_s(q, k, v):
+            return jnp.sum(sharded_flash_attention(
+                mesh, q, k, v, mask, impl="pallas", interpret=True) ** 2)
+
+        def loss_r(q, k, v):
+            return jnp.sum(_reference_attention(q, k, v, mask) ** 2)
+
+        gs = jax.grad(loss_s, argnums=(0, 1, 2))(q, k, v)
+        gr = jax.grad(loss_r, argnums=(0, 1, 2))(q, k, v)
+        for a, b in zip(gs, gr):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       atol=1e-4, rtol=1e-4)
+
     def test_sharded_dropout_runs(self):
         from metaopt_tpu.parallel.mesh import make_mesh
 
@@ -328,6 +355,43 @@ class TestRouting:
         monkeypatch.setenv("METAOPT_TPU_FLASH", "0")
         assert not use_flash_attention()
         assert attention_impl() is None
+
+    def test_tpu_default_is_pallas_and_dropout_takes_chunked(self, monkeypatch):
+        """The rule is in the call's input: no dropout -> the kernels,
+        dropout -> the chunked twin, whatever the backend default says."""
+        monkeypatch.delenv("METAOPT_TPU_FLASH", raising=False)
+        assert attention_impl() is None  # this process runs on the CPU
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        assert attention_impl() == "pallas"
+        assert attention_route(0.0) == "pallas"
+        assert attention_route(0.1) == "chunked"
+        monkeypatch.setenv("METAOPT_TPU_FLASH", "chunked")
+        assert attention_route(0.0) == attention_route(0.1) == "chunked"
+        monkeypatch.setenv("METAOPT_TPU_FLASH", "off")
+        assert attention_route(0.0) is None and attention_route(0.1) is None
+
+    def test_mha_resolves_the_route_from_its_dropout(self, monkeypatch):
+        from metaopt_tpu.models.transformer import MHA
+        from metaopt_tpu.ops import attention
+
+        monkeypatch.delenv("METAOPT_TPU_FLASH", raising=False)
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        taken = []
+
+        def recorder(q, k, v, mask=None, *, dropout_rate=0.0,
+                     dropout_key=None, impl=None, **kw):
+            taken.append((impl, dropout_rate))  # Mosaic cannot run here
+            return _reference_attention(q, k, v, mask)
+
+        monkeypatch.setattr(attention, "flash_attention", recorder)
+        mha = MHA(d_model=32, n_heads=2, dropout=0.1, partitioned=False)
+        x = jnp.ones((2, 8, 32), jnp.float32)
+        params = mha.init(jax.random.PRNGKey(0), x, x)
+        taken.clear()
+        mha.apply(params, x, x, train=True,
+                  rngs={"dropout": jax.random.PRNGKey(1)})
+        mha.apply(params, x, x, train=False)
+        assert taken == [("chunked", 0.1), ("pallas", 0.0)]
 
     def test_transformer_forward_with_flash(self, monkeypatch):
         """The full demo Transformer runs with the kernel routed in."""
@@ -421,3 +485,104 @@ class TestPallasBackward:
             np.testing.assert_allclose(
                 a.astype(jnp.float32), b, atol=5e-2, rtol=5e-2
             )
+
+
+def _lse_reference(q, k, mask):
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k)
+    if mask is not None:
+        s = jnp.where(mask[:, None], s, -jnp.inf)
+    m = jnp.max(s, axis=-1, keepdims=True)
+    m = jnp.where(jnp.isfinite(m), m, 0.0)
+    lse = m[..., 0] + jnp.log(jnp.sum(jnp.exp(s - m), axis=-1))
+    return jnp.where(jnp.isfinite(lse), lse, jnp.inf)  # no key: +inf
+
+
+def _cell_case(name):
+    """(sq, sk, mask) of one case at the benchmark cell's shape class."""
+    b, sq, sk = 2, 256, 256
+    lens = jnp.array([256, 131])
+    if name == "cross":
+        sq = 128
+    elif name == "long_k":
+        sk = 512
+        lens = jnp.array([512, 300])
+    elif name == "irregular":
+        sq = sk = 200
+        lens = jnp.array([200, 77])
+    pad = jnp.broadcast_to((jnp.arange(sk)[None] < lens[:, None])[:, None],
+                           (b, sq, sk))
+    if name == "none":
+        mask = None
+    elif name in ("causal", "irregular"):
+        mask = pad & jnp.tril(jnp.ones((sq, sk), bool))[None]
+    elif name == "dead_row":
+        mask = pad.at[:, 5, :].set(False).at[1, 200:, :].set(False)
+    else:
+        mask = pad
+    return sq, sk, mask
+
+
+class TestPallasAtTheCellsShape:
+    """The kernels as the benchmark's cell runs them: 8 heads of 64,
+    bfloat16, sequences of 128-512, in interpret mode, against the plain
+    float32 reference fed the same bfloat16 values.
+
+    Tolerances, from what bfloat16 operands give and not from the float32
+    tests above: products of bfloat16 operands are exact in the float32
+    accumulator, so lse (float32 all the way) agrees to float32 rounding;
+    out and the gradients pass p and ds through one bfloat16 rounding as
+    matmul operands and take one more on the way out, 2^-9 = 2e-3 relative
+    each, so 1e-2 of the largest reference value leaves room for both and
+    the summation order (the chip reads 3e-3 to 4.5e-3, PERF.md)."""
+
+    @pytest.mark.parametrize("case", ["none", "padding", "causal", "cross",
+                                      "long_k", "dead_row", "irregular"])
+    def test_forward_lse_and_gradients(self, case):
+        sq, sk, mask = _cell_case(case)
+        ks = jax.random.split(jax.random.PRNGKey(50), 4)
+        q = (jax.random.normal(ks[0], (2, sq, 8, 64)) / 8).astype(jnp.bfloat16)
+        k = jax.random.normal(ks[1], (2, sk, 8, 64)).astype(jnp.bfloat16)
+        v = jax.random.normal(ks[2], (2, sk, 8, 64)).astype(jnp.bfloat16)
+        w = jax.random.normal(ks[3], (2, sq, 8, 64), jnp.float32)
+
+        def loss(attend):
+            return lambda q, k, v: jnp.sum(
+                attend(q, k, v).astype(jnp.float32) * w)
+
+        kernel = lambda q, k, v: flash_attention(  # noqa: E731
+            q, k, v, mask, impl="pallas", interpret=True)
+        out = kernel(q, k, v)
+        grads = jax.grad(loss(kernel), argnums=(0, 1, 2))(q, k, v)
+        assert out.dtype == jnp.bfloat16
+        assert all(g.dtype == jnp.bfloat16 for g in grads)
+        f32 = [t.astype(jnp.float32) for t in (q, k, v)]
+        plain = lambda q, k, v: _reference_attention(q, k, v, mask)  # noqa: E731
+        ref = plain(*f32)
+        ref_grads = jax.grad(loss(plain), argnums=(0, 1, 2))(*f32)
+        for name, a, r in zip(("out", "dq", "dk", "dv"), (out, *grads),
+                              (ref, *ref_grads)):
+            a, r = np.asarray(a, np.float32), np.asarray(r)
+            assert a.shape == r.shape and np.isfinite(a).all(), name
+            assert np.max(np.abs(a - r)) <= 1e-2 * np.max(np.abs(r)), name
+
+        # lse, from the forward as flash_attention pads for it
+        (bq, sq_p), (bk, sk_p) = _derived_block(sq), _derived_block(sk)
+        grow = lambda t, n: jnp.pad(  # noqa: E731
+            t, ((0, 0), (0, n - t.shape[1])) + ((0, 0),) * (t.ndim - 2))
+        full = mask if mask is not None else jnp.ones((2, sq, sk), bool)
+        padded = jnp.pad(full, ((0, 0), (0, sq_p - sq), (0, sk_p - sk)))
+        _, lse = _pallas_forward(grow(q, sq_p), grow(k, sk_p), grow(v, sk_p),
+                                 padded, block_q=bq, block_k=bk,
+                                 interpret=True)
+        want = np.asarray(_lse_reference(f32[0], f32[1], mask))
+        got = np.asarray(lse)[:, :, :sq]
+        alive = np.isfinite(want)
+        assert (got[~alive] == np.inf).all()
+        np.testing.assert_allclose(got[alive], want[alive], atol=1e-4,
+                                   rtol=1e-5)
+
+        if case == "dead_row":  # zeros out, no gradient in or out of it
+            dead = ~np.asarray(mask).any(axis=-1)
+            assert dead[:, 5].all() and dead[1, 200:].all()
+            assert (np.asarray(out, np.float32)[dead] == 0).all()
+            assert (np.asarray(grads[0], np.float32)[dead] == 0).all()

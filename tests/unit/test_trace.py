@@ -237,6 +237,40 @@ def test_the_optimizer_s_ops_are_not_under_a_model_scope(lowered_op_names):
 # -- the trial ----------------------------------------------------------------
 
 
+@pytest.mark.parametrize("backend, flash, dropout, train, evaluation", [
+    ("tpu", None, 0.0, "pallas", "pallas"),
+    ("tpu", None, 0.1, "chunked", "pallas"),
+    ("tpu", "chunked", 0.0, "chunked", "chunked"),
+    ("cpu", None, 0.1, "reference", "reference"),
+])
+def test_trial_setup_s_span_says_which_attention_route_the_steps_take(
+        monkeypatch, capsys, backend, flash, dropout, train, evaluation):
+    import jax
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from metaopt_tpu.models.transformer import trial_setup
+
+    one = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("dp", "tp"))
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if flash is None:
+        monkeypatch.delenv("METAOPT_TPU_FLASH", raising=False)
+    else:
+        monkeypatch.setenv("METAOPT_TPU_FLASH", flash)
+    trial_setup({"dropout": dropout}, one, 1, 1, 1, 100)
+    setup = trace.spans("trial.setup")[-1]
+    assert setup["attrs"]["attention"] == {
+        "dropout": dropout, "train": train, "eval": evaluation}
+    # and the reader prints it, a line a trial
+    rec = dict(setup, trial="T-9")
+    rows = trace.table([rec])
+    assert rows[0]["phase"] == "trial.setup"
+    trace.print_routes([rec])
+    assert capsys.readouterr().out == (
+        f"trial T-9: attention {train} in training (dropout {dropout}), "
+        f"{evaluation} in evaluation\n")
+
+
 def test_train_and_eval_leaves_the_trial_s_phases_in_the_ring(tmp_path):
     from metaopt_tpu.models.transformer import train_and_eval
 
