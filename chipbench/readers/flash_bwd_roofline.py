@@ -1,0 +1,10 @@
+"""% of its roofline the backward attention kernel ``flash_bwd`` reached in
+the traced slice: the operations and bytes its calls need
+(chipbench/flops_lm.py: five products a seen pair) over their device time
+and the chip's peaks (chipbench/kernel_trace.py)."""
+
+from chipbench import kernel_trace
+
+
+def read(records):
+    return kernel_trace.attention_kernel_roofline(records, "flash_bwd")

@@ -12,9 +12,26 @@
 METAOPT_TPU_SP_IMPL=ulysses for the all-to-all variant) — the
 decoder-only model is where long-context sequence parallelism earns
 its keep.
+
+``--model FILE`` names the model by a description: a JSON object of
+``make_lm``'s hyperparameters, the published names included
+(``hidden_size``, ``num_key_value_heads``, ``rope_layout``,
+``sliding_window_layout``, ``moe_num_primary_experts`` ...) and the share
+a chip holds (``experts_held``, ``vocab_held``). The sweep then searches
+the optimizer's hyperparameters over that model. The benchmark prints the
+description of a configuration of its own, SmallThinker-21BA3B's share of
+one chip for example:
+
+    python -m chipbench.lm_config \
+        chipbench/configs/smallthinker-21b-a3b-ep4.json > st.json
+    python -m metaopt_tpu hunt -n st --n-chips 1 --max-trials 8 \
+        examples/lm_causal.py --model=st.json \
+        --seq-len=8192 --batch-size=1 --n-train=64 --steps=20 \
+        --lr~'loguniform(1e-5, 1e-3)'
 """
 
 import argparse
+import json
 
 from metaopt_tpu import client
 from metaopt_tpu.client import report_results
@@ -33,6 +50,11 @@ def main():
     p.add_argument("--sp", type=int, default=1)
     p.add_argument("--ep", type=int, default=1)
     p.add_argument("--n-experts", dest="n_experts", type=int, default=0)
+    p.add_argument("--model", help="a JSON description of the model "
+                   "(make_lm's hyperparameters) in place of the widths above")
+    p.add_argument("--batch-size", dest="batch_size", type=int, default=32)
+    p.add_argument("--n-train", dest="n_train", type=int, default=2048)
+    p.add_argument("--warmup", type=int, default=10)
     a = p.parse_args()
 
     from metaopt_tpu.models.lm import train_lm
@@ -42,12 +64,17 @@ def main():
         # orbax trial checkpoints: PBT handoff / suspended-trial resume
         own, parent = client.checkpoint_paths()
         kw = {"save_dir": own, "restore_dir": parent or own}
+    if a.model:
+        with open(a.model) as f:
+            hparams = dict(json.load(f), lr=a.lr, warmup=a.warmup)
+    else:
+        hparams = {"lr": a.lr, "dropout": a.dropout, "d_model": a.d_model,
+                   "n_layers": a.n_layers, "d_ff": a.d_ff,
+                   "n_heads": max(1, a.d_model // 64),
+                   "n_experts": a.n_experts, "warmup": a.warmup}
     loss = train_lm(
-        {"lr": a.lr, "dropout": a.dropout, "d_model": a.d_model,
-         "n_layers": a.n_layers, "d_ff": a.d_ff,
-         "n_heads": max(1, a.d_model // 64), "n_experts": a.n_experts},
-        tp=a.tp, sp=a.sp, ep=a.ep,
-        seq_len=a.seq_len, steps=a.steps,
+        hparams, tp=a.tp, sp=a.sp, ep=a.ep, seq_len=a.seq_len,
+        steps=a.steps, batch_size=a.batch_size, n_train=a.n_train,
         **kw,
     )
     report_results([{"name": "loss", "type": "objective", "value": loss}])

@@ -36,17 +36,33 @@ against.
 ref: the reference framework has no model code (SURVEY.md §2.8) — this is
 demo-zoo surface, here so trials can exercise expert-parallel shardings
 on gang-scheduled sub-slices.
+
+:class:`DroplessMoE` is the other expert layer, for the decoders whose
+description names ``moe_num_primary_experts``: top-k on the router's
+LOGITS, a softmax over the chosen ones, gated ReLU experts of three
+matrices, and **no capacity**: every (token, choice) item is computed,
+whatever the imbalance. It is told which experts it holds (a contiguous
+share of the published count), routes over all of them, and returns the
+part of the layer's output that its own experts give: the items whose
+expert lives here are ordered by expert and go through three grouped
+matrix products (:func:`grouped_matmul`) whose buffers have the static
+worst-case size, all ``tokens x k`` items. On an ``ep`` mesh axis each
+chip holds ``held / ep`` of them and the parts are summed over the axis;
+on one chip the layer runs without that exchange.
 """
 
 from __future__ import annotations
 
+import functools
 import math
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
 from metaopt_tpu.parallel.sharding import with_mesh_partitioning
+from metaopt_tpu.utils import trace
 
 
 class MoEFeedForward(nn.Module):
@@ -153,3 +169,215 @@ class MoEFeedForward(nn.Module):
         y = jnp.where(kept[:, None], y, 0.0) * gatef[:, None]
         y = jnp.sum(y.reshape(t, k, d), axis=1).reshape(b, s, d)
         return y.astype(x.dtype)
+
+
+# ---------------------------------------------------------------------------
+# dropless routing over the experts held here
+
+
+def _gmm_tiling(m: int, k: int, n: int):
+    """megablox's tiles for an (m, k) x (g, k, n) product: the largest of
+    512, 256, 128 that divides each axis, None for an axis none divides
+    (measured: 128-tiles, megablox's default, are five times slower)."""
+    return tuple(next((t for t in (512, 256, 128) if size % t == 0), None)
+                 for size in (m, k, n))
+
+
+def grouped_matmul_impl(m: int, k: int, n: int) -> str:
+    """Which grouped matrix product :class:`DroplessMoE` runs on (m, k)
+    rows and (g, k, n) weights: the Pallas ``megablox`` kernels on a TPU
+    where a tile divides each axis, ``jax.lax.ragged_dot`` elsewhere. The
+    one place that decides: the layer and ``trial.setup``'s span both ask.
+
+    Measured on the v5e at the benchmark cell's shapes (49 152 rows of
+    which a quarter are filled, 16 groups, 2560 x 768; PERF.md): XLA's own
+    ``ragged_dot`` and megablox at its best tiling take the same time,
+    forward and backward, alone and inside the step, and both skip the
+    rows no group fills. But XLA names its ragged products
+    ``ragged-dot-none`` in a device trace, outside every scope of the
+    program, and megablox's ``gmm`` / ``tgmm`` calls keep theirs; so the
+    TPU takes megablox, and the backends that cannot run its kernels take
+    ``ragged_dot``."""
+    if jax.default_backend() == "tpu" and all(_gmm_tiling(m, k, n)):
+        return "megablox"
+    return "ragged_dot"
+
+
+def grouped_matmul(x, w, group_sizes, impl: str):
+    """``x`` (m, k) rows ordered by group, ``w`` (g, k, n), ``group_sizes``
+    (g,) int32 -> (m, n) in x's dtype: row r of group e times ``w[e]``, by
+    ``impl`` (:func:`grouped_matmul_impl`). Rows past ``sum(group_sizes)``
+    hold nothing a caller may read."""
+    if impl == "megablox":
+        from jax.experimental.pallas.ops.tpu.megablox import ops
+
+        return ops.gmm(x, w, group_sizes, preferred_element_type=x.dtype,
+                       tiling=_gmm_tiling(*x.shape, w.shape[2]))
+    return jax.lax.ragged_dot(x, w, group_sizes,
+                              preferred_element_type=x.dtype)
+
+
+@jax.custom_vjp
+def _take_rows(x, index, inverse):
+    """``x[index]`` for a permutation ``index`` of x's rows with its
+    ``inverse``: the gradient is then a gather too, never a scatter."""
+    return x[index]
+
+
+def _take_rows_fwd(x, index, inverse):
+    return x[index], inverse
+
+
+def _take_rows_bwd(inverse, g):
+    return g[inverse], None, None
+
+
+_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _rows_of_items(x, order, inverse, mine, k: int):
+    """Row ``order[i] // k`` of ``x`` (t, d) for each of the t * k items in
+    dispatch order ``order`` (a permutation with its ``inverse``): what
+    ``repeat(x, k)[order]`` gives, without the repeat. The gradient is a
+    gather by ``inverse`` and a sum over a token's k items, those alone
+    that ``mine`` (t, k) marks: the rows of the others hold nothing."""
+    return x[order // k]
+
+
+def _rows_of_items_fwd(x, order, inverse, mine, k):
+    return x[order // k], (inverse, mine)
+
+
+def _rows_of_items_bwd(k, res, g):
+    inverse, mine = res
+    items = g[inverse].reshape(*mine.shape, g.shape[-1])
+    mine_only = jnp.where(mine[..., None], items, 0).astype(jnp.float32)
+    return jnp.sum(mine_only, axis=1).astype(g.dtype), None, None, None
+
+
+_rows_of_items.defvjp(_rows_of_items_fwd, _rows_of_items_bwd)
+
+
+def route_top_k(logits, k: int):
+    """(weights, experts), both (t, k): the k largest logits of each token
+    and a softmax over those k alone, float32."""
+    with trace.scope("moe.router"):
+        top, experts = jax.lax.top_k(logits.astype(jnp.float32), k)
+        return jax.nn.softmax(top, axis=-1), experts
+
+
+def dropless_experts(x, weights, experts, w_gate, w_up, w_down, first):
+    """The held experts' part of a gated-ReLU expert layer.
+
+    x (t, d); weights, experts (t, k) from :func:`route_top_k`; w_gate,
+    w_up (held, d, f), w_down (held, f, d): experts ``first .. first +
+    held - 1`` of the published ones. Returns (y (t, d) float32, counts):
+    ``sum_k weights * expert(x)`` over the choices whose expert is held,
+    and ``{"items": (held,) int32 a held expert, "dropped": () int32}``.
+    Nothing is dropped: the buffers hold all t * k items, the most that
+    can be routed here. Rows of the buffers that no held item fills are
+    never read into a result: the products skip them, and what comes back
+    in item order is masked by ``mine``.
+    """
+    t, d = x.shape
+    k = experts.shape[1]
+    held = w_gate.shape[0]
+    n = t * k
+    with trace.scope("moe.dispatch"):
+        local = experts - first
+        mine = (local >= 0) & (local < held)                 # (t, k)
+        # items of experts elsewhere sort behind every held expert's
+        group = jnp.where(mine, local, held).astype(jnp.int32).reshape(n)
+        order = jnp.argsort(group, stable=True).astype(jnp.int32)
+        inverse = jnp.argsort(order).astype(jnp.int32)
+        # a count by comparison: a scatter-add into 16 bins is slow on a TPU
+        items = jnp.sum(group[:, None] == jnp.arange(held)[None],
+                        axis=0, dtype=jnp.int32)
+        rows = _rows_of_items(x.astype(jnp.bfloat16), order, inverse, mine, k)
+    with trace.scope("moe.experts"):
+        product = functools.partial(
+            grouped_matmul, group_sizes=items,
+            impl=grouped_matmul_impl(n, d, w_gate.shape[2]))
+        h = nn.relu(product(rows, w_gate.astype(jnp.bfloat16))) \
+            * product(rows, w_up.astype(jnp.bfloat16))
+        out = product(h, w_down.astype(jnp.bfloat16))
+    with trace.scope("moe.combine"):
+        out = _take_rows(out, inverse, order).reshape(t, k, d)
+        y = jnp.sum(jnp.where(mine[..., None], out, 0).astype(jnp.float32)
+                    * weights[..., None], axis=1)
+    # the items that found no row in the buffers: 0 while the buffers have
+    # a row for each of the n items, as they do; buffers cut below that
+    # (a capacity) would move it
+    dropped = jnp.maximum(jnp.sum(items) - rows.shape[0], 0)
+    return y, {"items": items, "dropped": dropped}
+
+
+class DroplessMoE(nn.Module):
+    """Gated-ReLU experts under dropless top-k routing, for the share of the
+    experts held here (module docstring). ``held`` = (first, count) of the
+    ``n_experts`` published ones; the router's logits come from outside
+    (these decoders read them before attention)."""
+
+    d_model: int
+    d_ff: int
+    n_experts: int
+    top_k: int
+    held: Tuple[int, int]
+
+    @nn.compact
+    @trace.scope("moe")
+    def __call__(self, x, logits):
+        from metaopt_tpu.parallel.mesh import active_mesh
+
+        b, s, d = x.shape
+        first, count = self.held
+        if not 0 <= first <= first + count <= self.n_experts:
+            raise ValueError(f"experts held {self.held} are not among the "
+                             f"{self.n_experts} routed over")
+        init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1,
+                                            batch_axis=(0,))
+        w = {name: self.param(
+            name, with_mesh_partitioning(init, ("ep", None, None)), shape)
+            for name, shape in (("gate", (count, d, self.d_ff)),
+                                ("up", (count, d, self.d_ff)),
+                                ("down", (count, self.d_ff, d)))}
+        weights, experts = route_top_k(logits.reshape(b * s, -1), self.top_k)
+        mesh = active_mesh()
+        ep = dict(mesh.shape).get("ep", 1) if mesh is not None else 1
+        if ep == 1:
+            y, counts = dropless_experts(
+                x.reshape(b * s, d), weights, experts, w["gate"], w["up"],
+                w["down"], first)
+        else:
+            y, counts = _over_ep(mesh, ep, x.reshape(b * s, d), weights,
+                                 experts, w, first)
+        self.sow("moe_stats", "items", counts["items"])
+        self.sow("moe_stats", "dropped", counts["dropped"])
+        return y.reshape(b, s, d)
+
+
+def _over_ep(mesh, ep: int, x, weights, experts, w, first: int):
+    """The layer on an ``ep`` mesh axis: each chip holds ``held / ep``
+    experts, computes their part for every token, and the parts are summed
+    over the axis (tokens are not exchanged: every chip of the axis has
+    them all)."""
+    from jax.sharding import PartitionSpec as P
+
+    count = w["gate"].shape[0]
+    if count % ep:
+        raise ValueError(f"{count} held experts do not divide over ep={ep}")
+
+    def local(x, weights, experts, gate, up, down):
+        mine = first + jax.lax.axis_index("ep") * (count // ep)
+        y, counts = dropless_experts(x, weights, experts, gate, up, down,
+                                     mine)
+        return (jax.lax.psum(y, "ep"), counts["items"],
+                jax.lax.psum(counts["dropped"], "ep"))
+
+    y, items, dropped = jax.shard_map(
+        local, mesh=mesh,
+        in_specs=(P(), P(), P(), P("ep"), P("ep"), P("ep")),
+        out_specs=(P(), P("ep"), P()), check_vma=False,
+    )(x, weights, experts, w["gate"], w["up"], w["down"])
+    return y, {"items": items, "dropped": dropped}

@@ -368,7 +368,8 @@ def blocked_xent_enabled(
 
 @trace.scope("readout_xent")
 def readout_xent(out, params, labels, vocab, blocked):
-    """Per-token xent from the model output against the tied embedding.
+    """Per-token xent from the model output against the readout's table
+    (the tied embedding, or the model's untied ``head``).
 
     ``out`` is pre-readout features when ``blocked`` (the f32 (B, T, V)
     logits tensor never exists in HBM — ops/xent.py folds the tied readout
@@ -379,7 +380,8 @@ def readout_xent(out, params, labels, vocab, blocked):
     if blocked:
         from metaopt_tpu.ops.xent import blocked_softmax_xent, pick_block_v
 
-        emb = params["embed"]["embedding"]
+        # an untied head where the model has one (models/lm.py's pattern)
+        emb = params.get("head", params["embed"])["embedding"]
         if hasattr(emb, "unbox"):  # nn.Partitioned leaf (sharded init path)
             emb = emb.unbox()
         feats = out.reshape(-1, out.shape[-1]).astype(jnp.bfloat16)
@@ -490,7 +492,7 @@ def sharded_init(init_fn, mesh: Mesh, seed: int = 0):
 
 
 def trial_setup(hparams: Dict[str, Any], mesh: Optional[Mesh],
-                tp: int, sp: int, ep: int, steps: int):
+                tp: int, sp: int, ep: int, steps: int, describe=None):
     """The shared trial-harness preamble: mesh assembly + optimizer.
 
     sp > 1 shards the sequence axis (ring attention over ICI); ep > 1
@@ -501,6 +503,9 @@ def trial_setup(hparams: Dict[str, Any], mesh: Optional[Mesh],
     Its span says which attention route the trial's steps take
     (``attrs["attention"]``): the dropout rate decides between the Pallas
     kernels and the chunked twin (ops/attention.attention_route).
+    ``describe``, a harness's own, is asked what else the span should say,
+    given the route without dropout (lm.py: each kind of layer's route and
+    mask form, what the expert layers hold and run their products with).
     """
     from metaopt_tpu.ops.attention import attention_route
     from metaopt_tpu.parallel.mesh import trial_mesh
@@ -522,6 +527,9 @@ def trial_setup(hparams: Dict[str, Any], mesh: Optional[Mesh],
             route = lambda rate: attention_route(rate) or "reference"  # noqa: E731
         setup["attrs"]["attention"] = {
             "dropout": dropout, "train": route(dropout), "eval": route(0.0)}
+        if describe is not None:
+            setup["attrs"].update(
+                describe(attention_route(0.0) or "reference"))
     lr = float(hparams.get("lr", 1e-3))
     warmup = int(hparams.get("warmup", 10))
     sched = optax.warmup_cosine_decay_schedule(

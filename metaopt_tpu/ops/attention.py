@@ -1,52 +1,73 @@
-"""Flash attention for the demo-zoo Transformer: Pallas + chunked-XLA twins.
+"""Flash attention for the model zoo: Pallas kernels + a chunked-XLA twin.
 
-The demo-zoo Transformer (BASELINE config 4) is the framework's flagship
-trial workload; its attention is the one genuinely hot op we own end-to-end.
 The plain XLA path materializes the (B, H, Sq, Sk) logits tensor in HBM —
-O(S²) memory traffic, the classic attention bottleneck. Two memory-efficient
-implementations share one custom-VJP wrapper:
+O(S²) memory traffic, the classic attention bottleneck. The
+memory-efficient implementations share one shape of custom VJP:
 
 - ``impl="pallas"`` — Pallas TPU kernels: blocked **online-softmax**
-  attention whose score tiles live and die in VMEM. A program is one batch
-  row, all its heads and one tile of the sequence (up to 512 long, the
-  whole sequence where it is no longer); the MXU takes the operands at the
-  width they arrive in (bfloat16 from the models) and accumulates in
-  float32, the VPU does the softmax in float32. Compiles via Mosaic on the
-  TPU; tests off the chip pass ``interpret=True``.
+  attention whose score tiles live and die in VMEM; the MXU takes the
+  operands at the width they arrive in (bfloat16 from the models) and
+  accumulates in float32, the VPU does the softmax in float32. Compiles
+  via Mosaic on the TPU; tests off the chip pass ``interpret=True``. Two
+  pairs of kernels, chosen by **how the mask is stated**:
+
+  * a dense ``(B, Sq, Sk)`` array or none (the 2017 Transformer's padding
+    and causal masks): ``_flash_fwd_kernel`` / ``_flash_bwd_kernel``. A
+    program is one batch row, all its heads and one tile of the sequence
+    (up to 512 long); the other sequence is resident in VMEM whole, as is
+    the program's slab of the mask. Right for the short sequences those
+    models train on (seq 256-1024).
+  * a :class:`CausalMask` — causal, with a window length or none —
+    ``_causal_fwd_kernel`` / ``_causal_bwd_kernel``: limits computed in
+    the kernel from positions, K/V heads fewer than query heads (query
+    head h reads K/V head ``h // group`` through the block index, no
+    copy), a program per (row, query head, tile) so that one head of the
+    other sequence is all that VMEM holds (bounded at S 8192 and 16 384),
+    and a walk that covers only the tiles the structure lets through:
+    tiles beyond the causal limit or the window are **skipped, not
+    masked**, in forward and backward.
+
 - ``impl="chunked"`` — the same blocked online-softmax as a ``lax.scan``
   over K blocks in plain XLA. Live tiles are O(Sq·block_k), never
   O(Sq·Sk), but each scan step's float32 tile goes through HBM. This twin
   compiles on ANY backend and supports attention-probability dropout,
   reproduced bit-exactly in the backward from the same ``fold_in`` counter
-  stream.
+  stream. It knows one head count and dense masks: grouped K/V heads are
+  repeated and a ``CausalMask`` made dense for it (``_plain_operands``),
+  as for the plain reference.
 
 Backward is blockwise recompute from the saved (q, k, v, mask, lse) — the
 forward emits the per-row logsumexp for exactly this — so peak memory
 stays O(Sq·block_k) per step and the forward's HBM saving is preserved
-through training. ``impl="pallas"`` (dropout-free) runs one Pallas kernel,
-``_flash_bwd_kernel``: a program owns a K tile's dK and dV and adds its
-share of dQ to a float32 scratch that the K tiles of a batch row, run in
-order, sum (TPU has no cross-program atomics); everything else uses the
-chunked ``lax.scan`` formulation, which also replays dropout.
+through training. Each Pallas backward is ONE kernel: a program owns a K
+tile's dK and dV and adds its share of dQ to a float32 scratch that the K
+tiles of a batch row (of a head, under a ``CausalMask``), run in order,
+sum (TPU has no cross-program atomics); everything else uses the chunked
+``lax.scan`` formulation, which also replays dropout.
 
 Irregular sequence lengths are padded up to block multiples with masked
 tails; block sizes follow the shapes (``_derived_block`` for the kernels,
 128-wide K blocks for the scan) unless a caller names them, and then never
 exceed what it names (``_block_and_pad``).
 
-``MHA`` in metaopt_tpu.models.transformer routes here by default on TPU
-backends: a call without dropout (every evaluation step, every training
-step at dropout 0) takes the Pallas kernels, a call with dropout the
-chunked twin (:func:`attention_route`; :func:`attention_impl` has the
+``MHA`` in metaopt_tpu.models.transformer and ``GroupedAttention`` in
+metaopt_tpu.models.lm route here by default on TPU backends: a call
+without dropout (every evaluation step, every training step at dropout 0)
+takes the Pallas kernels, a call with dropout the chunked twin
+(:func:`attention_route`; :func:`attention_impl` has the
 ``METAOPT_TPU_FLASH`` table). On a trial mesh the call is wrapped in
 ``shard_map`` (batch on "dp", heads on "tp") via
 :func:`sharded_flash_attention` — attention is embarrassingly
 parallel over (batch, head), so each shard runs the kernel locally and the
 Megatron head split survives instead of GSPMD all-gathering q/k/v.
+
+Not here: per-row key lengths under a ``CausalMask`` (a padded row still
+needs the dense form), segment ids and packing.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 import os
@@ -403,6 +424,262 @@ def _pallas_backward(q, k, v, mask, out, lse, g, block_q, block_k, interpret):
 
 
 # ---------------------------------------------------------------------------
+# Pallas kernels for a mask stated by structure (CausalMask)
+#
+# The same feature-major operands and transposed score tile as above, with
+# three differences that long sequences force. (1) No mask array: a tile's
+# limits come from its positions, ``0 <= i - j < window``, made from two
+# iotas and one scalar. (2) A program is one batch row, ONE query head and
+# one tile; the head's K/V head (``h // group``) is picked by the block
+# index, so grouped heads cost no copy, and what is resident in VMEM is one
+# head of the other sequence: (D, S), 2 MiB at S 8192 and D 128, whatever
+# the number of heads. (3) The walk over the resident sequence covers only
+# the tiles the structure lets through: tiles beyond the causal limit or
+# the window are skipped, not masked, and of those walked only the ones the
+# diagonal or the window's edge crosses pay for the iota compare.
+
+
+@dataclasses.dataclass(frozen=True)
+class CausalMask:
+    """A mask stated by structure: key ``j`` is seen by query ``i`` iff
+    ``0 <= i - j`` and (``window`` is None or ``i - j < window``). For self
+    attention (as many keys as queries, positions 0..S-1)."""
+
+    window: Optional[int] = None
+
+    def dense(self, sq: int, sk: int):
+        """The (1, sq, sk) boolean array that says the same."""
+        diff = jnp.arange(sq)[:, None] - jnp.arange(sk)[None, :]
+        seen = diff >= 0
+        if self.window is not None:
+            seen &= diff < self.window
+        return seen[None]
+
+
+def _cdiv(a, b: int):
+    return jax.lax.div(a + (b - 1), b)
+
+
+def _walk_ranges(first, last_excl, full_first, full_last_excl):
+    """(lo, full_lo, full_hi, hi): tiles [lo, hi) are walked, those in
+    [full_lo, full_hi) need no mask."""
+    full_lo = jnp.clip(full_first, first, last_excl)
+    full_hi = jnp.clip(full_last_excl, full_lo, last_excl)
+    return first, full_lo, full_hi, last_excl
+
+
+def _k_tiles_of(q0, bq: int, bk: int, n_k: int, window):
+    """K tiles that queries q0..q0+bq-1 see."""
+    hi = jnp.minimum(n_k, _cdiv(q0 + bq, bk))
+    full_hi = jax.lax.div(q0 + 1, bk)
+    if window is None:
+        return _walk_ranges(0, hi, 0, full_hi)
+    lo = jax.lax.div(jnp.maximum(q0 - window + 1, 0), bk)
+    full_lo = _cdiv(jnp.maximum(q0 + bq - window, 0), bk)
+    return _walk_ranges(lo, hi, full_lo, full_hi)
+
+
+def _q_tiles_of(k0, bk: int, bq: int, n_q: int, window):
+    """Q tiles that see keys k0..k0+bk-1."""
+    lo = jax.lax.div(k0, bq)
+    full_lo = _cdiv(k0 + bk - 1, bq)
+    if window is None:
+        return _walk_ranges(lo, n_q, full_lo, n_q)
+    hi = jnp.minimum(n_q, _cdiv(k0 + bk + window - 1, bq))
+    return _walk_ranges(lo, hi, full_lo, jax.lax.div(k0 + window, bq))
+
+
+def _seen(rel, shift, window):
+    """rel + shift = i - j on a (Bk, Bq) tile -> which pairs are seen."""
+    diff = rel + shift
+    seen = diff >= 0
+    return seen if window is None else seen & (diff < window)
+
+
+def _rel(bk: int, bq: int):
+    """(query's offset in its tile) - (key's offset in its tile), (Bk, Bq)."""
+    return (jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 1)
+            - jax.lax.broadcasted_iota(jnp.int32, (bk, bq), 0))
+
+
+def _walk3(ranges, body, init):
+    """Masked edge, unmasked middle, masked edge: three loops whose bounds
+    the program's place in the grid decides."""
+    lo, full_lo, full_hi, hi = ranges
+    c = jax.lax.fori_loop(lo, full_lo, functools.partial(body, True), init)
+    c = jax.lax.fori_loop(full_lo, full_hi, functools.partial(body, False), c)
+    return jax.lax.fori_loop(full_hi, hi, functools.partial(body, True), c)
+
+
+def _causal_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
+                       window):
+    """One (batch row, query head, q tile) program.
+
+    Shapes in VMEM: q, o (1, D, Bq); k, v (1, D, Sk), the head's K/V head;
+    lse (1, 1, 1, Bq) float32.
+    """
+    d, bq = q_ref.shape[1], q_ref.shape[2]
+    q0 = pl.program_id(2) * bq
+    rel = _rel(block_k, bq)
+    q = q_ref[0]
+
+    def fold(masked, i, carry):
+        m, l, acc = carry
+        ks = _tile(i, block_k)
+        st = _dot(k_ref[0, :, ks].T, q)                     # (Bk, Bq)
+        if masked:
+            st = jnp.where(_seen(rel, q0 - i * block_k, window), st,
+                           _NEG_BIG)
+        m_new = jnp.maximum(
+            jnp.maximum(m, jnp.max(st, axis=0, keepdims=True)),
+            0.5 * _NEG_BIG)
+        alpha = jnp.exp(m - m_new)
+        pt = jnp.exp(st - m_new)
+        l_new = alpha * l + jnp.sum(pt, axis=0, keepdims=True)
+        vt = v_ref[0, :, ks]                                # (D, Bk)
+        return m_new, l_new, alpha * acc + _dot(vt, pt.astype(vt.dtype))
+
+    m, l, acc = _walk3(
+        _k_tiles_of(q0, bq, block_k, k_ref.shape[2] // block_k, window),
+        fold, (jnp.full((1, bq), -jnp.inf, jnp.float32),
+               jnp.zeros((1, bq), jnp.float32),
+               jnp.zeros((d, bq), jnp.float32)))
+    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+    lse_ref[0, 0] = jnp.where(l > 0, m + jnp.log(jnp.maximum(l, 1e-30)),
+                              jnp.inf)
+
+
+def _causal_bwd_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref,
+                       dq_ref, dk_ref, dv_ref, acc_ref, *, block_q: int,
+                       window):
+    """One (batch row, query head, k tile) program: this head's share of
+    the tile's dK and dV (the heads of a group are summed outside), and
+    the tile's share of the head's dQ, summed over the K tiles of the head
+    in a float32 scratch as ``_flash_bwd_kernel`` does.
+
+    Shapes in VMEM: k, v (1, D, Bk); dk, dv (1, D, Bk) float32; q, dO, dq
+    (1, D, Sq); lse, delta (1, 1, 1, Sq); dq scratch (D, Sq) float32.
+    """
+    d, bk = k_ref.shape[1], k_ref.shape[2]
+    k0 = pl.program_id(2) * bk
+    rel = _rel(bk, block_q)
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    kt = k_ref[0]                                           # (D, Bk)
+    kb = kt.T
+    vb = v_ref[0].T
+
+    def body(masked, i, carry):
+        dk, dv = carry
+        qs = _tile(i, block_q)
+        qt = q_ref[0, :, qs]                                # (D, Bq)
+        gt = g_ref[0, :, qs]
+        st = _dot(kb, qt)                                   # (Bk, Bq)
+        if masked:
+            st = jnp.where(_seen(rel, i * block_q - k0, window), st,
+                           _NEG_BIG)
+        pt = jnp.exp(st - lse_ref[0, 0, :, qs])
+        dst = (pt * (_dot(vb, gt) - delta_ref[0, 0, :, qs])).astype(qt.dtype)
+        acc_ref[:, qs] += _dot(kt, dst)                     # dQ.T (D, Bq)
+        return (dk + _dot(qt, dst, _NT),                    # dK.T (D, Bk)
+                dv + _dot(gt, pt.astype(gt.dtype), _NT))
+
+    dk, dv = _walk3(
+        _q_tiles_of(k0, bk, block_q, q_ref.shape[2] // block_q, window),
+        body, (jnp.zeros((d, bk), jnp.float32),
+               jnp.zeros((d, bk), jnp.float32)))
+    dk_ref[0] = dk
+    dv_ref[0] = dv
+
+    @pl.when(pl.program_id(2) == pl.num_programs(2) - 1)
+    def _():
+        dq_ref[0] = acc_ref[...].astype(dq_ref.dtype)
+
+
+_causal_jit = functools.partial(
+    jax.jit, static_argnames=("window", "block_q", "block_k", "interpret"))
+
+
+@_causal_jit
+def _causal_forward(q, k, v, window, block_q, block_k, interpret):
+    """(out, lse) under a CausalMask. q (B, S, H, D); k, v (B, S, Hkv, D),
+    query head h reading K/V head h // (H // Hkv); S pre-padded."""
+    b, s, h, d = q.shape
+    group = h // k.shape[2]
+    q_spec = pl.BlockSpec((1, d, block_q), lambda i, hh, j: (i, hh, j))
+    kv_spec = pl.BlockSpec((1, d, s), lambda i, hh, j: (i, hh // group, 0))
+    out, lse = _call(
+        _causal_fwd_kernel, "flash_fwd", (b, h, s // block_q),
+        ("parallel", "parallel", "parallel"), [q_spec, kv_spec, kv_spec],
+        [q_spec, pl.BlockSpec((1, 1, 1, block_q),
+                              lambda i, hh, j: (i, hh, 0, j))],
+        [jax.ShapeDtypeStruct((b, h * d, s), q.dtype),
+         jax.ShapeDtypeStruct((b, h, 1, s), jnp.float32)],
+        [], [_feature_major(q), _feature_major(k), _feature_major(v)],
+        interpret, block_k=block_k, window=window)
+    return _heads_last(out, h), lse
+
+
+@_causal_jit
+def _causal_backward(q, k, v, out, lse, g, window, block_q, block_k,
+                     interpret):
+    """(dq, dk, dv) under a CausalMask. Shapes as ``_causal_forward``."""
+    b, s, h, d = q.shape
+    hkv = k.shape[2]
+    group = h // hkv
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
+                    axis=-1).transpose(0, 2, 1)[:, :, None]  # (B, H, 1, S)
+    whole = pl.BlockSpec((1, d, s), lambda i, hh, j: (i, hh, 0))
+    kv_spec = pl.BlockSpec((1, d, block_k),
+                           lambda i, hh, j: (i, hh // group, j))
+    stat = pl.BlockSpec((1, 1, 1, s), lambda i, hh, j: (i, hh, 0, 0))
+    tile = pl.BlockSpec((1, d, block_k), lambda i, hh, j: (i, hh, j))
+    dq, dk, dv = _call(
+        _causal_bwd_kernel, "flash_bwd", (b, h, s // block_k),
+        ("parallel", "parallel", "arbitrary"),
+        [whole, whole, kv_spec, kv_spec, stat, stat], [whole, tile, tile],
+        [jax.ShapeDtypeStruct((b, h * d, s), q.dtype),
+         jax.ShapeDtypeStruct((b, h * d, s), jnp.float32),
+         jax.ShapeDtypeStruct((b, h * d, s), jnp.float32)],
+        [(d, s)],
+        [_feature_major(q), _feature_major(g), _feature_major(k),
+         _feature_major(v), lse, delta],
+        interpret, block_q=block_q, window=window)
+
+    def group_sum(x, like):
+        """A K/V head's gradient: the sum over the query heads reading it."""
+        x = x.reshape(b, hkv, group, d, s).sum(axis=2).astype(like.dtype)
+        return _heads_last(x.reshape(b, hkv * d, s), hkv)
+
+    return _heads_last(dq, h), group_sum(dk, k), group_sum(dv, v)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _flash_causal(q, k, v, window, block_q, block_k, interpret):
+    return _flash_causal_fwd(q, k, v, window, block_q, block_k,
+                             interpret)[0]
+
+
+@trace.scope("attention.core")
+def _flash_causal_fwd(q, k, v, window, block_q, block_k, interpret):
+    out, lse = _causal_forward(q, k, v, window, block_q, block_k, interpret)
+    return out, (q, k, v, out, lse)
+
+
+@trace.scope("attention.core")
+def _flash_causal_bwd(window, block_q, block_k, interpret, residuals, g):
+    q, k, v, out, lse = residuals
+    return _causal_backward(q, k, v, out, lse, g, window, block_q, block_k,
+                            interpret)
+
+
+_flash_causal.defvjp(_flash_causal_fwd, _flash_causal_bwd)
+
+
+# ---------------------------------------------------------------------------
 # chunked (lax.scan) twin — pure XLA, any backend, dropout-capable
 
 
@@ -586,9 +863,30 @@ def _flash_bwd_rule(dropout_rate, block_q, block_k, impl, interpret,
 _flash.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
+def _narrowest(q, k, v):
+    """The kernels' matmul operands are as wide as the narrowest of q, k,
+    v (a model's scaled q may be float32 beside bfloat16 k and v)."""
+    return min((q.dtype, k.dtype, v.dtype),
+               key=lambda t: jnp.dtype(t).itemsize)
+
+
+def _plain_operands(q, k, v, mask):
+    """What the paths that know neither grouped heads nor a structural mask
+    take: each K/V head repeated for the query heads that read it, and the
+    mask as a dense array."""
+    group = q.shape[2] // k.shape[2]
+    if group > 1:
+        k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
+    if isinstance(mask, CausalMask):
+        mask = jnp.broadcast_to(mask.dense(q.shape[1], k.shape[1]),
+                                (q.shape[0], q.shape[1], k.shape[1]))
+    return q, k, v, mask
+
+
 @trace.scope("attention.core")
 def _reference_attention(q, k, v, mask, dropout_rate=0.0, dropout_key=None):
     """Plain XLA attention (f32 softmax) — the O(S²)-HBM fallback/oracle."""
+    q, k, v, mask = _plain_operands(q, k, v, mask)
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32)
     if mask is not None:
         s = jnp.where(mask[:, None], s, _NEG_BIG)
@@ -624,8 +922,11 @@ def flash_attention(
     """Blocked online-softmax attention with a blockwise backward.
 
     q: (B, Sq, H, D) — pre-scaled (multiply by 1/sqrt(D) before calling);
-    k, v: (B, Sk, H, D); mask: optional (B, Sq, Sk) bool, True = attend
-    (shared across heads); dropout_rate applies to attention probabilities
+    k, v: (B, Sk, Hkv, D), H a multiple of Hkv and query head h reading
+    K/V head h // (H // Hkv); mask: optional (B, Sq, Sk) bool, True =
+    attend (shared across heads), or a :class:`CausalMask`, which the
+    Pallas route computes from positions, skipping the tiles it hides;
+    dropout_rate applies to attention probabilities
     (chunked impl only) with dropout_key. Irregular Sq/Sk are padded to
     block multiples with masked tails; ``block_q`` / ``block_k`` left at
     None follow the shapes. Returns (B, Sq, H, D) in q's dtype.
@@ -640,9 +941,31 @@ def flash_attention(
         raise ValueError("attention dropout requires impl='chunked'")
     if dropout_rate > 0.0 and dropout_key is None:
         raise ValueError("dropout_rate > 0 needs a dropout_key")
+    if q.shape[2] % k.shape[2] or k.shape[2] != v.shape[2]:
+        raise ValueError(f"{q.shape[2]} query heads cannot share "
+                         f"{k.shape[2]} K/V heads")
 
     b, sq, h, d = q.shape
     sk = k.shape[1]
+    if isinstance(mask, CausalMask) and impl == "pallas":
+        if sq != sk:
+            raise ValueError("a CausalMask is for self attention: "
+                             f"{sq} queries against {sk} keys")
+        # a padded key lies after every real query, so causality hides it
+        bq, s_p = (_derived_block(sq) if block_q is None
+                   else _block_and_pad(sq, block_q))
+        bk = bq if block_k is None else block_k
+        if s_p % bk:
+            raise ValueError(f"block_k {bk} does not divide the padded "
+                             f"length {s_p}")
+        narrow = _narrowest(q, k, v)
+        pad = lambda x: jnp.pad(  # noqa: E731
+            x.astype(narrow), ((0, 0), (0, s_p - sq), (0, 0), (0, 0)))
+        out = _flash_causal(pad(q), pad(k), pad(v), mask.window, bq, bk,
+                            bool(interpret))
+        return out[:, :sq].astype(q.dtype)
+    # the paths below know one head count and a dense mask
+    q, k, v, mask = _plain_operands(q, k, v, mask)
     # blocks follow the shapes unless the caller names them: the Pallas
     # kernels take the largest tile the lengths allow, the chunked scan
     # its 128-wide K blocks
@@ -668,8 +991,7 @@ def flash_attention(
     if impl == "pallas":
         # the kernels' matmul operands are as wide as the narrowest of
         # q, k, v (MHA's scaled q is float32 beside bfloat16 k and v)
-        narrow = min((q.dtype, k.dtype, v.dtype),
-                     key=lambda t: jnp.dtype(t).itemsize)
+        narrow = _narrowest(q, k, v)
         q, k, v = q.astype(narrow), k.astype(narrow), v.astype(narrow)
     out = _flash(q, k, v, mask, dropout_key, float(dropout_rate), bq, bk,
                  impl, bool(interpret))
@@ -703,7 +1025,13 @@ def sharded_flash_attention(
     qs = P(ab, None, ah, None)
     ms = P(ab, None, None)
 
+    # a structural mask is no array: it rides in the closure
+    by_structure = mask if isinstance(mask, CausalMask) else None
+    if by_structure is not None:
+        mask = None
+
     def local(q, k, v, mask, key):
+        mask = by_structure or mask
         if key is not None:
             for ax in (ab, ah):
                 if ax is not None:

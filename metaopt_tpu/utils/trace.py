@@ -41,7 +41,9 @@ SPANS = (
 )
 #: every device scope (``jax.named_scope``) of the train steps
 SCOPES = ("embed", "attention", "attention.core", "ffn", "readout_xent",
-          "optimizer", "eval")
+          "optimizer", "eval",
+          # an expert layer (models/moe.DroplessMoE): all of it, and its parts
+          "moe", "moe.router", "moe.dispatch", "moe.experts", "moe.combine")
 #: spans a train loop makes every step. The ring keeps one whole only if it
 #: has a child (the step that compiled); the others are summed into the
 #: enclosing span's ``attrs["per_step"]`` as ``{name: [count, seconds]}``, so a
@@ -341,14 +343,33 @@ def main(argv: List[str]) -> int:
 
 def print_routes(recs: List[dict]) -> None:
     """A line a trial: which attention route its steps took, as its
-    ``trial.setup`` span has it (ops/attention.attention_route)."""
+    ``trial.setup`` span has it (ops/attention.attention_route); for a
+    model with a layer pattern a line each kind of layer, what the expert
+    layers hold, and (from ``trial.train``) what they counted."""
     for r in recs:
-        route = r["attrs"].get("attention") if r["name"] == "trial.setup" \
-            else None
+        attrs = r["attrs"]
+        route = attrs.get("attention") if r["name"] == "trial.setup" else None
         if route:
             print(f"trial {r['trial']}: attention {route['train']} in "
                   f"training (dropout {route['dropout']}), {route['eval']} "
                   "in evaluation")
+            for kind, how in attrs.get("attention_layers", {}).items():
+                print(f"trial {r['trial']}: {kind} layers: {how['route']}, "
+                      f"mask by {how['mask']}")
+            moe = attrs.get("moe")
+            if moe:
+                first, count = moe["held"]
+                print(f"trial {r['trial']}: experts {first}-"
+                      f"{first + count - 1} of {moe['routed_over']} held, "
+                      f"top {moe['top_k']}, products by {moe['products']}")
+        counts = attrs.get("moe") if r["name"] == "trial.train" else None
+        if counts:
+            for layer, items in enumerate(counts["items"]):
+                mean = sum(items) / len(items)
+                print(f"trial {r['trial']}: layer {layer}: {sum(items)} "
+                      f"items to held experts, fullest "
+                      f"{max(items) / mean if mean else 0.0:.2f}x the mean, "
+                      f"{counts['dropped'][layer]} dropped")
 
 
 dump_under(os.environ.get(PROFILE_DIR_ENV))

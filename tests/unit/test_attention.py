@@ -586,3 +586,121 @@ class TestPallasAtTheCellsShape:
             assert dead[:, 5].all() and dead[1, 200:].all()
             assert (np.asarray(out, np.float32)[dead] == 0).all()
             assert (np.asarray(grads[0], np.float32)[dead] == 0).all()
+
+
+# -- a mask stated by structure, grouped K/V heads ----------------------------
+
+from metaopt_tpu.ops.attention import (  # noqa: E402
+    CausalMask, _k_tiles_of, _q_tiles_of)
+
+# (length, window, query heads, K/V heads, head width, tile): lengths below,
+# at and across the window, some no multiple of a tile
+STRUCTURAL = [
+    (96, None, 4, 2, 128, 32), (96, 200, 4, 2, 128, 32),
+    (128, 128, 4, 1, 128, 32), (200, 64, 7, 1, 128, 64),
+    (300, 128, 4, 2, 128, 128), (257, 100, 2, 2, 64, 128),
+    (384, 130, 2, 1, 128, 128), (100, 40, 2, 1, 128, None),
+    (640, 257, 2, 1, 128, None),
+]
+
+
+def _structural_case(s, window, h, hkv, d, tile, seed=0):
+    key = jax.random.PRNGKey(seed)
+    kq, kk, kv, kg = jax.random.split(key, 4)
+    q = jax.random.normal(kq, (2, s, h, d)) / np.sqrt(d)
+    k = jax.random.normal(kk, (2, s, hkv, d))
+    v = jax.random.normal(kv, (2, s, hkv, d))
+    g = jax.random.normal(kg, (2, s, h, d))
+    kernel = lambda q, k, v: flash_attention(  # noqa: E731
+        q, k, v, CausalMask(window), impl="pallas", interpret=True,
+        block_q=tile, block_k=tile)
+    oracle = lambda q, k, v: _reference_attention(  # noqa: E731
+        q, k, v, CausalMask(window))
+    return (q, k, v, g), kernel, oracle
+
+
+_case_id = lambda c: "x".join(map(str, c))  # noqa: E731
+
+
+class TestStructuralMask:
+    @pytest.mark.parametrize("case", STRUCTURAL, ids=_case_id)
+    def test_forward_matches_the_reference(self, case):
+        (q, k, v, _), kernel, oracle = _structural_case(*case)
+        np.testing.assert_allclose(kernel(q, k, v), oracle(q, k, v),
+                                   atol=2e-5, rtol=2e-5)
+
+    @pytest.mark.parametrize("case", STRUCTURAL, ids=_case_id)
+    def test_gradients_match_the_reference(self, case):
+        (q, k, v, g), kernel, oracle = _structural_case(*case)
+        got = jax.grad(lambda *a: jnp.sum(kernel(*a) * g), (0, 1, 2))(q, k, v)
+        want = jax.grad(lambda *a: jnp.sum(oracle(*a) * g), (0, 1, 2))(q, k, v)
+        for a, b in zip(got, want):
+            assert a.shape == b.shape
+            np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
+
+    @pytest.mark.parametrize("window", [None, 5, 16, 40])
+    def test_the_dense_form_says_what_the_rule_says(self, window):
+        dense = np.asarray(CausalMask(window).dense(24, 24))[0]
+        for i in range(24):
+            for j in range(24):
+                assert dense[i, j] == (0 <= i - j and (
+                    window is None or i - j < window))
+
+    @pytest.mark.parametrize("bq, bk, window", [
+        (128, 128, None), (128, 128, 512), (256, 128, 300), (128, 256, 100),
+        (512, 512, 4096), (64, 64, 1)])
+    def test_the_walks_cover_the_seen_tiles_and_skip_the_others(
+            self, bq, bk, window):
+        """For every tile of one sequence, the tiles of the other that the
+        walk visits are exactly those with a seen pair, and the ones it
+        leaves unmasked have every pair seen."""
+        s = 8 * max(bq, bk)
+        seen = np.asarray(CausalMask(window).dense(s, s))[0]
+        for walk, mine, theirs, flip in (
+                (_k_tiles_of, bq, bk, False), (_q_tiles_of, bk, bq, True)):
+            for t in range(s // mine):
+                lo, full_lo, full_hi, hi = (int(x) for x in walk(
+                    jnp.int32(t * mine), mine, theirs, s // theirs, window))
+                for u in range(s // theirs):
+                    rows = slice(t * mine, (t + 1) * mine)
+                    cols = slice(u * theirs, (u + 1) * theirs)
+                    tile = seen[cols, rows] if flip else seen[rows, cols]
+                    assert (lo <= u < hi) == bool(tile.any()), (t, u)
+                    if full_lo <= u < full_hi:
+                        assert tile.all(), (t, u)
+
+    def test_grouped_heads_under_a_dense_mask_read_their_k_v_head(self):
+        """The dense-mask route with fewer K/V heads: each is repeated for
+        the query heads that read it."""
+        (q, k, v, _), _, _ = _structural_case(48, None, 4, 2, 16, None)
+        mask = jnp.tril(jnp.ones((48, 48), bool))[None].repeat(2, 0)
+        for impl in ("pallas", "chunked"):
+            got = flash_attention(q, k, v, mask, impl=impl, interpret=True)
+            want = _reference_attention(
+                q, jnp.repeat(k, 2, 2), jnp.repeat(v, 2, 2), mask)
+            np.testing.assert_allclose(got, want, atol=2e-5, rtol=2e-5)
+
+    def test_the_chunked_twin_takes_a_structural_mask_as_a_dense_one(self):
+        (q, k, v, _), _, oracle = _structural_case(48, 10, 4, 2, 16, None)
+        got = flash_attention(q, k, v, CausalMask(10), impl="chunked")
+        np.testing.assert_allclose(got, oracle(q, k, v), atol=2e-5,
+                                   rtol=2e-5)
+
+    def test_cross_attention_and_uneven_heads_are_refused(self):
+        q, k, v = rand_qkv(jax.random.PRNGKey(0), sq=16, sk=24, h=2)
+        with pytest.raises(ValueError, match="self attention"):
+            flash_attention(q, k, v, CausalMask(), impl="pallas",
+                            interpret=True)
+        q3 = jnp.zeros((2, 16, 3, 8))
+        with pytest.raises(ValueError, match="K/V heads"):
+            flash_attention(q3, k[:, :16], v[:, :16], None, interpret=True)
+
+    def test_sharded_over_heads_with_a_structural_mask(self):
+        from jax.sharding import Mesh
+
+        (q, k, v, _), _, oracle = _structural_case(64, 20, 4, 2, 16, None)
+        mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("dp", "tp"))
+        got = sharded_flash_attention(mesh, q, k, v, CausalMask(20),
+                                      impl="pallas", interpret=True)
+        np.testing.assert_allclose(got, oracle(q, k, v), atol=2e-5,
+                                   rtol=2e-5)

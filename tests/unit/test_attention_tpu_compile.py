@@ -12,7 +12,7 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from metaopt_tpu.ops.attention import flash_attention
+from metaopt_tpu.ops.attention import CausalMask, flash_attention
 
 
 @pytest.fixture(scope="module")
@@ -68,4 +68,30 @@ def test_forward_and_backward_compile_for_a_v5e(one_chip, shape, masked):
 
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         *args).compile()
+    assert compiled.as_text().count("tpu_custom_call") == 2  # fwd, bwd
+
+
+# (length, window, query heads, K/V heads, head width): SmallThinker's global
+# and window layers at the benchmark cell's 8192 tokens and at the model's
+# 16384, a length that pads, and 64-wide heads
+STRUCTURAL = [(8192, None, 28, 4, 128), (8192, 4096, 28, 4, 128),
+              (16384, 4096, 28, 4, 128), (16384, None, 28, 4, 128),
+              (1000, 300, 8, 2, 128), (2048, 512, 8, 8, 64)]
+
+
+@pytest.mark.parametrize("case", STRUCTURAL,
+                         ids=lambda c: "x".join(map(str, c)))
+def test_structural_masks_and_grouped_heads_compile_for_a_v5e(one_chip, case):
+    """VMEM stays bounded: a program holds one head of the other sequence,
+    2 MiB at 8192 x 128, whatever the number of heads."""
+    s, window, h, hkv, d = case
+    q = jax.ShapeDtypeStruct((1, s, h, d), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, s, hkv, d), jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, CausalMask(window), impl="pallas")
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv).compile()
     assert compiled.as_text().count("tpu_custom_call") == 2  # fwd, bwd

@@ -278,8 +278,9 @@ class TestKilledTrialIsAwaited:
         ex, pidfile = self._executor(tmp_path, heartbeat_every_s=0.0)
 
         def beat():
-            # reservation lost as soon as the child is up
-            return not pidfile.exists()
+            # reservation lost as soon as the child is up and has said who
+            # it is (the file exists, empty, a moment before it is written)
+            return not (pidfile.exists() and pidfile.read_text())
 
         res = ex.execute(_trial(), heartbeat=beat)
         assert res.status == "interrupted"

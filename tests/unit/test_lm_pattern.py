@@ -1,0 +1,493 @@
+"""The pattern decoder (models/lm.py) and its dropless expert layer
+(models/moe.py) against plain float32 references written here, in the test
+tree's own words: block by block, loss and every gradient leaf, the shares
+of a deployment adding up to the uncut layer, and no item dropped under
+the worst imbalance."""
+
+import math
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from flax import linen as nn
+
+HI = jax.lax.Precision.HIGHEST
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+D, H, KV, HD, F, E, TOPK, V, S, WINDOW = 32, 4, 2, 16, 24, 16, 3, 64, 24, 8
+
+
+def description(layers, held=(0, E), **over):
+    sliding, rotary = zip(*layers)
+    h = dict(hidden_size=D, num_attention_heads=H, num_key_value_heads=KV,
+             head_dim=HD, num_hidden_layers=len(layers), vocab_size=V,
+             sliding_window_layout=list(sliding), rope_layout=list(rotary),
+             sliding_window_size=WINDOW, rope_theta=1.5e6,
+             moe_num_primary_experts=E, moe_num_active_primary_experts=TOPK,
+             moe_ffn_hidden_size=F, experts_held=held, rms_norm_eps=1e-6)
+    h.update(over)
+    return h
+
+
+# -- the reference, in this file's own words ---------------------------------
+
+def ref_rms(x, g):
+    return x / jnp.sqrt(jnp.mean(x * x, -1, keepdims=True) + 1e-6) * g
+
+
+def ref_rope(x):
+    half = HD // 2
+    inv = 1.5e6 ** (-np.arange(half) * 2.0 / HD)
+    ang = np.arange(x.shape[0])[:, None] * inv[None]
+    c, s = jnp.cos(ang)[:, None], jnp.sin(ang)[:, None]
+    a, b = x[..., :half], x[..., half:]
+    return jnp.concatenate([a * c - b * s, a * s + b * c], -1)
+
+
+def ref_moe(m, logits, p, held):
+    """Held experts (``p``: theirs alone) applied to every token, weighed
+    by the routing."""
+    top, idx = jax.lax.top_k(logits, TOPK)
+    w = jax.nn.softmax(top, -1)
+    y = jnp.zeros_like(m)
+    first, count = held
+    for e in range(count):
+        we = jnp.sum(jnp.where(idx == first + e, w, 0.0), -1)
+        hid = jax.nn.relu(jnp.dot(m, p["gate"][e], precision=HI)) \
+            * jnp.dot(m, p["up"][e], precision=HI)
+        y = y + we[:, None] * jnp.dot(hid, p["down"][e], precision=HI)
+    return y
+
+
+def ref_block(p, x, sliding, rotary, held):
+    n = ref_rms(x, p["norm_in"]["scale"])
+    logits = jnp.dot(n, p["router"]["kernel"], precision=HI)
+    q = jnp.einsum("sd,dhk->shk", n, p["attn"]["q"]["kernel"], precision=HI)
+    k = jnp.einsum("sd,dhk->shk", n, p["attn"]["k"]["kernel"], precision=HI)
+    v = jnp.einsum("sd,dhk->shk", n, p["attn"]["v"]["kernel"], precision=HI)
+    if rotary:
+        q, k = ref_rope(q), ref_rope(k)
+    i, j = np.arange(S)[:, None], np.arange(S)[None, :]
+    seen = (i - j >= 0) & ((i - j < WINDOW) if sliding else True)
+    heads = []
+    for hh in range(H):
+        sc = jnp.dot(q[:, hh], k[:, hh // (H // KV)].T,
+                     precision=HI) / math.sqrt(HD)
+        pr = jax.nn.softmax(jnp.where(seen, sc, -jnp.inf), -1)
+        heads.append(jnp.dot(pr, v[:, hh // (H // KV)], precision=HI))
+    a = jnp.einsum("shk,hkd->sd", jnp.stack(heads, 1),
+                   p["attn"]["out"]["kernel"], precision=HI)
+    x1 = x + a
+    m = ref_rms(x1, p["norm_post"]["scale"])
+    return x1 + ref_moe(m, logits, p["experts"], held)
+
+
+def ref_loss(params, tokens, layers, held):
+    total = 0.0
+    for row in tokens:
+        x = params["embed"]["embedding"][row[:-1]]
+        for i, (sliding, rotary) in enumerate(layers):
+            x = ref_block(params[f"h{i}"], x, sliding, rotary, held)
+        x = ref_rms(x, params["norm_f"]["scale"])
+        logp = jax.nn.log_softmax(
+            jnp.dot(x, params["head"]["embedding"].T, precision=HI), -1)
+        total = total - jnp.sum(
+            jnp.take_along_axis(logp, row[1:, None], -1))
+    return total / (tokens.shape[0] * (tokens.shape[1] - 1))
+
+
+def seeded(model, tokens, seed=0):
+    """The model's parameters, unboxed, with a router spread enough that
+    rounding rarely changes a token's chosen experts."""
+    params = nn.meta.unbox(
+        model.init(jax.random.PRNGKey(seed), tokens[:, :-1],
+                   train=False)["params"])
+    for name, sub in params.items():
+        if "router" in sub:
+            sub["router"]["kernel"] = 4.0 * sub["router"]["kernel"]
+    params["embed"]["embedding"] = params["embed"]["embedding"].astype(
+        jnp.float32)
+    return params
+
+
+KINDS = {"global-nope": (0, 0), "window-rope": (1, 1),
+         "global-rope": (0, 1), "window-nope": (1, 0)}
+
+
+@pytest.fixture(scope="module")
+def both_sides():
+    """{kind: (program's loss, gradients; reference's)} for a one-layer
+    model of each kind, and for the whole period of four."""
+    from metaopt_tpu.models.lm import lm_loss_fn, make_lm
+
+    tokens = jax.random.randint(jax.random.PRNGKey(5), (2, S + 1), 2, V)
+    out = {}
+    for kind, layers in [(k, [v]) for k, v in KINDS.items()] + [
+            ("period", [(0, 0), (1, 1), (1, 1), (1, 1)])]:
+        model = make_lm(description(layers))
+        params = seeded(model, tokens)
+        prog = jax.value_and_grad(
+            lambda p: lm_loss_fn(model, p, tokens, jax.random.PRNGKey(0)))(
+                params)
+        ref = jax.value_and_grad(ref_loss)(params, tokens, layers, (0, E))
+        out[kind] = (prog, ref)
+    return out
+
+
+LEAVES = ["embed/embedding", "head/embedding", "norm_f/scale",
+          "h0/norm_in/scale", "h0/norm_post/scale", "h0/router/kernel",
+          "h0/attn/q/kernel", "h0/attn/k/kernel", "h0/attn/v/kernel",
+          "h0/attn/out/kernel", "h0/experts/gate", "h0/experts/up",
+          "h0/experts/down"]
+
+
+def leaf(tree, path):
+    for part in path.split("/"):
+        tree = tree[part]
+    return np.asarray(tree, np.float32)
+
+
+@pytest.mark.parametrize("kind", list(KINDS) + ["period"])
+def test_loss_matches_the_reference(both_sides, kind):
+    (prog, _), (ref, _) = both_sides[kind]
+    assert abs(float(prog) - float(ref)) <= 2e-3 * abs(float(ref))
+
+
+@pytest.mark.parametrize("path", LEAVES)
+@pytest.mark.parametrize("kind", ["global-nope", "window-rope"])
+def test_every_gradient_leaf_matches_the_reference(both_sides, kind, path):
+    """bfloat16 products against float32: the difference's norm stays under
+    a twentieth of the leaf's."""
+    (_, prog), (_, ref) = both_sides[kind]
+    p, r = leaf(prog, path), leaf(ref, path)
+    assert np.linalg.norm(p - r) <= 0.05 * np.linalg.norm(r), path
+
+
+@pytest.mark.parametrize("layer", ["h0", "h1", "h2", "h3"])
+def test_a_whole_period_s_gradients_match_layer_by_layer(both_sides, layer):
+    """Looser than a single layer: from layer 1 on, bfloat16 activations
+    move a few of the 48 tokens' third and fourth router logits past each
+    other, and at this size one such token shows in a leaf."""
+    (_, prog), (_, ref) = both_sides["period"]
+    for path in LEAVES[3:]:
+        path = path.replace("h0", layer)
+        p, r = leaf(prog, path), leaf(ref, path)
+        assert np.linalg.norm(p - r) <= 0.12 * np.linalg.norm(r), path
+
+
+def test_window_and_global_layers_differ_and_a_late_token_is_unseen():
+    """Poking a token changes later positions' logits inside the window and
+    leaves those beyond it alone (one window layer sees no further)."""
+    from metaopt_tpu.models.lm import make_lm
+
+    tokens = jax.random.randint(jax.random.PRNGKey(1), (1, S), 2, V)
+    poked = tokens.at[0, 2].set((tokens[0, 2] + 1) % (V - 2) + 2)
+    for sliding, reaches in ((1, False), (0, True)):
+        model = make_lm(description([(sliding, 1)],
+                                    moe_num_primary_experts=0))
+        params = model.init(jax.random.PRNGKey(0), tokens, train=False)
+        a = model.apply(params, tokens, train=False)
+        b = model.apply(params, poked, train=False)
+        changed = np.abs(np.asarray(a - b)).max(-1)[0]
+        assert changed[:2].max() == 0 and changed[2] > 0
+        assert changed[2 + WINDOW - 1] > 0          # the window's last
+        assert (changed[2 + WINDOW:].max() > 0) == reaches
+
+
+# -- the expert layer ----------------------------------------------------------
+
+def expert_layer(held, logits_bias=None, seed=3, t=40):
+    """(program's output and counts, reference's output) of one
+    DroplessMoE over ``held``, all shares from one set of weights."""
+    from metaopt_tpu.models.moe import DroplessMoE
+
+    key = jax.random.PRNGKey(seed)
+    x = jax.random.normal(key, (1, t, D))
+    logits = 2.0 * jax.random.normal(jax.random.fold_in(key, 1), (1, t, E))
+    if logits_bias is not None:
+        logits = logits + logits_bias
+    whole = DroplessMoE(D, F, E, TOPK, (0, E))
+    full = nn.meta.unbox(whole.init(key, x, logits)["params"])
+    first, count = held
+    mine = {k: v[first:first + count] for k, v in full.items()}
+    y, state = DroplessMoE(D, F, E, TOPK, held).apply(
+        {"params": mine}, x, logits, mutable=["moe_stats"])
+    ref = ref_moe(x[0], logits[0], full_as_ref(mine), held)
+    return y[0], state["moe_stats"], ref, ref_moe(
+        x[0], logits[0], full_as_ref(full), (0, E))
+
+
+def full_as_ref(full):
+    return {k: v.astype(jnp.float32) for k, v in full.items()}
+
+
+SHARES = [(0, 4), (4, 4), (8, 4), (12, 4)]
+
+
+@pytest.mark.parametrize("held", SHARES + [(0, 16), (3, 7)])
+def test_a_share_gives_its_own_experts_part(held):
+    y, _, ref, _ = expert_layer(held)
+    assert np.linalg.norm(y - ref) <= 0.02 * max(np.linalg.norm(ref), 1e-6)
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """16 experts, top 3, four shares of 4: the four partial outputs sum to
+    what the uncut reference gives for the whole layer."""
+    parts = [expert_layer(held) for held in SHARES]
+    total = sum(p[0] for p in parts)
+    uncut = parts[0][3]
+    assert np.linalg.norm(total - uncut) <= 0.02 * np.linalg.norm(uncut)
+    # and no share is idle: each adds something of its own
+    assert all(np.linalg.norm(p[0]) > 0.05 * np.linalg.norm(uncut)
+               for p in parts)
+
+
+@pytest.mark.parametrize("held", [(0, 4), (4, 4), (0, 16)])
+def test_nothing_is_dropped_under_the_worst_imbalance(held):
+    """A router biased so that every token picks experts 0, 1, 2: a share
+    that holds them gets all t x k items, another none; both equal the
+    reference and drop nothing."""
+    bias = jnp.zeros((E,)).at[:TOPK].set(50.0)
+    y, stats, ref, _ = expert_layer(held, logits_bias=bias)
+    items = np.asarray(stats["items"][0])
+    assert int(stats["dropped"][0]) == 0
+    assert items.sum() == (40 * TOPK if held[0] == 0 else 0)
+    assert np.linalg.norm(y - ref) <= 0.02 * max(np.linalg.norm(ref), 1e-6)
+
+
+def test_the_counts_are_the_routing_s():
+    _, stats, _, _ = expert_layer((4, 4))
+    key = jax.random.PRNGKey(3)
+    logits = 2.0 * jax.random.normal(jax.random.fold_in(key, 1), (40, E))
+    _, idx = jax.lax.top_k(logits, TOPK)
+    want = [int(jnp.sum(idx == e)) for e in range(4, 8)]
+    assert np.asarray(stats["items"][0]).tolist() == want
+
+
+def test_held_experts_outside_the_routed_ones_are_refused():
+    from metaopt_tpu.models.moe import DroplessMoE
+
+    x = jnp.zeros((1, 4, D))
+    with pytest.raises(ValueError, match="held"):
+        DroplessMoE(D, F, E, TOPK, (14, 4)).init(
+            jax.random.PRNGKey(0), x, jnp.zeros((1, 4, E)))
+
+
+@pytest.mark.parametrize("ep", [2, 4])
+def test_on_an_ep_axis_each_chip_holds_its_part_and_the_sum_is_the_layer(ep):
+    from jax.sharding import Mesh
+
+    from metaopt_tpu.models.moe import DroplessMoE
+    from metaopt_tpu.parallel.mesh import use_mesh
+
+    key = jax.random.PRNGKey(7)
+    x = jax.random.normal(key, (2, 12, D))
+    logits = 2.0 * jax.random.normal(jax.random.fold_in(key, 1), (2, 12, E))
+    layer = DroplessMoE(D, F, E, TOPK, (4, 8))
+    params = nn.meta.unbox(layer.init(key, x, logits)["params"])
+    alone, one = layer.apply({"params": params}, x, logits,
+                             mutable=["moe_stats"])
+    mesh = Mesh(np.array(jax.devices()[:ep]).reshape(1, 1, ep),
+                ("dp", "tp", "ep"))
+    with use_mesh(mesh):
+        shared, many = jax.jit(lambda p: layer.apply(
+            {"params": p}, x, logits, mutable=["moe_stats"]))(params)
+    np.testing.assert_allclose(np.asarray(shared), np.asarray(alone),
+                               rtol=2e-2, atol=2e-3)
+    assert np.asarray(many["moe_stats"]["items"][0]).tolist() \
+        == np.asarray(one["moe_stats"]["items"][0]).tolist()
+    assert int(many["moe_stats"]["dropped"][0]) == 0
+
+
+# -- the description -----------------------------------------------------------
+
+def one_device():
+    from jax.sharding import Mesh
+
+    return Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("dp", "tp"))
+
+
+def test_published_names_and_the_share_make_the_pattern():
+    from metaopt_tpu.models.lm import make_lm
+
+    model = make_lm(description([(0, 0), (1, 1), (1, 1), (1, 1)],
+                                held=(4, 4), vocab_held=(16, 32)))
+    p = model.pattern
+    assert p.layers == ((False, False), (True, True), (True, True),
+                        (True, True))
+    assert (p.n_kv_heads, p.head_dim, p.window, p.top_k) == (KV, HD, WINDOW,
+                                                             TOPK)
+    assert p.experts_held == (4, 4) and p.vocab_held == (16, 32)
+    assert p.kinds() == ["global-nope", "window-rope"]
+    assert model.dropout == 0.0
+    tokens = jnp.full((1, 8), 20, jnp.int32)
+    params = model.init(jax.random.PRNGKey(0), tokens, train=False)["params"]
+    assert params["head"]["embedding"].value.shape == (32, D)
+    assert nn.meta.unbox(params)["h1"]["experts"]["gate"].shape == (4, D, F)
+    assert "pos_embed" not in params
+
+
+def test_a_description_without_a_pattern_is_the_2017_stack():
+    from metaopt_tpu.models.lm import make_lm
+
+    model = make_lm({"d_model": 32, "n_heads": 2, "n_layers": 1})
+    assert model.pattern is None and model.dropout == 0.1
+
+
+def test_layouts_shorter_than_the_depth_are_refused():
+    from metaopt_tpu.models.lm import make_lm
+
+    with pytest.raises(ValueError, match="layouts"):
+        make_lm(description([(0, 0)], num_hidden_layers=2))
+
+
+def test_train_lm_reports_the_routing_s_counts_once(tmp_path):
+    from metaopt_tpu.models.lm import train_lm
+    from metaopt_tpu.utils import trace
+
+    hp = description([(0, 0), (1, 1)], held=(4, 8), lr=1e-3, remat=True)
+    loss = train_lm(hp, mesh=one_device(), n_train=8, batch_size=2,
+                    seq_len=S, steps=3)
+    assert np.isfinite(loss)
+    train = trace.spans("trial.train")[-1]
+    moe = train["attrs"]["moe"]
+    assert moe["dropped"] == [0, 0]
+    assert np.asarray(moe["items"]).shape == (2, 8)
+    # 3 steps x 2 rows x S tokens x top-k choices, the held experts' part
+    assert 0 < np.sum(moe["items"]) < 3 * 2 * S * TOPK * 2
+    setup = trace.spans("trial.setup")[-1]["attrs"]
+    assert setup["attention"]["dropout"] == 0.0
+    assert setup["attention_layers"] == {
+        "global-nope": {"route": "reference", "mask": "dense: causal"},
+        "window-rope": {"route": "reference",
+                        "mask": f"dense: causal, window {WINDOW}"}}
+    assert setup["moe"] == {"routed_over": E, "top_k": TOPK, "held": [4, 8],
+                            "products": "ragged_dot"}
+
+
+def test_the_trial_hands_out_its_loop_step_by_step():
+    """LMTrial.step(i) is the loop train_lm drives: the same steps by hand
+    give the same loss."""
+    from metaopt_tpu.models.lm import LMTrial, train_lm
+
+    hp = description([(0, 0), (1, 1)], lr=1e-3)
+    kw = dict(mesh=one_device(), n_train=8, batch_size=2, seq_len=S, steps=3,
+              seed=4)
+    whole = train_lm(hp, **kw)
+    trial = LMTrial(hp, **kw)
+    with trial:
+        for i in range(3):
+            loss = trial.step(i)
+    assert float(loss) == whole
+    assert trial.read_counts()["dropped"] == [0, 0]
+
+
+def test_the_reader_prints_the_pattern_s_routes_and_counts(capsys):
+    from metaopt_tpu.utils import trace
+
+    setup = {"name": "trial.setup", "trial": "T-1", "attrs": {
+        "attention": {"dropout": 0.0, "train": "pallas", "eval": "pallas"},
+        "attention_layers": {
+            "global-nope": {"route": "pallas", "mask": "structure: causal"}},
+        "moe": {"routed_over": 64, "top_k": 6, "held": [0, 16],
+                "products": "ragged_dot"}}}
+    train = {"name": "trial.train", "trial": "T-1", "attrs": {
+        "moe": {"items": [[30, 10]], "dropped": [0]}}}
+    trace.print_routes([setup, train])
+    assert capsys.readouterr().out.splitlines() == [
+        "trial T-1: attention pallas in training (dropout 0.0), pallas in "
+        "evaluation",
+        "trial T-1: global-nope layers: pallas, mask by structure: causal",
+        "trial T-1: experts 0-15 of 64 held, top 6, products by ragged_dot",
+        "trial T-1: layer 0: 40 items to held experts, fullest 1.50x the "
+        "mean, 0 dropped"]
+
+
+def test_the_example_takes_a_model_description(tmp_path, monkeypatch):
+    """examples/lm_causal.py --model: a description file names the model,
+    the command line the optimizer's hyperparameters."""
+    import json
+    import runpy
+    import sys
+
+    from metaopt_tpu import client
+    from metaopt_tpu.utils import trace
+
+    path = tmp_path / "model.json"
+    path.write_text(json.dumps(description([(0, 0), (1, 1)], held=(0, 4))))
+    reported = []
+    monkeypatch.setattr(client, "report_results", reported.append)
+    monkeypatch.setattr(sys, "argv", [
+        "lm_causal.py", f"--model={path}", "--lr=1e-3", f"--seq-len={S}",
+        "--batch-size=8", "--n-train=8", "--steps=2"])
+    runpy.run_path(os.path.join(ROOT, "examples", "lm_causal.py"),
+                   run_name="__main__")
+    assert np.isfinite(reported[0][0]["value"])
+    assert trace.spans("trial.setup")[-1]["attrs"]["moe"]["held"] == [0, 4]
+
+
+def test_the_benchmark_prints_a_description_the_program_takes():
+    """The program reads make_lm's description alone; the benchmark turns
+    a configuration of its own into one."""
+    import json
+    import subprocess
+    import sys
+
+    from metaopt_tpu.models.lm import make_lm
+
+    out = subprocess.run(
+        [sys.executable, "-m", "chipbench.lm_config", os.path.join(
+            "chipbench", "configs", "smallthinker-21b-a3b-ep4.json")],
+        cwd=ROOT, check=True, capture_output=True, text=True).stdout
+    desc = json.loads(out)
+    assert desc["moe_num_primary_experts"] == 64
+    model = make_lm(desc)
+    assert model.n_layers == 4 and model.remat is True
+    assert model.pattern.experts_held == (0, 16)
+    assert model.pattern.vocab_held == (0, 37984)
+
+
+@pytest.mark.parametrize("tree", ["examples", "metaopt_tpu"])
+def test_the_program_knows_nothing_of_the_benchmark(tree):
+    import pathlib
+
+    assert [str(p) for p in pathlib.Path(ROOT, tree).rglob("*.py")
+            if "import chipbench" in p.read_text()
+            or "from chipbench" in p.read_text()] == []
+
+
+@pytest.mark.parametrize("backend, rows, product", [
+    ("cpu", 49152, "ragged_dot"), ("tpu", 49152, "megablox"),
+    ("tpu", 600, "ragged_dot")])
+def test_one_place_decides_the_grouped_product(monkeypatch, backend, rows,
+                                               product):
+    """The layer and trial.setup's span ask the same function, with the
+    shapes: a row count no tile divides takes XLA's product and says so."""
+    import jax
+
+    from metaopt_tpu.models import lm, moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert moe.grouped_matmul_impl(rows, 2560, 768) == product
+    said = lm.describe_pattern(
+        {"rope_layout": [0], "sliding_window_layout": [0], "n_layers": 1,
+         "d_model": 2560, "moe_num_primary_experts": 64,
+         "moe_num_active_primary_experts": 6, "moe_ffn_hidden_size": 768},
+        "reference", tokens=rows // 6)
+    assert said["moe"]["products"] == product
+
+
+def test_the_step_compiles_once_and_starts_near_the_uniform_loss():
+    """The counts go into the jitted step as they come out of it, so step
+    1 finds step 0's program; and the head's initial size makes the first
+    loss about log(rows held)."""
+    from metaopt_tpu.models.lm import LMTrial
+
+    trial = LMTrial(description([(0, 0), (1, 1)], held=(0, 8)),
+                    mesh=one_device(), n_train=8, batch_size=2, seq_len=S)
+    with trial:
+        losses = [float(trial.step(i)) for i in range(3)]
+    assert trial._step_fn._cache_size() == 1
+    assert abs(losses[0] - math.log(V)) < 1.0

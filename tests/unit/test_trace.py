@@ -212,7 +212,8 @@ def _under(names, scope):
     return [n for n in names if at.search(n)]
 
 
-@pytest.mark.parametrize("scope", [s for s in trace.SCOPES if s != "eval"])
+@pytest.mark.parametrize("scope", [
+    s for s in trace.SCOPES if s != "eval" and not s.startswith("moe")])
 def test_every_scope_names_ops_of_the_train_step(lowered_op_names, scope):
     assert _under(lowered_op_names, scope), scope
 
@@ -226,6 +227,60 @@ def test_backward_ops_carry_the_scope_too(lowered_op_names, scope):
     backward = [n for n in _under(lowered_op_names, scope)
                 if "transpose(" in n]
     assert backward, scope
+
+
+@pytest.fixture(scope="module")
+def lowered_lm_op_names():
+    """Every ``op_name`` in the lowered train step of a pattern decoder
+    (models/lm.py) with dropless expert layers, rematerialised blocks."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from metaopt_tpu.models.lm import LMTrial
+
+    hp = dict(hidden_size=32, num_attention_heads=2, num_key_value_heads=1,
+              head_dim=16, num_hidden_layers=2, vocab_size=64,
+              rope_layout=[0, 1], sliding_window_layout=[0, 1],
+              sliding_window_size=8, moe_num_primary_experts=8,
+              moe_num_active_primary_experts=2, moe_ffn_hidden_size=16,
+              experts_held=(2, 4), remat=True)
+    one = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("dp", "tp"))
+    trial = LMTrial(hp, mesh=one, n_train=4, batch_size=2, seq_len=16)
+    with trial:
+        text = trial._step_fn.lower(
+            trial.params, trial.opt_state, trial.counts,
+            jnp.ones((2, 17), jnp.int32), jax.random.PRNGKey(0),
+        ).as_text(debug_info=True)
+    return set(re.findall(r'loc\("([^"]+)"', text))
+
+
+@pytest.mark.parametrize("scope", [
+    "embed", "attention", "attention.core", "readout_xent", "optimizer",
+    "moe", "moe.router", "moe.dispatch", "moe.experts", "moe.combine"])
+def test_a_pattern_decoder_s_step_has_ops_under_every_scope(
+        lowered_lm_op_names, scope):
+    assert _under(lowered_lm_op_names, scope), scope
+
+
+@pytest.mark.parametrize(
+    "scope", ["moe", "moe.router", "moe.dispatch", "moe.experts",
+              "moe.combine", "attention.core"])
+def test_an_expert_layer_s_backward_ops_carry_its_scopes(
+        lowered_lm_op_names, scope):
+    backward = [n for n in _under(lowered_lm_op_names, scope)
+                if "transpose(" in n]
+    assert backward, scope
+
+
+def test_the_router_s_product_is_under_moe_though_it_runs_before_attention(
+        lowered_lm_op_names):
+    router = _under(lowered_lm_op_names, "moe.router")
+    assert any("dot_general" in n for n in router)
+    assert all(_under([n], "moe") for n in router)
 
 
 def test_the_optimizer_s_ops_are_not_under_a_model_scope(lowered_op_names):
