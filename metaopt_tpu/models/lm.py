@@ -371,8 +371,10 @@ def describe_pattern(hparams: Dict[str, Any], route: str,
     """What ``trial.setup``'s span says of a description with a layer
     pattern ({} without one), for steps of ``tokens`` tokens on attention
     route ``route``: for each kind of layer the route and the form of its
-    mask, and the expert layers' share and the product they take."""
-    from metaopt_tpu.models.moe import grouped_matmul_impl
+    mask, and the expert layers' share, the product they take, the rows
+    of their buffers and the rows a trip of the routing's loops moves."""
+    from metaopt_tpu.models.moe import (grouped_matmul_impl,
+                                        routing_chunk_rows)
 
     h = _own_names(hparams)
     p = pattern_of(h)
@@ -390,7 +392,9 @@ def describe_pattern(hparams: Dict[str, Any], route: str,
                       "held": list(p.experts_held),
                       "products": grouped_matmul_impl(
                           tokens * p.top_k, int(h.get("d_model", 512)),
-                          p.expert_d_ff)}
+                          p.expert_d_ff),
+                      "buffer_rows": tokens * p.top_k,
+                      "chunk_rows": routing_chunk_rows(tokens * p.top_k)}
     return out
 
 
@@ -439,16 +443,16 @@ def lm_loss_fn(model, params, tokens, dropout_key,
 
 
 def moe_counts(mutated) -> Dict[str, Any]:
-    """{"items": (layers, held) int32, "dropped": (layers,) int32} from
-    the ``moe_stats`` the dropless expert layers sowed, layer by layer;
-    empty for a model without such layers."""
+    """{"items": (layers, held) int32, "dropped": (layers,) int32,
+    "chunks": (layers,) int32} from the ``moe_stats`` the dropless expert
+    layers sowed, layer by layer; empty for a model without such layers."""
     layers = [v for _, v in sorted(mutated.get("moe_stats", {}).items(),
                                    key=lambda kv: int(kv[0][1:]))  # h0, h1..
               if "items" in v.get("experts", {})]
     if not layers:
         return {}
     return {key: jnp.stack([v["experts"][key][0] for v in layers])
-            for key in ("items", "dropped")}
+            for key in ("items", "dropped", "chunks")}
 
 
 def make_lm_train_step(model, tx):
@@ -535,7 +539,8 @@ class LMTrial:
             layers = len(p.layers)
             self.counts = jax.device_put({
                 "items": jnp.zeros((layers, p.experts_held[1]), jnp.int32),
-                "dropped": jnp.zeros((layers,), jnp.int32)}, whole)
+                "dropped": jnp.zeros((layers,), jnp.int32),
+                "chunks": jnp.zeros((layers,), jnp.int32)}, whole)
 
     def __enter__(self):
         self._scope = self._use_mesh(self.mesh)
@@ -561,7 +566,8 @@ class LMTrial:
 
     def read_counts(self) -> Dict[str, Any]:
         """The counts so far, copied to the host (one round trip): items a
-        held expert and items dropped, a layer."""
+        held expert, items dropped and the trips of a pass of the routing
+        over the buffers, a layer."""
         return {k: v.tolist() for k, v in
                 jax.device_get(self.counts).items()}
 

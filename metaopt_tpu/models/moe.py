@@ -45,10 +45,15 @@ whatever the imbalance. It is told which experts it holds (a contiguous
 share of the published count), routes over all of them, and returns the
 part of the layer's output that its own experts give: the items whose
 expert lives here are ordered by expert and go through three grouped
-matrix products (:func:`grouped_matmul`) whose buffers have the static
-worst-case size, all ``tokens x k`` items. On an ``ep`` mesh axis each
-chip holds ``held / ep`` of them and the parts are summed over the axis;
-on one chip the layer runs without that exchange.
+matrix products (:func:`grouped_matmul`). The buffers have the static
+worst-case size, all ``tokens x k`` items, which is what makes the layer
+dropless; the passes over them have the size of what is filled: dispatch,
+combine and the backward of each are loops over chunks of rows whose trip
+count is read from the count of held items (:func:`routing_plan`), up to
+the buffers' end under the worst imbalance, and the rows behind the filled
+ones hold zeros. On an ``ep`` mesh axis each chip holds ``held / ep`` of
+the experts and the parts are summed over the axis; on one chip the layer
+runs without that exchange.
 """
 
 from __future__ import annotations
@@ -217,46 +222,191 @@ def grouped_matmul(x, w, group_sizes, impl: str):
                               preferred_element_type=x.dtype)
 
 
+#: rows one trip of a loop over the buffers' rows moves, and of a loop over
+#: the tokens (measured on the v5e at 49 152 x 2560 buffers; PERF.md)
+_CHUNK_ROWS = 2048
+_CHUNK_TOKENS = 512
+
+
+def routing_chunk_rows(rows: int) -> int:
+    """Rows one trip of the routing's loops moves, over buffers of ``rows``
+    rows."""
+    return min(_CHUNK_ROWS, rows)
+
+
+def _trips(count, chunk: int):
+    return (count + chunk - 1) // chunk
+
+
+def _over_chunks(count, size: int, chunk: int, body, init):
+    """``body(start, below, fresh, carry) -> carry`` over the first
+    ``count`` of ``size`` rows, ``chunk`` at a time: ``ceil(count /
+    chunk)`` trips, a number read on the device, ``ceil(size / chunk)`` at
+    the most. Of the trip's rows ``start .. start + chunk - 1``, ``below``
+    (chunk,) marks those under ``count`` and ``fresh`` those no earlier
+    trip had (a last trip that would pass ``size`` starts early instead)."""
+
+    def trip(i, carry):
+        start = jnp.minimum(i * chunk, size - chunk)
+        row = start + jnp.arange(chunk, dtype=jnp.int32)
+        return body(start, row < count, row >= i * chunk, carry)
+
+    return jax.lax.fori_loop(0, _trips(count, chunk), trip, init)
+
+
+def _zeros(shape, dtype, plan):
+    """Zeros that XLA does not take for a constant: it makes one unnamed
+    fill of the constant ones of a shape, for every layer, and a trace
+    then finds them under no scope."""
+    return jnp.broadcast_to(
+        jnp.minimum(plan["filled"], 0).astype(dtype), shape)
+
+
+def _take(x, index):
+    """Rows ``index`` of x, each of them a row that x has."""
+    return x.at[index].get(mode="promise_in_bounds")
+
+
+def _sum_to_tokens(buf, plan, weights, dtype):
+    """(t, width): for each token the float32 sum of its held items' rows
+    of ``buf`` (n, width), each times its weight (``weights`` (t, k) in the
+    order of ``plan["slot"]``, None for 1): the passes that sum back to
+    tokens. Level j adds the j-th held item of the tokens that have more
+    than j; over the levels that is every filled row once."""
+    t, k = plan["slot"].shape
+    chunk = min(_CHUNK_TOKENS, t)
+    acc = _zeros((t, buf.shape[1]), jnp.float32, plan)
+    for j in range(k):
+        slot = plan["slot"][:, j]
+        weight = None if weights is None else weights[:, j]
+
+        def body(start, below, fresh, acc, slot=slot, weight=weight):
+            live = below & fresh
+            at = jax.lax.dynamic_slice_in_dim(slot, start, chunk)
+            rows = _take(buf, jnp.where(live, at, 0)).astype(jnp.float32)
+            if weight is not None:
+                rows = rows * jax.lax.dynamic_slice_in_dim(
+                    weight, start, chunk)[:, None]
+            old = jax.lax.dynamic_slice_in_dim(acc, start, chunk)
+            return jax.lax.dynamic_update_slice_in_dim(
+                acc, old + jnp.where(live[:, None], rows, 0), start, 0)
+
+        acc = _over_chunks(plan["levels"][j], t, chunk, body, acc)
+    return _take(acc.astype(dtype), plan["back"])
+
+
+def routing_plan(group, held: int):
+    """Where every item goes, from ``group`` (t, k): the held expert 0 ..
+    held - 1 a choice names, ``held`` for an expert elsewhere. Integers
+    only; no gradient passes through them:
+
+    ``order`` (n,) the item in each buffer row, held items first and by
+    expert; ``inverse`` (n,) the row of each item; ``token`` (n,) the token
+    of each row; ``items`` (held,) the rows of each held expert and
+    ``filled`` () their sum. ``by_count`` (t,) the tokens, those with most
+    held items first, and ``back`` (t,) its inverse; for the tokens in that
+    order ``slot`` (t, k), the rows of a token's held items first, and
+    ``pick`` (t, k), the choice each came from; ``levels`` (k,) the tokens
+    with more than j held items, which sum to ``filled``."""
+    t, k = group.shape
+    n = t * k
+    flat = group.reshape(n)
+    order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+    inverse = jnp.argsort(order).astype(jnp.int32)
+    # a count by comparison: a scatter-add into 16 bins is slow on a TPU
+    items = jnp.sum(flat[:, None] == jnp.arange(held)[None],
+                    axis=0, dtype=jnp.int32)
+    mine = group < held
+    count = jnp.sum(mine, axis=1, dtype=jnp.int32)
+    by_count = jnp.argsort(-count, stable=True).astype(jnp.int32)
+    choice = jnp.broadcast_to(jnp.arange(k, dtype=jnp.int32), (t, k))
+    slot, pick = jax.lax.sort(
+        (jnp.where(mine, inverse.reshape(t, k), n), choice),
+        dimension=1, num_keys=1)
+    return {
+        "order": order, "inverse": inverse, "token": order // k,
+        "items": items, "filled": jnp.sum(items),
+        "by_count": by_count, "back": jnp.argsort(by_count).astype(jnp.int32),
+        "slot": slot[by_count], "pick": pick[by_count],
+        "levels": jnp.sum(count[:, None] > jnp.arange(k)[None],
+                          axis=0, dtype=jnp.int32)}
+
+
 @jax.custom_vjp
-def _take_rows(x, index, inverse):
-    """``x[index]`` for a permutation ``index`` of x's rows with its
-    ``inverse``: the gradient is then a gather too, never a scatter."""
-    return x[index]
+def _dispatch(x, plan):
+    """(n, d) buffer: row r < filled is the row of ``x`` (t, d) of the item
+    there, the others zeros. The gradient sums a token's held rows."""
+    n = plan["token"].shape[0]
+    chunk = routing_chunk_rows(n)
+
+    def body(start, below, fresh, buf):
+        tokens = jax.lax.dynamic_slice_in_dim(plan["token"], start, chunk)
+        rows = jnp.where(below[:, None], _take(x, tokens), 0)
+        return jax.lax.dynamic_update_slice_in_dim(buf, rows, start, 0)
+
+    return _over_chunks(plan["filled"], n, chunk, body,
+                        _zeros((n, x.shape[1]), x.dtype, plan))
 
 
-def _take_rows_fwd(x, index, inverse):
-    return x[index], inverse
+def _dispatch_fwd(x, plan):
+    return _dispatch(x, plan), plan
 
 
-def _take_rows_bwd(inverse, g):
-    return g[inverse], None, None
+def _dispatch_bwd(plan, g):
+    return _sum_to_tokens(g, plan, None, g.dtype), None
 
 
-_take_rows.defvjp(_take_rows_fwd, _take_rows_bwd)
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _rows_of_items(x, order, inverse, mine, k: int):
-    """Row ``order[i] // k`` of ``x`` (t, d) for each of the t * k items in
-    dispatch order ``order`` (a permutation with its ``inverse``): what
-    ``repeat(x, k)[order]`` gives, without the repeat. The gradient is a
-    gather by ``inverse`` and a sum over a token's k items, those alone
-    that ``mine`` (t, k) marks: the rows of the others hold nothing."""
-    return x[order // k]
+def _slot_weights(weights, plan):
+    """``weights`` (t, k) in the order of ``plan["slot"]``."""
+    chosen = plan["pick"][..., None] == jnp.arange(weights.shape[1])
+    return jnp.sum(jnp.where(chosen, weights[plan["by_count"]][:, None], 0),
+                   axis=2)
 
 
-def _rows_of_items_fwd(x, order, inverse, mine, k):
-    return x[order // k], (inverse, mine)
+@jax.custom_vjp
+def _combine(out, weights, plan):
+    """(t, d) float32: ``sum_j weights[t, j] * out[row of item (t, j)]``
+    over a token's held items, ``out`` (n, d) in buffer order. The gradient
+    to ``out`` is written in buffer order too, its rows past ``filled``
+    zeros: row r is its token's incoming row times the item's weight."""
+    return _sum_to_tokens(out, plan, _slot_weights(weights, plan),
+                          jnp.float32)
 
 
-def _rows_of_items_bwd(k, res, g):
-    inverse, mine = res
-    items = g[inverse].reshape(*mine.shape, g.shape[-1])
-    mine_only = jnp.where(mine[..., None], items, 0).astype(jnp.float32)
-    return jnp.sum(mine_only, axis=1).astype(g.dtype), None, None, None
+def _combine_fwd(out, weights, plan):
+    return _combine(out, weights, plan), (out, weights, plan)
 
 
-_rows_of_items.defvjp(_rows_of_items_fwd, _rows_of_items_bwd)
+def _combine_bwd(res, g):
+    out, weights, plan = res
+    n = out.shape[0]
+    chunk = routing_chunk_rows(n)
+    by_row = weights.reshape(n)[plan["order"]]
+
+    def body(start, below, fresh, carry):
+        d_out, d_by_row = carry
+        rows = _take(g, jax.lax.dynamic_slice_in_dim(plan["token"], start,
+                                                     chunk))
+        w = jax.lax.dynamic_slice_in_dim(by_row, start, chunk)
+        mine = jax.lax.dynamic_slice_in_dim(out, start, chunk)
+        d_rows = jnp.where(below[:, None], rows * w[:, None], 0)
+        d_w = jnp.where(below, jnp.sum(
+            rows * mine.astype(jnp.float32), axis=1), 0)
+        return (jax.lax.dynamic_update_slice_in_dim(
+                    d_out, d_rows.astype(out.dtype), start, 0),
+                jax.lax.dynamic_update_slice_in_dim(d_by_row, d_w, start, 0))
+
+    d_out, d_by_row = _over_chunks(
+        plan["filled"], n, chunk, body,
+        (_zeros(out.shape, out.dtype, plan), jnp.zeros((n,), jnp.float32)))
+    return (d_out, d_by_row[plan["inverse"]].reshape(weights.shape)
+            .astype(weights.dtype), None)
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 def route_top_k(logits, k: int):
@@ -274,11 +424,13 @@ def dropless_experts(x, weights, experts, w_gate, w_up, w_down, first):
     w_up (held, d, f), w_down (held, f, d): experts ``first .. first +
     held - 1`` of the published ones. Returns (y (t, d) float32, counts):
     ``sum_k weights * expert(x)`` over the choices whose expert is held,
-    and ``{"items": (held,) int32 a held expert, "dropped": () int32}``.
-    Nothing is dropped: the buffers hold all t * k items, the most that
-    can be routed here. Rows of the buffers that no held item fills are
-    never read into a result: the products skip them, and what comes back
-    in item order is masked by ``mine``.
+    and ``{"items": (held,) int32 a held expert, "dropped": () int32,
+    "chunks": () int32}``. Nothing is dropped: the buffers hold all t * k
+    items, the most that can be routed here. The passes over them move the
+    rows that held items fill, a chunk a trip, and leave zeros behind
+    them; the products skip those. ``chunks``: the trips of a pass in
+    buffer order, ``ceil(filled / chunk)`` (a pass that sums back to
+    tokens moves as many rows in at most k trips more).
     """
     t, d = x.shape
     k = experts.shape[1]
@@ -286,31 +438,25 @@ def dropless_experts(x, weights, experts, w_gate, w_up, w_down, first):
     n = t * k
     with trace.scope("moe.dispatch"):
         local = experts - first
-        mine = (local >= 0) & (local < held)                 # (t, k)
         # items of experts elsewhere sort behind every held expert's
-        group = jnp.where(mine, local, held).astype(jnp.int32).reshape(n)
-        order = jnp.argsort(group, stable=True).astype(jnp.int32)
-        inverse = jnp.argsort(order).astype(jnp.int32)
-        # a count by comparison: a scatter-add into 16 bins is slow on a TPU
-        items = jnp.sum(group[:, None] == jnp.arange(held)[None],
-                        axis=0, dtype=jnp.int32)
-        rows = _rows_of_items(x.astype(jnp.bfloat16), order, inverse, mine, k)
+        plan = routing_plan(jnp.where((local >= 0) & (local < held), local,
+                                      held).astype(jnp.int32), held)
+        rows = _dispatch(x.astype(jnp.bfloat16), plan)
     with trace.scope("moe.experts"):
         product = functools.partial(
-            grouped_matmul, group_sizes=items,
+            grouped_matmul, group_sizes=plan["items"],
             impl=grouped_matmul_impl(n, d, w_gate.shape[2]))
         h = nn.relu(product(rows, w_gate.astype(jnp.bfloat16))) \
             * product(rows, w_up.astype(jnp.bfloat16))
         out = product(h, w_down.astype(jnp.bfloat16))
     with trace.scope("moe.combine"):
-        out = _take_rows(out, inverse, order).reshape(t, k, d)
-        y = jnp.sum(jnp.where(mine[..., None], out, 0).astype(jnp.float32)
-                    * weights[..., None], axis=1)
+        y = _combine(out, weights, plan)
     # the items that found no row in the buffers: 0 while the buffers have
     # a row for each of the n items, as they do; buffers cut below that
     # (a capacity) would move it
-    dropped = jnp.maximum(jnp.sum(items) - rows.shape[0], 0)
-    return y, {"items": items, "dropped": dropped}
+    dropped = jnp.maximum(plan["filled"] - rows.shape[0], 0)
+    chunks = _trips(plan["filled"], routing_chunk_rows(n))
+    return y, {"items": plan["items"], "dropped": dropped, "chunks": chunks}
 
 
 class DroplessMoE(nn.Module):
@@ -354,6 +500,7 @@ class DroplessMoE(nn.Module):
                                  experts, w, first)
         self.sow("moe_stats", "items", counts["items"])
         self.sow("moe_stats", "dropped", counts["dropped"])
+        self.sow("moe_stats", "chunks", counts["chunks"])
         return y.reshape(b, s, d)
 
 
@@ -361,7 +508,7 @@ def _over_ep(mesh, ep: int, x, weights, experts, w, first: int):
     """The layer on an ``ep`` mesh axis: each chip holds ``held / ep``
     experts, computes their part for every token, and the parts are summed
     over the axis (tokens are not exchanged: every chip of the axis has
-    them all)."""
+    them all). ``chunks`` is the fullest chip's."""
     from jax.sharding import PartitionSpec as P
 
     count = w["gate"].shape[0]
@@ -373,11 +520,12 @@ def _over_ep(mesh, ep: int, x, weights, experts, w, first: int):
         y, counts = dropless_experts(x, weights, experts, gate, up, down,
                                      mine)
         return (jax.lax.psum(y, "ep"), counts["items"],
-                jax.lax.psum(counts["dropped"], "ep"))
+                jax.lax.psum(counts["dropped"], "ep"),
+                jax.lax.pmax(counts["chunks"], "ep"))
 
-    y, items, dropped = jax.shard_map(
+    y, items, dropped, chunks = jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(), P(), P(), P("ep"), P("ep"), P("ep")),
-        out_specs=(P(), P("ep"), P()), check_vma=False,
+        out_specs=(P(), P("ep"), P(), P()), check_vma=False,
     )(x, weights, experts, w["gate"], w["up"], w["down"])
-    return y, {"items": items, "dropped": dropped}
+    return y, {"items": items, "dropped": dropped, "chunks": chunks}

@@ -346,6 +346,7 @@ def print_routes(recs: List[dict]) -> None:
     ``trial.setup`` span has it (ops/attention.attention_route); for a
     model with a layer pattern a line each kind of layer, what the expert
     layers hold, and (from ``trial.train``) what they counted."""
+    held = {}  # trial -> its setup's attrs["moe"]
     for r in recs:
         attrs = r["attrs"]
         route = attrs.get("attention") if r["name"] == "trial.setup" else None
@@ -358,6 +359,7 @@ def print_routes(recs: List[dict]) -> None:
                       f"mask by {how['mask']}")
             moe = attrs.get("moe")
             if moe:
+                held[r["trial"]] = moe
                 first, count = moe["held"]
                 print(f"trial {r['trial']}: experts {first}-"
                       f"{first + count - 1} of {moe['routed_over']} held, "
@@ -370,6 +372,13 @@ def print_routes(recs: List[dict]) -> None:
                       f"items to held experts, fullest "
                       f"{max(items) / mean if mean else 0.0:.2f}x the mean, "
                       f"{counts['dropped'][layer]} dropped")
+            said = held.get(r["trial"], {})
+            rows = (attrs.get("steps", 0) * len(counts["items"])
+                    * said.get("buffer_rows", 0))
+            if rows and "chunks" in counts:
+                moved = sum(counts["chunks"]) * said["chunk_rows"]
+                print(f"trial {r['trial']}: routing moved "
+                      f"{100 * moved / rows:.1f} % of the buffers' rows")
 
 
 dump_under(os.environ.get(PROFILE_DIR_ENV))

@@ -299,6 +299,190 @@ def test_on_an_ep_axis_each_chip_holds_its_part_and_the_sum_is_the_layer(ep):
     assert np.asarray(many["moe_stats"]["items"][0]).tolist() \
         == np.asarray(one["moe_stats"]["items"][0]).tolist()
     assert int(many["moe_stats"]["dropped"][0]) == 0
+    # 24 tokens x top-k rows are one chunk: the fullest chip's one trip
+    assert int(many["moe_stats"]["chunks"][0]) == 1 == int(
+        one["moe_stats"]["chunks"][0])
+
+
+# -- the routing's passes over several chunks ----------------------------------
+
+T_LONG = 4000   # t x k = 12 000 rows: five chunks of 2048 and a part of one
+
+
+def ref_experts(x, weights, experts, gate, up, down, first):
+    """The held experts' part from the routing itself, float32: what
+    ``ref_moe`` does after its own top-k."""
+    y = jnp.zeros_like(x)
+    for e in range(gate.shape[0]):
+        we = jnp.sum(jnp.where(experts == first + e, weights, 0.0), -1)
+        hid = jax.nn.relu(jnp.dot(x, gate[e], precision=HI)) \
+            * jnp.dot(x, up[e], precision=HI)
+        y = y + we[:, None] * jnp.dot(hid, down[e], precision=HI)
+    return y
+
+
+#: share -> (held, whether the router sends every token to experts 0, 1, 2)
+ROUTINGS = {"nothing": ((12, 4), True), "a quarter": ((4, 4), False),
+            "everything": ((0, E), False), "the worst imbalance": ((0, 4),
+                                                                   True)}
+PARTS = ["y", "x", "weights", "gate", "up", "down"]
+
+
+@pytest.fixture(scope="module")
+def routed():
+    """{share: (program's {part: array}, counts; reference's {part})}: the
+    output and the gradient to each input of ``dropless_experts`` over
+    T_LONG tokens, where the filled rows span several of the loops' chunks
+    and end inside one."""
+    from metaopt_tpu.models.moe import dropless_experts, route_top_k
+
+    key = jax.random.PRNGKey(11)
+    ks = jax.random.split(key, 6)
+    x = jax.random.normal(ks[0], (T_LONG, D))
+    cot = jax.random.normal(ks[1], (T_LONG, D))
+    full = [jax.random.normal(k, shape) * shape[1] ** -0.5 for k, shape in
+            zip(ks[2:5], [(E, D, F), (E, D, F), (E, F, D)])]
+    out = {}
+    for share, ((first, count), biased) in ROUTINGS.items():
+        logits = 2.0 * jax.random.normal(ks[5], (T_LONG, E))
+        if biased:
+            logits = logits + jnp.zeros((E,)).at[:TOPK].set(50.0)
+        weights, experts = route_top_k(logits, TOPK)
+        mats = [m[first:first + count] for m in full]
+
+        def both(fn):
+            """{part: array} and what ``fn`` counted, ``fn`` -> (y, counts)."""
+            def loss(x, weights, gate, up, down):
+                y, counts = fn(x, weights, gate, up, down)
+                return jnp.sum(y * cot), (y, counts)
+            grads, (y, counts) = jax.grad(
+                loss, argnums=(0, 1, 2, 3, 4), has_aux=True)(
+                    x, weights, *mats)
+            return dict(zip(PARTS, (y,) + grads)), counts
+
+        mine, counts = both(lambda x, w, g, u, d: dropless_experts(
+            x, w, experts, g, u, d, first))
+        ref, _ = both(lambda x, w, g, u, d: (ref_experts(
+            x, w, experts, g, u, d, first), {}))
+        out[share] = (mine, {k: np.asarray(v) for k, v in counts.items()},
+                      ref)
+    return out
+
+
+@pytest.mark.parametrize("part", PARTS)
+@pytest.mark.parametrize("share", list(ROUTINGS))
+def test_over_several_chunks_output_and_gradients_match(routed, share, part):
+    """bfloat16 products against float32: within a fiftieth of the
+    reference's norm (0.005 here), but for the two gradients that pass
+    through ReLU's step, which bfloat16 moves for the pre-activations near
+    0 (0.06 here at most; a chunk left out or added twice reads 0.15 or
+    more)."""
+    mine, _, ref = routed[share]
+    assert mine[part].shape == ref[part].shape
+    assert np.linalg.norm(mine[part] - ref[part]) <= (
+        0.1 if part in ("x", "gate") else 0.02) * max(
+            np.linalg.norm(ref[part]), 1e-6)
+    if share == "nothing":
+        assert not np.any(np.asarray(mine[part]))
+
+
+@pytest.mark.parametrize("share", list(ROUTINGS))
+def test_the_loops_run_as_many_trips_as_the_filled_rows_need(routed, share):
+    from metaopt_tpu.models.moe import routing_chunk_rows
+
+    _, counts, _ = routed[share]
+    n = T_LONG * TOPK
+    filled = int(counts["items"].sum())
+    low, high = {"nothing": (0, 0), "a quarter": (0.2 * n, 0.3 * n)}.get(
+        share, (n, n))
+    assert low <= filled <= high
+    chunk = routing_chunk_rows(n)
+    # several chunks, the filled rows ending inside one
+    assert n > 5 * chunk and (filled % chunk or not filled)
+    assert int(counts["chunks"]) == -(-filled // chunk)
+    assert int(counts["dropped"]) == 0
+
+
+@pytest.mark.parametrize("share", list(ROUTINGS))
+def test_rows_of_the_buffers_past_the_filled_ones_are_zeros(share):
+    """Dispatch's buffer and the gradient combine hands the products: the
+    filled rows are the items', every row behind them is 0, whatever was
+    there (a masked tile of the products may read them)."""
+    from metaopt_tpu.models import moe
+
+    (first, count), biased = ROUTINGS[share]
+    key = jax.random.PRNGKey(2)
+    x = jax.random.normal(key, (T_LONG, D)).astype(jnp.bfloat16)
+    logits = jax.random.normal(jax.random.fold_in(key, 1), (T_LONG, E))
+    if biased:
+        logits = logits + jnp.zeros((E,)).at[:TOPK].set(50.0)
+    weights, experts = moe.route_top_k(logits, TOPK)
+    local = experts - first
+    plan = moe.routing_plan(jnp.where((local >= 0) & (local < count), local,
+                                      count).astype(jnp.int32), count)
+    filled = int(plan["filled"])
+    token = np.asarray(plan["token"])
+    rows = np.asarray(moe._dispatch(x, plan), np.float32)
+    assert np.array_equal(rows[:filled], np.asarray(x, np.float32)[
+        token[:filled]])
+    assert not rows[filled:].any()
+    out = jnp.full((T_LONG * TOPK, D), jnp.nan, jnp.bfloat16).at[
+        :filled].set(1.0)                 # past the filled rows: anything
+    g = jax.random.normal(key, (T_LONG, D))
+    y, back = jax.vjp(lambda o, w: moe._combine(o, w, plan), out, weights)
+    d_out, d_weights = back(g)
+    assert np.isfinite(np.asarray(y)).all()
+    assert np.isfinite(np.asarray(d_weights)).all()
+    d_out = np.asarray(d_out, np.float32)
+    assert np.isfinite(d_out).all() and not d_out[filled:].any()
+    by_row = np.asarray(weights).reshape(-1)[np.asarray(plan["order"])]
+    np.testing.assert_allclose(
+        d_out[:filled], (by_row[:filled, None] * np.asarray(g)[
+            token[:filled]]).astype(jnp.bfloat16).astype(np.float32),
+        rtol=1e-2, atol=1e-6)
+
+
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside its equations."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _equations(inner)
+
+
+def test_no_pass_of_the_routing_has_the_worst_case_s_size():
+    """The work the gradient of ``dropless_experts`` asks for at the
+    benchmark cell's t and k (d cut; traced, nothing run): all six passes
+    of the routing (dispatch, combine and the backward of each) are
+    covered. No scatter-add, no gather of t x k rows of width d, and no
+    float32 array of (t, k, d): each would be a pass over the buffers'
+    worst case come back."""
+    from metaopt_tpu.models.moe import dropless_experts
+
+    t, k, d, f, held = 8192, 6, 128, 64, 16
+    shapes = [jax.ShapeDtypeStruct(s, dt) for s, dt in [
+        ((t, d), jnp.float32), ((t, k), jnp.float32), ((t, k), jnp.int32),
+        ((held, d, f), jnp.float32), ((held, d, f), jnp.float32),
+        ((held, f, d), jnp.float32)]]
+
+    def loss(x, weights, experts, gate, up, down):
+        return jnp.sum(dropless_experts(x, weights, experts, gate, up, down,
+                                        0)[0])
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 3, 4, 5)))(*shapes)
+    eqns = list(_equations(jaxpr.jaxpr))
+    names = {e.primitive.name for e in eqns}
+    assert "while" in names and "gather" in names
+    assert not {n for n in names if n.startswith("scatter")}
+    shapes_out = [(e.primitive.name, v.aval.shape, v.aval.dtype)
+                  for e in eqns for v in e.outvars if hasattr(v.aval, "shape")]
+    assert [s for s in shapes_out
+            if s[0] == "gather" and s[1] == (t * k, d)] == []
+    assert [s for s in shapes_out if d in s[1] and s[2] == jnp.float32
+            and math.prod(s[1]) >= t * k * d] == []
 
 
 # -- the description -----------------------------------------------------------
@@ -354,6 +538,8 @@ def test_train_lm_reports_the_routing_s_counts_once(tmp_path):
     train = trace.spans("trial.train")[-1]
     moe = train["attrs"]["moe"]
     assert moe["dropped"] == [0, 0]
+    # 2 x S x top-k = 144 rows a layer are one chunk: a trip a step
+    assert moe["chunks"] == [3, 3]
     assert np.asarray(moe["items"]).shape == (2, 8)
     # 3 steps x 2 rows x S tokens x top-k choices, the held experts' part
     assert 0 < np.sum(moe["items"]) < 3 * 2 * S * TOPK * 2
@@ -364,7 +550,9 @@ def test_train_lm_reports_the_routing_s_counts_once(tmp_path):
         "window-rope": {"route": "reference",
                         "mask": f"dense: causal, window {WINDOW}"}}
     assert setup["moe"] == {"routed_over": E, "top_k": TOPK, "held": [4, 8],
-                            "products": "ragged_dot"}
+                            "products": "ragged_dot",
+                            "buffer_rows": 2 * S * TOPK,
+                            "chunk_rows": 2 * S * TOPK}
 
 
 def test_the_trial_hands_out_its_loop_step_by_step():
@@ -392,9 +580,11 @@ def test_the_reader_prints_the_pattern_s_routes_and_counts(capsys):
         "attention_layers": {
             "global-nope": {"route": "pallas", "mask": "structure: causal"}},
         "moe": {"routed_over": 64, "top_k": 6, "held": [0, 16],
-                "products": "ragged_dot"}}}
+                "products": "ragged_dot", "buffer_rows": 49152,
+                "chunk_rows": 2048}}}
     train = {"name": "trial.train", "trial": "T-1", "attrs": {
-        "moe": {"items": [[30, 10]], "dropped": [0]}}}
+        "steps": 2, "moe": {"items": [[30, 10]], "dropped": [0],
+                            "chunks": [13]}}}
     trace.print_routes([setup, train])
     assert capsys.readouterr().out.splitlines() == [
         "trial T-1: attention pallas in training (dropout 0.0), pallas in "
@@ -402,7 +592,8 @@ def test_the_reader_prints_the_pattern_s_routes_and_counts(capsys):
         "trial T-1: global-nope layers: pallas, mask by structure: causal",
         "trial T-1: experts 0-15 of 64 held, top 6, products by ragged_dot",
         "trial T-1: layer 0: 40 items to held experts, fullest 1.50x the "
-        "mean, 0 dropped"]
+        "mean, 0 dropped",
+        "trial T-1: routing moved 27.1 % of the buffers' rows"]
 
 
 def test_the_example_takes_a_model_description(tmp_path, monkeypatch):
