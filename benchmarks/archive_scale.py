@@ -31,7 +31,7 @@ run against the real started server.
                                        [--observe-n 20000] [--save]
 
 Emits one JSON line per (mode, scale) probe plus an `observe` row and a
-`summary` row carrying the regression-gate keys.
+`summary` row carrying the headline keys.
 """
 
 from __future__ import annotations
@@ -262,7 +262,7 @@ def main() -> int:
     a, r = by[("archived", top)], by[("resident", top)]
     summary = {
         "kind": "summary", "trials": top,
-        # regression-gate keys (benchmarks/check_regression.py)
+        # the headline keys
         "coord_rss_bytes_per_trial_1m": a["rss_bytes_per_trial"],
         "coord_archive_rss_ratio": round(
             r["rss_bytes_per_trial"] / a["rss_bytes_per_trial"], 2),
@@ -283,6 +283,7 @@ def main() -> int:
         stamp = time.strftime("%Y-%m-%d")
         path = os.path.join(REPO, "benchmarks", "results",
                             f"archive_scale_{stamp}.jsonl")
+        os.makedirs(os.path.dirname(path), exist_ok=True)
         with open(path, "a") as fh:
             for row in rows:
                 fh.write(json.dumps(row) + "\n")

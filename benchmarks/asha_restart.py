@@ -166,6 +166,7 @@ def main() -> int:
         stamp = time.strftime("%Y-%m-%d", time.gmtime())
         path = os.path.join(REPO, "benchmarks", "results",
                             f"asha_restart_{stamp}.jsonl")
+        os.makedirs(os.path.dirname(path), exist_ok=True)
         with open(path, "a") as f:
             f.write(json.dumps(row) + "\n")
         print(f"saved -> {path}", file=sys.stderr)

@@ -149,6 +149,7 @@ def main() -> None:
         stamp = time.strftime("%Y-%m-%d")
         path = os.path.join(REPO, "benchmarks", "results",
                             f"batch_eval_{stamp}.jsonl")
+        os.makedirs(os.path.dirname(path), exist_ok=True)
         with open(path, "a") as fh:
             for row in rows:
                 fh.write(json.dumps(row) + "\n")

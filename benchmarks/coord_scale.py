@@ -33,8 +33,7 @@ coalesced-vs-serial property tests).
 
 "fused+wal" is the shipped configuration with the write-ahead log on
 (snapshot+WAL in a tempdir, group-commit fsync on every mutating reply);
-the fused vs fused+wal delta is the durability tax, gated at <10% by
-benchmarks/check_regression.py. `--recovery` additionally times a crash
+the fused vs fused+wal delta is the durability tax. `--recovery` additionally times a crash
 restart (restore + replay of a 2000-trial WAL).
 
 "sharded" (via --shards) is the multi-process deployment: a
@@ -47,7 +46,7 @@ reported ratio is same-run/same-machine, because PR 3 showed absolute
 trials/s drifts >10% between sessions on the CI box and poisons
 cross-session comparisons. On a one-core box sharding cannot scale (the
 shards time-slice one core); the honest figure there is the 1-shard
-overhead vs the in-process server, which the regression gate bounds.
+overhead vs the in-process server.
 
     python benchmarks/coord_scale.py [--workers 1 8 32]
                                      [--modes serial fused fused+wal]
@@ -131,8 +130,7 @@ def _make_server(mode: str, produce_coalesce_ms: float, shards=None):
     dispatch shape so the baseline is the pre-change server, not the new
     server driven serially. ``fused+wal`` is the shipped server with the
     write-ahead log on (group-commit fsync before every mutating reply) —
-    the fused/fused+wal ratio is the durability tax the regression gate
-    bounds at 10%. ``sharded`` is the multi-process deployment: N
+    the fused/fused+wal ratio is the durability tax. ``sharded`` is the multi-process deployment: N
     subprocess shards, one WAL each, under a ShardSupervisor."""
     import shutil
     import tempfile
@@ -428,8 +426,7 @@ def run_handoff(trials: int = 48, seed: int = 0) -> dict:
     experiment's writers see ``Migrating`` retries). ``coord_failover_
     time_s`` is the supervisor's own death-to-redistributed figure for a
     killed shard whose experiment is recovered from snapshot+WAL on
-    disk. Both are quoted by the runbook; the regression gates stay
-    informational until a committed baseline carries them.
+    disk. Both are quoted by the runbook.
     """
     import shutil
     import tempfile
@@ -1126,7 +1123,7 @@ def main():
             print(json.dumps(row), flush=True)
             rows.append(row)
             by[(key, n)] = row
-    # the headline ratio the regression gate rides on: fused vs serial at
+    # the headline ratio: fused vs serial at
     # the widest fan-in measured in the SAME run on the SAME machine
     widest = max(args.workers) if args.workers else 0
     f, s = by.get(("fused", widest)), by.get(("serial", widest))
@@ -1152,8 +1149,7 @@ def main():
             "coord_wire_bytes_per_trial": f.get("wire_bytes_per_trial"),
             "json_wire_bytes_per_trial": j.get("wire_bytes_per_trial"),
         }), flush=True)
-    # the durability tax: fused+wal vs fused in the same run — the gate
-    # benchmarks/check_regression.py bounds at 10%
+    # the durability tax: fused+wal vs fused in the same run
     w = by.get(("fused+wal", widest))
     if f and w and f.get("trials_per_s") and w.get("trials_per_s"):
         print(json.dumps({
@@ -1170,7 +1166,7 @@ def main():
         one = by.get(("shard1", widest))
         # the process tax: 1 sharded subprocess (WAL on) vs the in-process
         # durable server on the SAME multi-experiment workload — the figure
-        # check_regression.py bounds on one-core CI where scaling can't show
+        # to read on a one-core box, where scaling can't show
         if (base and one and base.get("trials_per_s")
                 and one.get("trials_per_s")):
             print(json.dumps({
@@ -1222,7 +1218,7 @@ def main():
             print(json.dumps(row), flush=True)
             rows.append(row)
             fs_by[n] = row
-        # the headline the regression gate rides on: the widest fleet's
+        # the headline: the widest fleet's
         # same-run fused-vs-serial ratio and its launch amortization
         top = fs_by[max(fs_by)]
         print(json.dumps({
@@ -1237,6 +1233,7 @@ def main():
         stamp = time.strftime("%Y-%m-%d")
         path = os.path.join(REPO, "benchmarks", "results",
                             f"coord_scale_{stamp}.jsonl")
+        os.makedirs(os.path.dirname(path), exist_ok=True)
         with open(path, "a") as fh:
             for row in rows:
                 fh.write(json.dumps(row) + "\n")

@@ -254,6 +254,7 @@ def main() -> int:
             REPO, "benchmarks", "results",
             f"{args.scale}_{backend}_{stamp}.jsonl",
         )
+        os.makedirs(os.path.dirname(save_path), exist_ok=True)
     # run id groups one attempt's rows inside the appended-to dated file —
     # a retry on the same day must not double-count
     run_id = f"{int(time.time())}-{os.getpid()}"

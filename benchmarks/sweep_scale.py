@@ -158,6 +158,7 @@ def main():
         stamp = time.strftime("%Y-%m-%d")
         path = os.path.join(REPO, "benchmarks", "results",
                             f"sweep_scale_{stamp}.jsonl")
+        os.makedirs(os.path.dirname(path), exist_ok=True)
         with open(path, "a") as f:
             for row in rows:
                 f.write(json.dumps(row) + "\n")

@@ -7,9 +7,9 @@
 
 Drives the main path -- ``python -m metaopt_tpu hunt --n-chips 1`` with
 subprocess trials -- through the normal entry points at the full width
-of Transformer-base (the shape ``bench.py`` measures: d_model 512, 8
-heads, d_ff 2048, vocab 32000, batch 64, sequence 256; random weights
-from a seed), and checks what comes out by the repo's own means. Depth
+of Transformer-base (the shape of the benchmark's first cell: d_model
+512, 8 heads, d_ff 2048, vocab 32000, batch 64, sequence 256; random
+weights from a seed), and checks what comes out by the repo's own means. Depth
 is cut from 6 layers to 2: every trial compiles its own program (lr and
 dropout are constants in it), and at 6 layers that is 113 s of XLA
 compile in a 160 s trial (PERF.md), which five trials cannot afford.
@@ -75,7 +75,8 @@ OUT = os.path.join(HERE, "chiprun_out", "chip_smoke")
 CKPT = os.path.join(HERE, ".cache", "chip_smoke_ckpt")
 PHASES = ("sweep", "kernels", "cache", "handoff", "busy", "four")
 
-#: Transformer-base as bench.py's bench_transformer sizes it, depth cut.
+#: Transformer-base as chipbench/configs/transformer-base-wmt.json sizes
+#: it, depth cut.
 #: GP-BO gets 1k observations: its cold fit at 10k took 566 s on the v5e.
 FULL = {"d_model": 512, "n_layers": 2, "d_ff": 2048, "vocab": 32000,
         "batch": 64, "seq": 256, "max_len": 512, "steps": 20,
@@ -428,6 +429,35 @@ def _own_device(rehearsal: bool) -> str:
     return cache_dir
 
 
+def _with_observations(algo_cls, n_obs: int, seed: int = 0):
+    """``algo_cls`` (TPE, GPBO) over a mixed space of eight dimensions,
+    holding ``n_obs`` seeded observations as if it had been told them."""
+    import numpy as np
+
+    from metaopt_tpu.space import build_space
+
+    space = build_space(
+        {
+            "lr": "loguniform(1e-5, 1e-1)",
+            "wd": "loguniform(1e-6, 1e-2)",
+            "width": "uniform(32, 1024, discrete=True)",
+            "depth": "uniform(1, 12, discrete=True)",
+            "dropout": "uniform(0.0, 0.5)",
+            "momentum": "uniform(0.5, 0.999)",
+            "opt": "choices(['adam', 'sgd', 'lamb'])",
+            "schedule": "choices(['cosine', 'linear', 'constant'])",
+        }
+    )
+    algo = algo_cls(space, seed=seed, n_initial_points=8)
+    rng = np.random.default_rng(seed)
+    X = rng.random((n_obs, algo.cube.n_dims))
+    y = rng.random(n_obs).tolist()
+    algo._X = list(X)
+    algo._y = y
+    algo._observed = {str(i): y[i] for i in range(n_obs)}
+    return algo
+
+
 def child_kernels(cfg, rehearsal):
     _own_device(rehearsal)
     import jax
@@ -487,12 +517,13 @@ def child_kernels(cfg, rehearsal):
             out["kernels"].append(row)
 
     # the coordinator-chip deployment: suggest kernels on this device
-    sys.path.insert(0, HERE)
-    from bench import build_gpbo, build_tpe
+    from metaopt_tpu.algo import GPBO, TPE
 
     for nm, algo, n_obs, n in (
-            ("tpe", build_tpe(cfg["tpe_obs"]), cfg["tpe_obs"], 8),
-            ("gp_bo", build_gpbo(cfg["gp_obs"]), cfg["gp_obs"], 1)):
+            ("tpe", _with_observations(TPE, cfg["tpe_obs"]),
+             cfg["tpe_obs"], 8),
+            ("gp_bo", _with_observations(GPBO, cfg["gp_obs"]),
+             cfg["gp_obs"], 1)):
         t0 = time.perf_counter()
         pts = algo.suggest(n)
         t1 = time.perf_counter()

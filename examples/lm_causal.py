@@ -11,7 +11,9 @@
 ``--sp 2`` shards the sequence axis (ring attention over ICI;
 METAOPT_TPU_SP_IMPL=ulysses for the all-to-all variant) — the
 decoder-only model is where long-context sequence parallelism earns
-its keep.
+its keep. Which attention a step takes is ops/attention.attention_route's
+one rule, from the mesh, the backend and the dropout rate; a model with a
+layer pattern (``--model``) has no sequence-parallel route.
 
 ``--model FILE`` names the model by a description: a JSON object of
 ``make_lm``'s hyperparameters, the published names included

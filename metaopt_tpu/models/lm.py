@@ -23,9 +23,10 @@
   ``vocab_held`` = (first, count) of the vocabulary's rows (ids are drawn
   from that slice, and logits and loss are over it).
 
-``MHA`` / :class:`GroupedAttention` route through the Pallas or chunked
-flash attention on one chip (ops/attention.attention_route); the 2017
-blocks also through ring/Ulysses sequence parallelism on an ``sp`` mesh.
+``MHA`` / :class:`GroupedAttention` call ops/attention.attend, whose one
+rule (``attention_route``) names the route from the mesh, the backend and
+the dropout rate; an ``sp`` mesh (ring/Ulysses sequence parallelism) is
+for the 2017 blocks' dense masks only.
 The loss rides ``readout_xent``, so the per-device logits-bytes routing
 between materializing and blocked online-softmax xent
 (transformer.blocked_xent_enabled) applies to both kinds of stack.
@@ -60,6 +61,7 @@ from metaopt_tpu.models.transformer import (
     readout_xent,
     sharded_init,
 )
+from metaopt_tpu.ops.attention import CausalMask, attend
 from metaopt_tpu.parallel.sharding import with_mesh_partitioning
 from metaopt_tpu.utils import trace
 
@@ -110,12 +112,6 @@ class GroupedAttention(nn.Module):
     @nn.compact
     @trace.scope("attention")
     def __call__(self, x):
-        from metaopt_tpu.ops.attention import (
-            CausalMask, _reference_attention, attention_route,
-            flash_attention, sharded_flash_attention,
-        )
-        from metaopt_tpu.parallel.mesh import active_mesh
-
         proj = lambda name, heads: nn.DenseGeneral(  # noqa: E731
             (heads, self.head_dim), axis=-1, dtype=jnp.bfloat16, name=name,
             use_bias=False, kernel_init=_pinit(True, (None, "tp", None)))
@@ -126,18 +122,7 @@ class GroupedAttention(nn.Module):
             q, k = rope(q, self.rope_theta), rope(k, self.rope_theta)
         q = (q / math.sqrt(self.head_dim)).astype(jnp.bfloat16)
         k = k.astype(jnp.bfloat16)
-        mask = CausalMask(self.window)
-        mesh = active_mesh()
-        if mesh is not None and dict(mesh.shape).get("sp", 1) > 1:
-            raise ValueError("the pattern's attention has no sequence-"
-                             "parallel route: drop sp from the trial mesh")
-        impl = attention_route(0.0)
-        if impl is None:
-            out = _reference_attention(q, k, v, mask)
-        elif mesh is not None and getattr(mesh, "size", 1) > 1:
-            out = sharded_flash_attention(mesh, q, k, v, mask, impl=impl)
-        else:
-            out = flash_attention(q, k, v, mask, impl=impl)
+        out = attend(q, k, v, CausalMask(self.window))
         return nn.DenseGeneral(
             self.d_model, axis=(-2, -1), dtype=jnp.bfloat16, name="out",
             use_bias=False, kernel_init=_pinit(True, ("tp", None, None)),

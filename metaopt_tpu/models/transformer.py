@@ -27,6 +27,7 @@ from flax import linen as nn
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from metaopt_tpu.models.data import synthetic_seq2seq
+from metaopt_tpu.ops.attention import attend, attention_route
 from metaopt_tpu.parallel.sharding import shard_batch, with_mesh_partitioning
 from metaopt_tpu.utils import trace
 
@@ -60,14 +61,6 @@ class MHA(nn.Module):
         q = dense("q")(q_in) / np.sqrt(d_head)
         k = dense("k")(kv_in)
         v = dense("v")(kv_in)
-        from metaopt_tpu.ops.attention import (
-            _reference_attention,
-            attention_route,
-            flash_attention,
-            sharded_flash_attention,
-        )
-        from metaopt_tpu.parallel.mesh import active_mesh
-
         # masks here are (b, 1, q|1, k) with heads shared — flatten to the
         # kernel's (b, q, k) convention
         m3 = None
@@ -77,59 +70,11 @@ class MHA(nn.Module):
             )
         rate = self.dropout if train else 0.0
         key = self.make_rng("dropout") if rate > 0.0 else None
-        out_proj = nn.DenseGeneral(
+        out = attend(q, k, v, m3, dropout_rate=rate, dropout_key=key)
+        return nn.DenseGeneral(
             self.d_model, axis=(-2, -1), dtype=jnp.bfloat16, name="out",
             kernel_init=_pinit(self.partitioned, ("tp", None, None)),
-        )
-
-        mesh = active_mesh()
-        if mesh is not None and dict(mesh.shape).get("sp", 1) > 1:
-            sp = mesh.shape["sp"]
-            if q.shape[1] % sp or k.shape[1] % sp:
-                # never silently fall back to sp-replicated attention: the
-                # user asked for sequence sharding, and the fallback would
-                # quietly pay the full O(S²) memory on every chip
-                raise ValueError(
-                    f"seq lengths (q={q.shape[1]}, kv={k.shape[1]}) must be "
-                    f"multiples of the sp mesh axis ({sp}); pad the batch "
-                    f"or drop sp from the trial mesh"
-                )
-            # sequence-parallel mesh: the long-context path. Default =
-            # ring attention (K/V ride the ICI ring, lowest per-chip
-            # memory); METAOPT_TPU_SP_IMPL=ulysses selects the all-to-all
-            # head/sequence exchange instead (fewer collectives, needs
-            # per-device heads % sp == 0)
-            from metaopt_tpu.ops.ulysses import sp_impl, ulysses_attention
-
-            if sp_impl() == "ulysses":
-                return out_proj(ulysses_attention(
-                    q, k, v, m3, mesh=mesh,
-                    dropout_rate=rate, dropout_key=key,
-                ))
-            from metaopt_tpu.ops.ring_attention import ring_attention
-
-            return out_proj(ring_attention(
-                q, k, v, m3, mesh=mesh,
-                dropout_rate=rate, dropout_key=key,
-            ))
-
-        impl = attention_route(rate)
-        if impl is None:
-            out = _reference_attention(q, k, v, m3, rate, key)
-        else:
-            if mesh is not None and getattr(mesh, "size", 1) > 1:
-                # batch on dp, heads on tp: keeps the Megatron head split
-                # local to each shard instead of GSPMD all-gathering q/k/v
-                out = sharded_flash_attention(
-                    mesh, q, k, v, m3,
-                    dropout_rate=rate, dropout_key=key, impl=impl,
-                )
-            else:
-                out = flash_attention(
-                    q, k, v, m3,
-                    dropout_rate=rate, dropout_key=key, impl=impl,
-                )
-        return out_proj(out)
+        )(out)
 
 
 class FeedForward(nn.Module):
@@ -327,7 +272,7 @@ def make_model(hparams: Optional[Dict[str, Any]] = None, **overrides) -> Transfo
 
 #: materialized f32 (B, T, V) logits size above which loss_fn switches to
 #: the blocked xent. Below it the plain optax path is simpler AND faster:
-#: measured on the v5e (bench 2026-08-01, vocab 32000) the 2.1 GB flagship
+#: measured on the v5e (2026-08-01, vocab 32000) the 2.1 GB flagship
 #: tensor fits HBM comfortably and materializing beats blocked 58.5 vs
 #: 65.3 ms/step at seq256 (parity at seq512) — the blocked path only pays
 #: for itself once the tensor genuinely threatens HBM capacity
@@ -341,16 +286,14 @@ def blocked_xent_enabled(
 
     Gates on the PER-DEVICE materialized f32 logits size: on a parallel
     mesh the batch dims are sharded over dp/sp, so HBM pressure is
-    ``global_bytes / batch_shards``, not the global tensor. bench.py labels
-    its records with this same predicate — keep them in sync by calling it,
-    not copying it.
+    ``global_bytes / batch_shards``, not the global tensor.
 
     Routing: ``shards`` is the number of ways the (B, T) batch dims are
     split. With the default ``shards=None`` the predicate reads the
     ambient mesh (``active_mesh()``): inside a ``with mesh:`` scope it
     divides by ``dp * sp``; outside any mesh it treats the tensor as
     unsharded. Callers deciding routing FOR a mesh they have not entered
-    yet (launchers, planners, bench labeling a future run) pass the shard
+    yet (launchers, planners) pass the shard
     count explicitly — the ambient lookup would silently read whatever
     mesh the caller happens to be inside, or none.
     """
@@ -501,13 +444,12 @@ def trial_setup(hparams: Dict[str, Any], mesh: Optional[Mesh],
     mesh/scheduler behavior cannot drift between families.
 
     Its span says which attention route the trial's steps take
-    (``attrs["attention"]``): the dropout rate decides between the Pallas
-    kernels and the chunked twin (ops/attention.attention_route).
-    ``describe``, a harness's own, is asked what else the span should say,
-    given the route without dropout (lm.py: each kind of layer's route and
-    mask form, what the expert layers hold and run their products with).
+    (``attrs["attention"]``), for the training and the evaluation rate, as
+    ops/attention.attention_route names it on this mesh. ``describe``, a
+    harness's own, is asked what else the span should say, given the route
+    without dropout (lm.py: each kind of layer's route and mask form, what
+    the expert layers hold and run their products with).
     """
-    from metaopt_tpu.ops.attention import attention_route
     from metaopt_tpu.parallel.mesh import trial_mesh
 
     # the span holds the first jax.devices() of a trial, as a rule
@@ -519,17 +461,12 @@ def trial_setup(hparams: Dict[str, Any], mesh: Optional[Mesh],
             extra.append(("ep", ep))
         mesh = mesh or trial_mesh(tp=tp, extra_axes=tuple(extra))
         dropout = float(hparams.get("dropout", 0.1))
-        if sp > 1:  # MHA's sequence-parallel branch comes first
-            from metaopt_tpu.ops.ulysses import sp_impl
-
-            route = lambda rate: sp_impl()  # noqa: E731
-        else:
-            route = lambda rate: attention_route(rate) or "reference"  # noqa: E731
+        evaluation = attention_route(0.0, mesh)
         setup["attrs"]["attention"] = {
-            "dropout": dropout, "train": route(dropout), "eval": route(0.0)}
+            "dropout": dropout, "train": attention_route(dropout, mesh),
+            "eval": evaluation}
         if describe is not None:
-            setup["attrs"].update(
-                describe(attention_route(0.0) or "reference"))
+            setup["attrs"].update(describe(evaluation))
     lr = float(hparams.get("lr", 1e-3))
     warmup = int(hparams.get("warmup", 10))
     sched = optax.warmup_cosine_decay_schedule(

@@ -51,15 +51,18 @@ tails; block sizes follow the shapes (``_derived_block`` for the kernels,
 exceed what it names (``_block_and_pad``).
 
 ``MHA`` in metaopt_tpu.models.transformer and ``GroupedAttention`` in
-metaopt_tpu.models.lm route here by default on TPU backends: a call
-without dropout (every evaluation step, every training step at dropout 0)
-takes the Pallas kernels, a call with dropout the chunked twin
-(:func:`attention_route`; :func:`attention_impl` has the
-``METAOPT_TPU_FLASH`` table). On a trial mesh the call is wrapped in
-``shard_map`` (batch on "dp", heads on "tp") via
-:func:`sharded_flash_attention` — attention is embarrassingly
+metaopt_tpu.models.lm project, scale, build their mask and call
+:func:`attend`, the one door; :func:`attention_route` is the one rule, from
+what the call can see: an ``sp`` mesh axis takes ring attention (or
+Ulysses), a backend other than the TPU the plain reference, a call with
+dropout the chunked twin, and every other call (every evaluation step,
+every training step at dropout 0) the Pallas kernels. On a trial mesh the
+kernels' call is wrapped in ``shard_map`` (batch on "dp", heads on "tp")
+via :func:`sharded_flash_attention` — attention is embarrassingly
 parallel over (batch, head), so each shard runs the kernel locally and the
 Megatron head split survives instead of GSPMD all-gathering q/k/v.
+:func:`flash_attention` is the kernels' own entry and takes ``impl`` as
+its caller states it.
 
 Not here: per-row key lengths under a ``CausalMask`` (a padded row still
 needs the dense form), segment ids and packing.
@@ -70,7 +73,6 @@ from __future__ import annotations
 import dataclasses
 import functools
 import math
-import os
 from typing import Optional
 
 import jax
@@ -78,6 +80,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from metaopt_tpu.parallel.mesh import active_mesh
 from metaopt_tpu.utils import trace
 
 _NEG_BIG = -1e30
@@ -848,8 +851,8 @@ def _flash_bwd_rule(dropout_rate, block_q, block_k, impl, interpret,
                     residuals, g):
     q, k, v, mask, key, out, lse = residuals
     if impl == "pallas" and dropout_rate == 0.0:
-        # the pallas forward never carries dropout (flash_attention routes
-        # dropout to chunked), so the pallas backward needs no mask replay
+        # the pallas forward never carries dropout (flash_attention raises
+        # on it), so the pallas backward needs no mask replay
         dq, dk, dv = _pallas_backward(
             q, k, v, mask, out, lse, g, block_q, block_k, interpret
         )
@@ -916,7 +919,7 @@ def flash_attention(
     dropout_key: Optional[jnp.ndarray] = None,
     block_q: Optional[int] = None,
     block_k: Optional[int] = None,
-    impl: Optional[str] = None,
+    impl: str = "pallas",
     interpret: bool = False,
 ) -> jnp.ndarray:
     """Blocked online-softmax attention with a blockwise backward.
@@ -935,8 +938,6 @@ def flash_attention(
     off the chip). It is never chosen for the caller: ``impl="pallas"``
     compiles through Mosaic or raises with the compiler's message.
     """
-    if impl is None:
-        impl = "chunked" if dropout_rate > 0.0 else "pallas"
     if dropout_rate > 0.0 and impl == "pallas":
         raise ValueError("attention dropout requires impl='chunked'")
     if dropout_rate > 0.0 and dropout_key is None:
@@ -1005,7 +1006,7 @@ def sharded_flash_attention(
     *,
     dropout_rate: float = 0.0,
     dropout_key=None,
-    impl: Optional[str] = None,
+    impl: str = "pallas",
     batch_axis: str = "dp",
     head_axis: str = "tp",
     **kwargs,
@@ -1049,49 +1050,73 @@ def sharded_flash_attention(
     return wrapped(q, k, v, mask, dropout_key)
 
 
-def attention_impl() -> Optional[str]:
-    """Which implementation MHA routes through, from ``METAOPT_TPU_FLASH``.
+def attention_route(dropout_rate: float, mesh=None) -> str:
+    """The route a call takes, from what the call can see: the one place
+    that decides (:func:`attend` and ``trial.setup``'s span both ask).
 
-    - unset → backend default: **``pallas`` on TPU**, where the kernels
-      keep every score tile in VMEM (PERF.md has the per-call device times
-      against the chunked twin at seq 256, 512 and 1024), ``None`` (plain
-      XLA reference) on CPU, where the O(S²) path is faster at test shapes
-      and numerically the oracle.
-    - ``0``/``off`` → ``None``: force the plain XLA reference attention.
-    - ``1``/``pallas`` → the Pallas kernels, compiled through Mosaic (TPU
-      only; they raise elsewhere).
-    - ``chunked``/``scan`` → force the lax.scan twin on any backend.
-
-    Whatever this answers, a call WITH attention dropout takes the chunked
-    twin (:func:`attention_route`): the kernels carry no dropout RNG.
+    An ``sp`` axis larger than 1 in ``mesh`` -> ``"ring"``, or ``"ulysses"``
+    where ``METAOPT_TPU_SP_IMPL`` says so (ops/ulysses.sp_impl: neither has
+    a chip record yet, ROADMAP D2); a backend other than the TPU ->
+    ``"reference"``, the plain XLA attention, faster at test shapes and
+    numerically the oracle; dropout -> ``"chunked"``, whose masks replay
+    bit-exactly in the backward (the kernels carry no dropout RNG); else
+    ``"pallas"``, the kernels that keep every score tile in VMEM (PERF.md
+    has their device times against the chunked twin).
     """
-    env = (os.environ.get("METAOPT_TPU_FLASH") or "").strip().lower()
-    if env in ("", None):
-        return "pallas" if jax.default_backend() == "tpu" else None
-    if env in ("0", "false", "no", "off"):
-        return None
-    if env in ("chunked", "scan", "2"):
-        return "chunked"
-    if env in ("1", "true", "yes", "on", "pallas"):
-        return "pallas"
-    # a typo must not silently select another path
-    raise ValueError(
-        f"METAOPT_TPU_FLASH={env!r}: expected off/pallas/chunked"
-    )
+    if mesh is not None and dict(mesh.shape).get("sp", 1) > 1:
+        from metaopt_tpu.ops.ulysses import sp_impl
+
+        return sp_impl()
+    if jax.default_backend() != "tpu":
+        return "reference"
+    return "chunked" if dropout_rate > 0.0 else "pallas"
 
 
-def attention_route(dropout_rate: float) -> Optional[str]:
-    """The implementation a call with this dropout rate takes.
+def attend(q, k, v, mask=None, *, dropout_rate: float = 0.0,
+           dropout_key=None):
+    """The models' one door into attention: what :func:`attention_route`
+    names for this call under the ambient mesh. Operands and mask as
+    :func:`flash_attention` takes them.
 
-    The one rule the program follows is in its input: no dropout (every
-    evaluation step, every training step at dropout 0) → what
-    :func:`attention_impl` answers; dropout → never Pallas but the chunked
-    twin, whose masks replay bit-exactly in the backward.
+    On a trial mesh of more than one device the kernels run under
+    ``shard_map`` (:func:`sharded_flash_attention`: batch on dp, heads on
+    tp, so the Megatron head split stays local to each shard instead of
+    GSPMD all-gathering q/k/v); the plain reference never does.
     """
-    impl = attention_impl()
-    return "chunked" if impl == "pallas" and dropout_rate > 0.0 else impl
-
-
-def use_flash_attention() -> bool:
-    """Back-compat boolean view of :func:`attention_impl`."""
-    return attention_impl() is not None
+    mesh = active_mesh()
+    route = attention_route(dropout_rate, mesh)
+    if route in ("ring", "ulysses"):
+        # sequence-parallel mesh, the long-context path: K/V ride the ICI
+        # ring (lowest per-chip memory), or Ulysses' all-to-all exchange of
+        # heads for sequence (fewer collectives, needs per-device heads %
+        # sp == 0). Those modules import this one.
+        if isinstance(mask, CausalMask):
+            raise ValueError("the pattern's attention has no sequence-"
+                             "parallel route: drop sp from the trial mesh")
+        sp = mesh.shape["sp"]
+        if q.shape[1] % sp or k.shape[1] % sp:
+            # never silently fall back to sp-replicated attention: the
+            # user asked for sequence sharding, and the fallback would
+            # quietly pay the full O(S²) memory on every chip
+            raise ValueError(
+                f"seq lengths (q={q.shape[1]}, kv={k.shape[1]}) must be "
+                f"multiples of the sp mesh axis ({sp}); pad the batch "
+                f"or drop sp from the trial mesh"
+            )
+        if route == "ulysses":
+            from metaopt_tpu.ops.ulysses import (
+                ulysses_attention as sequence_parallel)
+        else:
+            from metaopt_tpu.ops.ring_attention import (
+                ring_attention as sequence_parallel)
+        return sequence_parallel(q, k, v, mask, mesh=mesh,
+                                 dropout_rate=dropout_rate,
+                                 dropout_key=dropout_key)
+    if route == "reference":
+        return _reference_attention(q, k, v, mask, dropout_rate, dropout_key)
+    if mesh is not None and mesh.size > 1:
+        return sharded_flash_attention(
+            mesh, q, k, v, mask, dropout_rate=dropout_rate,
+            dropout_key=dropout_key, impl=route)
+    return flash_attention(q, k, v, mask, dropout_rate=dropout_rate,
+                           dropout_key=dropout_key, impl=route)

@@ -15,9 +15,10 @@ Trade-off vs the ring, honestly stated: the ring's peak activation memory
 is O(S/sp · d) (never the full sequence) and it pipelines transfers with
 compute — better for the longest contexts; Ulysses has lower collective
 count and latency at moderate lengths and maps onto XLA's native
-``all_to_all``. Both compose with dp/tp in one ``shard_map``. The demo
-Transformer picks via ``METAOPT_TPU_SP_IMPL`` (``ring`` default,
-``ulysses`` opt-in) — see :func:`sp_impl`.
+``all_to_all``. Both compose with dp/tp in one ``shard_map``. Neither has
+been timed on a chip, so which one an ``sp`` mesh takes is still a
+variable, ``METAOPT_TPU_SP_IMPL`` (``ring`` default, ``ulysses`` opt-in;
+:func:`sp_impl`), until a four-chip cell decides (ROADMAP D2).
 
 ref: the reference framework has no attention code at all (SURVEY.md §5
 long-context: "absent by design"); TPU-native demo-zoo surface.
@@ -36,7 +37,8 @@ from metaopt_tpu.ops.attention import flash_attention, shard_map_nocheck
 
 
 def sp_impl() -> str:
-    """Which sequence-parallel attention MHA uses when the mesh has sp>1.
+    """Which sequence-parallel attention a mesh with sp>1 takes; asked by
+    ops/attention.attention_route alone.
 
     ``METAOPT_TPU_SP_IMPL``: ``ring`` (default — lowest per-chip memory,
     transfers overlap compute) or ``ulysses`` (2 all-to-alls, needs
@@ -60,7 +62,7 @@ def ulysses_attention(
     head_axis: Optional[str] = "tp",
     dropout_rate: float = 0.0,
     dropout_key: Optional[jnp.ndarray] = None,
-    impl: Optional[str] = "chunked",
+    impl: str = "chunked",
 ) -> jnp.ndarray:
     """Sequence-parallel attention via head/sequence all-to-all exchange.
 

@@ -17,7 +17,8 @@ per block instead of a (B, T, V) round-trip through HBM.
 FLOP cost: 2·N·D·V forward + 6·N·D·V backward (one logits recompute, dY,
 dEmb) vs 2+4 for the materializing path — 33% more readout FLOPs traded
 for never touching a (N, V) f32 tensor in HBM. On bandwidth-bound shapes
-that trade wins by construction; bench.py measures it (mfu_seq256).
+that trade wins by construction; where it starts to pay is
+``models/transformer.blocked_xent_enabled``'s question.
 """
 
 from __future__ import annotations

@@ -1,7 +1,8 @@
 """Child processes under a deadline, the compile-cache rule, the device probe.
 
 No jax import at module level: launchers that must stay off the chip
-(the hunt parent, ``chip_smoke.py``, ``bench.py``) import this freely.
+(the hunt parent, ``chip_smoke.py``, ``benchmarks/run.py``) import this
+freely.
 
 A TPU chip belongs to one process at a time, so a launcher asks a
 short-lived child what devices there are (:func:`probe_devices`) and

@@ -2,8 +2,8 @@
 
 Every perf row must self-describe (commit, timestamp, backend) — the round-4
 judge had to `git log -p` to learn that two coord rows 100× apart straddled
-an optimization commit. One helper, used by bench.py and every
-benchmarks/*.py emitter, so the stamp format can never drift between them.
+an optimization commit. One helper, used by every benchmarks/*.py
+emitter, so the stamp format can never drift between them.
 """
 
 from __future__ import annotations

@@ -343,7 +343,8 @@ def main(argv: List[str]) -> int:
 
 def print_routes(recs: List[dict]) -> None:
     """A line a trial: which attention route its steps took, as its
-    ``trial.setup`` span has it (ops/attention.attention_route); for a
+    ``trial.setup`` span has it (ops/attention.attention_route, the one
+    rule, asked for the training and the evaluation rate); for a
     model with a layer pattern a line each kind of layer, what the expert
     layers hold, and (from ``trial.train``) what they counted."""
     held = {}  # trial -> its setup's attrs["moe"]

@@ -16,11 +16,10 @@ from metaopt_tpu.ops.attention import (
     _derived_block,
     _pallas_forward,
     _reference_attention,
-    attention_impl,
+    attend,
     attention_route,
     flash_attention,
     sharded_flash_attention,
-    use_flash_attention,
 )
 
 
@@ -345,36 +344,114 @@ class TestSharded:
         assert np.all(np.isfinite(np.asarray(out, np.float32)))
 
 
+_DP_TP = (("dp", 2), ("tp", 4))
+_SP = (("dp", 2), ("sp", 2), ("tp", 2))
+
+
 class TestRouting:
-    def test_env_override(self, monkeypatch):
-        monkeypatch.setenv("METAOPT_TPU_FLASH", "1")
-        assert use_flash_attention()
-        assert attention_impl() == "pallas"
-        monkeypatch.setenv("METAOPT_TPU_FLASH", "chunked")
-        assert attention_impl() == "chunked"
-        monkeypatch.setenv("METAOPT_TPU_FLASH", "0")
-        assert not use_flash_attention()
-        assert attention_impl() is None
+    """The one rule (``attention_route``) and the one door (``attend``)."""
+
+    # (backend, mesh axes, METAOPT_TPU_SP_IMPL, dropout, mask form) ->
+    # route, and what ``attend`` then runs: the function (the kernels'
+    # entry is told the route as its ``impl``) on a shard's (batch, heads)
+    # of the call's (4, 4)
+    @pytest.mark.parametrize(
+        "backend, axes, sp_var, dropout, structural, route, runs, shard", [
+            ("cpu", None, None, 0.0, False, "reference",
+             "_reference_attention", (4, 4)),
+            ("cpu", None, None, 0.1, False, "reference",
+             "_reference_attention", (4, 4)),
+            ("cpu", None, None, 0.0, True, "reference",
+             "_reference_attention", (4, 4)),
+            # never under shard_map: GSPMD shards the plain path
+            ("cpu", _DP_TP, None, 0.1, False, "reference",
+             "_reference_attention", (4, 4)),
+            ("tpu", None, None, 0.0, False, "pallas",
+             "flash_attention", (4, 4)),
+            ("tpu", None, None, 0.1, False, "chunked",
+             "flash_attention", (4, 4)),
+            ("tpu", None, None, 0.0, True, "pallas",
+             "flash_attention", (4, 4)),
+            # under shard_map: batch on dp, heads on tp
+            ("tpu", _DP_TP, None, 0.0, False, "pallas",
+             "flash_attention", (2, 1)),
+            ("tpu", _DP_TP, None, 0.0, True, "pallas",
+             "flash_attention", (2, 1)),
+            ("tpu", _DP_TP, None, 0.1, False, "chunked",
+             "flash_attention", (2, 1)),
+            # an sp axis comes first, whatever the backend and the rate
+            ("tpu", _SP, None, 0.0, False, "ring", "ring_attention", (4, 4)),
+            ("cpu", _SP, None, 0.1, False, "ring", "ring_attention", (4, 4)),
+            ("tpu", _SP, "ulysses", 0.1, False, "ulysses",
+             "ulysses_attention", (4, 4)),
+        ])
+    def test_the_door_runs_what_the_rule_names(
+            self, monkeypatch, backend, axes, sp_var, dropout, structural,
+            route, runs, shard):
+        import contextlib
+
+        from metaopt_tpu.ops import attention, ring_attention, ulysses
+        from metaopt_tpu.parallel.mesh import make_mesh, use_mesh
+
+        monkeypatch.setattr(jax, "default_backend", lambda: backend)
+        if sp_var is None:
+            monkeypatch.delenv("METAOPT_TPU_SP_IMPL", raising=False)
+        else:
+            monkeypatch.setenv("METAOPT_TPU_SP_IMPL", sp_var)
+        ran = []
+
+        def recorder(name):
+            # the routes' own functions are not run: Mosaic cannot compile
+            # here, and each has its tests
+            def record(q, k, v, mask=None, *a, dropout_rate=0.0, impl=None,
+                       **kw):
+                if a:  # _reference_attention takes the rate by position
+                    dropout_rate = a[0]
+                ran.append((name, impl, q.shape[0::2], dropout_rate,
+                            isinstance(mask, attention.CausalMask)))
+                return jnp.zeros_like(q)
+            return record
+
+        for module, name in ((attention, "_reference_attention"),
+                             (attention, "flash_attention"),
+                             (ring_attention, "ring_attention"),
+                             (ulysses, "ulysses_attention")):
+            monkeypatch.setattr(module, name, recorder(name))
+        mesh = make_mesh(list(axes)) if axes else None
+        assert attention_route(dropout, mesh) == route
+        q, k, v = rand_qkv(jax.random.PRNGKey(50), b=4, sq=16, sk=16, h=4)
+        mask = (attention.CausalMask(8) if structural
+                else jnp.ones((4, 16, 16), bool))
+        with use_mesh(mesh) if mesh else contextlib.nullcontext():
+            out = attend(q, k, v, mask, dropout_rate=dropout,
+                         dropout_key=jax.random.PRNGKey(51))
+        assert out.shape == q.shape
+        impl = route if runs == "flash_attention" else None
+        assert ran == [(runs, impl, shard, dropout, structural)]
+
+    def test_a_causal_mask_on_an_sp_mesh_raises(self):
+        from metaopt_tpu.ops.attention import CausalMask
+        from metaopt_tpu.parallel.mesh import make_mesh, use_mesh
+
+        q, k, v = rand_qkv(jax.random.PRNGKey(52), b=2, sq=16, sk=16)
+        with use_mesh(make_mesh(list(_SP))), pytest.raises(
+                ValueError, match="no sequence-parallel route"):
+            attend(q, k, v, CausalMask())
 
     def test_tpu_default_is_pallas_and_dropout_takes_chunked(self, monkeypatch):
-        """The rule is in the call's input: no dropout -> the kernels,
-        dropout -> the chunked twin, whatever the backend default says."""
-        monkeypatch.delenv("METAOPT_TPU_FLASH", raising=False)
-        assert attention_impl() is None  # this process runs on the CPU
+        """The rule is in what the call can see: off the TPU the plain
+        reference; on it no dropout -> the kernels, dropout -> the chunked
+        twin."""
+        # this process runs on the CPU
+        assert attention_route(0.0) == attention_route(0.1) == "reference"
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-        assert attention_impl() == "pallas"
         assert attention_route(0.0) == "pallas"
         assert attention_route(0.1) == "chunked"
-        monkeypatch.setenv("METAOPT_TPU_FLASH", "chunked")
-        assert attention_route(0.0) == attention_route(0.1) == "chunked"
-        monkeypatch.setenv("METAOPT_TPU_FLASH", "off")
-        assert attention_route(0.0) is None and attention_route(0.1) is None
 
     def test_mha_resolves_the_route_from_its_dropout(self, monkeypatch):
         from metaopt_tpu.models.transformer import MHA
         from metaopt_tpu.ops import attention
 
-        monkeypatch.delenv("METAOPT_TPU_FLASH", raising=False)
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         taken = []
 
@@ -397,15 +474,8 @@ class TestRouting:
         """The full demo Transformer runs with the kernel routed in."""
         import functools
 
-        from metaopt_tpu.ops import attention
-
-        monkeypatch.setenv("METAOPT_TPU_FLASH", "1")
-        # off the chip the Pallas kernel runs only when a caller asks for
-        # the interpreter; the model path never asks
-        monkeypatch.setattr(
-            attention, "flash_attention",
-            functools.partial(attention.flash_attention, interpret=True))
         from metaopt_tpu.models.transformer import make_model
+        from metaopt_tpu.ops import attention
 
         model = make_model(
             {"d_model": 32, "n_heads": 2, "n_layers": 1, "d_ff": 64,
@@ -414,8 +484,16 @@ class TestRouting:
         src = jnp.ones((2, 16), jnp.int32)
         tgt = jnp.ones((2, 16), jnp.int32)
         params = model.init(jax.random.PRNGKey(0), src, tgt, train=False)
-        out_flash = model.apply(params, src, tgt, train=False)
-        monkeypatch.setenv("METAOPT_TPU_FLASH", "0")
+        with monkeypatch.context() as on_tpu:
+            on_tpu.setattr(jax, "default_backend", lambda: "tpu")
+            # off the chip the Pallas kernel runs only when a caller asks
+            # for the interpreter; the model path never asks
+            on_tpu.setattr(
+                attention, "flash_attention",
+                functools.partial(attention.flash_attention, interpret=True))
+            assert attention_route(0.0) == "pallas"
+            out_flash = model.apply(params, src, tgt, train=False)
+        assert attention_route(0.0) == "reference"
         out_plain = model.apply(params, src, tgt, train=False)
         np.testing.assert_allclose(
             np.asarray(out_flash, np.float32),

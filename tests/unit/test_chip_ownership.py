@@ -351,31 +351,6 @@ def test_chip_smoke_alone_in_a_directory_fails(tmp_path):
     assert proc.stdout == ""
 
 
-def test_bench_without_a_tpu_prints_no_number(monkeypatch, capsys):
-    sys.path.insert(0, REPO)
-    import bench
-
-    monkeypatch.setattr(bench, "use_xla_cache", lambda: None)
-    for _ in _each_way_of_having_no_tpu(monkeypatch):
-        assert bench.main() == 1
-        captured = capsys.readouterr()
-        assert captured.out == ""
-        assert "no TPU" in captured.err
-
-
-def test_peak_flops_raises_on_an_unknown_device_kind():
-    sys.path.insert(0, REPO)
-    import bench
-
-    class Dev:
-        device_kind = "TPU v5 lite"
-
-    assert bench.peak_flops(Dev()) == 197e12
-    Dev.device_kind = "cpu"
-    with pytest.raises(ValueError, match="no peak FLOP/s known"):
-        bench.peak_flops(Dev())
-
-
 def test_run_py_backend_tpu_without_a_chip_is_an_error(monkeypatch, capsys):
     sys.path.insert(0, os.path.join(REPO, "benchmarks"))
     import run

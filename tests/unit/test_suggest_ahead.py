@@ -133,7 +133,7 @@ class TestMixinHygiene:
         assert any(a is tpe for a in algo_base._live_instances)
 
     def test_refill_thread_attr_name_preserved(self):
-        # bench.py and the TPE tests join `_refill_thread` by name
+        # the TPE tests join `_refill_thread` by name
         space = make_space()
         tpe = TPE(space, seed=9, n_initial_points=3)
         for i in range(4):

@@ -180,12 +180,14 @@ def lowered_op_names(request):
     from metaopt_tpu.models.transformer import (
         init_sharded, make_model, make_train_step, trial_setup,
     )
+    from metaopt_tpu.ops import attention
     from metaopt_tpu.parallel.mesh import use_mesh
 
-    old = os.environ.get("METAOPT_TPU_FLASH")
-    os.environ["METAOPT_TPU_FLASH"] = (
-        "chunked" if request.param == "chunked" else "off")
-    try:
+    with pytest.MonkeyPatch.context() as patch:
+        # the chunked twin at dropout 0, as no backend routes it: the step
+        # stays the one the reference case lowers, but for attention
+        patch.setattr(attention, "attention_route",
+                      lambda rate, mesh=None: request.param)
         hp = dict(d_model=64, n_layers=1, d_ff=128, n_heads=1, vocab=512,
                   max_len=32, dropout=0.0)
         one = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("dp", "tp"))
@@ -197,11 +199,6 @@ def lowered_op_names(request):
             text = jax.jit(make_train_step(model, tx)).lower(
                 params, opt_state, (rows, rows), jax.random.PRNGKey(0),
             ).as_text(debug_info=True)
-    finally:
-        if old is None:
-            del os.environ["METAOPT_TPU_FLASH"]
-        else:
-            os.environ["METAOPT_TPU_FLASH"] = old
     return set(re.findall(r'loc\("([^"]+)"', text))
 
 
@@ -292,27 +289,25 @@ def test_the_optimizer_s_ops_are_not_under_a_model_scope(lowered_op_names):
 # -- the trial ----------------------------------------------------------------
 
 
-@pytest.mark.parametrize("backend, flash, dropout, train, evaluation", [
-    ("tpu", None, 0.0, "pallas", "pallas"),
-    ("tpu", None, 0.1, "chunked", "pallas"),
-    ("tpu", "chunked", 0.0, "chunked", "chunked"),
-    ("cpu", None, 0.1, "reference", "reference"),
+@pytest.mark.parametrize("backend, sp, dropout, train, evaluation", [
+    ("tpu", 1, 0.0, "pallas", "pallas"),
+    ("tpu", 1, 0.1, "chunked", "pallas"),
+    ("cpu", 1, 0.1, "reference", "reference"),
+    ("tpu", 2, 0.1, "ring", "ring"),
 ])
 def test_trial_setup_s_span_says_which_attention_route_the_steps_take(
-        monkeypatch, capsys, backend, flash, dropout, train, evaluation):
+        monkeypatch, capsys, backend, sp, dropout, train, evaluation):
     import jax
     import numpy as np
     from jax.sharding import Mesh
 
     from metaopt_tpu.models.transformer import trial_setup
 
-    one = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("dp", "tp"))
+    mesh = Mesh(np.array(jax.devices()[:sp]).reshape(1, sp, 1),
+                ("dp", "sp", "tp"))
     monkeypatch.setattr(jax, "default_backend", lambda: backend)
-    if flash is None:
-        monkeypatch.delenv("METAOPT_TPU_FLASH", raising=False)
-    else:
-        monkeypatch.setenv("METAOPT_TPU_FLASH", flash)
-    trial_setup({"dropout": dropout}, one, 1, 1, 1, 100)
+    monkeypatch.delenv("METAOPT_TPU_SP_IMPL", raising=False)
+    trial_setup({"dropout": dropout}, mesh, 1, sp, 1, 100)
     setup = trace.spans("trial.setup")[-1]
     assert setup["attrs"]["attention"] == {
         "dropout": dropout, "train": train, "eval": evaluation}
