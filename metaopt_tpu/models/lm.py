@@ -59,6 +59,7 @@ from metaopt_tpu.models.transformer import (
     blocked_xent_enabled,
     masked_mean_with_aux,
     readout_xent,
+    rematerialised,
     sharded_init,
 )
 from metaopt_tpu.ops.attention import CausalMask, attend
@@ -224,7 +225,9 @@ class DecoderOnlyLM(nn.Module):
     n_experts: int = 0
     capacity_factor: float = 1.25
     router_top_k: int = 1
-    #: rematerialize each block in the backward pass (the HBM/FLOPs trade)
+    #: rematerialize each block in the backward pass (the HBM/FLOPs trade):
+    #: a block keeps its input and its attention kernel's ``out`` and
+    #: ``lse`` (transformer.rematerialised), and makes the rest again
     remat: bool = False
     #: the layer pattern of a description that has one (module docstring)
     pattern: Optional[Pattern] = None
@@ -253,7 +256,7 @@ class DecoderOnlyLM(nn.Module):
         pad = (tokens != 0)[:, None, None, :]                     # (b,1,1,k)
         causal = jnp.tril(jnp.ones((t_len, t_len), bool))[None, None]
         mask = causal & pad
-        block_cls = (nn.remat(EncoderLayer, static_argnums=(3,))
+        block_cls = (rematerialised(EncoderLayer, static_argnums=(3,))
                      if self.remat else EncoderLayer)
         with trace.scope("embed"):
             x = emb(tokens) + pos[None, :t_len].astype(jnp.bfloat16)
@@ -287,7 +290,8 @@ class DecoderOnlyLM(nn.Module):
             rows, self.d_model, dtype=jnp.bfloat16, name=name,
             embedding_init=nn.with_partitioning(
                 nn.initializers.normal(size), (None, None)))
-        block_cls = nn.remat(PatternBlock) if self.remat else PatternBlock
+        block_cls = (rematerialised(PatternBlock) if self.remat
+                     else PatternBlock)
         with trace.scope("embed"):
             x = table("embed")(tokens - first).astype(jnp.float32)
         for i, (sliding, rotary) in enumerate(p.layers):
@@ -497,7 +501,8 @@ class LMTrial:
         self.mesh, tx = trial_setup(
             {**hparams, "dropout": self.model.dropout}, mesh, tp, sp, ep,
             steps, describe=functools.partial(
-                describe_pattern, hparams, tokens=batch_size * seq_len))
+                describe_pattern, hparams, tokens=batch_size * seq_len),
+            remat_blocks=self.model.n_layers if self.model.remat else 0)
         first, vocab = self.model.held_vocab()
         kd, self._kstep = jax.random.split(jax.random.PRNGKey(seed))
         self.tokens = first + synthetic_lm(kd, n_train, seq_len + 1, vocab)

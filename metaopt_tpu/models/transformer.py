@@ -27,7 +27,7 @@ from flax import linen as nn
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from metaopt_tpu.models.data import synthetic_seq2seq
-from metaopt_tpu.ops.attention import attend, attention_route
+from metaopt_tpu.ops.attention import REMAT_KEEPS, attend, attention_route
 from metaopt_tpu.parallel.sharding import shard_batch, with_mesh_partitioning
 from metaopt_tpu.utils import trace
 
@@ -97,6 +97,16 @@ class FeedForward(nn.Module):
         h = nn.relu(wi(x))
         h = nn.Dropout(self.dropout, deterministic=not train)(h)
         return wo(h)
+
+
+def rematerialised(block_cls, **kwargs):
+    """``block_cls`` run again in the backward pass, keeping its input and
+    what its attention kernel made (ops/attention.REMAT_KEEPS: ``out`` and
+    ``lse``, which only the kernel could make again). The one rule for
+    every ``remat`` site of the zoo's attention blocks."""
+    return nn.remat(
+        block_cls, **kwargs,
+        policy=jax.checkpoint_policies.save_only_these_names(*REMAT_KEEPS))
 
 
 def _make_mlp(d_model, d_ff, dropout, n_experts, capacity_factor=1.25,
@@ -180,7 +190,9 @@ class Transformer(nn.Module):
     router_top_k: int = 1
     #: rematerialize each layer in the backward pass: activation memory
     #: drops from O(layers) to O(1) layers, buying batch size (and with it
-    #: MFU) at ~1/3 extra FLOPs — the standard TPU HBM trade
+    #: MFU) at ~1/3 extra FLOPs — the standard TPU HBM trade. A layer
+    #: keeps its input and its attention kernels' ``out`` and ``lse``
+    #: (:func:`rematerialised`)
     remat: bool = False
 
     @nn.compact
@@ -213,9 +225,9 @@ class Transformer(nn.Module):
 
         # static_argnums pins `train` (python control flow inside);
         # counting includes self, so train sits at index 3 / 5
-        enc_cls = (nn.remat(EncoderLayer, static_argnums=(3,))
+        enc_cls = (rematerialised(EncoderLayer, static_argnums=(3,))
                    if self.remat else EncoderLayer)
-        dec_cls = (nn.remat(DecoderLayer, static_argnums=(5,))
+        dec_cls = (rematerialised(DecoderLayer, static_argnums=(5,))
                    if self.remat else DecoderLayer)
         with trace.scope("embed"):
             x = emb(src) + pos[None, :s_len].astype(jnp.bfloat16)
@@ -435,7 +447,8 @@ def sharded_init(init_fn, mesh: Mesh, seed: int = 0):
 
 
 def trial_setup(hparams: Dict[str, Any], mesh: Optional[Mesh],
-                tp: int, sp: int, ep: int, steps: int, describe=None):
+                tp: int, sp: int, ep: int, steps: int, describe=None,
+                remat_blocks: int = 0):
     """The shared trial-harness preamble: mesh assembly + optimizer.
 
     sp > 1 shards the sequence axis (ring attention over ICI); ep > 1
@@ -448,7 +461,9 @@ def trial_setup(hparams: Dict[str, Any], mesh: Optional[Mesh],
     ops/attention.attention_route names it on this mesh. ``describe``, a
     harness's own, is asked what else the span should say, given the route
     without dropout (lm.py: each kind of layer's route and mask form, what
-    the expert layers hold and run their products with).
+    the expert layers hold and run their products with). ``remat_blocks``,
+    the blocks the model runs again in the backward pass, puts what each
+    keeps besides its input into ``attrs["remat"]`` (absent at 0).
     """
     from metaopt_tpu.parallel.mesh import trial_mesh
 
@@ -467,6 +482,9 @@ def trial_setup(hparams: Dict[str, Any], mesh: Optional[Mesh],
             "eval": evaluation}
         if describe is not None:
             setup["attrs"].update(describe(evaluation))
+        if remat_blocks:
+            setup["attrs"]["remat"] = {"blocks": remat_blocks,
+                                       "keeps": list(REMAT_KEEPS)}
     lr = float(hparams.get("lr", 1e-3))
     warmup = int(hparams.get("warmup", 10))
     sched = optax.warmup_cosine_decay_schedule(
@@ -519,8 +537,10 @@ def train_and_eval(
     if n_train < batch_size:
         raise ValueError(
             f"n_train ({n_train}) must be >= batch_size ({batch_size})")
-    mesh, tx = trial_setup(hparams, mesh, tp, sp, ep, steps)
     model = make_model(hparams)
+    mesh, tx = trial_setup(
+        hparams, mesh, tp, sp, ep, steps,
+        remat_blocks=2 * model.n_layers if model.remat else 0)
 
     key = jax.random.PRNGKey(seed)
     kd, kstep = jax.random.split(key)

@@ -77,6 +77,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -666,9 +667,19 @@ def _flash_causal(q, k, v, window, block_q, block_k, interpret):
                              interpret)[0]
 
 
+def _kept(out, lse):
+    """The two residuals only the kernel can make, under the names a
+    rematerialised block keeps them by (:data:`REMAT_KEEPS`). Named inside
+    the forward RULE: a name on ``attend``'s return value would keep
+    ``out`` and still run the kernel again, for ``lse``."""
+    return tuple(checkpoint_name(x, name)
+                 for x, name in zip((out, lse), REMAT_KEEPS))
+
+
 @trace.scope("attention.core")
 def _flash_causal_fwd(q, k, v, window, block_q, block_k, interpret):
-    out, lse = _causal_forward(q, k, v, window, block_q, block_k, interpret)
+    out, lse = _kept(*_causal_forward(q, k, v, window, block_q, block_k,
+                                      interpret))
     return out, (q, k, v, out, lse)
 
 
@@ -843,6 +854,7 @@ def _flash_fwd_rule(q, k, v, mask, key, dropout_rate, block_q, block_k, impl,
         out, lse = _pallas_forward(q, k, v, mask, block_q, block_k, interpret)
     else:
         out, lse = _chunked_forward(q, k, v, mask, block_k, dropout_rate, key)
+    out, lse = _kept(out, lse)
     return out, (q, k, v, mask, key, out, lse)
 
 
@@ -1048,6 +1060,16 @@ def sharded_flash_attention(
         out_specs=qs,
     )
     return wrapped(q, k, v, mask, dropout_key)
+
+
+#: What a rematerialised block keeps besides its input (the models'
+#: ``remat`` asks for these names and no others): of a custom VJP's
+#: residuals the two that cost the whole kernel to make again, ``out``
+#: ((2 H D) bytes a token in bfloat16) and ``lse`` (4 H). q, k and v are a
+#: norm, three projections and rotary away and are made again. Without a
+#: policy the names are identities; on the routes that never reach the
+#: rules (reference, ring, Ulysses) they do not occur and nothing is kept.
+REMAT_KEEPS = ("attention.out", "attention.lse")
 
 
 def attention_route(dropout_rate: float, mesh=None) -> str:

@@ -346,7 +346,8 @@ def print_routes(recs: List[dict]) -> None:
     ``trial.setup`` span has it (ops/attention.attention_route, the one
     rule, asked for the training and the evaluation rate); for a
     model with a layer pattern a line each kind of layer, what the expert
-    layers hold, and (from ``trial.train``) what they counted."""
+    layers hold, and (from ``trial.train``) what they counted; for a
+    rematerialised model what a block keeps besides its input."""
     held = {}  # trial -> its setup's attrs["moe"]
     for r in recs:
         attrs = r["attrs"]
@@ -358,6 +359,10 @@ def print_routes(recs: List[dict]) -> None:
             for kind, how in attrs.get("attention_layers", {}).items():
                 print(f"trial {r['trial']}: {kind} layers: {how['route']}, "
                       f"mask by {how['mask']}")
+            remat = attrs.get("remat")
+            if remat:
+                print(f"trial {r['trial']}: remat: {remat['blocks']} blocks "
+                      f"keep {', '.join(remat['keeps'])}")
             moe = attrs.get("moe")
             if moe:
                 held[r["trial"]] = moe

@@ -95,3 +95,42 @@ def test_structural_masks_and_grouped_heads_compile_for_a_v5e(one_chip, case):
     compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
         q, kv, kv).compile()
     assert compiled.as_text().count("tpu_custom_call") == 2  # fwd, bwd
+
+
+@pytest.mark.parametrize("how, forwards", [("kept", 2), ("bare", 4)])
+def test_a_rematerialised_stack_s_gradient_holds_a_forward_kernel_a_layer(
+        one_chip, monkeypatch, how, forwards):
+    """What Mosaic and XLA make of a rematerialised two-layer pattern
+    stack (a global and a window layer): with the rule's policy the
+    compiled gradient calls ``flash_fwd`` once a layer, under a bare
+    ``nn.remat`` twice; ``flash_bwd`` once either way."""
+    import re
+
+    from flax import linen as nn
+
+    from metaopt_tpu.models import lm
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    if how == "bare":
+        monkeypatch.setattr(lm, "rematerialised", nn.remat)
+    s = 256
+    model = lm.make_lm(dict(
+        hidden_size=256, num_attention_heads=4, num_key_value_heads=2,
+        head_dim=128, num_hidden_layers=2, vocab_size=512,
+        sliding_window_layout=[0, 1], rope_layout=[0, 1],
+        sliding_window_size=128, moe_num_primary_experts=8,
+        moe_num_active_primary_experts=2, moe_ffn_hidden_size=128,
+        remat=True))
+    on_chip = lambda x: jax.ShapeDtypeStruct(  # noqa: E731
+        x.shape, x.dtype, sharding=one_chip)
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda key: nn.meta.unbox(model.init(
+            key, jnp.zeros((1, s), jnp.int32), train=False)["params"]),
+        jax.random.PRNGKey(0)))
+    tokens = on_chip(jax.ShapeDtypeStruct((1, s + 1), jnp.int32))
+    text = jax.jit(jax.grad(lambda p, t: lm.lm_loss_fn(
+        model, p, t, jax.random.PRNGKey(0)))).lower(
+            params, tokens).compile().as_text()
+    calls = lambda name: len(re.findall(  # noqa: E731
+        rf'custom_call_target="tpu_custom_call".*/{name}/pallas_call', text))
+    assert (calls("flash_fwd"), calls("flash_bwd")) == (forwards, 2)

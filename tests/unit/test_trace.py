@@ -321,6 +321,41 @@ def test_trial_setup_s_span_says_which_attention_route_the_steps_take(
         f"{evaluation} in evaluation\n")
 
 
+@pytest.mark.parametrize("blocks, line", [
+    (0, None),
+    (4, "trial T-9: remat: 4 blocks keep attention.out, attention.lse\n")])
+def test_trial_setup_s_span_says_what_a_rematerialised_block_keeps(
+        capsys, blocks, line):
+    """``attrs["remat"]``: the blocks run again in the backward pass and
+    the names of ops/attention.REMAT_KEEPS; absent without remat."""
+    import jax
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from metaopt_tpu.models.transformer import trial_setup
+    from metaopt_tpu.ops.attention import REMAT_KEEPS
+
+    mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("dp", "tp"))
+    trial_setup({"dropout": 0.0}, mesh, 1, 1, 1, 100, remat_blocks=blocks)
+    setup = trace.spans("trial.setup")[-1]
+    assert setup["attrs"].get("remat") == (
+        {"blocks": blocks, "keeps": list(REMAT_KEEPS)} if blocks else None)
+    trace.print_routes([dict(setup, trial="T-9")])
+    said = capsys.readouterr().out.splitlines(keepends=True)
+    assert said[1:] == ([line] if line else [])
+
+
+@pytest.mark.parametrize("remat, blocks", [(True, 2), (False, None)])
+def test_train_and_eval_counts_both_stacks_blocks(remat, blocks):
+    from metaopt_tpu.models.transformer import train_and_eval
+
+    hp = dict(d_model=32, n_layers=1, d_ff=64, n_heads=1, vocab=128,
+              max_len=16, dropout=0.0, remat=remat)
+    train_and_eval(hp, n_train=16, batch_size=8, seq_len=8, steps=1)
+    said = trace.spans("trial.setup")[-1]["attrs"].get("remat")
+    assert (said and said["blocks"]) == blocks
+
+
 def test_train_and_eval_leaves_the_trial_s_phases_in_the_ring(tmp_path):
     from metaopt_tpu.models.transformer import train_and_eval
 
