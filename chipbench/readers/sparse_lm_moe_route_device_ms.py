@@ -1,0 +1,10 @@
+"""``moe_route_device_ms`` in a cell whose attention runs over selected keys,
+read by that metric's own reader: the scope ``moe`` less ``moe.experts``. An
+accepted metric's list of cells takes no new cell, so the cell reports it
+under a name of its own."""
+
+from chipbench.run import _reader
+
+
+def read(records):
+    return _reader("moe_route_device_ms").read(records)

@@ -1,0 +1,10 @@
+"""``readout_xent_device_ms`` in a cell whose attention runs over selected
+keys, read by that metric's own reader: the scope ``readout_xent``: the
+untied head over the held rows and the loss. An accepted metric's list of
+cells takes no new cell, so the cell reports it under a name of its own."""
+
+from chipbench.run import _reader
+
+
+def read(records):
+    return _reader("readout_xent_device_ms").read(records)

@@ -30,6 +30,13 @@ one chip for example:
         examples/lm_causal.py --model=st.json \
         --seq-len=8192 --batch-size=1 --n-train=64 --steps=20 \
         --lr~'loguniform(1e-5, 1e-3)'
+
+A description in the Qwen3-MoE family's words (``num_experts``,
+``num_experts_per_tok``, ``moe_intermediate_size``, ``hidden_act``) with an
+``sa_config`` names a decoder whose attention runs over the keys an indexer
+selects (``python -m chipbench.sparse_lm_config
+chipbench/configs/keye-vl2-30b-a3b-ep8.json`` prints Keye-VL-2.0-30B-A3B's
+share of one chip; run it at ``--seq-len=16384``).
 """
 
 import argparse

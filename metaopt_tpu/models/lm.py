@@ -18,6 +18,18 @@
   (models/moe.DroplessMoE: top-k of ``moe_num_primary_experts`` on the
   logits, gated ReLU experts of width ``moe_ffn_hidden_size``) or, with no
   experts, a gated ReLU feed-forward; no learned positions, an untied head.
+  A description in the Qwen3-MoE family's words (``num_experts``,
+  ``num_experts_per_tok``, ``moe_intermediate_size``, ``hidden_act``) has
+  that family's layer: RMS norms of q and k over a head's width, the router
+  read AFTER attention from the second norm, the experts' activation as
+  named. With ``sa_config`` every layer is of a third kind, *selected*:
+  an :class:`Indexer` (``indexer_num_heads`` heads of ``indexer_head_dim``
+  on one key head) scores every causal key for every query and attention
+  runs over the ``topk`` best (ops/sparse_index.py,
+  ops/attention.SelectedMask). The indexer reads a ``stop_gradient`` and its
+  choice is piecewise constant, so the next-token loss sends it no
+  gradient: its parameters are a frozen part of the trial, outside the
+  gradient tree and outside AdamW (:func:`split_frozen`).
   The chip's share of a deployment is part of the description too:
   ``experts_held`` = (first, count) of the routed experts and
   ``vocab_held`` = (first, count) of the vocabulary's rows (ids are drawn
@@ -94,14 +106,56 @@ def rope(x, theta: float):
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
 
 
-def layer_kind(sliding: bool, rotary: bool) -> str:
-    return ("window" if sliding else "global") + \
-        ("-rope" if rotary else "-nope")
+def layer_kind(sliding: bool, rotary: bool, selected: bool = False) -> str:
+    return ("selected" if selected else "window" if sliding else "global") \
+        + ("-rope" if rotary else "-nope")
+
+
+class Indexer(nn.Module):
+    """Which keys each query attends to: ``n_heads`` index heads of width
+    ``head_dim`` on one layer-normed key head, a weight a (query, head)
+    read from the hidden state, and the ``top_k`` causal keys with the
+    largest ``sum_j w[t, j] relu(q[t, j] . k[s])`` (ops/sparse_index.py).
+    Float32 at matmul precision highest throughout: the choice hangs on the
+    scores' last bits. Its parameters get no gradient (module docstring)."""
+
+    n_heads: int
+    head_dim: int
+    top_k: int
+    rope_theta: Optional[float]
+
+    @nn.compact
+    def __call__(self, n):
+        from metaopt_tpu.ops import sparse_index
+
+        n = jax.lax.stop_gradient(n.astype(jnp.float32))
+        with trace.scope("attention.index"):
+            proj = lambda name, features: nn.DenseGeneral(  # noqa: E731
+                features, use_bias=False, name=name,
+                precision=jax.lax.Precision.HIGHEST,
+                kernel_init=with_mesh_partitioning(
+                    nn.initializers.lecun_normal(),
+                    (None,) * (1 + len(features))))(n)
+            q = proj("q", (self.n_heads, self.head_dim))
+            k = nn.LayerNorm(name="k_norm")(proj("k", (self.head_dim,)))
+            if self.rope_theta is not None:
+                q = rope(q, self.rope_theta)
+                k = rope(k[:, :, None], self.rope_theta)[:, :, 0]
+            w = proj("w", (self.n_heads,)) * (
+                self.n_heads ** -0.5 * self.head_dim ** -0.5)
+        mask, selected = sparse_index.select(q, k, w, self.top_k)
+        b, s = n.shape[:2]
+        self.sow("attn_stats", "selected_pairs", selected)
+        self.sow("attn_stats", "causal_pairs",
+                 jnp.asarray(b * s * (s + 1) // 2, jnp.int32))
+        return mask
 
 
 class GroupedAttention(nn.Module):
     """Causal self attention with fewer K/V heads than query heads, no
-    bias; rotary or no positions, a window or none."""
+    bias; rotary or no positions; a window, none, or the keys an
+    :class:`Indexer` selects (``selection``: its heads, their width and
+    ``topk``); RMS norms of q and k over a head's width or none."""
 
     d_model: int
     n_heads: int
@@ -109,6 +163,8 @@ class GroupedAttention(nn.Module):
     head_dim: int
     window: Optional[int]
     rope_theta: Optional[float]
+    qk_norm: Optional[float] = None     # the norms' eps
+    selection: Optional[Tuple[int, int, int]] = None
 
     @nn.compact
     @trace.scope("attention")
@@ -116,14 +172,21 @@ class GroupedAttention(nn.Module):
         proj = lambda name, heads: nn.DenseGeneral(  # noqa: E731
             (heads, self.head_dim), axis=-1, dtype=jnp.bfloat16, name=name,
             use_bias=False, kernel_init=_pinit(True, (None, "tp", None)))
+        mask = CausalMask(self.window)
+        if self.selection is not None:
+            mask = Indexer(*self.selection, self.rope_theta,
+                           name="indexer")(x)
         x = x.astype(jnp.bfloat16)
         q, k, v = (proj("q", self.n_heads)(x), proj("k", self.n_kv_heads)(x),
                    proj("v", self.n_kv_heads)(x))
+        if self.qk_norm is not None:
+            q = RMSNorm(self.qk_norm, name="q_norm")(q)
+            k = RMSNorm(self.qk_norm, name="k_norm")(k)
         if self.rope_theta is not None:
             q, k = rope(q, self.rope_theta), rope(k, self.rope_theta)
         q = (q / math.sqrt(self.head_dim)).astype(jnp.bfloat16)
         k = k.astype(jnp.bfloat16)
-        out = attend(q, k, v, CausalMask(self.window))
+        out = attend(q, k, v, mask)
         return nn.DenseGeneral(
             self.d_model, axis=(-2, -1), dtype=jnp.bfloat16, name="out",
             use_bias=False, kernel_init=_pinit(True, ("tp", None, None)),
@@ -164,16 +227,25 @@ class Pattern:
     expert_d_ff: int
     experts_held: Tuple[int, int]
     vocab_held: Tuple[int, int]
+    #: the Qwen3-MoE family's layer: q/k norms, the router after attention
+    qk_norm: bool = False
+    router_after_attention: bool = False
+    activation: str = "relu"
+    #: (index heads, their width, top k) of ``sa_config``: every layer
+    #: attends to the keys its indexer selects
+    selection: Optional[Tuple[int, int, int]] = None
 
     def kinds(self):
         """The distinct layer kinds, in the pattern's order."""
-        return list(dict.fromkeys(layer_kind(*l) for l in self.layers))
+        return list(dict.fromkeys(
+            layer_kind(*l, self.selection is not None) for l in self.layers))
 
 
 class PatternBlock(nn.Module):
     """x + attention(norm(x)), then + experts(norm(.)) routed by logits
-    read from the FIRST norm's output, before attention. The residual
-    stream is float32."""
+    read from the FIRST norm's output, before attention, or (the pattern's
+    ``router_after_attention``) from the second's. The residual stream is
+    float32."""
 
     d_model: int
     n_heads: int
@@ -185,27 +257,34 @@ class PatternBlock(nn.Module):
     @nn.compact
     def __call__(self, x):
         p = self.pattern
-        n = RMSNorm(p.rms_eps, name="norm_in")(x)
-        if p.n_experts:
+
+        def router(read):
             with trace.scope("moe"), trace.scope("moe.router"):
                 # float32 in earnest: a TPU's default precision would make
                 # this product in bfloat16 passes, and the top-k choice
                 # hangs on the logits' last bits
-                logits = nn.Dense(
+                return nn.Dense(
                     p.n_experts, use_bias=False, name="router",
                     precision=jax.lax.Precision.HIGHEST,
                     kernel_init=with_mesh_partitioning(
-                        nn.initializers.lecun_normal(), (None, None)))(n)
+                        nn.initializers.lecun_normal(), (None, None)))(read)
+
+        n = RMSNorm(p.rms_eps, name="norm_in")(x)
+        if p.n_experts and not p.router_after_attention:
+            logits = router(n)
         x = x + GroupedAttention(
             self.d_model, self.n_heads, p.n_kv_heads, p.head_dim,
             p.window if self.sliding else None,
-            p.rope_theta if self.rotary else None, name="attn")(n)
+            p.rope_theta if self.rotary else None,
+            p.rms_eps if p.qk_norm else None, p.selection, name="attn")(n)
         m = RMSNorm(p.rms_eps, name="norm_post")(x)
         if p.n_experts:
             from metaopt_tpu.models.moe import DroplessMoE
 
+            if p.router_after_attention:
+                logits = router(m)
             return x + DroplessMoE(self.d_model, p.expert_d_ff, p.n_experts,
-                                   p.top_k, p.experts_held,
+                                   p.top_k, p.experts_held, p.activation,
                                    name="experts")(m, logits)
         return x + GatedFeedForward(self.d_model, self.d_ff, name="mlp")(m)
 
@@ -312,7 +391,11 @@ class DecoderOnlyLM(nn.Module):
 
 #: a description's published names beside the zoo's own
 _PUBLISHED = {"hidden_size": "d_model", "num_attention_heads": "n_heads",
-              "num_hidden_layers": "n_layers", "vocab_size": "vocab"}
+              "num_hidden_layers": "n_layers", "vocab_size": "vocab",
+              # the Qwen3-MoE family's words for what pattern_of reads
+              "num_experts": "moe_num_primary_experts",
+              "num_experts_per_tok": "moe_num_active_primary_experts",
+              "moe_intermediate_size": "moe_ffn_hidden_size"}
 
 
 def _own_names(hparams: Dict[str, Any]) -> Dict[str, Any]:
@@ -325,8 +408,11 @@ def _own_names(hparams: Dict[str, Any]) -> Dict[str, Any]:
 
 def pattern_of(h: Dict[str, Any]) -> Optional[Pattern]:
     """The layer pattern a description names, or None. The two layouts are
-    read up to ``n_layers`` (a cut in depth keeps the leading layers)."""
-    if "rope_layout" not in h and "sliding_window_layout" not in h:
+    read up to ``n_layers`` (a cut in depth keeps the leading layers);
+    without them every layer is global and rotary. ``num_experts`` (the
+    Qwen3-MoE family's word) brings that family's q/k norms and router
+    placement, ``sa_config`` the selected attention."""
+    if not {"rope_layout", "sliding_window_layout", "sa_config"} & set(h):
         return None
     n_layers = int(h.get("n_layers", 6))
     rotary = list(h.get("rope_layout") or [1] * n_layers)
@@ -352,7 +438,23 @@ def pattern_of(h: Dict[str, Any]) -> Optional[Pattern]:
         expert_d_ff=int(h.get("moe_ffn_hidden_size", h.get("d_ff", 2048))),
         experts_held=held("experts_held", n_experts),
         vocab_held=held("vocab_held", vocab),
+        qk_norm="num_experts" in h,
+        router_after_attention="num_experts" in h,
+        activation=str(h.get("hidden_act", "relu")),
+        selection=_selection(h.get("sa_config")),
     )
+
+
+def _selection(sa: Optional[Dict[str, Any]]):
+    """(index heads, their width, top k) of a published ``sa_config``; its
+    chunk sizes tile the computation and do not change the result."""
+    if not sa:
+        return None
+    if int(sa.get("indexer_num_kv_heads", 1)) != 1:
+        raise ValueError("the indexer has one key head, not "
+                         f"{sa['indexer_num_kv_heads']}")
+    return (int(sa["indexer_num_heads"]), int(sa["indexer_head_dim"]),
+            int(sa["topk"]))
 
 
 def describe_pattern(hparams: Dict[str, Any], route: str,
@@ -370,12 +472,18 @@ def describe_pattern(hparams: Dict[str, Any], route: str,
     if p is None:
         return {}
     by_structure = route == "pallas"
-    out = {"attention_layers": {
-        kind: {"route": route,
-               "mask": ("structure" if by_structure else "dense") + (
-                   f": causal, window {p.window}" if kind.startswith("window")
-                   else ": causal")}
-        for kind in p.kinds()}}
+
+    def mask_of(kind):
+        if kind.startswith("selected"):
+            heads, _, top_k = p.selection
+            return (f"selected: causal, top {top_k} of the index scores, "
+                    f"{heads} index heads")
+        return ("structure" if by_structure else "dense") + (
+            f": causal, window {p.window}" if kind.startswith("window")
+            else ": causal")
+
+    out = {"attention_layers": {kind: {"route": route, "mask": mask_of(kind)}
+                                for kind in p.kinds()}}
     if p.n_experts:
         out["moe"] = {"routed_over": p.n_experts, "top_k": p.top_k,
                       "held": list(p.experts_held),
@@ -423,20 +531,21 @@ def lm_loss_fn(model, params, tokens, dropout_key,
     out, mutated = model.apply(
         {"params": params}, inp, train=True, features=blocked,
         rngs={"dropout": dropout_key},
-        mutable=["aux_loss", "moe_stats"],
+        mutable=["aux_loss", "moe_stats", "attn_stats"],
     )
     mask = (labels != 0).astype(jnp.float32)
     loss = readout_xent(out, params, labels - first, vocab, blocked)
     loss = masked_mean_with_aux(loss, mask, mutated, moe_aux_weight)
-    return (loss, moe_counts(mutated)) if with_stats else loss
+    if not with_stats:
+        return loss
+    return loss, {**moe_counts(mutated), **selection_counts(mutated)}
 
 
 def moe_counts(mutated) -> Dict[str, Any]:
     """{"items": (layers, held) int32, "dropped": (layers,) int32,
     "chunks": (layers,) int32} from the ``moe_stats`` the dropless expert
     layers sowed, layer by layer; empty for a model without such layers."""
-    layers = [v for _, v in sorted(mutated.get("moe_stats", {}).items(),
-                                   key=lambda kv: int(kv[0][1:]))  # h0, h1..
+    layers = [v for v in _by_layer(mutated.get("moe_stats", {}))
               if "items" in v.get("experts", {})]
     if not layers:
         return {}
@@ -444,20 +553,94 @@ def moe_counts(mutated) -> Dict[str, Any]:
             for key in ("items", "dropped", "chunks")}
 
 
+def _by_layer(collection) -> list:
+    """A sown collection's per-layer entries, h0, h1, ... in order."""
+    return [v for _, v in sorted(collection.items(),
+                                 key=lambda kv: int(kv[0][1:]))]
+
+
+#: the pairs' counts are kept as (high, low) int32 words of _LIMB bits in
+#: the low one: a step of 16 384 tokens selects 31 M pairs a layer, which
+#: a plain int32 sum holds for 68 steps
+_LIMB = 24
+_LOW = (1 << _LIMB) - 1
+
+
+def selection_counts(mutated) -> Dict[str, Any]:
+    """{"selected_pairs", "causal_pairs": (layers,) int32} from what the
+    selected-attention layers sowed this step; empty without such layers."""
+    layers = [v["attn"]["indexer"] for v in
+              _by_layer(mutated.get("attn_stats", {}))]
+    if not layers:
+        return {}
+    return {key: jnp.stack([v[key][0] for v in layers])
+            for key in ("selected_pairs", "causal_pairs")}
+
+
+def _add_counts(total, new):
+    """The running sums with a step's counts added. The expert layers'
+    are plain sums; the pairs' (layers, 2) carry from the low word."""
+    out = {}
+    for key, step in sorted(new.items()):
+        if key.endswith("_pairs"):
+            low = total[key][:, 1] + (step & _LOW)
+            out[key] = jnp.stack(
+                [total[key][:, 0] + (step >> _LIMB) + (low >> _LIMB),
+                 low & _LOW], axis=1)
+        else:
+            out[key] = total[key] + step
+    return out
+
+
+#: the module whose parameters no gradient reaches (:class:`Indexer`)
+FROZEN = "indexer"
+
+
+def split_frozen(params):
+    """(trained, frozen): ``params`` without and with only the subtrees
+    named ``FROZEN``. The trained tree is what is differentiated and what
+    AdamW holds moments for; for a model without an indexer it is
+    ``params``' own structure and ``frozen`` is empty."""
+    trained, frozen = {}, {}
+    for name, sub in params.items():
+        if name == FROZEN:
+            frozen[name] = sub
+        elif isinstance(sub, dict):
+            trained[name], below = split_frozen(sub)
+            if below:
+                frozen[name] = below
+        else:
+            trained[name] = sub
+    return trained, frozen
+
+
+def merge_frozen(trained, frozen):
+    """The inverse of :func:`split_frozen`."""
+    out = dict(trained)
+    for name, sub in frozen.items():
+        out[name] = merge_frozen(trained[name], sub) if name in trained \
+            else sub
+    return out
+
+
 def make_lm_train_step(model, tx):
     """The jittable train step (donated params/opt state). ``counts`` is
-    the running sum of :func:`moe_counts` over the steps, on the device
-    (an empty dict for a model that counts nothing, zeros before the
-    first step otherwise)."""
+    the running sum of :func:`moe_counts` and :func:`selection_counts`
+    over the steps, on the device (an empty dict for a model that counts
+    nothing, zeros before the first step otherwise). The frozen part of
+    ``params`` (:func:`split_frozen`) goes through unchanged."""
 
     def train_step(params, opt_state, counts, tokens, step_key):
+        trained, frozen = split_frozen(params)
         (loss, new), grads = jax.value_and_grad(
-            lambda p: lm_loss_fn(model, p, tokens, step_key,
-                                 with_stats=True), has_aux=True)(params)
+            lambda p: lm_loss_fn(model, merge_frozen(p, frozen), tokens,
+                                 step_key, with_stats=True),
+            has_aux=True)(trained)
         with trace.scope("optimizer"):
-            updates, opt_state = tx.update(grads, opt_state, params)
-            params = optax.apply_updates(params, updates)
-        return params, opt_state, jax.tree.map(jnp.add, counts, new), loss
+            updates, opt_state = tx.update(grads, opt_state, trained)
+            trained = optax.apply_updates(trained, updates)
+        return (merge_frozen(trained, frozen), opt_state,
+                _add_counts(counts, new), loss)
 
     return train_step
 
@@ -470,7 +653,7 @@ def init_sharded_lm(model: DecoderOnlyLM, mesh: Mesh, tx,
 
     def init_fn(key):
         params = model.init(key, toks, train=False)["params"]
-        return params, tx.init(params)
+        return params, tx.init(split_frozen(params)[0])
 
     return sharded_init(init_fn, mesh, seed)
 
@@ -522,15 +705,21 @@ class LMTrial:
                                None),
                 donate_argnums=(0, 1, 2),
             )
-        #: the expert layers' counts summed over the steps, on the device
+        #: the expert layers' counts, and the selected-attention layers',
+        #: summed over the steps, on the device
         self.counts: Dict[str, Any] = {}
         p = self.model.pattern
+        layers = len(p.layers) if p is not None else 0
         if p is not None and p.n_experts:
-            layers = len(p.layers)
-            self.counts = jax.device_put({
+            self.counts = {
                 "items": jnp.zeros((layers, p.experts_held[1]), jnp.int32),
                 "dropped": jnp.zeros((layers,), jnp.int32),
-                "chunks": jnp.zeros((layers,), jnp.int32)}, whole)
+                "chunks": jnp.zeros((layers,), jnp.int32)}
+        if p is not None and p.selection:
+            self.counts.update(
+                selected_pairs=jnp.zeros((layers, 2), jnp.int32),
+                causal_pairs=jnp.zeros((layers, 2), jnp.int32))
+        self.counts = jax.device_put(self.counts, whole)
 
     def __enter__(self):
         self._scope = self._use_mesh(self.mesh)
@@ -557,9 +746,12 @@ class LMTrial:
     def read_counts(self) -> Dict[str, Any]:
         """The counts so far, copied to the host (one round trip): items a
         held expert, items dropped and the trips of a pass of the routing
-        over the buffers, a layer."""
-        return {k: v.tolist() for k, v in
-                jax.device_get(self.counts).items()}
+        over the buffers, a layer; and, where layers select their keys, the
+        (query, key) pairs selected and the causal pairs they were chosen
+        among, a layer."""
+        return {k: [(hi << _LIMB) + lo for hi, lo in v.tolist()]
+                if k.endswith("_pairs") else v.tolist()
+                for k, v in jax.device_get(self.counts).items()}
 
 
 def train_lm(
@@ -586,7 +778,9 @@ def train_lm(
     harness. ``restore_dir``/``save_dir``: orbax trial checkpoints, same
     PBT-handoff/suspend-resume contract as ``train_and_eval``. The loop
     is :class:`LMTrial`'s; the expert layers' counts are read once after
-    it, into ``trial.train``'s ``attrs["moe"]``.
+    it, into ``trial.train``'s ``attrs["moe"]``, and the selected and
+    causal pairs of layers that select their keys into
+    ``attrs["selection"]``.
     """
     trial = LMTrial(hparams, mesh=mesh, tp=tp, sp=sp, ep=ep, n_train=n_train,
                     batch_size=batch_size, seq_len=seq_len, steps=steps,
@@ -600,7 +794,13 @@ def train_lm(
             # float(loss) below would wait for it anyway, once
             loss.block_until_ready()
         if trial.counts:
-            train["attrs"]["moe"] = trial.read_counts()
+            counts = trial.read_counts()
+            pairs = {k: counts.pop(k) for k in list(counts)
+                     if k.endswith("_pairs")}
+            if counts:
+                train["attrs"]["moe"] = counts
+            if pairs:
+                train["attrs"]["selection"] = pairs
     if save_dir:
         from metaopt_tpu.models.checkpoint import save_state
 

@@ -39,9 +39,9 @@ on gang-scheduled sub-slices.
 
 :class:`DroplessMoE` is the other expert layer, for the decoders whose
 description names ``moe_num_primary_experts``: top-k on the router's
-LOGITS, a softmax over the chosen ones, gated ReLU experts of three
-matrices, and **no capacity**: every (token, choice) item is computed,
-whatever the imbalance. It is told which experts it holds (a contiguous
+LOGITS, a softmax over the chosen ones, gated experts of three matrices
+(ReLU or SiLU on the gate, as the description says), and **no capacity**:
+every (token, choice) item is computed, whatever the imbalance. It is told which experts it holds (a contiguous
 share of the published count), routes over all of them, and returns the
 part of the layer's output that its own experts give: the items whose
 expert lives here are ordered by expert and go through three grouped
@@ -417,8 +417,10 @@ def route_top_k(logits, k: int):
         return jax.nn.softmax(top, axis=-1), experts
 
 
-def dropless_experts(x, weights, experts, w_gate, w_up, w_down, first):
-    """The held experts' part of a gated-ReLU expert layer.
+def dropless_experts(x, weights, experts, w_gate, w_up, w_down, first,
+                     activation=nn.relu):
+    """The held experts' part of a gated expert layer (``activation`` on
+    the gate's product: ReLU or, as the description names it, SiLU).
 
     x (t, d); weights, experts (t, k) from :func:`route_top_k`; w_gate,
     w_up (held, d, f), w_down (held, f, d): experts ``first .. first +
@@ -446,7 +448,7 @@ def dropless_experts(x, weights, experts, w_gate, w_up, w_down, first):
         product = functools.partial(
             grouped_matmul, group_sizes=plan["items"],
             impl=grouped_matmul_impl(n, d, w_gate.shape[2]))
-        h = nn.relu(product(rows, w_gate.astype(jnp.bfloat16))) \
+        h = activation(product(rows, w_gate.astype(jnp.bfloat16))) \
             * product(rows, w_up.astype(jnp.bfloat16))
         out = product(h, w_down.astype(jnp.bfloat16))
     with trace.scope("moe.combine"):
@@ -460,16 +462,18 @@ def dropless_experts(x, weights, experts, w_gate, w_up, w_down, first):
 
 
 class DroplessMoE(nn.Module):
-    """Gated-ReLU experts under dropless top-k routing, for the share of the
+    """Gated experts under dropless top-k routing, for the share of the
     experts held here (module docstring). ``held`` = (first, count) of the
     ``n_experts`` published ones; the router's logits come from outside
-    (these decoders read them before attention)."""
+    (a decoder reads them before attention or after it); ``activation``
+    is the gate's, by the name a published config gives it."""
 
     d_model: int
     d_ff: int
     n_experts: int
     top_k: int
     held: Tuple[int, int]
+    activation: str = "relu"
 
     @nn.compact
     @trace.scope("moe")
@@ -489,22 +493,28 @@ class DroplessMoE(nn.Module):
                                 ("up", (count, d, self.d_ff)),
                                 ("down", (count, self.d_ff, d)))}
         weights, experts = route_top_k(logits.reshape(b * s, -1), self.top_k)
+        act = _ACTIVATIONS[self.activation]
         mesh = active_mesh()
         ep = dict(mesh.shape).get("ep", 1) if mesh is not None else 1
         if ep == 1:
             y, counts = dropless_experts(
                 x.reshape(b * s, d), weights, experts, w["gate"], w["up"],
-                w["down"], first)
+                w["down"], first, act)
         else:
             y, counts = _over_ep(mesh, ep, x.reshape(b * s, d), weights,
-                                 experts, w, first)
+                                 experts, w, first, act)
         self.sow("moe_stats", "items", counts["items"])
         self.sow("moe_stats", "dropped", counts["dropped"])
         self.sow("moe_stats", "chunks", counts["chunks"])
         return y.reshape(b, s, d)
 
 
-def _over_ep(mesh, ep: int, x, weights, experts, w, first: int):
+#: a published config's ``hidden_act`` -> the gate's activation
+_ACTIVATIONS = {"relu": nn.relu, "silu": nn.silu}
+
+
+def _over_ep(mesh, ep: int, x, weights, experts, w, first: int,
+             activation=nn.relu):
     """The layer on an ``ep`` mesh axis: each chip holds ``held / ep``
     experts, computes their part for every token, and the parts are summed
     over the axis (tokens are not exchanged: every chip of the axis has
@@ -518,7 +528,7 @@ def _over_ep(mesh, ep: int, x, weights, experts, w, first: int):
     def local(x, weights, experts, gate, up, down):
         mine = first + jax.lax.axis_index("ep") * (count // ep)
         y, counts = dropless_experts(x, weights, experts, gate, up, down,
-                                     mine)
+                                     mine, activation)
         return (jax.lax.psum(y, "ep"), counts["items"],
                 jax.lax.psum(counts["dropped"], "ep"),
                 jax.lax.pmax(counts["chunks"], "ep"))

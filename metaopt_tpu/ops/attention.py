@@ -64,8 +64,8 @@ Megatron head split survives instead of GSPMD all-gathering q/k/v.
 :func:`flash_attention` is the kernels' own entry and takes ``impl`` as
 its caller states it.
 
-Not here: per-row key lengths under a ``CausalMask`` (a padded row still
-needs the dense form), segment ids and packing.
+A :class:`SelectedMask` (keys chosen a query: ops/selected_attention.py,
+loaded when one arrives). Not here: key lengths, segment ids and packing.
 """
 
 from __future__ import annotations
@@ -693,6 +693,32 @@ def _flash_causal_bwd(window, block_q, block_k, interpret, residuals, g):
 _flash_causal.defvjp(_flash_causal_fwd, _flash_causal_bwd)
 
 
+@dataclasses.dataclass(frozen=True)
+class SelectedMask:
+    """A mask stated by a selection: *causal and selected*, a key set of its
+    own for every query row, the same for all heads (ops/sparse_index.py
+    makes one from an indexer's scores). ``bits`` (B, S_p / 32, S_p) int32
+    holds a bit a (key, query) pair, S_p the length padded to ``block``
+    tiles: key ``r`` of key tile ``T`` is bit ``r // (block / 32)`` of word
+    ``T * (block / 32) + r % (block / 32)`` in query ``i``'s column, so a
+    kernel's (block / 32, Bq) slab of words unpacks into its (block, Bq)
+    score tile with one shift a row (ops/selected_attention.py). 32 MB at
+    16 384 x 16 384 where a dense float mask is 1 GB. No bit is set for a
+    key after its query or for a padded key or query."""
+
+    bits: jax.Array
+    block: int
+
+    def dense(self, sq: int, sk: int):
+        """The (B, sq, sk) boolean array that says the same."""
+        b, words, s_p = self.bits.shape
+        per_tile = self.block // 32
+        w = self.bits.transpose(0, 2, 1).reshape(
+            b, s_p, words // per_tile, 1, per_tile)
+        bit = jnp.arange(32, dtype=jnp.int32)[:, None]
+        return (((w >> bit) & 1) != 0).reshape(b, s_p, s_p)[:, :sq, :sk]
+
+
 # ---------------------------------------------------------------------------
 # chunked (lax.scan) twin — pure XLA, any backend, dropout-capable
 
@@ -892,7 +918,7 @@ def _plain_operands(q, k, v, mask):
     group = q.shape[2] // k.shape[2]
     if group > 1:
         k, v = jnp.repeat(k, group, axis=2), jnp.repeat(v, group, axis=2)
-    if isinstance(mask, CausalMask):
+    if isinstance(mask, (CausalMask, SelectedMask)):
         mask = jnp.broadcast_to(mask.dense(q.shape[1], k.shape[1]),
                                 (q.shape[0], q.shape[1], k.shape[1]))
     return q, k, v, mask
@@ -940,7 +966,9 @@ def flash_attention(
     k, v: (B, Sk, Hkv, D), H a multiple of Hkv and query head h reading
     K/V head h // (H // Hkv); mask: optional (B, Sq, Sk) bool, True =
     attend (shared across heads), or a :class:`CausalMask`, which the
-    Pallas route computes from positions, skipping the tiles it hides;
+    Pallas route computes from positions, skipping the tiles it hides, or
+    a :class:`SelectedMask`, whose packed bits the Pallas route unpacks
+    tile by tile (its own kernels; the other routes take it dense);
     dropout_rate applies to attention probabilities
     (chunked impl only) with dropout_key. Irregular Sq/Sk are padded to
     block multiples with masked tails; ``block_q`` / ``block_k`` left at
@@ -960,6 +988,10 @@ def flash_attention(
 
     b, sq, h, d = q.shape
     sk = k.shape[1]
+    if isinstance(mask, SelectedMask) and impl == "pallas":
+        from metaopt_tpu.ops.selected_attention import flash_selected
+
+        return flash_selected(q, k, v, mask, bool(interpret))
     if isinstance(mask, CausalMask) and impl == "pallas":
         if sq != sk:
             raise ValueError("a CausalMask is for self attention: "
@@ -1069,7 +1101,10 @@ def sharded_flash_attention(
 #: norm, three projections and rotary away and are made again. Without a
 #: policy the names are identities; on the routes that never reach the
 #: rules (reference, ring, Ulysses) they do not occur and nothing is kept.
-REMAT_KEEPS = ("attention.out", "attention.lse")
+#: ``selected`` is a :class:`SelectedMask`'s packed bits (S^2 / 8 bytes a
+#: row), which the backward kernel reads and which cost the indexer's scores
+#: and an exact top-k to make again; it occurs only where a layer selects.
+REMAT_KEEPS = ("attention.out", "attention.lse", "attention.selected")
 
 
 def attention_route(dropout_rate: float, mesh=None) -> str:
@@ -1112,7 +1147,7 @@ def attend(q, k, v, mask=None, *, dropout_rate: float = 0.0,
         # ring (lowest per-chip memory), or Ulysses' all-to-all exchange of
         # heads for sequence (fewer collectives, needs per-device heads %
         # sp == 0). Those modules import this one.
-        if isinstance(mask, CausalMask):
+        if isinstance(mask, (CausalMask, SelectedMask)):
             raise ValueError("the pattern's attention has no sequence-"
                              "parallel route: drop sp from the trial mesh")
         sp = mesh.shape["sp"]
@@ -1137,6 +1172,9 @@ def attend(q, k, v, mask=None, *, dropout_rate: float = 0.0,
     if route == "reference":
         return _reference_attention(q, k, v, mask, dropout_rate, dropout_key)
     if mesh is not None and mesh.size > 1:
+        if isinstance(mask, SelectedMask):
+            raise ValueError("a selected mask has no route over a mesh of "
+                             f"{mesh.size} devices yet: run the trial on one")
         return sharded_flash_attention(
             mesh, q, k, v, mask, dropout_rate=dropout_rate,
             dropout_key=dropout_key, impl=route)

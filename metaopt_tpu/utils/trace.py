@@ -43,7 +43,10 @@ SPANS = (
 SCOPES = ("embed", "attention", "attention.core", "ffn", "readout_xent",
           "optimizer", "eval",
           # an expert layer (models/moe.DroplessMoE): all of it, and its parts
-          "moe", "moe.router", "moe.dispatch", "moe.experts", "moe.combine")
+          "moe", "moe.router", "moe.dispatch", "moe.experts", "moe.combine",
+          # attention over selected keys (ops/sparse_index.py): the
+          # indexer's projections and scores, and the exact top-k
+          "attention.index", "attention.select")
 #: spans a train loop makes every step. The ring keeps one whole only if it
 #: has a child (the step that compiled); the others are summed into the
 #: enclosing span's ``attrs["per_step"]`` as ``{name: [count, seconds]}``, so a
@@ -346,8 +349,9 @@ def print_routes(recs: List[dict]) -> None:
     ``trial.setup`` span has it (ops/attention.attention_route, the one
     rule, asked for the training and the evaluation rate); for a
     model with a layer pattern a line each kind of layer, what the expert
-    layers hold, and (from ``trial.train``) what they counted; for a
-    rematerialised model what a block keeps besides its input."""
+    layers hold, and (from ``trial.train``) what they counted and, where
+    layers select their keys, the selected pairs among the causal ones; for
+    a rematerialised model what a block keeps besides its input."""
     held = {}  # trial -> its setup's attrs["moe"]
     for r in recs:
         attrs = r["attrs"]
@@ -385,6 +389,14 @@ def print_routes(recs: List[dict]) -> None:
                 moved = sum(counts["chunks"]) * said["chunk_rows"]
                 print(f"trial {r['trial']}: routing moved "
                       f"{100 * moved / rows:.1f} % of the buffers' rows")
+        pairs = (attrs.get("selection") or {}) \
+            if r["name"] == "trial.train" else {}
+        for layer, (chosen, seen) in enumerate(zip(
+                pairs.get("selected_pairs", ()),
+                pairs.get("causal_pairs", ()))):
+            print(f"trial {r['trial']}: layer {layer}: attention over "
+                  f"{chosen} selected of {seen} causal pairs, "
+                  f"{100 * chosen / seen if seen else 0.0:.1f} %")
 
 
 dump_under(os.environ.get(PROFILE_DIR_ENV))

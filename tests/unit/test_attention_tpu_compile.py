@@ -97,6 +97,54 @@ def test_structural_masks_and_grouped_heads_compile_for_a_v5e(one_chip, case):
     assert compiled.as_text().count("tpu_custom_call") == 2  # fwd, bwd
 
 
+# (length, query heads, K/V heads, head width): the selected-attention
+# cell's 16384 tokens (a program holds one (128, 16384) head of K and V and
+# a 1 MiB slab of the packed selection), a length that pads to 256-tiles,
+# and 64-wide heads
+SELECTED = [(16384, 32, 4, 128), (1000, 8, 2, 128), (2048, 8, 8, 64)]
+
+
+@pytest.mark.parametrize("case", SELECTED,
+                         ids=lambda c: "x".join(map(str, c)))
+def test_a_selected_mask_s_kernels_compile_for_a_v5e(one_chip, case):
+    """The packed bits' unpacking (a sublane repeat and a shift a row) and
+    the slabs' block shapes pass Mosaic; VMEM stays inside the limit."""
+    from metaopt_tpu.ops.attention import SelectedMask
+    from metaopt_tpu.ops.selected_attention import selected_block
+
+    s, h, hkv, d = case
+    block, s_p = selected_block(s)
+    q = jax.ShapeDtypeStruct((1, s, h, d), jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((1, s, hkv, d), jnp.bfloat16, sharding=one_chip)
+    bits = jax.ShapeDtypeStruct((1, s_p // 32, s_p), jnp.int32,
+                                sharding=one_chip)
+
+    def loss(q, k, v, bits):
+        out = flash_attention(q, k, v, SelectedMask(bits, block),
+                              impl="pallas")
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        q, kv, kv, bits).compile().as_text()
+    assert text.count("tpu_custom_call") == 2  # sparse_fwd, sparse_bwd
+    assert "sparse_fwd" in text and "sparse_bwd" in text
+
+
+def test_the_selection_compiles_for_a_v5e_at_the_cell_s_length(one_chip):
+    """16384 rows of 16 x 64 index heads, top 2048: four groups of blocks,
+    a fraction of a GB of temporaries (the scores of all heads at once
+    would be 17 GB)."""
+    from metaopt_tpu.ops import sparse_index
+
+    s = 16384
+    shape = lambda *dims: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, jnp.float32, sharding=one_chip)
+    compiled = jax.jit(
+        lambda q, k, w: sparse_index.select(q, k, w, 2048)[0].bits).lower(
+            shape(1, s, 16, 64), shape(1, s, 64), shape(1, s, 16)).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+
+
 @pytest.mark.parametrize("how, forwards", [("kept", 2), ("bare", 4)])
 def test_a_rematerialised_stack_s_gradient_holds_a_forward_kernel_a_layer(
         one_chip, monkeypatch, how, forwards):

@@ -46,7 +46,7 @@ def ref_rope(x):
     return jnp.concatenate([a * c - b * s, a * s + b * c], -1)
 
 
-def ref_moe(m, logits, p, held):
+def ref_moe(m, logits, p, held, act=jax.nn.relu):
     """Held experts (``p``: theirs alone) applied to every token, weighed
     by the routing."""
     top, idx = jax.lax.top_k(logits, TOPK)
@@ -55,7 +55,7 @@ def ref_moe(m, logits, p, held):
     first, count = held
     for e in range(count):
         we = jnp.sum(jnp.where(idx == first + e, w, 0.0), -1)
-        hid = jax.nn.relu(jnp.dot(m, p["gate"][e], precision=HI)) \
+        hid = act(jnp.dot(m, p["gate"][e], precision=HI)) \
             * jnp.dot(m, p["up"][e], precision=HI)
         y = y + we[:, None] * jnp.dot(hid, p["down"][e], precision=HI)
     return y
@@ -198,10 +198,12 @@ def test_window_and_global_layers_differ_and_a_late_token_is_unseen():
 
 # -- the expert layer ----------------------------------------------------------
 
-def expert_layer(held, logits_bias=None, seed=3, t=40):
+def expert_layer(held, logits_bias=None, seed=3, t=40, activation="relu"):
     """(program's output and counts, reference's output) of one
     DroplessMoE over ``held``, all shares from one set of weights."""
     from metaopt_tpu.models.moe import DroplessMoE
+
+    act = {"relu": jax.nn.relu, "silu": jax.nn.silu}[activation]
 
     key = jax.random.PRNGKey(seed)
     x = jax.random.normal(key, (1, t, D))
@@ -212,11 +214,11 @@ def expert_layer(held, logits_bias=None, seed=3, t=40):
     full = nn.meta.unbox(whole.init(key, x, logits)["params"])
     first, count = held
     mine = {k: v[first:first + count] for k, v in full.items()}
-    y, state = DroplessMoE(D, F, E, TOPK, held).apply(
+    y, state = DroplessMoE(D, F, E, TOPK, held, activation).apply(
         {"params": mine}, x, logits, mutable=["moe_stats"])
-    ref = ref_moe(x[0], logits[0], full_as_ref(mine), held)
+    ref = ref_moe(x[0], logits[0], full_as_ref(mine), held, act)
     return y[0], state["moe_stats"], ref, ref_moe(
-        x[0], logits[0], full_as_ref(full), (0, E))
+        x[0], logits[0], full_as_ref(full), (0, E), act)
 
 
 def full_as_ref(full):
@@ -232,10 +234,14 @@ def test_a_share_gives_its_own_experts_part(held):
     assert np.linalg.norm(y - ref) <= 0.02 * max(np.linalg.norm(ref), 1e-6)
 
 
-def test_the_shares_add_up_to_the_uncut_layer():
-    """16 experts, top 3, four shares of 4: the four partial outputs sum to
-    what the uncut reference gives for the whole layer."""
-    parts = [expert_layer(held) for held in SHARES]
+@pytest.mark.parametrize("shares, activation", [
+    (SHARES, "relu"), ([(first, 2) for first in range(0, E, 2)], "silu")],
+    ids=["four-shares-relu", "eight-shares-silu"])
+def test_the_shares_add_up_to_the_uncut_layer(shares, activation):
+    """16 experts, top 3, four shares of 4 (gated ReLU) or eight of 2
+    (gated SiLU): the partial outputs sum to what the uncut reference
+    gives for the whole layer."""
+    parts = [expert_layer(held, activation=activation) for held in shares]
     total = sum(p[0] for p in parts)
     uncut = parts[0][3]
     assert np.linalg.norm(total - uncut) <= 0.02 * np.linalg.norm(uncut)
@@ -553,8 +559,8 @@ def test_train_lm_reports_the_routing_s_counts_once(tmp_path):
                             "products": "ragged_dot",
                             "buffer_rows": 2 * S * TOPK,
                             "chunk_rows": 2 * S * TOPK}
-    assert setup["remat"] == {"blocks": 2, "keeps": ["attention.out",
-                                                     "attention.lse"]}
+    assert setup["remat"] == {"blocks": 2, "keeps": [
+        "attention.out", "attention.lse", "attention.selected"]}
 
 
 def test_the_trial_hands_out_its_loop_step_by_step():

@@ -210,7 +210,9 @@ def _under(names, scope):
 
 
 @pytest.mark.parametrize("scope", [
-    s for s in trace.SCOPES if s != "eval" and not s.startswith("moe")])
+    s for s in trace.SCOPES if s != "eval" and not s.startswith("moe")
+    # a decoder's selected-attention layers': tests/unit/test_lm_selected.py
+    and s not in ("attention.index", "attention.select")])
 def test_every_scope_names_ops_of_the_train_step(lowered_op_names, scope):
     assert _under(lowered_op_names, scope), scope
 
@@ -323,7 +325,8 @@ def test_trial_setup_s_span_says_which_attention_route_the_steps_take(
 
 @pytest.mark.parametrize("blocks, line", [
     (0, None),
-    (4, "trial T-9: remat: 4 blocks keep attention.out, attention.lse\n")])
+    (4, "trial T-9: remat: 4 blocks keep attention.out, attention.lse, "
+        "attention.selected\n")])
 def test_trial_setup_s_span_says_what_a_rematerialised_block_keeps(
         capsys, blocks, line):
     """``attrs["remat"]``: the blocks run again in the backward pass and
