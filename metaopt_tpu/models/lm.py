@@ -457,13 +457,15 @@ def _selection(sa: Optional[Dict[str, Any]]):
             int(sa["topk"]))
 
 
-def describe_pattern(hparams: Dict[str, Any], route: str,
-                     tokens: int) -> Dict[str, Any]:
+def describe_pattern(hparams: Dict[str, Any], route: str, tokens: int,
+                     seq_len: Optional[int] = None) -> Dict[str, Any]:
     """What ``trial.setup``'s span says of a description with a layer
-    pattern ({} without one), for steps of ``tokens`` tokens on attention
-    route ``route``: for each kind of layer the route and the form of its
-    mask, and the expert layers' share, the product they take, the rows
-    of their buffers and the rows a trip of the routing's loops moves."""
+    pattern ({} without one), for steps of ``tokens`` tokens in rows of
+    ``seq_len`` on attention route ``route``: for each kind of layer the
+    route and the form of its mask, for a layer that selects its keys the
+    form its index scores take at that length, and the expert layers'
+    share, the product they take, the rows of their buffers and the rows a
+    trip of the routing's loops moves."""
     from metaopt_tpu.models.moe import (grouped_matmul_impl,
                                         routing_chunk_rows)
 
@@ -484,6 +486,13 @@ def describe_pattern(hparams: Dict[str, Any], route: str,
 
     out = {"attention_layers": {kind: {"route": route, "mask": mask_of(kind)}
                                 for kind in p.kinds()}}
+    if p.selection is not None and seq_len:
+        from metaopt_tpu.ops.sparse_index import scores_of_a_row
+
+        for kind, said in out["attention_layers"].items():
+            if kind.startswith("selected"):
+                said["index_scores"] = scores_of_a_row(seq_len,
+                                                       p.selection[1])
     if p.n_experts:
         out["moe"] = {"routed_over": p.n_experts, "top_k": p.top_k,
                       "held": list(p.experts_held),
@@ -684,7 +693,8 @@ class LMTrial:
         self.mesh, tx = trial_setup(
             {**hparams, "dropout": self.model.dropout}, mesh, tp, sp, ep,
             steps, describe=functools.partial(
-                describe_pattern, hparams, tokens=batch_size * seq_len),
+                describe_pattern, hparams, tokens=batch_size * seq_len,
+                seq_len=seq_len),
             remat_blocks=self.model.n_layers if self.model.remat else 0)
         first, vocab = self.model.held_vocab()
         kd, self._kstep = jax.random.split(jax.random.PRNGKey(seed))

@@ -28,13 +28,31 @@ a tie). Rows come in blocks of ``ROWS`` queries, a head at a time, so that
 what is live is two (ROWS, S) arrays and not the (S, 16, S) scores of all
 heads (17 GB at 16 384); a block is scored against the keys up to the end
 of its quarter of the rows, not beyond (62 % of the square).
+
+**How a block is scored.** Two forms of one sum, and one rule that names
+which runs (:func:`index_scores_route`, from the backend and the shapes
+alone). The plain form is a loop of XLA's products over the heads. On the
+TPU, where a tile divides the block, it is one Pallas kernel,
+``index_scores``: a program owns a (query tile, key tile) of the scores,
+adds the heads' ``w relu(q . k)`` into it on the chip and writes it once;
+a key tile that starts after the query tile's last row is written as zeros
+(:func:`select_top_k` masks what lies after a query before it reads a
+score). A float32 product at precision highest is the six bfloat16 products
+``hh + hm + mh + mm + hl + lh`` of the operands' three parts (``x = hi + mid
++ lo``); the kernel's operands are those parts laid side by side along the
+contraction (:func:`_stacked`), so that one bfloat16 product with float32
+accumulation, 6 x 64 = 384 deep, makes all six at the MXU's full depth.
 """
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from metaopt_tpu.ops.attention import REMAT_KEEPS, SelectedMask
 from metaopt_tpu.ops.selected_attention import selected_block
@@ -45,9 +63,39 @@ _HI = jax.lax.Precision.HIGHEST
 ROWS = 1024
 
 
-def index_scores(q, k, w):
+#: the kernel's tile of scores, (queries, keys)
+TILE = 512
+#: which of an operand's (hi, mid, lo) parts stand side by side, so that
+#: the stacked queries times the stacked keys are hh + mm + hm + mh + hl + lh
+_Q_PARTS, _K_PARTS = (0, 1, 0, 1, 0, 2), (0, 1, 1, 0, 2, 0)
+
+
+def index_scores_route(rows: int, keys: int, width: int) -> dict:
+    """Which form of :func:`index_scores` scores ``rows`` queries of index
+    heads ``width`` wide against ``keys`` keys: the one place that decides
+    (:func:`index_scores` and ``trial.setup``'s span both ask). On a TPU,
+    where the tile divides both extents and the six parts of a head fill
+    whole passes of the MXU's depth, the Pallas kernel, with its tiles and
+    the depth of a pass; elsewhere XLA's products."""
+    if (jax.default_backend() == "tpu" and rows % TILE == 0
+            and keys % TILE == 0 and 6 * width % 128 == 0):
+        return {"route": "pallas", "tiles": [TILE, TILE], "depth": 128}
+    return {"route": "xla"}
+
+
+def index_scores(q, k, w, first_row=0):
     """``I`` (R, E) of query rows ``q`` (R, H, D), ``w`` (R, H) against
-    keys ``k`` (E, D), causal or not: one head's (R, E) product at a time."""
+    keys ``k`` (E, D), by the form :func:`index_scores_route` names.
+    ``first_row`` is the first query's position among the keys: what lies
+    after a query's own position is 0 or its score, the caller's to mask."""
+    if index_scores_route(q.shape[0], k.shape[0], q.shape[2])[
+            "route"] == "pallas":
+        return _scores_pallas(q, k, w, first_row)
+    return _scores_xla(q, k, w)
+
+
+def _scores_xla(q, k, w):
+    """One head's (R, E) product at a time, the accumulator an array."""
     heads = jnp.moveaxis(q, 1, 0)                           # (H, R, D)
     weights = w.T                                           # (H, R)
 
@@ -60,6 +108,84 @@ def index_scores(q, k, w):
         jnp.zeros((q.shape[0], k.shape[0]), jnp.float32))
     # -0.0 and 0.0 are one score (all heads clipped): one bit pattern
     return jnp.where(scores == 0, 0.0, scores)
+
+
+def _stacked(x, parts):
+    """float32 (..., D) -> bfloat16 (..., 6 D): the three bfloat16 parts
+    of ``x`` that a product at precision highest multiplies, in the order
+    ``parts``. ``reduce_precision`` rounds as the conversion does and no
+    pass of the compiler may take it for the identity."""
+    hi = jax.lax.reduce_precision(x, 8, 7)
+    mid = jax.lax.reduce_precision(x - hi, 8, 7)
+    lo = x - hi - mid
+    return jnp.concatenate([(hi, mid, lo)[i] for i in parts],
+                           axis=-1).astype(jnp.bfloat16)
+
+
+def _last_seen(i, first_ref, tq: int, tk: int):
+    """The last key tile that a row of query tile ``i`` can see."""
+    return (first_ref[0] + (i + 1) * tq - 1) // tk
+
+
+def _index_scores_kernel(first_ref, q_ref, k_ref, w_ref, o_ref):
+    """One (query tile, key tile) program: the heads' sum stays here.
+
+    Shapes in VMEM: q (Tq, H * 6 D) bfloat16, head after head; k (6 D, Tk)
+    bfloat16; w (Tq, H) float32; o (Tq, Tk) float32. ``first_ref`` (1,)
+    int32, in SMEM: the block's first row.
+    """
+    tq, tk = o_ref.shape
+    depth = k_ref.shape[0]
+    seen = pl.program_id(1) <= _last_seen(pl.program_id(0), first_ref, tq,
+                                          tk)
+
+    @pl.when(seen)
+    def _():
+        k = k_ref[...]
+        acc = jnp.zeros((tq, tk), jnp.float32)
+        for j in range(w_ref.shape[1]):
+            s = jnp.dot(q_ref[:, j * depth:(j + 1) * depth], k,
+                        preferred_element_type=jnp.float32)
+            acc = acc + w_ref[:, j:j + 1] * jnp.maximum(s, 0.0)
+        # -0.0 and 0.0 are one score (all heads clipped): one bit pattern
+        o_ref[...] = jnp.where(acc == 0, 0.0, acc)
+
+    @pl.when(jnp.logical_not(seen))
+    def _():
+        o_ref[...] = jnp.zeros((tq, tk), jnp.float32)
+
+
+# jitted, so that the call sites of one shape (a group of blocks in every
+# layer) share one trace and one lowering of the Mosaic kernel
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _scores_pallas(q, k, w, first_row, interpret: bool = False):
+    """:func:`index_scores` by the kernel; extents as the route asks."""
+    r, h, d = q.shape
+    e, depth = k.shape[0], 6 * d
+    tq = tk = TILE
+    seen = lambda i, j, first: (  # noqa: E731  no copy of an unseen tile
+        0, jnp.minimum(j, _last_seen(i, first, tq, tk)))
+    # the blocks twice (w's lanes padded to a tile), a head's product and
+    # the sum beside them
+    vmem = 2 * (2 * (tq * h * depth + depth * tk) + 4 * (tq * 128 + tq * tk)) \
+        + 8 * 4 * tq * tk
+    return pl.pallas_call(
+        _index_scores_kernel, name="index_scores",
+        out_shape=jax.ShapeDtypeStruct((r, e), jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(r // tq, e // tk),
+            in_specs=[
+                pl.BlockSpec((tq, h * depth), lambda i, j, first: (i, 0)),
+                pl.BlockSpec((depth, tk), seen),
+                pl.BlockSpec((tq, h), lambda i, j, first: (i, 0))],
+            out_specs=pl.BlockSpec((tq, tk), lambda i, j, first: (i, j))),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=vmem),
+        interpret=interpret,
+    )(jnp.asarray(first_row, jnp.int32).reshape(1),
+      _stacked(q, _Q_PARTS).reshape(r, h * depth),
+      _stacked(k, _K_PARTS).T, w)
 
 
 def _ordered(x):
@@ -118,6 +244,20 @@ def pack(selected, block: int):
         r, e // 32).T
 
 
+def _blocks(block: int, s_p: int) -> tuple:
+    """(query rows a block, rows a group of blocks) of ``s_p`` positions
+    packed in tiles of ``block``: a group's extent is every later one's
+    divisor."""
+    rows = ROWS if ROWS % block == 0 and s_p % ROWS == 0 else block
+    return rows, rows * -(-s_p // rows // 4)
+
+
+def scores_of_a_row(size: int, width: int) -> dict:
+    """What :func:`index_scores_route` says of the blocks that a row of
+    ``size`` tokens is scored in, index heads ``width`` wide."""
+    return index_scores_route(*_blocks(*selected_block(size)), width)
+
+
 def _one_row(q, k, w, top_k: int, block: int, s_p: int):
     """(s_p / 32, s_p) int32: one batch row's packed selection. The blocks
     of ``rows`` queries come in up to four groups; a group's blocks all see
@@ -128,15 +268,14 @@ def _one_row(q, k, w, top_k: int, block: int, s_p: int):
     grow = lambda x: jnp.pad(  # noqa: E731
         x, ((0, s_p - s),) + ((0, 0),) * (x.ndim - 1))
     q, k, w = grow(q), grow(k), grow(w)
-    rows = ROWS if ROWS % block == 0 and s_p % ROWS == 0 else block
-    group = rows * -(-s_p // rows // 4)
+    rows, group = _blocks(block, s_p)
 
     def block_of(start, hi):
         """(hi / 32, rows): queries start .. start + rows - 1, keys < hi."""
         with trace.scope("attention.index"):
             scores = index_scores(
                 jax.lax.dynamic_slice_in_dim(q, start, rows), k[:hi],
-                jax.lax.dynamic_slice_in_dim(w, start, rows))
+                jax.lax.dynamic_slice_in_dim(w, start, rows), start)
         with trace.scope("attention.select"):
             chosen = select_top_k(scores, start, top_k)
             # a padded query selects nothing (a padded key lies after

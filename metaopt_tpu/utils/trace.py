@@ -344,6 +344,14 @@ def main(argv: List[str]) -> int:
     return 0
 
 
+def _scores_form(said: dict) -> str:
+    """``ops/sparse_index.py::index_scores_route``'s answer in words."""
+    if said["route"] != "pallas":
+        return said["route"]
+    tq, tk = said["tiles"]
+    return f"pallas, tiles {tq} x {tk}, passes {said['depth']} deep"
+
+
 def print_routes(recs: List[dict]) -> None:
     """A line a trial: which attention route its steps took, as its
     ``trial.setup`` span has it (ops/attention.attention_route, the one
@@ -361,8 +369,11 @@ def print_routes(recs: List[dict]) -> None:
                   f"training (dropout {route['dropout']}), {route['eval']} "
                   "in evaluation")
             for kind, how in attrs.get("attention_layers", {}).items():
+                scores = how.get("index_scores")
                 print(f"trial {r['trial']}: {kind} layers: {how['route']}, "
-                      f"mask by {how['mask']}")
+                      f"mask by {how['mask']}" + (
+                          f", index scores by {_scores_form(scores)}"
+                          if scores else ""))
             remat = attrs.get("remat")
             if remat:
                 print(f"trial {r['trial']}: remat: {remat['blocks']} blocks "

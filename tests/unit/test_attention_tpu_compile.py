@@ -130,12 +130,20 @@ def test_a_selected_mask_s_kernels_compile_for_a_v5e(one_chip, case):
     assert "sparse_fwd" in text and "sparse_bwd" in text
 
 
-def test_the_selection_compiles_for_a_v5e_at_the_cell_s_length(one_chip):
+@pytest.mark.parametrize("backend, kernels", [("tpu", 4), ("cpu", 0)])
+def test_the_selection_compiles_for_a_v5e_at_the_cell_s_length(
+        one_chip, monkeypatch, backend, kernels):
     """16384 rows of 16 x 64 index heads, top 2048: four groups of blocks,
     a fraction of a GB of temporaries (the scores of all heads at once
-    would be 17 GB)."""
+    would be 17 GB). As the chip takes it the scores are the Pallas kernel,
+    one call a group (a (512, 6144) tile of stacked queries, a (384, 512)
+    tile of stacked keys and the heads' sum pass Mosaic inside the limit it
+    is given); the plain twin compiles there too."""
+    import re
+
     from metaopt_tpu.ops import sparse_index
 
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
     s = 16384
     shape = lambda *dims: jax.ShapeDtypeStruct(  # noqa: E731
         dims, jnp.float32, sharding=one_chip)
@@ -143,6 +151,56 @@ def test_the_selection_compiles_for_a_v5e_at_the_cell_s_length(one_chip):
         lambda q, k, w: sparse_index.select(q, k, w, 2048)[0].bits).lower(
             shape(1, s, 16, 64), shape(1, s, 64), shape(1, s, 16)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes < 1 << 30
+    assert len(re.findall(
+        r'custom_call_target="tpu_custom_call".*/index_scores/pallas_call',
+        compiled.as_text())) == kernels
+
+
+@pytest.mark.parametrize("cell", ["transformer-base-wmt",
+                                  "smallthinker-21b-a3b-ep4"])
+def test_the_cells_without_an_indexer_trace_no_line_of_it(one_chip,
+                                                          monkeypatch, cell):
+    """The two configurations that stand build and lower their loss's
+    gradient, as the chip routes it, without ``ops/sparse_index.py`` ever
+    being imported: what changes there cannot reach their programs."""
+    import json
+    import os
+    import sys
+
+    from flax import linen as nn
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    for name in [n for n in sys.modules if n.endswith("ops.sparse_index")]:
+        monkeypatch.delitem(sys.modules, name)
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "chipbench", "configs", cell + ".json")) as f:
+        config = json.load(f)
+    key = jax.random.PRNGKey(0)
+    if cell.startswith("transformer"):
+        from metaopt_tpu.models import transformer as zoo
+
+        model = zoo.make_model({**config["script_args"], **config["hparams"],
+                                "n_layers": 1})
+        tokens = jnp.zeros((2, 128), jnp.int32)
+        init = lambda: model.init(key, tokens, tokens, train=False)  # noqa: E731,E501
+        loss = lambda p, t: zoo.loss_fn(model, p, (t, t), key)  # noqa: E731
+    else:
+        from chipbench import lm_config
+        from metaopt_tpu.models import lm as zoo
+
+        model = zoo.make_lm({**lm_config.description(config),
+                             "num_hidden_layers": 2})
+        tokens = jnp.zeros((1, 513), jnp.int32)
+        init = lambda: model.init(key, tokens[:, :-1], train=False)  # noqa: E731,E501
+        loss = lambda p, t: zoo.lm_loss_fn(model, p, t, key)  # noqa: E731
+    on_chip = lambda x: jax.ShapeDtypeStruct(  # noqa: E731
+        x.shape, x.dtype, sharding=one_chip)
+    params = jax.tree.map(on_chip, jax.eval_shape(
+        lambda: nn.meta.unbox(init()["params"])))
+    text = jax.jit(jax.grad(loss)).lower(params, on_chip(tokens)).as_text()
+    assert "tpu_custom_call" in text      # the route the chip takes
+    assert not [n for n in sys.modules if n.endswith("ops.sparse_index")]
 
 
 @pytest.mark.parametrize("how, forwards", [("kept", 2), ("bare", 4)])
