@@ -1,0 +1,11 @@
+"""``flash_fwd_roofline`` in a hybrid linear-attention decoder's cell, read
+by that metric's own reader: ``flash_fwd``'s calls against the causal pairs
+of the full layers (15 query heads on 15 K/V heads here). An accepted
+metric's list of cells takes no new cell, so the cell reports it under a
+name of its own."""
+
+from chipbench.run import _reader
+
+
+def read(records):
+    return _reader("flash_fwd_roofline").read(records)

@@ -30,10 +30,18 @@
   choice is piecewise constant, so the next-token loss sends it no
   gradient: its parameters are a frozen part of the trial, outside the
   gradient tree and outside AdamW (:func:`split_frozen`).
+  With ``layer_types`` (the Olmo hybrid family's word) a layer is
+  *linear*, a :class:`LinearAttention` mixer (the gated delta rule of
+  ops/linear_attention.py: a recurrence, no positions), or full attention
+  with RMS norms of q and k over the projected width; the block has that
+  family's norm placement, x + norm(mixer(x)) then x + norm(ffn(x)), and
+  the feed-forward is gated by ``hidden_act``.
   The chip's share of a deployment is part of the description too:
-  ``experts_held`` = (first, count) of the routed experts and
+  ``experts_held`` = (first, count) of the routed experts,
   ``vocab_held`` = (first, count) of the vocabulary's rows (ids are drawn
-  from that slice, and logits and loss are over it).
+  from that slice, and logits and loss are over it) and ``heads_held`` =
+  (first, count) of ``num_attention_heads``: every kind of head is built
+  in that proportion.
 
 ``MHA`` / :class:`GroupedAttention` call ops/attention.attend, whose one
 rule (``attention_route``) names the route from the mesh, the backend and
@@ -74,7 +82,7 @@ from metaopt_tpu.models.transformer import (
     rematerialised,
     sharded_init,
 )
-from metaopt_tpu.ops.attention import CausalMask, attend
+from metaopt_tpu.ops.attention import REMAT_KEEPS, CausalMask, attend
 from metaopt_tpu.parallel.sharding import with_mesh_partitioning
 from metaopt_tpu.utils import trace
 
@@ -106,9 +114,19 @@ def rope(x, theta: float):
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
 
 
-def layer_kind(sliding: bool, rotary: bool, selected: bool = False) -> str:
-    return ("selected" if selected else "window" if sliding else "global") \
-        + ("-rope" if rotary else "-nope")
+#: the kinds of layer: (name, has positions to say). A layer is of the
+#: first kind it can be said to be, in :func:`layer_kind`'s order.
+KINDS = (("linear", False), ("selected", True), ("window", True),
+         ("global", True))
+
+
+def layer_kind(sliding: bool, rotary: bool, selected: bool = False,
+               linear: bool = False) -> str:
+    for (name, positions), is_it in zip(KINDS,
+                                        (linear, selected, sliding, True)):
+        if is_it:
+            return name + (("-rope" if rotary else "-nope") if positions
+                           else "")
 
 
 class Indexer(nn.Module):
@@ -155,7 +173,9 @@ class GroupedAttention(nn.Module):
     """Causal self attention with fewer K/V heads than query heads, no
     bias; rotary or no positions; a window, none, or the keys an
     :class:`Indexer` selects (``selection``: its heads, their width and
-    ``topk``); RMS norms of q and k over a head's width or none."""
+    ``topk``); RMS norms of q and k over a head's width, over the
+    projected width (``qk_norm_whole``: the heads held here together, one
+    scale vector of heads x width) or none."""
 
     d_model: int
     n_heads: int
@@ -165,6 +185,7 @@ class GroupedAttention(nn.Module):
     rope_theta: Optional[float]
     qk_norm: Optional[float] = None     # the norms' eps
     selection: Optional[Tuple[int, int, int]] = None
+    qk_norm_whole: bool = False
 
     @nn.compact
     @trace.scope("attention")
@@ -180,8 +201,10 @@ class GroupedAttention(nn.Module):
         q, k, v = (proj("q", self.n_heads)(x), proj("k", self.n_kv_heads)(x),
                    proj("v", self.n_kv_heads)(x))
         if self.qk_norm is not None:
-            q = RMSNorm(self.qk_norm, name="q_norm")(q)
-            k = RMSNorm(self.qk_norm, name="k_norm")(k)
+            whole = lambda y: y.reshape(  # noqa: E731
+                *y.shape[:2], -1) if self.qk_norm_whole else y
+            q = RMSNorm(self.qk_norm, name="q_norm")(whole(q)).reshape(q.shape)
+            k = RMSNorm(self.qk_norm, name="k_norm")(whole(k)).reshape(k.shape)
         if self.rope_theta is not None:
             q, k = rope(q, self.rope_theta), rope(k, self.rope_theta)
         q = (q / math.sqrt(self.head_dim)).astype(jnp.bfloat16)
@@ -194,10 +217,12 @@ class GroupedAttention(nn.Module):
 
 
 class GatedFeedForward(nn.Module):
-    """relu(x W_gate) * (x W_up) W_down, no bias."""
+    """(act(x W_gate) * (x W_up)) W_down, no bias; ``activation`` by name,
+    as ``DroplessMoE`` takes it."""
 
     d_model: int
     d_ff: int
+    activation: str = "relu"
 
     @nn.compact
     @trace.scope("ffn")
@@ -206,9 +231,22 @@ class GatedFeedForward(nn.Module):
             n, dtype=jnp.bfloat16, name=name, use_bias=False,
             kernel_init=_pinit(True, axes))
         x = x.astype(jnp.bfloat16)
-        h = nn.relu(dense("gate", self.d_ff, (None, "tp"))(x)) \
+        act = {"relu": nn.relu, "silu": nn.silu}[self.activation]
+        h = act(dense("gate", self.d_ff, (None, "tp"))(x)) \
             * dense("up", self.d_ff, (None, "tp"))(x)
         return dense("down", self.d_model, ("tp", None))(h)
+
+
+@dataclasses.dataclass(frozen=True)
+class LinearSpec:
+    """What a description says of its linear-attention layers."""
+
+    heads: int              # held here
+    of: int                 # the layer's published count
+    key_dim: int
+    value_dim: int
+    conv: int               # taps of the short convolutions
+    neg_eigval: bool        # beta in (0, 2), not (0, 1)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -234,18 +272,34 @@ class Pattern:
     #: (index heads, their width, top k) of ``sa_config``: every layer
     #: attends to the keys its indexer selects
     selection: Optional[Tuple[int, int, int]] = None
+    #: the Olmo hybrid family's layer: which layers are linear (none where
+    #: empty) and what of; the norms on the branches' outputs; q/k norms
+    #: over the projected width; (first, count) of the heads held
+    linear_layers: Tuple[bool, ...] = ()
+    linear: Optional[LinearSpec] = None
+    norm_after: bool = False
+    qk_norm_whole: bool = False
+    heads_held: Optional[Tuple[int, int]] = None
+
+    def is_linear(self, i: int) -> bool:
+        return bool(self.linear_layers) and self.linear_layers[i]
+
+    def kind(self, i: int) -> str:
+        return layer_kind(*self.layers[i], self.selection is not None,
+                          self.is_linear(i))
 
     def kinds(self):
         """The distinct layer kinds, in the pattern's order."""
-        return list(dict.fromkeys(
-            layer_kind(*l, self.selection is not None) for l in self.layers))
+        return list(dict.fromkeys(map(self.kind, range(len(self.layers)))))
 
 
 class PatternBlock(nn.Module):
     """x + attention(norm(x)), then + experts(norm(.)) routed by logits
     read from the FIRST norm's output, before attention, or (the pattern's
-    ``router_after_attention``) from the second's. The residual stream is
-    float32."""
+    ``router_after_attention``) from the second's; with the pattern's
+    ``norm_after``, x + norm(mixer(x)) then + norm(ffn(.)), the mixer a
+    :class:`LinearAttention` where the layer is ``linear``. The residual
+    stream is float32."""
 
     d_model: int
     n_heads: int
@@ -253,6 +307,7 @@ class PatternBlock(nn.Module):
     pattern: Pattern
     sliding: bool
     rotary: bool
+    linear: bool = False
 
     @nn.compact
     def __call__(self, x):
@@ -269,14 +324,23 @@ class PatternBlock(nn.Module):
                     kernel_init=with_mesh_partitioning(
                         nn.initializers.lecun_normal(), (None, None)))(read)
 
-        n = RMSNorm(p.rms_eps, name="norm_in")(x)
-        if p.n_experts and not p.router_after_attention:
-            logits = router(n)
-        x = x + GroupedAttention(
+        attention = lambda: GroupedAttention(  # noqa: E731
             self.d_model, self.n_heads, p.n_kv_heads, p.head_dim,
             p.window if self.sliding else None,
             p.rope_theta if self.rotary else None,
-            p.rms_eps if p.qk_norm else None, p.selection, name="attn")(n)
+            p.rms_eps if p.qk_norm else None, p.selection, p.qk_norm_whole,
+            name="attn")
+        if p.norm_after:
+            mixer = LinearAttention(self.d_model, p.linear, p.rms_eps,
+                                    name="linear") if self.linear \
+                else attention()
+            x = x + RMSNorm(p.rms_eps, name="norm_mixer")(mixer(x))
+            return x + RMSNorm(p.rms_eps, name="norm_ffn")(GatedFeedForward(
+                self.d_model, self.d_ff, p.activation, name="mlp")(x))
+        n = RMSNorm(p.rms_eps, name="norm_in")(x)
+        if p.n_experts and not p.router_after_attention:
+            logits = router(n)
+        x = x + attention()(n)
         m = RMSNorm(p.rms_eps, name="norm_post")(x)
         if p.n_experts:
             from metaopt_tpu.models.moe import DroplessMoE
@@ -286,7 +350,8 @@ class PatternBlock(nn.Module):
             return x + DroplessMoE(self.d_model, p.expert_d_ff, p.n_experts,
                                    p.top_k, p.experts_held, p.activation,
                                    name="experts")(m, logits)
-        return x + GatedFeedForward(self.d_model, self.d_ff, name="mlp")(m)
+        return x + GatedFeedForward(self.d_model, self.d_ff, p.activation,
+                                    name="mlp")(m)
 
 
 class DecoderOnlyLM(nn.Module):
@@ -369,13 +434,14 @@ class DecoderOnlyLM(nn.Module):
             rows, self.d_model, dtype=jnp.bfloat16, name=name,
             embedding_init=nn.with_partitioning(
                 nn.initializers.normal(size), (None, None)))
-        block_cls = (rematerialised(PatternBlock) if self.remat
-                     else PatternBlock)
+        block_cls = (rematerialised(PatternBlock, keeps=remat_keeps(p))
+                     if self.remat else PatternBlock)
         with trace.scope("embed"):
             x = table("embed")(tokens - first).astype(jnp.float32)
+        heads = p.heads_held[1] if p.heads_held else self.n_heads
         for i, (sliding, rotary) in enumerate(p.layers):
-            x = block_cls(self.d_model, self.n_heads, self.d_ff, p, sliding,
-                          rotary, name=f"h{i}")(x)
+            x = block_cls(self.d_model, heads, self.d_ff, p, sliding,
+                          rotary, p.is_linear(i), name=f"h{i}")(x)
         x = RMSNorm(p.rms_eps, name="norm_f")(x)
         head = table("head", self.d_model ** -0.5)
         if features:
@@ -395,7 +461,12 @@ _PUBLISHED = {"hidden_size": "d_model", "num_attention_heads": "n_heads",
               # the Qwen3-MoE family's words for what pattern_of reads
               "num_experts": "moe_num_primary_experts",
               "num_experts_per_tok": "moe_num_active_primary_experts",
-              "moe_intermediate_size": "moe_ffn_hidden_size"}
+              "moe_intermediate_size": "moe_ffn_hidden_size",
+              # the Olmo hybrid family's
+              "intermediate_size": "d_ff"}
+
+#: a published ``layer_types`` entry -> is the layer linear?
+_LAYER_TYPES = {"linear_attention": True, "full_attention": False}
 
 
 def _own_names(hparams: Dict[str, Any]) -> Dict[str, Any]:
@@ -411,38 +482,82 @@ def pattern_of(h: Dict[str, Any]) -> Optional[Pattern]:
     read up to ``n_layers`` (a cut in depth keeps the leading layers);
     without them every layer is global and rotary. ``num_experts`` (the
     Qwen3-MoE family's word) brings that family's q/k norms and router
-    placement, ``sa_config`` the selected attention."""
-    if not {"rope_layout", "sliding_window_layout", "sa_config"} & set(h):
+    placement, ``sa_config`` the selected attention, ``layer_types`` (the
+    Olmo hybrid family's) that family's block, its linear layers and, with
+    ``rope_parameters.rope_theta`` null, no positions anywhere."""
+    if not {"rope_layout", "sliding_window_layout", "sa_config",
+            "layer_types"} & set(h):
         return None
     n_layers = int(h.get("n_layers", 6))
-    rotary = list(h.get("rope_layout") or [1] * n_layers)
+    types = h.get("layer_types")
+    unknown = sorted(set(types or ()) - set(_LAYER_TYPES))
+    if unknown:
+        raise ValueError(f"layer_types names {unknown}; known: "
+                         f"{sorted(_LAYER_TYPES)}")
+    theta = (h.get("rope_parameters") or h).get("rope_theta", 10000.0)
+    rotary = list(h.get("rope_layout") or [int(theta is not None)] * n_layers)
     sliding = list(h.get("sliding_window_layout") or [0] * n_layers)
-    if min(len(rotary), len(sliding)) < n_layers:
-        raise ValueError(f"the layouts name {len(rotary)} and {len(sliding)} "
-                         f"layers, the model has {n_layers}")
+    if min(len(rotary), len(sliding), len(types or rotary)) < n_layers:
+        raise ValueError(f"the layouts name {len(rotary)}, {len(sliding)} "
+                         f"and {len(types or rotary)} layers, the model has "
+                         f"{n_layers}")
     n_experts = int(h.get("moe_num_primary_experts", 0))
     vocab = int(h.get("vocab", 1000))
     held = lambda key, whole: tuple(  # noqa: E731
         int(v) for v in h.get(key) or (0, whole))
+    n_heads = int(h.get("n_heads", 8))
+    heads_held = held("heads_held", n_heads)
+    share = lambda heads: int(heads) * heads_held[1] // n_heads  # noqa: E731
+    linear_layers = tuple(_LAYER_TYPES[t] for t in (types or ())[:n_layers])
     return Pattern(
         layers=tuple((bool(s), bool(r)) for s, r in
                      zip(sliding[:n_layers], rotary[:n_layers])),
-        n_kv_heads=int(h.get("num_key_value_heads", h.get("n_heads", 8))),
-        head_dim=int(h.get("head_dim", int(h.get("d_model", 512))
-                           // int(h.get("n_heads", 8)))),
+        n_kv_heads=share(h.get("num_key_value_heads", n_heads)),
+        head_dim=int(h.get("head_dim") or int(h.get("d_model", 512))
+                     // n_heads),
         window=int(h.get("sliding_window_size", 4096)),
-        rope_theta=float(h.get("rope_theta", 10000.0)),
+        rope_theta=float(10000.0 if theta is None else theta),
         rms_eps=float(h.get("rms_norm_eps", 1e-6)),
         n_experts=n_experts,
         top_k=int(h.get("moe_num_active_primary_experts", 1)),
         expert_d_ff=int(h.get("moe_ffn_hidden_size", h.get("d_ff", 2048))),
         experts_held=held("experts_held", n_experts),
         vocab_held=held("vocab_held", vocab),
-        qk_norm="num_experts" in h,
+        qk_norm="num_experts" in h or types is not None,
         router_after_attention="num_experts" in h,
         activation=str(h.get("hidden_act", "relu")),
         selection=_selection(h.get("sa_config")),
+        linear_layers=linear_layers,
+        linear=_linear(h, share) if any(linear_layers) else None,
+        norm_after=types is not None,
+        qk_norm_whole=types is not None,
+        heads_held=heads_held if "heads_held" in h else None,
     )
+
+
+def _linear(h: Dict[str, Any], share) -> LinearSpec:
+    """The linear layers of a description in the Olmo hybrid family's
+    words, ``share`` of each kind of head held."""
+    heads = int(h["linear_num_value_heads"])
+    if int(h.get("linear_num_key_heads", heads)) != heads:
+        raise ValueError("a linear layer has as many key heads as value "
+                         f"heads here, not {h['linear_num_key_heads']} and "
+                         f"{heads}")
+    return LinearSpec(
+        heads=share(heads), of=heads, key_dim=int(h["linear_key_head_dim"]),
+        value_dim=int(h["linear_value_head_dim"]),
+        conv=int(h.get("linear_conv_kernel_dim", 4)),
+        neg_eigval=bool(h.get("linear_allow_neg_eigval", False)))
+
+
+def remat_keeps(p: Optional[Pattern]) -> Tuple[str, ...]:
+    """The names a rematerialised block of the model keeps: the attention
+    kernels', and the scan's where a layer is linear."""
+    if p is None or p.linear is None:
+        return REMAT_KEEPS
+    from metaopt_tpu.ops import linear_attention
+
+    return REMAT_KEEPS + linear_attention.REMAT_KEEPS
 
 
 def _selection(sa: Optional[Dict[str, Any]]):
@@ -485,7 +600,16 @@ def describe_pattern(hparams: Dict[str, Any], route: str, tokens: int,
             else ": causal")
 
     out = {"attention_layers": {kind: {"route": route, "mask": mask_of(kind)}
-                                for kind in p.kinds()}}
+                                for kind in p.kinds() if kind != "linear"}}
+    if p.linear is not None:
+        from metaopt_tpu.ops.linear_attention import linear_attention_route
+
+        spec = p.linear
+        out["attention_layers"]["linear"] = {
+            **linear_attention_route(),
+            "layers": [i for i, lin in enumerate(p.linear_layers) if lin],
+            "heads": [spec.heads, spec.of], "key_dim": spec.key_dim,
+            "value_dim": spec.value_dim, "conv": spec.conv}
     if p.selection is not None and seq_len:
         from metaopt_tpu.ops.sparse_index import scores_of_a_row
 
@@ -695,7 +819,8 @@ class LMTrial:
             steps, describe=functools.partial(
                 describe_pattern, hparams, tokens=batch_size * seq_len,
                 seq_len=seq_len),
-            remat_blocks=self.model.n_layers if self.model.remat else 0)
+            remat_blocks=self.model.n_layers if self.model.remat else 0,
+            remat_keeps=remat_keeps(self.model.pattern))
         first, vocab = self.model.held_vocab()
         kd, self._kstep = jax.random.split(jax.random.PRNGKey(seed))
         self.tokens = first + synthetic_lm(kd, n_train, seq_len + 1, vocab)
@@ -817,3 +942,91 @@ def train_lm(
         save_state(save_dir + "/params", trial.params)
         save_state(save_dir + "/opt_state", trial.opt_state)
     return float(loss)
+
+
+# ---------------------------------------------------------------------------
+# the linear-attention mixer (at the file's end: the lines above lm_loss_fn
+# are part of the Pallas kernels' compile-cache keys, PERF.md PRs 27-29)
+
+
+def _decay_init(key, shape, dtype=jnp.float32):
+    """``A_log`` as Gated DeltaNet's published initialiser draws it:
+    log of U(0, 16) (from 2**-6 on, so that the log is finite)."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 2.0 ** -6, 16.0))
+
+
+def _dt_bias_init(key, shape, dtype=jnp.float32):
+    """``dt_bias`` likewise: softplus^-1 of dt, log dt ~ U(log 1e-3, log
+    1e-1): with ``A_log``, a decay of exp(-A dt) a token at a zero input."""
+    dt = jnp.exp(jax.random.uniform(key, shape, dtype, math.log(1e-3),
+                                    math.log(1e-1)))
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
+def short_conv(x, taps):
+    """Causal depthwise convolution along axis 1 of ``x`` (B, T, ...) with
+    ``taps`` (K, ...), no bias: y_t = sum_i taps[i] x_{t - (K - 1) + i},
+    x before the row's start = 0."""
+    k, t = taps.shape[0], x.shape[1]
+    x = jnp.pad(x, ((0, 0), (k - 1, 0)) + ((0, 0),) * (x.ndim - 2))
+    return sum(taps[i] * x[:, i:i + t] for i in range(k))
+
+
+class LinearAttention(nn.Module):
+    """A gated-delta-rule mixer (Gated DeltaNet, arXiv:2412.06464) over the
+    ``spec.heads`` heads held here: q, k (width ``key_dim``) and v (width
+    ``value_dim``) each projected, passed through a causal depthwise
+    convolution of ``conv`` taps and a SiLU; q and k L2-normalised over a
+    head (q times key_dim^-1/2); a step beta = sigmoid(x W_b) (twice that
+    with ``neg_eigval``) and a log decay g = -exp(A_log) softplus(x W_a +
+    dt_bias) a (token, head); the recurrence (ops/linear_attention.py, the
+    one rule there names its route); an RMS norm over a head's
+    ``value_dim`` gated by silu(x W_g); the output projection. Element-wise
+    work, gates and norms in float32; the two gates' projections float32 at
+    matmul precision highest, as a router's are."""
+
+    d_model: int
+    spec: LinearSpec
+    eps: float
+
+    @nn.compact
+    @trace.scope("linear_attention")
+    def __call__(self, x):
+        from metaopt_tpu.ops.linear_attention import gated_delta_rule
+
+        sp = self.spec
+        proj = lambda name, width: nn.DenseGeneral(  # noqa: E731
+            (sp.heads, width), axis=-1, dtype=jnp.bfloat16, name=name,
+            use_bias=False, kernel_init=_pinit(True, (None, "tp", None)))
+        gate = lambda name: nn.DenseGeneral(  # noqa: E731
+            sp.heads, use_bias=False, name=name,
+            precision=jax.lax.Precision.HIGHEST,
+            kernel_init=with_mesh_partitioning(
+                nn.initializers.lecun_normal(), (None, "tp")))
+        own = lambda name, init, shape, axes: self.param(  # noqa: E731
+            name, with_mesh_partitioning(init, axes), shape)
+        taps = lambda name, width: own(  # noqa: E731  U(-1/2, 1/2) at 4 taps
+            name, nn.initializers.variance_scaling(
+                1 / 3, "fan_in", "uniform", in_axis=0, out_axis=(1, 2)),
+            (sp.conv, sp.heads, width), (None, "tp", None))
+        mixed = lambda name, width: jax.nn.silu(short_conv(  # noqa: E731
+            proj(name, width)(xb).astype(jnp.float32),
+            taps("conv_" + name, width)))
+        unit = lambda y: y * jax.lax.rsqrt(  # noqa: E731
+            jnp.sum(jnp.square(y), axis=-1, keepdims=True) + 1e-6)
+        xb, xf = x.astype(jnp.bfloat16), x.astype(jnp.float32)
+        q = unit(mixed("q", sp.key_dim)) * sp.key_dim ** -0.5
+        k = unit(mixed("k", sp.key_dim))
+        v = mixed("v", sp.value_dim)
+        beta = jax.nn.sigmoid(gate("b")(xf)) * (2.0 if sp.neg_eigval else 1.0)
+        g = -jnp.exp(own("A_log", _decay_init, (sp.heads,), ("tp",))) \
+            * jax.nn.softplus(gate("a")(xf) + own(
+                "dt_bias", _dt_bias_init, (sp.heads,), ("tp",)))
+        o = gated_delta_rule(q.astype(jnp.bfloat16), k.astype(jnp.bfloat16),
+                             v.astype(jnp.bfloat16), g, beta)
+        y = RMSNorm(self.eps, name="norm")(o) \
+            * jax.nn.silu(proj("g", sp.value_dim)(xb).astype(jnp.float32))
+        return nn.DenseGeneral(
+            self.d_model, axis=(-2, -1), dtype=jnp.bfloat16, name="out",
+            use_bias=False, kernel_init=_pinit(True, ("tp", None, None)),
+        )(y.astype(jnp.bfloat16))

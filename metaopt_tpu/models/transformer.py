@@ -99,14 +99,14 @@ class FeedForward(nn.Module):
         return wo(h)
 
 
-def rematerialised(block_cls, **kwargs):
+def rematerialised(block_cls, keeps=REMAT_KEEPS, **kwargs):
     """``block_cls`` run again in the backward pass, keeping its input and
-    what its attention kernel made (ops/attention.REMAT_KEEPS: ``out`` and
+    what its kernels made (``keeps``; ops/attention.REMAT_KEEPS: ``out`` and
     ``lse``, which only the kernel could make again). The one rule for
     every ``remat`` site of the zoo's attention blocks."""
     return nn.remat(
         block_cls, **kwargs,
-        policy=jax.checkpoint_policies.save_only_these_names(*REMAT_KEEPS))
+        policy=jax.checkpoint_policies.save_only_these_names(*keeps))
 
 
 def _make_mlp(d_model, d_ff, dropout, n_experts, capacity_factor=1.25,
@@ -448,7 +448,7 @@ def sharded_init(init_fn, mesh: Mesh, seed: int = 0):
 
 def trial_setup(hparams: Dict[str, Any], mesh: Optional[Mesh],
                 tp: int, sp: int, ep: int, steps: int, describe=None,
-                remat_blocks: int = 0):
+                remat_blocks: int = 0, remat_keeps=REMAT_KEEPS):
     """The shared trial-harness preamble: mesh assembly + optimizer.
 
     sp > 1 shards the sequence axis (ring attention over ICI); ep > 1
@@ -463,7 +463,7 @@ def trial_setup(hparams: Dict[str, Any], mesh: Optional[Mesh],
     without dropout (lm.py: each kind of layer's route and mask form, what
     the expert layers hold and run their products with). ``remat_blocks``,
     the blocks the model runs again in the backward pass, puts what each
-    keeps besides its input into ``attrs["remat"]`` (absent at 0).
+    keeps besides its input, ``remat_keeps``, into ``attrs["remat"]``.
     """
     from metaopt_tpu.parallel.mesh import trial_mesh
 
@@ -484,7 +484,7 @@ def trial_setup(hparams: Dict[str, Any], mesh: Optional[Mesh],
             setup["attrs"].update(describe(evaluation))
         if remat_blocks:
             setup["attrs"]["remat"] = {"blocks": remat_blocks,
-                                       "keeps": list(REMAT_KEEPS)}
+                                       "keeps": list(remat_keeps)}
     lr = float(hparams.get("lr", 1e-3))
     warmup = int(hparams.get("warmup", 10))
     sched = optax.warmup_cosine_decay_schedule(

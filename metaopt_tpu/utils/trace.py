@@ -46,7 +46,11 @@ SCOPES = ("embed", "attention", "attention.core", "ffn", "readout_xent",
           "moe", "moe.router", "moe.dispatch", "moe.experts", "moe.combine",
           # attention over selected keys (ops/sparse_index.py): the
           # indexer's projections and scores, and the exact top-k
-          "attention.index", "attention.select")
+          "attention.index", "attention.select",
+          # a linear-attention mixer (models/lm.LinearAttention): all of it,
+          # and the chunked scan of the gated delta rule
+          # (ops/linear_attention.py), forward and backward
+          "linear_attention", "linear_attention.core")
 #: spans a train loop makes every step. The ring keeps one whole only if it
 #: has a child (the step that compiled); the others are summed into the
 #: enclosing span's ``attrs["per_step"]`` as ``{name: [count, seconds]}``, so a
@@ -352,6 +356,17 @@ def _scores_form(said: dict) -> str:
     return f"pallas, tiles {tq} x {tk}, passes {said['depth']} deep"
 
 
+def _runs(numbers) -> str:
+    """[0, 1, 2, 4] -> "0-2, 4": sorted numbers as runs."""
+    runs = []
+    for n in sorted(numbers):
+        if runs and n == runs[-1][1] + 1:
+            runs[-1][1] = n
+        else:
+            runs.append([n, n])
+    return ", ".join(str(a) if a == b else f"{a}-{b}" for a, b in runs)
+
+
 def print_routes(recs: List[dict]) -> None:
     """A line a trial: which attention route its steps took, as its
     ``trial.setup`` span has it (ops/attention.attention_route, the one
@@ -369,6 +384,15 @@ def print_routes(recs: List[dict]) -> None:
                   f"training (dropout {route['dropout']}), {route['eval']} "
                   "in evaluation")
             for kind, how in attrs.get("attention_layers", {}).items():
+                if kind == "linear":
+                    print(f"trial {r['trial']}: linear layers "
+                          f"{_runs(how['layers'])}: gated delta rule, "
+                          f"{how['heads'][0]} of {how['heads'][1]} heads, "
+                          f"keys {how['key_dim']}, values "
+                          f"{how['value_dim']}, convolutions of "
+                          f"{how['conv']}, chunks of {how['chunk']} by "
+                          f"{how['route']}")
+                    continue
                 scores = how.get("index_scores")
                 print(f"trial {r['trial']}: {kind} layers: {how['route']}, "
                       f"mask by {how['mask']}" + (

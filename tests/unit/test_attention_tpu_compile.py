@@ -218,7 +218,8 @@ def test_a_rematerialised_stack_s_gradient_holds_a_forward_kernel_a_layer(
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     if how == "bare":
-        monkeypatch.setattr(lm, "rematerialised", nn.remat)
+        monkeypatch.setattr(lm, "rematerialised",
+                            lambda cls, keeps: nn.remat(cls))
     s = 256
     model = lm.make_lm(dict(
         hidden_size=256, num_attention_heads=4, num_key_value_heads=2,
@@ -240,3 +241,33 @@ def test_a_rematerialised_stack_s_gradient_holds_a_forward_kernel_a_layer(
     calls = lambda name: len(re.findall(  # noqa: E731
         rf'custom_call_target="tpu_custom_call".*/{name}/pallas_call', text))
     assert (calls("flash_fwd"), calls("flash_bwd")) == (forwards, 2)
+
+
+# (tokens, heads, key width, value width): the benchmark cell's linear
+# layers (15 of Olmo-Hybrid-7B's 30 heads, one row of 8192 tokens), and a
+# length that pads to whole chunks
+SCANS = [(8192, 15, 96, 192), (1000, 4, 96, 192)]
+
+
+@pytest.mark.parametrize("shape", SCANS, ids=lambda s: "x".join(map(str, s)))
+def test_the_gated_delta_rule_s_kernels_compile_for_a_v5e(one_chip, shape):
+    """ops/linear_attention.py's ``linear_scan_fwd`` and ``linear_scan_bwd``
+    through Mosaic: blocks of 96- and 192-wide heads, float32 products at
+    precision highest for the triangular inverse, transposed operands."""
+    from metaopt_tpu.ops.linear_attention import gated_delta_rule
+
+    t, h, dk, dv = shape
+    on_chip = lambda s, d: jax.ShapeDtypeStruct(  # noqa: E731
+        s, d, sharding=one_chip)
+    args = [on_chip((1, t, h, dk), jnp.bfloat16)] * 2 + [
+        on_chip((1, t, h, dv), jnp.bfloat16)] + [
+        on_chip((1, t, h), jnp.float32)] * 2
+
+    def loss(q, k, v, g, beta):
+        out = gated_delta_rule(q, k, v, g, beta, interpret=False)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        *args).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "linear_scan_fwd" in text and "linear_scan_bwd" in text

@@ -694,6 +694,12 @@ def test_the_step_compiles_once_and_starts_near_the_uniform_loss():
 
 # -- what a rematerialised block keeps ----------------------------------------
 
+def _bare_remat(cls, keeps=None, **kw):
+    """``nn.remat`` without the rule's policy, in ``rematerialised``'s
+    place."""
+    return nn.remat(cls, **kw)
+
+
 def _on_the_kernels(patch):
     """The Pallas route as the chip takes it, interpreted here: the backend
     reads as the TPU, and the kernels' entry runs the interpreter on float32
@@ -752,7 +758,7 @@ def kept_or_not():
             with pytest.MonkeyPatch.context() as patch:
                 _on_the_kernels(patch)
                 if how == "bare":
-                    patch.setattr(lm, "rematerialised", nn.remat)
+                    patch.setattr(lm, "rematerialised", _bare_remat)
                 fn, params = gradient(True)
                 side[how] = (_kernel_calls(jax.make_jaxpr(fn)(params)),
                              *fn(params))
@@ -825,7 +831,7 @@ def test_a_rematerialised_2017_layer_keeps_its_kernel_s_output_too(shape):
         with pytest.MonkeyPatch.context() as patch, on_mesh():
             _on_the_kernels(patch)
             if how == "bare":
-                patch.setattr(lm, "rematerialised", nn.remat)
+                patch.setattr(lm, "rematerialised", _bare_remat)
             model = lm.make_lm({"d_model": D, "n_heads": H, "n_layers": 2,
                                 "d_ff": F, "vocab": V, "dropout": 0.0,
                                 "remat": True})

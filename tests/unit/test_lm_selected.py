@@ -675,7 +675,8 @@ def test_a_rematerialised_block_keeps_the_selection(monkeypatch):
     counted = {}
     for how in ("kept", "bare"):
         if how == "bare":
-            monkeypatch.setattr(lm, "rematerialised", nn.remat)
+            monkeypatch.setattr(lm, "rematerialised",
+                                lambda cls, keeps: nn.remat(cls))
         model = lm.make_lm(description(2, remat=True))
         trained, frozen = lm.split_frozen(nn.meta.unbox(model.init(
             jax.random.PRNGKey(0), tokens[:, :-1], train=False)["params"]))

@@ -211,8 +211,10 @@ def _under(names, scope):
 
 @pytest.mark.parametrize("scope", [
     s for s in trace.SCOPES if s != "eval" and not s.startswith("moe")
-    # a decoder's selected-attention layers': tests/unit/test_lm_selected.py
-    and s not in ("attention.index", "attention.select")])
+    # a decoder's selected-attention layers': tests/unit/test_lm_selected.py;
+    # its linear-attention layers': tests/unit/test_lm_hybrid.py
+    and s not in ("attention.index", "attention.select", "linear_attention",
+                  "linear_attention.core")])
 def test_every_scope_names_ops_of_the_train_step(lowered_op_names, scope):
     assert _under(lowered_op_names, scope), scope
 
