@@ -71,12 +71,14 @@ import jax
 import jax.numpy as jnp
 import optax
 from flax import linen as nn
+from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from metaopt_tpu.models.transformer import (
     EncoderLayer,
     _pinit,
     blocked_xent_enabled,
+    held_parameters,
     masked_mean_with_aux,
     readout_xent,
     rematerialised,
@@ -216,9 +218,20 @@ class GroupedAttention(nn.Module):
         )(out)
 
 
+#: What a rematerialised block keeps of its gated feed-forward where
+#: :func:`remat_keeps` finds the room, in order of gain a byte: the down
+#: product, the module's output (2 d_model bytes a token; with the block's
+#: norm on the branch's output the backward pass needs it, and would run
+#: the matmul again for it), then the gate and the up product (2 d_ff
+#: each); ``act(gate) * up`` is made again from those two, elementwise.
+FFN_REMAT_KEEPS = ("ffn.down", "ffn.gate", "ffn.up")
+
+
 class GatedFeedForward(nn.Module):
     """(act(x W_gate) * (x W_up)) W_down, no bias; ``activation`` by name,
-    as ``DroplessMoE`` takes it."""
+    as ``DroplessMoE`` takes it. The three products carry the names of
+    ``FFN_REMAT_KEEPS``: identities unless a block's policy asks for them.
+    The gating is kept out of the matmuls around it (a barrier, below)."""
 
     d_model: int
     d_ff: int
@@ -232,9 +245,22 @@ class GatedFeedForward(nn.Module):
             kernel_init=_pinit(True, axes))
         x = x.astype(jnp.bfloat16)
         act = {"relu": nn.relu, "silu": nn.silu}[self.activation]
-        h = act(dense("gate", self.d_ff, (None, "tp"))(x)) \
-            * dense("up", self.d_ff, (None, "tp"))(x)
-        return dense("down", self.d_model, ("tp", None))(h)
+        kept_down, kept_gate, kept_up = FFN_REMAT_KEEPS
+        # The two products stand in memory before the gating reads them
+        # and, by the barrier's transpose, so do their gradients before the
+        # four matmuls that read those. Left to itself XLA makes
+        # act(gate) * up and its derivative inside those matmuls' operands,
+        # again for every pass over a tile: on a v5e they then take 7.7-9.5
+        # ms where a matmul on operands that stand takes 3.9 (PERF.md
+        # section 6, PR 33).
+        gate, up = jax.lax.optimization_barrier((
+            checkpoint_name(dense("gate", self.d_ff, (None, "tp"))(x),
+                            kept_gate),
+            checkpoint_name(dense("up", self.d_ff, (None, "tp"))(x),
+                            kept_up)))
+        return checkpoint_name(
+            dense("down", self.d_model, ("tp", None))(act(gate) * up),
+            kept_down)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -370,11 +396,16 @@ class DecoderOnlyLM(nn.Module):
     capacity_factor: float = 1.25
     router_top_k: int = 1
     #: rematerialize each block in the backward pass (the HBM/FLOPs trade):
-    #: a block keeps its input and its attention kernel's ``out`` and
-    #: ``lse`` (transformer.rematerialised), and makes the rest again
+    #: a block keeps its input and what ``keeps`` names
+    #: (transformer.rematerialised), and makes the rest again
     remat: bool = False
     #: the layer pattern of a description that has one (module docstring)
     pattern: Optional[Pattern] = None
+    #: the names a rematerialised :class:`PatternBlock` keeps, as
+    #: :func:`remat_keeps` decided them for the trial's sizes and device
+    #: (:class:`LMTrial`); None: what it says of the pattern alone, the
+    #: kernels' outputs
+    keeps: Optional[Tuple[str, ...]] = None
 
     @nn.compact
     def __call__(self, tokens, *, train: bool, features: bool = False):
@@ -434,8 +465,11 @@ class DecoderOnlyLM(nn.Module):
             rows, self.d_model, dtype=jnp.bfloat16, name=name,
             embedding_init=nn.with_partitioning(
                 nn.initializers.normal(size), (None, None)))
-        block_cls = (rematerialised(PatternBlock, keeps=remat_keeps(p))
-                     if self.remat else PatternBlock)
+        block_cls = PatternBlock
+        if self.remat:
+            block_cls = rematerialised(
+                PatternBlock, keeps=remat_keeps(p)["keeps"]
+                if self.keeps is None else self.keeps)
         with trace.scope("embed"):
             x = table("embed")(tokens - first).astype(jnp.float32)
         heads = p.heads_held[1] if p.heads_held else self.n_heads
@@ -550,14 +584,75 @@ def _linear(h: Dict[str, Any], share) -> LinearSpec:
         neg_eigval=bool(h.get("linear_allow_neg_eigval", False)))
 
 
-def remat_keeps(p: Optional[Pattern]) -> Tuple[str, ...]:
-    """The names a rematerialised block of the model keeps: the attention
-    kernels', and the scan's where a layer is linear."""
-    if p is None or p.linear is None:
-        return REMAT_KEEPS
-    from metaopt_tpu.ops import linear_attention
+#: bytes of a trial's state a parameter: its value, AdamW's two moments and
+#: its gradient, float32 each
+STATE_BYTES_A_PARAMETER = 16
 
-    return REMAT_KEEPS + linear_attention.REMAT_KEEPS
+
+def remat_keeps(p: Optional[Pattern], *, tokens: int = 0, d_model: int = 0,
+                d_ff: int = 0, parameters: int = 0,
+                bytes_limit: Optional[int] = None) -> Dict[str, Any]:
+    """What a rematerialised block of the model keeps besides its input, as
+    ``trial.setup``'s ``attrs["remat"]`` says it. ``keeps``: the attention
+    kernels' names, the scan's where a layer is linear and, for a model
+    whose layers carry a :class:`GatedFeedForward`, as many of its products
+    (``FFN_REMAT_KEEPS``, in that order: the down product, then gate and up
+    together) as fit the device. It is one trade, time for memory, whose
+    right side depends on size, so the rule reads the sizes: one device's
+    ``tokens`` a step, its ``d_model`` and ``d_ff``, its ``parameters`` and
+    its memory's ``bytes_limit``. The products of all the layers stand at
+    once (``ffn_bytes``, each name's bytes a block) and are held against
+    ``room``: half of what the limit leaves beside the state
+    (``STATE_BYTES_A_PARAMETER``), the other half being the step's own (the
+    blocks' inputs, one block's backward pass, the head's logits). Without
+    a limit (a backend that reports none, a model outside a trial) no
+    product is kept. Every argument is explicit: the answer is made once,
+    outside the traced function (:class:`LMTrial`)."""
+    keeps = REMAT_KEEPS
+    if p is not None and p.linear is not None:
+        from metaopt_tpu.ops import linear_attention
+
+        keeps += linear_attention.REMAT_KEEPS
+    if p is None or p.n_experts:
+        return {"keeps": list(keeps)}
+    down, gate, up = (2 * tokens * n for n in (d_model, d_ff, d_ff))
+    room = None if bytes_limit is None else max(
+        0, bytes_limit - STATE_BYTES_A_PARAMETER * parameters) // 2
+    layers = len(p.layers)
+    if room is not None and layers * (down + gate + up) <= room:
+        keeps += FFN_REMAT_KEEPS
+    elif room is not None and layers * down <= room:
+        keeps += FFN_REMAT_KEEPS[:1]
+    return {"keeps": list(keeps), "room": room,
+            "ffn_bytes": dict(zip(FFN_REMAT_KEEPS, (down, gate, up)))}
+
+
+def remat_on(model: "DecoderOnlyLM", mesh: Mesh,
+             batch_shape) -> Dict[str, Any]:
+    """:func:`remat_keeps` of ``model`` for steps of ``batch_shape`` on
+    ``mesh``: the share of the step one device sees, the parameters it
+    holds and what its memory reports. Only a model with a gated
+    feed-forward pays for the count of its parameters."""
+    p = model.pattern
+    if p is None or p.n_experts:
+        return remat_keeps(p)
+    b, s = batch_shape
+    shapes = jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), jnp.zeros((b, s), jnp.int32),
+        train=False)["params"])
+    across = lambda *axes: math.prod(  # noqa: E731
+        mesh.shape.get(a, 1) for a in axes)
+    return remat_keeps(
+        p, tokens=b * s // across("dp", "sp"), d_model=model.d_model,
+        d_ff=model.d_ff // across("tp"),
+        parameters=held_parameters(shapes, mesh),
+        bytes_limit=device_bytes_limit(mesh))
+
+
+def device_bytes_limit(mesh: Mesh) -> Optional[int]:
+    """What a device of the mesh says its memory holds, None where the
+    backend does not say (the CPU's)."""
+    return (mesh.devices.flat[0].memory_stats() or {}).get("bytes_limit")
 
 
 def _selection(sa: Optional[Dict[str, Any]]):
@@ -811,16 +906,23 @@ class LMTrial:
                 f"n_train ({n_train}) must be >= batch_size ({batch_size})")
         self.batch_size, self.n_train = batch_size, n_train
         self._shard_batch, self._use_mesh = shard_batch, use_mesh
-        self.model = make_lm(hparams, max_len=max(
+        model = make_lm(hparams, max_len=max(
             int(hparams.get("max_len", 512)), seq_len))
+        # what a rematerialised block keeps depends on the mesh, which
+        # trial_setup makes: asked there, once, and the model is given the
+        # same answer
+        remat = functools.cache(functools.partial(
+            remat_on, model, batch_shape=(batch_size, seq_len)))
         # the model's own dropout: a layer pattern has none unless it says so
         self.mesh, tx = trial_setup(
-            {**hparams, "dropout": self.model.dropout}, mesh, tp, sp, ep,
+            {**hparams, "dropout": model.dropout}, mesh, tp, sp, ep,
             steps, describe=functools.partial(
                 describe_pattern, hparams, tokens=batch_size * seq_len,
                 seq_len=seq_len),
-            remat_blocks=self.model.n_layers if self.model.remat else 0,
-            remat_keeps=remat_keeps(self.model.pattern))
+            remat_blocks=model.n_layers if model.remat else 0,
+            remat_keeps=remat)
+        self.model = model.clone(
+            keeps=tuple(remat(self.mesh)["keeps"])) if model.remat else model
         first, vocab = self.model.held_vocab()
         kd, self._kstep = jax.random.split(jax.random.PRNGKey(seed))
         self.tokens = first + synthetic_lm(kd, n_train, seq_len + 1, vocab)

@@ -17,6 +17,7 @@ the FULL train step over an n-device dp×tp mesh.
 
 from __future__ import annotations
 
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -101,9 +102,11 @@ class FeedForward(nn.Module):
 
 def rematerialised(block_cls, keeps=REMAT_KEEPS, **kwargs):
     """``block_cls`` run again in the backward pass, keeping its input and
-    what its kernels made (``keeps``; ops/attention.REMAT_KEEPS: ``out`` and
-    ``lse``, which only the kernel could make again). The one rule for
-    every ``remat`` site of the zoo's attention blocks."""
+    the values named ``keeps``: ops/attention.REMAT_KEEPS, the attention
+    kernels' ``out`` and ``lse``, which only the kernel could make again;
+    for a pattern decoder what models/lm.py::remat_keeps says (the scan's
+    output and states, a gated feed-forward's products where they fit the
+    device). The one rule for every ``remat`` site of the zoo's blocks."""
     return nn.remat(
         block_cls, **kwargs,
         policy=jax.checkpoint_policies.save_only_these_names(*keeps))
@@ -429,6 +432,19 @@ def _prune_spec(spec, mesh):
     return P(*cleaned)
 
 
+def held_parameters(shapes, mesh: Mesh) -> int:
+    """The parameters one device of ``mesh`` holds of a tree of shapes as
+    ``model.init`` annotates it (``jax.eval_shape``): a leaf divided over
+    the mesh axes its partitioning names."""
+    return sum(
+        math.prod(NamedSharding(mesh, _prune_spec(spec, mesh))
+                  .shard_shape(x.shape))
+        for x, spec in zip(
+            jax.tree.leaves(nn.meta.unbox(shapes)),
+            jax.tree.leaves(nn.get_partition_spec(shapes),
+                            is_leaf=lambda s: isinstance(s, P))))
+
+
 @trace.span("trial.init")
 def sharded_init(init_fn, mesh: Mesh, seed: int = 0):
     """Run ``init_fn(key)`` with outputs materialized directly sharded.
@@ -463,7 +479,10 @@ def trial_setup(hparams: Dict[str, Any], mesh: Optional[Mesh],
     without dropout (lm.py: each kind of layer's route and mask form, what
     the expert layers hold and run their products with). ``remat_blocks``,
     the blocks the model runs again in the backward pass, puts what each
-    keeps besides its input, ``remat_keeps``, into ``attrs["remat"]``.
+    keeps besides its input into ``attrs["remat"]``: ``remat_keeps``, the
+    names, or a harness's function of the mesh that gives the names and
+    what they were held against (lm.py::remat_on: the bytes of a gated
+    feed-forward's products and the device's room).
     """
     from metaopt_tpu.parallel.mesh import trial_mesh
 
@@ -483,8 +502,10 @@ def trial_setup(hparams: Dict[str, Any], mesh: Optional[Mesh],
         if describe is not None:
             setup["attrs"].update(describe(evaluation))
         if remat_blocks:
-            setup["attrs"]["remat"] = {"blocks": remat_blocks,
-                                       "keeps": list(remat_keeps)}
+            setup["attrs"]["remat"] = {
+                "blocks": remat_blocks,
+                **(remat_keeps(mesh) if callable(remat_keeps)
+                   else {"keeps": list(remat_keeps)})}
     lr = float(hparams.get("lr", 1e-3))
     warmup = int(hparams.get("warmup", 10))
     sched = optax.warmup_cosine_decay_schedule(

@@ -144,6 +144,9 @@ def with_and_without_remat():
     out = {}
     for remat in (False, True):
         model = lm.make_lm(description(**PAIR, remat=remat))
+        if remat:  # every name the rule can say, the feed-forward's too
+            model = model.clone(keeps=tuple(
+                lm.remat_keeps(model.pattern)["keeps"]) + lm.FFN_REMAT_KEEPS)
         params = nn.meta.unbox(model.init(
             jax.random.PRNGKey(0), tokens[:, :-1], train=False)["params"])
         tx = optax.adamw(1e-2)
@@ -189,8 +192,8 @@ def test_a_rematerialised_block_keeps_what_the_scan_made(monkeypatch):
     from metaopt_tpu.ops import linear_attention
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert lm.remat_keeps(lm.make_lm(description(4)).pattern)[-2:] \
-        == linear_attention.REMAT_KEEPS
+    assert lm.remat_keeps(lm.make_lm(description(4)).pattern)["keeps"][-2:] \
+        == list(linear_attention.REMAT_KEEPS)
     tokens = jax.random.randint(jax.random.PRNGKey(5), (1, S + 1), 2, V)
     counted = {}
     for how in ("kept", "bare"):
@@ -208,6 +211,177 @@ def test_a_rematerialised_block_keeps_what_the_scan_made(monkeypatch):
             "linear_scan_fwd", "linear_scan_bwd", "flash_fwd", "flash_bwd"))
     assert counted["kept"] == (3, 3, 1, 1)
     assert counted["bare"] == (6, 3, 2, 1)
+
+
+
+# -- what a rematerialised block keeps of its feed-forward ----------------------
+
+def _ffn_products(jaxpr):
+    """The ``dot_general``s of a jaxpr one of whose sides is ``F`` wide:
+    the feed-forward's (no other width of these sizes is 96)."""
+    from test_lm_pattern import _equations
+
+    return sum(
+        e.primitive.name == "dot_general" and any(
+            F in v.aval.shape for v in (*e.invars, *e.outvars))
+        for e in _equations(jaxpr.jaxpr))
+
+
+@pytest.mark.parametrize("how, a_layer", [("kept", 9), ("down", 11),
+                                          ("today", 12), ("bare", 12)])
+def test_a_rematerialised_block_keeps_what_its_feed_forward_made(
+        monkeypatch, how, a_layer):
+    """The gradient of a rematerialised period holds nine products of the
+    feed-forward's shapes a layer with the three names kept (three forward,
+    six backward) and twelve under a bare ``nn.remat`` or today's list; with
+    the down product's name alone the gate and up products run again."""
+    from metaopt_tpu.models import lm
+
+    model = lm.make_lm(description(4, remat=True))
+    today = tuple(lm.remat_keeps(model.pattern)["keeps"])
+    if how == "bare":
+        monkeypatch.setattr(lm, "rematerialised",
+                            lambda cls, keeps: nn.remat(cls))
+    else:
+        model = model.clone(keeps=today + {
+            "kept": lm.FFN_REMAT_KEEPS, "down": lm.FFN_REMAT_KEEPS[:1],
+            "today": ()}[how])
+    tokens = jax.random.randint(jax.random.PRNGKey(5), (1, S + 1), 2, V)
+    params = jax.eval_shape(lambda: nn.meta.unbox(model.init(
+        jax.random.PRNGKey(0), tokens[:, :-1], train=False)["params"]))
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p: lm.lm_loss_fn(
+        model, p, tokens, jax.random.PRNGKey(0))))(params)
+    assert _ffn_products(jaxpr) == 4 * a_layer
+
+
+def test_without_remat_the_feed_forward_s_names_are_identities(monkeypatch):
+    """Not rematerialised, the gradient is the one without the names but
+    for three ``name`` equations a layer."""
+    from test_lm_pattern import _equations
+
+    from metaopt_tpu.models import lm
+
+    tokens = jax.random.randint(jax.random.PRNGKey(5), (1, S + 1), 2, V)
+    primitives = {}
+    for how in ("named", "unnamed"):
+        if how == "unnamed":
+            monkeypatch.setattr(lm, "checkpoint_name", lambda x, name: x)
+        model = lm.make_lm(description(**PAIR))
+        params = jax.eval_shape(lambda: nn.meta.unbox(model.init(
+            jax.random.PRNGKey(0), tokens[:, :-1], train=False)["params"]))
+        jaxpr = jax.make_jaxpr(jax.grad(lambda p: lm.lm_loss_fn(
+            model, p, tokens, jax.random.PRNGKey(0))))(params)
+        primitives[how] = [e.primitive.name
+                           for e in _equations(jaxpr.jaxpr)]
+    named, unnamed = primitives["named"], primitives["unnamed"]
+    assert "checkpoint" not in named
+    # the gating stands apart from the matmuls around it, forward and (the
+    # barrier's transpose) backward, named or not
+    assert named.count("optimization_barrier") == 2 * 2 \
+        == unnamed.count("optimization_barrier")
+    assert named.count("name") - unnamed.count("name") == 3 * 2
+    assert [p for p in named if p != "name"] \
+        == [p for p in unnamed if p != "name"]
+
+
+#: the benchmark cell's sizes (chipbench/configs/olmo-hybrid-7b-tp2.json):
+#: one row of 8192 tokens, 3840 x 11 008, 766.2 M parameters on a device
+#: that states 15.75 GiB
+CELL = dict(tokens=8192, d_model=3840, d_ff=11008, parameters=766_200_000,
+            bytes_limit=int(15.75 * 2 ** 30))
+
+
+@pytest.mark.parametrize("layers, over, kept, room", [
+    (4, {}, ["ffn.down", "ffn.gate", "ffn.up"], 2_326_116_864),
+    # a row twice as long: 3.39 GB of products, the down ones 0.50
+    (4, {"tokens": 16384}, ["ffn.down"], 2_326_116_864),
+    # so long that the down products alone pass the room
+    (4, {"tokens": 16 * 8192}, [], 2_326_116_864),
+    # the published depth on one device: the state alone passes the limit
+    (32, {"parameters": 6_129_600_000}, [], 0),
+    # 8 layers whose state leaves 4.6 GB: 3.39 GB of products against 2.3
+    (8, {}, ["ffn.down"], 2_326_116_864),
+    # a backend that states no limit
+    (4, {"bytes_limit": None}, [], None)])
+def test_the_rule_keeps_the_feed_forward_s_products_where_they_fit(
+        layers, over, kept, room):
+    from metaopt_tpu.models import lm
+    from metaopt_tpu.ops import linear_attention
+    from metaopt_tpu.ops.attention import REMAT_KEEPS
+
+    sizes = {**CELL, **over}
+    p = lm.make_lm(description(layers, layer_types=PERIOD * 8)).pattern
+    said = lm.remat_keeps(p, **sizes)
+    assert said["keeps"] == list(REMAT_KEEPS + linear_attention.REMAT_KEEPS) \
+        + kept
+    assert said["room"] == room
+    t = sizes["tokens"]
+    assert said["ffn_bytes"] == {"ffn.down": 2 * t * 3840,
+                                 "ffn.gate": 2 * t * 11008,
+                                 "ffn.up": 2 * t * 11008}
+    if room is not None:
+        standing = layers * sum(said["ffn_bytes"][n] for n in kept)
+        assert standing <= room
+    if CELL == sizes and layers == 4:
+        assert standing == 1_694_498_816     # 4 x 423.6 MB
+
+
+@pytest.mark.parametrize("limit, kept", [
+    (None, ()), (2 ** 20, ()), (2 ** 34, ("ffn.down", "ffn.gate", "ffn.up"))])
+def test_the_model_and_the_span_are_told_the_same_once(monkeypatch, limit,
+                                                       kept):
+    """``LMTrial`` asks the rule once, inside ``trial.setup`` (the mesh
+    exists there), and hands the one answer to the model's blocks and to
+    the span; a device's limit is pinned in ``device_bytes_limit``'s
+    place."""
+    from test_lm_pattern import one_device
+
+    from metaopt_tpu.models import lm
+    from metaopt_tpu.utils import trace
+
+    asked = []
+    real = lm.remat_keeps
+
+    def counting(p, **sizes):
+        if sizes:  # not the bare model's own say of its pattern alone
+            asked.append(sizes)
+        return real(p, **sizes)
+
+    monkeypatch.setattr(lm, "remat_keeps", counting)
+    monkeypatch.setattr(lm, "device_bytes_limit", lambda mesh: limit)
+    trial = lm.LMTrial({**description(**PAIR), "remat": True},
+                       mesh=one_device(), n_train=4, batch_size=2, seq_len=S)
+    said = trace.spans("trial.setup")[-1]["attrs"]["remat"]
+    assert tuple(said["keeps"]) == trial.model.keeps
+    assert trial.model.keeps[-len(kept):] == kept if kept \
+        else not set(trial.model.keeps) & set(lm.FFN_REMAT_KEEPS)
+    assert said["blocks"] == 2
+    assert said["room"] == (None if limit is None else max(
+        0, limit - 16 * asked[0]["parameters"]) // 2)
+    params = jax.tree.leaves(nn.meta.unbox(trial.params))
+    assert asked == [dict(tokens=2 * S, d_model=D, d_ff=F,
+                          parameters=sum(x.size for x in params),
+                          bytes_limit=limit)]
+
+
+def test_a_mesh_s_axes_divide_what_a_device_holds():
+    """Two devices over ``tp`` hold half of the feed-forward's width and of
+    the partitioned kernels; two over ``dp`` half of the step's tokens."""
+    from jax.sharding import Mesh
+
+    from metaopt_tpu.models import lm
+
+    model = lm.make_lm(description(**PAIR, remat=True))
+    said = {}
+    for shape in ((1, 1), (2, 1), (1, 2)):
+        mesh = Mesh(np.array(jax.devices()[:shape[0] * shape[1]]).reshape(
+            shape), ("dp", "tp"))
+        said[shape] = lm.remat_on(model, mesh, (2, S))["ffn_bytes"]
+    assert said[1, 1] == {"ffn.down": 2 * 2 * S * D, "ffn.gate": 2 * 2 * S * F,
+                          "ffn.up": 2 * 2 * S * F}
+    assert said[2, 1] == {k: v // 2 for k, v in said[1, 1].items()}
+    assert said[1, 2] == {**said[1, 1], "ffn.gate": 2 * S * F,
+                          "ffn.up": 2 * S * F}
 
 
 # -- the description -------------------------------------------------------------
@@ -319,8 +493,9 @@ def test_a_description_that_stood_builds_the_pattern_it_built(family):
     assert not p.norm_after and not p.qk_norm_whole and p.heads_held is None
     assert p.kinds() == (["global-nope", "window-rope"]
                          if family == "smallthinker" else ["selected-rope"])
-    assert lm.remat_keeps(p) == ("attention.out", "attention.lse",
-                                 "attention.selected")
+    assert lm.remat_keeps(p, tokens=8192, d_model=64, d_ff=96,
+                          parameters=10 ** 6, bytes_limit=2 ** 34) == {
+        "keeps": ["attention.out", "attention.lse", "attention.selected"]}
     model = lm.make_lm(h)
     params = jax.eval_shape(lambda: nn.meta.unbox(model.init(
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32),
@@ -461,9 +636,13 @@ def test_train_lm_says_which_layers_are_linear_and_what_a_block_keeps():
         "linear": {"route": "xla", "chunk": CHUNK, "layers": [0],
                    "heads": [HELD, HEADS], "key_dim": KD, "value_dim": VD,
                    "conv": 4}}
+    # this backend states no limit: the feed-forward's products are sized
+    # and not kept
     assert setup["remat"] == {"blocks": 2, "keeps": [
         "attention.out", "attention.lse", "attention.selected",
-        "linear_attention.out", "linear_attention.states"]}
+        "linear_attention.out", "linear_attention.states"],
+        "ffn_bytes": {"ffn.down": 2 * 2 * S * D, "ffn.gate": 2 * 2 * S * F,
+                      "ffn.up": 2 * 2 * S * F}, "room": None}
     assert "moe" not in setup
 
 

@@ -325,14 +325,39 @@ def test_trial_setup_s_span_says_which_attention_route_the_steps_take(
         f"{evaluation} in evaluation\n")
 
 
-@pytest.mark.parametrize("blocks, line", [
-    (0, None),
-    (4, "trial T-9: remat: 4 blocks keep attention.out, attention.lse, "
-        "attention.selected\n")])
+_NAMES = "attention.out, attention.lse, attention.selected"
+_FFN = {"ffn.down": 62_914_560, "ffn.gate": 180_355_072,
+        "ffn.up": 180_355_072}
+
+
+@pytest.mark.parametrize("blocks, keeps, line", [
+    (0, None, None),
+    (4, None, f"trial T-9: remat: 4 blocks keep {_NAMES}\n"),
+    # a harness's own say (models/lm.py::remat_on), as a function of the
+    # mesh: a gated feed-forward's products kept, declined in part, declined
+    # whole, and on a device that states no limit
+    (4, {"keeps": ["attention.out", *_FFN], "ffn_bytes": _FFN,
+         "room": 2_325_067_032},
+     "trial T-9: remat: 4 blocks keep attention.out, ffn.down, ffn.gate, "
+     "ffn.up; kept bytes 1.69 GB of room 2.33 GB\n"),
+    (8, {"keeps": ["attention.out", "ffn.down"], "ffn_bytes": _FFN,
+         "room": 2_325_067_032},
+     "trial T-9: remat: 8 blocks keep attention.out, ffn.down; kept bytes "
+     "0.50 GB of room 2.33 GB; ffn.gate, ffn.up: kept bytes 3.39 GB of room "
+     "2.33 GB: not kept\n"),
+    (32, {"keeps": ["attention.out"], "ffn_bytes": _FFN, "room": 0},
+     "trial T-9: remat: 32 blocks keep attention.out; ffn.down, ffn.gate, "
+     "ffn.up: kept bytes 13.56 GB of room 0.00 GB: not kept\n"),
+    (4, {"keeps": ["attention.out"], "ffn_bytes": _FFN, "room": None},
+     "trial T-9: remat: 4 blocks keep attention.out; ffn.down, ffn.gate, "
+     "ffn.up: kept bytes 1.69 GB of no room stated by the device: not "
+     "kept\n")])
 def test_trial_setup_s_span_says_what_a_rematerialised_block_keeps(
-        capsys, blocks, line):
+        capsys, blocks, keeps, line):
     """``attrs["remat"]``: the blocks run again in the backward pass and
-    the names of ops/attention.REMAT_KEEPS; absent without remat."""
+    the names of ops/attention.REMAT_KEEPS, or what a harness's function of
+    the mesh says (the names, a feed-forward's bytes, the room); absent
+    without remat."""
     import jax
     import numpy as np
     from jax.sharding import Mesh
@@ -341,10 +366,15 @@ def test_trial_setup_s_span_says_what_a_rematerialised_block_keeps(
     from metaopt_tpu.ops.attention import REMAT_KEEPS
 
     mesh = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("dp", "tp"))
-    trial_setup({"dropout": 0.0}, mesh, 1, 1, 1, 100, remat_blocks=blocks)
+    asked = []
+    trial_setup({"dropout": 0.0}, mesh, 1, 1, 1, 100, remat_blocks=blocks,
+                **({"remat_keeps": lambda m: asked.append(m) or keeps}
+                   if keeps else {}))
+    assert asked == ([mesh] if keeps else [])
     setup = trace.spans("trial.setup")[-1]
     assert setup["attrs"].get("remat") == (
-        {"blocks": blocks, "keeps": list(REMAT_KEEPS)} if blocks else None)
+        {"blocks": blocks, **(keeps or {"keeps": list(REMAT_KEEPS)})}
+        if blocks else None)
     trace.print_routes([dict(setup, trial="T-9")])
     said = capsys.readouterr().out.splitlines(keepends=True)
     assert said[1:] == ([line] if line else [])
