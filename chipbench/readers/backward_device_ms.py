@@ -1,0 +1,11 @@
+"""Device milliseconds a step of the operations in direction ``backward``
+(``trace.direction``: under ``transpose(`` and not a second run), every
+layer: the gradients' products, the kernels' backward rules, the scatter
+of the embedding's gradient, with AdamW's update where XLA fuses it into a
+weight-gradient matmul."""
+
+from chipbench import layer_trace
+
+
+def read(records):
+    return layer_trace.direction_ms(records, "backward")

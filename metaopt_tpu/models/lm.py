@@ -79,9 +79,11 @@ from metaopt_tpu.models.transformer import (
     _pinit,
     blocked_xent_enabled,
     held_parameters,
+    layer_norm,
     masked_mean_with_aux,
     readout_xent,
     rematerialised,
+    residual,
     sharded_init,
 )
 from metaopt_tpu.ops.attention import REMAT_KEEPS, CausalMask, attend
@@ -94,9 +96,14 @@ from metaopt_tpu.utils import trace
 
 
 class RMSNorm(nn.Module):
+    """Under the scope ``norm``; inside a mixer (q/k norms, the gated norm
+    of a linear layer) the mixer's scope is the outer one and owns the
+    operations (utils/trace.py::layer_of)."""
+
     eps: float = 1e-6
 
     @nn.compact
+    @trace.scope("norm")
     def __call__(self, x):
         scale = self.param("scale", nn.initializers.ones, (x.shape[-1],))
         x = x.astype(jnp.float32)
@@ -360,24 +367,25 @@ class PatternBlock(nn.Module):
             mixer = LinearAttention(self.d_model, p.linear, p.rms_eps,
                                     name="linear") if self.linear \
                 else attention()
-            x = x + RMSNorm(p.rms_eps, name="norm_mixer")(mixer(x))
-            return x + RMSNorm(p.rms_eps, name="norm_ffn")(GatedFeedForward(
-                self.d_model, self.d_ff, p.activation, name="mlp")(x))
+            x = residual(x, RMSNorm(p.rms_eps, name="norm_mixer")(mixer(x)))
+            return residual(x, RMSNorm(p.rms_eps, name="norm_ffn")(
+                GatedFeedForward(self.d_model, self.d_ff, p.activation,
+                                 name="mlp")(x)))
         n = RMSNorm(p.rms_eps, name="norm_in")(x)
         if p.n_experts and not p.router_after_attention:
             logits = router(n)
-        x = x + attention()(n)
+        x = residual(x, attention()(n))
         m = RMSNorm(p.rms_eps, name="norm_post")(x)
         if p.n_experts:
             from metaopt_tpu.models.moe import DroplessMoE
 
             if p.router_after_attention:
                 logits = router(m)
-            return x + DroplessMoE(self.d_model, p.expert_d_ff, p.n_experts,
-                                   p.top_k, p.experts_held, p.activation,
-                                   name="experts")(m, logits)
-        return x + GatedFeedForward(self.d_model, self.d_ff, p.activation,
-                                    name="mlp")(m)
+            return residual(x, DroplessMoE(
+                self.d_model, p.expert_d_ff, p.n_experts, p.top_k,
+                p.experts_held, p.activation, name="experts")(m, logits))
+        return residual(x, GatedFeedForward(
+            self.d_model, self.d_ff, p.activation, name="mlp")(m))
 
 
 class DecoderOnlyLM(nn.Module):
@@ -428,9 +436,10 @@ class DecoderOnlyLM(nn.Module):
                 f"sequence length {t_len} exceeds the positional table "
                 f"(max_len={self.max_len}); pass max_len>=seq to make_lm"
             )
-        pad = (tokens != 0)[:, None, None, :]                     # (b,1,1,k)
-        causal = jnp.tril(jnp.ones((t_len, t_len), bool))[None, None]
-        mask = causal & pad
+        with trace.scope("attention"):  # the mask is attention's operand
+            pad = (tokens != 0)[:, None, None, :]                 # (b,1,1,k)
+            causal = jnp.tril(jnp.ones((t_len, t_len), bool))[None, None]
+            mask = causal & pad
         block_cls = (rematerialised(EncoderLayer, static_argnums=(3,))
                      if self.remat else EncoderLayer)
         with trace.scope("embed"):
@@ -440,7 +449,7 @@ class DecoderOnlyLM(nn.Module):
                           self.dropout, self.n_experts,
                           self.capacity_factor, True, self.router_top_k,
                           name=f"h{i}")(x, mask, train)
-        x = nn.LayerNorm(dtype=jnp.float32, name="ln_f")(x)
+        x = layer_norm("ln_f", x)
         if features:
             # pre-readout features for the blocked xent: the (B, T, V)
             # logits tensor never materializes (see readout_xent)
@@ -753,20 +762,23 @@ def lm_loss_fn(model, params, tokens, dropout_key,
     ``with_stats``: (loss, what the expert layers counted this step)."""
     from metaopt_tpu.parallel.sharding import pin_batch_layout
 
-    inp, labels = pin_batch_layout(tokens[:, :-1]), tokens[:, 1:]
     first, vocab = model.held_vocab()
+    with trace.scope("loss"):  # the shifted rows and the mask
+        inp, labels = pin_batch_layout(tokens[:, :-1]), tokens[:, 1:]
+        mask = (labels != 0).astype(jnp.float32)
+        held = labels - first
     blocked = blocked_xent_enabled(labels.shape[0], labels.shape[1], vocab)
     out, mutated = model.apply(
         {"params": params}, inp, train=True, features=blocked,
         rngs={"dropout": dropout_key},
         mutable=["aux_loss", "moe_stats", "attn_stats"],
     )
-    mask = (labels != 0).astype(jnp.float32)
-    loss = readout_xent(out, params, labels - first, vocab, blocked)
+    loss = readout_xent(out, params, held, vocab, blocked)
     loss = masked_mean_with_aux(loss, mask, mutated, moe_aux_weight)
     if not with_stats:
         return loss
-    return loss, {**moe_counts(mutated), **selection_counts(mutated)}
+    with trace.scope("loss"):  # the counts ride out beside the loss
+        return loss, {**moe_counts(mutated), **selection_counts(mutated)}
 
 
 def moe_counts(mutated) -> Dict[str, Any]:
@@ -867,8 +879,9 @@ def make_lm_train_step(model, tx):
         with trace.scope("optimizer"):
             updates, opt_state = tx.update(grads, opt_state, trained)
             trained = optax.apply_updates(trained, updates)
-        return (merge_frozen(trained, frozen), opt_state,
-                _add_counts(counts, new), loss)
+        with trace.scope("loss"):  # its second output, summed over steps
+            counts = _add_counts(counts, new)
+        return merge_frozen(trained, frozen), opt_state, counts, loss
 
     return train_step
 

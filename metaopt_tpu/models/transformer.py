@@ -45,6 +45,20 @@ def _pinit(partitioned: bool, axes):
     return with_mesh_partitioning(init, axes) if partitioned else init
 
 
+@trace.scope("residual")
+def residual(x, branch):
+    """``x + branch`` on the residual stream: the sum and the cast of the
+    branch to the stream's dtype, under a scope of their own."""
+    return x + branch
+
+
+def layer_norm(name: str, x, out_dtype=jnp.float32):
+    """A float32 LayerNorm of the trunk and the cast of its output, under
+    the scope ``norm``."""
+    with trace.scope("norm"):
+        return nn.LayerNorm(dtype=jnp.float32, name=name)(x).astype(out_dtype)
+
+
 class MHA(nn.Module):
     d_model: int
     n_heads: int
@@ -134,16 +148,15 @@ class EncoderLayer(nn.Module):
 
     @nn.compact
     def __call__(self, x, pad_mask, train: bool = False):
-        ln = lambda n: nn.LayerNorm(dtype=jnp.float32, name=n)  # noqa: E731
-        y = ln("ln1")(x)
-        x = x + MHA(self.d_model, self.n_heads, self.dropout,
-                    self.partitioned,
-                    name="self_attn")(y, y, pad_mask, train=train)
-        y = ln("ln2")(x)
-        x = x + _make_mlp(self.d_model, self.d_ff, self.dropout,
-                          self.n_experts, self.capacity_factor,
-                          self.partitioned, self.router_top_k)(y, train=train)
-        return x
+        y = layer_norm("ln1", x)
+        x = residual(x, MHA(self.d_model, self.n_heads, self.dropout,
+                            self.partitioned,
+                            name="self_attn")(y, y, pad_mask, train=train))
+        y = layer_norm("ln2", x)
+        return residual(x, _make_mlp(
+            self.d_model, self.d_ff, self.dropout, self.n_experts,
+            self.capacity_factor, self.partitioned,
+            self.router_top_k)(y, train=train))
 
 
 class DecoderLayer(nn.Module):
@@ -158,20 +171,19 @@ class DecoderLayer(nn.Module):
 
     @nn.compact
     def __call__(self, x, enc, causal_mask, cross_mask, train: bool = False):
-        ln = lambda n: nn.LayerNorm(dtype=jnp.float32, name=n)  # noqa: E731
-        y = ln("ln1")(x)
-        x = x + MHA(self.d_model, self.n_heads, self.dropout,
-                    self.partitioned,
-                    name="self_attn")(y, y, causal_mask, train=train)
-        y = ln("ln2")(x)
-        x = x + MHA(self.d_model, self.n_heads, self.dropout,
-                    self.partitioned,
-                    name="cross_attn")(y, enc, cross_mask, train=train)
-        y = ln("ln3")(x)
-        x = x + _make_mlp(self.d_model, self.d_ff, self.dropout,
-                          self.n_experts, self.capacity_factor,
-                          self.partitioned, self.router_top_k)(y, train=train)
-        return x
+        y = layer_norm("ln1", x)
+        x = residual(x, MHA(self.d_model, self.n_heads, self.dropout,
+                            self.partitioned, name="self_attn")(
+            y, y, causal_mask, train=train))
+        y = layer_norm("ln2", x)
+        x = residual(x, MHA(self.d_model, self.n_heads, self.dropout,
+                            self.partitioned, name="cross_attn")(
+            y, enc, cross_mask, train=train))
+        y = layer_norm("ln3", x)
+        return residual(x, _make_mlp(
+            self.d_model, self.d_ff, self.dropout, self.n_experts,
+            self.capacity_factor, self.partitioned,
+            self.router_top_k)(y, train=train))
 
 
 class Transformer(nn.Module):
@@ -220,11 +232,12 @@ class Transformer(nn.Module):
                 f"table (max_len={self.max_len}); pass max_len>=seq to "
                 f"make_model"
             )
-        src_pad = (src != 0)[:, None, None, :]                    # (b,1,1,k)
-        causal = jnp.tril(jnp.ones((t_len, t_len), bool))[None, None]
-        tgt_pad = (tgt_in != 0)[:, None, None, :]
-        causal_mask = causal & tgt_pad
-        cross_mask = src_pad
+        with trace.scope("attention"):  # the masks are attention's operands
+            src_pad = (src != 0)[:, None, None, :]                # (b,1,1,k)
+            causal = jnp.tril(jnp.ones((t_len, t_len), bool))[None, None]
+            tgt_pad = (tgt_in != 0)[:, None, None, :]
+            causal_mask = causal & tgt_pad
+            cross_mask = src_pad
 
         # static_argnums pins `train` (python control flow inside);
         # counting includes self, so train sits at index 3 / 5
@@ -239,7 +252,7 @@ class Transformer(nn.Module):
                         self.dropout, self.n_experts,
                         self.capacity_factor, True, self.router_top_k,
                         name=f"enc{i}")(x, src_pad, train)
-        enc = nn.LayerNorm(dtype=jnp.float32, name="enc_ln")(x).astype(jnp.bfloat16)
+        enc = layer_norm("enc_ln", x, jnp.bfloat16)
 
         with trace.scope("embed"):
             y = emb(tgt_in) + pos[None, :t_len].astype(jnp.bfloat16)
@@ -250,7 +263,7 @@ class Transformer(nn.Module):
                         name=f"dec{i}")(
                 y, enc, causal_mask, cross_mask, train
             )
-        y = nn.LayerNorm(dtype=jnp.float32, name="dec_ln")(y)
+        y = layer_norm("dec_ln", y)
         if features:
             # pre-readout features for the blocked-xent loss (ops/xent.py):
             # the caller folds the tied embedding table in blockwise and
@@ -350,6 +363,7 @@ def readout_xent(out, params, labels, vocab, blocked):
     return optax.softmax_cross_entropy_with_integer_labels(out, labels)
 
 
+@trace.scope("loss")
 def masked_mean_with_aux(loss, mask, mutated, moe_aux_weight):
     """Masked token-mean plus the MoE switch load-balancing term."""
     total = (loss * mask).sum() / jnp.maximum(mask.sum(), 1.0)
@@ -363,16 +377,17 @@ def loss_fn(model, params, batch, dropout_key, moe_aux_weight: float = 0.01):
     from metaopt_tpu.parallel.sharding import pin_batch_layout
 
     src, tgt = batch
-    bos = jnp.ones((tgt.shape[0], 1), tgt.dtype)
-    tgt_in = pin_batch_layout(
-        jnp.concatenate([bos, tgt[:, :-1]], axis=1))
+    with trace.scope("loss"):  # the shifted rows and the mask
+        bos = jnp.ones((tgt.shape[0], 1), tgt.dtype)
+        tgt_in = pin_batch_layout(
+            jnp.concatenate([bos, tgt[:, :-1]], axis=1))
+        mask = (tgt != 0).astype(jnp.float32)
     blocked = blocked_xent_enabled(tgt.shape[0], tgt.shape[1], model.vocab)
     out, mutated = model.apply(
         {"params": params}, src, tgt_in, train=True, features=blocked,
         rngs={"dropout": dropout_key},
         mutable=["aux_loss"],
     )
-    mask = (tgt != 0).astype(jnp.float32)
     loss = readout_xent(out, params, tgt, model.vocab, blocked)
     return masked_mean_with_aux(loss, mask, mutated, moe_aux_weight)
 

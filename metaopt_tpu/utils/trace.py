@@ -12,6 +12,15 @@ parent and the launchers stay off it. The ring is written at exit to
 ``<METAOPT_TPU_PROFILE_DIR>/<trial id or worker id>/spans.jsonl`` when that
 variable is set (``hunt --profile-dir``), and never otherwise.
 
+A device operation carries the scopes it was traced under in its
+``op_name`` (``jit(train_step)/transpose(jvp(DecoderOnlyLM))/.../h0/attn/
+attention/attention.core/...``). ``SCOPES`` closes over a train step's
+source: every operation the program writes is under one of its nineteen
+names, and two rules read a path, :func:`layer_of` (which top-level scope
+owns it) and :func:`direction` (forward, the forward's second run under
+remat, backward, update). What carries no name of ``SCOPES`` the compiler
+made.
+
 ``python -m metaopt_tpu.utils.trace DIR`` reads what a sweep left under DIR.
 """
 
@@ -23,6 +32,7 @@ import contextlib
 import itertools
 import json
 import os
+import re
 import statistics
 import sys
 import threading
@@ -50,7 +60,17 @@ SCOPES = ("embed", "attention", "attention.core", "ffn", "readout_xent",
           # a linear-attention mixer (models/lm.LinearAttention): all of it,
           # and the chunked scan of the gated delta rule
           # (ops/linear_attention.py), forward and backward
-          "linear_attention", "linear_attention.core")
+          "linear_attention", "linear_attention.core",
+          # the trunk between the layers: the blocks' and the model's norms
+          # outside a branch; the residual stream's sums and casts; what a
+          # loss function does around the model and ``readout_xent`` (the
+          # shifted rows, the mask, the masked mean, the step's counts)
+          "norm", "residual", "loss")
+#: the top-level scopes, a partition of a step's named operations: every
+#: other scope is ``<layer>.<part>``, a part of its layer
+LAYERS = tuple(s for s in SCOPES if "." not in s)
+#: what :func:`direction` answers
+DIRECTIONS = ("forward", "forward.again", "backward", "update")
 #: spans a train loop makes every step. The ring keeps one whole only if it
 #: has a child (the step that compiled); the others are summed into the
 #: enclosing span's ``attrs["per_step"]`` as ``{name: [count, seconds]}``, so a
@@ -142,6 +162,42 @@ def scope(name: str):
     import jax
 
     return jax.named_scope(name)
+
+
+def layer_of(op_name: str) -> Optional[str]:
+    """The layer that owns a device operation: the top-level scope of the
+    OUTERMOST name of ``SCOPES`` on its path, bare or inside a transform's
+    brackets (``transpose(jvp(readout_xent))``). ``attention.core`` is
+    ``attention``'s part, not a layer; a q/k norm inside ``attention`` is
+    under ``norm`` too and stays attention's: the outermost name owns the
+    path, the one tie-break. None: the path carries no name of the
+    program's, so the compiler made the operation (a copy, a slice).
+
+    XLA fuses across scopes and a fusion carries its root's ``op_name``:
+    the answer is for the operation *named* so, not for every source line
+    folded into it."""
+    for part in re.split(r"[/()]", op_name):
+        if part in SCOPES:
+            return part.partition(".")[0]
+    return None
+
+
+def direction(op_name: str) -> str:
+    """One of ``DIRECTIONS`` for a device operation of a train step:
+    ``forward.again`` where jax says the operation is a rematerialised
+    block's second run (``rematted_computation`` on the path, which lies
+    under ``transpose(`` too), else ``backward`` under ``transpose(`` (a
+    ``custom_vjp`` kernel's backward rule is traced there), else ``update``
+    under the scope ``optimizer``, else ``forward``.
+
+    The same limit as :func:`layer_of`'s: a second-run product that XLA
+    folds into a backward fusion reads ``backward``, an update folded into
+    a weight-gradient matmul likewise."""
+    if "rematted_computation" in op_name:
+        return "forward.again"
+    if "transpose(" in op_name:
+        return "backward"
+    return "update" if layer_of(op_name) == "optimizer" else "forward"
 
 
 def spans(name: Optional[str] = None) -> List[dict]:
