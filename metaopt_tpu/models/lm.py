@@ -178,13 +178,29 @@ class Indexer(nn.Module):
         return mask
 
 
+#: What a rematerialised block keeps of its mixer's projections where
+#: :func:`remat_keeps` finds the room: the products as the matmuls leave
+#: them, q, k, v (and a linear layer's g, a, b) BEFORE the norms, the
+#: convolutions, rotary, the scale and the casts, which are made again from
+#: them, elementwise: a norm's backward needs the product itself, so a
+#: kept normed value would bring the matmul back. The last name of each is
+#: the output projection's.
+ATTENTION_REMAT_KEEPS = ("attention.q_proj", "attention.k_proj",
+                         "attention.v_proj", "attention.out_proj")
+LINEAR_REMAT_KEEPS = tuple(
+    f"linear_attention.{n}_proj" for n in ("q", "k", "v", "g", "a", "b",
+                                           "out"))
+
+
 class GroupedAttention(nn.Module):
     """Causal self attention with fewer K/V heads than query heads, no
     bias; rotary or no positions; a window, none, or the keys an
     :class:`Indexer` selects (``selection``: its heads, their width and
     ``topk``); RMS norms of q and k over a head's width, over the
     projected width (``qk_norm_whole``: the heads held here together, one
-    scale vector of heads x width) or none."""
+    scale vector of heads x width) or none. The four projections' products
+    carry the names of ``ATTENTION_REMAT_KEEPS``: identities unless a
+    block's policy asks for them."""
 
     d_model: int
     n_heads: int
@@ -207,8 +223,10 @@ class GroupedAttention(nn.Module):
             mask = Indexer(*self.selection, self.rope_theta,
                            name="indexer")(x)
         x = x.astype(jnp.bfloat16)
-        q, k, v = (proj("q", self.n_heads)(x), proj("k", self.n_kv_heads)(x),
-                   proj("v", self.n_kv_heads)(x))
+        kept_q, kept_k, kept_v, kept_out = ATTENTION_REMAT_KEEPS
+        q, k, v = (checkpoint_name(proj("q", self.n_heads)(x), kept_q),
+                   checkpoint_name(proj("k", self.n_kv_heads)(x), kept_k),
+                   checkpoint_name(proj("v", self.n_kv_heads)(x), kept_v))
         if self.qk_norm is not None:
             whole = lambda y: y.reshape(  # noqa: E731
                 *y.shape[:2], -1) if self.qk_norm_whole else y
@@ -219,10 +237,10 @@ class GroupedAttention(nn.Module):
         q = (q / math.sqrt(self.head_dim)).astype(jnp.bfloat16)
         k = k.astype(jnp.bfloat16)
         out = attend(q, k, v, mask)
-        return nn.DenseGeneral(
+        return checkpoint_name(nn.DenseGeneral(
             self.d_model, axis=(-2, -1), dtype=jnp.bfloat16, name="out",
             use_bias=False, kernel_init=_pinit(True, ("tp", None, None)),
-        )(out)
+        )(out), kept_out)
 
 
 #: What a rematerialised block keeps of its gated feed-forward where
@@ -492,8 +510,16 @@ class DecoderOnlyLM(nn.Module):
             head.embedding  # noqa: B018
             return x
         with trace.scope("readout_xent"):
+            # The cast stands in memory before the head's matmuls read it.
+            # Left to itself XLA makes it inside their operands from the
+            # float32 stream, and the weight-gradient matmul then runs at
+            # its rate only while the compiler also happens to move that
+            # stream on chip for it: on a v5e it takes 10.2 ms so and 17.7
+            # without, and which it is turned on what the blocks keep
+            # (PERF.md section 6, PR 35).
             return jnp.einsum(
-                "btd,vd->btv", x.astype(jnp.bfloat16),
+                "btd,vd->btv",
+                jax.lax.optimization_barrier(x.astype(jnp.bfloat16)),
                 head.embedding.astype(jnp.bfloat16),
                 preferred_element_type=jnp.float32)
 
@@ -599,61 +625,125 @@ STATE_BYTES_A_PARAMETER = 16
 
 
 def remat_keeps(p: Optional[Pattern], *, tokens: int = 0, d_model: int = 0,
-                d_ff: int = 0, parameters: int = 0,
+                d_ff: int = 0, n_heads: int = 0, parameters: int = 0,
                 bytes_limit: Optional[int] = None) -> Dict[str, Any]:
     """What a rematerialised block of the model keeps besides its input, as
     ``trial.setup``'s ``attrs["remat"]`` says it. ``keeps``: the attention
     kernels' names, the scan's where a layer is linear and, for a model
-    whose layers carry a :class:`GatedFeedForward`, as many of its products
-    (``FFN_REMAT_KEEPS``, in that order: the down product, then gate and up
-    together) as fit the device. It is one trade, time for memory, whose
-    right side depends on size, so the rule reads the sizes: one device's
-    ``tokens`` a step, its ``d_model`` and ``d_ff``, its ``parameters`` and
-    its memory's ``bytes_limit``. The products of all the layers stand at
-    once (``ffn_bytes``, each name's bytes a block) and are held against
-    ``room``: half of what the limit leaves beside the state
-    (``STATE_BYTES_A_PARAMETER``), the other half being the step's own (the
-    blocks' inputs, one block's backward pass, the head's logits). Without
-    a limit (a backend that reports none, a model outside a trial) no
-    product is kept. Every argument is explicit: the answer is made once,
-    outside the traced function (:class:`LMTrial`)."""
+    with a layer pattern, as many of its layers' matrix products as fit the
+    device. It is one trade, time for memory, whose right side depends on
+    size, so the rule reads the sizes: one device's ``tokens`` a step, its
+    ``d_model``, ``d_ff`` and ``n_heads`` (the pattern's other head counts
+    and widths are in ``p``), its ``parameters`` and its memory's
+    ``bytes_limit``. The candidates (:func:`_products`) are taken in order
+    of gain a byte: a product's FLOPs over the bytes of its output, which
+    is its contracting width. The products of a name stand over all the
+    layers that make it at once (``bytes``, each candidate name's) and a
+    candidate is kept if it fits what is left of ``room``: half of what the
+    limit leaves beside the state (``STATE_BYTES_A_PARAMETER``), the other
+    half being the step's own (the blocks' inputs, one block's backward
+    pass, the head's logits); one that does not fit is declined and the
+    next is held against the same room. Without a limit (a backend that
+    reports none, a model outside a trial) no product is kept. Every
+    argument is explicit: the answer is made once, outside the traced
+    function (:class:`LMTrial`)."""
     keeps = REMAT_KEEPS
-    if p is not None and p.linear is not None:
+    if p is None:
+        return {"keeps": list(keeps)}
+    if p.linear is not None:
         from metaopt_tpu.ops import linear_attention
 
         keeps += linear_attention.REMAT_KEEPS
-    if p is None or p.n_experts:
-        return {"keeps": list(keeps)}
-    down, gate, up = (2 * tokens * n for n in (d_model, d_ff, d_ff))
     room = None if bytes_limit is None else max(
         0, bytes_limit - STATE_BYTES_A_PARAMETER * parameters) // 2
-    layers = len(p.layers)
-    if room is not None and layers * (down + gate + up) <= room:
-        keeps += FFN_REMAT_KEEPS
-    elif room is not None and layers * down <= room:
-        keeps += FFN_REMAT_KEEPS[:1]
+    products = _products(p, tokens, d_model, d_ff, n_heads)
+    left = room
+    for _, sizes in sorted(products, key=lambda c: -c[0]):
+        need = sum(sizes.values())
+        if left is not None and need <= left:
+            left -= need
+            keeps += tuple(sizes)
     return {"keeps": list(keeps), "room": room,
-            "ffn_bytes": dict(zip(FFN_REMAT_KEEPS, (down, gate, up)))}
+            "bytes": {n: b for _, sizes in products
+                      for n, b in sizes.items()}}
 
 
-def remat_on(model: "DecoderOnlyLM", mesh: Mesh,
-             batch_shape) -> Dict[str, Any]:
+def _products(p: Pattern, tokens: int, d_model: int, d_ff: int,
+              n_heads: int):
+    """The matrix products a rematerialised block of the pattern can keep,
+    as :func:`remat_keeps` takes them: (contracting width, {name: the bytes
+    of its outputs over all the layers that make it}) a candidate, those of
+    one width listed in the order they are tried. Products that read one
+    input at one width are one candidate, kept or declined together: a
+    gated feed-forward's gate and up, a mixer's input projections (a linear
+    layer's two float32 gates among them: nothing in bytes, six passes at
+    precision highest in time)."""
+    linear = sum(map(p.is_linear, range(len(p.layers))))
+    out = []
+
+    def add(width, layers, bytes_a_token):
+        if layers:
+            out.append((width, {n: layers * tokens * b
+                                for n, b in bytes_a_token.items()}))
+
+    if not p.n_experts:
+        down, gate, up = FFN_REMAT_KEEPS
+        add(d_ff, len(p.layers), {down: 2 * d_model})
+        add(d_model, len(p.layers), {gate: 2 * d_ff, up: 2 * d_ff})
+    q, k, v, last = ATTENTION_REMAT_KEEPS
+    add(d_model, len(p.layers) - linear, {
+        q: 2 * n_heads * p.head_dim, k: 2 * p.n_kv_heads * p.head_dim,
+        v: 2 * p.n_kv_heads * p.head_dim})
+    add(n_heads * p.head_dim, len(p.layers) - linear, {last: 2 * d_model})
+    if p.linear is not None:
+        sp = p.linear
+        q, k, v, g, a, b, last = LINEAR_REMAT_KEEPS
+        add(d_model, linear, {
+            q: 2 * sp.heads * sp.key_dim, k: 2 * sp.heads * sp.key_dim,
+            v: 2 * sp.heads * sp.value_dim, g: 2 * sp.heads * sp.value_dim,
+            a: 4 * sp.heads, b: 4 * sp.heads})
+        add(sp.heads * sp.value_dim, linear, {last: 2 * d_model})
+    return out
+
+
+def param_init(model: "DecoderOnlyLM", batch_shape):
+    """key -> ``model.init``'s parameters for rows of ``batch_shape``,
+    jitted: ONE function, so that whoever asks for its shapes
+    (``jax.eval_shape``: :func:`remat_on`'s count) and the sharded init
+    that calls it share one trace of the model."""
+    return jax.jit(lambda key: model.init(
+        key, jnp.zeros(batch_shape, jnp.int32), train=False)["params"])
+
+
+def remat_on(model: "DecoderOnlyLM", mesh: Mesh, batch_shape,
+             init_params=None) -> Dict[str, Any]:
     """:func:`remat_keeps` of ``model`` for steps of ``batch_shape`` on
     ``mesh``: the share of the step one device sees, the parameters it
-    holds and what its memory reports. Only a model with a gated
-    feed-forward pays for the count of its parameters."""
+    holds (counted from the shapes of ``init_params``, the caller's
+    :func:`param_init`, or of one made here), its share of every kind of
+    head and what its memory reports. Only a model with a layer pattern
+    needs the count."""
     p = model.pattern
-    if p is None or p.n_experts:
+    if p is None:
         return remat_keeps(p)
+    from metaopt_tpu.parallel.mesh import use_mesh
+
+    with use_mesh(mesh):  # as the init will trace it: the trace is shared
+        shapes = jax.eval_shape(
+            init_params or param_init(model, batch_shape),
+            jax.ShapeDtypeStruct((2,), jnp.uint32))
     b, s = batch_shape
-    shapes = jax.eval_shape(lambda: model.init(
-        jax.random.PRNGKey(0), jnp.zeros((b, s), jnp.int32),
-        train=False)["params"])
     across = lambda *axes: math.prod(  # noqa: E731
         mesh.shape.get(a, 1) for a in axes)
+    tp = across("tp")
     return remat_keeps(
-        p, tokens=b * s // across("dp", "sp"), d_model=model.d_model,
-        d_ff=model.d_ff // across("tp"),
+        dataclasses.replace(
+            p, n_kv_heads=p.n_kv_heads // tp,
+            linear=p.linear and dataclasses.replace(
+                p.linear, heads=p.linear.heads // tp)),
+        tokens=b * s // across("dp", "sp"), d_model=model.d_model,
+        d_ff=model.d_ff // tp,
+        n_heads=(p.heads_held[1] if p.heads_held else model.n_heads) // tp,
         parameters=held_parameters(shapes, mesh),
         bytes_limit=device_bytes_limit(mesh))
 
@@ -887,13 +977,14 @@ def make_lm_train_step(model, tx):
 
 
 def init_sharded_lm(model: DecoderOnlyLM, mesh: Mesh, tx,
-                    batch_shape, seed: int = 0):
-    """Params/opt state materialized directly on the mesh (one token input)."""
-    b, s = batch_shape
-    toks = jnp.zeros((b, s), jnp.int32)
+                    batch_shape, seed: int = 0, init_params=None):
+    """Params/opt state materialized directly on the mesh (one token
+    input). ``init_params``: the model's :func:`param_init` where the
+    caller has traced it already."""
+    init_params = init_params or param_init(model, batch_shape)
 
     def init_fn(key):
-        params = model.init(key, toks, train=False)["params"]
+        params = init_params(key)
         return params, tx.init(split_frozen(params)[0])
 
     return sharded_init(init_fn, mesh, seed)
@@ -923,9 +1014,11 @@ class LMTrial:
             int(hparams.get("max_len", 512)), seq_len))
         # what a rematerialised block keeps depends on the mesh, which
         # trial_setup makes: asked there, once, and the model is given the
-        # same answer
-        remat = functools.cache(functools.partial(
-            remat_on, model, batch_shape=(batch_size, seq_len)))
+        # same answer; the one trace of the model it counts parameters
+        # from serves the init too
+        init_params = param_init(model, (batch_size, seq_len))
+        remat = functools.cache(lambda mesh: remat_on(
+            model, mesh, (batch_size, seq_len), init_params))
         # the model's own dropout: a layer pattern has none unless it says so
         self.mesh, tx = trial_setup(
             {**hparams, "dropout": model.dropout}, mesh, tp, sp, ep,
@@ -941,7 +1034,8 @@ class LMTrial:
         self.tokens = first + synthetic_lm(kd, n_train, seq_len + 1, vocab)
         with use_mesh(self.mesh):
             params, opt_state, self.shardings = init_sharded_lm(
-                self.model, self.mesh, tx, (batch_size, seq_len), seed)
+                self.model, self.mesh, tx, (batch_size, seq_len), seed,
+                init_params)
             self.params, self.opt_state = maybe_restore(
                 restore_dir, params, opt_state, self.shardings)
             # the counts go in as they come out, replicated: a first step
@@ -1098,7 +1192,9 @@ class LinearAttention(nn.Module):
     one rule there names its route); an RMS norm over a head's
     ``value_dim`` gated by silu(x W_g); the output projection. Element-wise
     work, gates and norms in float32; the two gates' projections float32 at
-    matmul precision highest, as a router's are."""
+    matmul precision highest, as a router's are. The seven projections'
+    products carry the names of ``LINEAR_REMAT_KEEPS`` (identities unless a
+    block's policy asks for them)."""
 
     d_model: int
     spec: LinearSpec
@@ -1124,24 +1220,27 @@ class LinearAttention(nn.Module):
             name, nn.initializers.variance_scaling(
                 1 / 3, "fan_in", "uniform", in_axis=0, out_axis=(1, 2)),
             (sp.conv, sp.heads, width), (None, "tp", None))
+        kept = dict(zip(("q", "k", "v", "g", "a", "b", "out"),
+                        LINEAR_REMAT_KEEPS))
         mixed = lambda name, width: jax.nn.silu(short_conv(  # noqa: E731
-            proj(name, width)(xb).astype(jnp.float32),
-            taps("conv_" + name, width)))
+            checkpoint_name(proj(name, width)(xb), kept[name])
+            .astype(jnp.float32), taps("conv_" + name, width)))
         unit = lambda y: y * jax.lax.rsqrt(  # noqa: E731
             jnp.sum(jnp.square(y), axis=-1, keepdims=True) + 1e-6)
         xb, xf = x.astype(jnp.bfloat16), x.astype(jnp.float32)
         q = unit(mixed("q", sp.key_dim)) * sp.key_dim ** -0.5
         k = unit(mixed("k", sp.key_dim))
         v = mixed("v", sp.value_dim)
-        beta = jax.nn.sigmoid(gate("b")(xf)) * (2.0 if sp.neg_eigval else 1.0)
+        beta = jax.nn.sigmoid(checkpoint_name(gate("b")(xf), kept["b"])) \
+            * (2.0 if sp.neg_eigval else 1.0)
         g = -jnp.exp(own("A_log", _decay_init, (sp.heads,), ("tp",))) \
-            * jax.nn.softplus(gate("a")(xf) + own(
+            * jax.nn.softplus(checkpoint_name(gate("a")(xf), kept["a"]) + own(
                 "dt_bias", _dt_bias_init, (sp.heads,), ("tp",)))
         o = gated_delta_rule(q.astype(jnp.bfloat16), k.astype(jnp.bfloat16),
                              v.astype(jnp.bfloat16), g, beta)
-        y = RMSNorm(self.eps, name="norm")(o) \
-            * jax.nn.silu(proj("g", sp.value_dim)(xb).astype(jnp.float32))
-        return nn.DenseGeneral(
+        y = RMSNorm(self.eps, name="norm")(o) * jax.nn.silu(checkpoint_name(
+            proj("g", sp.value_dim)(xb), kept["g"]).astype(jnp.float32))
+        return checkpoint_name(nn.DenseGeneral(
             self.d_model, axis=(-2, -1), dtype=jnp.bfloat16, name="out",
             use_bias=False, kernel_init=_pinit(True, ("tp", None, None)),
-        )(y.astype(jnp.bfloat16))
+        )(y.astype(jnp.bfloat16)), kept["out"])

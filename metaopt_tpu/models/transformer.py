@@ -496,8 +496,8 @@ def trial_setup(hparams: Dict[str, Any], mesh: Optional[Mesh],
     the blocks the model runs again in the backward pass, puts what each
     keeps besides its input into ``attrs["remat"]``: ``remat_keeps``, the
     names, or a harness's function of the mesh that gives the names and
-    what they were held against (lm.py::remat_on: the bytes of a gated
-    feed-forward's products and the device's room).
+    what they were held against (lm.py::remat_on: the bytes of every
+    product a block could keep and the device's room).
     """
     from metaopt_tpu.parallel.mesh import trial_mesh
 

@@ -423,16 +423,17 @@ def _runs(numbers) -> str:
     return ", ".join(str(a) if a == b else f"{a}-{b}" for a, b in runs)
 
 
-def _ffn_room(remat: dict) -> str:
-    """What ``attrs["remat"]`` says of a gated feed-forward's products
-    (models/lm.py::remat_keeps): the bytes kept over all the blocks and the
-    room they were held against and, of the names the rule declined, the
-    bytes that would have stood; "" for a model without one."""
-    sizes = remat.get("ffn_bytes")
+def _room(remat: dict) -> str:
+    """What ``attrs["remat"]`` says of the matrix products a block could
+    keep (models/lm.py::remat_keeps): the bytes kept over all the layers
+    and the room they were held against and, of the names the rule
+    declined, the bytes that would have stood with them; "" for a model
+    that has no such candidates."""
+    sizes = remat.get("bytes")
     if not sizes:
         return ""
     gb = lambda names: (  # noqa: E731
-        f"{remat['blocks'] * sum(sizes[n] for n in names) / 1e9:.2f} GB")
+        f"{sum(sizes[n] for n in names) / 1e9:.2f} GB")
     room = ("no room stated by the device" if remat["room"] is None
             else f"room {remat['room'] / 1e9:.2f} GB")
     kept = [n for n in sizes if n in remat["keeps"]]
@@ -450,8 +451,9 @@ def print_routes(recs: List[dict]) -> None:
     model with a layer pattern a line each kind of layer, what the expert
     layers hold, and (from ``trial.train``) what they counted and, where
     layers select their keys, the selected pairs among the causal ones; for
-    a rematerialised model what a block keeps besides its input and, of a
-    gated feed-forward's products, the bytes against the device's room."""
+    a rematerialised model what a block keeps besides its input and, of
+    the matrix products it could keep, the bytes against the device's
+    room."""
     held = {}  # trial -> its setup's attrs["moe"]
     for r in recs:
         attrs = r["attrs"]
@@ -479,7 +481,7 @@ def print_routes(recs: List[dict]) -> None:
             if remat:
                 print(f"trial {r['trial']}: remat: {remat['blocks']} blocks "
                       f"keep {', '.join(remat['keeps'])}"
-                      + _ffn_room(remat))
+                      + _room(remat))
             moe = attrs.get("moe")
             if moe:
                 held[r["trial"]] = moe

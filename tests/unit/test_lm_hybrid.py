@@ -12,6 +12,8 @@ uncut layer; the scopes name operations of the train step and the trace's
 reader prints the linear kind's line.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -144,9 +146,11 @@ def with_and_without_remat():
     out = {}
     for remat in (False, True):
         model = lm.make_lm(description(**PAIR, remat=remat))
-        if remat:  # every name the rule can say, the feed-forward's too
+        if remat:  # every name the rule can say: the feed-forward's and
+            # both kinds of mixer's projections too
             model = model.clone(keeps=tuple(
-                lm.remat_keeps(model.pattern)["keeps"]) + lm.FFN_REMAT_KEEPS)
+                lm.remat_keeps(model.pattern)["keeps"]) + lm.FFN_REMAT_KEEPS
+                + lm.ATTENTION_REMAT_KEEPS + lm.LINEAR_REMAT_KEEPS)
         params = nn.meta.unbox(model.init(
             jax.random.PRNGKey(0), tokens[:, :-1], train=False)["params"])
         tx = optax.adamw(1e-2)
@@ -162,7 +166,10 @@ def with_and_without_remat():
 @pytest.mark.parametrize("path", ["loss", "embed/embedding", "h0/linear/A_log",
                                   "h0/linear/conv_k", "h0/linear/q/kernel",
                                   "h0/linear/b/kernel", "h1/attn/k/kernel",
-                                  "h1/attn/q_norm/scale", "h1/mlp/up/kernel"])
+                                  "h1/attn/q_norm/scale", "h1/mlp/up/kernel",
+                                  "h0/linear/g/kernel", "h0/linear/a/kernel",
+                                  "h0/linear/out/kernel", "h0/linear/norm/scale",
+                                  "h1/attn/q/kernel", "h1/attn/out/kernel"])
 def test_remat_changes_nothing_to_the_last_bit(with_and_without_remat, what,
                                                path):
     (loss, grads, after), (r_loss, r_grads, r_after) = (
@@ -174,9 +181,11 @@ def test_remat_changes_nothing_to_the_last_bit(with_and_without_remat, what,
                  if what == "gradient"
                  else (leaf(r_after, path), leaf(after, path)))
     assert np.abs(want).max() > 0
-    if what == "gradient" and path.split("/")[-2] in ("a", "b"):
-        # float32 products at precision highest whose operand the backward
-        # pass makes again: this CPU sums them in another order there
+    if what == "gradient" and path.split("/")[-2] in ("a", "b", "norm"):
+        # float32 products at precision highest, and the gated norm's
+        # float32 sum over the tokens, whose operand the backward pass
+        # makes again: this CPU sums them in another order there (with
+        # and without the projections' names kept)
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-9)
         return
     np.testing.assert_array_equal(got, want)
@@ -256,7 +265,7 @@ def test_a_rematerialised_block_keeps_what_its_feed_forward_made(
 
 def test_without_remat_the_feed_forward_s_names_are_identities(monkeypatch):
     """Not rematerialised, the gradient is the one without the names but
-    for three ``name`` equations a layer."""
+    for three ``name`` equations a layer and one a mixer's projection."""
     from test_lm_pattern import _equations
 
     from metaopt_tpu.models import lm
@@ -277,70 +286,192 @@ def test_without_remat_the_feed_forward_s_names_are_identities(monkeypatch):
     assert "checkpoint" not in named
     # the gating stands apart from the matmuls around it, forward and (the
     # barrier's transpose) backward, named or not
-    assert named.count("optimization_barrier") == 2 * 2 \
+    # (and so does the head's bfloat16 input from its two matmuls)
+    assert named.count("optimization_barrier") == 2 * 2 + 2 \
         == unnamed.count("optimization_barrier")
-    assert named.count("name") - unnamed.count("name") == 3 * 2
+    # the feed-forward's three a layer, a linear mixer's seven, a full
+    # layer's four
+    assert named.count("name") - unnamed.count("name") == 3 * 2 + 7 + 4
     assert [p for p in named if p != "name"] \
         == [p for p in unnamed if p != "name"]
 
 
 #: the benchmark cell's sizes (chipbench/configs/olmo-hybrid-7b-tp2.json):
-#: one row of 8192 tokens, 3840 x 11 008, 766.2 M parameters on a device
-#: that states 15.75 GiB
-CELL = dict(tokens=8192, d_model=3840, d_ff=11008, parameters=766_200_000,
-            bytes_limit=int(15.75 * 2 ** 30))
+#: one row of 8192 tokens, 3840 x 11 008, 15 heads of each kind, 766.2 M
+#: parameters on a device that states 15.75 GiB
+LIMIT = int(15.75 * 2 ** 30)
+CELL = dict(tokens=8192, d_model=3840, d_ff=11008, n_heads=15,
+            parameters=766_200_000, bytes_limit=LIMIT)
+#: family -> (its cell's configuration, the chipbench module that turns it
+#: into the program's description)
+CELLS = {"smallthinker": ("smallthinker-21b-a3b-ep4", "lm_config"),
+         "keye": ("keye-vl2-30b-a3b-ep8", "sparse_lm_config"),
+         "hybrid": ("olmo-hybrid-7b-tp2", "hybrid_lm_config")}
+FFN = ["ffn.down", "ffn.gate", "ffn.up"]
+ATT_IN = ["attention.q_proj", "attention.k_proj", "attention.v_proj"]
+ATT_OUT = ["attention.out_proj"]
+LIN_IN = [f"linear_attention.{n}_proj" for n in "qkvgab"]
+LIN_OUT = ["linear_attention.out_proj"]
 
 
-@pytest.mark.parametrize("layers, over, kept, room", [
-    (4, {}, ["ffn.down", "ffn.gate", "ffn.up"], 2_326_116_864),
-    # a row twice as long: 3.39 GB of products, the down ones 0.50
-    (4, {"tokens": 16384}, ["ffn.down"], 2_326_116_864),
-    # so long that the down products alone pass the room
-    (4, {"tokens": 16 * 8192}, [], 2_326_116_864),
+@functools.lru_cache(maxsize=None)
+def cell_model(family, rehearsal=False, **over):
+    """A family's cell as the benchmark describes it to the program, at
+    its own sizes or its rehearsal's, and what ``remat_on`` would tell the
+    rule of it on one device that states ``LIMIT`` (traced once a case)."""
+    import importlib
+    import json
+    import os
+
+    from chipbench.run import rehearsal_sizes
+    from metaopt_tpu.models import lm
+
+    name, module = CELLS[family]
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "chipbench", "configs", name + ".json")) as f:
+        config = json.load(f)
+    if rehearsal:
+        rehearsal_sizes(config)
+    model = lm.make_lm({**importlib.import_module(
+        "chipbench." + module).description(config), **over})
+    a, p = config["script_args"], model.pattern
+    return model, dict(
+        tokens=a["batch_size"] * a["seq_len"], d_model=model.d_model,
+        d_ff=model.d_ff,
+        n_heads=p.heads_held[1] if p.heads_held else model.n_heads,
+        parameters=sum(x.size for x in jax.tree.leaves(nn.meta.unbox(
+            jax.eval_shape(lm.param_init(model, (1, 8)),
+                           jax.random.PRNGKey(0))))), bytes_limit=LIMIT)
+
+
+def contracting_width(name, p, sizes):
+    """FLOPs of a kept product over the bytes of its output."""
+    linear = p.linear.heads * p.linear.value_dim if p.linear else None
+    return {"ffn.down": sizes["d_ff"],
+            "attention.out_proj": sizes["n_heads"] * p.head_dim,
+            "linear_attention.out_proj": linear}.get(name, sizes["d_model"])
+
+
+@pytest.mark.parametrize("family, layers, over, kept, room", [
+    ("hybrid", 4, {}, FFN + ATT_IN + LIN_IN + ATT_OUT, 2_326_116_864),
+    # a row twice as long: the gate and up products (2.89 GB) do not fit,
+    # every other name (2.05 GB) does
+    ("hybrid", 4, {"tokens": 16384},
+     FFN[:1] + ATT_IN + LIN_IN + LIN_OUT + ATT_OUT, 2_326_116_864),
+    # so long that the down products alone pass the room: only the full
+    # layer's q, k, v (1.51 GB) are small enough
+    ("hybrid", 4, {"tokens": 16 * 8192}, ATT_IN, 2_326_116_864),
     # the published depth on one device: the state alone passes the limit
-    (32, {"parameters": 6_129_600_000}, [], 0),
-    # 8 layers whose state leaves 4.6 GB: 3.39 GB of products against 2.3
-    (8, {}, ["ffn.down"], 2_326_116_864),
+    ("hybrid", 32, {"parameters": 6_129_600_000}, [], 0),
+    # 8 layers whose state leaves 4.6 GB: 3.39 GB of the feed-forward's
+    # products against 2.3; the mixers' 1.55 GB fit beside the down ones
+    ("hybrid", 8, {}, FFN[:1] + ATT_IN + LIN_IN + LIN_OUT + ATT_OUT,
+     2_326_116_864),
     # a backend that states no limit
-    (4, {"bytes_limit": None}, [], None)])
+    ("hybrid", 4, {"bytes_limit": None}, [], None),
+    # the two MoE cells: every name fits (0.47 and 0.94 GB), the output
+    # projection's first (28 and 32 heads x 128 against 2560 and 2048)
+    ("smallthinker", 4, None, ATT_OUT + ATT_IN, 3_203_477_504),
+    ("smallthinker", 4, {"bytes_limit": None}, [], None),
+    ("keye", 4, None, ATT_OUT + ATT_IN, 4_732_588_032),
+    ("keye", 4, {"bytes_limit": None}, [], None)])
 def test_the_rule_keeps_the_feed_forward_s_products_where_they_fit(
-        layers, over, kept, room):
+        family, layers, over, kept, room):
+    """At the cells' sizes, candidates in order of contracting width, each
+    held against what the ones before it left of the room."""
     from metaopt_tpu.models import lm
     from metaopt_tpu.ops import linear_attention
     from metaopt_tpu.ops.attention import REMAT_KEEPS
 
-    sizes = {**CELL, **over}
-    p = lm.make_lm(description(layers, layer_types=PERIOD * 8)).pattern
+    model, sizes = cell_model(family, num_hidden_layers=layers)
+    p = model.pattern
+    if family == "hybrid":      # the counts these cases were written with
+        assert layers != 4 or sizes == {**CELL, "parameters": 766_241_946}
+        sizes = {**CELL, **over}
+    else:
+        sizes = {**sizes, **(over or {})}
     said = lm.remat_keeps(p, **sizes)
-    assert said["keeps"] == list(REMAT_KEEPS + linear_attention.REMAT_KEEPS) \
-        + kept
+    today = list(REMAT_KEEPS + (linear_attention.REMAT_KEEPS
+                                if family == "hybrid" else ()))
+    assert said["keeps"] == today + kept
     assert said["room"] == room
     t = sizes["tokens"]
-    assert said["ffn_bytes"] == {"ffn.down": 2 * t * 3840,
-                                 "ffn.gate": 2 * t * 11008,
-                                 "ffn.up": 2 * t * 11008}
+    candidates = (FFN + ATT_IN + ATT_OUT + LIN_IN + LIN_OUT
+                  if family == "hybrid" else ATT_IN + ATT_OUT)
+    assert list(said["bytes"]) == candidates
+    widths = [contracting_width(n, p, sizes) for n in kept]
+    assert widths == sorted(widths, reverse=True)
+    if family == "hybrid":
+        full = layers // 4
+        assert {n: said["bytes"][n] for n in FFN + ATT_OUT + LIN_OUT
+                + LIN_IN[3:5]} == {
+            "ffn.down": layers * 2 * t * 3840,
+            "ffn.gate": layers * 2 * t * 11008,
+            "ffn.up": layers * 2 * t * 11008,
+            "attention.out_proj": full * 2 * t * 3840,
+            "linear_attention.out_proj": 3 * full * 2 * t * 3840,
+            "linear_attention.g_proj": 3 * full * 2 * t * 15 * 192,
+            "linear_attention.a_proj": 3 * full * 4 * t * 15}
+    else:
+        heads = {"smallthinker": 28, "keye": 32}[family]
+        assert said["bytes"] == {
+            "attention.q_proj": 4 * 2 * t * heads * 128,
+            "attention.k_proj": 4 * 2 * t * 4 * 128,
+            "attention.v_proj": 4 * 2 * t * 4 * 128,
+            "attention.out_proj": 4 * 2 * t * sizes["d_model"]}
+    standing = sum(said["bytes"][n] for n in kept)
     if room is not None:
-        standing = layers * sum(said["ffn_bytes"][n] for n in kept)
         assert standing <= room
-    if CELL == sizes and layers == 4:
-        assert standing == 1_694_498_816     # 4 x 423.6 MB
+        declined = set(candidates) - set(kept)      # they did not fit
+        assert not declined or sum(
+            said["bytes"][n] for n in declined) > room - standing
+    if (family, layers, over) == ("hybrid", 4, {}):
+        # 4 x 423.6 MB of the feed-forward, 0.52 GB of the four mixers'
+        # input projections, the full layer's output projection
+        assert standing == 1_694_498_816 + 521_994_240 + 62_914_560
+    if over is None:
+        assert standing == {"smallthinker": 469_762_048,
+                            "keye": 939_524_096}[family]
 
 
-@pytest.mark.parametrize("limit, kept", [
-    (None, ()), (2 ** 20, ()), (2 ** 34, ("ffn.down", "ffn.gate", "ffn.up"))])
-def test_the_model_and_the_span_are_told_the_same_once(monkeypatch, limit,
-                                                       kept):
+@pytest.mark.parametrize("limit", [LIMIT, None])
+@pytest.mark.parametrize("family", list(CELLS))
+def test_at_a_rehearsal_s_sizes_every_name_fits_or_none_is_kept(family,
+                                                                limit):
+    from metaopt_tpu.models import lm
+
+    model, sizes = cell_model(family, rehearsal=True)
+    said = lm.remat_keeps(model.pattern, **{**sizes, "bytes_limit": limit})
+    new = [n for n in said["keeps"] if n in said["bytes"]]
+    assert sorted(new) == (sorted(said["bytes"]) if limit else [])
+    assert len(said["bytes"]) == (14 if family == "hybrid" else 4)
+    widths = [contracting_width(n, model.pattern, sizes) for n in new]
+    assert widths == sorted(widths, reverse=True)
+    assert said["room"] == (limit and (limit - 16 * sizes["parameters"]) // 2)
+
+
+@pytest.mark.parametrize("family, limit, kept", [
+    ("hybrid", None, ()), ("hybrid", 2 ** 20, ()),
+    ("hybrid", 2 ** 34, tuple(FFN + ATT_IN + LIN_IN + ATT_OUT + LIN_OUT)),
+    ("smallthinker", None, ()),
+    ("smallthinker", 2 ** 34, tuple(ATT_OUT + ATT_IN)),
+    ("keye", None, ()), ("keye", 2 ** 34, tuple(ATT_OUT + ATT_IN))])
+def test_the_model_and_the_span_are_told_the_same_once(monkeypatch, family,
+                                                       limit, kept):
     """``LMTrial`` asks the rule once, inside ``trial.setup`` (the mesh
     exists there), and hands the one answer to the model's blocks and to
     the span; a device's limit is pinned in ``device_bytes_limit``'s
-    place."""
-    from test_lm_pattern import one_device
+    place. The model is traced once: the rule's count and the init share
+    the trace of one ``param_init``."""
+    import test_lm_pattern
+    import test_lm_selected
 
     from metaopt_tpu.models import lm
     from metaopt_tpu.utils import trace
 
-    asked = []
-    real = lm.remat_keeps
+    asked, traced = [], []
+    real, real_init = lm.remat_keeps, lm.DecoderOnlyLM.init
 
     def counting(p, **sizes):
         if sizes:  # not the bare model's own say of its pattern alone
@@ -348,25 +479,62 @@ def test_the_model_and_the_span_are_told_the_same_once(monkeypatch, limit,
         return real(p, **sizes)
 
     monkeypatch.setattr(lm, "remat_keeps", counting)
+    monkeypatch.setattr(lm.DecoderOnlyLM, "init", lambda *a, **kw: (
+        traced.append(a) or real_init(*a, **kw)))
     monkeypatch.setattr(lm, "device_bytes_limit", lambda mesh: limit)
-    trial = lm.LMTrial({**description(**PAIR), "remat": True},
-                       mesh=one_device(), n_train=4, batch_size=2, seq_len=S)
+    described = {"hybrid": lambda: description(**PAIR),
+                 "smallthinker": lambda: test_lm_pattern.description(
+                     [(0, 0), (1, 1)]),
+                 "keye": lambda: test_lm_selected.description(2)}[family]()
+    trial = lm.LMTrial({**described, "remat": True},
+                       mesh=test_lm_pattern.one_device(), n_train=4,
+                       batch_size=2, seq_len=S)
     said = trace.spans("trial.setup")[-1]["attrs"]["remat"]
     assert tuple(said["keeps"]) == trial.model.keeps
     assert trial.model.keeps[-len(kept):] == kept if kept \
-        else not set(trial.model.keeps) & set(lm.FFN_REMAT_KEEPS)
+        else not set(trial.model.keeps) & set(said["bytes"])
+    assert set(kept) <= set(said["bytes"])
     assert said["blocks"] == 2
     assert said["room"] == (None if limit is None else max(
         0, limit - 16 * asked[0]["parameters"]) // 2)
     params = jax.tree.leaves(nn.meta.unbox(trial.params))
-    assert asked == [dict(tokens=2 * S, d_model=D, d_ff=F,
-                          parameters=sum(x.size for x in params),
-                          bytes_limit=limit)]
+    model = trial.model
+    assert asked == [dict(
+        tokens=2 * S, d_model=model.d_model, d_ff=model.d_ff,
+        n_heads=HELD if family == "hybrid" else model.n_heads,
+        parameters=sum(x.size for x in params), bytes_limit=limit)]
+    assert len(traced) == 1
+
+
+def test_the_init_given_the_rule_s_function_places_the_same_trees():
+    """``init_sharded_lm`` given the ``param_init`` whose shapes the rule
+    counted places the same trees as one that makes its own."""
+    import optax
+    from test_lm_pattern import one_device
+
+    from metaopt_tpu.models import lm
+    from metaopt_tpu.parallel.mesh import use_mesh
+
+    model, tx, mesh = lm.make_lm(description(**PAIR)), optax.adamw(1e-3), \
+        one_device()
+    init_params = lm.param_init(model, (2, S))
+    assert lm.remat_on(model, mesh, (2, S), init_params) \
+        == lm.remat_on(model, mesh, (2, S))
+    with use_mesh(mesh):
+        own = lm.init_sharded_lm(model, mesh, tx, (2, S), 3)
+        given = lm.init_sharded_lm(model, mesh, tx, (2, S), 3, init_params)
+    assert jax.tree.structure(own) == jax.tree.structure(given)
+    for a, b in zip(jax.tree.leaves(own), jax.tree.leaves(given)):
+        if isinstance(a, jax.Array):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        else:
+            assert a == b
 
 
 def test_a_mesh_s_axes_divide_what_a_device_holds():
-    """Two devices over ``tp`` hold half of the feed-forward's width and of
-    the partitioned kernels; two over ``dp`` half of the step's tokens."""
+    """Two devices over ``tp`` hold half of the feed-forward's width, of
+    every kind of head and of the partitioned kernels; two over ``dp`` half
+    of the step's tokens."""
     from jax.sharding import Mesh
 
     from metaopt_tpu.models import lm
@@ -376,12 +544,19 @@ def test_a_mesh_s_axes_divide_what_a_device_holds():
     for shape in ((1, 1), (2, 1), (1, 2)):
         mesh = Mesh(np.array(jax.devices()[:shape[0] * shape[1]]).reshape(
             shape), ("dp", "tp"))
-        said[shape] = lm.remat_on(model, mesh, (2, S))["ffn_bytes"]
-    assert said[1, 1] == {"ffn.down": 2 * 2 * S * D, "ffn.gate": 2 * 2 * S * F,
-                          "ffn.up": 2 * 2 * S * F}
+        said[shape] = lm.remat_on(model, mesh, (2, S))["bytes"]
+    t = 2 * S
+    assert said[1, 1] == {                      # a linear and a full layer
+        "ffn.down": 2 * 2 * t * D, "ffn.gate": 2 * 2 * t * F,
+        "ffn.up": 2 * 2 * t * F,
+        **{n: 2 * t * HELD * HD for n in ATT_IN}, "attention.out_proj": 2 * t * D,
+        **{n: 2 * t * HELD * w for n, w in zip(LIN_IN, (KD, KD, VD, VD))},
+        **{n: 4 * t * HELD for n in LIN_IN[4:]},
+        "linear_attention.out_proj": 2 * t * D}
     assert said[2, 1] == {k: v // 2 for k, v in said[1, 1].items()}
-    assert said[1, 2] == {**said[1, 1], "ffn.gate": 2 * S * F,
-                          "ffn.up": 2 * S * F}
+    whole = FFN[:1] + ATT_OUT + LIN_OUT         # as wide as the stream
+    assert said[1, 2] == {k: v if k in whole else v // 2
+                          for k, v in said[1, 1].items()}
 
 
 # -- the description -------------------------------------------------------------
@@ -493,9 +668,11 @@ def test_a_description_that_stood_builds_the_pattern_it_built(family):
     assert not p.norm_after and not p.qk_norm_whole and p.heads_held is None
     assert p.kinds() == (["global-nope", "window-rope"]
                          if family == "smallthinker" else ["selected-rope"])
-    assert lm.remat_keeps(p, tokens=8192, d_model=64, d_ff=96,
-                          parameters=10 ** 6, bytes_limit=2 ** 34) == {
-        "keeps": ["attention.out", "attention.lse", "attention.selected"]}
+    said = lm.remat_keeps(p, tokens=8192, d_model=64, d_ff=96, n_heads=4,
+                          parameters=10 ** 6, bytes_limit=2 ** 34)
+    assert said["keeps"] == ["attention.out", "attention.lse",
+                             "attention.selected"] + ATT_IN + ATT_OUT
+    assert list(said["bytes"]) == ATT_IN + ATT_OUT
     model = lm.make_lm(h)
     params = jax.eval_shape(lambda: nn.meta.unbox(model.init(
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32),
@@ -636,13 +813,15 @@ def test_train_lm_says_which_layers_are_linear_and_what_a_block_keeps():
         "linear": {"route": "xla", "chunk": CHUNK, "layers": [0],
                    "heads": [HELD, HEADS], "key_dim": KD, "value_dim": VD,
                    "conv": 4}}
-    # this backend states no limit: the feed-forward's products are sized
-    # and not kept
+    # this backend states no limit: the products are sized and not kept
+    sizes = setup["remat"].pop("bytes")
     assert setup["remat"] == {"blocks": 2, "keeps": [
         "attention.out", "attention.lse", "attention.selected",
-        "linear_attention.out", "linear_attention.states"],
-        "ffn_bytes": {"ffn.down": 2 * 2 * S * D, "ffn.gate": 2 * 2 * S * F,
-                      "ffn.up": 2 * 2 * S * F}, "room": None}
+        "linear_attention.out", "linear_attention.states"], "room": None}
+    assert list(sizes) == FFN + ATT_IN + ATT_OUT + LIN_IN + LIN_OUT
+    assert {n: sizes[n] for n in FFN} == {
+        "ffn.down": 2 * 2 * 2 * S * D, "ffn.gate": 2 * 2 * 2 * S * F,
+        "ffn.up": 2 * 2 * 2 * S * F}
     assert "moe" not in setup
 
 

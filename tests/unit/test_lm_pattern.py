@@ -559,8 +559,14 @@ def test_train_lm_reports_the_routing_s_counts_once(tmp_path):
                             "products": "ragged_dot",
                             "buffer_rows": 2 * S * TOPK,
                             "chunk_rows": 2 * S * TOPK}
+    # this backend states no limit: the projections are sized and not kept
     assert setup["remat"] == {"blocks": 2, "keeps": [
-        "attention.out", "attention.lse", "attention.selected"]}
+        "attention.out", "attention.lse", "attention.selected"],
+        "room": None, "bytes": {
+            "attention.q_proj": 2 * 2 * 2 * S * H * HD,
+            "attention.k_proj": 2 * 2 * 2 * S * KV * HD,
+            "attention.v_proj": 2 * 2 * 2 * S * KV * HD,
+            "attention.out_proj": 2 * 2 * 2 * S * D}}
 
 
 def test_the_trial_hands_out_its_loop_step_by_step():
@@ -749,6 +755,9 @@ def kept_or_not():
 
         def gradient(remat):
             model = lm.make_lm(description(layers, remat=remat))
+            if remat:   # the projections' names too (a bare remat has none)
+                model = model.clone(keeps=tuple(lm.remat_keeps(
+                    model.pattern)["keeps"]) + lm.ATTENTION_REMAT_KEEPS)
             params = seeded(model, tokens)
             return jax.value_and_grad(lambda p: lm.lm_loss_fn(
                 model, p, tokens, jax.random.PRNGKey(0))), params
@@ -766,8 +775,9 @@ def kept_or_not():
             with pytest.MonkeyPatch.context() as patch:
                 _on_the_kernels(patch)
                 if how == "unnamed":
-                    patch.setattr(attention, "checkpoint_name",
-                                  lambda x, name: x)
+                    for module in (attention, lm):
+                        patch.setattr(module, "checkpoint_name",
+                                      lambda x, name: x)
                 fn, params = gradient(False)
                 side[how] = _primitives(jax.make_jaxpr(fn)(params))
         out[kind] = side
@@ -785,7 +795,8 @@ def test_a_rematerialised_block_runs_its_forward_kernel_once(kept_or_not,
 
 
 @pytest.mark.parametrize("path", ["loss"] + LEAVES + [
-    "h1/attn/q/kernel", "h1/attn/out/kernel", "h1/experts/down"])
+    "h1/attn/q/kernel", "h1/attn/out/kernel", "h1/experts/down",
+    "h1/attn/k/kernel", "h1/attn/v/kernel"])
 @pytest.mark.parametrize("kind", REMAT_KINDS)
 def test_what_is_kept_is_what_would_be_made_again(kept_or_not, kind, path):
     """Loss and every gradient leaf equal to the last bit."""
@@ -802,10 +813,11 @@ def test_what_is_kept_is_what_would_be_made_again(kept_or_not, kind, path):
 @pytest.mark.parametrize("kind", REMAT_KINDS)
 def test_without_remat_the_names_are_identities(kept_or_not, kind):
     """Not rematerialised, the gradient is the one without the names, but
-    for a ``name`` equation on ``out`` and on ``lse`` a layer."""
+    for a ``name`` equation on ``out`` and on ``lse`` a layer and on each
+    of its four projections' products."""
     plain, unnamed = kept_or_not[kind]["plain"], kept_or_not[kind]["unnamed"]
     assert "name" not in unnamed and "checkpoint" not in plain
-    assert plain.count("name") == 2 * 2
+    assert plain.count("name") == (2 + 4) * 2
     assert [p for p in plain if p != "name"] == unnamed
 
 

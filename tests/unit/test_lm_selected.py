@@ -620,6 +620,9 @@ def with_and_without_remat():
     out = {}
     for remat in (False, True):
         model = lm.make_lm(description(2, remat=remat))
+        if remat:  # every name the rule can say: the projections' too
+            model = model.clone(keeps=tuple(lm.remat_keeps(
+                model.pattern)["keeps"]) + lm.ATTENTION_REMAT_KEEPS)
         params = nn.meta.unbox(model.init(
             jax.random.PRNGKey(0), tokens[:, :-1], train=False)["params"])
         tx = optax.adamw(1e-2)
@@ -640,7 +643,8 @@ def with_and_without_remat():
 @pytest.mark.parametrize("what", ["gradient", "update"])
 @pytest.mark.parametrize("path", ["loss"] + [p for p in LEAVES
                                              if "/e" not in p] + [
-    "h1/attn/q/kernel", "h1/experts/down", "h0/experts/gate"])
+    "h1/attn/q/kernel", "h1/experts/down", "h0/experts/gate",
+    "h1/attn/k/kernel", "h1/attn/out/kernel", "h1/attn/k_norm/scale"])
 def test_remat_changes_nothing_to_the_last_bit(with_and_without_remat, what,
                                                path):
     (loss, grads, after), (r_loss, r_grads, r_after) = (

@@ -327,7 +327,27 @@ def test_trial_setup_s_span_says_which_attention_route_the_steps_take(
 
 _NAMES = "attention.out, attention.lse, attention.selected"
 _FFN = {"ffn.down": 62_914_560, "ffn.gate": 180_355_072,
-        "ffn.up": 180_355_072}
+        "ffn.up": 180_355_072}        # a block's
+_ATT = {"attention.q_proj": 234_881_024, "attention.k_proj": 33_554_432,
+        "attention.v_proj": 33_554_432, "attention.out_proj": 167_772_160}
+#: the hybrid cell's fourteen candidates (models/lm.py::remat_keeps there)
+_HYBRID = {
+    **{n: 4 * b for n, b in _FFN.items()},
+    **{f"attention.{n}_proj": 31_457_280 for n in "qkv"},
+    "attention.out_proj": 62_914_560,
+    "linear_attention.q_proj": 70_778_880,
+    "linear_attention.k_proj": 70_778_880,
+    "linear_attention.v_proj": 141_557_760,
+    "linear_attention.g_proj": 141_557_760,
+    "linear_attention.a_proj": 1_474_560,
+    "linear_attention.b_proj": 1_474_560,
+    "linear_attention.out_proj": 188_743_680}
+
+
+def _over(blocks):
+    """The feed-forward's bytes over ``blocks`` blocks: a name's bytes
+    stand over all the layers that make it."""
+    return {n: blocks * b for n, b in _FFN.items()}
 
 
 @pytest.mark.parametrize("blocks, keeps, line", [
@@ -336,28 +356,48 @@ _FFN = {"ffn.down": 62_914_560, "ffn.gate": 180_355_072,
     # a harness's own say (models/lm.py::remat_on), as a function of the
     # mesh: a gated feed-forward's products kept, declined in part, declined
     # whole, and on a device that states no limit
-    (4, {"keeps": ["attention.out", *_FFN], "ffn_bytes": _FFN,
+    (4, {"keeps": ["attention.out", *_FFN], "bytes": _over(4),
          "room": 2_325_067_032},
      "trial T-9: remat: 4 blocks keep attention.out, ffn.down, ffn.gate, "
      "ffn.up; kept bytes 1.69 GB of room 2.33 GB\n"),
-    (8, {"keeps": ["attention.out", "ffn.down"], "ffn_bytes": _FFN,
+    (8, {"keeps": ["attention.out", "ffn.down"], "bytes": _over(8),
          "room": 2_325_067_032},
      "trial T-9: remat: 8 blocks keep attention.out, ffn.down; kept bytes "
      "0.50 GB of room 2.33 GB; ffn.gate, ffn.up: kept bytes 3.39 GB of room "
      "2.33 GB: not kept\n"),
-    (32, {"keeps": ["attention.out"], "ffn_bytes": _FFN, "room": 0},
+    (32, {"keeps": ["attention.out"], "bytes": _over(32), "room": 0},
      "trial T-9: remat: 32 blocks keep attention.out; ffn.down, ffn.gate, "
      "ffn.up: kept bytes 13.56 GB of room 0.00 GB: not kept\n"),
-    (4, {"keeps": ["attention.out"], "ffn_bytes": _FFN, "room": None},
+    (4, {"keeps": ["attention.out"], "bytes": _over(4), "room": None},
      "trial T-9: remat: 4 blocks keep attention.out; ffn.down, ffn.gate, "
      "ffn.up: kept bytes 1.69 GB of no room stated by the device: not "
-     "kept\n")])
+     "kept\n"),
+    # a model without a gated feed-forward (the 8k MoE decoder's cell): its
+    # attention's four projections, kept, and on a device without a limit
+    (4, {"keeps": ["attention.out", "attention.out_proj", *list(_ATT)[:3]],
+         "bytes": _ATT, "room": 3_202_428_672},
+     "trial T-9: remat: 4 blocks keep attention.out, attention.out_proj, "
+     "attention.q_proj, attention.k_proj, attention.v_proj; kept bytes 0.47 "
+     "GB of room 3.20 GB\n"),
+    (4, {"keeps": ["attention.out"], "bytes": _ATT, "room": None},
+     "trial T-9: remat: 4 blocks keep attention.out; attention.q_proj, "
+     "attention.k_proj, attention.v_proj, attention.out_proj: kept bytes "
+     "0.47 GB of no room stated by the device: not kept\n"),
+    # one with (the hybrid cell): the linear layers' output projection is
+    # the one name that does not fit
+    (4, {"keeps": ["linear_attention.states", *[
+        n for n in _HYBRID if n != "linear_attention.out_proj"]],
+         "bytes": _HYBRID, "room": 2_324_732_464},
+     "trial T-9: remat: 4 blocks keep linear_attention.states, "
+     + ", ".join(n for n in _HYBRID if n != "linear_attention.out_proj")
+     + "; kept bytes 2.28 GB of room 2.32 GB; linear_attention.out_proj: "
+     "kept bytes 2.47 GB of room 2.32 GB: not kept\n")])
 def test_trial_setup_s_span_says_what_a_rematerialised_block_keeps(
         capsys, blocks, keeps, line):
     """``attrs["remat"]``: the blocks run again in the backward pass and
     the names of ops/attention.REMAT_KEEPS, or what a harness's function of
-    the mesh says (the names, a feed-forward's bytes, the room); absent
-    without remat."""
+    the mesh says (the names, each candidate product's bytes, the room);
+    absent without remat."""
     import jax
     import numpy as np
     from jax.sharding import Mesh
