@@ -1,0 +1,9 @@
+"""``moe_experts_device_ms`` in a latent-attention MoE decoder's cell, read by
+that metric's own reader: the scope ``moe.experts``: the grouped products of the 16 held experts.
+An accepted metric's list of cells takes no new cell, so the cell reports it under a name of its own."""
+
+from chipbench.run import _reader
+
+
+def read(records):
+    return _reader("moe_experts_device_ms").read(records)

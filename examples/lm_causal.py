@@ -36,7 +36,16 @@ A description in the Qwen3-MoE family's words (``num_experts``,
 ``sa_config`` names a decoder whose attention runs over the keys an indexer
 selects (``python -m chipbench.sparse_lm_config
 chipbench/configs/keye-vl2-30b-a3b-ep8.json`` prints Keye-VL-2.0-30B-A3B's
-share of one chip; run it at ``--seq-len=16384``).
+share of one chip; run it at ``--seq-len=16384``). One with ``layer_types``
+(the Olmo hybrid family's) has linear-attention layers beside full ones.
+One in the DeepSeek-V3 family's words (``kv_lora_rank``,
+``qk_nope_head_dim``, ``qk_rope_head_dim``, ``v_head_dim``,
+``n_routed_experts``, ``n_shared_experts``, ``first_k_dense_replace``,
+``scoring_func``, ``topk_method``, ``routed_scaling_factor``) names the fifth
+kind of layer, *latent* attention, with a leading dense layer, sigmoid
+routing and shared experts (``python -m chipbench.mla_lm_config
+chipbench/configs/kanana-2-30b-a3b-ep8.json`` prints
+kanana-2-30b-a3b-instruct-2601's share of one chip; ``--seq-len=16384``).
 """
 
 import argparse
@@ -60,7 +69,10 @@ def main():
     p.add_argument("--ep", type=int, default=1)
     p.add_argument("--n-experts", dest="n_experts", type=int, default=0)
     p.add_argument("--model", help="a JSON description of the model "
-                   "(make_lm's hyperparameters) in place of the widths above")
+                   "(make_lm's hyperparameters) in place of the widths "
+                   "above; a layer is linear, latent (the DeepSeek-V3 "
+                   "family's words: kv_lora_rank ...), selected, window or "
+                   "global by what the description says")
     p.add_argument("--batch-size", dest="batch_size", type=int, default=32)
     p.add_argument("--n-train", dest="n_train", type=int, default=2048)
     p.add_argument("--warmup", type=int, default=10)

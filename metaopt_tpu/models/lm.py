@@ -36,6 +36,17 @@
   with RMS norms of q and k over the projected width; the block has that
   family's norm placement, x + norm(mixer(x)) then x + norm(ffn(x)), and
   the feed-forward is gated by ``hidden_act``.
+  With ``kv_lora_rank`` (the DeepSeek-V3 family's word) every layer is of
+  a fifth kind, *latent* (:class:`LatentAttention`: K and V come up from
+  one compressed latent a token, q.k is ``qk_nope_head_dim`` +
+  ``qk_rope_head_dim`` wide, the second part on ONE rotary key all heads
+  share, rotary pairs adjacent with ``rope_interleave``, v
+  ``v_head_dim`` wide); the first ``first_k_dense_replace`` layers take a
+  gated feed-forward of ``intermediate_size`` and the others route over
+  ``n_routed_experts`` by sigmoid scores (``scoring_func``), chosen with
+  a correction bias (``topk_method`` noaux_tc) that is a frozen leaf as
+  the indexer is, weighed without it (``norm_topk_prob``,
+  ``routed_scaling_factor``), beside ``n_shared_experts`` shared ones.
   The chip's share of a deployment is part of the description too:
   ``experts_held`` = (first, count) of the routed experts,
   ``vocab_held`` = (first, count) of the vocabulary's rows (ids are drawn
@@ -86,6 +97,7 @@ from metaopt_tpu.models.transformer import (
     residual,
     sharded_init,
 )
+from metaopt_tpu.models.moe import RoutingRule
 from metaopt_tpu.ops.attention import REMAT_KEEPS, CausalMask, attend
 from metaopt_tpu.parallel.sharding import with_mesh_partitioning
 from metaopt_tpu.utils import trace
@@ -111,28 +123,34 @@ class RMSNorm(nn.Module):
             jnp.mean(jnp.square(x), axis=-1, keepdims=True) + self.eps) * scale
 
 
-def rope(x, theta: float):
-    """Rotary positions 0..S-1 on ``x`` (B, S, H, D), float32: the two
-    halves of a head are the pairs (the ``rotate_half`` convention)."""
+def rope(x, theta: float, adjacent: bool = False):
+    """Rotary positions 0..S-1 on ``x`` (B, S, H, D), float32: pair j turns
+    by pos * theta^(-2j/D). The two halves of a head are the pairs,
+    channels (j, j + D/2) (the ``rotate_half`` convention), or, with
+    ``adjacent``, channels (2j, 2j + 1) (``rope_interleave``)."""
     s, d = x.shape[1], x.shape[-1]
     freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
     angle = jnp.arange(s, dtype=jnp.float32)[:, None] * freq[None]  # (S, D/2)
     cos, sin = jnp.cos(angle)[None, :, None], jnp.sin(angle)[None, :, None]
     x = x.astype(jnp.float32)
+    if adjacent:
+        a, b = x[..., 0::2], x[..., 1::2]
+        return jnp.stack([a * cos - b * sin, b * cos + a * sin],
+                         axis=-1).reshape(x.shape)
     a, b = x[..., :d // 2], x[..., d // 2:]
     return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1)
 
 
 #: the kinds of layer: (name, has positions to say). A layer is of the
 #: first kind it can be said to be, in :func:`layer_kind`'s order.
-KINDS = (("linear", False), ("selected", True), ("window", True),
-         ("global", True))
+KINDS = (("linear", False), ("latent", True), ("selected", True),
+         ("window", True), ("global", True))
 
 
 def layer_kind(sliding: bool, rotary: bool, selected: bool = False,
-               linear: bool = False) -> str:
-    for (name, positions), is_it in zip(KINDS,
-                                        (linear, selected, sliding, True)):
+               linear: bool = False, latent: bool = False) -> str:
+    for (name, positions), is_it in zip(
+            KINDS, (linear, latent, selected, sliding, True)):
         if is_it:
             return name + (("-rope" if rotary else "-nope") if positions
                            else "")
@@ -243,6 +261,90 @@ class GroupedAttention(nn.Module):
         )(out), kept_out)
 
 
+@dataclasses.dataclass(frozen=True)
+class LatentSpec:
+    """What a description says of its latent attention: the K/V latent's
+    ``rank``, a head's q.k width without positions (``nope``) and with
+    (``rope``: one rotary key for all heads), its value width ``v``, and
+    whether the rotary pairs are adjacent channels."""
+
+    rank: int
+    nope: int
+    rope: int
+    v: int
+    adjacent: bool
+
+
+#: What a rematerialised block keeps of a latent layer's projections where
+#: :func:`remat_keeps` finds the room: q's product, the down-projection's
+#: (the latent and the shared rotary key before its rotation: 2 (rank +
+#: rope) bytes a token, the cheapest thing a block can keep), the
+#: up-projection's (2 heads (nope + v): the dearest, and made again from
+#: the kept latent by a norm and a rank-deep matmul) and the output
+#: projection's.
+LATENT_REMAT_KEEPS = ("attention.q_proj", "attention.kv_latent",
+                      "attention.kv_up", "attention.out_proj")
+#: how the one rotary key reaches a latent layer's scores: "a", a copy of
+#: it joined to every head's own keys, one 192-row K a head for the
+#: kernels; "b" would be the kernel reading it once beside each head's
+#: keys: timed on the v5e at the cell's sizes, (a) is the faster by 2 to 7
+#: % of a layer's call (PERF.md section 6, PR 37), and the kernels need
+#: no second key for it
+LATENT_SHARED_KEY = "a"
+
+
+class LatentAttention(nn.Module):
+    """Causal self attention over a compressed K/V (the DeepSeek-V3
+    family's): q of ``n_heads`` heads ``nope + rope`` wide straight from x
+    (no q rank); c, k_pe = split(x W_kva, [rank, rope]); k_nope, v =
+    split(rmsnorm(c) W_kvb, [nope, v]) a head; rotary on q's last ``rope``
+    columns and on k_pe, the ONE key all heads share (a copy of it is
+    joined to every head's k_nope: ``LATENT_SHARED_KEY``); scores (q_nope .
+    k_nope + q_pe . k_pe) (nope + rope)^-1/2; out ``v`` wide a head, then
+    the output projection. No bias, no q/k norms. The down-projection, the
+    latent's norm, the up-projection and the shared key's rotary are under
+    the scope ``attention.latent``; the four matmuls' products carry the
+    names of ``LATENT_REMAT_KEEPS``."""
+
+    d_model: int
+    n_heads: int
+    spec: LatentSpec
+    rope_theta: float
+    eps: float
+
+    @nn.compact
+    @trace.scope("attention")
+    def __call__(self, x):
+        sp = self.spec
+        heads = lambda name, width: nn.DenseGeneral(  # noqa: E731
+            (self.n_heads, width), axis=-1, dtype=jnp.bfloat16, name=name,
+            use_bias=False, kernel_init=_pinit(True, (None, "tp", None)))
+        kept_q, kept_latent, kept_up, kept_out = LATENT_REMAT_KEEPS
+        x = x.astype(jnp.bfloat16)
+        q = checkpoint_name(heads("q", sp.nope + sp.rope)(x), kept_q)
+        with trace.scope("attention.latent"):
+            down = checkpoint_name(nn.Dense(
+                sp.rank + sp.rope, dtype=jnp.bfloat16, name="kv_a",
+                use_bias=False, kernel_init=_pinit(True, (None, None)))(x),
+                kept_latent)
+            c = RMSNorm(self.eps, name="kv_a_norm")(down[..., :sp.rank])
+            kv = checkpoint_name(heads("kv_b", sp.nope + sp.v)(
+                c.astype(jnp.bfloat16)), kept_up)
+            k_pe = rope(down[..., None, sp.rank:], self.rope_theta,
+                        sp.adjacent)[:, :, 0].astype(jnp.bfloat16)
+        q_pe = rope(q[..., sp.nope:], self.rope_theta, sp.adjacent)
+        q = (jnp.concatenate([q[..., :sp.nope].astype(jnp.float32), q_pe],
+                             axis=-1)
+             / math.sqrt(sp.nope + sp.rope)).astype(jnp.bfloat16)
+        k = jnp.concatenate([kv[..., :sp.nope], jnp.broadcast_to(
+            k_pe[:, :, None], (*kv.shape[:3], sp.rope))], axis=-1)
+        out = attend(q, k, kv[..., sp.nope:], CausalMask())
+        return checkpoint_name(nn.DenseGeneral(
+            self.d_model, axis=(-2, -1), dtype=jnp.bfloat16, name="out",
+            use_bias=False, kernel_init=_pinit(True, ("tp", None, None)),
+        )(out), kept_out)
+
+
 #: What a rematerialised block keeps of its gated feed-forward where
 #: :func:`remat_keeps` finds the room, in order of gain a byte: the down
 #: product, the module's output (2 d_model bytes a token; with the block's
@@ -331,13 +433,28 @@ class Pattern:
     norm_after: bool = False
     qk_norm_whole: bool = False
     heads_held: Optional[Tuple[int, int]] = None
+    #: the DeepSeek-V3 family's layer: every layer's attention is latent;
+    #: the first ``dense_layers`` layers take a gated feed-forward of
+    #: ``d_ff`` where the others route; beside the routed experts every
+    #: token meets shared ones, one gated feed-forward ``shared_d_ff``
+    #: wide; how the routing chooses and weighs
+    latent: Optional[LatentSpec] = None
+    dense_layers: int = 0
+    shared_d_ff: int = 0
+    routing: RoutingRule = RoutingRule()
 
     def is_linear(self, i: int) -> bool:
         return bool(self.linear_layers) and self.linear_layers[i]
 
+    def is_routed(self, i: int) -> bool:
+        return bool(self.n_experts) and i >= self.dense_layers
+
+    def routed_layers(self) -> int:
+        return sum(map(self.is_routed, range(len(self.layers))))
+
     def kind(self, i: int) -> str:
         return layer_kind(*self.layers[i], self.selection is not None,
-                          self.is_linear(i))
+                          self.is_linear(i), self.latent is not None)
 
     def kinds(self):
         """The distinct layer kinds, in the pattern's order."""
@@ -349,8 +466,11 @@ class PatternBlock(nn.Module):
     read from the FIRST norm's output, before attention, or (the pattern's
     ``router_after_attention``) from the second's; with the pattern's
     ``norm_after``, x + norm(mixer(x)) then + norm(ffn(.)), the mixer a
-    :class:`LinearAttention` where the layer is ``linear``. The residual
-    stream is float32."""
+    :class:`LinearAttention` where the layer is ``linear``. The attention
+    is a :class:`LatentAttention` where the pattern has a latent; a layer
+    that is not ``routed`` (a model without experts, a leading dense layer)
+    takes the gated feed-forward of ``d_ff``. The residual stream is
+    float32."""
 
     d_model: int
     n_heads: int
@@ -359,6 +479,7 @@ class PatternBlock(nn.Module):
     sliding: bool
     rotary: bool
     linear: bool = False
+    routed: bool = False
 
     @nn.compact
     def __call__(self, x):
@@ -375,7 +496,9 @@ class PatternBlock(nn.Module):
                     kernel_init=with_mesh_partitioning(
                         nn.initializers.lecun_normal(), (None, None)))(read)
 
-        attention = lambda: GroupedAttention(  # noqa: E731
+        attention = lambda: LatentAttention(  # noqa: E731
+            self.d_model, self.n_heads, p.latent, p.rope_theta, p.rms_eps,
+            name="attn") if p.latent is not None else GroupedAttention(
             self.d_model, self.n_heads, p.n_kv_heads, p.head_dim,
             p.window if self.sliding else None,
             p.rope_theta if self.rotary else None,
@@ -390,18 +513,24 @@ class PatternBlock(nn.Module):
                 GatedFeedForward(self.d_model, self.d_ff, p.activation,
                                  name="mlp")(x)))
         n = RMSNorm(p.rms_eps, name="norm_in")(x)
-        if p.n_experts and not p.router_after_attention:
+        if self.routed and not p.router_after_attention:
             logits = router(n)
         x = residual(x, attention()(n))
         m = RMSNorm(p.rms_eps, name="norm_post")(x)
-        if p.n_experts:
+        if self.routed:
             from metaopt_tpu.models.moe import DroplessMoE
 
             if p.router_after_attention:
                 logits = router(m)
+            # the rule's correction bias: a frozen leaf (``FROZEN``)
+            bias = self.param(
+                "choice_bias", with_mesh_partitioning(
+                    nn.initializers.zeros, (None,)),
+                (p.n_experts,)) if p.routing.bias else None
             return residual(x, DroplessMoE(
                 self.d_model, p.expert_d_ff, p.n_experts, p.top_k,
-                p.experts_held, p.activation, name="experts")(m, logits))
+                p.experts_held, p.activation, p.routing, p.shared_d_ff,
+                name="experts")(m, logits, bias))
         return residual(x, GatedFeedForward(
             self.d_model, self.d_ff, p.activation, name="mlp")(m))
 
@@ -502,7 +631,8 @@ class DecoderOnlyLM(nn.Module):
         heads = p.heads_held[1] if p.heads_held else self.n_heads
         for i, (sliding, rotary) in enumerate(p.layers):
             x = block_cls(self.d_model, heads, self.d_ff, p, sliding,
-                          rotary, p.is_linear(i), name=f"h{i}")(x)
+                          rotary, p.is_linear(i), p.is_routed(i),
+                          name=f"h{i}")(x)
         x = RMSNorm(p.rms_eps, name="norm_f")(x)
         head = table("head", self.d_model ** -0.5)
         if features:
@@ -532,7 +662,9 @@ _PUBLISHED = {"hidden_size": "d_model", "num_attention_heads": "n_heads",
               "num_experts_per_tok": "moe_num_active_primary_experts",
               "moe_intermediate_size": "moe_ffn_hidden_size",
               # the Olmo hybrid family's
-              "intermediate_size": "d_ff"}
+              "intermediate_size": "d_ff",
+              # the DeepSeek-V3 family's
+              "n_routed_experts": "moe_num_primary_experts"}
 
 #: a published ``layer_types`` entry -> is the layer linear?
 _LAYER_TYPES = {"linear_attention": True, "full_attention": False}
@@ -546,16 +678,48 @@ def _own_names(hparams: Dict[str, Any]) -> Dict[str, Any]:
     return h
 
 
+#: what a family's layer is, by the family and not by how a key of its
+#: description is spelt: RMS norms of q and k (over a head, or ``whole``:
+#: over the projected width), where the router reads, the norms' placement
+_FAMILIES = {
+    # SmallThinker's: the layouts' own keys
+    "layouts": {},
+    "qwen3_moe": {"qk_norm": True, "router_after_attention": True},
+    "olmo_hybrid": {"qk_norm": True, "qk_norm_whole": True,
+                    "norm_after": True},
+    "deepseek_v3": {"router_after_attention": True},
+}
+
+
+def family_of(h: Dict[str, Any]) -> Optional[str]:
+    """The family whose words a description with a layer pattern speaks
+    (a key of ``_FAMILIES``), None for one without a pattern:
+    ``kv_lora_rank`` is the DeepSeek-V3 family's, ``layer_types`` the Olmo
+    hybrid's, ``num_experts`` the Qwen3-MoE family's, the two layouts or
+    ``sa_config`` alone SmallThinker's."""
+    for key, family in (("kv_lora_rank", "deepseek_v3"),
+                        ("layer_types", "olmo_hybrid"),
+                        ("num_experts", "qwen3_moe"),
+                        ("sa_config", "layouts"),
+                        ("rope_layout", "layouts"),
+                        ("sliding_window_layout", "layouts")):
+        if key in h:
+            return family
+    return None
+
+
 def pattern_of(h: Dict[str, Any]) -> Optional[Pattern]:
     """The layer pattern a description names, or None. The two layouts are
     read up to ``n_layers`` (a cut in depth keeps the leading layers);
-    without them every layer is global and rotary. ``num_experts`` (the
-    Qwen3-MoE family's word) brings that family's q/k norms and router
-    placement, ``sa_config`` the selected attention, ``layer_types`` (the
-    Olmo hybrid family's) that family's block, its linear layers and, with
-    ``rope_parameters.rope_theta`` null, no positions anywhere."""
-    if not {"rope_layout", "sliding_window_layout", "sa_config",
-            "layer_types"} & set(h):
+    without them every layer is global and rotary. The family
+    (:func:`family_of`) brings its layer: the Qwen3-MoE family's q/k norms
+    and router placement, with ``sa_config`` the selected attention; the
+    Olmo hybrid family's block, its linear layers and, with
+    ``rope_parameters.rope_theta`` null, no positions anywhere; the
+    DeepSeek-V3 family's latent attention, leading dense layers, shared
+    experts and routing rule (:func:`_latent`, :func:`_routing`)."""
+    family = family_of(h)
+    if family is None:
         return None
     n_layers = int(h.get("n_layers", 6))
     types = h.get("layer_types")
@@ -578,6 +742,14 @@ def pattern_of(h: Dict[str, Any]) -> Optional[Pattern]:
     heads_held = held("heads_held", n_heads)
     share = lambda heads: int(heads) * heads_held[1] // n_heads  # noqa: E731
     linear_layers = tuple(_LAYER_TYPES[t] for t in (types or ())[:n_layers])
+    expert_d_ff = int(h.get("moe_ffn_hidden_size", h.get("d_ff", 2048)))
+    of_the_family = dict(_FAMILIES[family])
+    if family == "deepseek_v3":
+        of_the_family.update(
+            latent=_latent(h), routing=_routing(h),
+            dense_layers=min(int(h.get("first_k_dense_replace", 0)),
+                             n_layers),
+            shared_d_ff=int(h.get("n_shared_experts") or 0) * expert_d_ff)
     return Pattern(
         layers=tuple((bool(s), bool(r)) for s, r in
                      zip(sliding[:n_layers], rotary[:n_layers])),
@@ -589,19 +761,51 @@ def pattern_of(h: Dict[str, Any]) -> Optional[Pattern]:
         rms_eps=float(h.get("rms_norm_eps", 1e-6)),
         n_experts=n_experts,
         top_k=int(h.get("moe_num_active_primary_experts", 1)),
-        expert_d_ff=int(h.get("moe_ffn_hidden_size", h.get("d_ff", 2048))),
+        expert_d_ff=expert_d_ff,
         experts_held=held("experts_held", n_experts),
         vocab_held=held("vocab_held", vocab),
-        qk_norm="num_experts" in h or types is not None,
-        router_after_attention="num_experts" in h,
         activation=str(h.get("hidden_act", "relu")),
         selection=_selection(h.get("sa_config")),
         linear_layers=linear_layers,
         linear=_linear(h, share) if any(linear_layers) else None,
-        norm_after=types is not None,
-        qk_norm_whole=types is not None,
         heads_held=heads_held if "heads_held" in h else None,
+        **of_the_family,
     )
+
+
+def _latent(h: Dict[str, Any]) -> LatentSpec:
+    """The latent attention of a description in the DeepSeek-V3 family's
+    words. What has no layer here is refused by its name: a q rank (and
+    the norm that comes with it), rotary scaling (its ``mscale``)."""
+    for key in ("q_lora_rank", "rope_scaling"):
+        if h.get(key) is not None:
+            raise ValueError(f"{key} {h[key]!r}: a latent layer has none "
+                             "here")
+    return LatentSpec(
+        rank=int(h["kv_lora_rank"]), nope=int(h["qk_nope_head_dim"]),
+        rope=int(h["qk_rope_head_dim"]), v=int(h["v_head_dim"]),
+        adjacent=bool(h.get("rope_interleave", False)))
+
+
+def _routing(h: Dict[str, Any]) -> RoutingRule:
+    """The routing rule of a description in the DeepSeek-V3 family's words:
+    sigmoid scores and a correction bias (``topk_method`` noaux_tc), in
+    one group. A choice limited to groups of experts, an expert layer
+    every other layer, another scoring or another method have no rule
+    here and are refused by name."""
+    for key in ("n_group", "topk_group", "moe_layer_freq"):
+        if int(h.get(key, 1)) != 1:
+            raise ValueError(f"{key} {h[key]}: the routing knows one group "
+                             "of experts and an expert layer every layer")
+    scoring, method = h.get("scoring_func", "sigmoid"), \
+        h.get("topk_method", "noaux_tc")
+    if scoring != "sigmoid" or method != "noaux_tc":
+        raise ValueError(f"scoring_func {scoring!r} with topk_method "
+                         f"{method!r}: the family's rule here is sigmoid "
+                         "scores under noaux_tc")
+    return RoutingRule("sigmoid", bias=True,
+                       normalised=bool(h.get("norm_topk_prob", False)),
+                       scale=float(h.get("routed_scaling_factor", 1.0)))
 
 
 def _linear(h: Dict[str, Any], share) -> LinearSpec:
@@ -677,7 +881,9 @@ def _products(p: Pattern, tokens: int, d_model: int, d_ff: int,
     input at one width are one candidate, kept or declined together: a
     gated feed-forward's gate and up, a mixer's input projections (a linear
     layer's two float32 gates among them: nothing in bytes, six passes at
-    precision highest in time)."""
+    precision highest in time); a latent layer's q and its down-projection
+    are a candidate each (the second is a tenth of the first and the last
+    thing worth giving up)."""
     linear = sum(map(p.is_linear, range(len(p.layers))))
     out = []
 
@@ -686,10 +892,25 @@ def _products(p: Pattern, tokens: int, d_model: int, d_ff: int,
             out.append((width, {n: layers * tokens * b
                                 for n, b in bytes_a_token.items()}))
 
-    if not p.n_experts:
+    # the gated feed-forwards: a layer that is not routed has one d_ff
+    # wide, a routed one its shared experts'; one name is kept or declined
+    # over all of them, at the mean width of what it stands for
+    widths = [p.shared_d_ff if p.is_routed(i) else d_ff
+              for i in range(len(p.layers))]
+    if any(widths):
         down, gate, up = FFN_REMAT_KEEPS
-        add(d_ff, len(p.layers), {down: 2 * d_model})
-        add(d_model, len(p.layers), {gate: 2 * d_ff, up: 2 * d_ff})
+        made = sum(map(bool, widths))
+        add(sum(widths) / made, made, {down: 2 * d_model})
+        add(d_model, 1, {gate: 2 * sum(widths), up: 2 * sum(widths)})
+    if p.latent is not None:
+        sp = p.latent
+        q, latent, up, last = LATENT_REMAT_KEEPS
+        add(d_model, len(p.layers),
+            {q: 2 * n_heads * (sp.nope + sp.rope)})
+        add(d_model, len(p.layers), {latent: 2 * (sp.rank + sp.rope)})
+        add(sp.rank, len(p.layers), {up: 2 * n_heads * (sp.nope + sp.v)})
+        add(n_heads * sp.v, len(p.layers), {last: 2 * d_model})
+        return out
     q, k, v, last = ATTENTION_REMAT_KEEPS
     add(d_model, len(p.layers) - linear, {
         q: 2 * n_heads * p.head_dim, k: 2 * p.n_kv_heads * p.head_dim,
@@ -772,9 +993,11 @@ def describe_pattern(hparams: Dict[str, Any], route: str, tokens: int,
     pattern ({} without one), for steps of ``tokens`` tokens in rows of
     ``seq_len`` on attention route ``route``: for each kind of layer the
     route and the form of its mask, for a layer that selects its keys the
-    form its index scores take at that length, and the expert layers'
-    share, the product they take, the rows of their buffers and the rows a
-    trip of the routing's loops moves."""
+    form its index scores take at that length, for a latent layer its
+    widths and how its shared key reaches the scores, and the expert
+    layers' share, the product they take, the rows of their buffers, the
+    rows a trip of the routing's loops moves and, for the DeepSeek-V3
+    family, its routing rule, shared experts and leading dense layers."""
     from metaopt_tpu.models.moe import (grouped_matmul_impl,
                                         routing_chunk_rows)
 
@@ -795,6 +1018,13 @@ def describe_pattern(hparams: Dict[str, Any], route: str, tokens: int,
 
     out = {"attention_layers": {kind: {"route": route, "mask": mask_of(kind)}
                                 for kind in p.kinds() if kind != "linear"}}
+    if p.latent is not None:
+        sp = p.latent
+        for kind, said in out["attention_layers"].items():
+            said.update(
+                layers=[i for i in range(len(p.layers)) if p.kind(i) == kind],
+                heads=int(h.get("n_heads", 8)), nope=sp.nope, rope=sp.rope,
+                v=sp.v, rank=sp.rank, shared_key=LATENT_SHARED_KEY)
     if p.linear is not None:
         from metaopt_tpu.ops.linear_attention import linear_attention_route
 
@@ -819,6 +1049,11 @@ def describe_pattern(hparams: Dict[str, Any], route: str, tokens: int,
                           p.expert_d_ff),
                       "buffer_rows": tokens * p.top_k,
                       "chunk_rows": routing_chunk_rows(tokens * p.top_k)}
+        if p.latent is not None:  # the family's routing, beside the counts
+            out["moe"].update(
+                scoring=p.routing.scoring, bias=p.routing.bias,
+                scale=p.routing.scale, shared_d_ff=p.shared_d_ff,
+                dense_layers=p.dense_layers, d_ff=int(h.get("d_ff", 2048)))
     return out
 
 
@@ -874,13 +1109,16 @@ def lm_loss_fn(model, params, tokens, dropout_key,
 def moe_counts(mutated) -> Dict[str, Any]:
     """{"items": (layers, held) int32, "dropped": (layers,) int32,
     "chunks": (layers,) int32} from the ``moe_stats`` the dropless expert
-    layers sowed, layer by layer; empty for a model without such layers."""
+    layers sowed, routed layer by routed layer, and ``bias_moved``
+    (layers,) int32 where the routing rule has a correction bias; empty for
+    a model without such layers."""
     layers = [v for v in _by_layer(mutated.get("moe_stats", {}))
               if "items" in v.get("experts", {})]
     if not layers:
         return {}
     return {key: jnp.stack([v["experts"][key][0] for v in layers])
-            for key in ("items", "dropped", "chunks")}
+            for key in ("items", "dropped", "chunks", "bias_moved")
+            if key in layers[0]["experts"]}
 
 
 def _by_layer(collection) -> list:
@@ -922,18 +1160,20 @@ def _add_counts(total, new):
     return out
 
 
-#: the module whose parameters no gradient reaches (:class:`Indexer`)
-FROZEN = "indexer"
+#: the names no gradient reaches: a module's (:class:`Indexer`: its choice
+#: is piecewise constant) and a leaf's (a routing rule's correction bias,
+#: which enters the choice alone)
+FROZEN = ("indexer", "choice_bias")
 
 
 def split_frozen(params):
     """(trained, frozen): ``params`` without and with only the subtrees
-    named ``FROZEN``. The trained tree is what is differentiated and what
-    AdamW holds moments for; for a model without an indexer it is
+    named in ``FROZEN``. The trained tree is what is differentiated and
+    what AdamW holds moments for; for a model without such names it is
     ``params``' own structure and ``frozen`` is empty."""
     trained, frozen = {}, {}
     for name, sub in params.items():
-        if name == FROZEN:
+        if name in FROZEN:
             frozen[name] = sub
         elif isinstance(sub, dict):
             trained[name], below = split_frozen(sub)
@@ -1055,10 +1295,13 @@ class LMTrial:
         p = self.model.pattern
         layers = len(p.layers) if p is not None else 0
         if p is not None and p.n_experts:
+            routed = p.routed_layers()
             self.counts = {
-                "items": jnp.zeros((layers, p.experts_held[1]), jnp.int32),
-                "dropped": jnp.zeros((layers,), jnp.int32),
-                "chunks": jnp.zeros((layers,), jnp.int32)}
+                "items": jnp.zeros((routed, p.experts_held[1]), jnp.int32),
+                "dropped": jnp.zeros((routed,), jnp.int32),
+                "chunks": jnp.zeros((routed,), jnp.int32)}
+            if p.routing.bias:
+                self.counts["bias_moved"] = jnp.zeros((routed,), jnp.int32)
         if p is not None and p.selection:
             self.counts.update(
                 selected_pairs=jnp.zeros((layers, 2), jnp.int32),
@@ -1090,7 +1333,9 @@ class LMTrial:
     def read_counts(self) -> Dict[str, Any]:
         """The counts so far, copied to the host (one round trip): items a
         held expert, items dropped and the trips of a pass of the routing
-        over the buffers, a layer; and, where layers select their keys, the
+        over the buffers, a routed layer, with the tokens whose choice the
+        routing rule's bias moved where it has one; and, where layers
+        select their keys, the
         (query, key) pairs selected and the causal pairs they were chosen
         among, a layer."""
         return {k: [(hi << _LIMB) + lo for hi, lo in v.tolist()]

@@ -39,7 +39,9 @@ on gang-scheduled sub-slices.
 
 :class:`DroplessMoE` is the other expert layer, for the decoders whose
 description names ``moe_num_primary_experts``: top-k on the router's
-LOGITS, a softmax over the chosen ones, gated experts of three matrices
+LOGITS and a softmax over the chosen ones or, by the description's
+:class:`RoutingRule`, sigmoid scores chosen with a correction bias and
+weighed without it, with shared experts beside the routed ones; gated experts of three matrices
 (ReLU or SiLU on the gate, as the description says), and **no capacity**:
 every (token, choice) item is computed, whatever the imbalance. It is told which experts it holds (a contiguous
 share of the published count), routes over all of them, and returns the
@@ -58,6 +60,7 @@ runs without that exchange.
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import math
 from typing import Tuple
@@ -409,12 +412,56 @@ def _combine_bwd(res, g):
 _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
-def route_top_k(logits, k: int):
-    """(weights, experts), both (t, k): the k largest logits of each token
-    and a softmax over those k alone, float32."""
+@dataclasses.dataclass(frozen=True)
+class RoutingRule:
+    """How a token's experts are chosen and weighed from the router's
+    logits. ``softmax``: the k largest logits, a softmax over those k.
+    ``sigmoid`` (the DeepSeek-V3 family's): scores sigmoid(logits); the k
+    largest of score + bias where the family has a correction bias (it
+    enters the choice alone, never a weight, so no gradient reaches it);
+    the chosen scores over their sum + 1e-20 where ``normalised``, times
+    ``scale``."""
+
+    scoring: str = "softmax"
+    bias: bool = False
+    normalised: bool = True
+    scale: float = 1.0
+
+
+def route_top_k(logits, k: int, rule: RoutingRule = RoutingRule(),
+                bias=None):
+    """(weights, experts), both (t, k), float32 and int32, by ``rule``; ties
+    go to the lower index. ``bias`` (experts,): the rule's correction bias.
+    The one place a choice is made."""
     with trace.scope("moe.router"):
-        top, experts = jax.lax.top_k(logits.astype(jnp.float32), k)
-        return jax.nn.softmax(top, axis=-1), experts
+        logits = logits.astype(jnp.float32)
+        if rule.scoring == "softmax":
+            top, experts = jax.lax.top_k(logits, k)
+            return jax.nn.softmax(top, axis=-1), experts
+        if rule.scoring != "sigmoid":
+            raise ValueError(f"no routing rule scores by {rule.scoring!r}")
+        scores = jax.nn.sigmoid(logits)
+        _, experts = jax.lax.top_k(
+            scores + bias if rule.bias else scores, k)
+        weights = jnp.take_along_axis(scores, experts, axis=1)
+        if rule.normalised:
+            weights = weights / (jnp.sum(weights, axis=1, keepdims=True)
+                                 + 1e-20)
+        return weights * rule.scale, experts
+
+
+def bias_moved_tokens(logits, experts):
+    """() int32: the tokens whose chosen ``experts`` (t, k) are not the k
+    of the largest sigmoid scores of ``logits`` (t, E): those where the
+    least score among the chosen lies under the largest among the others,
+    one masked maximum. 0 = the correction bias never moved a choice."""
+    with trace.scope("moe.router"):
+        scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+        chosen = jnp.any(
+            experts[:, :, None] == jnp.arange(scores.shape[1]), axis=1)
+        least = jnp.min(jnp.where(chosen, scores, jnp.inf), axis=1)
+        other = jnp.max(jnp.where(chosen, -jnp.inf, scores), axis=1)
+        return jnp.sum(least < other, dtype=jnp.int32)
 
 
 def dropless_experts(x, weights, experts, w_gate, w_up, w_down, first,
@@ -465,8 +512,13 @@ class DroplessMoE(nn.Module):
     """Gated experts under dropless top-k routing, for the share of the
     experts held here (module docstring). ``held`` = (first, count) of the
     ``n_experts`` published ones; the router's logits come from outside
-    (a decoder reads them before attention or after it); ``activation``
-    is the gate's, by the name a published config gives it."""
+    (a decoder reads them before attention or after it), as does the
+    correction bias of a ``rule`` that has one; ``activation`` is the
+    gate's, by the name a published config gives it. ``shared_d_ff`` > 0
+    adds the shared experts, one gated feed-forward of that width that
+    every token meets (models/lm.GatedFeedForward under ``moe.shared``):
+    whole on every chip, and on an ``ep`` axis added once, after the sum
+    over the axis."""
 
     d_model: int
     d_ff: int
@@ -474,10 +526,12 @@ class DroplessMoE(nn.Module):
     top_k: int
     held: Tuple[int, int]
     activation: str = "relu"
+    rule: RoutingRule = RoutingRule()
+    shared_d_ff: int = 0
 
     @nn.compact
     @trace.scope("moe")
-    def __call__(self, x, logits):
+    def __call__(self, x, logits, bias=None):
         from metaopt_tpu.parallel.mesh import active_mesh
 
         b, s, d = x.shape
@@ -492,7 +546,8 @@ class DroplessMoE(nn.Module):
             for name, shape in (("gate", (count, d, self.d_ff)),
                                 ("up", (count, d, self.d_ff)),
                                 ("down", (count, self.d_ff, d)))}
-        weights, experts = route_top_k(logits.reshape(b * s, -1), self.top_k)
+        logits = logits.reshape(b * s, -1)
+        weights, experts = route_top_k(logits, self.top_k, self.rule, bias)
         act = _ACTIVATIONS[self.activation]
         mesh = active_mesh()
         ep = dict(mesh.shape).get("ep", 1) if mesh is not None else 1
@@ -506,7 +561,18 @@ class DroplessMoE(nn.Module):
         self.sow("moe_stats", "items", counts["items"])
         self.sow("moe_stats", "dropped", counts["dropped"])
         self.sow("moe_stats", "chunks", counts["chunks"])
-        return y.reshape(b, s, d)
+        if self.rule.bias:
+            self.sow("moe_stats", "bias_moved",
+                     bias_moved_tokens(logits, experts))
+        y = y.reshape(b, s, d)
+        if self.shared_d_ff:
+            from metaopt_tpu.models.lm import GatedFeedForward
+
+            with trace.scope("moe.shared"):
+                y = y + GatedFeedForward(
+                    d, self.shared_d_ff, self.activation,
+                    name="shared")(x).astype(y.dtype)
+        return y
 
 
 #: a published config's ``hidden_act`` -> the gate's activation

@@ -21,7 +21,8 @@ memory-efficient implementations share one shape of custom VJP:
     ``_causal_fwd_kernel`` / ``_causal_bwd_kernel``: limits computed in
     the kernel from positions, K/V heads fewer than query heads (query
     head h reads K/V head ``h // group`` through the block index, no
-    copy), a program per (row, query head, tile) so that one head of the
+    copy), q.k and v each at a width of its own (a latent layer's 192 and
+    128), a program per (row, query head, tile) so that one head of the
     other sequence is all that VMEM holds (bounded at S 8192 and 16 384),
     and a walk that covers only the tiles the structure lets through:
     tiles beyond the causal limit or the window are **skipped, not
@@ -519,10 +520,10 @@ def _causal_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
                        window):
     """One (batch row, query head, q tile) program.
 
-    Shapes in VMEM: q, o (1, D, Bq); k, v (1, D, Sk), the head's K/V head;
-    lse (1, 1, 1, Bq) float32.
+    Shapes in VMEM: q (1, Dqk, Bq); k (1, Dqk, Sk) and v (1, Dv, Sk), the
+    head's K/V head; o (1, Dv, Bq); lse (1, 1, 1, Bq) float32.
     """
-    d, bq = q_ref.shape[1], q_ref.shape[2]
+    d, bq = o_ref.shape[1], q_ref.shape[2]
     q0 = pl.program_id(2) * bq
     rel = _rel(block_k, bq)
     q = q_ref[0]
@@ -540,7 +541,7 @@ def _causal_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, *, block_k: int,
         alpha = jnp.exp(m - m_new)
         pt = jnp.exp(st - m_new)
         l_new = alpha * l + jnp.sum(pt, axis=0, keepdims=True)
-        vt = v_ref[0, :, ks]                                # (D, Bk)
+        vt = v_ref[0, :, ks]                                # (Dv, Bk)
         return m_new, l_new, alpha * acc + _dot(vt, pt.astype(vt.dtype))
 
     m, l, acc = _walk3(
@@ -561,10 +562,11 @@ def _causal_bwd_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref,
     the tile's share of the head's dQ, summed over the K tiles of the head
     in a float32 scratch as ``_flash_bwd_kernel`` does.
 
-    Shapes in VMEM: k, v (1, D, Bk); dk, dv (1, D, Bk) float32; q, dO, dq
-    (1, D, Sq); lse, delta (1, 1, 1, Sq); dq scratch (D, Sq) float32.
+    Shapes in VMEM: k (1, Dqk, Bk), v (1, Dv, Bk); dk, dv likewise, float32;
+    q, dq (1, Dqk, Sq), dO (1, Dv, Sq); lse, delta (1, 1, 1, Sq); dq
+    scratch (Dqk, Sq) float32.
     """
-    d, bk = k_ref.shape[1], k_ref.shape[2]
+    bk = k_ref.shape[2]
     k0 = pl.program_id(2) * bk
     rel = _rel(bk, block_q)
 
@@ -572,29 +574,29 @@ def _causal_bwd_kernel(q_ref, g_ref, k_ref, v_ref, lse_ref, delta_ref,
     def _():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    kt = k_ref[0]                                           # (D, Bk)
+    kt = k_ref[0]                                           # (Dqk, Bk)
     kb = kt.T
     vb = v_ref[0].T
 
     def body(masked, i, carry):
         dk, dv = carry
         qs = _tile(i, block_q)
-        qt = q_ref[0, :, qs]                                # (D, Bq)
-        gt = g_ref[0, :, qs]
+        qt = q_ref[0, :, qs]                                # (Dqk, Bq)
+        gt = g_ref[0, :, qs]                                # (Dv, Bq)
         st = _dot(kb, qt)                                   # (Bk, Bq)
         if masked:
             st = jnp.where(_seen(rel, i * block_q - k0, window), st,
                            _NEG_BIG)
         pt = jnp.exp(st - lse_ref[0, 0, :, qs])
         dst = (pt * (_dot(vb, gt) - delta_ref[0, 0, :, qs])).astype(qt.dtype)
-        acc_ref[:, qs] += _dot(kt, dst)                     # dQ.T (D, Bq)
-        return (dk + _dot(qt, dst, _NT),                    # dK.T (D, Bk)
-                dv + _dot(gt, pt.astype(gt.dtype), _NT))
+        acc_ref[:, qs] += _dot(kt, dst)                     # dQ.T (Dqk, Bq)
+        return (dk + _dot(qt, dst, _NT),                    # dK.T (Dqk, Bk)
+                dv + _dot(gt, pt.astype(gt.dtype), _NT))    # dV.T (Dv, Bk)
 
     dk, dv = _walk3(
         _q_tiles_of(k0, bk, block_q, q_ref.shape[2] // block_q, window),
-        body, (jnp.zeros((d, bk), jnp.float32),
-               jnp.zeros((d, bk), jnp.float32)))
+        body, (jnp.zeros((k_ref.shape[1], bk), jnp.float32),
+               jnp.zeros((v_ref.shape[1], bk), jnp.float32)))
     dk_ref[0] = dk
     dv_ref[0] = dv
 
@@ -609,18 +611,22 @@ _causal_jit = functools.partial(
 
 @_causal_jit
 def _causal_forward(q, k, v, window, block_q, block_k, interpret):
-    """(out, lse) under a CausalMask. q (B, S, H, D); k, v (B, S, Hkv, D),
-    query head h reading K/V head h // (H // Hkv); S pre-padded."""
-    b, s, h, d = q.shape
+    """(out, lse) under a CausalMask. q (B, S, H, Dqk); k (B, S, Hkv, Dqk),
+    v (B, S, Hkv, Dv), query head h reading K/V head h // (H // Hkv); out
+    (B, S, H, Dv); S pre-padded."""
+    b, s, h, dqk = q.shape
+    dv = v.shape[3]
     group = h // k.shape[2]
-    q_spec = pl.BlockSpec((1, d, block_q), lambda i, hh, j: (i, hh, j))
-    kv_spec = pl.BlockSpec((1, d, s), lambda i, hh, j: (i, hh // group, 0))
+    at_q = lambda i, hh, j: (i, hh, j)            # noqa: E731
+    at_kv = lambda i, hh, j: (i, hh // group, 0)  # noqa: E731
     out, lse = _call(
         _causal_fwd_kernel, "flash_fwd", (b, h, s // block_q),
-        ("parallel", "parallel", "parallel"), [q_spec, kv_spec, kv_spec],
-        [q_spec, pl.BlockSpec((1, 1, 1, block_q),
-                              lambda i, hh, j: (i, hh, 0, j))],
-        [jax.ShapeDtypeStruct((b, h * d, s), q.dtype),
+        ("parallel", "parallel", "parallel"),
+        [pl.BlockSpec((1, dqk, block_q), at_q),
+         pl.BlockSpec((1, dqk, s), at_kv), pl.BlockSpec((1, dv, s), at_kv)],
+        [pl.BlockSpec((1, dv, block_q), at_q),
+         pl.BlockSpec((1, 1, 1, block_q), lambda i, hh, j: (i, hh, 0, j))],
+        [jax.ShapeDtypeStruct((b, h * dv, s), q.dtype),
          jax.ShapeDtypeStruct((b, h, 1, s), jnp.float32)],
         [], [_feature_major(q), _feature_major(k), _feature_major(v)],
         interpret, block_k=block_k, window=window)
@@ -631,30 +637,34 @@ def _causal_forward(q, k, v, window, block_q, block_k, interpret):
 def _causal_backward(q, k, v, out, lse, g, window, block_q, block_k,
                      interpret):
     """(dq, dk, dv) under a CausalMask. Shapes as ``_causal_forward``."""
-    b, s, h, d = q.shape
-    hkv = k.shape[2]
+    b, s, h, dqk = q.shape
+    hkv, dv_rows = k.shape[2], v.shape[3]
     group = h // hkv
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1).transpose(0, 2, 1)[:, :, None]  # (B, H, 1, S)
-    whole = pl.BlockSpec((1, d, s), lambda i, hh, j: (i, hh, 0))
-    kv_spec = pl.BlockSpec((1, d, block_k),
-                           lambda i, hh, j: (i, hh // group, j))
+    whole = lambda rows: pl.BlockSpec(  # noqa: E731
+        (1, rows, s), lambda i, hh, j: (i, hh, 0))
+    kv_spec = lambda rows: pl.BlockSpec(  # noqa: E731
+        (1, rows, block_k), lambda i, hh, j: (i, hh // group, j))
     stat = pl.BlockSpec((1, 1, 1, s), lambda i, hh, j: (i, hh, 0, 0))
-    tile = pl.BlockSpec((1, d, block_k), lambda i, hh, j: (i, hh, j))
+    tile = lambda rows: pl.BlockSpec(  # noqa: E731
+        (1, rows, block_k), lambda i, hh, j: (i, hh, j))
     dq, dk, dv = _call(
         _causal_bwd_kernel, "flash_bwd", (b, h, s // block_k),
         ("parallel", "parallel", "arbitrary"),
-        [whole, whole, kv_spec, kv_spec, stat, stat], [whole, tile, tile],
-        [jax.ShapeDtypeStruct((b, h * d, s), q.dtype),
-         jax.ShapeDtypeStruct((b, h * d, s), jnp.float32),
-         jax.ShapeDtypeStruct((b, h * d, s), jnp.float32)],
-        [(d, s)],
+        [whole(dqk), whole(dv_rows), kv_spec(dqk), kv_spec(dv_rows), stat,
+         stat], [whole(dqk), tile(dqk), tile(dv_rows)],
+        [jax.ShapeDtypeStruct((b, h * dqk, s), q.dtype),
+         jax.ShapeDtypeStruct((b, h * dqk, s), jnp.float32),
+         jax.ShapeDtypeStruct((b, h * dv_rows, s), jnp.float32)],
+        [(dqk, s)],
         [_feature_major(q), _feature_major(g), _feature_major(k),
          _feature_major(v), lse, delta],
         interpret, block_q=block_q, window=window)
 
     def group_sum(x, like):
         """A K/V head's gradient: the sum over the query heads reading it."""
+        d = like.shape[3]
         x = x.reshape(b, hkv, group, d, s).sum(axis=2).astype(like.dtype)
         return _heads_last(x.reshape(b, hkv * d, s), hkv)
 
@@ -796,7 +806,7 @@ def _chunked_forward(q, k, v, mask, block_k, dropout_rate, key):
 
     m0 = jnp.full((b, h, sq, 1), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((b, h, sq, 1), jnp.float32)
-    acc0 = jnp.zeros((b, h, sq, d), jnp.float32)
+    acc0 = jnp.zeros((b, h, sq, v.shape[3]), jnp.float32)
     (m, l, acc), _ = jax.lax.scan(body, (m0, l0, acc0), jnp.arange(nk))
     out = (acc / jnp.maximum(l, 1e-30)).astype(q.dtype)
     lse = jnp.where(
@@ -855,7 +865,7 @@ def _chunked_backward(q, k, v, mask, key, out, lse, g, block_k, dropout_rate):
     dq0 = jnp.zeros_like(qt)
     dq, (dks, dvs) = jax.lax.scan(body, dq0, jnp.arange(nk))
     dk = jnp.moveaxis(dks, 0, 2).reshape(b, h, sk, d)     # (nk,b,h,bk,d)
-    dv = jnp.moveaxis(dvs, 0, 2).reshape(b, h, sk, d)
+    dv = jnp.moveaxis(dvs, 0, 2).reshape(b, h, sk, v.shape[3])
     to_in = lambda t, ref: t.transpose(0, 2, 1, 3).astype(ref.dtype)  # noqa: E731
     return to_in(dq, q), to_in(dk, k), to_in(dv, v)
 
@@ -964,7 +974,10 @@ def flash_attention(
 
     q: (B, Sq, H, D) — pre-scaled (multiply by 1/sqrt(D) before calling);
     k, v: (B, Sk, Hkv, D), H a multiple of Hkv and query head h reading
-    K/V head h // (H // Hkv); mask: optional (B, Sq, Sk) bool, True =
+    K/V head h // (H // Hkv). Under a :class:`CausalMask`, and on the
+    chunked route under any mask, v may have a width of its own, the
+    output's (a latent layer's 192-wide q.k beside 128-wide values: no
+    operand is padded to a common width). mask: optional (B, Sq, Sk) bool, True =
     attend (shared across heads), or a :class:`CausalMask`, which the
     Pallas route computes from positions, skipping the tiles it hides, or
     a :class:`SelectedMask`, whose packed bits the Pallas route unpacks
@@ -985,6 +998,12 @@ def flash_attention(
     if q.shape[2] % k.shape[2] or k.shape[2] != v.shape[2]:
         raise ValueError(f"{q.shape[2]} query heads cannot share "
                          f"{k.shape[2]} K/V heads")
+    if q.shape[3] != k.shape[3]:
+        raise ValueError(f"q is {q.shape[3]} wide, its keys {k.shape[3]}")
+    if impl == "pallas" and v.shape[3] != q.shape[3] \
+            and not isinstance(mask, CausalMask):
+        raise ValueError("q.k and v of different widths have Pallas "
+                         "kernels under a CausalMask alone")
 
     b, sq, h, d = q.shape
     sk = k.shape[1]

@@ -15,7 +15,7 @@ variable is set (``hunt --profile-dir``), and never otherwise.
 A device operation carries the scopes it was traced under in its
 ``op_name`` (``jit(train_step)/transpose(jvp(DecoderOnlyLM))/.../h0/attn/
 attention/attention.core/...``). ``SCOPES`` closes over a train step's
-source: every operation the program writes is under one of its nineteen
+source: every operation the program writes is under one of its twenty-one
 names, and two rules read a path, :func:`layer_of` (which top-level scope
 owns it) and :func:`direction` (forward, the forward's second run under
 remat, backward, update). What carries no name of ``SCOPES`` the compiler
@@ -54,9 +54,16 @@ SCOPES = ("embed", "attention", "attention.core", "ffn", "readout_xent",
           "optimizer", "eval",
           # an expert layer (models/moe.DroplessMoE): all of it, and its parts
           "moe", "moe.router", "moe.dispatch", "moe.experts", "moe.combine",
+          # the shared experts beside the routed ones (DroplessMoE's
+          # ``shared_d_ff``: a gated feed-forward every token meets)
+          "moe.shared",
           # attention over selected keys (ops/sparse_index.py): the
           # indexer's projections and scores, and the exact top-k
           "attention.index", "attention.select",
+          # a latent layer's own (models/lm.LatentAttention): the K/V
+          # down-projection, the latent's norm, the up-projection and the
+          # shared key's rotary
+          "attention.latent",
           # a linear-attention mixer (models/lm.LinearAttention): all of it,
           # and the chunked scan of the gated delta rule
           # (ops/linear_attention.py), forward and backward
@@ -472,6 +479,16 @@ def print_routes(recs: List[dict]) -> None:
                           f"{how['conv']}, chunks of {how['chunk']} by "
                           f"{how['route']}")
                     continue
+                if kind.startswith("latent"):
+                    print(f"trial {r['trial']}: layers "
+                          f"{_runs(how['layers'])}: latent attention, "
+                          f"{how['heads']} heads, q\u00b7k {how['nope']} + "
+                          f"{how['rope']} rotary on one shared key"
+                          + (" (read once by the kernel)"
+                             if how["shared_key"] == "b" else "")
+                          + f", v {how['v']}, K/V rank "
+                          f"{how['rank']}, by {how['route']}")
+                    continue
                 scores = how.get("index_scores")
                 print(f"trial {r['trial']}: {kind} layers: {how['route']}, "
                       f"mask by {how['mask']}" + (
@@ -489,14 +506,25 @@ def print_routes(recs: List[dict]) -> None:
                 print(f"trial {r['trial']}: experts {first}-"
                       f"{first + count - 1} of {moe['routed_over']} held, "
                       f"top {moe['top_k']}, products by {moe['products']}")
+                if "scoring" in moe:
+                    bias = " on score + bias" if moe["bias"] else ""
+                    print(f"trial {r['trial']}: experts: "
+                          f"{moe['scoring']} scores, "
+                          f"{moe['top_k']} of {moe['routed_over']}{bias}, "
+                          f"weights scaled {moe['scale']:g}, {count} held, "
+                          f"shared as one of {moe['shared_d_ff']}; layers "
+                          f"{_runs(range(moe['dense_layers']))} dense "
+                          f"{moe['d_ff']}")
         counts = attrs.get("moe") if r["name"] == "trial.train" else None
         if counts:
-            for layer, items in enumerate(counts["items"]):
+            # the counts are the routed layers': behind the leading dense ones
+            dense = held.get(r["trial"], {}).get("dense_layers", 0)
+            for layer, items in enumerate(counts["items"], dense):
                 mean = sum(items) / len(items)
                 print(f"trial {r['trial']}: layer {layer}: {sum(items)} "
                       f"items to held experts, fullest "
                       f"{max(items) / mean if mean else 0.0:.2f}x the mean, "
-                      f"{counts['dropped'][layer]} dropped")
+                      f"{counts['dropped'][layer - dense]} dropped")
             said = held.get(r["trial"], {})
             rows = (attrs.get("steps", 0) * len(counts["items"])
                     * said.get("buffer_rows", 0))

@@ -97,6 +97,36 @@ def test_structural_masks_and_grouped_heads_compile_for_a_v5e(one_chip, case):
     assert compiled.as_text().count("tpu_custom_call") == 2  # fwd, bwd
 
 
+# (length, q.k width, v's, query heads, K/V heads): the latent-attention
+# cell's layer at its 16 384 tokens (192 rows a head: a tile and a half of
+# the MXU's depth), the same at the model's 32 768 positions, a length that
+# pads, and unequal widths on grouped K/V heads
+UNEQUAL = [(16384, 192, 128, 32, 32), (32768, 192, 128, 32, 32),
+           (1000, 192, 128, 4, 4), (2048, 192, 128, 8, 2),
+           (2048, 64, 128, 8, 8)]
+
+
+@pytest.mark.parametrize("case", UNEQUAL, ids=lambda c: "x".join(map(str, c)))
+def test_unequal_widths_compile_for_a_v5e(one_chip, case):
+    """q.k 192 deep against v 128 wide with no operand padded to a common
+    width: the backward's q, dO, dq (6 + 4 + 6 MiB at 16 384) and its
+    float32 scratch (12 MiB) stay inside the VMEM limit ``_call`` asks
+    for, and a 192-row block passes Mosaic's tiling."""
+    s, dqk, dv, h, hkv = case
+    shape = lambda *dims: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, CausalMask(), impl="pallas")
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        shape(1, s, h, dqk), shape(1, s, hkv, dqk),
+        shape(1, s, hkv, dv)).compile().as_text()
+    assert text.count("tpu_custom_call") == 2  # flash_fwd, flash_bwd
+    assert "flash_fwd" in text and "flash_bwd" in text
+
+
 # (length, query heads, K/V heads, head width): the selected-attention
 # cell's 16384 tokens (a program holds one (128, 16384) head of K and V and
 # a 1 MiB slab of the packed selection), a length that pads to 256-tiles,

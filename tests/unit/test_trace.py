@@ -212,9 +212,10 @@ def _under(names, scope):
 @pytest.mark.parametrize("scope", [
     s for s in trace.SCOPES if s != "eval" and not s.startswith("moe")
     # a decoder's selected-attention layers': tests/unit/test_lm_selected.py;
-    # its linear-attention layers': tests/unit/test_lm_hybrid.py
+    # its linear-attention layers': tests/unit/test_lm_hybrid.py; its
+    # latent layers' (and ``moe.shared``): tests/unit/test_lm_latent.py
     and s not in ("attention.index", "attention.select", "linear_attention",
-                  "linear_attention.core")])
+                  "linear_attention.core", "attention.latent")])
 def test_every_scope_names_ops_of_the_train_step(lowered_op_names, scope):
     assert _under(lowered_op_names, scope), scope
 

@@ -4,7 +4,7 @@ every operation the program writes has a layer (``layer_of``) and a
 direction (``direction``), in the forward, its second run under remat and
 the backward.
 
-The four steps are the benchmark's four model kinds at their rehearsal
+The five steps are the benchmark's five model kinds at their rehearsal
 sizes, lowered and not compiled. Each is lowered twice: as this backend
 routes it, and for the TPU with ``jax.default_backend`` answering "tpu", so
 that the Pallas kernels are on the path (lowering a kernel for the TPU needs
@@ -31,6 +31,7 @@ KINDS = {
     "selected-attention-decoder": ("keye-vl2-30b-a3b-ep8",
                                    "sparse_lm_config"),
     "hybrid-decoder": ("olmo-hybrid-7b-tp2", "hybrid_lm_config"),
+    "latent-decoder": ("kanana-2-30b-a3b-ep8", "mla_lm_config"),
 }
 #: no operation of the device: a literal, a function's end, and remat's own
 #: barrier around a block's kept values (jax names it ``.../remat2``)
@@ -180,7 +181,8 @@ def test_every_operation_of_a_train_step_has_a_layer(lowered_steps, kind,
 
 @pytest.mark.parametrize("kind, remat", [
     ("2017-base", False), ("pattern-decoder", True),
-    ("selected-attention-decoder", True), ("hybrid-decoder", True)])
+    ("selected-attention-decoder", True), ("hybrid-decoder", True),
+    ("latent-decoder", True)])
 def test_a_step_has_operations_in_every_direction_it_runs(lowered_steps,
                                                          kind, remat):
     """Forward, backward and update in every step; the forward's second
@@ -211,6 +213,8 @@ def test_a_step_has_operations_in_every_direction_it_runs(lowered_steps,
     ("hybrid-decoder", "linear_scan_bwd", 0, 3),
     ("hybrid-decoder", "flash_fwd", 1, 0),
     ("hybrid-decoder", "flash_bwd", 0, 1),
+    ("latent-decoder", "flash_fwd", 5, 0),
+    ("latent-decoder", "flash_bwd", 0, 5),
 ])
 def test_a_kernel_s_calls_by_direction(lowered_steps, kind, kernel, forward,
                                        backward):
@@ -231,6 +235,7 @@ def test_a_kernel_s_calls_by_direction(lowered_steps, kind, kernel, forward,
     ("pattern-decoder", "_causal_backward"),
     ("selected-attention-decoder", "_backward"),
     ("hybrid-decoder", "_bwd_pallas"),
+    ("latent-decoder", "_causal_backward"),
 ])
 def test_a_backward_rule_s_helpers_are_backward(lowered_steps, kind, helper):
     """What a kernel's backward rule runs around the kernel (the delta,
@@ -278,6 +283,17 @@ _BLOCK = ("jit(train_step)/transpose(jvp(DecoderOnlyLM))/DecoderOnlyLM."
     ("jit(train_step)/jvp(DecoderOnlyLM)/DecoderOnlyLM._patterned/h3/attn/"
      "attention/indexer/attention.index/jit(_scores_pallas)/index_scores/"
      "pallas_call", "attention", "forward"),
+    # a latent layer's own part of attention, its norm included; the
+    # shared experts' feed-forward is the expert layer's, not ``ffn``'s
+    ("jit(train_step)/jvp(DecoderOnlyLM)/DecoderOnlyLM._patterned/h0/attn/"
+     "attention/attention.latent/kv_a_norm/norm/rsqrt", "attention",
+     "forward"),
+    (_BLOCK + "rematted_computation/h1/attn/attention/attention.latent/"
+     "kv_b/dot_general", "attention", "forward.again"),
+    (_BLOCK + "h1/experts/moe/moe.shared/shared/ffn/down/dot_general",
+     "moe", "backward"),
+    ("jit(train_step)/jvp(DecoderOnlyLM)/DecoderOnlyLM._patterned/h0/mlp/"
+     "ffn/gate/dot_general", "ffn", "forward"),
     # the trunk
     ("jit(train_step)/jvp(DecoderOnlyLM)/DecoderOnlyLM._patterned/h0/"
      "residual/add", "residual", "forward"),
@@ -298,7 +314,8 @@ def test_the_two_rules_on_a_path(op_name, layer, direction):
 
 
 def test_the_layers_are_the_top_level_scopes_and_every_scope_has_one():
-    assert len(trace.SCOPES) == len(set(trace.SCOPES)) == 19
+    assert len(trace.SCOPES) == len(set(trace.SCOPES)) == 21
+    assert {"attention.latent", "moe.shared"} <= set(trace.SCOPES)
     assert set(trace.LAYERS) == {
         "embed", "attention", "ffn", "moe", "linear_attention",
         "readout_xent", "optimizer", "eval", "norm", "residual", "loss"}
