@@ -98,7 +98,8 @@ from metaopt_tpu.models.transformer import (
     sharded_init,
 )
 from metaopt_tpu.models.moe import RoutingRule
-from metaopt_tpu.ops.attention import REMAT_KEEPS, CausalMask, attend
+from metaopt_tpu.ops.attention import (REMAT_KEEPS, CausalMask, LatentKV,
+                                       attend, attention_route)
 from metaopt_tpu.parallel.sharding import with_mesh_partitioning
 from metaopt_tpu.utils import trace
 
@@ -284,13 +285,15 @@ class LatentSpec:
 #: projection's.
 LATENT_REMAT_KEEPS = ("attention.q_proj", "attention.kv_latent",
                       "attention.kv_up", "attention.out_proj")
-#: how the one rotary key reaches a latent layer's scores: "a", a copy of
-#: it joined to every head's own keys, one 192-row K a head for the
-#: kernels; "b" would be the kernel reading it once beside each head's
-#: keys: timed on the v5e at the cell's sizes, (a) is the faster by 2 to 7
-#: % of a layer's call (PERF.md section 6, PR 37), and the kernels need
-#: no second key for it
-LATENT_SHARED_KEY = "a"
+
+
+def _pairs_first(w):
+    """The last axis' channels (0, 1, 2, 3, ...) as (0, 2, ..., 1, 3, ...):
+    rotary on the adjacent pairs of ``w`` is rotary on the halves of this,
+    channel for channel, and a score sums over the channels in any order
+    that q and the key share."""
+    return jnp.swapaxes(w.reshape(*w.shape[:-1], -1, 2), -1, -2).reshape(
+        w.shape)
 
 
 class LatentAttention(nn.Module):
@@ -298,13 +301,21 @@ class LatentAttention(nn.Module):
     family's): q of ``n_heads`` heads ``nope + rope`` wide straight from x
     (no q rank); c, k_pe = split(x W_kva, [rank, rope]); k_nope, v =
     split(rmsnorm(c) W_kvb, [nope, v]) a head; rotary on q's last ``rope``
-    columns and on k_pe, the ONE key all heads share (a copy of it is
-    joined to every head's k_nope: ``LATENT_SHARED_KEY``); scores (q_nope .
+    columns and on k_pe, the ONE key all heads share; scores (q_nope .
     k_nope + q_pe . k_pe) (nope + rope)^-1/2; out ``v`` wide a head, then
     the output projection. No bias, no q/k norms. The down-projection, the
     latent's norm, the up-projection and the shared key's rotary are under
     the scope ``attention.latent``; the four matmuls' products carry the
-    names of ``LATENT_REMAT_KEEPS``."""
+    names of ``LATENT_REMAT_KEEPS``.
+
+    How the operands reach attention is ops/latent_attention.hand_over's
+    to say. ``"copies"``: q, k, v (B, S, H, D), rotary and the scale in
+    float32 arrays, a copy of the shared key joined to every head's
+    k_nope: what the reference takes, and the tests' oracle. ``"in
+    place"``: the matmuls leave their products feature-major and the
+    kernels read them where they lie; q's product reaches them in one
+    pass, with the rotary pairs' de-interleaving on W_q's and the shared
+    key's rotary columns (the parameters keep the published order)."""
 
     d_model: int
     n_heads: int
@@ -315,10 +326,29 @@ class LatentAttention(nn.Module):
     @nn.compact
     @trace.scope("attention")
     def __call__(self, x):
+        from metaopt_tpu.ops import latent_attention as la
+        from metaopt_tpu.parallel.mesh import active_mesh
+
         sp = self.spec
+        mesh = active_mesh()
+        in_place = la.hand_over(attention_route(0.0, mesh), mesh, sp.nope,
+                                sp.v) == "in place"
+        # in place the pairs are made halves where that costs a weight's
+        # bytes (W_q's rotary columns) or the one key's, not q's
+        halves = _pairs_first if in_place and sp.adjacent else (lambda w: w)
+        adjacent = sp.adjacent and not in_place
+        how = {"q": {}, "kv_b": {}, "out": {"axis": (-2, -1)}}
+        if in_place:
+            how = {"q": {"dot_general": lambda x, w, *a, **kw: la.project_t(
+                       x, jnp.concatenate([w[..., :sp.nope],
+                                           halves(w[..., sp.nope:])], -1),
+                       *a, **kw)},
+                   "kv_b": {"dot_general": la.project_t},
+                   "out": {"axis": (1, 2), "dot_general": la.contract_t}}
         heads = lambda name, width: nn.DenseGeneral(  # noqa: E731
             (self.n_heads, width), axis=-1, dtype=jnp.bfloat16, name=name,
-            use_bias=False, kernel_init=_pinit(True, (None, "tp", None)))
+            use_bias=False, kernel_init=_pinit(True, (None, "tp", None)),
+            **how[name])
         kept_q, kept_latent, kept_up, kept_out = LATENT_REMAT_KEEPS
         x = x.astype(jnp.bfloat16)
         q = checkpoint_name(heads("q", sp.nope + sp.rope)(x), kept_q)
@@ -330,18 +360,25 @@ class LatentAttention(nn.Module):
             c = RMSNorm(self.eps, name="kv_a_norm")(down[..., :sp.rank])
             kv = checkpoint_name(heads("kv_b", sp.nope + sp.v)(
                 c.astype(jnp.bfloat16)), kept_up)
-            k_pe = rope(down[..., None, sp.rank:], self.rope_theta,
-                        sp.adjacent)[:, :, 0].astype(jnp.bfloat16)
-        q_pe = rope(q[..., sp.nope:], self.rope_theta, sp.adjacent)
-        q = (jnp.concatenate([q[..., :sp.nope].astype(jnp.float32), q_pe],
-                             axis=-1)
-             / math.sqrt(sp.nope + sp.rope)).astype(jnp.bfloat16)
-        k = jnp.concatenate([kv[..., :sp.nope], jnp.broadcast_to(
-            k_pe[:, :, None], (*kv.shape[:3], sp.rope))], axis=-1)
-        out = attend(q, k, kv[..., sp.nope:], CausalMask())
+            k_pe = rope(halves(down[..., None, sp.rank:]), self.rope_theta,
+                        adjacent)[:, :, 0].astype(jnp.bfloat16)
+        if in_place:  # q (B, H (nope + rope), S), kv (B, H (nope + v), S)
+            q = la.rotary_scaled(q, self.n_heads, sp.nope, self.rope_theta,
+                                 1.0 / math.sqrt(sp.nope + sp.rope))
+            out = attend(q, LatentKV(kv, k_pe.transpose(0, 2, 1), sp.nope),
+                         None, CausalMask())
+            out = out.reshape(out.shape[0], self.n_heads, sp.v, -1)
+        else:         # q (B, S, H, nope + rope), kv (B, S, H, nope + v)
+            q_pe = rope(q[..., sp.nope:], self.rope_theta, adjacent)
+            q = (jnp.concatenate(
+                [q[..., :sp.nope].astype(jnp.float32), q_pe], axis=-1)
+                / math.sqrt(sp.nope + sp.rope)).astype(jnp.bfloat16)
+            k = jnp.concatenate([kv[..., :sp.nope], jnp.broadcast_to(
+                k_pe[:, :, None], (*kv.shape[:3], sp.rope))], axis=-1)
+            out = attend(q, k, kv[..., sp.nope:], CausalMask())
         return checkpoint_name(nn.DenseGeneral(
-            self.d_model, axis=(-2, -1), dtype=jnp.bfloat16, name="out",
-            use_bias=False, kernel_init=_pinit(True, ("tp", None, None)),
+            self.d_model, dtype=jnp.bfloat16, name="out", use_bias=False,
+            kernel_init=_pinit(True, ("tp", None, None)), **how["out"],
         )(out), kept_out)
 
 
@@ -988,16 +1025,19 @@ def _selection(sa: Optional[Dict[str, Any]]):
 
 
 def describe_pattern(hparams: Dict[str, Any], route: str, tokens: int,
-                     seq_len: Optional[int] = None) -> Dict[str, Any]:
+                     seq_len: Optional[int] = None,
+                     mesh: Optional[Mesh] = None) -> Dict[str, Any]:
     """What ``trial.setup``'s span says of a description with a layer
     pattern ({} without one), for steps of ``tokens`` tokens in rows of
-    ``seq_len`` on attention route ``route``: for each kind of layer the
-    route and the form of its mask, for a layer that selects its keys the
-    form its index scores take at that length, for a latent layer its
-    widths and how its shared key reaches the scores, and the expert
-    layers' share, the product they take, the rows of their buffers, the
-    rows a trip of the routing's loops moves and, for the DeepSeek-V3
-    family, its routing rule, shared experts and leading dense layers."""
+    ``seq_len`` on attention route ``route`` under ``mesh``: for each kind
+    of layer the route and the form of its mask, for a layer that selects
+    its keys the form its index scores take at that length, for a latent
+    layer its widths and how its operands reach attention
+    (ops/latent_attention.hand_over: ``"in place"`` or ``"copies"``), and
+    the expert layers' share, the product they take, the rows of their
+    buffers, the rows a trip of the routing's loops moves and, for the
+    DeepSeek-V3 family, its routing rule, shared experts and leading dense
+    layers."""
     from metaopt_tpu.models.moe import (grouped_matmul_impl,
                                         routing_chunk_rows)
 
@@ -1019,12 +1059,15 @@ def describe_pattern(hparams: Dict[str, Any], route: str, tokens: int,
     out = {"attention_layers": {kind: {"route": route, "mask": mask_of(kind)}
                                 for kind in p.kinds() if kind != "linear"}}
     if p.latent is not None:
+        from metaopt_tpu.ops.latent_attention import hand_over
+
         sp = p.latent
         for kind, said in out["attention_layers"].items():
             said.update(
                 layers=[i for i in range(len(p.layers)) if p.kind(i) == kind],
                 heads=int(h.get("n_heads", 8)), nope=sp.nope, rope=sp.rope,
-                v=sp.v, rank=sp.rank, shared_key=LATENT_SHARED_KEY)
+                v=sp.v, rank=sp.rank,
+                hand_over=hand_over(route, mesh, sp.nope, sp.v))
     if p.linear is not None:
         from metaopt_tpu.ops.linear_attention import linear_attention_route
 

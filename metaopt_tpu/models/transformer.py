@@ -491,10 +491,11 @@ def trial_setup(hparams: Dict[str, Any], mesh: Optional[Mesh],
     (``attrs["attention"]``), for the training and the evaluation rate, as
     ops/attention.attention_route names it on this mesh. ``describe``, a
     harness's own, is asked what else the span should say, given the route
-    without dropout (lm.py: each kind of layer's route and mask form, what
-    the expert layers hold and run their products with). ``remat_blocks``,
-    the blocks the model runs again in the backward pass, puts what each
-    keeps besides its input into ``attrs["remat"]``: ``remat_keeps``, the
+    without dropout and the mesh (lm.py: each kind of layer's route and
+    mask form, what the expert layers hold and run their products with).
+    ``remat_blocks``, the blocks the model runs again in the backward
+    pass, puts what each keeps besides its input into
+    ``attrs["remat"]``: ``remat_keeps``, the
     names, or a harness's function of the mesh that gives the names and
     what they were held against (lm.py::remat_on: the bytes of every
     product a block could keep and the device's room).
@@ -515,7 +516,7 @@ def trial_setup(hparams: Dict[str, Any], mesh: Optional[Mesh],
             "dropout": dropout, "train": attention_route(dropout, mesh),
             "eval": evaluation}
         if describe is not None:
-            setup["attrs"].update(describe(evaluation))
+            setup["attrs"].update(describe(evaluation, mesh=mesh))
         if remat_blocks:
             setup["attrs"]["remat"] = {
                 "blocks": remat_blocks,

@@ -65,8 +65,10 @@ Megatron head split survives instead of GSPMD all-gathering q/k/v.
 :func:`flash_attention` is the kernels' own entry and takes ``impl`` as
 its caller states it.
 
-A :class:`SelectedMask` (keys chosen a query: ops/selected_attention.py,
-loaded when one arrives). Not here: key lengths, segment ids and packing.
+A :class:`SelectedMask` (keys chosen a query: ops/selected_attention.py)
+and a :class:`LatentKV` (a latent layer's K and V read where its matmuls
+leave them: ops/latent_attention.py) have files of their own, loaded when
+one arrives. Not here: key lengths, segment ids and packing.
 """
 
 from __future__ import annotations
@@ -314,19 +316,21 @@ def _flash_bwd_kernel(*refs, n_heads: int, block_q: int, masked: bool):
 def _call(kernel, name, grid, semantics, in_specs, out_specs, out_shape,
           scratch, operands, interpret, **static):
     """pallas_call with the VMEM limit its blocks (double-buffered) and
-    float32 scratch need."""
+    scratch need; a scratch is a shape (float32) or says its dtype too."""
     blocks = [(sp.block_shape, x.dtype) for sp, x in zip(in_specs, operands)]
     blocks += [(sp.block_shape, o.dtype) for sp, o in zip(out_specs, out_shape)]
+    scratch = [s if hasattr(s, "dtype") else jax.ShapeDtypeStruct(
+        s, jnp.float32) for s in scratch]
     need = 2 * sum(math.prod(shp) * jnp.dtype(dt).itemsize
                    for shp, dt in blocks)
-    need += sum(4 * math.prod(shp) for shp in scratch)
+    need += sum(math.prod(s.shape) * s.dtype.itemsize for s in scratch)
     # the score tiles and their temporaries live beside the blocks
     limit = min(max(2 * need, _VMEM_FLOOR), _VMEM_CEIL)
     return pl.pallas_call(
         functools.partial(kernel, **static),
         out_shape=out_shape, grid=grid, in_specs=in_specs,
         out_specs=out_specs, name=name,
-        scratch_shapes=[pltpu.VMEM(shp, jnp.float32) for shp in scratch],
+        scratch_shapes=[pltpu.VMEM(s.shape, s.dtype) for s in scratch],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=semantics, vmem_limit_bytes=limit),
         interpret=interpret,
@@ -729,6 +733,21 @@ class SelectedMask:
         return (((w >> bit) & 1) != 0).reshape(b, s_p, s_p)[:, :sq, :sk]
 
 
+@dataclasses.dataclass(frozen=True)
+class LatentKV:
+    """K and V of a latent layer where its matmuls leave them, feature-major
+    (ops/latent_attention.py reads them in place): ``kv`` (B, H * (nope +
+    v), S), a head's ``nope`` rows of keys above its v rows of values, and
+    ``shared`` (B, rope, S), the one rotary key every head's scores add.
+    Takes k's place in :func:`flash_attention` with q (B, H * (nope +
+    rope), S) and no v, under a ``CausalMask()``, and the output comes
+    back (B, H * v, S)."""
+
+    kv: jax.Array
+    shared: jax.Array
+    nope: int
+
+
 # ---------------------------------------------------------------------------
 # chunked (lax.scan) twin — pure XLA, any backend, dropout-capable
 
@@ -991,6 +1010,13 @@ def flash_attention(
     off the chip). It is never chosen for the caller: ``impl="pallas"``
     compiles through Mosaic or raises with the compiler's message.
     """
+    if isinstance(k, LatentKV):
+        if impl != "pallas" or mask != CausalMask() or dropout_rate:
+            raise ValueError("a latent layer's operands are read in place "
+                             "by the causal Pallas kernels alone")
+        from metaopt_tpu.ops.latent_attention import flash_latent
+
+        return flash_latent(q, k, bool(interpret))
     if dropout_rate > 0.0 and impl == "pallas":
         raise ValueError("attention dropout requires impl='chunked'")
     if dropout_rate > 0.0 and dropout_key is None:

@@ -419,6 +419,15 @@ def _scores_form(said: dict) -> str:
     return f"pallas, tiles {tq} x {tk}, passes {said['depth']} deep"
 
 
+#: ``ops/latent_attention.py::hand_over``'s answer in words
+_HAND_OVER = {
+    "copies": "",
+    "in place": (", the kernels reading q after one pass, K, V and out "
+                 "where the matmuls leave them, the shared key joined in "
+                 "VMEM"),
+}
+
+
 def _runs(numbers) -> str:
     """[0, 1, 2, 4] -> "0-2, 4": sorted numbers as runs."""
     runs = []
@@ -483,11 +492,9 @@ def print_routes(recs: List[dict]) -> None:
                     print(f"trial {r['trial']}: layers "
                           f"{_runs(how['layers'])}: latent attention, "
                           f"{how['heads']} heads, q\u00b7k {how['nope']} + "
-                          f"{how['rope']} rotary on one shared key"
-                          + (" (read once by the kernel)"
-                             if how["shared_key"] == "b" else "")
-                          + f", v {how['v']}, K/V rank "
-                          f"{how['rank']}, by {how['route']}")
+                          f"{how['rope']} rotary on one shared key, v "
+                          f"{how['v']}, K/V rank {how['rank']}, by "
+                          f"{how['route']}" + _HAND_OVER[how["hand_over"]])
                     continue
                 scores = how.get("index_scores")
                 print(f"trial {r['trial']}: {kind} layers: {how['route']}, "

@@ -127,6 +127,55 @@ def test_unequal_widths_compile_for_a_v5e(one_chip, case):
     assert "flash_fwd" in text and "flash_bwd" in text
 
 
+# (length, heads): the latent-attention cell's layer at its 16 384 tokens and
+# 32 heads, the same at the model's 32 768 positions, and a short length
+IN_PLACE = [(16384, 32), (32768, 32), (1024, 4)]
+
+
+@pytest.mark.parametrize("case", IN_PLACE, ids=lambda c: "x".join(map(str, c)))
+def test_a_latent_layer_s_hand_over_compiles_for_a_v5e(one_chip, monkeypatch,
+                                                       case):
+    """models/lm.LatentAttention on the Pallas route, its gradient under the
+    block's remat policy: the kernels read q after ``latent_q``'s one pass
+    (forward, again, backward) and K, V and out in place: a 192-row q
+    block, 128-row halves of the up-projection's 256-row product by block
+    index, the keys' bfloat16 scratch (6 MiB at 16 384) beside the
+    double-buffered blocks inside the VMEM limit ``_call`` asks for; and
+    no float32 array as large as q is left in the compiled program."""
+    import re
+
+    from metaopt_tpu.models import lm
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    s, heads = case
+    layer = lm.LatentAttention(2048, heads,
+                               lm.LatentSpec(512, 128, 64, 128, True), 1e6,
+                               1e-6)
+    on_chip = lambda x: jax.ShapeDtypeStruct(  # noqa: E731
+        x.shape, x.dtype, sharding=one_chip)
+    x = on_chip(jax.ShapeDtypeStruct((1, s, 2048), jnp.float32))
+    params = jax.tree.map(on_chip, jax.eval_shape(lambda: layer.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, s, 2048)))["params"]))
+    policy = jax.checkpoint_policies.save_only_these_names(
+        "attention.out", "attention.lse", *(
+            n for n in lm.LATENT_REMAT_KEEPS if n != "attention.kv_up"))
+
+    def loss(p, x):
+        out = jax.checkpoint(lambda p, x: layer.apply({"params": p}, x),
+                             policy=policy)(p, x)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    calls = lambda name: len(re.findall(  # noqa: E731
+        rf'custom_call_target="tpu_custom_call".*/{name}/pallas_call', text))
+    assert (calls("flash_fwd"), calls("flash_bwd"), calls("latent_q")) == (
+        1, 1, 3)
+    entry = text[text.index("ENTRY"):]
+    assert not re.findall(
+        rf" = f32\[1,(?:{heads * 192},{s}|{s},{heads},192)\]", entry)
+
+
 # (length, query heads, K/V heads, head width): the selected-attention
 # cell's 16384 tokens (a program holds one (128, 16384) head of K and V and
 # a 1 MiB slab of the packed selection), a length that pads to 256-tiles,

@@ -478,6 +478,48 @@ def test_an_accepted_description_builds_the_pattern_it_built(name, module):
     assert got["routing"] == dataclasses.asdict(moe.RoutingRule())
 
 
+def _equations(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside its equations."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (list, tuple)) else [value]:
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    yield from _equations(inner)
+
+
+@pytest.mark.parametrize("kind, window, theta", [
+    ("global-nope", None, None), ("window-rope", 128, 1e6)])
+def test_a_grouped_layer_traces_to_the_program_it_traced_to(monkeypatch, kind,
+                                                            window, theta):
+    """The 8k decoder's and the hybrid's layer (``GroupedAttention`` through
+    ``attend`` under a ``CausalMask``: equal widths, grouped K/V heads, a
+    window and none) on the Pallas route: the gradient's equations, kernel
+    bodies and index maps included (primitive, a call's name, grid and
+    compiler parameters, the results' types) are the ones PR 37's tree
+    traced, by their count and hash: a latent layer's hand-over is another
+    entry, not another form of this one."""
+    import hashlib
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    layer = lm.GroupedAttention(64, 4, 2, 32, window, theta)
+    x = jnp.zeros((2, 256, 64))
+    params = jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0),
+                                               x)["params"])
+    jaxpr = jax.make_jaxpr(jax.grad(lambda p, x: jnp.sum(layer.apply(
+        {"params": p}, x).astype(jnp.float32)), argnums=(0, 1)))(params, x)
+    lines = [" ".join([
+        e.primitive.name, str(e.params.get("name", "")),
+        str(getattr(e.params.get("grid_mapping"), "grid", "")),
+        str(e.params.get("compiler_params", "")),
+        *(str(v.aval) for v in e.outvars)]) for e in _equations(jaxpr.jaxpr)]
+    assert sum("flash_fwd" in line or "flash_bwd" in line
+               for line in lines) == 2
+    assert [len(lines), hashlib.sha256("\n".join(lines).encode()).hexdigest()
+            ] == GOLDEN["causal_kernels"][kind]
+
+
 @pytest.mark.parametrize("keys, family", [
     ({"kv_lora_rank": 4}, "deepseek_v3"), ({"layer_types": []},
                                            "olmo_hybrid"),
@@ -527,7 +569,8 @@ def test_the_setup_span_says_the_kind_and_the_routing(trial, capsys):
     said = setup["attrs"]["attention_layers"]["latent-rope"]
     assert said == {"route": "reference", "mask": "dense: causal",
                     "layers": [0, 1, 2], "heads": H, "nope": NOPE,
-                    "rope": ROPE, "v": VD, "rank": RANK, "shared_key": "a"}
+                    "rope": ROPE, "v": VD, "rank": RANK,
+                    "hand_over": "copies"}
     routing = setup["attrs"]["moe"]
     assert (routing["scoring"], routing["bias"], routing["scale"],
             routing["shared_d_ff"], routing["dense_layers"],
@@ -542,6 +585,16 @@ def test_the_setup_span_says_the_kind_and_the_routing(trial, capsys):
     assert ("experts: sigmoid scores, 3 of 16 on score + bias, weights "
             "scaled 2.448, 8 held, shared as one of 64; layers 0 dense 96"
             ) in out
+    # on the Pallas route of one device the line says the hand-over's form
+    chip = lm.describe_pattern(description(), "pallas", tokens=64)[
+        "attention_layers"]["latent-rope"]
+    assert (chip["hand_over"], chip["mask"]) == ("in place",
+                                                 "structure: causal")
+    trace.print_routes([{**setup, "trial": "t", "attrs": {
+        **setup["attrs"], "attention_layers": {"latent-rope": chip}}}])
+    assert ("K/V rank 32, by pallas, the kernels reading q after one pass, "
+            "K, V and out where the matmuls leave them, the shared key "
+            "joined in VMEM") in capsys.readouterr().out
 
 
 @pytest.fixture(scope="module")
@@ -583,3 +636,211 @@ def test_the_shared_key_s_rotary_is_the_latent_s_and_q_s_is_attention_s(
     assert any("attention.latent" in n for n in cos)
     assert any("attention.latent" not in n and "/attention/" in n
                for n in cos)
+
+
+# -- the hand-over to the kernels -----------------------------------------------
+
+def _on_the_kernels():
+    """The Pallas route as the chip takes it, interpreted here, until the
+    returned patch is undone: the backend reads as the TPU, every Pallas
+    call runs the interpreter, and the causal kernels take float32 operands
+    (inside their loops this CPU's dot takes no pair of bfloat16), widened
+    under the core's own scope."""
+    from jax.experimental import pallas as pl
+
+    from metaopt_tpu.ops import attention, latent_attention
+
+    patch = pytest.MonkeyPatch()
+    call, flash = pl.pallas_call, latent_attention.flash_latent
+
+    @trace.scope("attention.core")
+    def wide(q, k, interpret=False):
+        f32 = lambda x: x.astype(jnp.float32)  # noqa: E731
+        return flash(f32(q), attention.LatentKV(f32(k.kv), f32(k.shared),
+                                                k.nope), True).astype(q.dtype)
+
+    patch.setattr(jax, "default_backend", lambda: "tpu")
+    patch.setattr(pl, "pallas_call", lambda *a, **kw: call(
+        *a, **{**kw, "interpret": True}))
+    patch.setattr(latent_attention, "flash_latent", wide)
+    return patch
+
+
+@pytest.fixture(scope="module")
+def layer_both_ways():
+    """{form: (output, {leaf: gradient}, input's gradient)} of one latent
+    layer on seeded weights: ``copies`` as the reference route takes it,
+    ``in place`` through the kernels, interpreted."""
+    layer = lm.LatentAttention(D, H, lm.LatentSpec(RANK, NOPE, ROPE, VD, True),
+                               1e6, 1e-6)
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, S, D))
+    w = jax.random.normal(jax.random.PRNGKey(1), (2, S, D))
+    params = jax.tree.map(lambda p: 3.0 * p, layer.init(
+        jax.random.PRNGKey(2), x)["params"])
+
+    def run():
+        out = layer.apply({"params": params}, x)
+        dp, dx = jax.grad(lambda p, x: jnp.sum(layer.apply(
+            {"params": p}, x).astype(jnp.float32) * w), argnums=(0, 1))(
+                params, x)
+        return out, {"/".join(str(k.key) for k in path
+                              if hasattr(k, "key")): g for path, g in
+                     jax.tree_util.tree_flatten_with_path(dp)[0]}, dx
+
+    got = {"copies": run()}
+    patch = _on_the_kernels()
+    try:
+        got["in place"] = run()
+    finally:
+        patch.undo()
+    return got
+
+
+@pytest.mark.parametrize("what", ["out", "x", "q/kernel", "kv_a/kernel",
+                                  "kv_a_norm/scale", "kv_b/kernel",
+                                  "out/kernel"])
+def test_the_kernels_read_in_place_what_the_reference_form_copies(
+        layer_both_ways, what):
+    """The layer's output, its input's gradient and all five parameters':
+    bfloat16 against bfloat16, one rounding of q either way (readings
+    0.008-0.032)."""
+    def pick(form):
+        out, grads, dx = layer_both_ways[form]
+        return np.asarray({"out": out, "x": dx, **grads}[what], np.float32)
+
+    a, b = pick("in place"), pick("copies")
+    assert a.shape == b.shape and np.linalg.norm(b) > 0
+    assert close(a, b, 0.08), np.linalg.norm(a - b) / np.linalg.norm(b)
+
+
+def test_the_rule_for_the_hand_over():
+    from jax.sharding import Mesh
+
+    from metaopt_tpu.ops.latent_attention import hand_over
+
+    one = Mesh(np.array(jax.devices()[:1]).reshape(1, 1), ("dp", "tp"))
+    two = Mesh(np.array(jax.devices()[:2]).reshape(1, 2), ("dp", "tp"))
+    assert hand_over("pallas", None, 128, 128) == "in place"
+    assert hand_over("pallas", one, 128, 128) == "in place"
+    assert hand_over("pallas", two, 128, 128) == "copies"
+    assert hand_over("pallas", one, 128, 64) == "copies"
+    for route in ("reference", "chunked", "ring"):
+        assert hand_over(route, one, 128, 128) == "copies"
+
+
+LOWERED_S = 96   # a q-sized array then holds more than any weight or stream
+
+
+@pytest.fixture(scope="module")
+def lowered_steps():
+    """{form: [(operation, [(shape, dtype) of its results], name)]} of a
+    rematerialised three-layer model's gradient, lowered for the reference
+    route (``copies``) and for the Pallas route, interpreted (``in
+    place``)."""
+    import re
+
+    tokens = jnp.zeros((2, LOWERED_S + 1), jnp.int32)
+    model = lm.make_lm({**description(), "remat": True})
+    keeps = lm.remat_keeps(model.pattern)["keeps"] + [
+        n for n in lm.LATENT_REMAT_KEEPS if n != "attention.kv_up"]
+    model = model.clone(keeps=tuple(keeps))
+    trained, frozen = lm.split_frozen(jax.eval_shape(lambda: model.init(
+        jax.random.PRNGKey(0), tokens[:, :-1], train=False)["params"]))
+
+    def lowered():
+        text = jax.jit(jax.grad(lambda p, fr, t: lm.lm_loss_fn(
+            model, lm.merge_frozen(p, fr), t, jax.random.PRNGKey(0)))).lower(
+                trained, frozen, tokens).as_text(debug_info=True)
+        names = dict(re.findall(r'^(#loc\d+) = loc\("([^"]+)"', text, re.M))
+        ops = []
+        for line in text.splitlines():
+            m = re.search(r'= "?(stablehlo\.\w+|call @\w+?)(?:_\d+)?"?[ (].*'
+                          r' loc\((#loc\d+)\)$', line)
+            if m and m.group(2) in names:
+                results = line.rsplit("->", 1)[-1] if "->" in line \
+                    else line.rsplit(" : ", 1)[-1]
+                ops.append((m.group(1), [
+                    (tuple(int(d) for d in dims.split("x") if d), dtype)
+                    for dims, dtype in re.findall(
+                        r"tensor<((?:\d+x)*)(\w+)>", results)],
+                    names[m.group(2)]))
+        return ops
+
+    got = {"copies": lowered()}
+    patch = _on_the_kernels()
+    try:
+        got["in place"] = lowered()
+    finally:
+        patch.undo()
+    return got
+
+
+def _glue(ops, direction):
+    """What stands between a latent layer's matmuls and the kernels, of
+    the operations named under ``attention`` and outside ``attention.core``
+    in one direction: joins nope + rope wide, float32 arrays as large as
+    q, and transposes that move the last axis of an array as large as a
+    head's keys or values."""
+    import re
+
+    core = re.compile(r"(?:^|[/(])attention\.core(?:$|[/)])")
+    q_sized = 2 * LOWERED_S * H * (NOPE + ROPE)
+    kv_sized = 2 * LOWERED_S * H * min(NOPE, VD)
+    found = []
+    for op, results, name in ops:
+        if trace.layer_of(name) != "attention" or core.search(name) \
+                or trace.direction(name) != direction:
+            continue
+        for shape, dtype in results:
+            size = int(np.prod(shape))
+            if op == "stablehlo.concatenate" and size >= kv_sized and (
+                    NOPE + ROPE in shape or H * (NOPE + ROPE) in shape):
+                found.append(("join", shape, name))
+            if dtype == "f32" and size >= q_sized:
+                found.append(("float32", shape, name))
+            if op == "stablehlo.transpose" and size >= kv_sized \
+                    and name.endswith("/transpose") and shape[-1] not in (
+                        LOWERED_S,):
+                found.append(("transpose", shape, name))
+    return found
+
+
+@pytest.mark.parametrize("direction", ["forward", "forward.again",
+                                       "backward"])
+def test_nothing_stands_between_the_matmuls_and_the_kernels(lowered_steps,
+                                                            direction):
+    """In place: no join of width nope + rope, no float32 array of q's
+    full width and no transposed copy of a q-, K- or V-sized array under
+    ``attention`` outside the core, in any direction (the sequence is every
+    such array's last axis and stays it). The reference form has them
+    (backward a join is slices): the check can see them."""
+    assert _glue(lowered_steps["in place"], direction) == []
+    kinds = {kind for kind, _, _ in _glue(lowered_steps["copies"], direction)}
+    assert kinds >= ({"float32"} if direction == "backward"
+                     else {"join", "float32"}), kinds
+
+
+def test_the_kernels_are_called_once_a_layer_under_their_names(lowered_steps):
+    """How often the mechanism engages, off the chip: the jitted entries
+    that hold ``flash_fwd`` and ``flash_bwd`` are called under
+    ``attention.core``, once a layer each, the forward one never in a
+    block's second run; q's pass once a layer in each direction; the
+    reference form calls none."""
+    import collections
+
+    calls = collections.Counter(
+        (op.removeprefix("call @"), trace.direction(name),
+         "attention.core" in name)
+        for op, _, name in lowered_steps["in place"]
+        if op in ("call @_forward", "call @_backward", "call @_q_pass")
+        and trace.layer_of(name) == "attention")
+    assert calls == {("_forward", "forward", True): 3,
+                     ("_backward", "backward", True): 3,
+                     ("_q_pass", "forward", False): 3,
+                     ("_q_pass", "forward.again", False): 3,
+                     ("_q_pass", "backward", False): 3}
+    names = {name for _, _, name in lowered_steps["in place"]}
+    assert {"flash_fwd/pallas_call", "flash_bwd/pallas_call",
+            "latent_q/pallas_call"} <= names
+    assert not [op for op, _, name in lowered_steps["copies"]
+                if op.startswith("call @_forward")]

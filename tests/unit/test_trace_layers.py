@@ -235,7 +235,7 @@ def test_a_kernel_s_calls_by_direction(lowered_steps, kind, kernel, forward,
     ("pattern-decoder", "_causal_backward"),
     ("selected-attention-decoder", "_backward"),
     ("hybrid-decoder", "_bwd_pallas"),
-    ("latent-decoder", "_causal_backward"),
+    ("latent-decoder", "_backward"),
 ])
 def test_a_backward_rule_s_helpers_are_backward(lowered_steps, kind, helper):
     """What a kernel's backward rule runs around the kernel (the delta,
