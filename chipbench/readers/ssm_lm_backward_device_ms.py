@@ -1,0 +1,10 @@
+"""``backward_device_ms`` in a state-space decoder's cell, read by that
+metric's own reader: the operations in direction ``backward``, every layer.
+An accepted metric's list of cells takes no new cell, so the cell reports
+it under a name of its own."""
+
+from chipbench.run import _reader
+
+
+def read(records):
+    return _reader("backward_device_ms").read(records)

@@ -46,6 +46,13 @@ kind of layer, *latent* attention, with a leading dense layer, sigmoid
 routing and shared experts (``python -m chipbench.mla_lm_config
 chipbench/configs/kanana-2-30b-a3b-ep8.json`` prints
 kanana-2-30b-a3b-instruct-2601's share of one chip; ``--seq-len=16384``).
+One with ``model_type`` ``phi4flash`` names a decoder-hybrid-decoder:
+state-space layers (Mamba-1's selective scan), differential attention
+under a window and without, gated memory units and cross attention on an
+earlier layer's K and V, the layers a chip holds listed by their published
+numbers in ``layers_held`` (``python -m chipbench.ssm_lm_config
+chipbench/configs/phi-4-mini-flash-vp8.json`` prints
+Phi-4-mini-flash-reasoning's share of one chip; ``--seq-len=8192``).
 """
 
 import argparse
@@ -72,7 +79,9 @@ def main():
                    "(make_lm's hyperparameters) in place of the widths "
                    "above; a layer is linear, latent (the DeepSeek-V3 "
                    "family's words: kv_lora_rank ...), selected, window or "
-                   "global by what the description says")
+                   "global, or (model_type phi4flash) state-space, a "
+                   "memory unit or differential attention, by what the "
+                   "description says")
     p.add_argument("--batch-size", dest="batch_size", type=int, default=32)
     p.add_argument("--n-train", dest="n_train", type=int, default=2048)
     p.add_argument("--warmup", type=int, default=10)

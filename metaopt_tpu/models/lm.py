@@ -47,6 +47,22 @@
   a correction bias (``topk_method`` noaux_tc) that is a frozen leaf as
   the indexer is, weighed without it (``norm_topk_prob``,
   ``routed_scaling_factor``), beside ``n_shared_experts`` shared ones.
+  With ``model_type`` ``phi4flash`` (the SambaY decoder-hybrid-decoder of
+  arXiv:2507.06607, read at the PUBLISHED depth N) a layer is one of five
+  more kinds: even layers up to N/2 are *state-space*
+  (:class:`StateSpaceMixer`: Mamba-1's selective scan,
+  ops/selective_scan.py: a recurrence, no positions), later even ones
+  *gated memory units* (:class:`GatedMemoryUnit`) that gate layer N/2's
+  scan output; odd layers are :class:`DifferentialAttention` (pairs of
+  heads, a_1 - lambda a_2 under a norm over the pair's joined value),
+  ``sliding_window`` wide below N/2, full at N/2 + 1, and from N/2 + 3 on
+  *cross*: q of their own on layer N/2 + 1's K and V. What a layer hands
+  to later ones the trunk carries (:class:`HybridBlock` returns it); the
+  block is x + mixer(LN(x)) then + ffn(LN(.)), LayerNorm with bias, the
+  feed-forward gated by ``hidden_act``, no positions anywhere, the head
+  tied to the embedding. ``layers_held`` lists the published numbers of
+  the layers this chip holds (a pipeline stage's; not the first n): kinds
+  and lambda_init are read at those numbers.
   The chip's share of a deployment is part of the description too:
   ``experts_held`` = (first, count) of the routed experts,
   ``vocab_held`` = (first, count) of the vocabulary's rows (ids are drawn
@@ -440,6 +456,57 @@ class LinearSpec:
 
 
 @dataclasses.dataclass(frozen=True)
+class HybridSpec:
+    """What a ``phi4flash`` description says of its decoder-hybrid-decoder:
+    the published numbers of the ``layers`` held here and the kind of each
+    (``HYBRID_KINDS``), read at the published depth ``of``; the
+    state-space mixers' sizes; which layer's scan output is the memory and
+    which layer's K and V the cross layers read."""
+
+    layers: Tuple[int, ...]
+    kinds: Tuple[str, ...]
+    of: int
+    d_inner: int
+    d_state: int
+    d_conv: int
+    dt_rank: int
+    memory_layer: int
+    kv_layer: int
+
+    def held(self, *kinds: str):
+        """The published numbers of the held layers of ``kinds``."""
+        return [n for n, k in zip(self.layers, self.kinds) if k in kinds]
+
+
+#: a ``phi4flash`` layer's kinds and what each reads of an earlier layer
+HYBRID_KINDS = {"ssm": (), "window": (), "full": (), "gmu": ("memory",),
+                "cross": ("kv",)}
+
+
+def hybrid_kind(layer: int, of: int) -> str:
+    """The kind of published layer ``layer`` of a ``phi4flash`` model ``of``
+    layers deep (``mb_per_layer`` 2: every other layer is a state-space or
+    memory layer; the decoders split at ``of`` / 2)."""
+    half = of // 2
+    if layer % 2 == 0:
+        return "ssm" if layer <= half else "gmu"
+    if layer < half:
+        return "window"
+    if layer == half + 1:
+        return "full"
+    if layer >= half + 3:
+        return "cross"
+    raise ValueError(f"layer {layer} of {of} has no kind: the full layer is "
+                     f"{half + 1}, the cross layers start at {half + 3}")
+
+
+def lambda_init(layer: int) -> float:
+    """The differential transformer's 0.8 - 0.6 exp(-0.3 l), at the
+    PUBLISHED layer number."""
+    return 0.8 - 0.6 * math.exp(-0.3 * layer)
+
+
+@dataclasses.dataclass(frozen=True)
 class Pattern:
     """What a description with a layer pattern says of every layer, and
     ``layers``: (sliding window?, rotary positions?) of each."""
@@ -479,6 +546,9 @@ class Pattern:
     dense_layers: int = 0
     shared_d_ff: int = 0
     routing: RoutingRule = RoutingRule()
+    #: the ``phi4flash`` family's decoder-hybrid-decoder: every layer is a
+    #: :class:`HybridBlock` of the kind ``hybrid`` names, the head tied
+    hybrid: Optional[HybridSpec] = None
 
     def is_linear(self, i: int) -> bool:
         return bool(self.linear_layers) and self.linear_layers[i]
@@ -490,6 +560,10 @@ class Pattern:
         return sum(map(self.is_routed, range(len(self.layers))))
 
     def kind(self, i: int) -> str:
+        if self.hybrid is not None:
+            kind = self.hybrid.kinds[i]
+            return kind if kind in ("ssm", "gmu") else \
+                {"full": "global"}.get(kind, kind) + "-nope"
         return layer_kind(*self.layers[i], self.selection is not None,
                           self.is_linear(i), self.latent is not None)
 
@@ -651,6 +725,8 @@ class DecoderOnlyLM(nn.Module):
 
     def _patterned(self, tokens, features: bool):
         p = self.pattern
+        if p.hybrid is not None:
+            return self._hybrid(tokens, features)
         first, rows = p.vocab_held
         # the embedding at size 1 (the first norm rescales it), the head
         # at 1/sqrt(d): logits of size 1, a loss near log(rows) at the start
@@ -676,19 +752,57 @@ class DecoderOnlyLM(nn.Module):
             # the head's table has to exist for readout_xent to fold in
             head.embedding  # noqa: B018
             return x
-        with trace.scope("readout_xent"):
-            # The cast stands in memory before the head's matmuls read it.
-            # Left to itself XLA makes it inside their operands from the
-            # float32 stream, and the weight-gradient matmul then runs at
-            # its rate only while the compiler also happens to move that
-            # stream on chip for it: on a v5e it takes 10.2 ms so and 17.7
-            # without, and which it is turned on what the blocks keep
-            # (PERF.md section 6, PR 35).
-            return jnp.einsum(
-                "btd,vd->btv",
-                jax.lax.optimization_barrier(x.astype(jnp.bfloat16)),
-                head.embedding.astype(jnp.bfloat16),
-                preferred_element_type=jnp.float32)
+        return _logits(x, head.embedding)
+
+    def _hybrid(self, tokens, features: bool):
+        """The ``phi4flash`` trunk: it carries what a layer hands on (the
+        memory layer's scan output, the full layer's K and V) to the
+        layers that read it. A handed value is an output of its block and
+        an input of each reader, so a rematerialised block keeps it and
+        its gradient is the sum over the readers."""
+        p = self.pattern
+        sp = p.hybrid
+        first, rows = p.vocab_held
+        # the tied table at 1/sqrt(d): logits of size 1; the first norm
+        # rescales the stream
+        emb = nn.Embed(
+            rows, self.d_model, dtype=jnp.bfloat16, name="embed",
+            embedding_init=nn.with_partitioning(
+                nn.initializers.normal(self.d_model ** -0.5), (None, None)))
+        block_cls = HybridBlock
+        if self.remat:
+            block_cls = rematerialised(
+                HybridBlock, keeps=remat_keeps(p)["keeps"]
+                if self.keeps is None else self.keeps)
+        with trace.scope("embed"):
+            x = emb(tokens - first).astype(jnp.float32)
+        heads = p.heads_held[1] if p.heads_held else self.n_heads
+        handed = {}
+        for i, (layer, kind) in enumerate(zip(sp.layers, sp.kinds)):
+            x, on = block_cls(self.d_model, heads, self.d_ff, p, i,
+                              name=f"h{layer}")(
+                x, *(handed[r] for r in HYBRID_KINDS[kind]))
+            handed.update(on)
+        x = _layer_norm("norm_f", x, p.rms_eps)
+        if features:
+            return x
+        return _logits(x, emb.embedding)
+
+
+@trace.scope("readout_xent")
+def _logits(x, table):
+    """The float32 logits of a pattern decoder's last norm ``x`` over the
+    rows of ``table`` (its untied head, or the tied embedding).
+
+    The cast stands in memory before the head's matmuls read it. Left to
+    itself XLA makes it inside their operands from the float32 stream, and
+    the weight-gradient matmul then runs at its rate only while the
+    compiler also happens to move that stream on chip for it: on a v5e it
+    takes 10.2 ms so and 17.7 without, and which it is turned on what the
+    blocks keep (PERF.md section 6, PR 35)."""
+    return jnp.einsum(
+        "btd,vd->btv", jax.lax.optimization_barrier(x.astype(jnp.bfloat16)),
+        table.astype(jnp.bfloat16), preferred_element_type=jnp.float32)
 
 
 #: a description's published names beside the zoo's own
@@ -725,6 +839,8 @@ _FAMILIES = {
     "olmo_hybrid": {"qk_norm": True, "qk_norm_whole": True,
                     "norm_after": True},
     "deepseek_v3": {"router_after_attention": True},
+    # the SambaY decoder-hybrid-decoder: its own block (HybridBlock)
+    "phi4flash": {},
 }
 
 
@@ -733,7 +849,10 @@ def family_of(h: Dict[str, Any]) -> Optional[str]:
     (a key of ``_FAMILIES``), None for one without a pattern:
     ``kv_lora_rank`` is the DeepSeek-V3 family's, ``layer_types`` the Olmo
     hybrid's, ``num_experts`` the Qwen3-MoE family's, the two layouts or
-    ``sa_config`` alone SmallThinker's."""
+    ``sa_config`` alone SmallThinker's; ``model_type`` ``phi4flash`` names
+    its family itself."""
+    if h.get("model_type") == "phi4flash":
+        return "phi4flash"
     for key, family in (("kv_lora_rank", "deepseek_v3"),
                         ("layer_types", "olmo_hybrid"),
                         ("num_experts", "qwen3_moe"),
@@ -754,11 +873,21 @@ def pattern_of(h: Dict[str, Any]) -> Optional[Pattern]:
     Olmo hybrid family's block, its linear layers and, with
     ``rope_parameters.rope_theta`` null, no positions anywhere; the
     DeepSeek-V3 family's latent attention, leading dense layers, shared
-    experts and routing rule (:func:`_latent`, :func:`_routing`)."""
+    experts and routing rule (:func:`_latent`, :func:`_routing`); the
+    ``phi4flash`` family's kinds of layer at the published numbers of
+    ``layers_held`` (:func:`_hybrid_spec`), ``sliding_window`` and
+    ``layer_norm_eps``."""
     family = family_of(h)
     if family is None:
         return None
     n_layers = int(h.get("n_layers", 6))
+    if family == "phi4flash":
+        hybrid = _hybrid_spec(h, n_layers)
+        h = {"sliding_window_size": h.get("sliding_window", 512),
+             "rms_norm_eps": h.get("layer_norm_eps", 1e-5),
+             "sliding_window_layout": [k == "window" for k in hybrid.kinds],
+             "rope_parameters": {"rope_theta": None}, **h}
+        n_layers = len(hybrid.layers)
     types = h.get("layer_types")
     unknown = sorted(set(types or ()) - set(_LAYER_TYPES))
     if unknown:
@@ -781,6 +910,8 @@ def pattern_of(h: Dict[str, Any]) -> Optional[Pattern]:
     linear_layers = tuple(_LAYER_TYPES[t] for t in (types or ())[:n_layers])
     expert_d_ff = int(h.get("moe_ffn_hidden_size", h.get("d_ff", 2048)))
     of_the_family = dict(_FAMILIES[family])
+    if family == "phi4flash":
+        of_the_family.update(hybrid=hybrid)
     if family == "deepseek_v3":
         of_the_family.update(
             latent=_latent(h), routing=_routing(h),
@@ -808,6 +939,36 @@ def pattern_of(h: Dict[str, Any]) -> Optional[Pattern]:
         heads_held=heads_held if "heads_held" in h else None,
         **of_the_family,
     )
+
+
+def _hybrid_spec(h: Dict[str, Any], of: int) -> HybridSpec:
+    """The decoder-hybrid-decoder of a ``phi4flash`` description ``of``
+    layers deep: the layers held (all, without ``layers_held``), their
+    kinds, and Mamba-1's sizes by the family's convention where the
+    description is silent (``mamba_expand`` 2, ``mamba_d_state`` 16,
+    ``mamba_d_conv`` 4, ``mamba_dt_rank`` hidden / 16). A layer that reads
+    what a layer not held would hand on is refused by name."""
+    layers = tuple(int(n) for n in h.get("layers_held") or range(of))
+    if list(layers) != sorted(set(layers)) or not layers \
+            or not 0 <= layers[0] <= layers[-1] < of:
+        raise ValueError(f"layers_held {list(layers)}: the published "
+                         f"numbers of layers 0..{of - 1}, ascending")
+    kinds = tuple(hybrid_kind(n, of) for n in layers)
+    spec = HybridSpec(
+        layers=layers, kinds=kinds, of=of,
+        d_inner=int(h.get("mamba_expand", 2)) * int(h.get("d_model", 512)),
+        d_state=int(h.get("mamba_d_state", 16)),
+        d_conv=int(h.get("mamba_d_conv", 4)),
+        dt_rank=int(h.get("mamba_dt_rank")
+                    or -(-int(h.get("d_model", 512)) // 16)),
+        memory_layer=of // 2, kv_layer=of // 2 + 1)
+    for kind, source, what in (("gmu", spec.memory_layer, "the memory"),
+                               ("cross", spec.kv_layer, "K and V")):
+        if spec.held(kind) and source not in layers:
+            raise ValueError(
+                f"layer {spec.held(kind)[0]} reads {what} of layer "
+                f"{source}, which is not among layers_held {list(layers)}")
+    return spec
 
 
 def _latent(h: Dict[str, Any]) -> LatentSpec:
@@ -895,6 +1056,10 @@ def remat_keeps(p: Optional[Pattern], *, tokens: int = 0, d_model: int = 0,
         from metaopt_tpu.ops import linear_attention
 
         keeps += linear_attention.REMAT_KEEPS
+    if p.hybrid is not None:
+        from metaopt_tpu.ops import selective_scan
+
+        keeps += selective_scan.REMAT_KEEPS
     room = None if bytes_limit is None else max(
         0, bytes_limit - STATE_BYTES_A_PARAMETER * parameters) // 2
     products = _products(p, tokens, d_model, d_ff, n_heads)
@@ -920,7 +1085,10 @@ def _products(p: Pattern, tokens: int, d_model: int, d_ff: int,
     layer's two float32 gates among them: nothing in bytes, six passes at
     precision highest in time); a latent layer's q and its down-projection
     are a candidate each (the second is a tenth of the first and the last
-    thing worth giving up)."""
+    thing worth giving up). A ``phi4flash`` pattern's attention layers make
+    q (the cross layers too) and, but for the cross layers, k and v; its
+    state-space mixers' four products and its memory units' two are a
+    candidate each, at their own contracting widths."""
     linear = sum(map(p.is_linear, range(len(p.layers))))
     out = []
 
@@ -947,6 +1115,25 @@ def _products(p: Pattern, tokens: int, d_model: int, d_ff: int,
         add(d_model, len(p.layers), {latent: 2 * (sp.rank + sp.rope)})
         add(sp.rank, len(p.layers), {up: 2 * n_heads * (sp.nope + sp.v)})
         add(n_heads * sp.v, len(p.layers), {last: 2 * d_model})
+        return out
+    if p.hybrid is not None:
+        sp = p.hybrid
+        count = lambda *kinds: len(sp.held(*kinds))  # noqa: E731
+        q, k, v, last = ATTENTION_REMAT_KEEPS
+        own, every = count("window", "full"), \
+            count("window", "full", "cross")
+        kv = 2 * own * p.n_kv_heads * p.head_dim
+        add(d_model, 1, {q: 2 * every * n_heads * p.head_dim, k: kv, v: kv})
+        add(n_heads * p.head_dim, every, {last: 2 * d_model})
+        proj, x_proj, dt_proj, last = SSM_REMAT_KEEPS
+        add(d_model, count("ssm"), {proj: 2 * 2 * sp.d_inner})
+        add(sp.d_inner, count("ssm"),
+            {x_proj: 4 * (sp.dt_rank + 2 * sp.d_state)})
+        add(sp.dt_rank, count("ssm"), {dt_proj: 4 * sp.d_inner})
+        add(sp.d_inner, count("ssm"), {last: 2 * d_model})
+        proj, last = GMU_REMAT_KEEPS
+        add(d_model, count("gmu"), {proj: 2 * sp.d_inner})
+        add(sp.d_inner, count("gmu"), {last: 2 * d_model})
         return out
     q, k, v, last = ATTENTION_REMAT_KEEPS
     add(d_model, len(p.layers) - linear, {
@@ -998,7 +1185,9 @@ def remat_on(model: "DecoderOnlyLM", mesh: Mesh, batch_shape,
         dataclasses.replace(
             p, n_kv_heads=p.n_kv_heads // tp,
             linear=p.linear and dataclasses.replace(
-                p.linear, heads=p.linear.heads // tp)),
+                p.linear, heads=p.linear.heads // tp),
+            hybrid=p.hybrid and dataclasses.replace(
+                p.hybrid, d_inner=p.hybrid.d_inner // tp)),
         tokens=b * s // across("dp", "sp"), d_model=model.d_model,
         d_ff=model.d_ff // tp,
         n_heads=(p.heads_held[1] if p.heads_held else model.n_heads) // tp,
@@ -1037,7 +1226,9 @@ def describe_pattern(hparams: Dict[str, Any], route: str, tokens: int,
     the expert layers' share, the product they take, the rows of their
     buffers, the rows a trip of the routing's loops moves and, for the
     DeepSeek-V3 family, its routing rule, shared experts and leading dense
-    layers."""
+    layers; for the ``phi4flash`` family the scan's route, chunk and state
+    type, the differential pairs' widths, and which layers hand on and
+    which read."""
     from metaopt_tpu.models.moe import (grouped_matmul_impl,
                                         routing_chunk_rows)
 
@@ -1057,7 +1248,29 @@ def describe_pattern(hparams: Dict[str, Any], route: str, tokens: int,
             else ": causal")
 
     out = {"attention_layers": {kind: {"route": route, "mask": mask_of(kind)}
-                                for kind in p.kinds() if kind != "linear"}}
+                                for kind in p.kinds()
+                                if kind not in ("linear", "ssm", "gmu")}}
+    if p.hybrid is not None:
+        from metaopt_tpu.ops.selective_scan import selective_scan_route
+
+        sp = p.hybrid
+        for kind, said in out["attention_layers"].items():
+            said.update(
+                layers=[n for i, n in enumerate(sp.layers)
+                        if p.kind(i) == kind],
+                differential=[int(h.get("n_heads", 8)) // 2, p.n_kv_heads // 2,
+                              p.head_dim, 2 * p.head_dim])
+            if kind.startswith("cross"):
+                said["reads"] = sp.kv_layer
+        out["attention_layers"]["ssm"] = {
+            **selective_scan_route(seq_len or tokens, mesh),
+            "layers": sp.held("ssm"), "d_inner": sp.d_inner,
+            "d_state": sp.d_state, "conv": sp.d_conv, "dt_rank": sp.dt_rank,
+            "hands_on": sp.held("gmu") and sp.memory_layer}
+        if sp.held("gmu"):
+            out["attention_layers"]["gmu"] = {
+                "layers": sp.held("gmu"), "reads": sp.memory_layer,
+                "d_inner": sp.d_inner}
     if p.latent is not None:
         from metaopt_tpu.ops.latent_attention import hand_over
 
@@ -1105,14 +1318,16 @@ def make_lm(hparams: Optional[Dict[str, Any]] = None,
     """The model a description names: the zoo's own keys (``d_model``,
     ``n_layers`` ...) or a published config's (``hidden_size``,
     ``num_hidden_layers``, ``rope_layout`` ...), plus the chip's share
-    (``experts_held``, ``vocab_held``: (first, count))."""
+    (``experts_held``, ``vocab_held``: (first, count); ``layers_held``: the
+    published numbers of a ``phi4flash`` model's layers)."""
     h = _own_names({**(hparams or {}), **overrides})
     pattern = pattern_of(h)
     return DecoderOnlyLM(
         vocab=int(h.get("vocab", 1000)),
         d_model=int(h.get("d_model", 512)),
         n_heads=int(h.get("n_heads", 8)),
-        n_layers=int(h.get("n_layers", 6)),
+        n_layers=len(pattern.layers) if pattern else int(
+            h.get("n_layers", 6)),
         d_ff=int(h.get("d_ff", 2048)),
         dropout=float(h.get("dropout", 0.0 if pattern else 0.1)),
         max_len=int(h.get("max_len", 512)),
@@ -1532,3 +1747,215 @@ class LinearAttention(nn.Module):
             self.d_model, axis=(-2, -1), dtype=jnp.bfloat16, name="out",
             use_bias=False, kernel_init=_pinit(True, ("tp", None, None)),
         )(y.astype(jnp.bfloat16)), kept["out"])
+
+
+# ---------------------------------------------------------------------------
+# the phi4flash family's block and its four mixers (at the file's end too)
+
+#: What a rematerialised block keeps of a state-space mixer's projections
+#: where :func:`remat_keeps` finds the room: the input projection's product
+#: (x and z before the convolution and the gate), x_proj's (delta, B, C
+#: before the step's projection) and dt_proj's (before the softplus), and
+#: the output projection's.
+SSM_REMAT_KEEPS = ("ssm.in_proj", "ssm.x_proj", "ssm.dt_proj",
+                   "ssm.out_proj")
+GMU_REMAT_KEEPS = ("gmu.in_proj", "gmu.out_proj")
+
+
+def _layer_norm(name: str, x, eps: float):
+    """A float32 LayerNorm with weight and bias, under the scope ``norm``."""
+    with trace.scope("norm"):
+        return nn.LayerNorm(epsilon=eps, dtype=jnp.float32, name=name)(x)
+
+
+def _state_decay_init(key, shape, dtype=jnp.float32):
+    """``A_log`` as Mamba's published initialiser has it: A[d, n] = n + 1."""
+    return jnp.broadcast_to(
+        jnp.log(jnp.arange(1, shape[1] + 1, dtype=dtype)), shape)
+
+
+def _taps_init(key, shape, dtype=jnp.float32):
+    """U(-1/2, 1/2): a depthwise convolution's default at 4 taps, for the
+    taps and their bias."""
+    return jax.random.uniform(key, shape, dtype, -0.5, 0.5)
+
+
+class StateSpaceMixer(nn.Module):
+    """Mamba-1 (arXiv:2312.00752) over ``spec.d_inner`` channels: (x, z) =
+    u W_in; x = silu(conv(x) + b), a causal depthwise convolution of
+    ``d_conv`` taps; (delta, B, C) = x W_x, split dt_rank / d_state /
+    d_state; Delta = softplus(delta W_dt + b_dt); A = -exp(A_log); the
+    selective scan (ops/selective_scan.py, the one rule there names its
+    route) with y += D x; out = (y silu(z)) W_out. Returns (out, y): y,
+    BEFORE the gate, is what the memory layer hands on. Element-wise work,
+    the convolution and the scan in float32; x_proj and dt_proj float32 at
+    matmul precision highest (they make the scan's steps, whose decays
+    exp(Delta A) reach A = -16), as a linear layer's gates are. The four
+    projections' products carry the names of ``SSM_REMAT_KEEPS``."""
+
+    d_model: int
+    spec: HybridSpec
+
+    @nn.compact
+    @trace.scope("ssm")
+    def __call__(self, u):
+        from metaopt_tpu.ops.selective_scan import selective_scan
+
+        sp = self.spec
+        own = lambda name, init, shape, axes: self.param(  # noqa: E731
+            name, with_mesh_partitioning(init, axes), shape)
+        exact = lambda name, width, axes, **kw: nn.Dense(  # noqa: E731
+            width, name=name, precision=jax.lax.Precision.HIGHEST,
+            kernel_init=with_mesh_partitioning(
+                nn.initializers.lecun_normal(), axes), **kw)
+        kept_in, kept_x, kept_dt, kept_out = SSM_REMAT_KEEPS
+        xz = checkpoint_name(nn.DenseGeneral(
+            (2, sp.d_inner), dtype=jnp.bfloat16, name="in_proj",
+            use_bias=False, kernel_init=_pinit(True, (None, None, "tp")),
+        )(u.astype(jnp.bfloat16)), kept_in)
+        x = jax.nn.silu(
+            short_conv(xz[..., 0, :].astype(jnp.float32), own(
+                "conv", _taps_init, (sp.d_conv, sp.d_inner), (None, "tp")))
+            + own("conv_bias", _taps_init, (sp.d_inner,), ("tp",)))
+        dbc = checkpoint_name(exact(
+            "x_proj", sp.dt_rank + 2 * sp.d_state, ("tp", None),
+            use_bias=False)(x), kept_x)
+        delta, b, c = jnp.split(
+            dbc, [sp.dt_rank, sp.dt_rank + sp.d_state], axis=-1)
+        dt = jax.nn.softplus(checkpoint_name(exact(
+            "dt_proj", sp.d_inner, (None, "tp"), bias_init=_dt_bias_init,
+        )(delta), kept_dt))
+        a = -jnp.exp(own("A_log", _state_decay_init,
+                         (sp.d_inner, sp.d_state), ("tp", None)))
+        y = selective_scan(x, dt, a, b, c) \
+            + own("D", nn.initializers.ones, (sp.d_inner,), ("tp",)) * x
+        gated = y * jax.nn.silu(xz[..., 1, :].astype(jnp.float32))
+        return checkpoint_name(nn.Dense(
+            self.d_model, dtype=jnp.bfloat16, name="out_proj",
+            use_bias=False, kernel_init=_pinit(True, ("tp", None)),
+        )(gated.astype(jnp.bfloat16)), kept_out), y
+
+
+class GatedMemoryUnit(nn.Module):
+    """out = (memory * silu(u W_in)) W_out: an earlier layer's scan output
+    (``memory``, float32, ``d_inner`` wide) gated by this layer's own
+    projection of its input. No bias. The two products carry the names of
+    ``GMU_REMAT_KEEPS``."""
+
+    d_model: int
+    d_inner: int
+
+    @nn.compact
+    @trace.scope("gmu")
+    def __call__(self, u, memory):
+        kept_in, kept_out = GMU_REMAT_KEEPS
+        gate = checkpoint_name(nn.Dense(
+            self.d_inner, dtype=jnp.bfloat16, name="in_proj", use_bias=False,
+            kernel_init=_pinit(True, (None, "tp")),
+        )(u.astype(jnp.bfloat16)), kept_in)
+        gated = memory * jax.nn.silu(gate.astype(jnp.float32))
+        return checkpoint_name(nn.Dense(
+            self.d_model, dtype=jnp.bfloat16, name="out_proj",
+            use_bias=False, kernel_init=_pinit(True, ("tp", None)),
+        )(gated.astype(jnp.bfloat16)), kept_out)
+
+
+class DifferentialAttention(nn.Module):
+    """Differential attention (arXiv:2410.05258) under a causal mask, with
+    bias on the projections. The ``n_heads`` query heads are pairs (2p, 2p +
+    1) = (q_1, q_2), the ``n_kv_heads`` K/V heads pairs (2j, 2j + 1) =
+    (k_1, k_2) whose two values are joined to one v twice as wide; query
+    pair p reads K/V pair p // (query pairs / K/V pairs). a_i =
+    softmax(q_i k_i^T / sqrt(head_dim) + mask) v; o = (1 - lambda_init)
+    rmsnorm(a_1 - lambda a_2) over the joined width, lambda =
+    exp(lq1 . lk1) - exp(lq2 . lk2) + lambda_init; then the output
+    projection. ``kv``: another layer's (k, v) after its projection, read in
+    place of this layer's own (a cross layer, which then has no k and v
+    projections). Returns (out, (k, v)).
+
+    The pairs reach the ``CausalMask`` kernels as two calls, q_1 on k_1 and
+    q_2 on k_2 (every other head: the kernels' grouping, query head h on
+    K/V head h // group, is then the pairs' own order), both on the one
+    joined v, which is a reshape and no copy; the kernels run at q.k
+    ``head_dim`` and v 2 ``head_dim`` wide. The combination is under the
+    scope ``attention.diff``."""
+
+    d_model: int
+    n_heads: int
+    n_kv_heads: int
+    head_dim: int
+    window: Optional[int]
+    lambda_init: float
+    eps: float
+
+    @nn.compact
+    @trace.scope("attention")
+    def __call__(self, u, kv=None):
+        proj = lambda name, heads: nn.DenseGeneral(  # noqa: E731
+            (heads, self.head_dim), axis=-1, dtype=jnp.bfloat16, name=name,
+            kernel_init=_pinit(True, (None, "tp", None)))
+        kept_q, kept_k, kept_v, kept_out = ATTENTION_REMAT_KEEPS
+        u = u.astype(jnp.bfloat16)
+        q = checkpoint_name(proj("q", self.n_heads)(u), kept_q)
+        if kv is None:
+            kv = (checkpoint_name(proj("k", self.n_kv_heads)(u), kept_k),
+                  checkpoint_name(proj("v", self.n_kv_heads)(u), kept_v))
+        k, v = kv
+        q = (q / math.sqrt(self.head_dim)).astype(jnp.bfloat16)
+        joined = v.reshape(*v.shape[:2], -1, 2 * self.head_dim)
+        mask = CausalMask(self.window)
+        a1, a2 = (attend(q[:, :, i::2], k[:, :, i::2], joined, mask)
+                  for i in (0, 1))
+        with trace.scope("attention.diff"):
+            lam = lambda name: self.param(  # noqa: E731
+                name, nn.initializers.normal(0.1), (self.head_dim,))
+            weight = jnp.exp(jnp.sum(lam("lambda_q1") * lam("lambda_k1"))) \
+                - jnp.exp(jnp.sum(lam("lambda_q2") * lam("lambda_k2"))) \
+                + self.lambda_init
+            out = RMSNorm(self.eps, name="subln")(
+                a1.astype(jnp.float32) - weight * a2.astype(jnp.float32)) \
+                * (1.0 - self.lambda_init)
+        return checkpoint_name(nn.DenseGeneral(
+            self.d_model, axis=(-2, -1), dtype=jnp.bfloat16, name="out",
+            kernel_init=_pinit(True, ("tp", None, None)),
+        )(out.astype(jnp.bfloat16)), kept_out), kv
+
+
+class HybridBlock(nn.Module):
+    """The ``phi4flash`` family's block for held layer ``index`` of the
+    pattern: x + mixer(LN(x)), then + ffn(LN(.)), LayerNorm with weight and
+    bias, the mixer of the layer's kind (``HYBRID_KINDS``: a memory unit
+    takes the memory, a cross layer K and V, as further arguments).
+    Returns (x, what the layer hands on): ``{"memory": y}`` from the memory
+    layer, ``{"kv": (k, v)}`` from the full layer, else ``{}``."""
+
+    d_model: int
+    n_heads: int
+    d_ff: int
+    pattern: Pattern
+    index: int
+
+    @nn.compact
+    def __call__(self, x, *read):
+        p, sp = self.pattern, self.pattern.hybrid
+        layer, kind = sp.layers[self.index], sp.kinds[self.index]
+        n = _layer_norm("norm_in", x, p.rms_eps)
+        on = {}
+        if kind == "ssm":
+            branch, y = StateSpaceMixer(self.d_model, sp, name="ssm")(n)
+            if layer == sp.memory_layer:
+                on["memory"] = y
+        elif kind == "gmu":
+            branch = GatedMemoryUnit(self.d_model, sp.d_inner,
+                                     name="gmu")(n, *read)
+        else:
+            branch, kv = DifferentialAttention(
+                self.d_model, self.n_heads, p.n_kv_heads, p.head_dim,
+                p.window if kind == "window" else None, lambda_init(layer),
+                p.rms_eps, name="attn")(n, *read)
+            if layer == sp.kv_layer:
+                on["kv"] = kv
+        x = residual(x, branch)
+        return residual(x, GatedFeedForward(
+            self.d_model, self.d_ff, p.activation, name="mlp")(
+                _layer_norm("norm_post", x, p.rms_eps))), on

@@ -15,7 +15,7 @@ variable is set (``hunt --profile-dir``), and never otherwise.
 A device operation carries the scopes it was traced under in its
 ``op_name`` (``jit(train_step)/transpose(jvp(DecoderOnlyLM))/.../h0/attn/
 attention/attention.core/...``). ``SCOPES`` closes over a train step's
-source: every operation the program writes is under one of its twenty-one
+source: every operation the program writes is under one of its twenty-five
 names, and two rules read a path, :func:`layer_of` (which top-level scope
 owns it) and :func:`direction` (forward, the forward's second run under
 remat, backward, update). What carries no name of ``SCOPES`` the compiler
@@ -68,6 +68,15 @@ SCOPES = ("embed", "attention", "attention.core", "ffn", "readout_xent",
           # and the chunked scan of the gated delta rule
           # (ops/linear_attention.py), forward and backward
           "linear_attention", "linear_attention.core",
+          # a state-space mixer (models/lm.StateSpaceMixer): all of it, and
+          # the selective scan (ops/selective_scan.py), forward and backward
+          "ssm", "ssm.core",
+          # a gated memory unit (models/lm.GatedMemoryUnit): an earlier
+          # layer's scan output gated by this layer's projection
+          "gmu",
+          # differential attention's own (models/lm.DifferentialAttention):
+          # lambda, a_1 - lambda a_2, the pair's norm and (1 - lambda_init)
+          "attention.diff",
           # the trunk between the layers: the blocks' and the model's norms
           # outside a branch; the residual stream's sums and casts; what a
           # loss function does around the model and ``readout_xent`` (the
@@ -464,8 +473,9 @@ def print_routes(recs: List[dict]) -> None:
     """A line a trial: which attention route its steps took, as its
     ``trial.setup`` span has it (ops/attention.attention_route, the one
     rule, asked for the training and the evaluation rate); for a
-    model with a layer pattern a line each kind of layer, what the expert
-    layers hold, and (from ``trial.train``) what they counted and, where
+    model with a layer pattern a line each kind of layer (a state-space
+    kind's route, chunk and state type, which layers hand on and which
+    read), what the expert layers hold, and (from ``trial.train``) what they counted and, where
     layers select their keys, the selected pairs among the causal ones; for
     a rematerialised model what a block keeps besides its input and, of
     the matrix products it could keep, the bytes against the device's
@@ -488,6 +498,21 @@ def print_routes(recs: List[dict]) -> None:
                           f"{how['conv']}, chunks of {how['chunk']} by "
                           f"{how['route']}")
                     continue
+                if kind == "ssm":
+                    print(f"trial {r['trial']}: state-space layers "
+                          f"{_runs(how['layers'])}: selective scan over "
+                          f"{how['d_inner']} channels x {how['d_state']} "
+                          f"states ({how['state']}), convolutions of "
+                          f"{how['conv']}, steps of rank {how['dt_rank']}, "
+                          f"chunks of {how['chunk']} by {how['route']}" + (
+                              f"; layer {how['hands_on']} hands on its scan "
+                              "output" if how["hands_on"] else ""))
+                    continue
+                if kind == "gmu":
+                    print(f"trial {r['trial']}: gated memory units "
+                          f"{_runs(how['layers'])}: {how['d_inner']} wide, "
+                          f"reading layer {how['reads']}'s scan output")
+                    continue
                 if kind.startswith("latent"):
                     print(f"trial {r['trial']}: layers "
                           f"{_runs(how['layers'])}: latent attention, "
@@ -497,10 +522,17 @@ def print_routes(recs: List[dict]) -> None:
                           f"{how['route']}" + _HAND_OVER[how["hand_over"]])
                     continue
                 scores = how.get("index_scores")
+                pairs = how.get("differential")
                 print(f"trial {r['trial']}: {kind} layers: {how['route']}, "
                       f"mask by {how['mask']}" + (
                           f", index scores by {_scores_form(scores)}"
-                          if scores else ""))
+                          if scores else "") + (
+                          f"; layers {_runs(how['layers'])} differential: "
+                          f"{pairs[0]} query pairs on {pairs[1]} K/V pairs, "
+                          f"q\u00b7k {pairs[2]}, v {pairs[3]}" + (
+                              f", reading layer {how['reads']}'s K and V"
+                              if "reads" in how else "")
+                          if pairs else ""))
             remat = attrs.get("remat")
             if remat:
                 print(f"trial {r['trial']}: remat: {remat['blocks']} blocks "

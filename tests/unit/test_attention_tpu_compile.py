@@ -104,6 +104,28 @@ def test_structural_masks_and_grouped_heads_compile_for_a_v5e(one_chip, case):
 UNEQUAL = [(16384, 192, 128, 32, 32), (32768, 192, 128, 32, 32),
            (1000, 192, 128, 4, 4), (2048, 192, 128, 8, 2),
            (2048, 64, 128, 8, 8)]
+# (length, window): one map of the state-space cell's differential layers,
+# 20 query heads on 10 K/V heads at q.k 64 and the pair's joined v 128
+DIFFERENTIAL = [(8192, 512), (8192, None)]
+
+
+@pytest.mark.parametrize("case", DIFFERENTIAL, ids=str)
+def test_a_differential_map_compiles_for_a_v5e(one_chip, case):
+    """q_i on k_i and the joined v as ``models/lm.py::DifferentialAttention``
+    hands them over: grouped, unequal widths, under the window and without."""
+    s, window = case
+    shape = lambda *dims: jax.ShapeDtypeStruct(  # noqa: E731
+        dims, jnp.bfloat16, sharding=one_chip)
+
+    def loss(q, k, v):
+        out = flash_attention(q, k, v, CausalMask(window), impl="pallas")
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        shape(1, s, 20, 64), shape(1, s, 10, 64),
+        shape(1, s, 10, 128)).compile().as_text()
+    assert text.count("tpu_custom_call") == 2  # flash_fwd, flash_bwd
+    assert "flash_fwd" in text and "flash_bwd" in text
 
 
 @pytest.mark.parametrize("case", UNEQUAL, ids=lambda c: "x".join(map(str, c)))
@@ -350,3 +372,31 @@ def test_the_gated_delta_rule_s_kernels_compile_for_a_v5e(one_chip, shape):
         *args).compile().as_text()
     assert text.count("tpu_custom_call") == 2
     assert "linear_scan_fwd" in text and "linear_scan_bwd" in text
+
+
+# (tokens, channels, states): the state-space cell's Mamba layers (one row
+# of 8192 tokens, d_inner 5120, 16 states), and channels that pad to a tile
+SELECTIVE = [(8192, 5120, 16), (96, 160, 4)]
+
+
+@pytest.mark.parametrize("shape", SELECTIVE,
+                         ids=lambda s: "x".join(map(str, s)))
+def test_the_selective_scan_s_kernels_compile_for_a_v5e(one_chip, shape):
+    """ops/selective_scan.py's ``selective_scan_fwd`` and
+    ``selective_scan_bwd`` through Mosaic: a tile's channels as whole
+    registers, B and C as scalars from SMEM, a chunk's states (8.5 MB at
+    128 tokens) inside the VMEM limit the call asks for."""
+    from metaopt_tpu.ops.selective_scan import selective_scan
+
+    t, d, n = shape
+    on_chip = lambda *s: jax.ShapeDtypeStruct(  # noqa: E731
+        s, jnp.float32, sharding=one_chip)
+
+    def loss(x, dt, a, b, c):
+        return jnp.sum(selective_scan(x, dt, a, b, c, interpret=False) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        on_chip(1, t, d), on_chip(1, t, d), on_chip(d, n), on_chip(1, t, n),
+        on_chip(1, t, n)).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "selective_scan_fwd" in text and "selective_scan_bwd" in text

@@ -32,6 +32,7 @@ KINDS = {
                                    "sparse_lm_config"),
     "hybrid-decoder": ("olmo-hybrid-7b-tp2", "hybrid_lm_config"),
     "latent-decoder": ("kanana-2-30b-a3b-ep8", "mla_lm_config"),
+    "state-space-decoder": ("phi-4-mini-flash-vp8", "ssm_lm_config"),
 }
 #: no operation of the device: a literal, a function's end, and remat's own
 #: barrier around a block's kept values (jax names it ``.../remat2``)
@@ -182,7 +183,7 @@ def test_every_operation_of_a_train_step_has_a_layer(lowered_steps, kind,
 @pytest.mark.parametrize("kind, remat", [
     ("2017-base", False), ("pattern-decoder", True),
     ("selected-attention-decoder", True), ("hybrid-decoder", True),
-    ("latent-decoder", True)])
+    ("latent-decoder", True), ("state-space-decoder", True)])
 def test_a_step_has_operations_in_every_direction_it_runs(lowered_steps,
                                                          kind, remat):
     """Forward, backward and update in every step; the forward's second
@@ -215,6 +216,11 @@ def test_a_step_has_operations_in_every_direction_it_runs(lowered_steps,
     ("hybrid-decoder", "flash_bwd", 0, 1),
     ("latent-decoder", "flash_fwd", 5, 0),
     ("latent-decoder", "flash_bwd", 0, 5),
+    ("state-space-decoder", "selective_scan_fwd", 2, 0),
+    ("state-space-decoder", "selective_scan_bwd", 0, 2),
+    # two maps a layer of differential attention, three such layers
+    ("state-space-decoder", "flash_fwd", 6, 0),
+    ("state-space-decoder", "flash_bwd", 0, 6),
 ])
 def test_a_kernel_s_calls_by_direction(lowered_steps, kind, kernel, forward,
                                        backward):
@@ -227,7 +233,8 @@ def test_a_kernel_s_calls_by_direction(lowered_steps, kind, kernel, forward,
                     for d in trace.DIRECTIONS}
     assert by_direction == {"forward": forward, "forward.again": 0,
                             "backward": backward, "update": 0}
-    layer = "linear_attention" if kernel.startswith("linear") else "attention"
+    layer = {"linear": "linear_attention", "select": "ssm"}.get(
+        kernel[:6], "attention")
     assert {trace.layer_of(n) for n in calls} == {layer}
 
 
@@ -314,10 +321,11 @@ def test_the_two_rules_on_a_path(op_name, layer, direction):
 
 
 def test_the_layers_are_the_top_level_scopes_and_every_scope_has_one():
-    assert len(trace.SCOPES) == len(set(trace.SCOPES)) == 21
-    assert {"attention.latent", "moe.shared"} <= set(trace.SCOPES)
+    assert len(trace.SCOPES) == len(set(trace.SCOPES)) == 25
+    assert {"attention.latent", "moe.shared", "ssm.core",
+            "attention.diff"} <= set(trace.SCOPES)
     assert set(trace.LAYERS) == {
-        "embed", "attention", "ffn", "moe", "linear_attention",
+        "embed", "attention", "ffn", "moe", "linear_attention", "ssm", "gmu",
         "readout_xent", "optimizer", "eval", "norm", "residual", "loss"}
     for scope in trace.SCOPES:
         assert trace.layer_of(scope) in trace.LAYERS
