@@ -116,6 +116,7 @@ from metaopt_tpu.models.transformer import (
 from metaopt_tpu.models.moe import RoutingRule
 from metaopt_tpu.ops.attention import (REMAT_KEEPS, CausalMask, LatentKV,
                                        attend, attention_route)
+from metaopt_tpu.ops.embed import embed_gradient_route, embed_rows
 from metaopt_tpu.parallel.sharding import with_mesh_partitioning
 from metaopt_tpu.utils import trace
 
@@ -740,7 +741,8 @@ class DecoderOnlyLM(nn.Module):
                 PatternBlock, keeps=remat_keeps(p)["keeps"]
                 if self.keeps is None else self.keeps)
         with trace.scope("embed"):
-            x = table("embed")(tokens - first).astype(jnp.float32)
+            x = embed_rows(table("embed").embedding,
+                           tokens - first).astype(jnp.float32)
         heads = p.heads_held[1] if p.heads_held else self.n_heads
         for i, (sliding, rotary) in enumerate(p.layers):
             x = block_cls(self.d_model, heads, self.d_ff, p, sliding,
@@ -775,7 +777,7 @@ class DecoderOnlyLM(nn.Module):
                 HybridBlock, keeps=remat_keeps(p)["keeps"]
                 if self.keeps is None else self.keeps)
         with trace.scope("embed"):
-            x = emb(tokens - first).astype(jnp.float32)
+            x = embed_rows(emb.embedding, tokens - first).astype(jnp.float32)
         heads = p.heads_held[1] if p.heads_held else self.n_heads
         handed = {}
         for i, (layer, kind) in enumerate(zip(sp.layers, sp.kinds)):
@@ -1218,7 +1220,10 @@ def describe_pattern(hparams: Dict[str, Any], route: str, tokens: int,
                      mesh: Optional[Mesh] = None) -> Dict[str, Any]:
     """What ``trial.setup``'s span says of a description with a layer
     pattern ({} without one), for steps of ``tokens`` tokens in rows of
-    ``seq_len`` on attention route ``route`` under ``mesh``: for each kind
+    ``seq_len`` on attention route ``route`` under ``mesh``: the route the
+    embedding's gradient takes (ops/embed.embed_gradient_route: ``"sorted"``
+    or ``"take"``), the table's held rows and width, the tokens a step
+    looks up and whether the head reads the same table; for each kind
     of layer the route and the form of its mask, for a layer that selects
     its keys the form its index scores take at that length, for a latent
     layer its widths and how its operands reach attention
@@ -1249,7 +1254,11 @@ def describe_pattern(hparams: Dict[str, Any], route: str, tokens: int,
 
     out = {"attention_layers": {kind: {"route": route, "mask": mask_of(kind)}
                                 for kind in p.kinds()
-                                if kind not in ("linear", "ssm", "gmu")}}
+                                if kind not in ("linear", "ssm", "gmu")},
+           "embed": {"gradient": embed_gradient_route(mesh),
+                     "rows": p.vocab_held[1],
+                     "width": int(h.get("d_model", 512)), "tokens": tokens,
+                     "tied": p.hybrid is not None}}
     if p.hybrid is not None:
         from metaopt_tpu.ops.selective_scan import selective_scan_route
 

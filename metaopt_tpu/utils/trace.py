@@ -473,8 +473,9 @@ def print_routes(recs: List[dict]) -> None:
     """A line a trial: which attention route its steps took, as its
     ``trial.setup`` span has it (ops/attention.attention_route, the one
     rule, asked for the training and the evaluation rate); for a
-    model with a layer pattern a line each kind of layer (a state-space
-    kind's route, chunk and state type, which layers hand on and which
+    model with a layer pattern the embedding's table and its gradient's
+    route (ops/embed.embed_gradient_route), a line each kind of layer (a
+    state-space kind's route, chunk and state type, which layers hand on and which
     read), what the expert layers hold, and (from ``trial.train``) what they counted and, where
     layers select their keys, the selected pairs among the causal ones; for
     a rematerialised model what a block keeps besides its input and, of
@@ -533,6 +534,13 @@ def print_routes(recs: List[dict]) -> None:
                               f", reading layer {how['reads']}'s K and V"
                               if "reads" in how else "")
                           if pairs else ""))
+            embed = attrs.get("embed")
+            if embed:
+                print(f"trial {r['trial']}: embedding: {embed['rows']} rows "
+                      f"x {embed['width']}"
+                      + (", the head's table too" if embed["tied"] else "")
+                      + f", {embed['tokens']} tokens a step, gradient by "
+                      f"{embed['gradient']}")
             remat = attrs.get("remat")
             if remat:
                 print(f"trial {r['trial']}: remat: {remat['blocks']} blocks "

@@ -400,3 +400,29 @@ def test_the_selective_scan_s_kernels_compile_for_a_v5e(one_chip, shape):
         on_chip(1, t, n)).compile().as_text()
     assert text.count("tpu_custom_call") == 2
     assert "selective_scan_fwd" in text and "selective_scan_bwd" in text
+
+
+# (tokens, width, rows): the 8k decoder cell's lookup and the 16k
+# selected-attention cell's, and a width and a table that pad
+LOOKUPS = [(8192, 2560, 37984), (16384, 2048, 18992), (24, 160, 100)]
+
+
+@pytest.mark.parametrize("shape", LOOKUPS, ids=lambda s: "x".join(map(str, s)))
+def test_the_embedding_s_gradient_rule_compiles_for_a_v5e(one_chip, shape):
+    """ops/embed.py's ``embed_rows_bwd`` through Mosaic: a token's row out
+    of a block in VMEM by its sublane, a block's ids in SMEM, the table
+    written in whole (8, 128) tiles by copies the kernel starts itself."""
+    from metaopt_tpu.ops.embed import embed_rows
+
+    tokens, width, rows = shape
+    table = jax.ShapeDtypeStruct((rows, width), jnp.float32,
+                                 sharding=one_chip)
+    ids = jax.ShapeDtypeStruct((1, tokens), jnp.int32, sharding=one_chip)
+
+    def loss(table, ids):
+        return jnp.sum(embed_rows(table, ids, interpret=False).astype(
+            jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss)).lower(table, ids).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+    assert "embed_rows_bwd" in text and "scatter" not in text

@@ -103,6 +103,9 @@ def test_both_sides_name_the_same_leaves(both_sides):
     (_, prog), (_, ref) = both_sides["float32"]
     assert sorted(paths(prog)) == sorted(paths(ref))
     assert set(LEAVES) <= set(paths(ref))
+    # nn.Embed holds the two tables whatever looks their rows up
+    assert {p for p in paths(prog) if p.endswith("embedding")} == {
+        "embed/embedding", "head/embedding"}
 
 
 @pytest.mark.parametrize("how, tol", [("float32", 1e-5), ("bfloat16", 3e-3)])
@@ -823,6 +826,9 @@ def test_train_lm_says_which_layers_are_linear_and_what_a_block_keeps():
         "ffn.down": 2 * 2 * 2 * S * D, "ffn.gate": 2 * 2 * 2 * S * F,
         "ffn.up": 2 * 2 * 2 * S * F}
     assert "moe" not in setup
+    # the untied table and the route its gradient takes off the chip
+    assert setup["embed"] == {"gradient": "take", "rows": V, "width": D,
+                              "tokens": 2 * S, "tied": False}
 
 
 @pytest.mark.parametrize("scope", ["linear_attention", "linear_attention.core",

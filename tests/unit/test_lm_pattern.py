@@ -682,6 +682,10 @@ def test_one_place_decides_the_grouped_product(monkeypatch, backend, rows,
          "moe_num_active_primary_experts": 6, "moe_ffn_hidden_size": 768},
         "reference", tokens=rows // 6)
     assert said["moe"]["products"] == product
+    # the embedding's gradient asks its own one place (ops/embed.py)
+    assert said["embed"] == {
+        "gradient": "sorted" if backend == "tpu" else "take", "rows": 1000,
+        "width": 2560, "tokens": rows // 6, "tied": False}
 
 
 def test_the_step_compiles_once_and_starts_near_the_uniform_loss():
@@ -710,8 +714,11 @@ def _on_the_kernels(patch):
     """The Pallas route as the chip takes it, interpreted here: the backend
     reads as the TPU, and the kernels' entry runs the interpreter on float32
     operands (inside the structural kernels' loops this CPU's dot takes no
-    pair of bfloat16)."""
-    from metaopt_tpu.ops import attention
+    pair of bfloat16); the embedding's sorted gradient rule likewise."""
+    import functools
+
+    from metaopt_tpu.models import lm
+    from metaopt_tpu.ops import attention, embed
 
     real = attention.flash_attention
 
@@ -721,6 +728,8 @@ def _on_the_kernels(patch):
 
     patch.setattr(jax, "default_backend", lambda: "tpu")
     patch.setattr(attention, "flash_attention", interpreted)
+    patch.setattr(lm, "embed_rows", functools.partial(
+        embed.embed_rows, interpret=True))
 
 
 def _kernel_calls(jaxpr):

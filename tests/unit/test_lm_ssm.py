@@ -232,6 +232,8 @@ def test_both_sides_name_the_same_leaves(both_sides):
     assert sorted(paths(prog)) == sorted(paths(ref))
     assert set(LEAVES) <= set(paths(ref))
     assert "head" not in prog           # the head is the embedding's table
+    assert [p for p in paths(prog) if p.endswith("embedding")] == [
+        "embed/embedding"]
 
 
 @pytest.mark.parametrize("how, tol", [("float32", 1e-5), ("bfloat16", 3e-3)])
@@ -464,6 +466,10 @@ def test_the_description_builds_the_pattern():
     assert (p.window, p.rms_eps, p.head_dim, p.n_kv_heads, p.activation) \
         == (WINDOW, 1e-5, W, HKV, "silu")
     assert lm.make_lm(description()).n_layers == len(HELD)
+    # the one table, tied, and the route its lookup's gradient takes here
+    assert lm.describe_pattern(description(), "reference", tokens=S)[
+        "embed"] == {"gradient": "take", "rows": V, "width": D, "tokens": S,
+                     "tied": True}
     # without ``layers_held`` every published layer is held
     assert lm.pattern_of(lm._own_names(
         description(None))).hybrid.layers == tuple(range(OF))

@@ -221,6 +221,12 @@ def test_a_step_has_operations_in_every_direction_it_runs(lowered_steps,
     # two maps a layer of differential attention, three such layers
     ("state-space-decoder", "flash_fwd", 6, 0),
     ("state-space-decoder", "flash_bwd", 0, 6),
+    # the embedding's gradient rule: one table, written once
+    ("pattern-decoder", "embed_rows_bwd", 0, 1),
+    ("selected-attention-decoder", "embed_rows_bwd", 0, 1),
+    ("hybrid-decoder", "embed_rows_bwd", 0, 1),
+    ("latent-decoder", "embed_rows_bwd", 0, 1),
+    ("state-space-decoder", "embed_rows_bwd", 0, 1),
 ])
 def test_a_kernel_s_calls_by_direction(lowered_steps, kind, kernel, forward,
                                        backward):
@@ -233,9 +239,32 @@ def test_a_kernel_s_calls_by_direction(lowered_steps, kind, kernel, forward,
                     for d in trace.DIRECTIONS}
     assert by_direction == {"forward": forward, "forward.again": 0,
                             "backward": backward, "update": 0}
-    layer = {"linear": "linear_attention", "select": "ssm"}.get(
-        kernel[:6], "attention")
+    layer = {"linear": "linear_attention", "select": "ssm",
+             "embed_": "embed"}.get(kernel[:6], "attention")
     assert {trace.layer_of(n) for n in calls} == {layer}
+
+
+@pytest.mark.parametrize("kind", [k for k in KINDS if k != "2017-base"])
+def test_the_lookup_and_its_gradient_rule_are_the_embedding_s(lowered_steps,
+                                                             kind):
+    """ops/embed.py's two rules under the scope ``embed``: the gather in
+    the forward; the sort, the sorted rows and the kernel in the backward
+    (``transpose(`` with the rule's own scope), and no scatter-add."""
+    ops = [(k, n) for k, n in lowered_steps(kind, "tpu")
+           if trace.layer_of(n) == "embed"]
+    by_direction = {}
+    for k, n in ops:
+        by_direction.setdefault(trace.direction(n), set()).add(
+            k.split(".")[-1])
+    assert set(by_direction) == {"forward", "backward"}
+    assert "gather" in by_direction["forward"]
+    assert {"sort", "gather", "custom_call"} <= by_direction["backward"]
+    assert "scatter" not in by_direction["backward"]
+    # off the chip the plain lookup stands, and autodiff's scatter-add
+    plain = {k.split(".")[-1] for k, n in lowered_steps(kind, "here")
+             if trace.layer_of(n) == "embed"
+             and trace.direction(n) == "backward"}
+    assert "scatter" in plain and "custom_call" not in plain
 
 
 @pytest.mark.parametrize("kind, helper", [
