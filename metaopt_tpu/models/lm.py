@@ -1228,14 +1228,14 @@ def describe_pattern(hparams: Dict[str, Any], route: str, tokens: int,
     its keys the form its index scores take at that length, for a latent
     layer its widths and how its operands reach attention
     (ops/latent_attention.hand_over: ``"in place"`` or ``"copies"``), and
-    the expert layers' share, the product they take, the rows of their
+    the expert layers' share, the product they take and how the held
+    experts' part runs (models/moe.describe_experts), the rows of their
     buffers, the rows a trip of the routing's loops moves and, for the
     DeepSeek-V3 family, its routing rule, shared experts and leading dense
     layers; for the ``phi4flash`` family the scan's route, chunk and state
     type, the differential pairs' widths, and which layers hand on and
     which read."""
-    from metaopt_tpu.models.moe import (grouped_matmul_impl,
-                                        routing_chunk_rows)
+    from metaopt_tpu.models.moe import describe_experts, routing_chunk_rows
 
     h = _own_names(hparams)
     p = pattern_of(h)
@@ -1307,11 +1307,12 @@ def describe_pattern(hparams: Dict[str, Any], route: str, tokens: int,
                 said["index_scores"] = scores_of_a_row(seq_len,
                                                        p.selection[1])
     if p.n_experts:
+        # buffer rows, model width, an expert's width: what the route asks
+        how = describe_experts(tokens * p.top_k, int(h.get("d_model", 512)),
+                               p.expert_d_ff)
         out["moe"] = {"routed_over": p.n_experts, "top_k": p.top_k,
                       "held": list(p.experts_held),
-                      "products": grouped_matmul_impl(
-                          tokens * p.top_k, int(h.get("d_model", 512)),
-                          p.expert_d_ff),
+                      "products": how["products"], "experts": how,
                       "buffer_rows": tokens * p.top_k,
                       "chunk_rows": routing_chunk_rows(tokens * p.top_k)}
         if p.latent is not None:  # the family's routing, beside the counts

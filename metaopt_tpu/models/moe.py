@@ -46,14 +46,21 @@ weighed without it, with shared experts beside the routed ones; gated experts of
 every (token, choice) item is computed, whatever the imbalance. It is told which experts it holds (a contiguous
 share of the published count), routes over all of them, and returns the
 part of the layer's output that its own experts give: the items whose
-expert lives here are ordered by expert and go through three grouped
-matrix products (:func:`grouped_matmul`). The buffers have the static
+expert lives here are ordered by expert and go through the held experts'
+part (:func:`_held_experts`, one function with a gradient rule of its own):
+the gate's and the up matrix joined are ONE grouped product of ``2 f``
+columns, the gating ``act(gate) * up`` a pass over the filled tiles, the
+down matrix a second grouped product; backward two grouped products for the
+rows (the one through the joined matrix sums the gate's and the up
+product's input gradients in float32) and two for the weights, which
+contract over the rows where they lie. The buffers have the static
 worst-case size, all ``tokens x k`` items, which is what makes the layer
-dropless; the passes over them have the size of what is filled: dispatch,
+dropless; **every pass over them has the size of what is filled**: dispatch,
 combine and the backward of each are loops over chunks of rows whose trip
 count is read from the count of held items (:func:`routing_plan`), up to
-the buffers' end under the worst imbalance, and the rows behind the filled
-ones hold zeros. On an ``ep`` mesh axis each chip holds ``held / ep`` of
+the buffers' end under the worst imbalance, and leave zeros behind the
+filled rows; the experts' passes visit the tiles that hold a filled row and
+leave the rest as the memory held it. On an ``ep`` mesh axis each chip holds ``held / ep`` of
 the experts and the parts are summed over the axis; on one chip the layer
 runs without that exchange.
 """
@@ -183,46 +190,123 @@ class MoEFeedForward(nn.Module):
 # dropless routing over the experts held here
 
 
+#: bytes of VMEM the blocks of a grouped product may take together, by the
+#: count below (Mosaic's scoped limit on a v5e is 16 MiB, and its own count
+#: of megablox's kernels reads up to a MiB over this one)
+_TILE_BYTES = 14 * 2 ** 20
+
+
+def _divisor(size: int, tiles=(512, 256, 128)):
+    """The largest of ``tiles`` that divides ``size``, None if none does."""
+    return next((t for t in tiles if size % t == 0), None)
+
+
+def _widest(size: int, fits):
+    """The widest tile of whole lanes (128) that divides ``size`` and
+    ``fits``; ``size`` is whole lanes and a tile of 128 fits."""
+    return next(w for w in range(size, 0, -128)
+                if size % w == 0 and (fits(w) or w == 128))
+
+
+def _tiling(m: int, k: int, n: int, blocks):
+    """(tm, tk, tn) for a grouped product over m rows, None for an axis no
+    tile divides: rows in tiles of 256 (a group's first and last tile hold
+    other groups' rows too, which are computed and masked: the shorter the
+    tile the fewer); then k as whole as fits beside an n-tile of up to
+    512, then n as wide as fits, by ``blocks(tm, tk, tn)``, the bytes of
+    the kernel's blocks in VMEM, under ``_TILE_BYTES``. Measured on the v5e
+    at the three MoE cells' shapes (d 2560 and 2048, f 768, 49 152 to
+    131 072 rows of which a quarter to an eighth are filled, 16 groups;
+    PERF.md section 6, PR 43), forward, transposed and ``tgmm``: a product
+    whose k is one tile has no accumulation loop and a third to half as
+    many programs, and takes 0.35-0.94 ms where 512-row tiles with k cut in
+    two to five took 0.64-1.35; row tiles of 128 read 2 % under to 7 %
+    over, of 512 slower or over Mosaic's VMEM; 128-tiles throughout, megablox's
+    default, are over ten times slower."""
+    tm, side = _divisor(m, (256, 128)), _divisor(n)
+    if tm is None or side is None or k % 128:
+        return tm, _divisor(k), side
+
+    def fits(tk, tn):
+        return blocks(tm, tk, tn) <= _TILE_BYTES
+
+    tk = _widest(k, lambda tk: fits(tk, side))
+    return tm, tk, _widest(n, lambda tn: fits(tk, tn))
+
+
 def _gmm_tiling(m: int, k: int, n: int):
-    """megablox's tiles for an (m, k) x (g, k, n) product: the largest of
-    512, 256, 128 that divides each axis, None for an axis none divides
-    (measured: 128-tiles, megablox's default, are five times slower)."""
-    return tuple(next((t for t in (512, 256, 128) if size % t == 0), None)
-                 for size in (m, k, n))
+    """megablox's ``gmm`` over (m, k) rows and (g, k, n) weights: both
+    operands' and the output's bfloat16 blocks twice (one in flight), the
+    float32 sums once."""
+    return _tiling(m, k, n, lambda tm, tk, tn: 4 * (
+        tm * tk + tk * tn + tm * tn) + 4 * tm * tn)
+
+
+def _tgmm_tiling(m: int, k: int, n: int):
+    """megablox's ``tgmm`` over (m, k) and (m, n) rows: the two operands'
+    blocks twice, the output's (tk, tn) twice in bfloat16 and once in
+    float32, in VMEM over all of a group's rows."""
+    return _tiling(m, k, n, lambda tm, tk, tn: 4 * (
+        tm * tk + tm * tn) + 8 * tk * tn)
+
+
+def _gating_tile(n: int, f: int):
+    """Rows a program of the gating kernels (ops/experts.py) takes over
+    buffers of ``n`` rows and experts ``f`` wide: the largest of 512, 256,
+    128 that divides n and whose blocks and float32 temporaries fit (the
+    gradient's kernel takes ~36 bytes a row and column of f by Mosaic's
+    count at f 768 to 1536; 256 and 512 rows read the same time at the
+    cells' f 768)."""
+    return _divisor(n, [t for t in (512, 256, 128)
+                        if 36 * f * t <= _TILE_BYTES or t == 128])
 
 
 def grouped_matmul_impl(m: int, k: int, n: int) -> str:
     """Which grouped matrix product :class:`DroplessMoE` runs on (m, k)
     rows and (g, k, n) weights: the Pallas ``megablox`` kernels on a TPU
     where a tile divides each axis, ``jax.lax.ragged_dot`` elsewhere. The
-    one place that decides: the layer and ``trial.setup``'s span both ask.
+    one place that decides, for every pass of :func:`_held_experts`: the
+    gating kernels of ops/experts.py go with megablox (where a tile divides
+    m, k and n, one divides every product of the part, 2n columns, n deep,
+    k wide, and the gating's rows), loops of :func:`_over_chunks` with
+    ``ragged_dot``; the layer and ``trial.setup``'s span both ask.
 
-    Measured on the v5e at the benchmark cell's shapes (49 152 rows of
-    which a quarter are filled, 16 groups, 2560 x 768; PERF.md): XLA's own
-    ``ragged_dot`` and megablox at its best tiling take the same time,
-    forward and backward, alone and inside the step, and both skip the
-    rows no group fills. But XLA names its ragged products
+    Measured on the v5e at the 8k decoder cell's shapes (PR 27: 49 152 rows
+    of which a quarter are filled, 16 groups, a product 2560 x 768;
+    PERF.md): XLA's own ``ragged_dot`` and megablox at 512-tiles took the
+    same time, forward and backward, alone and inside the step, and both
+    skip the rows no group fills. But XLA names its ragged products
     ``ragged-dot-none`` in a device trace, outside every scope of the
-    program, and megablox's ``gmm`` / ``tgmm`` calls keep theirs; so the
-    TPU takes megablox, and the backends that cannot run its kernels take
-    ``ragged_dot``."""
+    program, and megablox's ``gmm`` calls keep theirs; so the TPU takes
+    megablox, and the backends that cannot run its kernels take
+    ``ragged_dot``. At the tiles :func:`_tiling` chooses (PR 43, the three
+    MoE cells' shapes) megablox's products take about half of what they
+    took at 512-tiles; ``ragged_dot`` was not measured again."""
     if jax.default_backend() == "tpu" and all(_gmm_tiling(m, k, n)):
         return "megablox"
     return "ragged_dot"
 
 
-def grouped_matmul(x, w, group_sizes, impl: str):
-    """``x`` (m, k) rows ordered by group, ``w`` (g, k, n), ``group_sizes``
-    (g,) int32 -> (m, n) in x's dtype: row r of group e times ``w[e]``, by
-    ``impl`` (:func:`grouped_matmul_impl`). Rows past ``sum(group_sizes)``
-    hold nothing a caller may read."""
-    if impl == "megablox":
-        from jax.experimental.pallas.ops.tpu.megablox import ops
-
-        return ops.gmm(x, w, group_sizes, preferred_element_type=x.dtype,
-                       tiling=_gmm_tiling(*x.shape, w.shape[2]))
-    return jax.lax.ragged_dot(x, w, group_sizes,
-                              preferred_element_type=x.dtype)
+def describe_experts(n: int, d: int, f: int) -> dict:
+    """What ``trial.setup``'s span says of the held experts' part over
+    buffers of ``n`` rows, a model width ``d`` and experts ``f`` wide: the
+    answer of :func:`grouped_matmul_impl`, the gating that goes with it
+    (kernels beside megablox, loops of :func:`_over_chunks` beside
+    ``ragged_dot``) and, on megablox, the (rows, k, n) tiles of the six
+    grouped products by the pass that makes them and the gating kernels'
+    rows a program (None where XLA tiles)."""
+    impl = grouped_matmul_impl(n, d, f)
+    kernels = impl == "megablox"
+    return {"gate_up": f"one product of {2 * f} columns",
+            "gating": "pallas" if kernels else "chunks",
+            "products": impl,
+            "tiles": {"gu": _gmm_tiling(n, d, 2 * f),
+                      "out": _gmm_tiling(n, f, d),
+                      "d_h": _gmm_tiling(n, d, f),
+                      "d_rows": _gmm_tiling(n, 2 * f, d),
+                      "d_w_gu": _tgmm_tiling(n, d, 2 * f),
+                      "d_w_down": _tgmm_tiling(n, f, d),
+                      "gating": _gating_tile(n, f)} if kernels else None}
 
 
 #: rows one trip of a loop over the buffers' rows moves, and of a loop over
@@ -405,11 +489,128 @@ def _combine_bwd(res, g):
     d_out, d_by_row = _over_chunks(
         plan["filled"], n, chunk, body,
         (_zeros(out.shape, out.dtype, plan), jnp.zeros((n,), jnp.float32)))
-    return (d_out, d_by_row[plan["inverse"]].reshape(weights.shape)
-            .astype(weights.dtype), None)
+    d_weights = d_by_row[plan["inverse"]].reshape(weights.shape)
+    # the gather back to item order is made before the experts' backward
+    # may start, while its (n,) operand is where the loop left it: behind
+    # the experts' Pallas calls the compiler reads it from HBM, 2.7 ms a
+    # layer for 0.9 at 131 072 rows (PERF.md section 6, PR 43)
+    d_out, d_weights = jax.lax.optimization_barrier((d_out, d_weights))
+    return d_out, d_weights.astype(weights.dtype), None
 
 
 _combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def _kernels():
+    """ops/experts.py, loaded when the chip's route first asks: Pallas and
+    megablox stay out of the import of this module and of models/lm.py,
+    which every cell pays in its set-up. (The tests put the same functions
+    with ``interpret=True`` in its place.)"""
+    from metaopt_tpu.ops import experts
+
+    return experts
+
+
+def _product(x, w, items, impl: str, transposed: bool = False):
+    """``x`` (m, k) rows ordered by group, ``w`` (g, k, n), or (g, n, k)
+    where ``transposed``, ``items`` (g,) int32 -> (m, n) in x's dtype,
+    float32 sums: row r of group e times ``w[e]``, by ``impl``
+    (:func:`grouped_matmul_impl`). Rows past ``sum(items)`` hold nothing a
+    caller may read."""
+    if impl == "megablox":
+        return _kernels().gmm(
+            x, w, items, _gmm_tiling(*x.shape, w.shape[1 if transposed else 2]),
+            transpose_rhs=transposed)
+    return jax.lax.ragged_dot(x, w.swapaxes(1, 2) if transposed else w, items,
+                              preferred_element_type=x.dtype)
+
+
+def _weight_gradient(x, g, items, impl: str):
+    """(held, k, n) in x's dtype, float32 sums: ``x[rows of e].T @ g[rows
+    of e]`` for each group, both read (rows, width) as they lie."""
+    if impl == "megablox":
+        return _kernels().tgmm(
+            x, g, items, _tgmm_tiling(x.shape[0], x.shape[1], g.shape[1]))
+    return jax.lax.ragged_dot_general(
+        x, g, items, jax.lax.RaggedDotDimensionNumbers(
+            dot_dimension_numbers=(((0,), (0,)), ((), ())),
+            lhs_ragged_dimensions=[0], rhs_group_dimensions=[]),
+        preferred_element_type=x.dtype)
+
+
+def _gate(gu, filled, activation, impl: str):
+    """(n, f): ``activation(gu[:, :f]) * gu[:, f:]`` in the filled rows (up
+    to the end of the tile or chunk that holds the last): beside megablox
+    the kernel, in float32 rounded once; else the loop, in the operands'
+    dtype as XLA has it."""
+    n, f = gu.shape[0], gu.shape[1] // 2
+    if impl == "megablox":
+        return _kernels().gating(gu, filled, activation, _gating_tile(n, f))
+    chunk = routing_chunk_rows(n)
+
+    def body(start, below, fresh, h):
+        mine = jax.lax.dynamic_slice_in_dim(gu, start, chunk)
+        return jax.lax.dynamic_update_slice_in_dim(
+            h, activation(mine[:, :f]) * mine[:, f:], start, 0)
+
+    return _over_chunks(filled, n, chunk, body,
+                        _zeros((n, f), gu.dtype, {"filled": filled}))
+
+
+def _gate_bwd(d_h, gu, filled, activation, impl: str):
+    """(n, 2f): the gradient of :func:`_gate` to ``gu``, in the same
+    rows."""
+    n, f = d_h.shape
+    if impl == "megablox":
+        return _kernels().gating_bwd(d_h, gu, filled, activation,
+                                    _gating_tile(n, f))
+    chunk = routing_chunk_rows(n)
+
+    def body(start, below, fresh, d_gu):
+        mine = jax.lax.dynamic_slice_in_dim(gu, start, chunk)
+        _, back = jax.vjp(lambda g, u: activation(g) * u,
+                          mine[:, :f], mine[:, f:])
+        return jax.lax.dynamic_update_slice_in_dim(
+            d_gu, jnp.concatenate(back(jax.lax.dynamic_slice_in_dim(
+                d_h, start, chunk)), axis=1), start, 0)
+
+    return _over_chunks(filled, n, chunk, body,
+                        _zeros((n, 2 * f), gu.dtype, {"filled": filled}))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
+def _held_experts(rows, w_gu, w_down, items, filled, activation, impl: str):
+    """(n, d): the held experts' gated feed-forward of each filled row of
+    ``rows`` (n, d), ordered by expert, ``items`` (held,) rows an expert and
+    ``filled`` their sum; ``w_gu`` (held, d, 2f) the gate's and the up
+    matrix joined, ``w_down`` (held, f, d), all of one dtype (bfloat16);
+    every pass by ``impl`` (:func:`grouped_matmul_impl`). No pass reads or
+    writes a row past the tile that holds row ``filled - 1``; the rows
+    behind hold what the memory held, and may meet nothing but a product
+    that skips them or a ``where``."""
+    return _held_experts_fwd(rows, w_gu, w_down, items, filled, activation,
+                             impl)[0]
+
+
+def _held_experts_fwd(rows, w_gu, w_down, items, filled, activation, impl):
+    gu = _product(rows, w_gu, items, impl)
+    h = _gate(gu, filled, activation, impl)
+    out = _product(h, w_down, items, impl)
+    return out, (rows, gu, h, w_gu, w_down, items, filled)
+
+
+def _held_experts_bwd(activation, impl, residuals, d_out):
+    rows, gu, h, w_gu, w_down, items, filled = residuals
+    d_h = _product(d_out, w_down, items, impl, transposed=True)
+    d_gu = _gate_bwd(d_h, gu, filled, activation, impl)
+    # one product, its sums in float32 over the 2f columns: the gate's and
+    # the up product's input gradients added before they are rounded
+    d_rows = _product(d_gu, w_gu, items, impl, transposed=True)
+    return (d_rows, _weight_gradient(rows, d_gu, items, impl),
+            _weight_gradient(h, d_out, items, impl), None, None)
+
+
+_held_experts.defvjp(_held_experts_fwd, _held_experts_bwd)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -475,11 +676,14 @@ def dropless_experts(x, weights, experts, w_gate, w_up, w_down, first,
     ``sum_k weights * expert(x)`` over the choices whose expert is held,
     and ``{"items": (held,) int32 a held expert, "dropped": () int32,
     "chunks": () int32}``. Nothing is dropped: the buffers hold all t * k
-    items, the most that can be routed here. The passes over them move the
-    rows that held items fill, a chunk a trip, and leave zeros behind
-    them; the products skip those. ``chunks``: the trips of a pass in
-    buffer order, ``ceil(filled / chunk)`` (a pass that sums back to
-    tokens moves as many rows in at most k trips more).
+    items, the most that can be routed here. The routing's passes over them
+    move the rows that held items fill, a chunk a trip, and leave zeros
+    behind them; between them the experts' part (:func:`_held_experts`, by
+    :func:`grouped_matmul_impl`) is two grouped products and the gating forward,
+    four grouped products and the gating's gradient backward, each over the
+    filled rows' tiles alone. ``chunks``: the trips of a pass in buffer
+    order, ``ceil(filled / chunk)`` (a pass that sums back to tokens moves
+    as many rows in at most k trips more).
     """
     t, d = x.shape
     k = experts.shape[1]
@@ -492,12 +696,13 @@ def dropless_experts(x, weights, experts, w_gate, w_up, w_down, first,
                                       held).astype(jnp.int32), held)
         rows = _dispatch(x.astype(jnp.bfloat16), plan)
     with trace.scope("moe.experts"):
-        product = functools.partial(
-            grouped_matmul, group_sizes=plan["items"],
-            impl=grouped_matmul_impl(n, d, w_gate.shape[2]))
-        h = activation(product(rows, w_gate.astype(jnp.bfloat16))) \
-            * product(rows, w_up.astype(jnp.bfloat16))
-        out = product(h, w_down.astype(jnp.bfloat16))
+        # gate and up are ONE product of 2f columns: joined in the cast
+        # that is made anyway (one fusion where two casts ran); autodiff
+        # cuts the joined matrix's gradient back into the two leaves'
+        w_gu = jnp.concatenate([w_gate, w_up], axis=2).astype(jnp.bfloat16)
+        out = _held_experts(rows, w_gu, w_down.astype(jnp.bfloat16),
+                            plan["items"], plan["filled"], activation,
+                            grouped_matmul_impl(n, d, w_gate.shape[2]))
     with trace.scope("moe.combine"):
         y = _combine(out, weights, plan)
     # the items that found no row in the buffers: 0 while the buffers have

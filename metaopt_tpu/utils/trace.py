@@ -550,9 +550,12 @@ def print_routes(recs: List[dict]) -> None:
             if moe:
                 held[r["trial"]] = moe
                 first, count = moe["held"]
+                how = moe.get("experts")
                 print(f"trial {r['trial']}: experts {first}-"
                       f"{first + count - 1} of {moe['routed_over']} held, "
-                      f"top {moe['top_k']}, products by {moe['products']}")
+                      f"top {moe['top_k']}, products by {moe['products']}"
+                      + (f", gate and up as {how['gate_up']}, gating by "
+                         f"{how['gating']}" if how else ""))
                 if "scoring" in moe:
                     bias = " on score + bias" if moe["bias"] else ""
                     print(f"trial {r['trial']}: experts: "
@@ -577,8 +580,10 @@ def print_routes(recs: List[dict]) -> None:
                     * said.get("buffer_rows", 0))
             if rows and "chunks" in counts:
                 moved = sum(counts["chunks"]) * said["chunk_rows"]
+                filled = sum(map(sum, counts["items"]))
                 print(f"trial {r['trial']}: routing moved "
-                      f"{100 * moved / rows:.1f} % of the buffers' rows")
+                      f"{100 * moved / rows:.1f} % of the buffers' rows, "
+                      f"the experts' passes {100 * filled / rows:.1f} %")
         pairs = (attrs.get("selection") or {}) \
             if r["name"] == "trial.train" else {}
         for layer, (chosen, seen) in enumerate(zip(

@@ -426,3 +426,46 @@ def test_the_embedding_s_gradient_rule_compiles_for_a_v5e(one_chip, shape):
     text = jax.jit(jax.grad(loss)).lower(table, ids).compile().as_text()
     assert text.count("tpu_custom_call") == 1
     assert "embed_rows_bwd" in text and "scatter" not in text
+
+
+# (buffer rows, d, f, activation): the held experts' part of the 16k
+# selected-attention cell, the latent cell and the 8k decoder; and experts
+# twice as wide as any cell's, where the tiles have to give way to VMEM
+# (the gating's 512 rows a program would take 28 MiB there)
+EXPERTS = [(131072, 2048, 768, "silu"), (98304, 2048, 768, "silu"),
+           (49152, 2560, 768, "relu"), (32768, 4096, 1536, "silu")]
+
+
+@pytest.mark.parametrize("shape", EXPERTS, ids=lambda s: "x".join(map(str, s)))
+def test_the_held_experts_part_compiles_for_a_v5e(one_chip, shape):
+    """models/moe.py's ``_held_experts`` and its gradient on the chip's
+    route, through Mosaic at the cells' own sizes and the tiles the layer
+    chooses: six megablox calls, the gating and its gradient with a grid
+    read on the device. The compiled program makes no transposed and no
+    other copy of a buffer: the turn a weight gradient gives its left
+    operand and the one megablox's ``tgmm`` gives it back are cancelled,
+    and the kernel reads ``rows`` and ``h`` as they lie."""
+    import re
+
+    from flax import linen as nn
+
+    from metaopt_tpu.models import moe
+
+    n, d, f, activation = shape
+    act = {"relu": nn.relu, "silu": nn.silu}[activation]
+    on_chip = lambda s, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        s, dtype, sharding=one_chip)
+
+    def loss(rows, w_gu, w_down, items):
+        out = moe._held_experts(rows, w_gu, w_down, items, jnp.sum(items),
+                                act, "megablox")
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        on_chip((n, d), jnp.bfloat16), on_chip((16, d, 2 * f), jnp.bfloat16),
+        on_chip((16, f, d), jnp.bfloat16),
+        on_chip((16,), jnp.int32)).compile().as_text()
+    assert text.count("tpu_custom_call") == 8
+    assert "expert_gating_bwd" in text
+    buffers = re.compile(rf"= \w+\[(\d+,)?{n}(,\d+)?\]\S* (transpose|copy)\(")
+    assert [line for line in text.splitlines() if buffers.search(line)] == []

@@ -315,13 +315,14 @@ def test_on_an_ep_axis_each_chip_holds_its_part_and_the_sum_is_the_layer(ep):
 T_LONG = 4000   # t x k = 12 000 rows: five chunks of 2048 and a part of one
 
 
-def ref_experts(x, weights, experts, gate, up, down, first):
+def ref_experts(x, weights, experts, gate, up, down, first,
+                act=jax.nn.relu):
     """The held experts' part from the routing itself, float32: what
     ``ref_moe`` does after its own top-k."""
     y = jnp.zeros_like(x)
     for e in range(gate.shape[0]):
         we = jnp.sum(jnp.where(experts == first + e, weights, 0.0), -1)
-        hid = jax.nn.relu(jnp.dot(x, gate[e], precision=HI)) \
+        hid = act(jnp.dot(x, gate[e], precision=HI)) \
             * jnp.dot(x, up[e], precision=HI)
         y = y + we[:, None] * jnp.dot(hid, down[e], precision=HI)
     return y
@@ -491,6 +492,166 @@ def test_no_pass_of_the_routing_has_the_worst_case_s_size():
             and math.prod(s[1]) >= t * k * d] == []
 
 
+# -- the held experts' part: the filled rows only ------------------------------
+
+def _routed_inputs(t, d, f, share, seed=11):
+    """x, a cotangent, the routing and the held share's three matrices for
+    ``share`` of ROUTINGS over ``t`` tokens."""
+    from metaopt_tpu.models.moe import route_top_k
+
+    (first, count), biased = ROUTINGS[share]
+    ks = jax.random.split(jax.random.PRNGKey(seed), 6)
+    x = jax.random.normal(ks[0], (t, d))
+    cot = jax.random.normal(ks[1], (t, d))
+    mats = [jax.random.normal(k, shape)[first:first + count]
+            * shape[1] ** -0.5 for k, shape in
+            zip(ks[2:5], [(E, d, f), (E, d, f), (E, f, d)])]
+    logits = 2.0 * jax.random.normal(ks[5], (t, E))
+    if biased:
+        logits = logits + jnp.zeros((E,)).at[:TOPK].set(50.0)
+    weights, experts = route_top_k(logits, TOPK)
+    return x, cot, weights, experts, mats, first
+
+
+def _output_and_gradients(fn, x, cot, weights, mats):
+    """{part: array}: ``fn``'s output and its gradient to each input."""
+    def loss(x, weights, gate, up, down):
+        y = fn(x, weights, gate, up, down)
+        return jnp.sum(y * cot), y
+    grads, y = jax.grad(loss, argnums=(0, 1, 2, 3, 4), has_aux=True)(
+        x, weights, *mats)
+    return {k: np.asarray(v) for k, v in zip(PARTS, (y,) + grads)}
+
+
+@pytest.mark.parametrize("activation", ["relu", "silu"])
+@pytest.mark.parametrize("share", list(ROUTINGS))
+def test_what_lies_past_the_filled_rows_reaches_nothing(monkeypatch, share,
+                                                        activation):
+    """``gu``, ``h``, ``d_h`` and ``d_gu`` hold whatever the passes left
+    behind row ``filled``: NaN planted there on the way in and on the way
+    out of the gating, forward and backward, reaches no output and no
+    gradient, to the bit."""
+    from metaopt_tpu.models import moe
+
+    act = {"relu": jax.nn.relu, "silu": jax.nn.silu}[activation]
+    x, cot, weights, experts, mats, first = _routed_inputs(T_LONG, D, F, share)
+
+    def layer(x, weights, gate, up, down):
+        return moe.dropless_experts(x, weights, experts, gate, up, down,
+                                    first, act)[0]
+
+    clean = _output_and_gradients(layer, x, cot, weights, mats)
+    planted = []
+
+    def past(a, filled):
+        planted.append(a.shape)
+        return jnp.where(jnp.arange(a.shape[0])[:, None] >= filled, jnp.nan,
+                         a)
+
+    gate, gate_bwd = moe._gate, moe._gate_bwd
+    monkeypatch.setattr(moe, "_gate", lambda gu, filled, *how: past(
+        gate(past(gu, filled), filled, *how), filled))
+    monkeypatch.setattr(moe, "_gate_bwd", lambda d_h, gu, filled, *how: past(
+        gate_bwd(past(d_h, filled), past(gu, filled), filled, *how), filled))
+    dirty = _output_and_gradients(layer, x, cot, weights, mats)
+    n = T_LONG * TOPK
+    assert sorted(set(planted)) == [(n, F), (n, 2 * F)] and len(planted) >= 5
+    for part in PARTS:
+        assert np.isfinite(dirty[part]).all(), part
+        np.testing.assert_array_equal(dirty[part], clean[part], err_msg=part)
+
+
+@pytest.mark.parametrize("activation", ["relu", "silu"])
+def test_the_held_experts_part_is_the_same_compiled_or_not(activation):
+    """``_held_experts`` and its gradient rule give the same bits run
+    operation by operation and compiled as one program, as a rematerialised
+    block compiles them: what remat moves in a last bit on this CPU
+    (test_lm_selected.py) is the routing's float32 sum back to tokens, not
+    the experts' part."""
+    from metaopt_tpu.models import moe
+
+    act = {"relu": jax.nn.relu, "silu": jax.nn.silu}[activation]
+    x, cot, _, experts, mats, first = _routed_inputs(T_LONG, D, F,
+                                                     "a quarter")
+    held = mats[0].shape[0]
+    local = experts - first
+    plan = moe.routing_plan(jnp.where((local >= 0) & (local < held), local,
+                                      held).astype(jnp.int32), held)
+    bf = jnp.bfloat16
+    rows = moe._dispatch(x.astype(bf), plan)
+    d_out = moe._dispatch(cot.astype(bf), plan)
+    w_gu = jnp.concatenate(mats[:2], axis=2).astype(bf)
+
+    def part(rows, w_gu, w_down, d_out):
+        out, back = jax.vjp(lambda *a: moe._held_experts(
+            *a, plan["items"], plan["filled"], act, "ragged_dot"),
+            rows, w_gu, w_down)
+        return (out,) + back(d_out)
+
+    args = (rows, w_gu, mats[2].astype(bf), d_out)
+    for one, other in zip(part(*args), jax.jit(part)(*args)):
+        assert np.abs(np.asarray(one, np.float32)).max() > 0
+        np.testing.assert_array_equal(np.asarray(one, np.float32),
+                                      np.asarray(other, np.float32))
+
+
+def test_the_experts_passes_touch_the_filled_rows_only(monkeypatch):
+    """The work the gradient of ``dropless_experts`` asks for between
+    dispatch and combine at the 16k cell's t and k (d, f cut) on the
+    megablox route (the backend read as the TPU; traced, nothing run).
+    Outside loops and kernels no gating and no sum of two input gradients
+    has the buffers' t x k rows; a layer is six grouped calls (gate and up
+    as one product: 2 forward, 2 + 2 backward) and the two gating kernels.
+    A weight gradient hands megablox's ``tgmm`` its left operand turned
+    and ``tgmm`` turns it back before its kernel: a pair the compiler
+    cancels (test_attention_tpu_compile.py holds that no copy is made)."""
+    from metaopt_tpu.models import moe
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    t, k, d, f, held = 16384, 8, 256, 128, 16
+    assert moe.grouped_matmul_impl(t * k, d, f) == "megablox"
+    shapes = [jax.ShapeDtypeStruct(s, dt) for s, dt in [
+        ((t, d), jnp.float32), ((t, k), jnp.float32), ((t, k), jnp.int32),
+        ((held, d, f), jnp.float32), ((held, d, f), jnp.float32),
+        ((held, f, d), jnp.float32)]]
+
+    def loss(x, weights, experts, gate, up, down):
+        return jnp.sum(moe.dropless_experts(x, weights, experts, gate, up,
+                                            down, 0, jax.nn.silu)[0])
+
+    jaxpr = jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 3, 4, 5)))(*shapes)
+
+    def outside(jaxpr):
+        """The equations outside every loop and kernel."""
+        for eqn in jaxpr.eqns:
+            yield eqn
+            if eqn.primitive.name in ("while", "scan", "pallas_call"):
+                continue
+            for value in eqn.params.values():
+                for sub in value if isinstance(value, (list, tuple)) \
+                        else [value]:
+                    inner = getattr(sub, "jaxpr", sub)
+                    if hasattr(inner, "eqns"):
+                        yield from outside(inner)
+
+    eqns = list(outside(jaxpr.jaxpr))
+    whole = [(e.primitive.name, v.aval.shape) for e in eqns
+             for v in e.outvars
+             if len(getattr(v.aval, "shape", ())) > 1 and t * k in v.aval.shape]
+    assert [w for w in whole if w[0] in (
+        "mul", "logistic", "max", "select_n", "add_any", "add")] == []
+    # there and back, for each of the two weight gradients
+    assert sorted(w[1] for w in whole if w[0] == "transpose") == sorted(
+        [(d, t * k), (t * k, d), (f, t * k), (t * k, f)])
+    # megablox gives its calls no name: a weight gradient's result has an
+    # axis of experts
+    calls = [e.params["name"] or ("tgmm" if len(e.outvars[0].aval.shape) == 3
+                                  else "gmm")
+             for e in eqns if e.primitive.name == "pallas_call"]
+    assert sorted(calls) == ["expert_gating", "expert_gating_bwd"] \
+        + 4 * ["gmm"] + 2 * ["tgmm"]
+
+
 # -- the description -----------------------------------------------------------
 
 def one_device():
@@ -557,6 +718,10 @@ def test_train_lm_reports_the_routing_s_counts_once(tmp_path):
                         "mask": f"dense: causal, window {WINDOW}"}}
     assert setup["moe"] == {"routed_over": E, "top_k": TOPK, "held": [4, 8],
                             "products": "ragged_dot",
+                            "experts": {
+                                "gate_up": f"one product of {2 * F} columns",
+                                "gating": "chunks", "products": "ragged_dot",
+                                "tiles": None},
                             "buffer_rows": 2 * S * TOPK,
                             "chunk_rows": 2 * S * TOPK}
     # this backend states no limit: the projections are sized and not kept
@@ -594,20 +759,25 @@ def test_the_reader_prints_the_pattern_s_routes_and_counts(capsys):
         "attention_layers": {
             "global-nope": {"route": "pallas", "mask": "structure: causal"}},
         "moe": {"routed_over": 64, "top_k": 6, "held": [0, 16],
-                "products": "ragged_dot", "buffer_rows": 49152,
-                "chunk_rows": 2048}}}
+                "products": "ragged_dot", "experts": {
+                    "gate_up": "one product of 1536 columns",
+                    "gating": "chunks", "products": "ragged_dot",
+                    "tiles": None},
+                "buffer_rows": 49152, "chunk_rows": 2048}}}
     train = {"name": "trial.train", "trial": "T-1", "attrs": {
-        "steps": 2, "moe": {"items": [[30, 10]], "dropped": [0],
+        "steps": 2, "moe": {"items": [[18000, 6000]], "dropped": [0],
                             "chunks": [13]}}}
     trace.print_routes([setup, train])
     assert capsys.readouterr().out.splitlines() == [
         "trial T-1: attention pallas in training (dropout 0.0), pallas in "
         "evaluation",
         "trial T-1: global-nope layers: pallas, mask by structure: causal",
-        "trial T-1: experts 0-15 of 64 held, top 6, products by ragged_dot",
-        "trial T-1: layer 0: 40 items to held experts, fullest 1.50x the "
+        "trial T-1: experts 0-15 of 64 held, top 6, products by ragged_dot, "
+        "gate and up as one product of 1536 columns, gating by chunks",
+        "trial T-1: layer 0: 24000 items to held experts, fullest 1.50x the "
         "mean, 0 dropped",
-        "trial T-1: routing moved 27.1 % of the buffers' rows"]
+        "trial T-1: routing moved 27.1 % of the buffers' rows, the experts' "
+        "passes 24.4 %"]
 
 
 def test_the_example_takes_a_model_description(tmp_path, monkeypatch):
@@ -682,6 +852,21 @@ def test_one_place_decides_the_grouped_product(monkeypatch, backend, rows,
          "moe_num_active_primary_experts": 6, "moe_ffn_hidden_size": 768},
         "reference", tokens=rows // 6)
     assert said["moe"]["products"] == product
+    # the held experts' part says the same answer, the gating that goes
+    # with it and, where the tiles are the program's to choose, the tiles
+    # (rows in 256, k whole, n as wide as the kernel's VMEM count allows)
+    how = said["moe"]["experts"]
+    assert how["products"] == product
+    assert how["gate_up"] == "one product of 1536 columns"
+    if product == "megablox":
+        assert how["gating"] == "pallas"
+        assert how["tiles"] == {
+            "gu": (256, 2560, 768), "out": (256, 768, 2560),
+            "d_h": (256, 2560, 768), "d_rows": (256, 1536, 1280),
+            "d_w_gu": (256, 2560, 512), "d_w_down": (256, 768, 1280),
+            "gating": 512}
+    else:
+        assert (how["gating"], how["tiles"]) == ("chunks", None)
     # the embedding's gradient asks its own one place (ops/embed.py)
     assert said["embed"] == {
         "gradient": "sorted" if backend == "tpu" else "take", "rows": 1000,

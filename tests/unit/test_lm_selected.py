@@ -662,6 +662,21 @@ def test_remat_changes_nothing_to_the_last_bit(with_and_without_remat, what,
         # inside the recomputed block (the update still rounds alike)
         np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-9)
         return
+    if (what, path) == ("gradient", "h0/norm_post/scale"):
+        # a float32 sum over the tokens of what the expert layer and the
+        # router hand the norm. The routing's ``_sum_to_tokens`` (``old +
+        # rows * weight`` in float32) rounds otherwise compiled inside a
+        # block than operation by operation on this CPU: ``_combine``'s
+        # output differs in its last bit in a fifth of its numbers, with
+        # PR 41's tree as with this one, and so does the cotangent that
+        # reaches this norm (21-37 of its 2560 numbers). Whether the sum
+        # over the tokens then rounds alike hangs on the values: it did
+        # for these tokens until PR 43 rounded the experts' ``d_rows`` once
+        # where it was rounded three times (PR 41's tree fails this leaf
+        # with the tokens of key 6 or 7). The held experts' part itself is
+        # the same to the bit compiled or not: test_lm_pattern.py holds it.
+        np.testing.assert_array_max_ulp(got, want, maxulp=1)
+        return
     np.testing.assert_array_equal(got, want)
 
 
