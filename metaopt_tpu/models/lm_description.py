@@ -1,0 +1,468 @@
+"""From a description's published words to a :class:`Pattern`: the one
+module of the zoo in which a family's name appears.
+
+A description is the hyperparameters of models/lm.py::make_lm: the zoo's
+own keys (``d_model``, ``n_layers`` ...) or a published config's
+(``_PUBLISHED``), plus the chip's share of a deployment: ``experts_held`` =
+(first, count) of the routed experts, ``vocab_held`` = (first, count) of the
+vocabulary's rows, ``heads_held`` = (first, count) of
+``num_attention_heads`` (every kind of head is built in that proportion) and,
+for ``phi4flash``, ``layers_held``: the published numbers of the layers this
+chip holds (a pipeline stage's; not the first n). :func:`family_of` says
+whose words a description speaks, and the family's reader (``_FAMILIES``)
+builds each held layer's specs (models/lm_layers.py, models/moe.py)
+directly. What has no layer here is refused by its name.
+:func:`describe_pattern` joins the specs' parts of ``trial.setup``'s
+``attrs``.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any, Dict, Optional, Tuple
+
+from jax.sharding import Mesh
+
+from metaopt_tpu.models.lm_layers import (
+    DifferentialSpec, GatedSpec, GroupedSpec, LatentSpec, LinearSpec,
+    MemoryUnitSpec, StateSpaceSpec)
+from metaopt_tpu.models.moe import RoutedSpec, RoutingRule
+from metaopt_tpu.ops.embed import embed_gradient_route
+
+#: a description's published names beside the zoo's own
+_PUBLISHED = {"hidden_size": "d_model", "num_attention_heads": "n_heads",
+              "num_hidden_layers": "n_layers", "vocab_size": "vocab",
+              # the Qwen3-MoE family's words
+              "num_experts": "moe_num_primary_experts",
+              "num_experts_per_tok": "moe_num_active_primary_experts",
+              "moe_intermediate_size": "moe_ffn_hidden_size",
+              # the Olmo hybrid family's
+              "intermediate_size": "d_ff",
+              # the DeepSeek-V3 family's
+              "n_routed_experts": "moe_num_primary_experts"}
+
+#: a published ``layer_types`` entry -> is the layer linear?
+_LAYER_TYPES = {"linear_attention": True, "full_attention": False}
+
+
+@dataclasses.dataclass(frozen=True)
+class Layer:
+    """One held layer: its published ``number`` (its block is ``h{number}``),
+    its mixer's and its feed-forward's spec, and the names of what it
+    ``reads`` of earlier layers and ``hands_on`` to later ones (of what its
+    mixer offers: a state-space mixer its scan output, ``"memory"``, a
+    differential layer its K and V, ``"kv"``)."""
+
+    number: int
+    mixer: Any
+    ffn: Any
+    reads: Tuple[str, ...] = ()
+    hands_on: Tuple[str, ...] = ()
+
+
+@dataclasses.dataclass(frozen=True)
+class Pattern:
+    """What a description with a layer pattern says: the held ``layers``,
+    the norm (a key of models/lm_layers.py::NORMS) and its ``eps``, the
+    chip's share of the vocabulary (ids are drawn from that slice, and
+    logits and loss are over it) and of ``num_attention_heads`` ((first,
+    count) each; None: all the heads), and whether the head is the
+    embedding's table."""
+
+    layers: Tuple[Layer, ...]
+    norm: str
+    eps: float
+    vocab_held: Tuple[int, int]
+    heads_held: Optional[Tuple[int, int]]
+    tied: bool
+
+    def kind(self, i: int) -> str:
+        return self.layers[i].mixer.kind
+
+    def kinds(self):
+        """The distinct layer kinds, in the pattern's order."""
+        return list(dict.fromkeys(layer.mixer.kind for layer in self.layers))
+
+    def by_kind(self):
+        """[(kind, its layers)], the kinds that attend before the
+        recurrences, each group in the pattern's order: the order in which
+        ``trial.setup`` lists them and the remat rule tries their
+        products."""
+        groups: Dict[str, list] = {}
+        for layer in self.layers:
+            groups.setdefault(layer.mixer.kind, []).append(layer)
+        return sorted(groups.items(),
+                      key=lambda group: not group[1][0].mixer.attends)
+
+    def under_tp(self, tp: int) -> "Pattern":
+        """The pattern as one device of a ``tp`` mesh axis holds it."""
+        return dataclasses.replace(self, layers=tuple(
+            dataclasses.replace(layer, mixer=layer.mixer.under_tp(tp),
+                                ffn=layer.ffn.under_tp(tp))
+            for layer in self.layers))
+
+
+@dataclasses.dataclass(frozen=True)
+class Step:
+    """What ``trial.setup`` describes a pattern for: steps of ``tokens``
+    tokens in rows of ``seq_len`` on attention route ``route`` under
+    ``mesh``, for a description ``d_model`` wide whose dense feed-forward is
+    ``d_ff``."""
+
+    route: str
+    mesh: Any
+    tokens: int
+    seq_len: Optional[int]
+    d_model: int
+    d_ff: int
+
+
+def _own_names(hparams: Dict[str, Any]) -> Dict[str, Any]:
+    h = dict(hparams)
+    for published, own in _PUBLISHED.items():
+        if published in h:
+            h.setdefault(own, h[published])
+    return h
+
+
+def family_of(h: Dict[str, Any]) -> Optional[str]:
+    """The family whose words a description with a layer pattern speaks
+    (a key of ``_FAMILIES``), None for one without a pattern:
+    ``kv_lora_rank`` is the DeepSeek-V3 family's, ``layer_types`` the Olmo
+    hybrid's, ``num_experts`` the Qwen3-MoE family's, the two layouts or
+    ``sa_config`` alone SmallThinker's; ``model_type`` ``phi4flash`` names
+    its family itself."""
+    if h.get("model_type") == "phi4flash":
+        return "phi4flash"
+    for key, family in (("kv_lora_rank", "deepseek_v3"),
+                        ("layer_types", "olmo_hybrid"),
+                        ("num_experts", "qwen3_moe"),
+                        ("sa_config", "layouts"),
+                        ("rope_layout", "layouts"),
+                        ("sliding_window_layout", "layouts")):
+        if key in h:
+            return family
+    return None
+
+
+def pattern_of(h: Dict[str, Any]) -> Optional[Pattern]:
+    """The layer pattern a description names, or None: what its family's
+    reader builds of it."""
+    family = family_of(h)
+    return None if family is None else _FAMILIES[family](h)
+
+
+# -- what several families say in the same words -----------------------------
+
+def _held(h, key: str, whole: int):
+    return tuple(int(v) for v in h.get(key) or (0, whole))
+
+
+def _heads(h):
+    """(the query heads held here, ``share``: a published head count ->
+    the count held in that proportion)."""
+    n_heads = int(h.get("n_heads", 8))
+    held = _held(h, "heads_held", n_heads)[1]
+    return held, lambda heads: int(heads) * held // n_heads
+
+
+def _pattern(h, layers, norm: str, eps_key: str = "rms_norm_eps",
+             eps: float = 1e-6, tied: bool = False) -> Pattern:
+    n_heads = int(h.get("n_heads", 8))
+    return Pattern(
+        layers=tuple(layers), norm=norm, eps=float(h.get(eps_key, eps)),
+        vocab_held=_held(h, "vocab_held", int(h.get("vocab", 1000))),
+        heads_held=_held(h, "heads_held", n_heads) if "heads_held" in h
+        else None, tied=tied)
+
+
+def _theta(h) -> Optional[float]:
+    return (h.get("rope_parameters") or h).get("rope_theta", 10000.0)
+
+
+def _gated(h) -> GatedSpec:
+    return GatedSpec(int(h.get("d_ff", 2048)), str(h.get("hidden_act",
+                                                         "relu")))
+
+
+def _feed_forward(h, router_after_mixer: bool, rule=RoutingRule(),
+                  shared: int = 0):
+    """A routed feed-forward where the description names experts (beside
+    ``shared`` shared ones), else the gated one."""
+    n_experts = int(h.get("moe_num_primary_experts", 0))
+    if not n_experts:
+        return _gated(h)
+    d_ff = int(h.get("moe_ffn_hidden_size", h.get("d_ff", 2048)))
+    return RoutedSpec(
+        n_experts=n_experts,
+        top_k=int(h.get("moe_num_active_primary_experts", 1)), d_ff=d_ff,
+        held=_held(h, "experts_held", n_experts),
+        activation=str(h.get("hidden_act", "relu")),
+        shared_d_ff=shared * d_ff, rule=rule,
+        router_after_mixer=router_after_mixer)
+
+
+def _grouped_layers(h, qk_norm: Optional[str], types=None):
+    """[a :class:`GroupedSpec` a layer], as the two layouts say (read up
+    to ``n_layers``: a cut in depth keeps the leading layers; without them
+    every layer is global and rotary; with ``rope_parameters.rope_theta``
+    null, no positions anywhere), with the ``sa_config``'s selection."""
+    n_layers = int(h.get("n_layers", 6))
+    theta = _theta(h)
+    rotary = list(h.get("rope_layout") or [int(theta is not None)] * n_layers)
+    sliding = list(h.get("sliding_window_layout") or [0] * n_layers)
+    if min(len(rotary), len(sliding), len(types or rotary)) < n_layers:
+        raise ValueError(f"the layouts name {len(rotary)}, {len(sliding)} "
+                         f"and {len(types or rotary)} layers, the model has "
+                         f"{n_layers}")
+    theta = float(10000.0 if theta is None else theta)
+    window = int(h.get("sliding_window_size", 4096))
+    whole = GroupedSpec(**_attention_heads(h), window=None, theta=None,
+                        qk_norm=qk_norm,
+                        selection=_selection(h.get("sa_config")))
+    return [dataclasses.replace(whole, window=window if s else None,
+                                theta=theta if r else None)
+            for s, r in zip(sliding[:n_layers], rotary[:n_layers])]
+
+
+def _attention_heads(h) -> Dict[str, int]:
+    """The query and K/V heads held here and their width."""
+    heads, share = _heads(h)
+    return dict(
+        heads=heads,
+        kv_heads=share(h.get("num_key_value_heads", h.get("n_heads", 8))),
+        head_dim=int(h.get("head_dim") or int(h.get("d_model", 512))
+                     // int(h.get("n_heads", 8))))
+
+
+def _selection(sa: Optional[Dict[str, Any]]):
+    """(index heads, their width, top k) of a published ``sa_config``; its
+    chunk sizes tile the computation and do not change the result."""
+    if not sa:
+        return None
+    if int(sa.get("indexer_num_kv_heads", 1)) != 1:
+        raise ValueError("the indexer has one key head, not "
+                         f"{sa['indexer_num_kv_heads']}")
+    return (int(sa["indexer_num_heads"]), int(sa["indexer_head_dim"]),
+            int(sa["topk"]))
+
+
+# -- a reader a family --------------------------------------------------------
+
+def _layouts(h, qk_norm=None, router_after_mixer=False) -> Pattern:
+    """SmallThinker's: RMS norms before the branches, the router read
+    BEFORE attention, experts or a feed-forward gated by ``hidden_act``
+    (ReLU unless named), an untied head."""
+    ffn = _feed_forward(h, router_after_mixer)
+    return _pattern(h, [Layer(i, mixer, ffn) for i, mixer in
+                        enumerate(_grouped_layers(h, qk_norm))], "rms")
+
+
+def _qwen3_moe(h) -> Pattern:
+    """The Qwen3-MoE family's: SmallThinker's layer with RMS norms of q and
+    k over a head's width and the router read AFTER attention, from the
+    second norm."""
+    return _layouts(h, qk_norm="head", router_after_mixer=True)
+
+
+def _olmo_hybrid(h) -> Pattern:
+    """The Olmo hybrid family's: ``layer_types`` says which layers are
+    linear (the gated delta rule) and which full attention, with RMS norms
+    of q and k over the projected width; x + norm(mixer(x)) then x +
+    norm(ffn(x)); the feed-forward gated by ``hidden_act``."""
+    types = h.get("layer_types")
+    unknown = sorted(set(types or ()) - set(_LAYER_TYPES))
+    if unknown:
+        raise ValueError(f"layer_types names {unknown}; known: "
+                         f"{sorted(_LAYER_TYPES)}")
+    full = _grouped_layers(h, "whole", types)
+    linear = [_LAYER_TYPES[t] for t in (types or ())[:len(full)]] \
+        or [False] * len(full)
+    spec = _linear(h, _heads(h)[1]) if any(linear) else None
+    return _pattern(h, [Layer(i, spec if lin else full[i], _gated(h))
+                        for i, lin in enumerate(linear)],
+                    "rms on the branches")
+
+
+def _linear(h: Dict[str, Any], share) -> LinearSpec:
+    """The linear layers of a description in the Olmo hybrid family's
+    words, ``share`` of each kind of head held."""
+    heads = int(h["linear_num_value_heads"])
+    if int(h.get("linear_num_key_heads", heads)) != heads:
+        raise ValueError("a linear layer has as many key heads as value "
+                         f"heads here, not {h['linear_num_key_heads']} and "
+                         f"{heads}")
+    return LinearSpec(
+        heads=share(heads), of=heads, key_dim=int(h["linear_key_head_dim"]),
+        value_dim=int(h["linear_value_head_dim"]),
+        conv=int(h.get("linear_conv_kernel_dim", 4)),
+        neg_eigval=bool(h.get("linear_allow_neg_eigval", False)))
+
+
+def _deepseek_v3(h) -> Pattern:
+    """The DeepSeek-V3 family's: every layer's attention is latent; the
+    first ``first_k_dense_replace`` layers take a gated feed-forward of
+    ``intermediate_size`` and the others route over ``n_routed_experts`` by
+    the family's rule (:func:`_routing`), the router read after attention,
+    beside ``n_shared_experts`` shared ones."""
+    n_layers = int(h.get("n_layers", 6))
+    dense = min(int(h.get("first_k_dense_replace", 0)), n_layers)
+    mixer = _latent(h)
+    routed = _feed_forward(h, True, _routing(h),
+                           int(h.get("n_shared_experts") or 0))
+    return _pattern(h, [Layer(i, mixer, _gated(h) if i < dense else routed)
+                        for i in range(n_layers)], "rms")
+
+
+def _latent(h: Dict[str, Any]) -> LatentSpec:
+    """The latent attention of a description in the DeepSeek-V3 family's
+    words. What has no layer here is refused by its name: a q rank (and
+    the norm that comes with it), rotary scaling (its ``mscale``)."""
+    for key in ("q_lora_rank", "rope_scaling"):
+        if h.get(key) is not None:
+            raise ValueError(f"{key} {h[key]!r}: a latent layer has none "
+                             "here")
+    theta = _theta(h)
+    return LatentSpec(
+        heads=_heads(h)[0], rank=int(h["kv_lora_rank"]),
+        nope=int(h["qk_nope_head_dim"]), rope=int(h["qk_rope_head_dim"]),
+        v=int(h["v_head_dim"]),
+        adjacent=bool(h.get("rope_interleave", False)),
+        theta=float(10000.0 if theta is None else theta))
+
+
+def _routing(h: Dict[str, Any]) -> RoutingRule:
+    """The routing rule of a description in the DeepSeek-V3 family's words:
+    sigmoid scores and a correction bias (``topk_method`` noaux_tc), in
+    one group. A choice limited to groups of experts, an expert layer
+    every other layer, another scoring or another method have no rule
+    here and are refused by name."""
+    for key in ("n_group", "topk_group", "moe_layer_freq"):
+        if int(h.get(key, 1)) != 1:
+            raise ValueError(f"{key} {h[key]}: the routing knows one group "
+                             "of experts and an expert layer every layer")
+    scoring, method = h.get("scoring_func", "sigmoid"), \
+        h.get("topk_method", "noaux_tc")
+    if scoring != "sigmoid" or method != "noaux_tc":
+        raise ValueError(f"scoring_func {scoring!r} with topk_method "
+                         f"{method!r}: the family's rule here is sigmoid "
+                         "scores under noaux_tc")
+    return RoutingRule("sigmoid", bias=True,
+                       normalised=bool(h.get("norm_topk_prob", False)),
+                       scale=float(h.get("routed_scaling_factor", 1.0)))
+
+
+def _phi4flash(h) -> Pattern:
+    """The SambaY decoder-hybrid-decoder of arXiv:2507.06607, read at the
+    PUBLISHED depth N: the layers held (all, without ``layers_held``), each
+    of the kind :func:`hybrid_kind` gives its published number: Mamba-1
+    state-space layers (sizes by the family's convention where the
+    description is silent: ``mamba_expand`` 2, ``mamba_d_state`` 16,
+    ``mamba_d_conv`` 4, ``mamba_dt_rank`` hidden / 16), gated memory units
+    that gate layer N/2's scan output, differential attention
+    ``sliding_window`` wide, full at N/2 + 1 and, the cross layers, on that
+    layer's K and V; LayerNorms with bias (``layer_norm_eps``) before the
+    branches, the feed-forward gated by ``hidden_act``, no positions
+    anywhere, the head tied to the embedding. A layer that reads what a
+    layer not held would hand on is refused by name."""
+    of = int(h.get("n_layers", 6))
+    numbers = tuple(int(n) for n in h.get("layers_held") or range(of))
+    if list(numbers) != sorted(set(numbers)) or not numbers \
+            or not 0 <= numbers[0] <= numbers[-1] < of:
+        raise ValueError(f"layers_held {list(numbers)}: the published "
+                         f"numbers of layers 0..{of - 1}, ascending")
+    kinds = [hybrid_kind(n, of) for n in numbers]
+    # who hands on what, and the kind that reads it
+    sources = {"memory": (of // 2, "gmu", "the memory"),
+               "kv": (of // 2 + 1, "cross", "K and V")}
+    read = {reader: name for name, (_, reader, _) in sources.items()}
+    for source, reader, what in sources.values():
+        if reader in kinds and source not in numbers:
+            raise ValueError(
+                f"layer {numbers[kinds.index(reader)]} reads {what} of "
+                f"layer {source}, which is not among layers_held "
+                f"{list(numbers)}")
+    d_model = int(h.get("d_model", 512))
+    d_inner = int(h.get("mamba_expand", 2)) * d_model
+    recurrent = {
+        "ssm": StateSpaceSpec(
+            d_inner=d_inner, d_state=int(h.get("mamba_d_state", 16)),
+            d_conv=int(h.get("mamba_d_conv", 4)),
+            dt_rank=int(h.get("mamba_dt_rank") or -(-d_model // 16))),
+        "gmu": MemoryUnitSpec(d_inner)}
+    attention = lambda n, kind: DifferentialSpec(  # noqa: E731
+        **_attention_heads(h),
+        window=int(h.get("sliding_window", 512)) if kind == "window"
+        else None, lambda_init=lambda_init(n), cross=kind == "cross")
+    return _pattern(h, [Layer(
+        n, recurrent.get(kind) or attention(n, kind),
+        _gated(h), reads=(read[kind],) if kind in read else (),
+        hands_on=tuple(name for name, (source, reader, _) in sources.items()
+                       if n == source and reader in kinds))
+        for n, kind in zip(numbers, kinds)],
+        "layer", "layer_norm_eps", 1e-5, tied=True)
+
+
+def hybrid_kind(layer: int, of: int) -> str:
+    """The kind of published layer ``layer`` of a ``phi4flash`` model ``of``
+    layers deep (``mb_per_layer`` 2: every other layer is a state-space or
+    memory layer; the decoders split at ``of`` / 2)."""
+    half = of // 2
+    if layer % 2 == 0:
+        return "ssm" if layer <= half else "gmu"
+    if layer < half:
+        return "window"
+    if layer == half + 1:
+        return "full"
+    if layer >= half + 3:
+        return "cross"
+    raise ValueError(f"layer {layer} of {of} has no kind: the full layer is "
+                     f"{half + 1}, the cross layers start at {half + 3}")
+
+
+def lambda_init(layer: int) -> float:
+    """The differential transformer's 0.8 - 0.6 exp(-0.3 l), at the
+    PUBLISHED layer number."""
+    return 0.8 - 0.6 * math.exp(-0.3 * layer)
+
+
+#: a family's reader: what its layer is, by the family and not by how a
+#: key of its description is spelt
+_FAMILIES = {"layouts": _layouts, "qwen3_moe": _qwen3_moe,
+             "olmo_hybrid": _olmo_hybrid, "deepseek_v3": _deepseek_v3,
+             "phi4flash": _phi4flash}
+
+
+def describe_pattern(hparams: Dict[str, Any], route: str, tokens: int,
+                     seq_len: Optional[int] = None,
+                     mesh: Optional[Mesh] = None) -> Dict[str, Any]:
+    """What ``trial.setup``'s span says of a description with a layer
+    pattern ({} without one), for steps of ``tokens`` tokens in rows of
+    ``seq_len`` on attention route ``route`` under ``mesh``: the route the
+    embedding's gradient takes (ops/embed.embed_gradient_route: ``"sorted"``
+    or ``"take"``), the table's held rows and width, the tokens a step
+    looks up and whether the head reads the same table; under
+    ``attention_layers`` what each kind of layer's mixer spec says of itself
+    (``Pattern.by_kind``'s order), and what each kind of feed-forward's
+    says (the expert layers', under ``moe``)."""
+    h = _own_names(hparams)
+    p = pattern_of(h)
+    if p is None:
+        return {}
+    step = Step(route, mesh, tokens, seq_len, int(h.get("d_model", 512)),
+                int(h.get("d_ff", 2048)))
+    sources = {name: layer.number for layer in p.layers
+               for name in layer.hands_on}
+    out = {"attention_layers": {
+               kind: layers[0].mixer.describe(step, layers, sources)
+               for kind, layers in p.by_kind()},
+           "embed": {"gradient": embed_gradient_route(mesh),
+                     "rows": p.vocab_held[1], "width": step.d_model,
+                     "tokens": tokens, "tied": p.tied}}
+    feeds: Dict[str, list] = {}
+    for layer in p.layers:
+        feeds.setdefault(layer.ffn.kind, []).append(layer)
+    for layers in feeds.values():
+        out.update(layers[0].ffn.describe(step, layers, len(p.layers)))
+    return out

@@ -76,6 +76,7 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from metaopt_tpu.models.lm_layers import GatedFeedForward, GatedSpec
 from metaopt_tpu.parallel.sharding import with_mesh_partitioning
 from metaopt_tpu.utils import trace
 
@@ -721,7 +722,7 @@ class DroplessMoE(nn.Module):
     correction bias of a ``rule`` that has one; ``activation`` is the
     gate's, by the name a published config gives it. ``shared_d_ff`` > 0
     adds the shared experts, one gated feed-forward of that width that
-    every token meets (models/lm.GatedFeedForward under ``moe.shared``):
+    every token meets (models/lm_layers.GatedFeedForward under ``moe.shared``):
     whole on every chip, and on an ``ep`` axis added once, after the sum
     over the axis."""
 
@@ -771,8 +772,6 @@ class DroplessMoE(nn.Module):
                      bias_moved_tokens(logits, experts))
         y = y.reshape(b, s, d)
         if self.shared_d_ff:
-            from metaopt_tpu.models.lm import GatedFeedForward
-
             with trace.scope("moe.shared"):
                 y = y + GatedFeedForward(
                     d, self.shared_d_ff, self.activation,
@@ -782,6 +781,91 @@ class DroplessMoE(nn.Module):
 
 #: a published config's ``hidden_act`` -> the gate's activation
 _ACTIVATIONS = {"relu": nn.relu, "silu": nn.silu}
+
+
+@dataclasses.dataclass(frozen=True)
+class RoutedSpec:
+    """A routed feed-forward as a description has it (the answers a spec
+    gives: models/lm_layers.py): top ``top_k`` of ``n_experts`` gated
+    experts ``d_ff`` wide, ``held`` = (first, count) of them here, chosen
+    and weighed by ``rule``, beside shared experts (one gated feed-forward
+    ``shared_d_ff`` wide, 0: none); the router reads the block's first
+    norm, BEFORE the mixer, or (``router_after_mixer``) the second's."""
+
+    n_experts: int
+    top_k: int
+    d_ff: int
+    held: Tuple[int, int]
+    activation: str
+    shared_d_ff: int
+    rule: RoutingRule
+    router_after_mixer: bool
+
+    kind = "routed"
+
+    def _router(self, read):
+        with trace.scope("moe"), trace.scope("moe.router"):
+            # float32 in earnest: a TPU's default precision would make
+            # this product in bfloat16 passes, and the top-k choice
+            # hangs on the logits' last bits
+            return nn.Dense(
+                self.n_experts, use_bias=False, name="router",
+                precision=jax.lax.Precision.HIGHEST,
+                kernel_init=with_mesh_partitioning(
+                    nn.initializers.lecun_normal(), (None, None)))(read)
+
+    def before_mixer(self, block, n):
+        return None if self.router_after_mixer else self._router(n)
+
+    def feed(self, block, m, logits):
+        if logits is None:
+            logits = self._router(m)
+        # the rule's correction bias: a frozen leaf of the block's own
+        # (models/lm.py::FROZEN)
+        bias = block.param(
+            "choice_bias", with_mesh_partitioning(
+                nn.initializers.zeros, (None,)),
+            (self.n_experts,)) if self.rule.bias else None
+        return DroplessMoE(
+            block.d_model, self.d_ff, self.n_experts, self.top_k, self.held,
+            self.activation, self.rule, self.shared_d_ff,
+            name="experts")(m, logits, bias)
+
+    def counts(self):
+        """What ``DroplessMoE`` sows a step, by ``moe_counts``' keys."""
+        bias = {"bias_moved": ()} if self.rule.bias else {}
+        return {"items": (self.held[1],), "dropped": (), "chunks": (), **bias}
+
+    def products(self, d_model):
+        """The shared experts' three, under the gated feed-forward's names
+        (the experts' own buffers are no candidates)."""
+        return GatedSpec(self.shared_d_ff, self.activation).products(
+            d_model) if self.shared_d_ff else []
+
+    def under_tp(self, tp: int):
+        # the experts split over ``ep``; the shared ones' width is counted
+        # whole, as the rule always has
+        return self
+
+    def describe(self, step, layers, depth: int):
+        """``trial.setup``'s ``attrs["moe"]``: the expert layers' share, the
+        product they take and how the held experts' part runs, the rows of
+        their buffers and of a trip of the routing's loops; where the rule
+        is not the plain one, the rule, the shared experts and the leading
+        dense layers beside the counts."""
+        rows = step.tokens * self.top_k
+        # buffer rows, model width, an expert's width: what the route asks
+        how = describe_experts(rows, step.d_model, self.d_ff)
+        said = {"routed_over": self.n_experts, "top_k": self.top_k,
+                "held": list(self.held), "products": how["products"],
+                "experts": how, "buffer_rows": rows,
+                "chunk_rows": routing_chunk_rows(rows)}
+        if self.rule != RoutingRule():
+            said.update(
+                scoring=self.rule.scoring, bias=self.rule.bias,
+                scale=self.rule.scale, shared_d_ff=self.shared_d_ff,
+                dense_layers=depth - len(layers), d_ff=step.d_ff)
+        return {"moe": said}
 
 
 def _over_ep(mesh, ep: int, x, weights, experts, w, first: int,

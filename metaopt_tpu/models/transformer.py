@@ -118,7 +118,7 @@ def rematerialised(block_cls, keeps=REMAT_KEEPS, **kwargs):
     """``block_cls`` run again in the backward pass, keeping its input and
     the values named ``keeps``: ops/attention.REMAT_KEEPS, the attention
     kernels' ``out`` and ``lse``, which only the kernel could make again;
-    for a pattern decoder what models/lm.py::remat_keeps says (the scan's
+    for a pattern decoder what models/lm_remat.py::remat_keeps says (the scan's
     output and states, a gated feed-forward's products where they fit the
     device). The one rule for every ``remat`` site of the zoo's blocks."""
     return nn.remat(
@@ -491,13 +491,13 @@ def trial_setup(hparams: Dict[str, Any], mesh: Optional[Mesh],
     (``attrs["attention"]``), for the training and the evaluation rate, as
     ops/attention.attention_route names it on this mesh. ``describe``, a
     harness's own, is asked what else the span should say, given the route
-    without dropout and the mesh (lm.py: each kind of layer's route and
+    without dropout and the mesh (lm_description.py: each kind of layer's route and
     mask form, what the expert layers hold and run their products with).
     ``remat_blocks``, the blocks the model runs again in the backward
     pass, puts what each keeps besides its input into
     ``attrs["remat"]``: ``remat_keeps``, the
     names, or a harness's function of the mesh that gives the names and
-    what they were held against (lm.py::remat_on: the bytes of every
+    what they were held against (lm_remat.py::remat_on: the bytes of every
     product a block could keep and the device's room).
     """
     from metaopt_tpu.parallel.mesh import trial_mesh

@@ -52,7 +52,7 @@ tails; block sizes follow the shapes (``_derived_block`` for the kernels,
 exceed what it names (``_block_and_pad``).
 
 ``MHA`` in metaopt_tpu.models.transformer and ``GroupedAttention`` in
-metaopt_tpu.models.lm project, scale, build their mask and call
+metaopt_tpu.models.lm_layers project, scale, build their mask and call
 :func:`attend`, the one door; :func:`attention_route` is the one rule, from
 what the call can see: an ``sp`` mesh axis takes ring attention (or
 Ulysses), a backend other than the TPU the plain reference, a call with

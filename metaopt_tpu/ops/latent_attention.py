@@ -1,6 +1,6 @@
 """A latent layer's hand-over to the causal kernels (attention.CausalMask).
 
-models/lm.LatentAttention makes q, one product that holds every head's
+models/lm_layers.LatentAttention makes q, one product that holds every head's
 ``k_nope`` and v, and ONE rotary key all heads share. The causal kernels
 of ops/attention.py read feature-major ``(B, rows, S)`` operands, so on the
 Pallas route the layer's matmuls leave their products in that layout and
@@ -25,7 +25,7 @@ the kernels read them where they lie:
 
 :func:`hand_over` is the one rule for whether a layer takes this form;
 loaded by ``attention.flash_attention`` when a :class:`attention.LatentKV`
-arrives and by models/lm.py for a model with latent layers.
+arrives and by models/lm_layers.py for a model with latent layers.
 """
 
 from __future__ import annotations

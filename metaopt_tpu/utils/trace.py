@@ -60,22 +60,22 @@ SCOPES = ("embed", "attention", "attention.core", "ffn", "readout_xent",
           # attention over selected keys (ops/sparse_index.py): the
           # indexer's projections and scores, and the exact top-k
           "attention.index", "attention.select",
-          # a latent layer's own (models/lm.LatentAttention): the K/V
+          # a latent layer's own (models/lm_layers.LatentAttention): the K/V
           # down-projection, the latent's norm, the up-projection and the
           # shared key's rotary
           "attention.latent",
-          # a linear-attention mixer (models/lm.LinearAttention): all of it,
-          # and the chunked scan of the gated delta rule
+          # a linear-attention mixer (models/lm_layers.LinearAttention): all
+          # of it, and the chunked scan of the gated delta rule
           # (ops/linear_attention.py), forward and backward
           "linear_attention", "linear_attention.core",
-          # a state-space mixer (models/lm.StateSpaceMixer): all of it, and
-          # the selective scan (ops/selective_scan.py), forward and backward
+          # a state-space mixer (models/lm_layers.StateSpaceMixer): all of it,
+          # and the selective scan (ops/selective_scan.py), both directions
           "ssm", "ssm.core",
-          # a gated memory unit (models/lm.GatedMemoryUnit): an earlier
+          # a gated memory unit (models/lm_layers.GatedMemoryUnit): an earlier
           # layer's scan output gated by this layer's projection
           "gmu",
-          # differential attention's own (models/lm.DifferentialAttention):
-          # lambda, a_1 - lambda a_2, the pair's norm and (1 - lambda_init)
+          # models/lm_layers.DifferentialAttention's own: lambda, a_1 -
+          # lambda a_2, the pair's norm and (1 - lambda_init)
           "attention.diff",
           # the trunk between the layers: the blocks' and the model's norms
           # outside a branch; the residual stream's sums and casts; what a
@@ -450,7 +450,7 @@ def _runs(numbers) -> str:
 
 def _room(remat: dict) -> str:
     """What ``attrs["remat"]`` says of the matrix products a block could
-    keep (models/lm.py::remat_keeps): the bytes kept over all the layers
+    keep (models/lm_remat.py::remat_keeps): the bytes kept over all the layers
     and the room they were held against and, of the names the rule
     declined, the bytes that would have stood with them; "" for a model
     that has no such candidates."""

@@ -157,8 +157,8 @@ IN_PLACE = [(16384, 32), (32768, 32), (1024, 4)]
 @pytest.mark.parametrize("case", IN_PLACE, ids=lambda c: "x".join(map(str, c)))
 def test_a_latent_layer_s_hand_over_compiles_for_a_v5e(one_chip, monkeypatch,
                                                        case):
-    """models/lm.LatentAttention on the Pallas route, its gradient under the
-    block's remat policy: the kernels read q after ``latent_q``'s one pass
+    """models/lm_layers.LatentAttention on the Pallas route, its gradient
+    under the block's remat policy: the kernels read q after ``latent_q``'s one pass
     (forward, again, backward) and K, V and out in place: a 192-row q
     block, 128-row halves of the up-projection's 256-row product by block
     index, the keys' bfloat16 scratch (6 MiB at 16 384) beside the
@@ -166,13 +166,12 @@ def test_a_latent_layer_s_hand_over_compiles_for_a_v5e(one_chip, monkeypatch,
     no float32 array as large as q is left in the compiled program."""
     import re
 
-    from metaopt_tpu.models import lm
+    from metaopt_tpu.models import lm_layers
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     s, heads = case
-    layer = lm.LatentAttention(2048, heads,
-                               lm.LatentSpec(512, 128, 64, 128, True), 1e6,
-                               1e-6)
+    layer = lm_layers.LatentAttention(2048, lm_layers.LatentSpec(
+        heads, 512, 128, 64, 128, True, 1e6), 1e-6)
     on_chip = lambda x: jax.ShapeDtypeStruct(  # noqa: E731
         x.shape, x.dtype, sharding=one_chip)
     x = on_chip(jax.ShapeDtypeStruct((1, s, 2048), jnp.float32))
@@ -180,7 +179,8 @@ def test_a_latent_layer_s_hand_over_compiles_for_a_v5e(one_chip, monkeypatch,
         jax.random.PRNGKey(0), jnp.zeros((1, s, 2048)))["params"]))
     policy = jax.checkpoint_policies.save_only_these_names(
         "attention.out", "attention.lse", *(
-            n for n in lm.LATENT_REMAT_KEEPS if n != "attention.kv_up"))
+            n for n in lm_layers.LatentSpec.KEPT.values()
+            if n != "attention.kv_up"))
 
     def loss(p, x):
         out = jax.checkpoint(lambda p, x: layer.apply({"params": p}, x),

@@ -212,7 +212,7 @@ def test_one_rule_names_the_route(monkeypatch, backend, devices, route):
 
 
 def test_the_kernel_runs_once_in_a_gradient_and_not_in_the_forward():
-    from test_lm_pattern import _equations
+    from lm_pattern_cases import _equations
 
     table, g = _table_and_rows(128)
     ids = _ids("sorted")

@@ -181,7 +181,7 @@ def on_the_kernels():
 @pytest.mark.parametrize("share", list(ROUTINGS))
 def test_on_the_chip_s_route_output_and_gradients_match(on_the_kernels, share,
                                                         activation, part):
-    """bfloat16 products against float32, as test_lm_pattern.py holds the
+    """bfloat16 products against float32, as test_lm_pattern_experts.py holds the
     other route: a fiftieth of the reference's norm, a tenth for the two
     gradients that pass through ReLU's step."""
     mine, ref = on_the_kernels[share, activation]
@@ -194,8 +194,8 @@ def test_on_the_chip_s_route_output_and_gradients_match(on_the_kernels, share,
 
 
 def test_the_models_load_without_the_kernels_file():
-    """models/lm.py imports ``RoutingRule`` from models/moe.py at its top:
-    neither pulls in ops/experts.py (Pallas, megablox) before an expert
+    """models/lm.py imports models/moe.py (through the description's reader)
+    at its top: neither pulls in ops/experts.py (Pallas, megablox) before an expert
     layer on the chip's route asks for it, so no cell's set-up grows."""
     code = ("import sys; import metaopt_tpu.models.lm; "
             "assert 'metaopt_tpu.ops.experts' not in sys.modules; "

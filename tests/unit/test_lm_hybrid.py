@@ -143,7 +143,7 @@ def test_the_models_own_products_stay_near_it(both_sides):
 def with_and_without_remat():
     import optax
 
-    from metaopt_tpu.models import lm
+    from metaopt_tpu.models import lm, lm_layers, lm_remat
 
     tokens = jax.random.randint(jax.random.PRNGKey(5), (2, S + 1), 2, V)
     out = {}
@@ -152,8 +152,10 @@ def with_and_without_remat():
         if remat:  # every name the rule can say: the feed-forward's and
             # both kinds of mixer's projections too
             model = model.clone(keeps=tuple(
-                lm.remat_keeps(model.pattern)["keeps"]) + lm.FFN_REMAT_KEEPS
-                + lm.ATTENTION_REMAT_KEEPS + lm.LINEAR_REMAT_KEEPS)
+                lm_remat.remat_keeps(model.pattern)["keeps"]) + tuple(
+                name for spec in (lm_layers.GatedSpec, lm_layers.GroupedSpec,
+                                  lm_layers.LinearSpec)
+                for name in spec.KEPT.values()))
         params = nn.meta.unbox(model.init(
             jax.random.PRNGKey(0), tokens[:, :-1], train=False)["params"])
         tx = optax.adamw(1e-2)
@@ -198,14 +200,14 @@ def test_a_rematerialised_block_keeps_what_the_scan_made(monkeypatch):
     """On the kernels' route the gradient of a rematerialised period holds
     one ``linear_scan_fwd`` a linear layer and one ``flash_fwd`` for the
     full one; a bare ``nn.remat`` walks each forward a second time."""
-    from test_lm_pattern import _equations
+    from lm_pattern_cases import _equations
 
-    from metaopt_tpu.models import lm
+    from metaopt_tpu.models import lm, lm_remat
     from metaopt_tpu.ops import linear_attention
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert lm.remat_keeps(lm.make_lm(description(4)).pattern)["keeps"][-2:] \
-        == list(linear_attention.REMAT_KEEPS)
+    assert lm_remat.remat_keeps(lm.make_lm(description(4)).pattern)[
+        "keeps"][-2:] == list(linear_attention.REMAT_KEEPS)
     tokens = jax.random.randint(jax.random.PRNGKey(5), (1, S + 1), 2, V)
     counted = {}
     for how in ("kept", "bare"):
@@ -231,7 +233,7 @@ def test_a_rematerialised_block_keeps_what_the_scan_made(monkeypatch):
 def _ffn_products(jaxpr):
     """The ``dot_general``s of a jaxpr one of whose sides is ``F`` wide:
     the feed-forward's (no other width of these sizes is 96)."""
-    from test_lm_pattern import _equations
+    from lm_pattern_cases import _equations
 
     return sum(
         e.primitive.name == "dot_general" and any(
@@ -247,17 +249,17 @@ def test_a_rematerialised_block_keeps_what_its_feed_forward_made(
     feed-forward's shapes a layer with the three names kept (three forward,
     six backward) and twelve under a bare ``nn.remat`` or today's list; with
     the down product's name alone the gate and up products run again."""
-    from metaopt_tpu.models import lm
+    from metaopt_tpu.models import lm, lm_layers, lm_remat
 
     model = lm.make_lm(description(4, remat=True))
-    today = tuple(lm.remat_keeps(model.pattern)["keeps"])
+    today = tuple(lm_remat.remat_keeps(model.pattern)["keeps"])
     if how == "bare":
         monkeypatch.setattr(lm, "rematerialised",
                             lambda cls, keeps: nn.remat(cls))
     else:
         model = model.clone(keeps=today + {
-            "kept": lm.FFN_REMAT_KEEPS, "down": lm.FFN_REMAT_KEEPS[:1],
-            "today": ()}[how])
+            "kept": tuple(lm_layers.GatedSpec.KEPT.values()),
+            "down": (lm_layers.GatedSpec.KEPT["down"],), "today": ()}[how])
     tokens = jax.random.randint(jax.random.PRNGKey(5), (1, S + 1), 2, V)
     params = jax.eval_shape(lambda: nn.meta.unbox(model.init(
         jax.random.PRNGKey(0), tokens[:, :-1], train=False)["params"]))
@@ -269,15 +271,16 @@ def test_a_rematerialised_block_keeps_what_its_feed_forward_made(
 def test_without_remat_the_feed_forward_s_names_are_identities(monkeypatch):
     """Not rematerialised, the gradient is the one without the names but
     for three ``name`` equations a layer and one a mixer's projection."""
-    from test_lm_pattern import _equations
+    from lm_pattern_cases import _equations
 
-    from metaopt_tpu.models import lm
+    from metaopt_tpu.models import lm, lm_layers
 
     tokens = jax.random.randint(jax.random.PRNGKey(5), (1, S + 1), 2, V)
     primitives = {}
     for how in ("named", "unnamed"):
         if how == "unnamed":
-            monkeypatch.setattr(lm, "checkpoint_name", lambda x, name: x)
+            monkeypatch.setattr(lm_layers, "checkpoint_name",
+                                lambda x, name: x)
         model = lm.make_lm(description(**PAIR))
         params = jax.eval_shape(lambda: nn.meta.unbox(model.init(
             jax.random.PRNGKey(0), tokens[:, :-1], train=False)["params"]))
@@ -303,8 +306,8 @@ def test_without_remat_the_feed_forward_s_names_are_identities(monkeypatch):
 #: one row of 8192 tokens, 3840 x 11 008, 15 heads of each kind, 766.2 M
 #: parameters on a device that states 15.75 GiB
 LIMIT = int(15.75 * 2 ** 30)
-CELL = dict(tokens=8192, d_model=3840, d_ff=11008, n_heads=15,
-            parameters=766_200_000, bytes_limit=LIMIT)
+CELL = dict(tokens=8192, d_model=3840, parameters=766_200_000,
+            bytes_limit=LIMIT)
 #: family -> (its cell's configuration, the chipbench module that turns it
 #: into the program's description)
 CELLS = {"smallthinker": ("smallthinker-21b-a3b-ep4", "lm_config"),
@@ -327,7 +330,7 @@ def cell_model(family, rehearsal=False, **over):
     import os
 
     from chipbench.run import rehearsal_sizes
-    from metaopt_tpu.models import lm
+    from metaopt_tpu.models import lm, lm_remat
 
     name, module = CELLS[family]
     root = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -338,22 +341,24 @@ def cell_model(family, rehearsal=False, **over):
         rehearsal_sizes(config)
     model = lm.make_lm({**importlib.import_module(
         "chipbench." + module).description(config), **over})
-    a, p = config["script_args"], model.pattern
+    a = config["script_args"]
     return model, dict(
         tokens=a["batch_size"] * a["seq_len"], d_model=model.d_model,
-        d_ff=model.d_ff,
-        n_heads=p.heads_held[1] if p.heads_held else model.n_heads,
         parameters=sum(x.size for x in jax.tree.leaves(nn.meta.unbox(
-            jax.eval_shape(lm.param_init(model, (1, 8)),
+            jax.eval_shape(lm_remat.param_init(model, (1, 8)),
                            jax.random.PRNGKey(0))))), bytes_limit=LIMIT)
 
 
 def contracting_width(name, p, sizes):
     """FLOPs of a kept product over the bytes of its output."""
-    linear = p.linear.heads * p.linear.value_dim if p.linear else None
-    return {"ffn.down": sizes["d_ff"],
-            "attention.out_proj": sizes["n_heads"] * p.head_dim,
-            "linear_attention.out_proj": linear}.get(name, sizes["d_model"])
+    mixers = {layer.mixer.kind.split("-")[0]: layer.mixer
+              for layer in p.layers}
+    full, linear = mixers.get("global", mixers.get("selected")), \
+        mixers.get("linear")
+    return {"ffn.down": getattr(p.layers[0].ffn, "d_ff", None),
+            "attention.out_proj": full.heads * full.head_dim,
+            "linear_attention.out_proj": linear and linear.heads
+            * linear.value_dim}.get(name, sizes["d_model"])
 
 
 @pytest.mark.parametrize("family, layers, over, kept, room", [
@@ -383,7 +388,7 @@ def test_the_rule_keeps_the_feed_forward_s_products_where_they_fit(
         family, layers, over, kept, room):
     """At the cells' sizes, candidates in order of contracting width, each
     held against what the ones before it left of the room."""
-    from metaopt_tpu.models import lm
+    from metaopt_tpu.models import lm_remat
     from metaopt_tpu.ops import linear_attention
     from metaopt_tpu.ops.attention import REMAT_KEEPS
 
@@ -394,7 +399,7 @@ def test_the_rule_keeps_the_feed_forward_s_products_where_they_fit(
         sizes = {**CELL, **over}
     else:
         sizes = {**sizes, **(over or {})}
-    said = lm.remat_keeps(p, **sizes)
+    said = lm_remat.remat_keeps(p, **sizes)
     today = list(REMAT_KEEPS + (linear_attention.REMAT_KEEPS
                                 if family == "hybrid" else ()))
     assert said["keeps"] == today + kept
@@ -442,10 +447,11 @@ def test_the_rule_keeps_the_feed_forward_s_products_where_they_fit(
 @pytest.mark.parametrize("family", list(CELLS))
 def test_at_a_rehearsal_s_sizes_every_name_fits_or_none_is_kept(family,
                                                                 limit):
-    from metaopt_tpu.models import lm
+    from metaopt_tpu.models import lm_remat
 
     model, sizes = cell_model(family, rehearsal=True)
-    said = lm.remat_keeps(model.pattern, **{**sizes, "bytes_limit": limit})
+    said = lm_remat.remat_keeps(model.pattern,
+                                **{**sizes, "bytes_limit": limit})
     new = [n for n in said["keeps"] if n in said["bytes"]]
     assert sorted(new) == (sorted(said["bytes"]) if limit else [])
     assert len(said["bytes"]) == (14 if family == "hybrid" else 4)
@@ -467,30 +473,30 @@ def test_the_model_and_the_span_are_told_the_same_once(monkeypatch, family,
     the span; a device's limit is pinned in ``device_bytes_limit``'s
     place. The model is traced once: the rule's count and the init share
     the trace of one ``param_init``."""
-    import test_lm_pattern
-    import test_lm_selected
+    import lm_pattern_cases
+    import lm_selected_cases
 
-    from metaopt_tpu.models import lm
+    from metaopt_tpu.models import lm, lm_remat
     from metaopt_tpu.utils import trace
 
     asked, traced = [], []
-    real, real_init = lm.remat_keeps, lm.DecoderOnlyLM.init
+    real, real_init = lm_remat.remat_keeps, lm.DecoderOnlyLM.init
 
     def counting(p, **sizes):
         if sizes:  # not the bare model's own say of its pattern alone
             asked.append(sizes)
         return real(p, **sizes)
 
-    monkeypatch.setattr(lm, "remat_keeps", counting)
+    monkeypatch.setattr(lm_remat, "remat_keeps", counting)
     monkeypatch.setattr(lm.DecoderOnlyLM, "init", lambda *a, **kw: (
         traced.append(a) or real_init(*a, **kw)))
-    monkeypatch.setattr(lm, "device_bytes_limit", lambda mesh: limit)
+    monkeypatch.setattr(lm_remat, "device_bytes_limit", lambda mesh: limit)
     described = {"hybrid": lambda: description(**PAIR),
-                 "smallthinker": lambda: test_lm_pattern.description(
+                 "smallthinker": lambda: lm_pattern_cases.description(
                      [(0, 0), (1, 1)]),
-                 "keye": lambda: test_lm_selected.description(2)}[family]()
+                 "keye": lambda: lm_selected_cases.description(2)}[family]()
     trial = lm.LMTrial({**described, "remat": True},
-                       mesh=test_lm_pattern.one_device(), n_train=4,
+                       mesh=lm_pattern_cases.one_device(), n_train=4,
                        batch_size=2, seq_len=S)
     said = trace.spans("trial.setup")[-1]["attrs"]["remat"]
     assert tuple(said["keeps"]) == trial.model.keeps
@@ -503,8 +509,7 @@ def test_the_model_and_the_span_are_told_the_same_once(monkeypatch, family,
     params = jax.tree.leaves(nn.meta.unbox(trial.params))
     model = trial.model
     assert asked == [dict(
-        tokens=2 * S, d_model=model.d_model, d_ff=model.d_ff,
-        n_heads=HELD if family == "hybrid" else model.n_heads,
+        tokens=2 * S, d_model=model.d_model,
         parameters=sum(x.size for x in params), bytes_limit=limit)]
     assert len(traced) == 1
 
@@ -513,16 +518,16 @@ def test_the_init_given_the_rule_s_function_places_the_same_trees():
     """``init_sharded_lm`` given the ``param_init`` whose shapes the rule
     counted places the same trees as one that makes its own."""
     import optax
-    from test_lm_pattern import one_device
+    from lm_pattern_cases import one_device
 
-    from metaopt_tpu.models import lm
+    from metaopt_tpu.models import lm, lm_remat
     from metaopt_tpu.parallel.mesh import use_mesh
 
     model, tx, mesh = lm.make_lm(description(**PAIR)), optax.adamw(1e-3), \
         one_device()
-    init_params = lm.param_init(model, (2, S))
-    assert lm.remat_on(model, mesh, (2, S), init_params) \
-        == lm.remat_on(model, mesh, (2, S))
+    init_params = lm_remat.param_init(model, (2, S))
+    assert lm_remat.remat_on(model, mesh, (2, S), init_params) \
+        == lm_remat.remat_on(model, mesh, (2, S))
     with use_mesh(mesh):
         own = lm.init_sharded_lm(model, mesh, tx, (2, S), 3)
         given = lm.init_sharded_lm(model, mesh, tx, (2, S), 3, init_params)
@@ -540,14 +545,14 @@ def test_a_mesh_s_axes_divide_what_a_device_holds():
     of the step's tokens."""
     from jax.sharding import Mesh
 
-    from metaopt_tpu.models import lm
+    from metaopt_tpu.models import lm, lm_remat
 
     model = lm.make_lm(description(**PAIR, remat=True))
     said = {}
     for shape in ((1, 1), (2, 1), (1, 2)):
         mesh = Mesh(np.array(jax.devices()[:shape[0] * shape[1]]).reshape(
             shape), ("dp", "tp"))
-        said[shape] = lm.remat_on(model, mesh, (2, S))["bytes"]
+        said[shape] = lm_remat.remat_on(model, mesh, (2, S))["bytes"]
     t = 2 * S
     assert said[1, 1] == {                      # a linear and a full layer
         "ffn.down": 2 * 2 * t * D, "ffn.gate": 2 * 2 * t * F,
@@ -565,20 +570,25 @@ def test_a_mesh_s_axes_divide_what_a_device_holds():
 # -- the description -------------------------------------------------------------
 
 def test_the_family_s_words_make_the_pattern():
-    from metaopt_tpu.models import lm
+    from metaopt_tpu.models import lm, lm_layers
 
     model = lm.make_lm(description(8))
     p = model.pattern
-    assert p.layers == ((False, False),) * 8
-    assert p.linear_layers == (True, True, True, False) * 2
+    assert [layer.number for layer in p.layers] == list(range(8))
+    assert [p.kind(i) for i in range(8)] == [
+        "linear", "linear", "linear", "global-nope"] * 2
     assert p.kinds() == ["linear", "global-nope"]
-    assert [p.kind(i) for i in (2, 3)] == ["linear", "global-nope"]
-    assert p.linear == lm.LinearSpec(heads=HELD, of=HEADS, key_dim=KD,
-                                     value_dim=VD, conv=4, neg_eigval=True)
-    assert (model.n_heads, p.heads_held, p.n_kv_heads, p.head_dim) == (
-        HEADS, (0, HELD), HELD, HD)
-    assert p.norm_after and p.qk_norm and p.qk_norm_whole
-    assert p.activation == "silu" and p.n_experts == 0 and model.d_ff == F
+    assert p.layers[2].mixer == lm_layers.LinearSpec(
+        heads=HELD, of=HEADS, key_dim=KD, value_dim=VD, conv=4,
+        neg_eigval=True)
+    # no window, no positions, q/k norms over the projected width
+    assert p.layers[3].mixer == lm_layers.GroupedSpec(
+        heads=HELD, kv_heads=HELD, head_dim=HD, window=None, theta=None,
+        qk_norm="whole", selection=None)
+    assert (model.n_heads, p.heads_held) == (HEADS, (0, HELD))
+    assert p.norm == "rms on the branches" and not p.tied
+    assert {layer.ffn for layer in p.layers} == {
+        lm_layers.GatedSpec(F, "silu")} and model.d_ff == F
     params = jax.eval_shape(lambda: nn.meta.unbox(model.init(
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32),
         train=False)["params"]))
@@ -599,8 +609,9 @@ def test_whole_heads_are_the_default():
     h = description(4)
     del h["heads_held"]
     p = lm.make_lm(h).pattern
-    assert p.heads_held is None and p.n_kv_heads == HEADS
-    assert p.linear.heads == p.linear.of == HEADS
+    linear, full = p.layers[0].mixer, p.layers[3].mixer
+    assert p.heads_held is None and full.heads == full.kv_heads == HEADS
+    assert linear.heads == linear.of == HEADS
 
 
 @pytest.mark.parametrize("sliding, rotary, selected, linear, name", [
@@ -610,10 +621,15 @@ def test_whole_heads_are_the_default():
     (False, False, False, False, "global-nope")])
 def test_a_layer_s_kind_comes_from_one_list(sliding, rotary, selected,
                                             linear, name):
-    from metaopt_tpu.models import lm
+    from metaopt_tpu.models import lm_layers
 
-    assert lm.layer_kind(sliding, rotary, selected, linear) == name
-    assert name.split("-")[0] in [k for k, _ in lm.KINDS]
+    spec = lm_layers.LinearSpec(2, 2, 8, 8, 4, True) if linear \
+        else lm_layers.GroupedSpec(
+            4, 2, 8, 16 if sliding else None, 1e4 if rotary else None, None,
+            (2, 4, 8) if selected else None)
+    assert spec.kind == name
+    # the kinds that say a route and a mask say their positions too
+    assert spec.attends == ("-" in name)
 
 
 def test_a_layer_type_it_does_not_know_is_refused_by_name():
@@ -640,9 +656,9 @@ def test_unequal_key_and_value_heads_are_refused():
 
 @pytest.mark.parametrize("activation", ["relu", "silu"])
 def test_the_feed_forward_s_activation_is_the_named_one(activation):
-    from metaopt_tpu.models import lm
+    from metaopt_tpu.models import lm_layers
 
-    ffn = lm.GatedFeedForward(8, 16, activation)
+    ffn = lm_layers.GatedFeedForward(8, 16, activation)
     x = jax.random.normal(jax.random.PRNGKey(0), (1, 5, 8))
     params = nn.meta.unbox(ffn.init(jax.random.PRNGKey(1), x))
     k = {n: params["params"][n]["kernel"].astype(jnp.bfloat16)
@@ -653,26 +669,28 @@ def test_the_feed_forward_s_activation_is_the_named_one(activation):
     np.testing.assert_allclose(np.asarray(ffn.apply(params, x), np.float32),
                                np.asarray(want, np.float32), rtol=2e-2,
                                atol=1e-3)
-    assert lm.GatedFeedForward(8, 16).activation == "relu"
+    assert lm_layers.GatedFeedForward(8, 16).activation == "relu"
 
 
 @pytest.mark.parametrize("family", ["smallthinker", "keye"])
 def test_a_description_that_stood_builds_the_pattern_it_built(family):
     """None of this family's fields is set by another family's words."""
-    import test_lm_pattern
+    import lm_pattern_cases
+    import lm_selected_cases
     import test_lm_selected
 
-    from metaopt_tpu.models import lm
+    from metaopt_tpu.models import lm, lm_layers, lm_remat
 
-    h = test_lm_pattern.description([(0, 0), (1, 1)]) \
-        if family == "smallthinker" else test_lm_selected.description(2)
+    h = lm_pattern_cases.description([(0, 0), (1, 1)]) \
+        if family == "smallthinker" else lm_selected_cases.description(2)
     p = lm.make_lm(h).pattern
-    assert p.linear is None and p.linear_layers == ()
-    assert not p.norm_after and not p.qk_norm_whole and p.heads_held is None
+    assert all(isinstance(layer.mixer, lm_layers.GroupedSpec)
+               and layer.mixer.qk_norm != "whole" for layer in p.layers)
+    assert p.norm == "rms" and p.heads_held is None
     assert p.kinds() == (["global-nope", "window-rope"]
                          if family == "smallthinker" else ["selected-rope"])
-    said = lm.remat_keeps(p, tokens=8192, d_model=64, d_ff=96, n_heads=4,
-                          parameters=10 ** 6, bytes_limit=2 ** 34)
+    said = lm_remat.remat_keeps(p, tokens=8192, d_model=64,
+                                parameters=10 ** 6, bytes_limit=2 ** 34)
     assert said["keeps"] == ["attention.out", "attention.lse",
                              "attention.selected"] + ATT_IN + ATT_OUT
     assert list(said["bytes"]) == ATT_IN + ATT_OUT
@@ -692,7 +710,7 @@ def test_a_description_that_stood_builds_the_pattern_it_built(family):
 
 
 def test_a_checkpoint_restores(tmp_path):
-    from test_lm_pattern import one_device
+    from lm_pattern_cases import one_device
 
     from metaopt_tpu.models.lm import LMTrial, train_lm
 
@@ -741,7 +759,7 @@ def test_two_head_shares_of_a_linear_layer_add_up_to_the_uncut_layer(uncut):
     heads alone) sum to the uncut reference's, and with the block's norm
     and the feed-forward counted ONCE give the uncut layer."""
     from chipbench.reference.lm import _rms
-    from metaopt_tpu.models import lm
+    from metaopt_tpu.models import lm_layers
 
     cfg, weights, x, reference = uncut
     p = weights["h0"]
@@ -749,8 +767,8 @@ def test_two_head_shares_of_a_linear_layer_add_up_to_the_uncut_layer(uncut):
             pytest.MonkeyPatch.context() as patch:
         patch.setattr(jnp, "bfloat16", jnp.float32)
         whole = reference._linear_mixer("float32", p["linear"], x, cfg)
-        spec = lm.LinearSpec(HELD, HEADS, KD, VD, 4, True)
-        shares = [lm.LinearAttention(D, spec, 1e-6).apply(
+        spec = lm_layers.LinearSpec(HELD, HEADS, KD, VD, 4, True)
+        shares = [lm_layers.LinearAttention(D, spec, 1e-6).apply(
             {"params": _heads(p["linear"], first, HELD, LINEAR_HEAD_AXIS)},
             x[None])[0] for first in (0, HELD)]
         np.testing.assert_allclose(np.asarray(shares[0] + shares[1]),
@@ -758,7 +776,7 @@ def test_two_head_shares_of_a_linear_layer_add_up_to_the_uncut_layer(uncut):
         assert float(jnp.abs(shares[0]).max()) > 1e-2 \
             and float(jnp.abs(shares[0] - shares[1]).max()) > 1e-2
         x1 = x + _rms(shares[0] + shares[1], p["norm_mixer"]["scale"], 1e-6)
-        once = lm.GatedFeedForward(D, F, "silu").apply(
+        once = lm_layers.GatedFeedForward(D, F, "silu").apply(
             {"params": p["mlp"]}, x1[None])[0]
         layer = x1 + _rms(once, p["norm_ffn"]["scale"], 1e-6)
         want = x + _rms(whole, p["norm_mixer"]["scale"], 1e-6)
@@ -800,7 +818,7 @@ def test_two_head_shares_of_the_full_layer_add_up_after_the_norms(uncut):
 # -- what the trace says ----------------------------------------------------------
 
 def test_train_lm_says_which_layers_are_linear_and_what_a_block_keeps():
-    from test_lm_pattern import one_device
+    from lm_pattern_cases import one_device
 
     from metaopt_tpu.models.lm import train_lm
     from metaopt_tpu.ops.linear_attention import CHUNK
@@ -885,7 +903,7 @@ def test_the_benchmark_prints_this_family_s_description_too():
     import subprocess
     import sys
 
-    from metaopt_tpu.models import lm
+    from metaopt_tpu.models import lm, lm_layers
 
     root = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
@@ -896,15 +914,18 @@ def test_the_benchmark_prints_this_family_s_description_too():
     model = lm.make_lm(json.loads(out))
     p = model.pattern
     assert model.n_layers == 4 and model.remat is True
-    assert (model.d_model, model.d_ff, model.n_heads, p.head_dim) == (
+    full = p.layers[3].mixer
+    assert (model.d_model, model.d_ff, model.n_heads, full.head_dim) == (
         3840, 11008, 30, 128)
-    assert p.heads_held == (0, 15) and p.n_kv_heads == 15
-    assert p.vocab_held == (0, 12544) and p.n_experts == 0
-    assert p.linear == lm.LinearSpec(heads=15, of=30, key_dim=96,
-                                     value_dim=192, conv=4, neg_eigval=True)
+    assert p.heads_held == (0, 15) and full.kv_heads == 15
+    assert p.vocab_held == (0, 12544)
+    assert {layer.ffn for layer in p.layers} == {
+        lm_layers.GatedSpec(11008, "silu")}
+    assert p.layers[0].mixer == lm_layers.LinearSpec(
+        heads=15, of=30, key_dim=96, value_dim=192, conv=4, neg_eigval=True)
     assert p.kinds() == ["linear", "global-nope"]
-    assert p.linear_layers == (True, True, True, False)
-    assert all(not rotary for _, rotary in p.layers)
+    assert [p.kind(i) for i in range(4)] == ["linear"] * 3 + ["global-nope"]
+    assert full.theta is None
     params = jax.eval_shape(lambda: model.init(
         jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32),
         train=False)["params"])

@@ -15,7 +15,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from metaopt_tpu.models import lm, moe
+from metaopt_tpu.models import (lm, lm_description, lm_layers, lm_remat,
+                                moe)
 from metaopt_tpu.utils import trace
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -198,7 +199,7 @@ def test_the_gradient_tree_names_no_bias_and_the_dense_layer_no_router(
 
 def test_rotary_on_adjacent_pairs_is_a_complex_rotation():
     x = jax.random.normal(jax.random.PRNGKey(1), (2, 9, 3, ROPE))
-    got = np.asarray(lm.rope(x, 1e6, adjacent=True))
+    got = np.asarray(lm_layers.rope(x, 1e6, adjacent=True))
     z = np.asarray(x, np.float64)
     z = z[..., 0::2] + 1j * z[..., 1::2]
     angle = np.arange(9)[:, None] * 1e6 ** (-np.arange(0, ROPE, 2) / ROPE)
@@ -209,13 +210,14 @@ def test_rotary_on_adjacent_pairs_is_a_complex_rotation():
 
 def test_the_halves_form_is_another_function():
     x = jax.random.normal(jax.random.PRNGKey(1), (1, 9, 1, ROPE))
-    halves, pairs = lm.rope(x, 1e6), lm.rope(x, 1e6, adjacent=True)
+    halves = lm_layers.rope(x, 1e6)
+    pairs = lm_layers.rope(x, 1e6, adjacent=True)
     np.testing.assert_allclose(halves[:, 0], pairs[:, 0], atol=1e-6)  # pos 0
     assert float(jnp.max(jnp.abs(halves[:, 1:] - pairs[:, 1:]))) > 0.1
     # the same rotation on a permuted head: halves(x[perm]) == pairs(x)[perm]
     perm = np.concatenate([np.arange(0, ROPE, 2), np.arange(1, ROPE, 2)])
-    np.testing.assert_allclose(lm.rope(x[..., perm], 1e6), pairs[..., perm],
-                               atol=2e-6)
+    np.testing.assert_allclose(lm_layers.rope(x[..., perm], 1e6),
+                               pairs[..., perm], atol=2e-6)
 
 
 # -- the routing rule -----------------------------------------------------------
@@ -341,7 +343,7 @@ def test_eight_shares_add_up_to_the_uncut_layer(expert_layer_operands):
     cfg, whole, params, x, w, logits = expert_layer_operands
     bias = whole["choice_bias"]
     uncut, uncut_dx = _run((0, E), params, x, w, logits, bias)
-    shared = lm.GatedFeedForward(D, SHARED * F, "silu")
+    shared = lm_layers.GatedFeedForward(D, SHARED * F, "silu")
     alike = lambda x: shared.apply(  # noqa: E731
         {"params": params["shared"]}, x).astype(jnp.float32)
     alike_dx = jax.grad(lambda x: jnp.sum(alike(x) * w))(x)
@@ -401,10 +403,8 @@ def _cell(name, module):
 
 def _asked(desc, tokens, parameters, bytes_limit):
     model = lm.make_lm(desc)
-    p = model.pattern
-    return lm.remat_keeps(
-        p, tokens=tokens, d_model=model.d_model, d_ff=model.d_ff,
-        n_heads=p.heads_held[1] if p.heads_held else model.n_heads,
+    return lm_remat.remat_keeps(
+        model.pattern, tokens=tokens, d_model=model.d_model,
         parameters=parameters, bytes_limit=bytes_limit)
 
 
@@ -463,19 +463,58 @@ def test_an_accepted_description_gets_the_answer_it_got(name, module):
     assert json.loads(json.dumps(ans)) == GOLDEN[name]["remat"]
 
 
+def _in_the_words_that_stood(p):
+    """A pattern's specs in the words of the flat ``Pattern`` that the
+    goldens were written from (PR 35's): what the specs still say of them
+    (a window no layer has, a rotary base no layer turns by, are not
+    said)."""
+    grouped = [layer.mixer for layer in p.layers
+               if isinstance(layer.mixer, lm_layers.GroupedSpec)]
+    linear = [layer.mixer for layer in p.layers
+              if isinstance(layer.mixer, lm_layers.LinearSpec)]
+    ffn, attention = p.layers[-1].ffn, grouped[0]
+    routed = isinstance(ffn, moe.RoutedSpec)
+    said = {
+        "activation": ffn.activation, "expert_d_ff": ffn.d_ff,
+        "experts_held": list(ffn.held) if routed else [0, 0],
+        "n_experts": ffn.n_experts if routed else 0,
+        "top_k": ffn.top_k if routed else 1,
+        "router_after_attention": routed and ffn.router_after_mixer,
+        "head_dim": attention.head_dim, "n_kv_heads": attention.kv_heads,
+        "qk_norm": attention.qk_norm is not None,
+        "qk_norm_whole": attention.qk_norm == "whole",
+        "selection": attention.selection and list(attention.selection),
+        "layers": [[bool(getattr(layer.mixer, "window", None)),
+                    getattr(layer.mixer, "theta", None) is not None]
+                   for layer in p.layers],
+        "linear": dataclasses.asdict(linear[0]) if linear else None,
+        "linear_layers": [layer.mixer.kind == "linear" for layer in p.layers]
+        if linear else [],
+        "norm_after": p.norm == "rms on the branches", "rms_eps": p.eps,
+        "heads_held": p.heads_held and list(p.heads_held),
+        "vocab_held": list(p.vocab_held)}
+    said.update({"window": g.window for g in grouped if g.window})
+    said.update({"rope_theta": g.theta for g in grouped if g.theta})
+    return said, (ffn.rule, ffn.shared_d_ff) if routed else None
+
+
 @pytest.mark.parametrize("name, module", ACCEPTED)
 def test_an_accepted_description_builds_the_pattern_it_built(name, module):
     """q/k norms and the router's place are facts of a family, not of a
-    key's spelling: the three accepted descriptions' ``Pattern`` is, field
-    by field, what ``pattern_of`` built before a third family's words."""
+    key's spelling: the three accepted descriptions' ``Pattern`` says,
+    field by field, what ``pattern_of`` built before a third family's
+    words."""
     desc, _ = _cell(name, module)
-    got = json.loads(json.dumps(dataclasses.asdict(
-        lm.make_lm(desc).pattern)))
-    for field, value in GOLDEN[name]["pattern"].items():
-        assert got[field] == value, field
-    assert got["latent"] is None and got["dense_layers"] == 0 \
-        and got["shared_d_ff"] == 0
-    assert got["routing"] == dataclasses.asdict(moe.RoutingRule())
+    p = lm.make_lm(desc).pattern
+    got, routing = _in_the_words_that_stood(p)
+    want = GOLDEN[name]["pattern"]
+    assert set(want) - set(got) <= {"window", "rope_theta"}
+    for field, value in got.items():
+        assert value == want[field], field
+    assert not any(isinstance(layer.mixer, lm_layers.LatentSpec)
+                   for layer in p.layers)
+    assert len({layer.ffn for layer in p.layers}) == 1  # no dense layers
+    assert routing in (None, (moe.RoutingRule(), 0))
 
 
 def _equations(jaxpr):
@@ -503,7 +542,8 @@ def test_a_grouped_layer_traces_to_the_program_it_traced_to(monkeypatch, kind,
     import hashlib
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    layer = lm.GroupedAttention(64, 4, 2, 32, window, theta)
+    layer = lm_layers.GroupedAttention(64, lm_layers.GroupedSpec(
+        4, 2, 32, window, theta, None, None), 1e-6)
     x = jnp.zeros((2, 256, 64))
     params = jax.eval_shape(lambda: layer.init(jax.random.PRNGKey(0),
                                                x)["params"])
@@ -528,7 +568,7 @@ def test_a_grouped_layer_traces_to_the_program_it_traced_to(monkeypatch, kind,
     ({"rope_layout": [1]}, "layouts"), ({"sa_config": {}}, "layouts"),
     ({"hidden_size": 8}, None)])
 def test_a_description_s_family(keys, family):
-    assert lm.family_of(keys) == family
+    assert lm_description.family_of(keys) == family
 
 
 # -- the trial, its counts and its spans -------------------------------------------
@@ -577,7 +617,8 @@ def test_the_setup_span_says_the_kind_and_the_routing(trial, capsys):
             routing["held"]) == ("sigmoid", True, SCALE, SHARED * F, 1,
                                  list(HELD))
     assert set(setup["attrs"]["remat"]["bytes"]) == {
-        "ffn.down", "ffn.gate", "ffn.up", *lm.LATENT_REMAT_KEEPS}
+        "ffn.down", "ffn.gate", "ffn.up",
+        *lm_layers.LatentSpec.KEPT.values()}
     trace.print_routes([{**setup, "trial": "t"}])
     out = capsys.readouterr().out
     assert ("layers 0-2: latent attention, 4 heads, q·k 16 + 8 rotary "
@@ -586,8 +627,8 @@ def test_the_setup_span_says_the_kind_and_the_routing(trial, capsys):
             "scaled 2.448, 8 held, shared as one of 64; layers 0 dense 96"
             ) in out
     # on the Pallas route of one device the line says the hand-over's form
-    chip = lm.describe_pattern(description(), "pallas", tokens=64)[
-        "attention_layers"]["latent-rope"]
+    chip = lm_description.describe_pattern(
+        description(), "pallas", tokens=64)["attention_layers"]["latent-rope"]
     assert (chip["hand_over"], chip["mask"]) == ("in place",
                                                  "structure: causal")
     trace.print_routes([{**setup, "trial": "t", "attrs": {
@@ -671,8 +712,8 @@ def layer_both_ways():
     """{form: (output, {leaf: gradient}, input's gradient)} of one latent
     layer on seeded weights: ``copies`` as the reference route takes it,
     ``in place`` through the kernels, interpreted."""
-    layer = lm.LatentAttention(D, H, lm.LatentSpec(RANK, NOPE, ROPE, VD, True),
-                               1e6, 1e-6)
+    layer = lm_layers.LatentAttention(D, lm_layers.LatentSpec(
+        H, RANK, NOPE, ROPE, VD, True, 1e6), 1e-6)
     x = jax.random.normal(jax.random.PRNGKey(0), (2, S, D))
     w = jax.random.normal(jax.random.PRNGKey(1), (2, S, D))
     params = jax.tree.map(lambda p: 3.0 * p, layer.init(
@@ -741,8 +782,9 @@ def lowered_steps():
 
     tokens = jnp.zeros((2, LOWERED_S + 1), jnp.int32)
     model = lm.make_lm({**description(), "remat": True})
-    keeps = lm.remat_keeps(model.pattern)["keeps"] + [
-        n for n in lm.LATENT_REMAT_KEEPS if n != "attention.kv_up"]
+    keeps = lm_remat.remat_keeps(model.pattern)["keeps"] + [
+        n for n in lm_layers.LatentSpec.KEPT.values()
+        if n != "attention.kv_up"]
     model = model.clone(keeps=tuple(keeps))
     trained, frozen = lm.split_frozen(jax.eval_shape(lambda: model.init(
         jax.random.PRNGKey(0), tokens[:, :-1], train=False)["params"]))

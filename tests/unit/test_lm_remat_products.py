@@ -1,5 +1,5 @@
 """What a rematerialised block keeps of its mixer's projections
-(models/lm.py: ``ATTENTION_REMAT_KEEPS``, ``LINEAR_REMAT_KEEPS``), for each
+(models/lm_layers.py: ``GroupedSpec.KEPT``, ``LinearSpec.KEPT``), for each
 of the three kinds of pattern block: the MoE decoder's, the one that
 selects its keys, the hybrid's linear and full layers.
 
@@ -19,13 +19,13 @@ S = 24
 
 
 def _description(kind, **over):
+    import lm_pattern_cases
+    import lm_selected_cases
     import test_lm_hybrid
-    import test_lm_pattern
-    import test_lm_selected
 
-    return {"pattern": lambda: test_lm_pattern.description(
+    return {"pattern": lambda: lm_pattern_cases.description(
                 [(0, 0), (1, 1)], **over),
-            "selected": lambda: test_lm_selected.description(2, **over),
+            "selected": lambda: lm_selected_cases.description(2, **over),
             "hybrid": lambda: test_lm_hybrid.description(
                 **test_lm_hybrid.PAIR, **over)}[kind]()
 
@@ -60,17 +60,18 @@ def products_by_direction(operations):
 def lowered():
     """(kind, limit) -> the products of the trial's lowered step, each
     lowered once a module."""
-    from test_lm_pattern import one_device
+    from lm_pattern_cases import one_device
     from test_trace_layers import operations
 
-    from metaopt_tpu.models import lm
+    from metaopt_tpu.models import lm, lm_remat
 
     made = {}
 
     def get(kind, limit):
         if (kind, limit) not in made:
             with pytest.MonkeyPatch.context() as patch:
-                patch.setattr(lm, "device_bytes_limit", lambda mesh: limit)
+                patch.setattr(lm_remat, "device_bytes_limit",
+                              lambda mesh: limit)
                 trial = lm.LMTrial(
                     {**_description(kind), "remat": True, "lr": 1e-3},
                     mesh=one_device(), n_train=4, batch_size=2, seq_len=S)
@@ -115,12 +116,14 @@ def test_a_declined_name_s_product_is_still_made_again():
     declined): that product alone keeps its second run."""
     from test_trace_layers import operations
 
-    from metaopt_tpu.models import lm
+    from metaopt_tpu.models import lm, lm_layers, lm_remat
 
     model = lm.make_lm(_description("hybrid", remat=True))
-    model = model.clone(keeps=tuple(
-        lm.remat_keeps(model.pattern)["keeps"]) + lm.ATTENTION_REMAT_KEEPS
-        + lm.LINEAR_REMAT_KEEPS[:-1])
+    linear = lm_layers.LinearSpec.KEPT
+    model = model.clone(keeps=(
+        *lm_remat.remat_keeps(model.pattern)["keeps"],
+        *lm_layers.GroupedSpec.KEPT.values(),
+        *(name for name in linear.values() if name != linear["out"])))
     tokens = jnp.zeros((1, S + 1), jnp.int32)
     params = jax.eval_shape(lambda: nn.meta.unbox(model.init(
         jax.random.PRNGKey(0), tokens[:, :-1], train=False)["params"]))
@@ -137,16 +140,17 @@ def test_without_a_policy_the_names_are_identities(kind, names):
     """Not rematerialised, the gradient is the one without models/lm.py's
     names but for a ``name`` equation a product (the hybrid's blocks:
     test_lm_hybrid.py, with the feed-forward's)."""
-    from test_lm_pattern import _equations
+    from lm_pattern_cases import _equations
 
-    from metaopt_tpu.models import lm
+    from metaopt_tpu.models import lm, lm_layers
 
     tokens = jnp.zeros((1, S + 1), jnp.int32)
     primitives = {}
     for how in ("named", "unnamed"):
         with pytest.MonkeyPatch.context() as patch:
             if how == "unnamed":
-                patch.setattr(lm, "checkpoint_name", lambda x, name: x)
+                patch.setattr(lm_layers, "checkpoint_name",
+                              lambda x, name: x)
             model = lm.make_lm(_description(kind))
             trained, frozen = lm.split_frozen(nn.meta.unbox(model.init(
                 jax.random.PRNGKey(0), tokens[:, :-1],
@@ -169,7 +173,7 @@ def test_the_head_reads_a_cast_that_stands(kind, feed_forwards):
     """The forward pass holds one barrier a gated feed-forward and one on
     the head's input: the stream as bfloat16, the one value the head's
     product reads beside its table."""
-    from test_lm_pattern import _equations
+    from lm_pattern_cases import _equations
 
     from metaopt_tpu.models import lm
 

@@ -83,23 +83,26 @@ def _mixers():
     earlier layer) to its output, for one row (the reference's) or a batch
     of one (the program's)."""
     from chipbench.reference import ssm_lm as reference
-    from metaopt_tpu.models import lm
+    from metaopt_tpu.models import lm_description, lm_layers
 
     cfg = reference_cfg()
     weights = seeded(cfg)
-    spec = lm.pattern_of(lm._own_names(description())).hybrid
+    spec = lm_description.pattern_of(lm_description._own_names(
+        description())).layers[0].mixer
     row = lambda fn: (lambda p, u, *read: jax.tree.map(  # noqa: E731
         lambda y: y[0], fn(p, u[None], *jax.tree.map(
             lambda v: v[None], read))))
-    attn = lambda layer, window: lm.DifferentialAttention(  # noqa: E731
-        D, H, HKV, W, window, lm.lambda_init(layer), 1e-5)
+    attn = lambda layer, window: lm_layers.DifferentialAttention(  # noqa: E731
+        D, lm_layers.DifferentialSpec(
+            H, HKV, W, window, lm_description.lambda_init(layer), False),
+        1e-5)
     apply = lambda module: row(  # noqa: E731
         lambda p, *args: module.apply({"params": p}, *args))
     return {
-        "ssm": (apply(lm.StateSpaceMixer(D, spec)),
+        "ssm": (apply(lm_layers.StateSpaceMixer(D, spec)),
                 lambda p, u: reference._mamba("float32", p, u, cfg, ()),
                 weights["h4"]["ssm"]),
-        "gmu": (apply(lm.GatedMemoryUnit(D, DI)),
+        "gmu": (apply(lm_layers.GatedMemoryUnit(D, DI)),
                 lambda p, u, m: reference._gmu("float32", p, u, m),
                 weights["h6"]["gmu"]),
         "window": (apply(attn(1, WINDOW)),
@@ -281,7 +284,7 @@ def test_a_dropped_reader_would_show(both_sides, path):
 def with_and_without_remat():
     import optax
 
-    from metaopt_tpu.models import lm
+    from metaopt_tpu.models import lm, lm_layers, lm_remat
 
     tokens = jax.random.randint(jax.random.PRNGKey(5), (1, S + 1), 2, V)
     out = {}
@@ -289,9 +292,11 @@ def with_and_without_remat():
         model = lm.make_lm(description(remat=remat))
         if remat:  # every name the rule can say
             model = model.clone(keeps=tuple(
-                lm.remat_keeps(model.pattern)["keeps"]) + lm.FFN_REMAT_KEEPS
-                + lm.ATTENTION_REMAT_KEEPS + lm.SSM_REMAT_KEEPS
-                + lm.GMU_REMAT_KEEPS)
+                lm_remat.remat_keeps(model.pattern)["keeps"]) + tuple(
+                name for spec in (
+                    lm_layers.GatedSpec, lm_layers.DifferentialSpec,
+                    lm_layers.StateSpaceSpec, lm_layers.MemoryUnitSpec)
+                for name in spec.KEPT.values()))
         params = nn.meta.unbox(model.init(
             jax.random.PRNGKey(0), tokens[:, :-1], train=False)["params"])
         tx = optax.sgd(1.0)   # the step IS the gradient: one program a case
@@ -327,14 +332,14 @@ def test_a_rematerialised_block_keeps_what_the_scan_made(monkeypatch):
     one ``selective_scan_fwd`` a Mamba layer and two ``flash_fwd`` an
     attention layer (the two maps); a bare ``nn.remat`` walks each forward a
     second time."""
-    from test_lm_pattern import _equations
+    from lm_pattern_cases import _equations
 
-    from metaopt_tpu.models import lm
+    from metaopt_tpu.models import lm, lm_remat
     from metaopt_tpu.ops import selective_scan
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    assert lm.remat_keeps(lm.make_lm(description()).pattern)["keeps"][-2:] \
-        == list(selective_scan.REMAT_KEEPS)
+    assert lm_remat.remat_keeps(lm.make_lm(description()).pattern)[
+        "keeps"][-2:] == list(selective_scan.REMAT_KEEPS)
     tokens = jax.random.randint(jax.random.PRNGKey(5), (1, 128 + 1), 2, V)
     counted = {}
     for how in ("kept", "bare"):
@@ -431,9 +436,9 @@ def test_the_tied_head_reads_out_over_the_held_slice():
 
 def test_the_published_rule_names_the_kinds_at_the_published_depth():
     from chipbench.reference import ssm_lm as reference
-    from metaopt_tpu.models import lm
+    from metaopt_tpu.models import lm_description
 
-    kinds = [lm.hybrid_kind(n, 32) for n in range(32)]
+    kinds = [lm_description.hybrid_kind(n, 32) for n in range(32)]
     assert [kinds.count(k) for k in ("ssm", "window", "full", "gmu",
                                      "cross")] == [9, 8, 1, 7, 7]
     assert [kinds[n] for n in (0, 1, 16, 17, 18, 19)] == [
@@ -445,34 +450,47 @@ def test_the_published_rule_names_the_kinds_at_the_published_depth():
     # numbers', which is why the cut lists them
     for n in (3, 5):
         with pytest.raises(ValueError, match=f"layer {n} of 6 has no kind"):
-            lm.hybrid_kind(n, 6)
-    assert lm.lambda_init(17) == pytest.approx(
+            lm_description.hybrid_kind(n, 6)
+    assert lm_description.lambda_init(17) == pytest.approx(
         0.8 - 0.6 * math.exp(-5.1)) == pytest.approx(reference.lambda_init(17))
 
 
 def test_the_description_builds_the_pattern():
-    from metaopt_tpu.models import lm
+    from metaopt_tpu.models import lm, lm_description, lm_layers
 
-    h = lm._own_names(description())
-    assert lm.family_of(h) == "phi4flash"
-    p = lm.pattern_of(h)
-    assert p.hybrid.layers == tuple(HELD) and p.hybrid.of == OF
-    assert p.hybrid.kinds == ("ssm", "window", "ssm", "full", "gmu", "cross")
-    assert (p.hybrid.d_inner, p.hybrid.d_state, p.hybrid.d_conv,
-            p.hybrid.dt_rank) == (DI, N, 4, R)
-    assert (p.hybrid.memory_layer, p.hybrid.kv_layer) == (4, 5)
+    h = lm_description._own_names(description())
+    assert lm_description.family_of(h) == "phi4flash"
+    p = lm_description.pattern_of(h)
+    assert [layer.number for layer in p.layers] == list(HELD)
+    assert [p.kind(i) for i in range(len(HELD))] == [
+        "ssm", "window-nope", "ssm", "global-nope", "gmu", "cross-nope"]
+    assert p.layers[0].mixer == p.layers[2].mixer \
+        == lm_layers.StateSpaceSpec(DI, N, 4, R)
+    assert p.layers[4].mixer == lm_layers.MemoryUnitSpec(DI)
+    # layer 4 hands its scan output to the memory unit, layer 5 its K and
+    # V to the cross layer
+    assert [(layer.reads, layer.hands_on) for layer in p.layers] == [
+        ((), ()), ((), ()), ((), ("memory",)), ((), ("kv",)),
+        (("memory",), ()), (("kv",), ())]
     assert p.kinds() == ["ssm", "window-nope", "global-nope", "gmu",
                          "cross-nope"]
-    assert (p.window, p.rms_eps, p.head_dim, p.n_kv_heads, p.activation) \
-        == (WINDOW, 1e-5, W, HKV, "silu")
+    assert [p.layers[i].mixer for i in (1, 3, 5)] == [
+        lm_layers.DifferentialSpec(H, HKV, W, window,
+                                   lm_description.lambda_init(n), cross)
+        for n, window, cross in ((1, WINDOW, False), (5, None, False),
+                                 (7, None, True))]
+    assert (p.norm, p.eps, p.tied) == ("layer", 1e-5, True)
+    assert {layer.ffn for layer in p.layers} == {
+        lm_layers.GatedSpec(F, "silu")}
     assert lm.make_lm(description()).n_layers == len(HELD)
     # the one table, tied, and the route its lookup's gradient takes here
-    assert lm.describe_pattern(description(), "reference", tokens=S)[
-        "embed"] == {"gradient": "take", "rows": V, "width": D, "tokens": S,
+    assert lm_description.describe_pattern(
+        description(), "reference", tokens=S)["embed"] == {"gradient": "take", "rows": V, "width": D, "tokens": S,
                      "tied": True}
     # without ``layers_held`` every published layer is held
-    assert lm.pattern_of(lm._own_names(
-        description(None))).hybrid.layers == tuple(range(OF))
+    assert [layer.number for layer in lm_description.pattern_of(
+        lm_description._own_names(description(None))).layers] \
+        == list(range(OF))
 
 
 @pytest.mark.parametrize("held, message", [
@@ -530,16 +548,17 @@ def test_the_cell_holds_697_million_parameters_on_both_sides():
     (``jax.eval_shape`` of its init), leaf for leaf: 697 M x 16 bytes =
     11.15 GB of the chip's 16."""
     from chipbench.reference import ssm_lm as reference
-    from metaopt_tpu.models import lm
+    from metaopt_tpu.models import lm, lm_remat
 
     config, ssm_lm_config = _cell()
     shapes = reference.param_shapes(ssm_lm_config.reference_cfg(config))
     assert _count(shapes) == 697_094_272
     assert 16 * _count(shapes) / 1e9 == pytest.approx(11.15, abs=0.01)
     model = lm.make_lm(ssm_lm_config.description(config))
-    assert model.pattern.hybrid.layers == (0, 1, 16, 17, 18, 19)
+    assert [layer.number for layer in model.pattern.layers] == [
+        0, 1, 16, 17, 18, 19]
     prog = nn.meta.unbox(jax.eval_shape(
-        lm.param_init(model, (1, 128)),
+        lm_remat.param_init(model, (1, 128)),
         jax.ShapeDtypeStruct((2,), jnp.uint32)))
     assert {p: x.shape for p, x in zip(paths(prog), jax.tree.leaves(prog))} \
         == {p: x.shape for p, x in zip(paths(shapes),
@@ -558,12 +577,12 @@ def test_the_rule_keeps_what_fits_the_cell():
     kernels' names, the feed-forward's three products (2.27 GB over six
     layers), attention's and the memory unit's projections; the Mamba
     layers' input projection and dt_proj (336 MB each) are declined."""
-    from metaopt_tpu.models import lm
+    from metaopt_tpu.models import lm, lm_layers, lm_remat
 
     config, ssm_lm_config = _cell()
     model = lm.make_lm(ssm_lm_config.description(config))
-    said = lm.remat_keeps(
-        model.pattern, tokens=8192, d_model=2560, d_ff=10240, n_heads=40,
+    said = lm_remat.remat_keeps(
+        model.pattern, tokens=8192, d_model=2560,
         parameters=697_094_272, bytes_limit=int(15.75 * 2 ** 30))
     assert said["bytes"]["ffn.gate"] == 6 * 8192 * 2 * 10240
     assert said["bytes"]["attention.q_proj"] == 3 * 8192 * 2 * 2560
@@ -572,8 +591,9 @@ def test_the_rule_keeps_what_fits_the_cell():
     assert said["bytes"]["gmu.in_proj"] == 8192 * 2 * 5120
     kept = set(said["keeps"])
     assert {"selective_scan.out", "selective_scan.states", "attention.out",
-            *lm.FFN_REMAT_KEEPS, "ssm.x_proj", "ssm.out_proj",
-            *lm.GMU_REMAT_KEEPS, *lm.ATTENTION_REMAT_KEEPS} <= kept
+            *lm_layers.GatedSpec.KEPT.values(), "ssm.x_proj",
+            "ssm.out_proj", *lm_layers.MemoryUnitSpec.KEPT.values(),
+            *lm_layers.DifferentialSpec.KEPT.values()} <= kept
     assert not {"ssm.in_proj", "ssm.dt_proj"} & kept
     assert sum(said["bytes"][n] for n in kept if n in said["bytes"]) \
         <= said["room"] == (int(15.75 * 2 ** 30) - 16 * 697_094_272) // 2

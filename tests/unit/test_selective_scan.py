@@ -89,7 +89,7 @@ def test_the_kept_names_are_on_the_rules_own_values():
     """Under ``save_only_these_names`` the gradient of a rematerialised
     call walks the row forward once: the output AND the chunks' states are
     kept, so no second forward kernel is in the backward pass."""
-    from test_lm_pattern import _equations
+    from lm_pattern_cases import _equations
 
     x = jnp.ones((1, 64, 8))
     bc = jnp.ones((1, 64, N))
