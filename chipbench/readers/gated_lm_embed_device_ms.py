@@ -1,0 +1,10 @@
+"""``embed_device_ms`` in a gated mixed-window MoE decoder's cell, read by that
+metric's own reader: the layer ``embed``: the embedding's gather and its
+gradient's sorted rows. An accepted metric's list of cells takes no new
+cell, so the cell reports it under a name of its own."""
+
+from chipbench.run import _reader
+
+
+def read(records):
+    return _reader("embed_device_ms").read(records)

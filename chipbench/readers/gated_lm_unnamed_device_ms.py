@@ -1,0 +1,10 @@
+"""``unnamed_device_ms`` in a gated mixed-window MoE decoder's cell, read by
+that metric's own reader: busy time in which no operation with a name of the
+program's ran. An accepted metric's list of cells takes no new cell, so the
+cell reports it under a name of its own."""
+
+from chipbench.run import _reader
+
+
+def read(records):
+    return _reader("unnamed_device_ms").read(records)

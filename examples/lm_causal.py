@@ -53,6 +53,15 @@ earlier layer's K and V, the layers a chip holds listed by their published
 numbers in ``layers_held`` (``python -m chipbench.ssm_lm_config
 chipbench/configs/phi-4-mini-flash-vp8.json`` prints
 Phi-4-mini-flash-reasoning's share of one chip; ``--seq-len=8192``).
+One with ``model_type`` ``laguna`` names grouped layers that differ layer
+by layer (``layer_types``, ``num_attention_heads_per_layer``,
+``rope_parameters``' entry a type: 48 heads under the causal mask and YaRN
+on half a head, 64 under a window of 512 and the plain rule), a sigmoid
+gate a head on attention's output (``gating``) and, by
+``mlp_layer_types``, a dense feed-forward or sigmoid routing beside a
+shared expert (``python -m chipbench.gated_lm_config
+chipbench/configs/laguna-xs2-33b-a3b-ep8.json`` prints Laguna-XS.2's share
+of one chip; ``--seq-len=8192``).
 """
 
 import argparse

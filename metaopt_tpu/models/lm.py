@@ -18,6 +18,8 @@ held rows or the embedding's table.
 kind               spec              module                 kernels (ops/)
 =================  ================  =====================  =================
 global-, window-   GroupedSpec       GroupedAttention       attention
+                   + ``rotary``,     (a rotary rule a
+                   ``gate``          layer, an output gate)
 selected-          + ``selection``   + Indexer              + sparse_index
 latent-rope        LatentSpec        LatentAttention        latent_attention
 linear             LinearSpec        LinearAttention        linear_attention
@@ -34,7 +36,10 @@ family): SmallThinker's layouts and the Qwen3-MoE family's words the
 grouped kinds (selected with ``sa_config``) and the routed feed-forward;
 the Olmo hybrid family's linear and global-nope, with a gated
 feed-forward; the DeepSeek-V3 family's latent, routed behind leading gated
-layers; ``phi4flash`` ssm, gmu and the differential kinds, gated.
+layers; ``phi4flash`` ssm, gmu and the differential kinds, gated;
+``laguna`` the grouped kinds with a head count, a rotary rule (YaRN on half
+a head, the plain rule on the whole) and an output gate a layer, gated or
+routed by the layer.
 
 The specs and modules are models/lm_layers.py's (the routed feed-forward's
 models/moe.py's); what a rematerialised block keeps is

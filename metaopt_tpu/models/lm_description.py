@@ -6,7 +6,8 @@ own keys (``d_model``, ``n_layers`` ...) or a published config's
 (``_PUBLISHED``), plus the chip's share of a deployment: ``experts_held`` =
 (first, count) of the routed experts, ``vocab_held`` = (first, count) of the
 vocabulary's rows, ``heads_held`` = (first, count) of
-``num_attention_heads`` (every kind of head is built in that proportion) and,
+``num_attention_heads`` (every kind of head is built in that proportion;
+``laguna``, whose layers differ in their head counts, takes none) and,
 for ``phi4flash``, ``layers_held``: the published numbers of the layers this
 chip holds (a pipeline stage's; not the first n). :func:`family_of` says
 whose words a description speaks, and the family's reader (``_FAMILIES``)
@@ -26,7 +27,7 @@ from jax.sharding import Mesh
 
 from metaopt_tpu.models.lm_layers import (
     DifferentialSpec, GatedSpec, GroupedSpec, LatentSpec, LinearSpec,
-    MemoryUnitSpec, StateSpaceSpec)
+    MemoryUnitSpec, Rotary, StateSpaceSpec)
 from metaopt_tpu.models.moe import RoutedSpec, RoutingRule
 from metaopt_tpu.ops.embed import embed_gradient_route
 
@@ -131,10 +132,11 @@ def family_of(h: Dict[str, Any]) -> Optional[str]:
     (a key of ``_FAMILIES``), None for one without a pattern:
     ``kv_lora_rank`` is the DeepSeek-V3 family's, ``layer_types`` the Olmo
     hybrid's, ``num_experts`` the Qwen3-MoE family's, the two layouts or
-    ``sa_config`` alone SmallThinker's; ``model_type`` ``phi4flash`` names
-    its family itself."""
-    if h.get("model_type") == "phi4flash":
-        return "phi4flash"
+    ``sa_config`` alone SmallThinker's; ``model_type`` ``phi4flash`` and
+    ``laguna`` name their families themselves (the second speaks the Olmo
+    hybrid's and the Qwen3-MoE family's words at once)."""
+    if h.get("model_type") in ("phi4flash", "laguna"):
+        return h["model_type"]
     for key, family in (("kv_lora_rank", "deepseek_v3"),
                         ("layer_types", "olmo_hybrid"),
                         ("num_experts", "qwen3_moe"),
@@ -186,20 +188,24 @@ def _gated(h) -> GatedSpec:
                                                          "relu")))
 
 
+def _expert_width(h) -> int:
+    return int(h.get("moe_ffn_hidden_size", h.get("d_ff", 2048)))
+
+
 def _feed_forward(h, router_after_mixer: bool, rule=RoutingRule(),
-                  shared: int = 0):
+                  shared_d_ff: int = 0):
     """A routed feed-forward where the description names experts (beside
-    ``shared`` shared ones), else the gated one."""
+    shared ones, one feed-forward ``shared_d_ff`` wide), else the gated
+    one."""
     n_experts = int(h.get("moe_num_primary_experts", 0))
     if not n_experts:
         return _gated(h)
-    d_ff = int(h.get("moe_ffn_hidden_size", h.get("d_ff", 2048)))
     return RoutedSpec(
         n_experts=n_experts,
-        top_k=int(h.get("moe_num_active_primary_experts", 1)), d_ff=d_ff,
-        held=_held(h, "experts_held", n_experts),
+        top_k=int(h.get("moe_num_active_primary_experts", 1)),
+        d_ff=_expert_width(h), held=_held(h, "experts_held", n_experts),
         activation=str(h.get("hidden_act", "relu")),
-        shared_d_ff=shared * d_ff, rule=rule,
+        shared_d_ff=shared_d_ff, rule=rule,
         router_after_mixer=router_after_mixer)
 
 
@@ -309,8 +315,8 @@ def _deepseek_v3(h) -> Pattern:
     n_layers = int(h.get("n_layers", 6))
     dense = min(int(h.get("first_k_dense_replace", 0)), n_layers)
     mixer = _latent(h)
-    routed = _feed_forward(h, True, _routing(h),
-                           int(h.get("n_shared_experts") or 0))
+    routed = _feed_forward(h, True, _routing(h), int(
+        h.get("n_shared_experts") or 0) * _expert_width(h))
     return _pattern(h, [Layer(i, mixer, _gated(h) if i < dense else routed)
                         for i in range(n_layers)], "rms")
 
@@ -427,11 +433,103 @@ def lambda_init(layer: int) -> float:
     return 0.8 - 0.6 * math.exp(-0.3 * layer)
 
 
+def _laguna(h) -> Pattern:
+    """The ``laguna`` family's: the Qwen3-MoE family's pre-norm block (RMS
+    norms, q/k norms over a head's width, the router read after attention)
+    with, layer by layer (three lists kept whole and read up to the depth),
+    the mask (``layer_types``: ``full_attention`` or ``sliding_attention``,
+    ``sliding_window`` wide), the query heads
+    (``num_attention_heads_per_layer``, on ``num_key_value_heads`` K/V
+    heads), the rotary rule (``rope_parameters``' entry of the layer's
+    type: :func:`_rotary`) and the feed-forward (``mlp_layer_types``:
+    ``dense``, gated ``intermediate_size`` wide, or ``sparse``: sigmoid
+    scores normalised over the chosen, times ``moe_routed_scaling_factor``,
+    no correction bias, beside one shared expert
+    ``shared_expert_intermediate_size`` wide); ``gating``: a sigmoid gate a
+    head on attention's output; the feed-forwards gated by ``hidden_act``
+    (SiLU unless named). What has no layer here is refused by its name."""
+    n_layers = int(h.get("n_layers", 6))
+    kv_heads = int(h.get("num_key_value_heads", 8))
+    lists = {key: list(h.get(key) or ()) for key in (
+        "layer_types", "mlp_layer_types", "num_attention_heads_per_layer")}
+    for key, entries in lists.items():
+        if len(entries) < n_layers:
+            raise ValueError(f"{key} names {len(entries)} layers, the model "
+                             f"has {n_layers}")
+        lists[key] = entries[:n_layers]
+    for key, known in (("layer_types", _MASKS), ("mlp_layer_types", _FEEDS)):
+        unknown = sorted(set(lists[key]) - set(known))
+        if unknown:
+            raise ValueError(f"{key} names {unknown}; known: {sorted(known)}")
+    for key in ("attention_bias", "moe_apply_router_weight_on_input"):
+        if h.get(key):
+            raise ValueError(f"{key} {h[key]!r}: a laguna layer has none "
+                             "here")
+    if "heads_held" in h:
+        raise ValueError(f"heads_held {h['heads_held']!r}: a laguna layer's "
+                         "head count is its own "
+                         "(num_attention_heads_per_layer) and the deployment "
+                         "shares no heads")
+    gating = h.get("gating", False)
+    if not isinstance(gating, bool):
+        raise ValueError(f"gating {gating!r}: known: true (a sigmoid gate a "
+                         "head) and false")
+    head_dim = int(h.get("head_dim") or int(h.get("d_model", 512))
+                   // int(h.get("n_heads", 8)))
+    ropes = h.get("rope_parameters") or {}
+    rules = {kind: _rotary(kind, ropes.get(kind) or {}, head_dim)
+             for kind in set(lists["layer_types"])}
+    act = {**h, "hidden_act": h.get("hidden_act", "silu")}
+    feeds = {"dense": _gated(act), "sparse": _feed_forward(
+        act, True, RoutingRule(
+            "sigmoid", bias=False, normalised=True,
+            scale=float(h.get("moe_routed_scaling_factor", 1.0))),
+        int(h.get("shared_expert_intermediate_size") or 0))}
+    layers = []
+    for i, (kind, feed, heads) in enumerate(zip(*lists.values())):
+        if int(heads) % kv_heads:
+            raise ValueError(f"num_attention_heads_per_layer[{i}] {heads}: "
+                             f"{kv_heads} K/V heads do not divide it")
+        layers.append(Layer(i, GroupedSpec(
+            heads=int(heads), kv_heads=kv_heads, head_dim=head_dim,
+            window=int(h.get("sliding_window", 512)) if _MASKS[kind]
+            else None, theta=rules[kind].theta, qk_norm="head",
+            selection=None, rotary=rules[kind],
+            gate="sigmoid" if gating else None), feeds[feed]))
+    return _pattern(h, layers, "rms")
+
+
+#: a ``laguna`` ``layer_types`` entry -> is the layer under the window?
+_MASKS = {"full_attention": False, "sliding_attention": True}
+#: its ``mlp_layer_types`` entries
+_FEEDS = ("dense", "sparse")
+
+
+def _rotary(kind: str, said: Dict[str, Any], head_dim: int) -> Rotary:
+    """The rotary rule of one ``rope_parameters`` entry (``kind``'s), for
+    heads ``head_dim`` wide: ``rope_type`` ``default`` or ``yarn`` (another
+    is refused by name), the first ``partial_rotary_factor`` of a head's
+    channels turned."""
+    rope_type = said.get("rope_type", "default")
+    if rope_type not in ("default", "yarn"):
+        raise ValueError(f"rope_parameters.{kind}.rope_type {rope_type!r}: "
+                         "known: 'default' and 'yarn'")
+    turned = int(head_dim * float(said.get("partial_rotary_factor", 1.0)))
+    yarn, factor = None, 1.0
+    if rope_type == "yarn":
+        yarn = (float(said["factor"]),
+                int(said["original_max_position_embeddings"]),
+                float(said["beta_fast"]), float(said["beta_slow"]))
+        factor = float(said["attention_factor"])
+    return Rotary(float(said.get("rope_theta", 10000.0)),
+                  turned if turned < head_dim else None, yarn, factor)
+
+
 #: a family's reader: what its layer is, by the family and not by how a
 #: key of its description is spelt
 _FAMILIES = {"layouts": _layouts, "qwen3_moe": _qwen3_moe,
              "olmo_hybrid": _olmo_hybrid, "deepseek_v3": _deepseek_v3,
-             "phi4flash": _phi4flash}
+             "phi4flash": _phi4flash, "laguna": _laguna}
 
 
 def describe_pattern(hparams: Dict[str, Any], route: str, tokens: int,

@@ -92,15 +92,70 @@ NORMS = {"rms": (_rms_norm, True), "rms on the branches": (_rms_norm, False),
          "layer": (_layer_norm, True)}
 
 
-def rope(x, theta: float, adjacent: bool = False):
-    """Rotary positions 0..S-1 on ``x`` (B, S, H, D), float32: pair j turns
-    by pos * theta^(-2j/D). The two halves of a head are the pairs,
-    channels (j, j + D/2) (the ``rotate_half`` convention), or, with
-    ``adjacent``, channels (2j, 2j + 1) (``rope_interleave``)."""
+@dataclasses.dataclass(frozen=True)
+class Rotary:
+    """A layer's rotary rule as a value: the base ``theta``; how many of a
+    head's channels turn (the FIRST ``turned``; None: all, a
+    ``partial_rotary_factor`` of 1); YaRN's numbers, ``yarn`` = (factor,
+    original positions, beta_fast, beta_slow), or None; and the ``factor``
+    cos and sin are multiplied by (YaRN's ``attention_factor``; 1: as they
+    are). ``Rotary(theta)`` is the plain rule."""
+
+    theta: float
+    turned: Optional[int] = None
+    yarn: Optional[Tuple[float, int, float, float]] = None
+    factor: float = 1.0
+
+    def frequencies(self, d: int):
+        """(d / 2,) float32: the angle a position turns pair j of the ``d``
+        turned channels by. Plain: theta^(-2j/d). YaRN, as the transformers
+        library's ``_compute_yarn_parameters`` has it with truncation on:
+        pairs below ``low`` keep the plain frequency, pairs from ``high`` on
+        take it over ``factor``, a straight ramp between, where ``low`` /
+        ``high`` are the floor / ceiling of the pair that turns beta_fast /
+        beta_slow times in the original positions."""
+        plain = self.theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+        if self.yarn is None:
+            return plain
+        factor, original, fast, slow = self.yarn
+        pair = lambda turns: d * math.log(  # noqa: E731
+            original / (turns * 2 * math.pi)) / (2 * math.log(self.theta))
+        low = max(math.floor(pair(fast)), 0)
+        high = min(math.ceil(pair(slow)), d - 1)
+        ramp = jnp.clip((jnp.arange(d // 2, dtype=jnp.float32) - low)
+                        / max(high - low, 0.001), 0.0, 1.0)
+        return plain * (1.0 - ramp) + plain / factor * ramp
+
+    def said(self, d: int) -> str:
+        """The rule in ``trial.setup``'s words, for heads ``d`` wide."""
+        turned = f"{d if self.turned is None else self.turned} of {d}"
+        if self.yarn is None:
+            return f"plain {self.theta:g}, {turned}"
+        return (f"yarn {self.theta:g} x{self.yarn[0]:g} over "
+                f"{self.yarn[1]}, {turned}, cos and sin x {self.factor:.4f}")
+
+
+def rope(x, rule, adjacent: bool = False):
+    """Rotary positions 0..S-1 on ``x`` (B, S, H, D), float32, by ``rule``
+    (a :class:`Rotary`; a bare base is the plain rule): pair j of the
+    turned channels turns by pos * ``rule.frequencies``[j], the channels
+    past ``rule.turned`` pass as they are. The two halves of the turned
+    channels are the pairs, channels (j, j + turned/2) (the ``rotate_half``
+    convention), or, with ``adjacent``, channels (2j, 2j + 1)
+    (``rope_interleave``)."""
+    if not isinstance(rule, Rotary):
+        rule = Rotary(rule)
     s, d = x.shape[1], x.shape[-1]
-    freq = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    if rule.turned is not None and rule.turned < d:
+        x = x.astype(jnp.float32)
+        return jnp.concatenate(
+            [rope(x[..., :rule.turned], dataclasses.replace(
+                rule, turned=None), adjacent), x[..., rule.turned:]], axis=-1)
+    freq = rule.frequencies(d)
     angle = jnp.arange(s, dtype=jnp.float32)[:, None] * freq[None]  # (S, D/2)
     cos, sin = jnp.cos(angle)[None, :, None], jnp.sin(angle)[None, :, None]
+    if rule.factor != 1.0:
+        cos, sin = cos * rule.factor, sin * rule.factor
     x = x.astype(jnp.float32)
     if adjacent:
         a, b = x[..., 0::2], x[..., 1::2]
@@ -188,9 +243,13 @@ class GroupedSpec(_Mixer):
     held here and their width, a ``window`` or None, a rotary base
     ``theta`` or None (no positions), RMS norms of q and k (``qk_norm``:
     None, ``"head"``: over a head's width, ``"whole"``: over the projected
-    width, the heads held here together under one scale vector) and
+    width, the heads held here together under one scale vector),
     ``selection``: None, or (index heads, their width, top k) of an
-    :class:`Indexer` whose keys the layer attends to."""
+    :class:`Indexer` whose keys the layer attends to; ``rotary``: the
+    layer's rotary rule where it is not the plain one at ``theta`` over
+    the whole head (:attr:`rule`), and ``gate``: None, or the activation (a
+    key of ``GATES``) of a gate on attention's output, one number a head
+    and token, read from the layer's input."""
 
     heads: int
     kv_heads: int
@@ -199,9 +258,19 @@ class GroupedSpec(_Mixer):
     theta: Optional[float]
     qk_norm: Optional[str]
     selection: Optional[Tuple[int, int, int]]
+    rotary: Optional[Rotary] = None
+    gate: Optional[str] = None
 
-    KEPT = _ATTENTION_KEPT
+    #: the four projections' products and the gate's
+    KEPT = {**_ATTENTION_KEPT, "gate": "attention.gate_proj"}
     TP = ("heads", "kv_heads")
+
+    @property
+    def rule(self) -> Optional[Rotary]:
+        """The rotary rule, None without positions."""
+        if self.theta is None:
+            return None
+        return self.rotary or Rotary(self.theta)
 
     def mix(self, block, x):
         return GroupedAttention(block.d_model, self, block.eps,
@@ -213,8 +282,10 @@ class GroupedSpec(_Mixer):
 
     def products(self, d_model):
         kept, kv = self.KEPT, 2 * self.kv_heads * self.head_dim
+        # the gate's product is float32, a number a head
+        gate = {kept["gate"]: 4 * self.heads} if self.gate else {}
         return [(d_model, {kept["q"]: 2 * self.heads * self.head_dim,
-                           kept["k"]: kv, kept["v"]: kv}),
+                           kept["k"]: kv, kept["v"]: kv, **gate}),
                 (self.heads * self.head_dim, {kept["out"]: 2 * d_model})]
 
     @property
@@ -225,8 +296,16 @@ class GroupedSpec(_Mixer):
 
     def describe(self, step, layers, sources):
         if not self.selection:
-            return {"route": step.route,
+            said = {"route": step.route,
                     "mask": _mask_said(step.route, self.window)}
+            if self.rotary or self.gate:  # where a field says more than the kind
+                said.update(
+                    layers=_numbers(layers), heads=self.heads,
+                    kv_heads=self.kv_heads,
+                    rotary=self.rule.said(self.head_dim) if self.rule
+                    else None,
+                    gate=f"{self.gate} a head" if self.gate else None)
+            return said
         from metaopt_tpu.ops.sparse_index import scores_of_a_row
 
         heads, width, top_k = self.selection
@@ -238,11 +317,19 @@ class GroupedSpec(_Mixer):
         return said
 
 
+#: a gate's activation by the name a spec gives it
+GATES = {"sigmoid": jax.nn.sigmoid}
+
+
 class GroupedAttention(nn.Module):
     """Causal self attention with fewer K/V heads than query heads, no
     bias, as ``spec`` says (:class:`GroupedSpec`); the q/k norms' eps is
-    ``eps``. The four projections' products carry the names of the spec's
-    ``KEPT``: identities unless a block's policy asks for them."""
+    ``eps``. With ``spec.gate``, head h's output is multiplied by
+    act(x W_g)[h] before the output projection (a gate a head and token,
+    the headwise form of arXiv:2505.06708; float32 at matmul precision
+    highest, as a linear layer's gates are), under the scope
+    ``attention.gate``. The projections' products carry the names of the
+    spec's ``KEPT``: identities unless a block's policy asks for them."""
 
     d_model: int
     spec: GroupedSpec
@@ -258,7 +345,7 @@ class GroupedAttention(nn.Module):
         mask = CausalMask(sp.window)
         if sp.selection:
             mask = Indexer(*sp.selection, sp.theta, name="indexer")(x)
-        x = x.astype(jnp.bfloat16)
+        gate_in, x = x, x.astype(jnp.bfloat16)
         q, k, v = (checkpoint_name(proj("q", sp.heads)(x), kept["q"]),
                    checkpoint_name(proj("k", sp.kv_heads)(x), kept["k"]),
                    checkpoint_name(proj("v", sp.kv_heads)(x), kept["v"]))
@@ -268,10 +355,20 @@ class GroupedAttention(nn.Module):
             q = RMSNorm(self.eps, name="q_norm")(whole(q)).reshape(q.shape)
             k = RMSNorm(self.eps, name="k_norm")(whole(k)).reshape(k.shape)
         if sp.theta is not None:
-            q, k = rope(q, sp.theta), rope(k, sp.theta)
+            q, k = rope(q, sp.rule), rope(k, sp.rule)
         q = (q / math.sqrt(sp.head_dim)).astype(jnp.bfloat16)
         k = k.astype(jnp.bfloat16)
         out = attend(q, k, v, mask)
+        if sp.gate is not None:
+            with trace.scope("attention.gate"):
+                g = checkpoint_name(nn.DenseGeneral(
+                    sp.heads, use_bias=False, name="gate",
+                    precision=jax.lax.Precision.HIGHEST,
+                    kernel_init=with_mesh_partitioning(
+                        nn.initializers.lecun_normal(), (None, "tp")),
+                )(gate_in.astype(jnp.float32)), kept["gate"])
+                out = (out.astype(jnp.float32)
+                       * GATES[sp.gate](g)[..., None]).astype(jnp.bfloat16)
         return checkpoint_name(nn.DenseGeneral(
             self.d_model, axis=(-2, -1), dtype=jnp.bfloat16, name="out",
             use_bias=False, kernel_init=_pinit(True, ("tp", None, None)),

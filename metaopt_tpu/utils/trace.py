@@ -15,7 +15,7 @@ variable is set (``hunt --profile-dir``), and never otherwise.
 A device operation carries the scopes it was traced under in its
 ``op_name`` (``jit(train_step)/transpose(jvp(DecoderOnlyLM))/.../h0/attn/
 attention/attention.core/...``). ``SCOPES`` closes over a train step's
-source: every operation the program writes is under one of its twenty-five
+source: every operation the program writes is under one of its twenty-six
 names, and two rules read a path, :func:`layer_of` (which top-level scope
 owns it) and :func:`direction` (forward, the forward's second run under
 remat, backward, update). What carries no name of ``SCOPES`` the compiler
@@ -77,6 +77,10 @@ SCOPES = ("embed", "attention", "attention.core", "ffn", "readout_xent",
           # models/lm_layers.DifferentialAttention's own: lambda, a_1 -
           # lambda a_2, the pair's norm and (1 - lambda_init)
           "attention.diff",
+          # a gate on attention's output (models/lm_layers.GroupedAttention
+          # with ``spec.gate``): its projection, the activation and the
+          # product a head, and their backward
+          "attention.gate",
           # the trunk between the layers: the blocks' and the model's norms
           # outside a branch; the residual stream's sums and casts; what a
           # loss function does around the model and ``readout_xent`` (the
@@ -533,7 +537,11 @@ def print_routes(recs: List[dict]) -> None:
                           f"q\u00b7k {pairs[2]}, v {pairs[3]}" + (
                               f", reading layer {how['reads']}'s K and V"
                               if "reads" in how else "")
-                          if pairs else ""))
+                          if pairs else "") + (
+                          f"; layers {_runs(how['layers'])}: {how['heads']} "
+                          f"query heads on {how['kv_heads']} K/V heads, "
+                          f"rotary {how['rotary']}, gate {how['gate']}"
+                          if "rotary" in how else ""))
             embed = attrs.get("embed")
             if embed:
                 print(f"trial {r['trial']}: embedding: {embed['rows']} rows "

@@ -73,10 +73,13 @@ def test_forward_and_backward_compile_for_a_v5e(one_chip, shape, masked):
 
 # (length, window, query heads, K/V heads, head width): SmallThinker's global
 # and window layers at the benchmark cell's 8192 tokens and at the model's
-# 16384, a length that pads, and 64-wide heads
+# 16384, a length that pads, 64-wide heads, and the gated mixed-window
+# cell's two kinds at its 8192 tokens: 64 query heads under the window of
+# 512 and 48 under the causal mask, on 8 K/V heads (groups of 8 and of 6)
 STRUCTURAL = [(8192, None, 28, 4, 128), (8192, 4096, 28, 4, 128),
               (16384, 4096, 28, 4, 128), (16384, None, 28, 4, 128),
-              (1000, 300, 8, 2, 128), (2048, 512, 8, 8, 64)]
+              (1000, 300, 8, 2, 128), (2048, 512, 8, 8, 64),
+              (8192, 512, 64, 8, 128), (8192, None, 48, 8, 128)]
 
 
 @pytest.mark.parametrize("case", STRUCTURAL,
@@ -428,12 +431,14 @@ def test_the_embedding_s_gradient_rule_compiles_for_a_v5e(one_chip, shape):
     assert "embed_rows_bwd" in text and "scatter" not in text
 
 
-# (buffer rows, d, f, activation): the held experts' part of the 16k
-# selected-attention cell, the latent cell and the 8k decoder; and experts
-# twice as wide as any cell's, where the tiles have to give way to VMEM
-# (the gating's 512 rows a program would take 28 MiB there)
-EXPERTS = [(131072, 2048, 768, "silu"), (98304, 2048, 768, "silu"),
-           (49152, 2560, 768, "relu"), (32768, 4096, 1536, "silu")]
+# (buffer rows, d, f, activation, held experts): the held experts' part of
+# the 16k selected-attention cell, the latent cell and the 8k decoder;
+# experts twice as wide as any cell's, where the tiles have to give way to
+# VMEM (the gating's 512 rows a program would take 28 MiB there); and the
+# gated mixed-window cell's: 8192 tokens x top 8, 32 held experts 512 wide
+EXPERTS = [(131072, 2048, 768, "silu", 16), (98304, 2048, 768, "silu", 16),
+           (49152, 2560, 768, "relu", 16), (32768, 4096, 1536, "silu", 16),
+           (65536, 2048, 512, "silu", 32)]
 
 
 @pytest.mark.parametrize("shape", EXPERTS, ids=lambda s: "x".join(map(str, s)))
@@ -451,7 +456,7 @@ def test_the_held_experts_part_compiles_for_a_v5e(one_chip, shape):
 
     from metaopt_tpu.models import moe
 
-    n, d, f, activation = shape
+    n, d, f, activation, held = shape
     act = {"relu": nn.relu, "silu": nn.silu}[activation]
     on_chip = lambda s, dtype: jax.ShapeDtypeStruct(  # noqa: E731
         s, dtype, sharding=one_chip)
@@ -462,9 +467,10 @@ def test_the_held_experts_part_compiles_for_a_v5e(one_chip, shape):
         return jnp.sum(out.astype(jnp.float32) ** 2)
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-        on_chip((n, d), jnp.bfloat16), on_chip((16, d, 2 * f), jnp.bfloat16),
-        on_chip((16, f, d), jnp.bfloat16),
-        on_chip((16,), jnp.int32)).compile().as_text()
+        on_chip((n, d), jnp.bfloat16),
+        on_chip((held, d, 2 * f), jnp.bfloat16),
+        on_chip((held, f, d), jnp.bfloat16),
+        on_chip((held,), jnp.int32)).compile().as_text()
     assert text.count("tpu_custom_call") == 8
     assert "expert_gating_bwd" in text
     buffers = re.compile(rf"= \w+\[(\d+,)?{n}(,\d+)?\]\S* (transpose|copy)\(")

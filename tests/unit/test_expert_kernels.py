@@ -113,7 +113,14 @@ HI = jax.lax.Precision.HIGHEST
 #: share -> (held, whether the router sends every token to experts 0, 1, 2)
 ROUTINGS = {"nothing": ((12, 4), True), "a quarter": ((4, 4), False),
             "everything": ((0, E), False),
-            "the worst imbalance": ((0, 4), True)}
+            "the worst imbalance": ((0, 4), True),
+            "32 of 256 at top 8, 512 wide": ((64, 32), False)}
+#: the shares that are not E experts D_FF wide at top TOPK by the softmax
+#: rule: (experts, top k, an expert's width, the routing rule's arguments);
+#: the gated mixed-window cell's regime, 32 held experts that each see a
+#: sixteenth of a tile's rows
+REGIMES = {"32 of 256 at top 8, 512 wide": (
+    256, 8, 512, ("sigmoid", False, True, 2.5))}
 PARTS = ["y", "x", "weights", "gate", "up", "down"]
 
 
@@ -144,16 +151,22 @@ def on_the_kernels():
     ks = jax.random.split(jax.random.PRNGKey(11), 6)
     x = jax.random.normal(ks[0], (T, D_MODEL))
     cot = jax.random.normal(ks[1], (T, D_MODEL))
-    full = [jax.random.normal(k, shape) * shape[1] ** -0.5 for k, shape in
-            zip(ks[2:5], [(E, D_MODEL, D_FF), (E, D_MODEL, D_FF),
-                          (E, D_FF, D_MODEL)])]
     out = {}
     try:
         for share, ((first, count), biased) in ROUTINGS.items():
-            logits = 2.0 * jax.random.normal(ks[5], (T, E))
+            n_experts, top_k, d_ff, rule = REGIMES.get(
+                share, (E, TOPK, D_FF, ()))
+            assert moe.grouped_matmul_impl(T * top_k, D_MODEL, d_ff) \
+                == "megablox"
+            full = [jax.random.normal(k, shape) * shape[1] ** -0.5
+                    for k, shape in zip(ks[2:5], [
+                        (n_experts, D_MODEL, d_ff), (n_experts, D_MODEL, d_ff),
+                        (n_experts, d_ff, D_MODEL)])]
+            logits = 2.0 * jax.random.normal(ks[5], (T, n_experts))
             if biased:
                 logits = logits + jnp.zeros((E,)).at[:TOPK].set(50.0)
-            weights, experts = moe.route_top_k(logits, TOPK)
+            weights, experts = moe.route_top_k(logits, top_k,
+                                               moe.RoutingRule(*rule))
             mats = [m[first:first + count] for m in full]
             for activation, act in ACTS.items():
                 def both(fn):

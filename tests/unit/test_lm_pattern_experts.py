@@ -18,26 +18,62 @@ from lm_pattern_cases import (D, E, F, HI, TOPK, _equations, ref_moe)
 # -- the expert layer ----------------------------------------------------------
 
 def expert_layer(held, logits_bias=None, seed=3, t=40, activation="relu"):
-    """(program's output and counts, reference's output) of one
-    DroplessMoE over ``held``, all shares from one set of weights."""
-    from metaopt_tpu.models.moe import DroplessMoE
+    """(program's output and counts, reference's output, the uncut
+    reference's) of one DroplessMoE over ``held``, all shares from one set
+    of weights."""
+    return _expert_layer(held, logits_bias, seed, t, activation)[:4]
+
+
+#: the laguna family's layer at this file's sizes: sigmoid scores
+#: normalised over the chosen and scaled, top 4, beside one shared expert
+GATED = {"top_k": 4, "normalised": True, "scale": 2.5}
+
+
+def _expert_layer(held, logits_bias=None, seed=3, t=40, activation="relu",
+                  gated=False):
+    """:func:`expert_layer`'s four and what every share computes alike (a
+    shared expert's output; 0 without one). ``gated``: the layer by
+    ``GATED``, its references chipbench/reference/gated_lm.py's."""
+    from metaopt_tpu.models.lm_layers import GatedFeedForward
+    from metaopt_tpu.models.moe import DroplessMoE, RoutingRule
 
     act = {"relu": jax.nn.relu, "silu": jax.nn.silu}[activation]
+    top_k = GATED["top_k"] if gated else TOPK
+    layer = lambda held: DroplessMoE(  # noqa: E731
+        D, F, E, top_k, held, activation,
+        RoutingRule("sigmoid", False, True, GATED["scale"]) if gated
+        else RoutingRule(), F if gated else 0)
 
     key = jax.random.PRNGKey(seed)
     x = jax.random.normal(key, (1, t, D))
     logits = 2.0 * jax.random.normal(jax.random.fold_in(key, 1), (1, t, E))
     if logits_bias is not None:
         logits = logits + logits_bias
-    whole = DroplessMoE(D, F, E, TOPK, (0, E))
-    full = nn.meta.unbox(whole.init(key, x, logits)["params"])
+    full = nn.meta.unbox(layer((0, E)).init(key, x, logits)["params"])
     first, count = held
-    mine = {k: v[first:first + count] for k, v in full.items()}
-    y, state = DroplessMoE(D, F, E, TOPK, held, activation).apply(
-        {"params": mine}, x, logits, mutable=["moe_stats"])
-    ref = ref_moe(x[0], logits[0], full_as_ref(mine), held, act)
-    return y[0], state["moe_stats"], ref, ref_moe(
-        x[0], logits[0], full_as_ref(full), (0, E), act)
+    mine = {k: v if k == "shared" else v[first:first + count]
+            for k, v in full.items()}
+    y, state = layer(held).apply({"params": mine}, x, logits,
+                                 mutable=["moe_stats"])
+    if not gated:
+        ref = ref_moe(x[0], logits[0], full_as_ref(mine), held, act)
+        return y[0], state["moe_stats"], ref, ref_moe(
+            x[0], logits[0], full_as_ref(full), (0, E), act), 0.0
+    from chipbench.reference import gated_lm as reference
+
+    def ref_gated(p, held):
+        cfg = {**GATED, "experts_held": list(held)}
+        each = {k: {f"e{e:02d}": p[k][e] for e in range(held[1])}
+                for k in ("gate", "up", "down")}
+        return reference._experts(
+            "float32", each, x[0], reference.routing_weights(logits[0], cfg),
+            held[0], act) + reference._gated("float32", p["shared"], x[0],
+                                             act)
+
+    alike = GatedFeedForward(D, F, activation).apply(
+        {"params": full["shared"]}, x)[0].astype(jnp.float32)
+    return (y[0], state["moe_stats"], ref_gated(mine, held),
+            ref_gated(full, (0, E)), alike)
 
 
 def full_as_ref(full):
@@ -53,19 +89,29 @@ def test_a_share_gives_its_own_experts_part(held):
     assert np.linalg.norm(y - ref) <= 0.02 * max(np.linalg.norm(ref), 1e-6)
 
 
-@pytest.mark.parametrize("shares, activation", [
-    (SHARES, "relu"), ([(first, 2) for first in range(0, E, 2)], "silu")],
-    ids=["four-shares-relu", "eight-shares-silu"])
-def test_the_shares_add_up_to_the_uncut_layer(shares, activation):
+@pytest.mark.parametrize("shares, activation, gated", [
+    (SHARES, "relu", False),
+    ([(first, 2) for first in range(0, E, 2)], "silu", False),
+    ([(first, 2) for first in range(0, E, 2)], "silu", True)],
+    ids=["four-shares-relu", "eight-shares-silu",
+         "eight-shares-sigmoid-top4-shared"])
+def test_the_shares_add_up_to_the_uncut_layer(shares, activation, gated):
     """16 experts, top 3, four shares of 4 (gated ReLU) or eight of 2
     (gated SiLU): the partial outputs sum to what the uncut reference
-    gives for the whole layer."""
-    parts = [expert_layer(held, activation=activation) for held in shares]
-    total = sum(p[0] for p in parts)
+    gives for the whole layer. And the laguna family's layer (sigmoid
+    scores normalised over all the chosen, times 2.5, top 4, beside a
+    shared expert): the eight shares' routed parts plus the shared expert,
+    which every share computes alike, counted once."""
+    parts = [_expert_layer(held, activation=activation, gated=gated)
+             for held in shares]
+    total = sum(p[0] for p in parts) - (len(parts) - 1) * parts[0][4]
     uncut = parts[0][3]
     assert np.linalg.norm(total - uncut) <= 0.02 * np.linalg.norm(uncut)
     # and no share is idle: each adds something of its own
-    assert all(np.linalg.norm(p[0]) > 0.05 * np.linalg.norm(uncut)
+    assert all(np.linalg.norm(p[0] - p[4]) > 0.05 * np.linalg.norm(uncut)
+               for p in parts)
+    # a share's own part is the reference's for that share
+    assert all(np.linalg.norm(p[0] - p[2]) <= 0.02 * np.linalg.norm(uncut)
                for p in parts)
 
 

@@ -53,6 +53,10 @@ def _specs():
             **grouped, "window": 4, "theta": None, "qk_norm": "whole"}), ()),
         "grouped-selected": (ll.GroupedSpec(**{
             **grouped, "selection": (2, 8, 4)}), ()),
+        "grouped-gated-yarn": (ll.GroupedSpec(**{
+            **grouped, "theta": 5e5, "qk_norm": "head", "gate": "sigmoid",
+            "rotary": ll.Rotary(5e5, 4, (64.0, 4096, 64.0, 1.0), 1.4159)}),
+            ()),
         "latent": (ll.LatentSpec(4, 16, 8, 4, 8, True, 1e4), ()),
         "linear": (ll.LinearSpec(2, 2, 8, 8, 4, True), ()),
         "ssm": (ll.StateSpaceSpec(64, 4, 4, 2), ()),
@@ -85,7 +89,8 @@ def _marked(jaxpr, found):
 
 
 @pytest.mark.parametrize("case", [
-    "grouped", "grouped-window-norms", "grouped-selected", "latent", "linear",
+    "grouped", "grouped-window-norms", "grouped-selected",
+    "grouped-gated-yarn", "latent", "linear",
     "ssm", "gmu", "differential", "differential-cross", "gated", "routed",
     "routed-early-router", "routed-shared-bias"])
 def test_a_spec_offers_the_names_its_module_marks(case):

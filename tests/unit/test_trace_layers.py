@@ -4,7 +4,7 @@ every operation the program writes has a layer (``layer_of``) and a
 direction (``direction``), in the forward, its second run under remat and
 the backward.
 
-The five steps are the benchmark's five model kinds at their rehearsal
+The steps are the benchmark's seven model kinds at their rehearsal
 sizes, lowered and not compiled. Each is lowered twice: as this backend
 routes it, and for the TPU with ``jax.default_backend`` answering "tpu", so
 that the Pallas kernels are on the path (lowering a kernel for the TPU needs
@@ -33,6 +33,7 @@ KINDS = {
     "hybrid-decoder": ("olmo-hybrid-7b-tp2", "hybrid_lm_config"),
     "latent-decoder": ("kanana-2-30b-a3b-ep8", "mla_lm_config"),
     "state-space-decoder": ("phi-4-mini-flash-vp8", "ssm_lm_config"),
+    "gated-decoder": ("laguna-xs2-33b-a3b-ep8", "gated_lm_config"),
 }
 #: no operation of the device: a literal, a function's end, and remat's own
 #: barrier around a block's kept values (jax names it ``.../remat2``)
@@ -183,7 +184,8 @@ def test_every_operation_of_a_train_step_has_a_layer(lowered_steps, kind,
 @pytest.mark.parametrize("kind, remat", [
     ("2017-base", False), ("pattern-decoder", True),
     ("selected-attention-decoder", True), ("hybrid-decoder", True),
-    ("latent-decoder", True), ("state-space-decoder", True)])
+    ("latent-decoder", True), ("state-space-decoder", True),
+    ("gated-decoder", True)])
 def test_a_step_has_operations_in_every_direction_it_runs(lowered_steps,
                                                          kind, remat):
     """Forward, backward and update in every step; the forward's second
@@ -221,12 +223,16 @@ def test_a_step_has_operations_in_every_direction_it_runs(lowered_steps,
     # two maps a layer of differential attention, three such layers
     ("state-space-decoder", "flash_fwd", 6, 0),
     ("state-space-decoder", "flash_bwd", 0, 6),
+    # two full and three window layers, one call each at its own head count
+    ("gated-decoder", "flash_fwd", 5, 0),
+    ("gated-decoder", "flash_bwd", 0, 5),
     # the embedding's gradient rule: one table, written once
     ("pattern-decoder", "embed_rows_bwd", 0, 1),
     ("selected-attention-decoder", "embed_rows_bwd", 0, 1),
     ("hybrid-decoder", "embed_rows_bwd", 0, 1),
     ("latent-decoder", "embed_rows_bwd", 0, 1),
     ("state-space-decoder", "embed_rows_bwd", 0, 1),
+    ("gated-decoder", "embed_rows_bwd", 0, 1),
 ])
 def test_a_kernel_s_calls_by_direction(lowered_steps, kind, kernel, forward,
                                        backward):
@@ -350,9 +356,9 @@ def test_the_two_rules_on_a_path(op_name, layer, direction):
 
 
 def test_the_layers_are_the_top_level_scopes_and_every_scope_has_one():
-    assert len(trace.SCOPES) == len(set(trace.SCOPES)) == 25
+    assert len(trace.SCOPES) == len(set(trace.SCOPES)) == 26
     assert {"attention.latent", "moe.shared", "ssm.core",
-            "attention.diff"} <= set(trace.SCOPES)
+            "attention.diff", "attention.gate"} <= set(trace.SCOPES)
     assert set(trace.LAYERS) == {
         "embed", "attention", "ffn", "moe", "linear_attention", "ssm", "gmu",
         "readout_xent", "optimizer", "eval", "norm", "residual", "loss"}
