@@ -54,6 +54,18 @@ def _mask_said(route: str, window: Optional[int]) -> str:
         f": causal, window {window}" if window else ": causal")
 
 
+def _kernels_said(step, window: Optional[int]) -> dict:
+    """Under ``kernels``, which causal kernels a layer under ``window``
+    takes on the Pallas route over rows of ``step.seq_len`` and the keys
+    they read a key seen (ops/window_attention.py::window_kernels, which
+    asks the rule the call asks); {} on another route or without a window."""
+    if step.route != "pallas" or not window or not step.seq_len:
+        return {}
+    from metaopt_tpu.ops.window_attention import window_kernels
+
+    return {"kernels": window_kernels(window, step.seq_len)}
+
+
 def _numbers(layers) -> list:
     return [layer.number for layer in layers]
 
@@ -297,7 +309,8 @@ class GroupedSpec(_Mixer):
     def describe(self, step, layers, sources):
         if not self.selection:
             said = {"route": step.route,
-                    "mask": _mask_said(step.route, self.window)}
+                    "mask": _mask_said(step.route, self.window),
+                    **_kernels_said(step, self.window)}
             if self.rotary or self.gate:  # where a field says more than the kind
                 said.update(
                     layers=_numbers(layers), heads=self.heads,
@@ -934,6 +947,7 @@ class DifferentialSpec(_Mixer):
     def describe(self, step, layers, sources):
         said = {"route": step.route,
                 "mask": _mask_said(step.route, self.window),
+                **_kernels_said(step, self.window),
                 "layers": _numbers(layers),
                 "differential": [self.heads // 2, self.kv_heads // 2,
                                  self.head_dim, 2 * self.head_dim]}

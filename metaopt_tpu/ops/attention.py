@@ -22,11 +22,11 @@ memory-efficient implementations share one shape of custom VJP:
     the kernel from positions, K/V heads fewer than query heads (query
     head h reads K/V head ``h // group`` through the block index, no
     copy), q.k and v each at a width of its own (a latent layer's 192 and
-    128), a program per (row, query head, tile) so that one head of the
-    other sequence is all that VMEM holds (bounded at S 8192 and 16 384),
-    and a walk that covers only the tiles the structure lets through:
-    tiles beyond the causal limit or the window are **skipped, not
-    masked**, in forward and backward.
+    128), a program per (row, query head, tile), one head of the other
+    sequence in VMEM (bounded at S 8192 and 16 384), and a walk over the
+    tiles the structure lets through alone: the others are **skipped, not
+    masked**, in forward and backward. Under a window no wider than a tile
+    the walk's sibling: one slab a sub-tile (ops/window_attention.py).
 
 - ``impl="chunked"`` — the same blocked online-softmax as a ``lax.scan``
   over K blocks in plain XLA. Live tiles are O(Sq·block_k), never
@@ -443,9 +443,9 @@ def _pallas_backward(q, k, v, mask, out, lse, g, block_q, block_k, interpret):
 # index, so grouped heads cost no copy, and what is resident in VMEM is one
 # head of the other sequence: (D, S), 2 MiB at S 8192 and D 128, whatever
 # the number of heads. (3) The walk over the resident sequence covers only
-# the tiles the structure lets through: tiles beyond the causal limit or
-# the window are skipped, not masked, and of those walked only the ones the
-# diagonal or the window's edge crosses pay for the iota compare.
+# the tiles the structure lets through: the others are skipped, not masked,
+# and of those walked only the ones an edge crosses pay for the iota compare.
+# (3') A window no wider than a tile: ops/window_attention.py, no walk at all.
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1048,10 +1048,10 @@ def flash_attention(
         if s_p % bk:
             raise ValueError(f"block_k {bk} does not divide the padded "
                              f"length {s_p}")
-        narrow = _narrowest(q, k, v)
-        pad = lambda x: jnp.pad(  # noqa: E731
-            x.astype(narrow), ((0, 0), (0, s_p - sq), (0, 0), (0, 0)))
-        out = _flash_causal(pad(q), pad(k), pad(v), mask.window, bq, bk,
+        flash_kernels = _causal_kernels(mask.window, bq, bk, s_p)
+        pad = lambda x: jnp.pad(x.astype(_narrowest(q, k, v)), (  # noqa: E731
+            (0, 0), (0, s_p - sq), (0, 0), (0, 0)))
+        out = flash_kernels(pad(q), pad(k), pad(v), mask.window, bq, bk,
                             bool(interpret))
         return out[:, :sq].astype(q.dtype)
     # the paths below know one head count and a dense mask
@@ -1225,3 +1225,17 @@ def attend(q, k, v, mask=None, *, dropout_rate: float = 0.0,
             dropout_key=dropout_key, impl=route)
     return flash_attention(q, k, v, mask, dropout_rate=dropout_rate,
                            dropout_key=dropout_key, impl=route)
+
+
+def _causal_kernels(window, block_q: int, block_k: int, length: int):
+    """The kernels a :class:`CausalMask` call takes, as ``_flash_causal``
+    is called: the walk over the tiles the structure lets through, or, for
+    a window no wider than a tile, the slab kernels
+    (ops/window_attention.py::slab_sub is the one rule; down here because
+    a moved line above :func:`attend` moves every kernel's body)."""
+    if window is not None and block_q == block_k:
+        from metaopt_tpu.ops import window_attention
+
+        if window_attention.slab_sub(window, block_q, length):
+            return window_attention.flash_window
+    return _flash_causal

@@ -432,6 +432,17 @@ def _scores_form(said: dict) -> str:
     return f"pallas, tiles {tq} x {tk}, passes {said['depth']} deep"
 
 
+def _kernels_form(said: Optional[dict]) -> str:
+    """``ops/window_attention.py::window_kernels``'s answer in words ("" for
+    a layer without a window, or a trace from before it was said)."""
+    if not said:
+        return ""
+    form = (f"one slab of keys a sub-tile of {said['sub']}"
+            if said["kernels"] == "slab" else "a walk over the tiles seen")
+    return (f", kernels {form} in tiles of {said['tile']}, "
+            f"{said['walked_over_seen']:g} keys read a key seen")
+
+
 #: ``ops/latent_attention.py::hand_over``'s answer in words
 _HAND_OVER = {
     "copies": "",
@@ -531,7 +542,8 @@ def print_routes(recs: List[dict]) -> None:
                 print(f"trial {r['trial']}: {kind} layers: {how['route']}, "
                       f"mask by {how['mask']}" + (
                           f", index scores by {_scores_form(scores)}"
-                          if scores else "") + (
+                          if scores else "") + _kernels_form(
+                              how.get("kernels")) + (
                           f"; layers {_runs(how['layers'])} differential: "
                           f"{pairs[0]} query pairs on {pairs[1]} K/V pairs, "
                           f"q\u00b7k {pairs[2]}, v {pairs[3]}" + (

@@ -680,15 +680,28 @@ STRUCTURAL = [
     (384, 130, 2, 1, 128, 128), (100, 40, 2, 1, 128, None),
     (640, 257, 2, 1, 128, None),
 ]
+# a window no wider than the tile: the slab kernels (ops/window_attention.py).
+# Lengths of one tile (below window + sub), of two (window + sub itself) and
+# of several, most of them padded; the two cells' groups of heads (64 on 8,
+# 20 on 10) and widths (q.k and v 128; q.k 64 beside v 128: a head width is
+# a number or (q.k, v)); the window as wide as the tile and narrower
+SLAB = [
+    (128, 128, 2, 1, 128, 128), (256, 128, 4, 2, (64, 128), 128),
+    (200, 128, 64, 8, 128, 128), (300, 128, 20, 10, (64, 128), 128),
+    (700, 256, 4, 2, (64, 128), 256), (1100, 512, 2, 1, 128, 512),
+    (1536, 256, 2, 2, 128, 512), (1030, 512, 2, 1, (64, 128), 512),
+]
+STRUCTURAL += SLAB
 
 
 def _structural_case(s, window, h, hkv, d, tile, seed=0):
     key = jax.random.PRNGKey(seed)
     kq, kk, kv, kg = jax.random.split(key, 4)
+    d, dv = d if isinstance(d, tuple) else (d, d)
     q = jax.random.normal(kq, (2, s, h, d)) / np.sqrt(d)
     k = jax.random.normal(kk, (2, s, hkv, d))
-    v = jax.random.normal(kv, (2, s, hkv, d))
-    g = jax.random.normal(kg, (2, s, h, d))
+    v = jax.random.normal(kv, (2, s, hkv, dv))
+    g = jax.random.normal(kg, (2, s, h, dv))
     kernel = lambda q, k, v: flash_attention(  # noqa: E731
         q, k, v, CausalMask(window), impl="pallas", interpret=True,
         block_q=tile, block_k=tile)
@@ -697,7 +710,8 @@ def _structural_case(s, window, h, hkv, d, tile, seed=0):
     return (q, k, v, g), kernel, oracle
 
 
-_case_id = lambda c: "x".join(map(str, c))  # noqa: E731
+_case_id = lambda c: "x".join(  # noqa: E731
+    "-".join(map(str, x)) if isinstance(x, tuple) else str(x) for x in c)
 
 
 class TestStructuralMask:

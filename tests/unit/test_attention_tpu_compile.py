@@ -110,13 +110,30 @@ UNEQUAL = [(16384, 192, 128, 32, 32), (32768, 192, 128, 32, 32),
 # (length, window): one map of the state-space cell's differential layers,
 # 20 query heads on 10 K/V heads at q.k 64 and the pair's joined v 128
 DIFFERENTIAL = [(8192, 512), (8192, None)]
+# (length, window, query heads, K/V heads, q.k, v, the kernels' entry): the
+# differential maps; the seventh cell's window layer, which with the window
+# map above takes the slab kernels (ops/window_attention.py); the 8k cell's
+# window of 4096, eight tiles wide, which keeps the walk
+WINDOWED = [(s, window, 20, 10, 64, 128,
+             "flash_window" if window else "_flash_causal")
+            for s, window in DIFFERENTIAL] + [
+    (8192, 512, 64, 8, 128, 128, "flash_window"),
+    (8192, 4096, 28, 4, 128, 128, "_flash_causal")]
 
 
-@pytest.mark.parametrize("case", DIFFERENTIAL, ids=str)
-def test_a_differential_map_compiles_for_a_v5e(one_chip, case):
+@pytest.mark.parametrize("case", WINDOWED,
+                         ids=lambda c: "x".join(map(str, c[:-1])))
+def test_a_layer_under_a_window_compiles_for_a_v5e(one_chip, monkeypatch,
+                                                   case):
     """q_i on k_i and the joined v as ``models/lm.py::DifferentialAttention``
-    hands them over: grouped, unequal widths, under the window and without."""
-    s, window = case
+    hands them over (grouped, unequal widths, under the window and without)
+    and the two decoder cells' window layers: whichever kernels the call
+    takes, one ``flash_fwd`` and one ``flash_bwd`` a layer a pass, which is
+    what the benchmark's readers divide the work by."""
+    from test_window_kernels import _taken
+
+    s, window, h, hkv, dqk, dv, entry = case
+    taken = _taken(monkeypatch)
     shape = lambda *dims: jax.ShapeDtypeStruct(  # noqa: E731
         dims, jnp.bfloat16, sharding=one_chip)
 
@@ -125,8 +142,9 @@ def test_a_differential_map_compiles_for_a_v5e(one_chip, case):
         return jnp.sum(out.astype(jnp.float32) ** 2)
 
     text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
-        shape(1, s, 20, 64), shape(1, s, 10, 64),
-        shape(1, s, 10, 128)).compile().as_text()
+        shape(1, s, h, dqk), shape(1, s, hkv, dqk),
+        shape(1, s, hkv, dv)).compile().as_text()
+    assert taken == [entry]
     assert text.count("tpu_custom_call") == 2  # flash_fwd, flash_bwd
     assert "flash_fwd" in text and "flash_bwd" in text
 

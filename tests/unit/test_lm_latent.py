@@ -529,7 +529,7 @@ def _equations(jaxpr):
 
 
 @pytest.mark.parametrize("kind, window, theta", [
-    ("global-nope", None, None), ("window-rope", 128, 1e6)])
+    ("global-nope", None, None), ("window-rope", 384, 1e6)])
 def test_a_grouped_layer_traces_to_the_program_it_traced_to(monkeypatch, kind,
                                                             window, theta):
     """The 8k decoder's and the hybrid's layer (``GroupedAttention`` through
@@ -538,7 +538,11 @@ def test_a_grouped_layer_traces_to_the_program_it_traced_to(monkeypatch, kind,
     bodies and index maps included (primitive, a call's name, grid and
     compiler parameters, the results' types) are the ones PR 37's tree
     traced, by their count and hash: a latent layer's hand-over is another
-    entry, not another form of this one."""
+    entry, not another form of this one. The window is wider than the
+    rows' one tile of 256, as the 8k decoder's 4096 is than its tiles of
+    512: the walk's kernels, whose trace no window's width changes (128
+    until PR 46, which gave a window no wider than the tile kernels of its
+    own: ``tests/unit/test_window_kernels.py``)."""
     import hashlib
 
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
