@@ -307,10 +307,14 @@ class GroupedSpec(_Mixer):
             "-nope" if self.theta is None else "-rope")
 
     def describe(self, step, layers, sources):
+        from metaopt_tpu.ops.grouped_hand_over import hand_over
+
+        q_and_k = {"hand_over": hand_over(
+            step.route, step.mesh, self.qk_norm, self.theta is not None)}
         if not self.selection:
             said = {"route": step.route,
                     "mask": _mask_said(step.route, self.window),
-                    **_kernels_said(step, self.window)}
+                    **_kernels_said(step, self.window), **q_and_k}
             if self.rotary or self.gate:  # where a field says more than the kind
                 said.update(
                     layers=_numbers(layers), heads=self.heads,
@@ -324,7 +328,7 @@ class GroupedSpec(_Mixer):
         heads, width, top_k = self.selection
         said = {"route": step.route,
                 "mask": f"selected: causal, top {top_k} of the index "
-                        f"scores, {heads} index heads"}
+                        f"scores, {heads} index heads", **q_and_k}
         if step.seq_len:
             said["index_scores"] = scores_of_a_row(step.seq_len, width)
         return said
@@ -342,7 +346,12 @@ class GroupedAttention(nn.Module):
     the headwise form of arXiv:2505.06708; float32 at matmul precision
     highest, as a linear layer's gates are), under the scope
     ``attention.gate``. The projections' products carry the names of the
-    spec's ``KEPT``: identities unless a block's policy asks for them."""
+    spec's ``KEPT``: identities unless a block's policy asks for them.
+
+    From q's and k's products to attention's operands (the q/k norms,
+    rotary by the spec's rule, q over sqrt(head_dim), one rounding) is
+    :func:`_handed_over`'s: one Pallas call an operand and direction where
+    ops/grouped_hand_over.hand_over says so, else XLA's passes."""
 
     d_model: int
     spec: GroupedSpec
@@ -362,16 +371,7 @@ class GroupedAttention(nn.Module):
         q, k, v = (checkpoint_name(proj("q", sp.heads)(x), kept["q"]),
                    checkpoint_name(proj("k", sp.kv_heads)(x), kept["k"]),
                    checkpoint_name(proj("v", sp.kv_heads)(x), kept["v"]))
-        if sp.qk_norm is not None:
-            whole = lambda y: y.reshape(  # noqa: E731
-                *y.shape[:2], -1) if sp.qk_norm == "whole" else y
-            q = RMSNorm(self.eps, name="q_norm")(whole(q)).reshape(q.shape)
-            k = RMSNorm(self.eps, name="k_norm")(whole(k)).reshape(k.shape)
-        if sp.theta is not None:
-            q, k = rope(q, sp.rule), rope(k, sp.rule)
-        q = (q / math.sqrt(sp.head_dim)).astype(jnp.bfloat16)
-        k = k.astype(jnp.bfloat16)
-        out = attend(q, k, v, mask)
+        out = attend(*_handed_over(self, q, k), v, mask)
         if sp.gate is not None:
             with trace.scope("attention.gate"):
                 g = checkpoint_name(nn.DenseGeneral(
@@ -1011,3 +1011,53 @@ class DifferentialAttention(nn.Module):
             self.d_model, axis=(-2, -1), dtype=jnp.bfloat16, name="out",
             kernel_init=_pinit(True, ("tp", None, None)),
         )(out.astype(jnp.bfloat16)), kept["out"]), kv
+
+
+# ---------------------------------------------------------------------------
+# A grouped layer's q and k, from the projections' products to attention's
+# operands. Down here because a line that moves above ``LatentSpec`` moves
+# the latent layer's frames above its Pallas calls, and with them that
+# cell's compiled kernels (ROADMAP S11).
+
+
+class NormScale(nn.Module):
+    """An RMS norm's scale vector alone, under the name and at the path
+    :class:`RMSNorm` gives it, for a norm that a kernel applies."""
+
+    @nn.compact
+    def __call__(self, width: int):
+        return self.param("scale", nn.initializers.ones, (width,))
+
+
+def _handed_over(layer: GroupedAttention, q, k):
+    """(q, k) as attention takes them, bfloat16, from the products of
+    ``layer``'s projections: the RMS norms its spec says, rotary by its
+    rule, q over sqrt(head_dim), float32 until one rounding. How is
+    ops/grouped_hand_over.hand_over's to say: ``"one pass"``, a Pallas call
+    an operand and direction, or ``"passes"``, XLA's elementwise passes,
+    which is also the tests' oracle for the first."""
+    from metaopt_tpu.ops import grouped_hand_over as gh
+    from metaopt_tpu.parallel.mesh import active_mesh
+
+    sp, mesh = layer.spec, active_mesh()
+    if gh.hand_over(attention_route(0.0, mesh), mesh, sp.qk_norm,
+                    sp.theta is not None) == "one pass":
+        scale = lambda name: NormScale(name=name)(  # noqa: E731
+            sp.head_dim) if sp.qk_norm else None
+        cos = sin = None
+        if sp.rule:
+            cos, sin = gh.tables(
+                sp.rule.frequencies(sp.rule.turned or sp.head_dim),
+                sp.rule.factor, q.shape[1])
+        return (gh.operand(q, scale("q_norm"), cos, sin, layer.eps,
+                           1.0 / math.sqrt(sp.head_dim)),
+                gh.operand(k, scale("k_norm"), cos, sin, layer.eps, 1.0))
+    if sp.qk_norm is not None:
+        whole = lambda y: y.reshape(  # noqa: E731
+            *y.shape[:2], -1) if sp.qk_norm == "whole" else y
+        q = RMSNorm(layer.eps, name="q_norm")(whole(q)).reshape(q.shape)
+        k = RMSNorm(layer.eps, name="k_norm")(whole(k)).reshape(k.shape)
+    if sp.theta is not None:
+        q, k = rope(q, sp.rule), rope(k, sp.rule)
+    return ((q / math.sqrt(sp.head_dim)).astype(jnp.bfloat16),
+            k.astype(jnp.bfloat16))

@@ -443,8 +443,12 @@ def _kernels_form(said: Optional[dict]) -> str:
             f"{said['walked_over_seen']:g} keys read a key seen")
 
 
-#: ``ops/latent_attention.py::hand_over``'s answer in words
+#: ``ops/latent_attention.py::hand_over``'s answer in words, and
+#: ``ops/grouped_hand_over.py::hand_over``'s (a trace from before it was
+#: said has neither)
 _HAND_OVER = {
+    None: "", "passes": "",
+    "one pass": ", q and k from their products in one pass a direction",
     "copies": "",
     "in place": (", the kernels reading q after one pass, K, V and out "
                  "where the matmuls leave them, the shared key joined in "
@@ -543,7 +547,8 @@ def print_routes(recs: List[dict]) -> None:
                       f"mask by {how['mask']}" + (
                           f", index scores by {_scores_form(scores)}"
                           if scores else "") + _kernels_form(
-                              how.get("kernels")) + (
+                              how.get("kernels")) + _HAND_OVER[
+                                  how.get("hand_over")] + (
                           f"; layers {_runs(how['layers'])} differential: "
                           f"{pairs[0]} query pairs on {pairs[1]} K/V pairs, "
                           f"q\u00b7k {pairs[2]}, v {pairs[3]}" + (

@@ -101,11 +101,12 @@ def _on_the_kernels(patch):
     """The Pallas route as the chip takes it, interpreted here: the backend
     reads as the TPU, and the kernels' entry runs the interpreter on float32
     operands (inside the structural kernels' loops this CPU's dot takes no
-    pair of bfloat16); the embedding's sorted gradient rule likewise."""
+    pair of bfloat16); the embedding's sorted gradient rule and the grouped
+    layers' hand-over of q and k likewise."""
     import functools
 
     from metaopt_tpu.models import lm
-    from metaopt_tpu.ops import attention, embed
+    from metaopt_tpu.ops import attention, embed, grouped_hand_over
 
     real = attention.flash_attention
 
@@ -117,3 +118,5 @@ def _on_the_kernels(patch):
     patch.setattr(attention, "flash_attention", interpreted)
     patch.setattr(lm, "embed_rows", functools.partial(
         embed.embed_rows, interpret=True))
+    patch.setattr(grouped_hand_over, "operand", functools.partial(
+        grouped_hand_over.operand, interpret=True))

@@ -219,6 +219,68 @@ def test_a_latent_layer_s_hand_over_compiles_for_a_v5e(one_chip, monkeypatch,
         rf" = f32\[1,(?:{heads * 192},{s}|{s},{heads},192)\]", entry)
 
 
+# (length, width, query heads, K/V heads, window, q/k norm, rotary rule): the
+# gated cell's window and full layers (a norm a head; the plain rule on the
+# whole head, YaRN on its first half), the 8k decoder's window layer (no
+# norm: the turn and the scale alone), the selected cell's heads at 16 384
+# under a causal mask, and a length no tile divides
+GROUPED = [(8192, 2048, 64, 8, 512, "head", (1e4, None, None, 1.0)),
+           (8192, 2048, 48, 8, None, "head",
+            (5e5, 64, (64.0, 4096, 64.0, 1.0), 1.4158883083359672)),
+           (8192, 2560, 28, 4, 4096, None, (1.5e6, None, None, 1.0)),
+           (16384, 2048, 32, 4, None, "head", (1e7, None, None, 1.0)),
+           (1000, 256, 4, 2, None, "head", (1e4, 64, None, 1.0))]
+
+
+@pytest.mark.parametrize("case", GROUPED, ids=lambda c: "x".join(
+    str(v) for v in c[:6]))
+def test_a_grouped_layer_s_hand_over_compiles_for_a_v5e(one_chip, monkeypatch,
+                                                        case):
+    """models/lm_layers.GroupedAttention on the Pallas route, its gradient
+    under the block's remat policy: q and k reach the kernels through
+    ``grouped_qk``'s one pass (forward, again, backward: 4 + 2 calls), the
+    kernels read its result where it lies (no transposed and no other copy
+    of an activation as large as q or k), and no float32 array as large
+    as q is left in the compiled program."""
+    import re
+
+    from metaopt_tpu.models import lm_layers
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    s, d_model, heads, kv, window, qk_norm, rule = case
+    spec = lm_layers.GroupedSpec(heads, kv, 128, window, rule[0], qk_norm,
+                                 None, lm_layers.Rotary(*rule))
+    layer = lm_layers.GroupedAttention(d_model, spec, 1e-6)
+    on_chip = lambda x: jax.ShapeDtypeStruct(  # noqa: E731
+        x.shape, x.dtype, sharding=one_chip)
+    x = on_chip(jax.ShapeDtypeStruct((1, s, d_model), jnp.float32))
+    params = jax.tree.map(on_chip, jax.eval_shape(lambda: layer.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, s, d_model)))["params"]))
+    policy = jax.checkpoint_policies.save_only_these_names(
+        "attention.out", "attention.lse", *spec.KEPT.values())
+
+    def loss(p, x):
+        out = jax.checkpoint(lambda p, x: layer.apply({"params": p}, x),
+                             policy=policy)(p, x)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        params, x).compile().as_text()
+    calls = lambda name: len(re.findall(  # noqa: E731
+        rf'custom_call_target="tpu_custom_call".*/{name}/pallas_call', text))
+    assert (calls("flash_fwd"), calls("flash_bwd"), calls("grouped_qk"),
+            calls("grouped_qk_bwd")) == (1, 1, 4, 2)
+    entry = text[text.index("ENTRY"):]
+    sized = "|".join(rf"{s},{n},128|{n * 128},{s}" for n in (heads, kv))
+    # (the backward kernel's own float32 dK and dV a query head are read
+    # out of its tuple; a copy into fast memory, S(1), moves no layout)
+    assert not re.findall(
+        rf" = f32\[1,(?:{sized})\]\S* (?!get-tuple-element)\w", entry)
+    assert not [line for line in re.findall(
+        rf" = (bf16\[1,(?:{sized})\]\S*) (?:copy|transpose)\(", entry)
+        if "S(1)" not in line]
+
+
 # (length, query heads, K/V heads, head width): the selected-attention
 # cell's 16384 tokens (a program holds one (128, 16384) head of K and V and
 # a 1 MiB slab of the packed selection), a length that pads to 256-tiles,

@@ -206,6 +206,63 @@ def test_the_two_sides_name_the_same_leaves(both_sides):
     assert not any("choice_bias" in name for name in names)
 
 
+# -- q and k from their products in one pass ----------------------------------
+
+@pytest.fixture(scope="module")
+def handed_over(both_sides):
+    """{form: (loss, gradients)} of the program on the Pallas route,
+    interpreted, from ``both_sides``' weights and rows: q and k by the one
+    Pallas call a direction (ops/grouped_hand_over.py: the norm a head,
+    YaRN on half a head or the plain rule on the whole, q's scale) and by
+    XLA's passes, every other line the same; and what the span says of
+    each kind either way."""
+    from lm_pattern_cases import _on_the_kernels
+
+    from chipbench import weights_lm
+    from metaopt_tpu.ops import grouped_hand_over
+
+    _, _, whole, tokens = both_sides
+    model, params = lm.make_lm(description()), weights_lm.stacked(whole)
+    run = lambda: jax.value_and_grad(lambda p: lm.lm_loss_fn(  # noqa: E731
+        model, p, tokens, jax.random.PRNGKey(0)))(params)
+    said = lambda: {kind: how["hand_over"] for kind, how in (  # noqa: E731
+        lm_description.describe_pattern(
+            description(), "pallas", tokens=2 * S,
+            seq_len=S)["attention_layers"].items())}
+    out, patch = {}, pytest.MonkeyPatch()
+    try:
+        _on_the_kernels(patch)
+        out["one pass"], out["said"] = run(), said()
+        patch.setattr(grouped_hand_over, "hand_over", lambda *a: "passes")
+        out["passes"], out["said otherwise"] = run(), said()
+    finally:
+        patch.undo()
+    return out
+
+
+def test_both_kinds_of_layer_hand_over_in_one_pass(handed_over):
+    assert handed_over["said"] == {"global-rope": "one pass",
+                                   "window-rope": "one pass"}
+    assert set(handed_over["said otherwise"].values()) == {"passes"}
+
+
+def test_the_hand_over_in_one_pass_changes_no_loss(handed_over):
+    one, passes = handed_over["one pass"][0], handed_over["passes"][0]
+    assert abs(float(one) - float(passes)) <= 2e-3 * abs(float(passes))
+
+
+@pytest.mark.parametrize("path", LEAVES)
+def test_the_hand_over_in_one_pass_changes_no_gradient(handed_over, path):
+    """To the shares the plain reference is held to above, the q and k
+    norms' scales, whose gradient the backward call sums, among them."""
+    from chipbench import weights_lm
+
+    p, r = (leaf(weights_lm.split(handed_over[form][1]), path)
+            for form in ("one pass", "passes"))
+    share = 0.33 if "router" in path or "norm_post" in path else 0.17
+    assert close(p, r, share), np.linalg.norm(p - r) / np.linalg.norm(r)
+
+
 # -- planted faults ------------------------------------------------------------
 
 @pytest.mark.parametrize("fault", [
@@ -481,6 +538,8 @@ def test_the_setup_span_says_heads_rotary_rule_and_gate_a_kind(trial, capsys):
         "yarn 500000 x64 over 4096, 8 of 16, cos and sin x 1.4159")
     assert said["window-rope"]["rotary"] == "plain 10000, 16 of 16"
     assert said["window-rope"]["gate"] == "sigmoid a head"
+    # off the TPU the reference takes q and k as XLA's passes make them
+    assert {how["hand_over"] for how in said.values()} == {"passes"}
     held = setup["attrs"]["moe"]
     assert (held["routed_over"], held["top_k"], held["held"]) \
         == (16, 4, [8, 8])

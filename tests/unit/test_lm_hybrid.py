@@ -830,7 +830,8 @@ def test_train_lm_says_which_layers_are_linear_and_what_a_block_keeps():
     assert np.isfinite(loss)
     setup = trace.spans("trial.setup")[-1]["attrs"]
     assert setup["attention_layers"] == {
-        "global-nope": {"route": "reference", "mask": "dense: causal"},
+        "global-nope": {"route": "reference", "mask": "dense: causal",
+                        "hand_over": "passes"},
         "linear": {"route": "xla", "chunk": CHUNK, "layers": [0],
                    "heads": [HELD, HEADS], "key_dim": KD, "value_dim": VD,
                    "conv": 4}}

@@ -542,10 +542,17 @@ def test_a_grouped_layer_traces_to_the_program_it_traced_to(monkeypatch, kind,
     rows' one tile of 256, as the 8k decoder's 4096 is than its tiles of
     512: the walk's kernels, whose trace no window's width changes (128
     until PR 46, which gave a window no wider than the tile kernels of its
-    own: ``tests/unit/test_window_kernels.py``)."""
+    own: ``tests/unit/test_window_kernels.py``). q and k reach the kernels
+    by XLA's passes here, the form PR 37's tree had and a layer with a norm
+    over the whole width still takes; the rotary layer's one pass (PR 47)
+    is ``tests/unit/test_grouped_hand_over.py``'s."""
     import hashlib
 
+    from metaopt_tpu.ops import grouped_hand_over
+
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(grouped_hand_over, "hand_over",
+                        lambda *a: "passes")
     layer = lm_layers.GroupedAttention(64, lm_layers.GroupedSpec(
         4, 2, 32, window, theta, None, None), 1e-6)
     x = jnp.zeros((2, 256, 64))

@@ -121,6 +121,70 @@ def test_a_whole_period_s_gradients_match_layer_by_layer(both_sides, layer):
         assert np.linalg.norm(p - r) <= 0.12 * np.linalg.norm(r), path
 
 
+@pytest.fixture(scope="module")
+def handed_over():
+    """{kind: {form: (loss, gradients)}} on the Pallas route, interpreted:
+    q and k from their products by the one Pallas call a direction
+    (ops/grouped_hand_over.py; the rotary layers engage it, a layer with
+    neither norm nor positions has nothing for it) and by XLA's passes,
+    every other line of the program the same."""
+    from lm_pattern_cases import _on_the_kernels
+    from metaopt_tpu.models import lm_description
+    from metaopt_tpu.models.lm import lm_loss_fn, make_lm
+    from metaopt_tpu.ops import grouped_hand_over
+
+    tokens = jax.random.randint(jax.random.PRNGKey(5), (2, S + 1), 2, V)
+    out, patch = {}, pytest.MonkeyPatch()
+    try:
+        _on_the_kernels(patch)
+        for kind, layers in [("window-rope", [KINDS["window-rope"]]),
+                             ("period", [(0, 0), (1, 1), (1, 1), (1, 1)])]:
+            model = make_lm(description(layers))
+            params = seeded(model, tokens)
+            run = lambda: jax.value_and_grad(lambda p: lm_loss_fn(  # noqa: E731
+                model, p, tokens, jax.random.PRNGKey(0)))(params)
+            said = lambda: {k: v["hand_over"] for k, v in (  # noqa: E731
+                lm_description.describe_pattern(
+                    description(layers), "pallas", tokens=2 * S,
+                    seq_len=S)["attention_layers"].items())}
+            out[kind] = {"one pass": run(), "said": said()}
+            with pytest.MonkeyPatch.context() as inner:
+                inner.setattr(grouped_hand_over, "hand_over",
+                              lambda *a: "passes")
+                out[kind]["passes"] = run()
+                assert set(said().values()) == {"passes"}
+    finally:
+        patch.undo()
+    return out
+
+
+def test_the_span_says_which_layers_hand_over_in_one_pass(handed_over):
+    assert handed_over["window-rope"]["said"] == {"window-rope": "one pass"}
+    assert handed_over["period"]["said"] == {
+        "global-nope": "passes", "window-rope": "one pass"}
+
+
+@pytest.mark.parametrize("kind", ["window-rope", "period"])
+def test_the_hand_over_in_one_pass_changes_no_loss(handed_over, kind):
+    (one, _), (passes, _) = (handed_over[kind][form]
+                             for form in ("one pass", "passes"))
+    assert abs(float(one) - float(passes)) <= 2e-3 * abs(float(passes))
+
+
+@pytest.mark.parametrize("path", LEAVES)
+@pytest.mark.parametrize("kind", ["window-rope", "period"])
+def test_the_hand_over_in_one_pass_changes_no_gradient(handed_over, kind,
+                                                       path):
+    """To the limit the reference is held to above; the period's first
+    rotary layer is layer 1."""
+    (_, one), (_, passes) = (handed_over[kind][form]
+                             for form in ("one pass", "passes"))
+    if kind == "period":
+        path = path.replace("h0", "h1")
+    p, r = leaf(one, path), leaf(passes, path)
+    assert np.linalg.norm(p - r) <= 0.05 * np.linalg.norm(r), path
+
+
 def test_window_and_global_layers_differ_and_a_late_token_is_unseen():
     """Poking a token changes later positions' logits inside the window and
     leaves those beyond it alone (one window layer sees no further)."""
@@ -199,9 +263,11 @@ def test_train_lm_reports_the_routing_s_counts_once(tmp_path):
     setup = trace.spans("trial.setup")[-1]["attrs"]
     assert setup["attention"]["dropout"] == 0.0
     assert setup["attention_layers"] == {
-        "global-nope": {"route": "reference", "mask": "dense: causal"},
+        "global-nope": {"route": "reference", "mask": "dense: causal",
+                        "hand_over": "passes"},
         "window-rope": {"route": "reference",
-                        "mask": f"dense: causal, window {WINDOW}"}}
+                        "mask": f"dense: causal, window {WINDOW}",
+                        "hand_over": "passes"}}
     assert setup["moe"] == {"routed_over": E, "top_k": TOPK, "held": [4, 8],
                             "products": "ragged_dot",
                             "experts": {

@@ -296,7 +296,7 @@ def test_train_lm_says_which_layers_select_and_counts_their_pairs():
         "route": "reference",
         "mask": f"selected: causal, top {KEYS} of the index scores, "
                 f"{IH} index heads",
-        "index_scores": {"route": "xla"}}}
+        "hand_over": "passes", "index_scores": {"route": "xla"}}}
     assert setup["remat"]["keeps"] == ["attention.out", "attention.lse",
                                        "attention.selected"]
     assert setup["moe"]["top_k"] == TOPK
