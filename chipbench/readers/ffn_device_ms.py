@@ -1,8 +1,14 @@
-"""Device milliseconds a step of the layer ``ffn`` in the 2017 cell: the
-two dense products of every layer's ``FeedForward`` and the ReLU, forward
-and backward, with AdamW's update where XLA fuses it into a weight-gradient
-matmul. (The hybrid decoder's cell reports its gated feed-forward as
-``hybrid_lm_ffn_device_ms``; the MoE decoders run no dense one.)"""
+"""Device milliseconds a step of the LAYER ``ffn`` (``trace.layer_of``: the
+outermost scope owns an operation): every dense feed-forward that is a
+block's own, forward, its second run under remat and backward, with
+AdamW's update where XLA fuses it into a weight-gradient matmul. The 2017
+cell's two products and ReLU a layer; a pattern decoder's gated
+feed-forward (``models/lm_layers.py::GatedFeedForward``) in every block of
+the hybrid and the state-space cells and in the leading dense layer alone
+of the latent and the gated cells: their shared experts run the same
+module under ``moe.shared`` and are ``moe``'s, so a union over the SCOPE
+``ffn`` would count them twice in the step's partition. The two 8k/16k
+MoE decoders run no dense feed-forward and do not list this metric."""
 
 from chipbench import layer_trace
 

@@ -1,7 +1,9 @@
 """% of its roofline the backward attention kernel ``flash_bwd`` reached in
-the traced slice: the operations and bytes its calls need
-(chipbench/flops_lm.py: five products a seen pair) over their device time
-and the chip's peaks (chipbench/kernel_trace.py)."""
+the traced slice: the operations and bytes its calls need, as the cell's
+runner counts them into ``kernel_work`` (five products a seen pair, each
+layer or call at its own widths and mask: chipbench/flops_lm.py,
+flops_hybrid_lm.py, flops_mla_lm.py, flops_ssm_lm.py), over their device
+time and the chip's peaks (chipbench/kernel_trace.py)."""
 
 from chipbench import kernel_trace
 

@@ -1,7 +1,11 @@
 """% of its roofline the forward attention kernel ``flash_fwd`` reached in
-the traced slice: the operations and bytes its calls need
-(chipbench/flops_lm.py: the seen pairs only) over their device time and
-the chip's peaks (chipbench/kernel_trace.py)."""
+the traced slice: the operations and bytes its calls need, as the cell's
+runner counts them into ``kernel_work`` (the seen pairs only, each layer
+or call at its own widths and mask: chipbench/flops_lm.py,
+flops_hybrid_lm.py, flops_mla_lm.py, flops_ssm_lm.py), over their device
+time and the chip's peaks (chipbench/kernel_trace.py). A cell whose kinds
+of layer differ in work a call reads a share a kind instead
+(chipbench/gated_kernel_trace.py)."""
 
 from chipbench import kernel_trace
 

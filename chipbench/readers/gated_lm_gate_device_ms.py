@@ -3,7 +3,7 @@ on attention's output (``models/lm_layers.py::GroupedAttention`` with
 ``spec.gate``): its projection (2048 -> 48 or 64, float32 at precision
 highest), the sigmoid, the product a head over (8192, heads, 128) and
 their backward, in a rematerialised block's second run too. A part of
-``gated_lm_attention_proj_device_ms``, as ``moe.experts`` is of ``moe``;
+``attention_proj_device_ms``, as ``moe.experts`` is of ``moe``;
 ``None`` on a program without the scope."""
 
 from chipbench import program_trace

@@ -1,6 +1,6 @@
-"""Device milliseconds a step under the scope ``moe.experts``: the three
-grouped matrix products of every expert layer with the gate between them,
-forward and backward (chipbench/program_trace.py)."""
+"""Device milliseconds a step under the scope ``moe.experts``: the
+grouped matrix products of every expert layer with the gating between
+them, forward, second run and backward (chipbench/program_trace.py)."""
 
 from chipbench import program_trace
 

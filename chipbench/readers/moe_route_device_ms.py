@@ -1,14 +1,16 @@
 """Device milliseconds a step of the expert layers' routing: the scope
-``moe`` less ``moe.experts`` (router, top-k, ordering, gather and
-combine), each a union of intervals (chipbench/program_trace.py)."""
+``moe`` less ``moe.experts`` less ``moe.shared`` (router, scores, top-k,
+ordering, gather and combine), each a union of intervals
+(chipbench/program_trace.py). The shared experts' branch is not routing;
+in a cell whose expert layers have none, no operation is under
+``moe.shared`` and the difference is ``moe`` less ``moe.experts``."""
 
 from chipbench import program_trace
 
 
 def read(records):
-    whole = program_trace.scope_ms_a_step(records, "moe", "train_step")
-    experts = program_trace.scope_ms_a_step(records, "moe.experts",
-                                            "train_step")
-    if whole is None or experts is None:
+    parts = [program_trace.scope_ms_a_step(records, scope, "train_step")
+             for scope in ("moe", "moe.experts", "moe.shared")]
+    if any(part is None for part in parts):
         return None
-    return whole - experts
+    return parts[0] - parts[1] - parts[2]

@@ -2,7 +2,7 @@
 differential combination of a layer's two maps: lambda from the four
 64-vectors, a_1 - lambda a_2 in float32, the RMS norm over the pair's 128
 and (1 - lambda_init), forward, second run and backward. A part of
-``ssm_lm_attention_proj_device_ms``, as ``attention.latent`` is in the
+``attention_proj_device_ms``, as ``attention.latent`` is in the
 latent cell."""
 
 from chipbench import program_trace

@@ -1,5 +1,6 @@
 """Seconds of set-up the host spent making the trial's data: the program's
-span ``trial.data`` (``synthetic_seq2seq``) in this process's ring."""
+span ``trial.data`` (``synthetic_seq2seq``, ``synthetic_lm``) in this
+process's ring."""
 
 from chipbench import program_trace
 

@@ -12,7 +12,7 @@ import sys
 from chipbench import program_trace
 from chipbench.run import _reader
 
-READERS = ("lm_attention_core_device_ms", "moe_device_ms",
+READERS = ("attention_core_device_ms", "moe_device_ms",
            "moe_experts_device_ms", "moe_route_device_ms",
            "flash_fwd_roofline", "flash_bwd_roofline", "moe_experts_roofline")
 

@@ -184,13 +184,12 @@ def mine():
     with open(os.path.join(harness.ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     return bench, [m["name"] for m in bench["per_layer"]
-                   if m.get("workloads") == [CELL]]
+                   if CELL in m.get("workloads", ())]
 
 
 def test_the_cell_s_line_names_its_metrics():
     bench, names = mine()
-    assert len(names) == 19 and len(bench["per_layer"]) <= 128
-    assert all(name.startswith("gated_lm_") for name in names)
+    assert len(names) == 25 and len(bench["per_layer"]) <= 128
     assert all(os.path.exists(os.path.join(
         harness.HERE, "readers", name + ".py")) for name in names)
     cell = next(w for w in bench["workloads"] if w["name"] == CELL)
@@ -201,8 +200,8 @@ def test_the_cell_s_line_names_its_metrics():
 def test_the_readers_leave_their_metric_out_without_a_trace():
     rec = {"step_s": [0.3, 0.3]}
     for name in mine()[1]:
-        if name not in ("gated_lm_moe_dropped_share",
-                        "gated_lm_program_load_s"):
+        if name not in ("moe_dropped_share", "program_load_s",
+                        "compile_cache_hit_share"):
             assert _reader(name).read(rec) is None, name
 
 
@@ -258,23 +257,25 @@ def test_the_trace_readers_on_a_few_operations(monkeypatch):
             "experts_pass": flops_lm.experts_pass(cfg, 8192)}
     rec = {"step_s": [0.5, 0.5], "trace": {"busy_s": 1.0, "window_s": 1.0},
            "kernel_work": work, "device_kind": "TPU v5 lite"}
-    read = lambda name: _reader("gated_lm_" + name).read(rec)  # noqa: E731
+    read = lambda name: _reader(name).read(rec)  # noqa: E731
     assert read("attention_core_device_ms") == pytest.approx(210)
     assert read("attention_proj_device_ms") == pytest.approx(90)
-    assert read("gate_device_ms") == pytest.approx(40)
+    assert read("gated_lm_gate_device_ms") == pytest.approx(40)
     assert read("ffn_device_ms") == pytest.approx(50)        # not the shared
     assert read("moe_device_ms") == pytest.approx(100)
     assert read("moe_experts_device_ms") == pytest.approx(50)
+    assert read("moe_shared_device_ms") == pytest.approx(50)
+    assert read("moe_route_device_ms") == pytest.approx(0)   # less both
     assert read("unnamed_device_ms") == pytest.approx(50)
     # one call of each kind in the slice, each against its own work
     peak = 197e12
-    assert read("full_flash_fwd_roofline") == pytest.approx(
+    assert read("gated_lm_full_flash_fwd_roofline") == pytest.approx(
         100 * work["flash_fwd"][0]["flops"] / peak / 0.1, rel=1e-6)
-    assert read("window_flash_fwd_roofline") == pytest.approx(
+    assert read("gated_lm_window_flash_fwd_roofline") == pytest.approx(
         100 * work["flash_fwd"][1]["flops"] / peak / 0.02, rel=1e-6)
-    assert read("window_flash_bwd_roofline") == pytest.approx(
+    assert read("gated_lm_window_flash_bwd_roofline") == pytest.approx(
         100 * work["flash_bwd"][1]["flops"] / peak / 0.05, rel=1e-6)
-    assert read("full_flash_bwd_roofline") == pytest.approx(
+    assert read("gated_lm_full_flash_bwd_roofline") == pytest.approx(
         100 * work["flash_bwd"][0]["flops"] / peak / 0.25, rel=1e-6)
     assert 0 < read("moe_experts_roofline") < 100
     # the line adds up: the top-level layers and the unnamed are the busy

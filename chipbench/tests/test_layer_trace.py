@@ -20,8 +20,8 @@ NEW = ("embed_device_ms", "attention_proj_device_ms", "ffn_device_ms",
        "backward_device_ms")
 #: the accepted metrics of the other top-level layers in a pattern
 #: decoder's cell: with the first five of NEW they are a step's busy time
-ACCEPTED_LAYERS = ("lm_attention_core_device_ms", "moe_device_ms",
-                   "lm_readout_xent_device_ms", "lm_optimizer_device_ms")
+ACCEPTED_LAYERS = ("attention_core_device_ms", "moe_device_ms",
+                   "readout_xent_device_ms", "optimizer_device_ms")
 TRACED = {"trace": {"busy_s": 1.0, "window_s": 1.0}}
 PLANE = "/device:TPU:0"
 
@@ -194,19 +194,21 @@ def test_an_untraced_run_or_a_trace_without_a_step_reads_none(monkeypatch,
     assert _reader(name).read(TRACED) is None
 
 
-def test_every_new_metric_lists_its_cells_and_has_one_reader():
+def test_the_partition_s_metrics_list_the_cells_that_run_what_they_read():
     root = os.path.dirname(os.path.dirname(HERE))
     with open(os.path.join(root, "BENCHMARK.json")) as f:
         bench = json.load(f)
     cells = [w["name"] for w in bench["workloads"]]
     entries = {m["name"]: m for m in bench["per_layer"]}
-    assert list(entries)[-len(NEW):] == list(NEW)
+    #: the two MoE decoders whose every feed-forward is an expert layer
+    no_dense = ("smallthinker-21b.steady-8k", "keye-vl2-30b.steady-16k")
     for name in NEW:
         assert os.path.exists(os.path.join(root, "chipbench", "readers",
                                            name + ".py"))
         m = entries[name]
         assert (m["source"], m["moves"], m["unit"], m["better"]) == (
             "device_trace", "train_items_per_s", "ms", "lower")
-        want = {"ffn_device_ms": cells[:1],
+        want = {"ffn_device_ms": [c for c in cells if c not in no_dense],
+                # the 2017 cell runs without remat: nothing is made twice
                 "forward_again_device_ms": cells[1:]}.get(name, cells)
         assert m["workloads"] == want

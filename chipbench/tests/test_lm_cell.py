@@ -126,7 +126,7 @@ def test_the_counters_readers_on_a_canned_run():
     assert _reader("moe_held_load_max_over_mean").read(rec) \
         == pytest.approx(30 * 4 / 80)
     assert _reader("step_ms_p50").read(rec) == 250.0
-    for name in ("lm_attention_core_device_ms", "moe_device_ms",
+    for name in ("attention_core_device_ms", "moe_device_ms",
                  "moe_route_device_ms", "flash_fwd_roofline",
                  "moe_experts_roofline"):
         assert _reader(name).read(rec) is None    # no trace in it
@@ -169,29 +169,25 @@ ACCEPTED_HOST = ("trial_data_s", "trial_init_s", "program_load_s",
 
 
 @pytest.mark.parametrize("accepted", ACCEPTED_DEVICE)
-def test_an_accepted_device_metric_under_the_cell_s_own_name(recorded_ops,
-                                                             accepted):
-    """The cell reports the accepted metrics of the layers it runs through
-    their own readers, under ``lm_<name>``."""
+def test_an_accepted_device_metric_in_this_cell(recorded_ops, accepted):
+    """The cell reports the accepted metrics of the layers it runs under
+    their own names: one entry a metric, the cell in its list."""
     rec = dict(canned(), trace={"busy_s": 1.0, "window_s": 1.0})
-    value = _reader("lm_" + accepted).read(rec)
+    value = _reader(accepted).read(rec)
     assert value is not None and value > 0
-    assert value == _reader(accepted).read(rec)
-    assert _reader("lm_" + accepted).read(canned()) is None   # no trace
+    assert _reader(accepted).read(canned()) is None   # no trace
 
 
 @pytest.mark.parametrize("accepted", ACCEPTED_HOST)
-def test_an_accepted_set_up_metric_under_the_cell_s_own_name(monkeypatch,
-                                                             accepted):
+def test_an_accepted_set_up_metric_in_this_cell(monkeypatch, accepted):
     from metaopt_tpu.utils import trace
 
     with open(os.path.join(HERE, "data", "ring_spans.jsonl")) as f:
         ring = [r for r in map(json.loads, f) if "name" in r]
     monkeypatch.setattr(trace, "_ring", ring)
-    value = _reader("lm_" + accepted).read({})
-    assert value is not None and value == _reader(accepted).read({})
+    assert _reader(accepted).read({}) is not None
     monkeypatch.setattr(trace, "_ring", [])        # no set-up in the ring
-    assert _reader("lm_" + accepted).read({}) is None
+    assert _reader(accepted).read({}) is None
 
 
 def test_the_cell_s_line_names_every_layer_it_runs():
@@ -199,7 +195,7 @@ def test_the_cell_s_line_names_every_layer_it_runs():
         bench = json.load(f)
     mine = {m["name"] for m in bench["per_layer"]
             if CELL in m.get("workloads", ())}
-    assert {"lm_" + n for n in ACCEPTED_DEVICE + ACCEPTED_HOST} <= mine
+    assert set(ACCEPTED_DEVICE + ACCEPTED_HOST) <= mine
 
 
 def test_the_trace_readers_read_nothing_without_the_kernels(monkeypatch):

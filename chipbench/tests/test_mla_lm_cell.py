@@ -158,16 +158,16 @@ def canned():
 def test_the_counters_readers_on_a_canned_run():
     rec = canned()
     assert _reader("mla_lm_moe_choice_bias_share").read(rec) == 20.0
-    assert _reader("mla_lm_moe_dropped_share").read(rec) == 0.0
-    assert _reader("mla_lm_moe_held_load_max_over_mean").read(rec) == 1.5
+    assert _reader("moe_dropped_share").read(rec) == 0.0
+    assert _reader("moe_held_load_max_over_mean").read(rec) == 1.5
     assert _reader("mla_lm_moe_choice_bias_share").read({}) is None
     assert _reader("mla_lm_moe_choice_bias_share").read(
         {"choice_counts": {"bias_moved": [], "tokens": 0}}) is None
-    for name in ("mla_attention_core_device_ms", "mla_latent_device_ms",
-                 "mla_flash_fwd_roofline", "mla_flash_bwd_roofline",
-                 "mla_lm_moe_shared_device_ms", "mla_lm_moe_route_device_ms",
-                 "mla_lm_moe_experts_roofline", "mla_lm_ffn_device_ms",
-                 "mla_lm_scoped_device_share", "mla_lm_unnamed_device_ms"):
+    for name in ("attention_core_device_ms", "mla_latent_device_ms",
+                 "flash_fwd_roofline", "flash_bwd_roofline",
+                 "moe_shared_device_ms", "moe_route_device_ms",
+                 "moe_experts_roofline", "ffn_device_ms",
+                 "scoped_device_share", "unnamed_device_ms"):
         assert _reader(name).read(rec) is None    # no trace in it
 
 
@@ -200,32 +200,32 @@ def test_the_trace_readers_on_a_few_operations(monkeypatch):
     rec = dict(canned(), trace={"busy_s": 1.0, "window_s": 1.0},
                kernel_work=work, device_kind="TPU v5 lite")
     read = lambda name: _reader(name).read(rec)  # noqa: E731
-    assert read("mla_attention_core_device_ms") == pytest.approx(150)
+    assert read("attention_core_device_ms") == pytest.approx(150)
     assert read("mla_latent_device_ms") == pytest.approx(100)
-    assert read("mla_lm_attention_proj_device_ms") == pytest.approx(150)
-    assert read("mla_lm_moe_device_ms") == pytest.approx(150)
-    assert read("mla_lm_moe_shared_device_ms") == pytest.approx(75)
-    assert read("mla_lm_moe_experts_device_ms") == pytest.approx(50)
-    assert read("mla_lm_moe_route_device_ms") == pytest.approx(25)
+    assert read("attention_proj_device_ms") == pytest.approx(150)
+    assert read("moe_device_ms") == pytest.approx(150)
+    assert read("moe_shared_device_ms") == pytest.approx(75)
+    assert read("moe_experts_device_ms") == pytest.approx(50)
+    assert read("moe_route_device_ms") == pytest.approx(25)
     # the dense layer's alone: the shared experts' ``ffn`` is the moe's
-    assert read("mla_lm_ffn_device_ms") == pytest.approx(50)
+    assert read("ffn_device_ms") == pytest.approx(50)
     # one call in the slice: 2.75 TFLOP over 0.1 s at 197 TFLOP/s
-    assert read("mla_flash_fwd_roofline") == pytest.approx(
+    assert read("flash_fwd_roofline") == pytest.approx(
         100 * work["flash_fwd"][0]["flops"] / 197e12 / 0.1, rel=1e-6)
-    assert 0 < read("mla_flash_bwd_roofline") < 100
-    assert 0 < read("mla_lm_moe_experts_roofline") < 100
+    assert 0 < read("flash_bwd_roofline") < 100
+    assert 0 < read("moe_experts_roofline") < 100
     # the line adds up: the top-level layers and the unnamed are the busy
     layers = sum(read(n) for n in (
-        "mla_attention_core_device_ms", "mla_lm_attention_proj_device_ms",
-        "mla_lm_ffn_device_ms", "mla_lm_moe_device_ms"))
-    assert layers + read("mla_lm_unnamed_device_ms") == pytest.approx(500)
+        "attention_core_device_ms", "attention_proj_device_ms",
+        "ffn_device_ms", "moe_device_ms"))
+    assert layers + read("unnamed_device_ms") == pytest.approx(500)
 
 
 def test_the_cell_s_line_names_every_metric_the_issue_lists():
     with open(os.path.join(harness.ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     mine = {m["name"] for m in bench["per_layer"]
-            if m.get("workloads") == [CELL]}
+            if CELL in m.get("workloads", ())}
     assert len(mine) == 24
     assert all(os.path.exists(os.path.join(
         harness.HERE, "readers", name + ".py")) for name in mine)

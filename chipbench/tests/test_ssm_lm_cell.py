@@ -181,10 +181,10 @@ def test_the_readers_leave_their_metric_out_without_a_trace():
     rec = canned()
     for name in ("ssm_mixer_device_ms", "ssm_scan_core_device_ms",
                  "ssm_scan_fwd_roofline", "ssm_scan_bwd_roofline",
-                 "ssm_gmu_device_ms", "ssm_lm_attention_core_device_ms",
-                 "ssm_lm_flash_fwd_roofline", "ssm_lm_flash_bwd_roofline",
-                 "ssm_lm_diff_combine_device_ms", "ssm_lm_ffn_device_ms",
-                 "ssm_lm_scoped_device_share", "ssm_lm_unnamed_device_ms"):
+                 "ssm_gmu_device_ms", "attention_core_device_ms",
+                 "flash_fwd_roofline", "flash_bwd_roofline",
+                 "ssm_lm_diff_combine_device_ms", "ffn_device_ms",
+                 "scoped_device_share", "unnamed_device_ms"):
         assert _reader(name).read(rec) is None    # no trace in it
 
 
@@ -222,30 +222,30 @@ def test_the_trace_readers_on_a_few_operations(monkeypatch):
     assert read("ssm_mixer_device_ms") == pytest.approx(150)
     assert read("ssm_scan_core_device_ms") == pytest.approx(100)
     assert read("ssm_gmu_device_ms") == pytest.approx(25)
-    assert read("ssm_lm_attention_core_device_ms") == pytest.approx(150)
+    assert read("attention_core_device_ms") == pytest.approx(150)
     assert read("ssm_lm_diff_combine_device_ms") == pytest.approx(25)
-    assert read("ssm_lm_attention_proj_device_ms") == pytest.approx(75)
-    assert read("ssm_lm_ffn_device_ms") == pytest.approx(100)
+    assert read("attention_proj_device_ms") == pytest.approx(75)
+    assert read("ffn_device_ms") == pytest.approx(100)
     # one call in the slice: 0.50 GB over 0.05 s at 819 GB/s
     assert read("ssm_scan_fwd_roofline") == pytest.approx(
         100 * work["selective_scan_fwd"][0]["bytes"] / 819e9 / 0.05,
         rel=1e-6)
     assert 0 < read("ssm_scan_bwd_roofline") < 100
-    assert 0 < read("ssm_lm_flash_fwd_roofline") < 100
-    assert 0 < read("ssm_lm_flash_bwd_roofline") < 100
+    assert 0 < read("flash_fwd_roofline") < 100
+    assert 0 < read("flash_bwd_roofline") < 100
     # the line adds up: the top-level layers and the unnamed are the busy
     layers = sum(read(n) for n in (
         "ssm_mixer_device_ms", "ssm_gmu_device_ms",
-        "ssm_lm_attention_core_device_ms", "ssm_lm_attention_proj_device_ms",
-        "ssm_lm_ffn_device_ms"))
-    assert layers + read("ssm_lm_unnamed_device_ms") == pytest.approx(500)
+        "attention_core_device_ms", "attention_proj_device_ms",
+        "ffn_device_ms"))
+    assert layers + read("unnamed_device_ms") == pytest.approx(500)
 
 
 def test_the_cell_s_line_names_every_metric_the_issue_lists():
     with open(os.path.join(harness.ROOT, "BENCHMARK.json")) as f:
         bench = json.load(f)
     mine = {m["name"] for m in bench["per_layer"]
-            if m.get("workloads") == [CELL]}
+            if CELL in m.get("workloads", ())}
     assert len(mine) == 21
     assert all(os.path.exists(os.path.join(
         harness.HERE, "readers", name + ".py")) for name in mine)
