@@ -62,6 +62,15 @@ gate a head on attention's output (``gating``) and, by
 shared expert (``python -m chipbench.gated_lm_config
 chipbench/configs/laguna-xs2-33b-a3b-ep8.json`` prints Laguna-XS.2's share
 of one chip; ``--seq-len=8192``).
+One with ``hybrid_override_pattern`` (``model_type`` ``nemotron_h``) names
+blocks of ONE sublayer each, by the pattern's letter at the block's
+published number (``layers_held``): ``M`` a Mamba-2 mixer
+(``mamba_num_heads``, ``mamba_head_dim``, ``n_groups``, ``ssm_state_size``,
+``conv_kernel``), ``*`` grouped attention without positions, ``E`` experts
+of two matrices under a squared ReLU beside a shared one (``python -m
+chipbench.ssd_lm_config chipbench/configs/nemotron-3-nano-30b-a3b-ep16.json``
+prints NVIDIA-Nemotron-3-Nano-30B-A3B's share of one chip;
+``--seq-len=8192``).
 """
 
 import argparse

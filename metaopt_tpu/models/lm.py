@@ -25,10 +25,15 @@ latent-rope        LatentSpec        LatentAttention        latent_attention
 linear             LinearSpec        LinearAttention        linear_attention
 ssm                StateSpaceSpec    StateSpaceMixer        selective_scan
 gmu                MemoryUnitSpec    GatedMemoryUnit        none
+ssd                ScalarDecaySpec   ScalarDecayMixer       linear_attention
 window-, global-,  DifferentialSpec  DifferentialAttention  attention, twice
 cross-nope
 gated (ffn)        GatedSpec         GatedFeedForward       none
 routed (ffn)       moe.RoutedSpec    router, DroplessMoE    experts, megablox
+                   + ``gated``       (experts of two
+                   false             matrices, no gate)
+absent (either)    Absent            none: the sublayer a
+                                     block does not have
 =================  ================  =====================  =================
 
 Who names them (models/lm_description.py, the one module that knows a
@@ -39,7 +44,9 @@ feed-forward; the DeepSeek-V3 family's latent, routed behind leading gated
 layers; ``phi4flash`` ssm, gmu and the differential kinds, gated;
 ``laguna`` the grouped kinds with a head count, a rotary rule (YaRN on half
 a head, the plain rule on the whole) and an output gate a layer, gated or
-routed by the layer.
+routed by the layer; ``nemotron_h`` one sublayer a block by its pattern's
+letter: ssd (Mamba-2), global-nope attention, or experts of two matrices
+under a squared ReLU beside a shared one.
 
 The specs and modules are models/lm_layers.py's (the routed feed-forward's
 models/moe.py's); what a rematerialised block keeps is
@@ -76,7 +83,8 @@ from metaopt_tpu.models.lm_description import (Layer, Pattern, _own_names,
                                                describe_pattern, pattern_of)
 # chipbench/runners/mla_lm_trial_steps.py::has_mechanism asks
 # hasattr(lm, "LatentAttention") and chipbench/ is not this PR's to edit
-from metaopt_tpu.models.lm_layers import NORMS, LatentAttention  # noqa: F401
+from metaopt_tpu.models.lm_layers import (NORMS, Absent,  # noqa: F401
+                                          LatentAttention)
 from metaopt_tpu.models.lm_remat import param_init, remat_keeps, remat_on
 from metaopt_tpu.models.transformer import (
     EncoderLayer,
@@ -100,7 +108,9 @@ class PatternBlock(nn.Module):
     """One held layer of a pattern, as its entry says (models/lm_layers.py:
     the specs build the modules): x + mixer(norm(x)) then + ffn(norm(.))
     or, with the norm on the branches, x + norm(mixer(x)) then +
-    norm(ffn(.)). The feed-forward may read the first norm's output before
+    norm(ffn(.)); a sublayer whose spec is ``Absent`` is passed over, branch
+    and norm (a pattern of one sublayer a block). The feed-forward may read
+    the first norm's output before
     the mixer runs (a router). ``read``: what the layer reads of earlier
     layers, in ``layer.reads``' order. Returns (x, {name: what the layer
     hands on}). The residual stream is float32."""
@@ -116,14 +126,17 @@ class PatternBlock(nn.Module):
         at = lambda name, y, here: (  # noqa: E731
             norm(name, y, self.eps) if here else y)
         mixer, ffn = self.layer.mixer, self.layer.ffn
-        n = at("norm_in", x, before)
-        early = ffn.before_mixer(self, n)
-        branch, offered = mixer.mix(self, n, *read)
-        x = residual(x, at("norm_mixer", branch, not before))
-        m = at("norm_post", x, before)
-        return residual(x, at("norm_ffn", ffn.feed(self, m, early),
-                              not before)), {
-            name: offered[name] for name in self.layer.hands_on}
+        early, offered = None, {}
+        if not isinstance(mixer, Absent):
+            n = at("norm_in", x, before)
+            early = ffn.before_mixer(self, n)
+            branch, offered = mixer.mix(self, n, *read)
+            x = residual(x, at("norm_mixer", branch, not before))
+        if not isinstance(ffn, Absent):
+            m = at("norm_post", x, before)
+            x = residual(x, at("norm_ffn", ffn.feed(self, m, early),
+                                not before))
+        return x, {name: offered[name] for name in self.layer.hands_on}
 
 
 class DecoderOnlyLM(nn.Module):

@@ -8,8 +8,9 @@ own keys (``d_model``, ``n_layers`` ...) or a published config's
 vocabulary's rows, ``heads_held`` = (first, count) of
 ``num_attention_heads`` (every kind of head is built in that proportion;
 ``laguna``, whose layers differ in their head counts, takes none) and,
-for ``phi4flash``, ``layers_held``: the published numbers of the layers this
-chip holds (a pipeline stage's; not the first n). :func:`family_of` says
+for ``phi4flash`` and ``nemotron_h``, ``layers_held``: the published numbers
+of the layers this chip holds (a pipeline stage's; not the first n).
+:func:`family_of` says
 whose words a description speaks, and the family's reader (``_FAMILIES``)
 builds each held layer's specs (models/lm_layers.py, models/moe.py)
 directly. What has no layer here is refused by its name.
@@ -26,8 +27,8 @@ from typing import Any, Dict, Optional, Tuple
 from jax.sharding import Mesh
 
 from metaopt_tpu.models.lm_layers import (
-    DifferentialSpec, GatedSpec, GroupedSpec, LatentSpec, LinearSpec,
-    MemoryUnitSpec, Rotary, StateSpaceSpec)
+    Absent, DifferentialSpec, GatedSpec, GroupedSpec, LatentSpec, LinearSpec,
+    MemoryUnitSpec, Rotary, ScalarDecaySpec, StateSpaceSpec)
 from metaopt_tpu.models.moe import RoutedSpec, RoutingRule
 from metaopt_tpu.ops.embed import embed_gradient_route
 
@@ -86,13 +87,15 @@ class Pattern:
         return list(dict.fromkeys(layer.mixer.kind for layer in self.layers))
 
     def by_kind(self):
-        """[(kind, its layers)], the kinds that attend before the
+        """[(kind, its layers)] of the layers that have a mixer, the kinds
+        that attend before the
         recurrences, each group in the pattern's order: the order in which
         ``trial.setup`` lists them and the remat rule tries their
         products."""
         groups: Dict[str, list] = {}
         for layer in self.layers:
-            groups.setdefault(layer.mixer.kind, []).append(layer)
+            if not isinstance(layer.mixer, Absent):
+                groups.setdefault(layer.mixer.kind, []).append(layer)
         return sorted(groups.items(),
                       key=lambda group: not group[1][0].mixer.attends)
 
@@ -134,10 +137,13 @@ def family_of(h: Dict[str, Any]) -> Optional[str]:
     hybrid's, ``num_experts`` the Qwen3-MoE family's, the two layouts or
     ``sa_config`` alone SmallThinker's; ``model_type`` ``phi4flash`` and
     ``laguna`` name their families themselves (the second speaks the Olmo
-    hybrid's and the Qwen3-MoE family's words at once)."""
+    hybrid's and the Qwen3-MoE family's words at once);
+    ``hybrid_override_pattern`` is ``nemotron_h``'s (which speaks the
+    DeepSeek-V3 family's routing words too, so it is asked first)."""
     if h.get("model_type") in ("phi4flash", "laguna"):
         return h["model_type"]
-    for key, family in (("kv_lora_rank", "deepseek_v3"),
+    for key, family in (("hybrid_override_pattern", "nemotron_h"),
+                        ("kv_lora_rank", "deepseek_v3"),
                         ("layer_types", "olmo_hybrid"),
                         ("num_experts", "qwen3_moe"),
                         ("sa_config", "layouts"),
@@ -159,6 +165,17 @@ def pattern_of(h: Dict[str, Any]) -> Optional[Pattern]:
 
 def _held(h, key: str, whole: int):
     return tuple(int(v) for v in h.get(key) or (0, whole))
+
+
+def _layers_held(h, of: int) -> Tuple[int, ...]:
+    """The published numbers of the layers held, of a model ``of`` deep:
+    ``layers_held`` (a pipeline stage's), or all of them."""
+    numbers = tuple(int(n) for n in h.get("layers_held") or range(of))
+    if list(numbers) != sorted(set(numbers)) or not numbers \
+            or not 0 <= numbers[0] <= numbers[-1] < of:
+        raise ValueError(f"layers_held {list(numbers)}: the published "
+                         f"numbers of layers 0..{of - 1}, ascending")
+    return numbers
 
 
 def _heads(h):
@@ -373,11 +390,7 @@ def _phi4flash(h) -> Pattern:
     anywhere, the head tied to the embedding. A layer that reads what a
     layer not held would hand on is refused by name."""
     of = int(h.get("n_layers", 6))
-    numbers = tuple(int(n) for n in h.get("layers_held") or range(of))
-    if list(numbers) != sorted(set(numbers)) or not numbers \
-            or not 0 <= numbers[0] <= numbers[-1] < of:
-        raise ValueError(f"layers_held {list(numbers)}: the published "
-                         f"numbers of layers 0..{of - 1}, ascending")
+    numbers = _layers_held(h, of)
     kinds = [hybrid_kind(n, of) for n in numbers]
     # who hands on what, and the kind that reads it
     sources = {"memory": (of // 2, "gmu", "the memory"),
@@ -525,11 +538,76 @@ def _rotary(kind: str, said: Dict[str, Any], head_dim: int) -> Rotary:
                   turned if turned < head_dim else None, yarn, factor)
 
 
+def _nemotron_h(h) -> Pattern:
+    """The ``nemotron_h`` family's: ONE sublayer a block, x + f(rmsnorm(x))
+    (eps ``norm_eps``), f by the block's letter in
+    ``hybrid_override_pattern``, read at the PUBLISHED numbers (all blocks,
+    or ``layers_held``, a pipeline stage's): ``M`` a Mamba-2 mixer
+    (``mamba_num_heads`` heads of ``mamba_head_dim``, B and C of
+    ``ssm_state_size`` shared by ``n_groups`` groups, ``conv_kernel`` taps
+    with a bias); ``*`` grouped attention without positions, q/k norms or
+    bias; ``E`` ``n_routed_experts`` experts of two matrices
+    ``moe_intermediate_size`` wide under ``mlp_hidden_act`` (a squared
+    ReLU), chosen by the DeepSeek-V3 family's rule (:func:`_routing`),
+    beside ``n_shared_experts`` shared ones of
+    ``moe_shared_expert_intermediate_size``. An untied head. What has no
+    layer here is refused by its name."""
+    letters = str(h["hybrid_override_pattern"])
+    unknown = sorted(set(letters) - set(_LETTERS))
+    if unknown:
+        raise ValueError(f"hybrid_override_pattern names {unknown}; known: "
+                         f"{sorted(_LETTERS)} "
+                         f"({', '.join(_LETTERS.values())})")
+    numbers = _layers_held(h, len(letters))
+    for key in ("attention_bias", "mamba_proj_bias", "mlp_bias", "use_bias"):
+        if h.get(key):
+            raise ValueError(f"{key} {h[key]!r}: a nemotron_h block has no "
+                             "such bias here")
+    if not h.get("use_conv_bias", True):
+        raise ValueError("use_conv_bias false: the mixer's convolution has "
+                         "its bias here")
+    act = str(h.get("mlp_hidden_act", "relu2"))
+    if act != "relu2" or h.get("mamba_hidden_act", "silu") != "silu":
+        raise ValueError(f"mlp_hidden_act {act!r} with mamba_hidden_act "
+                         f"{h.get('mamba_hidden_act')!r}: the family's here "
+                         "are 'relu2' and 'silu'")
+    heads, groups = int(h["mamba_num_heads"]), int(h.get("n_groups", 1))
+    if heads % groups:
+        raise ValueError(f"n_groups {groups} does not divide "
+                         f"mamba_num_heads {heads}")
+    if "heads_held" in h:
+        raise ValueError(f"heads_held {h['heads_held']!r}: the deployment "
+                         "shares no heads of a nemotron_h block")
+    n_experts = int(h.get("moe_num_primary_experts", 0))
+    kinds = {
+        "M": (ScalarDecaySpec(
+            heads=heads, head_dim=int(h["mamba_head_dim"]), groups=groups,
+            state=int(h["ssm_state_size"]),
+            conv=int(h.get("conv_kernel", 4))), Absent()),
+        "*": (GroupedSpec(**_attention_heads(h), window=None, theta=None,
+                          qk_norm=None, selection=None), Absent()),
+        "E": (Absent(), RoutedSpec(
+            n_experts=n_experts, top_k=int(h.get("num_experts_per_tok", 1)),
+            d_ff=int(h["moe_intermediate_size"]),
+            held=_held(h, "experts_held", n_experts), activation=act,
+            shared_d_ff=int(h.get("n_shared_experts") or 0) * int(
+                h.get("moe_shared_expert_intermediate_size", 0)),
+            rule=_routing(h), router_after_mixer=True, gated=False)
+            if "E" in letters else None)}
+    return _pattern(h, [Layer(n, *kinds[letters[n]]) for n in numbers],
+                    "rms", "norm_eps", 1e-5)
+
+
+#: a ``hybrid_override_pattern`` letter -> the block's one sublayer
+_LETTERS = {"M": "a Mamba-2 mixer", "*": "attention", "E": "experts"}
+
+
 #: a family's reader: what its layer is, by the family and not by how a
 #: key of its description is spelt
 _FAMILIES = {"layouts": _layouts, "qwen3_moe": _qwen3_moe,
              "olmo_hybrid": _olmo_hybrid, "deepseek_v3": _deepseek_v3,
-             "phi4flash": _phi4flash, "laguna": _laguna}
+             "phi4flash": _phi4flash, "laguna": _laguna,
+             "nemotron_h": _nemotron_h}
 
 
 def describe_pattern(hparams: Dict[str, Any], route: str, tokens: int,
@@ -561,6 +639,7 @@ def describe_pattern(hparams: Dict[str, Any], route: str, tokens: int,
     feeds: Dict[str, list] = {}
     for layer in p.layers:
         feeds.setdefault(layer.ffn.kind, []).append(layer)
+    fed = sum(not isinstance(layer.ffn, Absent) for layer in p.layers)
     for layers in feeds.values():
-        out.update(layers[0].ffn.describe(step, layers, len(p.layers)))
+        out.update(layers[0].ffn.describe(step, layers, fed))
     return out

@@ -1061,3 +1061,181 @@ def _handed_over(layer: GroupedAttention, q, k):
         q, k = rope(q, sp.rule), rope(k, sp.rule)
     return ((q / math.sqrt(sp.head_dim)).astype(jnp.bfloat16),
             k.astype(jnp.bfloat16))
+
+
+# ---------------------------------------------------------------------------
+# A Mamba-2 mixer, a feed-forward of two matrices and the sublayer a block
+# does not have. Down here for the reason :func:`_handed_over` is.
+
+
+#: a published config's activation by its name there; ``relu2``: the
+#: squared ReLU of a feed-forward that is not gated
+ACTIVATIONS = {"relu": nn.relu, "silu": nn.silu,
+               "relu2": lambda x: jnp.square(nn.relu(x))}
+
+
+class PlainFeedForward(nn.Module):
+    """act(x W_up) W_down, no gate and no bias: :class:`GatedFeedForward`
+    of two matrices, its products under the same names of
+    ``GatedSpec.KEPT`` and the up product behind the same barrier."""
+
+    d_model: int
+    d_ff: int
+    activation: str = "relu2"
+
+    @nn.compact
+    @trace.scope("ffn")
+    def __call__(self, x):
+        dense = lambda name, n, axes: nn.Dense(  # noqa: E731
+            n, dtype=jnp.bfloat16, name=name, use_bias=False,
+            kernel_init=_pinit(True, axes))
+        kept = GatedSpec.KEPT
+        up = jax.lax.optimization_barrier(checkpoint_name(
+            dense("up", self.d_ff, (None, "tp"))(x.astype(jnp.bfloat16)),
+            kept["up"]))
+        return checkpoint_name(dense("down", self.d_model, ("tp", None))(
+            ACTIVATIONS[self.activation](up)), kept["down"])
+
+
+@dataclasses.dataclass(frozen=True)
+class Absent(_Spec):
+    """The sublayer a block does not have (a pattern of one sublayer a
+    block: a mixer OR a feed-forward): no branch, no norm, no products, no
+    counts, no line in ``trial.setup``. models/lm.py::PatternBlock, the
+    pattern's kinds and the span pass it over by its type."""
+
+    kind = "absent"
+
+    def before_mixer(self, block, n):
+        return None
+
+    def products(self, d_model):
+        return []
+
+    def describe(self, step, layers, sources):
+        return {}
+
+
+@dataclasses.dataclass(frozen=True)
+class ScalarDecaySpec(_Mixer):
+    """A Mamba-2 mixer's sizes (arXiv:2405.21060): ``heads`` heads of
+    ``head_dim`` channels, B and C of ``state`` numbers shared by the heads
+    of a group (``groups`` of them), a convolution of ``conv`` taps with a
+    bias. On a ``tp`` axis the mixer is whole on every device: its one
+    input projection joins z, x, B, C and dt and has no one axis to cut."""
+
+    heads: int
+    head_dim: int
+    groups: int
+    state: int
+    conv: int
+
+    #: the input projection's product (z | x B C before the convolution),
+    #: the steps' (float32, a number a head: before the bias and the
+    #: softplus) and the output projection's
+    KEPT = {"in": "ssd.in_proj", "dt": "ssd.dt_proj", "out": "ssd.out_proj"}
+
+    attends = False
+    kind = "ssd"
+
+    @property
+    def d_inner(self) -> int:
+        return self.heads * self.head_dim
+
+    def mix(self, block, x):
+        return ScalarDecayMixer(block.d_model, self, block.eps,
+                                name="ssd")(x), {}
+
+    def kernel_keeps(self):
+        from metaopt_tpu.ops import linear_attention
+
+        return linear_attention.SCALAR_DECAY_KEEPS
+
+    def products(self, d_model):
+        kept = self.KEPT
+        return [(d_model, {kept["in"]: 2 * 2 * (
+                     self.d_inner + self.groups * self.state),
+                           kept["dt"]: 4 * self.heads}),
+                (self.d_inner, {kept["out"]: 2 * d_model})]
+
+    def describe(self, step, layers, sources):
+        from metaopt_tpu.ops.linear_attention import linear_attention_route
+
+        return {**linear_attention_route(), "layers": _numbers(layers),
+                "heads": self.heads, "head_dim": self.head_dim,
+                "groups": self.groups, "state": self.state,
+                "conv": self.conv,
+                "norm_group": self.d_inner // self.groups,
+                "program": f"group of {self.heads // self.groups} heads "
+                           "and chunk",
+                "remat_keeps": list(self.kernel_keeps())}
+
+
+def _head_decay_init(key, shape, dtype=jnp.float32):
+    """``A_log`` as Mamba-2's published initialiser draws it: log of
+    U(1, 16), a number a head."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+class ScalarDecayMixer(nn.Module):
+    """Mamba-2 (arXiv:2405.21060; the ``nemotron_h`` family's mixer) over
+    ``spec.heads`` heads: [z | xBC | dt] = u W_in, one projection, no bias;
+    xBC = silu(conv(xBC) + b), a causal depthwise convolution of ``conv``
+    taps; x (heads x head_dim), B, C (groups x state) split from it; dt =
+    softplus(dt + dt_bias) and a = -exp(A_log), a number a head; head h
+    reads group h // (heads / groups); H_t = exp(dt_t a) H_{t-1} + dt_t x_t
+    B_t^T, y_t = H_t C_t + D x_t: the scalar decay rule
+    (ops/linear_attention.py: q = C, k = B, v = dt x, g = dt a; the one
+    rule there names its route; a recurrence, no positions); y =
+    rmsnorm(y silu(z)) with the mean square over each group's d_inner /
+    groups channels (the gate BEFORE the norm); out = y W_out. Elementwise
+    work, the convolution, the decays and the norm in float32; dt's
+    columns of W_in are multiplied in float32 at matmul precision highest
+    (``heads`` columns: the decays exp(dt a) compound over a row), as a
+    linear layer's gates are. The three products carry the names of the
+    spec's ``KEPT``."""
+
+    d_model: int
+    spec: ScalarDecaySpec
+    eps: float
+
+    @nn.compact
+    @trace.scope("ssd")
+    def __call__(self, u):
+        from metaopt_tpu.ops.linear_attention import scalar_decay_rule
+
+        sp, kept = self.spec, self.spec.KEPT
+        own = lambda name, init, shape: self.param(  # noqa: E731
+            name, with_mesh_partitioning(init, (None,) * len(shape)), shape)
+        inner, bc = sp.d_inner, sp.groups * sp.state
+        w_in = own("in_proj", nn.initializers.lecun_normal(),
+                   (self.d_model, 2 * inner + 2 * bc + sp.heads))
+        zxbc = checkpoint_name(jnp.dot(
+            u.astype(jnp.bfloat16), w_in[:, :-sp.heads].astype(jnp.bfloat16),
+            preferred_element_type=jnp.bfloat16), kept["in"])
+        dt = jax.nn.softplus(checkpoint_name(jnp.dot(
+            u.astype(jnp.float32), w_in[:, -sp.heads:],
+            precision=jax.lax.Precision.HIGHEST), kept["dt"])
+            + own("dt_bias", _dt_bias_init, (sp.heads,)))
+        xbc = jax.nn.silu(
+            short_conv(zxbc[..., inner:].astype(jnp.float32),
+                       own("conv", _taps_init, (sp.conv, inner + 2 * bc)))
+            + own("conv_bias", _taps_init, (inner + 2 * bc,)))
+        heads = lambda y, n: y.reshape(*y.shape[:2], n, -1)  # noqa: E731
+        x = heads(xbc[..., :inner], sp.heads)
+        b, c = (heads(xbc[..., inner + i * bc:inner + (i + 1) * bc],
+                      sp.groups).astype(jnp.bfloat16) for i in (0, 1))
+        a = -jnp.exp(own("A_log", _head_decay_init, (sp.heads,)))
+        y = scalar_decay_rule(c, b, (dt[..., None] * x).astype(jnp.bfloat16),
+                              dt * a).astype(jnp.float32) \
+            + own("D", nn.initializers.ones, (sp.heads,))[:, None] * x
+        gated = y.reshape(zxbc.shape[:2] + (inner,)) * jax.nn.silu(
+            zxbc[..., :inner].astype(jnp.float32))
+        grouped = heads(gated, sp.groups)
+        normed = (grouped * jax.lax.rsqrt(jnp.mean(
+            jnp.square(grouped), axis=-1, keepdims=True) + self.eps)
+        ).reshape(gated.shape) * own("norm", nn.initializers.ones, (inner,))
+        return checkpoint_name(nn.Dense(
+            self.d_model, dtype=jnp.bfloat16, name="out_proj",
+            use_bias=False, kernel_init=_pinit(True, (None, None)),
+        )(normed.astype(jnp.bfloat16)), kept["out"])

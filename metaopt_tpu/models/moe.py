@@ -76,7 +76,8 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
-from metaopt_tpu.models.lm_layers import GatedFeedForward, GatedSpec
+from metaopt_tpu.models.lm_layers import (ACTIVATIONS, GatedFeedForward,
+                                          GatedSpec, PlainFeedForward)
 from metaopt_tpu.parallel.sharding import with_mesh_partitioning
 from metaopt_tpu.utils import trace
 
@@ -225,12 +226,20 @@ def _tiling(m: int, k: int, n: int, blocks):
     over, of 512 slower or over Mosaic's VMEM; 128-tiles throughout, megablox's
     default, are over ten times slower."""
     tm, side = _divisor(m, (256, 128)), _divisor(n)
-    if tm is None or side is None or k % 128:
+    if tm is None or (side is None and k % 128):
         return tm, _divisor(k), side
 
     def fits(tk, tn):
         return blocks(tm, tk, tn) <= _TILE_BYTES
 
+    # a width that is no whole lanes (an expert 1856 = 29 x 64 wide) is ONE
+    # block, the whole of it, on whichever axis it lies (a block as wide as
+    # its array needs no whole lanes; the parameters are neither cut nor
+    # padded), and the other axis is as wide as fits beside it
+    if k % 128:
+        return tm, k, _widest(n, lambda tn: fits(k, tn))
+    if side is None:
+        return tm, _widest(k, lambda tk: fits(tk, n)), n
     tk = _widest(k, lambda tk: fits(tk, side))
     return tm, tk, _widest(n, lambda tn: fits(tk, tn))
 
@@ -288,26 +297,33 @@ def grouped_matmul_impl(m: int, k: int, n: int) -> str:
     return "ragged_dot"
 
 
-def describe_experts(n: int, d: int, f: int) -> dict:
+def describe_experts(n: int, d: int, f: int, gated: bool = True) -> dict:
     """What ``trial.setup``'s span says of the held experts' part over
-    buffers of ``n`` rows, a model width ``d`` and experts ``f`` wide: the
-    answer of :func:`grouped_matmul_impl`, the gating that goes with it
-    (kernels beside megablox, loops of :func:`_over_chunks` beside
-    ``ragged_dot``) and, on megablox, the (rows, k, n) tiles of the six
-    grouped products by the pass that makes them and the gating kernels'
-    rows a program (None where XLA tiles)."""
+    buffers of ``n`` rows, a model width ``d`` and experts ``f`` wide,
+    ``gated`` or of two matrices: the answer of :func:`grouped_matmul_impl`,
+    the gating (or the activation's pass) that goes with it (kernels beside
+    megablox, loops of :func:`_over_chunks` beside ``ragged_dot``), how a
+    width of no whole lanes is tiled (``width``) and, on megablox, the
+    (rows, k, n) tiles of the six grouped products by the pass that makes
+    them and the gating kernels' rows a program (None where XLA tiles)."""
     impl = grouped_matmul_impl(n, d, f)
     kernels = impl == "megablox"
-    return {"gate_up": f"one product of {2 * f} columns",
+    first = 2 * f if gated else f  # the first product's columns
+    said = {"gate_up": f"one product of {first} columns" if gated
+            else f"no gate: one product of {f} columns",
             "gating": "pallas" if kernels else "chunks",
             "products": impl,
-            "tiles": {"gu": _gmm_tiling(n, d, 2 * f),
+            "tiles": {"gu": _gmm_tiling(n, d, first),
                       "out": _gmm_tiling(n, f, d),
                       "d_h": _gmm_tiling(n, d, f),
-                      "d_rows": _gmm_tiling(n, 2 * f, d),
-                      "d_w_gu": _tgmm_tiling(n, d, 2 * f),
+                      "d_rows": _gmm_tiling(n, first, d),
+                      "d_w_gu": _tgmm_tiling(n, d, first),
                       "d_w_down": _tgmm_tiling(n, f, d),
                       "gating": _gating_tile(n, f)} if kernels else None}
+    if kernels and f % 128:
+        said["width"] = (f"{f} is no whole lanes: one block of the whole "
+                         "width in every product, neither cut nor padded")
+    return said
 
 
 #: rows one trip of a loop over the buffers' rows moves, and of a loop over
@@ -539,71 +555,81 @@ def _weight_gradient(x, g, items, impl: str):
         preferred_element_type=x.dtype)
 
 
-def _gate(gu, filled, activation, impl: str):
-    """(n, f): ``activation(gu[:, :f]) * gu[:, f:]`` in the filled rows (up
-    to the end of the tile or chunk that holds the last): beside megablox
-    the kernel, in float32 rounded once; else the loop, in the operands'
-    dtype as XLA has it."""
-    n, f = gu.shape[0], gu.shape[1] // 2
+def _gate(gu, filled, activation, impl: str, gated: bool = True):
+    """(n, f): ``activation(gu[:, :f]) * gu[:, f:]`` or, for experts that
+    are not ``gated``, ``activation(gu)`` of the one product (n, f), in the
+    filled rows (up to the end of the tile or chunk that holds the last):
+    beside megablox the kernel, in float32 rounded once; else the loop, in
+    the operands' dtype as XLA has it."""
+    n, f = gu.shape[0], gu.shape[1] // 2 if gated else gu.shape[1]
     if impl == "megablox":
-        return _kernels().gating(gu, filled, activation, _gating_tile(n, f))
+        kernel = _kernels().gating if gated else _kernels().activation
+        return kernel(gu, filled, activation, _gating_tile(n, f))
     chunk = routing_chunk_rows(n)
 
     def body(start, below, fresh, h):
         mine = jax.lax.dynamic_slice_in_dim(gu, start, chunk)
         return jax.lax.dynamic_update_slice_in_dim(
-            h, activation(mine[:, :f]) * mine[:, f:], start, 0)
+            h, activation(mine[:, :f]) * mine[:, f:] if gated
+            else activation(mine), start, 0)
 
     return _over_chunks(filled, n, chunk, body,
                         _zeros((n, f), gu.dtype, {"filled": filled}))
 
 
-def _gate_bwd(d_h, gu, filled, activation, impl: str):
-    """(n, 2f): the gradient of :func:`_gate` to ``gu``, in the same
+def _gate_bwd(d_h, gu, filled, activation, impl: str, gated: bool = True):
+    """The gradient of :func:`_gate` to ``gu`` (like ``gu``), in the same
     rows."""
     n, f = d_h.shape
     if impl == "megablox":
-        return _kernels().gating_bwd(d_h, gu, filled, activation,
-                                    _gating_tile(n, f))
+        kernel = _kernels().gating_bwd if gated \
+            else _kernels().activation_bwd
+        return kernel(d_h, gu, filled, activation, _gating_tile(n, f))
     chunk = routing_chunk_rows(n)
+    split = (lambda mine: (mine[:, :f], mine[:, f:])) if gated \
+        else (lambda mine: (mine,))
+    fn = (lambda g, u: activation(g) * u) if gated else activation
 
     def body(start, below, fresh, d_gu):
         mine = jax.lax.dynamic_slice_in_dim(gu, start, chunk)
-        _, back = jax.vjp(lambda g, u: activation(g) * u,
-                          mine[:, :f], mine[:, f:])
+        _, back = jax.vjp(fn, *split(mine))
         return jax.lax.dynamic_update_slice_in_dim(
             d_gu, jnp.concatenate(back(jax.lax.dynamic_slice_in_dim(
                 d_h, start, chunk)), axis=1), start, 0)
 
     return _over_chunks(filled, n, chunk, body,
-                        _zeros((n, 2 * f), gu.dtype, {"filled": filled}))
+                        _zeros(gu.shape, gu.dtype, {"filled": filled}))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6))
-def _held_experts(rows, w_gu, w_down, items, filled, activation, impl: str):
-    """(n, d): the held experts' gated feed-forward of each filled row of
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _held_experts(rows, w_gu, w_down, items, filled, activation, impl: str,
+                  gated: bool = True):
+    """(n, d): the held experts' feed-forward of each filled row of
     ``rows`` (n, d), ordered by expert, ``items`` (held,) rows an expert and
     ``filled`` their sum; ``w_gu`` (held, d, 2f) the gate's and the up
-    matrix joined, ``w_down`` (held, f, d), all of one dtype (bfloat16);
-    every pass by ``impl`` (:func:`grouped_matmul_impl`). No pass reads or
-    writes a row past the tile that holds row ``filled - 1``; the rows
-    behind hold what the memory held, and may meet nothing but a product
-    that skips them or a ``where``."""
+    matrix joined or, for experts that are not ``gated`` (two products and
+    an activation of its own between them), the up matrix alone (held, d,
+    f); ``w_down`` (held, f, d), all of one dtype (bfloat16); every pass by
+    ``impl`` (:func:`grouped_matmul_impl`). No pass reads or writes a row
+    past the tile that holds row ``filled - 1``; the rows behind hold what
+    the memory held, and may meet nothing but a product that skips them or
+    a ``where``."""
     return _held_experts_fwd(rows, w_gu, w_down, items, filled, activation,
-                             impl)[0]
+                             impl, gated)[0]
 
 
-def _held_experts_fwd(rows, w_gu, w_down, items, filled, activation, impl):
+def _held_experts_fwd(rows, w_gu, w_down, items, filled, activation, impl,
+                      gated=True):
     gu = _product(rows, w_gu, items, impl)
-    h = _gate(gu, filled, activation, impl)
+    h = _gate(gu, filled, activation, impl, gated)
     out = _product(h, w_down, items, impl)
     return out, (rows, gu, h, w_gu, w_down, items, filled)
 
 
-def _held_experts_bwd(activation, impl, residuals, d_out):
+def _held_experts_bwd(activation, impl, gated, residuals, d_out):
     rows, gu, h, w_gu, w_down, items, filled = residuals
     d_h = _product(d_out, w_down, items, impl, transposed=True)
-    d_gu = _gate_bwd(d_h, gu, filled, activation, impl)
+    d_gu = _gate_bwd(d_h, gu, filled, activation, impl, gated)
     # one product, its sums in float32 over the 2f columns: the gate's and
     # the up product's input gradients added before they are rounded
     d_rows = _product(d_gu, w_gu, items, impl, transposed=True)
@@ -668,8 +694,10 @@ def bias_moved_tokens(logits, experts):
 
 def dropless_experts(x, weights, experts, w_gate, w_up, w_down, first,
                      activation=nn.relu):
-    """The held experts' part of a gated expert layer (``activation`` on
-    the gate's product: ReLU or, as the description names it, SiLU).
+    """The held experts' part of an expert layer: gated (``activation`` on
+    the gate's product: ReLU or, as the description names it, SiLU) or,
+    with ``w_gate`` None, of two matrices, ``activation`` (a squared ReLU
+    among them) on the up product.
 
     x (t, d); weights, experts (t, k) from :func:`route_top_k`; w_gate,
     w_up (held, d, f), w_down (held, f, d): experts ``first .. first +
@@ -688,7 +716,7 @@ def dropless_experts(x, weights, experts, w_gate, w_up, w_down, first,
     """
     t, d = x.shape
     k = experts.shape[1]
-    held = w_gate.shape[0]
+    held, gated = w_up.shape[0], w_gate is not None
     n = t * k
     with trace.scope("moe.dispatch"):
         local = experts - first
@@ -700,10 +728,11 @@ def dropless_experts(x, weights, experts, w_gate, w_up, w_down, first,
         # gate and up are ONE product of 2f columns: joined in the cast
         # that is made anyway (one fusion where two casts ran); autodiff
         # cuts the joined matrix's gradient back into the two leaves'
-        w_gu = jnp.concatenate([w_gate, w_up], axis=2).astype(jnp.bfloat16)
-        out = _held_experts(rows, w_gu, w_down.astype(jnp.bfloat16),
-                            plan["items"], plan["filled"], activation,
-                            grouped_matmul_impl(n, d, w_gate.shape[2]))
+        w_gu = jnp.concatenate([w_gate, w_up], axis=2) if gated else w_up
+        out = _held_experts(rows, w_gu.astype(jnp.bfloat16),
+                            w_down.astype(jnp.bfloat16), plan["items"],
+                            plan["filled"], activation,
+                            grouped_matmul_impl(n, d, w_up.shape[2]), gated)
     with trace.scope("moe.combine"):
         y = _combine(out, weights, plan)
     # the items that found no row in the buffers: 0 while the buffers have
@@ -734,6 +763,9 @@ class DroplessMoE(nn.Module):
     activation: str = "relu"
     rule: RoutingRule = RoutingRule()
     shared_d_ff: int = 0
+    #: False: experts of two matrices, ``activation`` on the up product, no
+    #: gate (the shared one too)
+    gated: bool = True
 
     @nn.compact
     @trace.scope("moe")
@@ -751,16 +783,17 @@ class DroplessMoE(nn.Module):
             name, with_mesh_partitioning(init, ("ep", None, None)), shape)
             for name, shape in (("gate", (count, d, self.d_ff)),
                                 ("up", (count, d, self.d_ff)),
-                                ("down", (count, self.d_ff, d)))}
+                                ("down", (count, self.d_ff, d)))
+            if self.gated or name != "gate"}
         logits = logits.reshape(b * s, -1)
         weights, experts = route_top_k(logits, self.top_k, self.rule, bias)
-        act = _ACTIVATIONS[self.activation]
+        act = ACTIVATIONS[self.activation]
         mesh = active_mesh()
         ep = dict(mesh.shape).get("ep", 1) if mesh is not None else 1
         if ep == 1:
             y, counts = dropless_experts(
-                x.reshape(b * s, d), weights, experts, w["gate"], w["up"],
-                w["down"], first, act)
+                x.reshape(b * s, d), weights, experts, w.get("gate"),
+                w["up"], w["down"], first, act)
         else:
             y, counts = _over_ep(mesh, ep, x.reshape(b * s, d), weights,
                                  experts, w, first, act)
@@ -773,14 +806,10 @@ class DroplessMoE(nn.Module):
         y = y.reshape(b, s, d)
         if self.shared_d_ff:
             with trace.scope("moe.shared"):
-                y = y + GatedFeedForward(
-                    d, self.shared_d_ff, self.activation,
-                    name="shared")(x).astype(y.dtype)
+                shared = GatedFeedForward if self.gated else PlainFeedForward
+                y = y + shared(d, self.shared_d_ff, self.activation,
+                               name="shared")(x).astype(y.dtype)
         return y
-
-
-#: a published config's ``hidden_act`` -> the gate's activation
-_ACTIVATIONS = {"relu": nn.relu, "silu": nn.silu}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -800,6 +829,8 @@ class RoutedSpec:
     shared_d_ff: int
     rule: RoutingRule
     router_after_mixer: bool
+    #: False: experts of two matrices and no gate, the shared one too
+    gated: bool = True
 
     kind = "routed"
 
@@ -828,7 +859,7 @@ class RoutedSpec:
             (self.n_experts,)) if self.rule.bias else None
         return DroplessMoE(
             block.d_model, self.d_ff, self.n_experts, self.top_k, self.held,
-            self.activation, self.rule, self.shared_d_ff,
+            self.activation, self.rule, self.shared_d_ff, self.gated,
             name="experts")(m, logits, bias)
 
     def counts(self):
@@ -839,8 +870,13 @@ class RoutedSpec:
     def products(self, d_model):
         """The shared experts' three, under the gated feed-forward's names
         (the experts' own buffers are no candidates)."""
-        return GatedSpec(self.shared_d_ff, self.activation).products(
-            d_model) if self.shared_d_ff else []
+        if not self.shared_d_ff:
+            return []
+        down, (width, ups) = GatedSpec(
+            self.shared_d_ff, self.activation).products(d_model)
+        if not self.gated:  # two matrices: no gate's product to keep
+            ups = {n: b for n, b in ups.items() if n != GatedSpec.KEPT["gate"]}
+        return [down, (width, ups)]
 
     def under_tp(self, tp: int):
         # the experts split over ``ep``; the shared ones' width is counted
@@ -852,10 +888,12 @@ class RoutedSpec:
         product they take and how the held experts' part runs, the rows of
         their buffers and of a trip of the routing's loops; where the rule
         is not the plain one, the rule, the shared experts and the leading
-        dense layers beside the counts."""
+        dense layers beside the counts (``depth``: the pattern's layers that
+        have a feed-forward); where the experts are not gated, that and the
+        layers' published numbers."""
         rows = step.tokens * self.top_k
         # buffer rows, model width, an expert's width: what the route asks
-        how = describe_experts(rows, step.d_model, self.d_ff)
+        how = describe_experts(rows, step.d_model, self.d_ff, self.gated)
         said = {"routed_over": self.n_experts, "top_k": self.top_k,
                 "held": list(self.held), "products": how["products"],
                 "experts": how, "buffer_rows": rows,
@@ -865,6 +903,8 @@ class RoutedSpec:
                 scoring=self.rule.scoring, bias=self.rule.bias,
                 scale=self.rule.scale, shared_d_ff=self.shared_d_ff,
                 dense_layers=depth - len(layers), d_ff=step.d_ff)
+        if not self.gated:  # the layers' published numbers, not the first n
+            said.update(gated=False, layers=[la.number for la in layers])
         return {"moe": said}
 
 
@@ -876,21 +916,24 @@ def _over_ep(mesh, ep: int, x, weights, experts, w, first: int,
     them all). ``chunks`` is the fullest chip's."""
     from jax.sharding import PartitionSpec as P
 
-    count = w["gate"].shape[0]
+    count = w["up"].shape[0]
     if count % ep:
         raise ValueError(f"{count} held experts do not divide over ep={ep}")
+    names = [n for n in ("gate", "up", "down") if n in w]
 
-    def local(x, weights, experts, gate, up, down):
+    def local(x, weights, experts, *mats):
         mine = first + jax.lax.axis_index("ep") * (count // ep)
-        y, counts = dropless_experts(x, weights, experts, gate, up, down,
-                                     mine, activation)
+        held = dict(zip(names, mats))
+        y, counts = dropless_experts(x, weights, experts, held.get("gate"),
+                                     held["up"], held["down"], mine,
+                                     activation)
         return (jax.lax.psum(y, "ep"), counts["items"],
                 jax.lax.psum(counts["dropped"], "ep"),
                 jax.lax.pmax(counts["chunks"], "ep"))
 
     y, items, dropped, chunks = jax.shard_map(
         local, mesh=mesh,
-        in_specs=(P(), P(), P(), P("ep"), P("ep"), P("ep")),
+        in_specs=(P(), P(), P()) + (P("ep"),) * len(names),
         out_specs=(P(), P("ep"), P(), P()), check_vma=False,
-    )(x, weights, experts, w["gate"], w["up"], w["down"])
+    )(x, weights, experts, *(w[n] for n in names))
     return y, {"items": items, "dropped": dropped, "chunks": chunks}

@@ -9,6 +9,8 @@ of which the held experts fill the first ``filled``.
   program a tile of rows, the grid as long as the filled rows need: a tile
   past ``filled`` is not visited and its output rows hold what the memory
   held. In float32 from bfloat16 operands, rounded once.
+- :func:`activation` and :func:`activation_bwd`: the same pass for experts
+  that are not gated, ``h = act(u)`` over the one product (n, f).
 - :func:`tgmm`: a group's weight gradient ``lhs[rows].T @ rhs[rows]``,
   megablox's too, from both operands as they lie, (rows, width) row-major.
 
@@ -92,6 +94,35 @@ def gating_bwd(d_h, gu, filled, activation, tile: int, *, interpret=False):
     return _over_filled_tiles(
         functools.partial(_gating_bwd_kernel, activation=activation),
         "expert_gating_bwd", filled, tile, (d_h, gu), gu.shape[1], interpret)
+
+
+def _activation_kernel(u_ref, h_ref, *, activation):
+    h_ref[...] = activation(u_ref[...].astype(jnp.float32)).astype(
+        h_ref.dtype)
+
+
+def _activation_bwd_kernel(d_h_ref, u_ref, d_u_ref, *, activation):
+    _, back = jax.vjp(activation, u_ref[...].astype(jnp.float32))
+    d_u_ref[...] = back(d_h_ref[...].astype(jnp.float32))[0].astype(
+        d_u_ref.dtype)
+
+
+def activation(u, filled, act, tile: int, *, interpret=False):
+    """(n, f): ``act(u)`` of experts that are not gated, in the rows
+    of the tiles that hold a row under ``filled``; ``tile`` divides n, f is
+    the whole width (no whole lanes needed)."""
+    return _over_filled_tiles(
+        functools.partial(_activation_kernel, activation=act),
+        "expert_activation", filled, tile, (u,), u.shape[1], interpret)
+
+
+def activation_bwd(d_h, u, filled, act, tile: int, *, interpret=False):
+    """(n, f): the gradient of :func:`activation` to ``u`` from ``d_h``, in
+    the same tiles."""
+    return _over_filled_tiles(
+        functools.partial(_activation_bwd_kernel, activation=act),
+        "expert_activation_bwd", filled, tile, (d_h, u), u.shape[1],
+        interpret)
 
 
 def tgmm(lhs, rhs, group_sizes, tiling, *, interpret=False):

@@ -154,7 +154,7 @@ def _local(q, k, v, gr, br, mx):
 def _carry(loc, s, mx):
     """(the chunk's output (C, d_v), the state that leaves it, the
     corrected values) from the state ``s`` (d_k, d_v) that enters."""
-    u = loc["ut"] - _mm(loc["w"], s, _NN, mx)
+    u = loc["ut"] - _mm(loc["w"], s, _NN, mx) if "w" in loc else loc["ut"]
     o = _mm(loc["qg"], s, _NN, mx) + _mm(loc["p"], u, _NN, mx)
     return o, loc["gam_last"] * s + _mm(loc["kd"], u, _TN, mx), u
 
@@ -163,9 +163,9 @@ def _carry_back(loc, do, ds_out, mx):
     """(the corrected values' cotangent, the entering state's) from the
     output's and the leaving state's."""
     du = _mm(loc["p"], do, _TN, mx) + _mm(loc["kd"], ds_out, _NN, mx)
-    ds = _mm(loc["qg"], do, _TN, mx) + loc["gam_last"] * ds_out \
-        - _mm(loc["w"], du, _TN, mx)
-    return du, ds
+    ds = _mm(loc["qg"], do, _TN, mx) + loc["gam_last"] * ds_out
+    corrected = _mm(loc["w"], du, _TN, mx) if "w" in loc else 0.0
+    return du, ds - corrected
 
 
 def _local_back(q, k, loc, s, u, do, du, ds_out, mx):
@@ -441,3 +441,283 @@ def gated_delta_rule(q, k, v, g, beta, *, interpret=None):
     starts at zero. ``interpret`` (tests): run the kernels whatever the
     backend, interpreted or not."""
     return _rule(q, k, v, g, beta, interpret)
+
+
+# ---------------------------------------------------------------------------
+# the scalar decay rule: the same walk without corrections, q and k shared
+# by a group of heads
+
+
+#: what a rematerialised block keeps of :func:`scalar_decay_rule`, as
+#: ``REMAT_KEEPS`` is of the delta rule: the output and the chunks' entering
+#: states (4 d_k d_v bytes a chunk and head)
+SCALAR_DECAY_KEEPS = ("ssd.out", "ssd.states")
+
+
+def _local_decay(q, k, qk, v, gr):
+    """:func:`_local` of the rule without corrections (A = 0, T = I, U = V:
+    a chunk's parts without ``"w"``), for one head of a group: from the
+    group's q, k (C, d_k) and their product ``qk`` (C, C), made once a
+    group, the head's v (C, d_v) and the row (1, C) of its running log
+    decay."""
+    c = q.shape[0]
+    f = gr.dtype
+    row, col = _grid(c)
+    low = row >= col
+    gc = _as_column(gr, row, col)
+    decay = jnp.where(low, jnp.exp(jnp.where(low, gc - gr, 0.0)), 0.0)
+    gam = jnp.exp(gc)
+    last = jnp.sum(jnp.where(col[0:1] == c - 1, gr, 0.0), axis=1,
+                   keepdims=True)
+    e = jnp.exp(last - gc)
+    kf = k.astype(f)
+    return {"decay": decay, "gam": gam, "kf": kf, "e": e, "ut": v.astype(f),
+            "p": decay * qk, "qg": gam * q.astype(f), "kd": e * kf,
+            "gam_last": jnp.exp(last)}
+
+
+def _head_fwd(q, k, qk, v, gr, s, mx):
+    """(a head's output (C, d_v), the state that leaves the chunk)."""
+    return _carry(_local_decay(q, k, qk, v, gr), s, mx)[:2]
+
+
+def _head_bwd(q, k, qk, v, gr, s, do, ds_out, mx):
+    """A head's part of a chunk's backward pass: (dq's and dk's terms
+    through the states (C, d_k), the masked scores' cotangent (C, C), which
+    the group sums before it meets k and q, dv, dgamma (1, C), the
+    entering state's cotangent)."""
+    loc = _local_decay(q, k, qk, v, gr)
+    gam, e, f = loc["gam"], loc["e"], loc["gam"].dtype
+    row, col = _grid(q.shape[0])
+    rowsum = lambda x: jnp.sum(x, axis=1, keepdims=True)  # noqa: E731
+    total = lambda x: jnp.sum(rowsum(x), axis=0, keepdims=True)  # noqa: E731
+    dv, ds = _carry_back(loc, do, ds_out, mx)
+    dp = jnp.where(row >= col, _mm(do, v, _NT, mx), 0.0)
+    dqs = _mm(do, s, _NT, mx)
+    dkd = _mm(v, ds_out, _NT, mx)
+    # gamma reaches the decay of p (a row's less a column's), exp(gamma) on
+    # q, exp(gamma_C - gamma) on k and, at the chunk's last token,
+    # exp(gamma_C) on the state and on every k
+    ek = e * rowsum(dkd * loc["kf"])
+    through = dp * loc["p"]
+    at_last = loc["gam_last"] * total(ds_out * s) + total(ek)
+    dgamma = _as_row(rowsum(through) - ek + rowsum(dqs * q.astype(f)) * gam,
+                     row, col) \
+        - jnp.sum(through, axis=0, keepdims=True) \
+        + jnp.where(col[0:1] == q.shape[0] - 1, at_last, 0.0)
+    return gam * dqs, e * dkd, dp * loc["decay"], dv, dgamma, ds
+
+
+def _group_fwd(q, k, v, g, s, mx):
+    """A chunk of a group: q, k (C, d_k), v (Hg, C, d_v), g (Hg, C) the
+    heads' running log decays, s (Hg, d_k, d_v) -> (o like v, s')."""
+    qk = _mm(q, k, _NT, mx)
+    o, s_new = zip(*(_head_fwd(q, k, qk, v[h], g[h:h + 1], s[h], mx)
+                     for h in range(v.shape[0])))
+    return jnp.stack(o), jnp.stack(s_new)
+
+
+def _group_bwd(q, k, v, g, s, do, ds_out, mx):
+    """(dq, dk (C, d_k), dv like v, dgamma like g, ds like s) of a chunk of
+    a group."""
+    qk = _mm(q, k, _NT, mx)
+    dq, dk, dpd, dv, dg, ds = zip(*(
+        _head_bwd(q, k, qk, v[h], g[h:h + 1], s[h], do[h], ds_out[h], mx)
+        for h in range(v.shape[0])))
+    dpd = sum(dpd)
+    return (sum(dq) + _mm(dpd, k, _NN, mx), sum(dk) + _mm(dpd, q, _TN, mx),
+            jnp.stack(dv), jnp.concatenate(dg, axis=0), jnp.stack(ds))
+
+
+def _decay_fwd_kernel(q_ref, k_ref, v_ref, g_ref, o_ref, states_ref, s_scr):
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        s_scr[...] = jnp.zeros_like(s_scr)
+
+    mx = q_ref.dtype
+    q, k = q_ref[...], k_ref[...]
+    qk = _mm(q, k, _NT, mx)
+    for h in range(v_ref.shape[0]):
+        s = s_scr[h]
+        states_ref[h] = s
+        o, s_scr[h] = _head_fwd(q, k, qk, v_ref[h], g_ref[h:h + 1, :], s, mx)
+        o_ref[h] = o.astype(o_ref.dtype)
+
+
+def _decay_bwd_kernel(q_ref, k_ref, v_ref, g_ref, states_ref, do_ref,
+                      dq_ref, dk_ref, dv_ref, dg_ref, ds_scr):
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        ds_scr[...] = jnp.zeros_like(ds_scr)
+
+    mx = q_ref.dtype
+    q, k = q_ref[...], k_ref[...]
+    qk = _mm(q, k, _NT, mx)
+    dq = dk = dpd = 0.0
+    for h in range(v_ref.shape[0]):
+        dq_h, dk_h, dpd_h, dv, dg_ref[h:h + 1, :], ds_scr[h] = _head_bwd(
+            q, k, qk, v_ref[h], g_ref[h:h + 1, :], states_ref[h], do_ref[h],
+            ds_scr[h], mx)
+        dv_ref[h] = dv.astype(dv_ref.dtype)
+        dq, dk, dpd = dq + dq_h, dk + dk_h, dpd + dpd_h
+    dq_ref[...] = (dq + _mm(dpd, k, _NN, mx)).astype(dq_ref.dtype)
+    dk_ref[...] = (dk + _mm(dpd, q, _TN, mx)).astype(dk_ref.dtype)
+
+
+def _decay_specs(c, dk, dv, hg, n, backward: bool):
+    at = (lambda i: n - 1 - i) if backward else (lambda i: i)
+    return {"qk": pl.BlockSpec((None, None, c, dk),
+                               lambda b, g, i: (b, g, at(i), 0)),
+            "v": pl.BlockSpec((None, None, hg, c, dv),
+                              lambda b, g, i: (b, g, 0, at(i), 0)),
+            "g": pl.BlockSpec((None, None, None, hg, c),
+                              lambda b, g, i: (b, g, at(i), 0, 0)),
+            "state": pl.BlockSpec((None, None, None, hg, dk, dv),
+                                  lambda b, g, i: (b, g, at(i), 0, 0, 0))}
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _decay_fwd_pallas(q, k, v, gam, interpret: bool = False):
+    b, grp, t, dk = q.shape
+    hg, dv, n, c = v.shape[2], v.shape[-1], gam.shape[2], gam.shape[-1]
+    sp = _decay_specs(c, dk, dv, hg, n, False)
+    return pl.pallas_call(
+        _decay_fwd_kernel, name="ssd_scan_fwd", grid=(b, grp, n),
+        in_specs=[sp["qk"], sp["qk"], sp["v"], sp["g"]],
+        out_specs=[sp["v"], sp["state"]],
+        out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
+                   jax.ShapeDtypeStruct((b, grp, n, hg, dk, dv), gam.dtype)],
+        scratch_shapes=[pltpu.VMEM((hg, dk, dv), gam.dtype)],
+        compiler_params=_PARAMS, interpret=interpret)(q, k, v, gam)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _decay_bwd_pallas(q, k, v, gam, states, do, interpret: bool = False):
+    b, grp, t, dk = q.shape
+    hg, dv, n, c = v.shape[2], v.shape[-1], gam.shape[2], gam.shape[-1]
+    sp = _decay_specs(c, dk, dv, hg, n, True)
+    like = lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype)  # noqa: E731
+    return pl.pallas_call(
+        _decay_bwd_kernel, name="ssd_scan_bwd", grid=(b, grp, n),
+        in_specs=[sp["qk"], sp["qk"], sp["v"], sp["g"], sp["state"],
+                  sp["v"]],
+        out_specs=[sp["qk"], sp["qk"], sp["v"], sp["g"]],
+        out_shape=[like(q), like(k), like(v), like(gam)],
+        scratch_shapes=[pltpu.VMEM((hg, dk, dv), gam.dtype)],
+        compiler_params=_PARAMS, interpret=interpret)(
+            q, k, v, gam, states, do)
+
+
+def _group_chunks(q, k, v, n):
+    """q, k (B, G, T, d_k) -> (B, G, N, C, d_k); v (B, G, Hg, T, d_v) ->
+    (B, G, N, Hg, C, d_v): the chunk axis where :func:`_walk` takes it."""
+    cut = lambda x: x.reshape(  # noqa: E731
+        *x.shape[:-2], n, x.shape[-2] // n, x.shape[-1])
+    return cut(q), cut(k), jnp.moveaxis(cut(v), 3, 2)
+
+
+def _decay_fwd_xla(q, k, v, gam):
+    qc, kc, vc = _group_chunks(q, k, v, gam.shape[2])
+    fwd = _over_chunks(functools.partial(_group_fwd, mx=q.dtype), 2)
+
+    def step(s, part):
+        o, s_new = fwd(part["q"], part["k"], part["v"], part["g"], s)
+        return s_new, (o, s)
+
+    init = jnp.zeros(v.shape[:3] + (q.shape[-1], v.shape[-1]), gam.dtype)
+    _, (o, states) = _walk(step, init,
+                           {"q": qc, "k": kc, "v": vc, "g": gam})
+    return jnp.moveaxis(o, 2, 3).reshape(v.shape).astype(v.dtype), states
+
+
+def _decay_bwd_xla(q, k, v, gam, states, do):
+    qc, kc, vc = _group_chunks(q, k, v, gam.shape[2])
+    doc = _group_chunks(q, k, do, gam.shape[2])[2]
+    bwd = _over_chunks(functools.partial(_group_bwd, mx=q.dtype), 2)
+
+    def step(ds_out, part):
+        dq, dk, dv, dg, ds = bwd(part["q"], part["k"], part["v"], part["g"],
+                                 part["s"], part["do"], ds_out)
+        return ds, (dq, dk, dv, dg)
+
+    _, (dq, dk, dv, dg) = _walk(
+        step, jnp.zeros_like(states[:, :, 0]),
+        {"q": qc, "k": kc, "v": vc, "g": gam, "s": states, "do": doc},
+        reverse=True)
+    return (dq.reshape(q.shape).astype(q.dtype),
+            dk.reshape(k.shape).astype(k.dtype),
+            jnp.moveaxis(dv, 2, 3).reshape(v.shape).astype(v.dtype), dg)
+
+
+def _decay_operands(q, k, v, g):
+    """Groups first, the length padded to whole chunks: q, k (B, G, T',
+    d_k), v (B, G, Hg, T', d_v) and ``gam`` (B, G, N, Hg, C), a chunk's
+    running log decay a head, float32."""
+    f = jnp.promote_types(g.dtype, jnp.float32)
+    b, grp = q.shape[0], q.shape[2]
+    vh = _heads_first(v)
+    gam = jnp.cumsum(_heads_first(g).astype(f).reshape(
+        b, grp, g.shape[2] // grp, -1, CHUNK), axis=-1)
+    return (_heads_first(q), _heads_first(k),
+            vh.reshape(b, grp, -1, *vh.shape[2:]), jnp.moveaxis(gam, 2, 3))
+
+
+def _decay_forward(q, k, v, g, interpret=None):
+    with trace.scope("ssd.core"):
+        qh, kh, vh, gam = _decay_operands(q, k, v, g)
+        if _by_kernels(interpret):
+            o, states = _decay_fwd_pallas(qh, kh, vh, gam,
+                                          interpret=bool(interpret))
+        else:
+            o, states = _decay_fwd_xla(qh, kh, vh, gam)
+        return _tokens_first(o.reshape(o.shape[0], -1, *o.shape[3:]),
+                             v), states
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _decay_rule(q, k, v, g, interpret):
+    return _decay_forward(q, k, v, g, interpret)[0]
+
+
+def _decay_rule_fwd(q, k, v, g, interpret):
+    o, states = _decay_forward(q, k, v, g, interpret)
+    o, states = (checkpoint_name(x, name)
+                 for x, name in zip((o, states), SCALAR_DECAY_KEEPS))
+    return o, (q, k, v, g, states)
+
+
+def _decay_rule_bwd(interpret, kept, do):
+    q, k, v, g, states = kept
+    with trace.scope("ssd.core"):
+        qh, kh, vh, gam = _decay_operands(q, k, v, g)
+        doh = _heads_first(do).astype(v.dtype).reshape(vh.shape)
+        if _by_kernels(interpret):
+            dq, dk, dv, dgam = _decay_bwd_pallas(
+                qh, kh, vh, gam, states, doh, interpret=bool(interpret))
+        else:
+            dq, dk, dv, dgam = _decay_bwd_xla(qh, kh, vh, gam, states, doh)
+        # gamma is the chunk's running sum of g: g_i collects gamma_i..C
+        dg = jnp.flip(jnp.cumsum(jnp.flip(dgam, -1), -1), -1)
+        heads = lambda x: x.reshape(  # noqa: E731  (B, G, Hg, ...) -> (B, H, ...)
+            x.shape[0], -1, *x.shape[3:])
+        dg = jnp.moveaxis(dg, 2, 3)                       # (B, G, Hg, N, C)
+        return (_tokens_first(dq, q), _tokens_first(dk, k),
+                _tokens_first(heads(dv), v),
+                _tokens_first(heads(dg.reshape(*dg.shape[:3], -1)), g))
+
+
+_decay_rule.defvjp(_decay_rule_fwd, _decay_rule_bwd)
+
+
+def scalar_decay_rule(q, k, v, g, *, interpret=None):
+    """``o`` (B, T, H, d_v) of S_t = exp(g_t) S_{t-1} + k_t v_t^T, o_t =
+    S_t^T q_t, a state (d_k, d_v) a (row, head) that starts at zero
+    (Mamba-2's recurrence in its matmul form, arXiv:2405.21060: q = C, k =
+    B, v = dt x, g = dt A): the chunks' walk of :func:`gated_delta_rule`
+    without corrections. ``q``, ``k`` (B, T, G, d_k) are shared by groups
+    of H / G heads (head h reads group h // (H / G)); ``v`` (B, T, H,
+    d_v); the log decays ``g`` <= 0 (B, T, H), a scalar a head and token.
+    On the Pallas route a program is a (row, GROUP, chunk): it makes q k^T
+    once and walks the group's heads under a mask each. The route is
+    :func:`linear_attention_route`'s; ``interpret`` as there."""
+    return _decay_rule(q, k, v, g, interpret)

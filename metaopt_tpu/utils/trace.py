@@ -71,6 +71,11 @@ SCOPES = ("embed", "attention", "attention.core", "ffn", "readout_xent",
           # a state-space mixer (models/lm_layers.StateSpaceMixer): all of it,
           # and the selective scan (ops/selective_scan.py), both directions
           "ssm", "ssm.core",
+          # a Mamba-2 mixer (models/lm_layers.ScalarDecayMixer): all of it (the
+          # convolution and the gated group norm among it), and the chunked
+          # scan of the scalar decay rule (ops/linear_attention.py), both
+          # directions
+          "ssd", "ssd.core",
           # a gated memory unit (models/lm_layers.GatedMemoryUnit): an earlier
           # layer's scan output gated by this layer's projection
           "gmu",
@@ -528,6 +533,17 @@ def print_routes(recs: List[dict]) -> None:
                               f"; layer {how['hands_on']} hands on its scan "
                               "output" if how["hands_on"] else ""))
                     continue
+                if kind == "ssd":
+                    print(f"trial {r['trial']}: Mamba-2 layers "
+                          f"{_runs(how['layers'])}: scalar decay rule, "
+                          f"{how['heads']} heads of {how['head_dim']} on "
+                          f"{how['groups']} groups' B and C, state "
+                          f"{how['state']}, convolution of {how['conv']} "
+                          f"with a bias, norm gated over groups of "
+                          f"{how['norm_group']}, chunks of {how['chunk']} by "
+                          f"{how['route']}, a program a "
+                          f"{how['program']}")
+                    continue
                 if kind == "gmu":
                     print(f"trial {r['trial']}: gated memory units "
                           f"{_runs(how['layers'])}: {how['d_inner']} wide, "
@@ -580,26 +596,36 @@ def print_routes(recs: List[dict]) -> None:
                       f"{first + count - 1} of {moe['routed_over']} held, "
                       f"top {moe['top_k']}, products by {moe['products']}"
                       + (f", gate and up as {how['gate_up']}, gating by "
-                         f"{how['gating']}" if how else ""))
+                         f"{how['gating']}" if how else "")
+                      + (f", width {how['width']}" if how
+                         and "width" in how else ""))
                 if "scoring" in moe:
                     bias = " on score + bias" if moe["bias"] else ""
                     print(f"trial {r['trial']}: experts: "
                           f"{moe['scoring']} scores, "
                           f"{moe['top_k']} of {moe['routed_over']}{bias}, "
                           f"weights scaled {moe['scale']:g}, {count} held, "
-                          f"shared as one of {moe['shared_d_ff']}; layers "
-                          f"{_runs(range(moe['dense_layers']))} dense "
-                          f"{moe['d_ff']}")
+                          f"shared as one of {moe['shared_d_ff']}" + (
+                              f"; layers {_runs(range(moe['dense_layers']))}"
+                              f" dense {moe['d_ff']}"
+                              if moe["dense_layers"] else "") + (
+                              f"; layers {_runs(moe['layers'])}: two "
+                              "matrices an expert, no gate"
+                              if not moe.get("gated", True) else ""))
         counts = attrs.get("moe") if r["name"] == "trial.train" else None
         if counts:
             # the counts are the routed layers': behind the leading dense ones
+            # (or where the setup names them, e.g. one sublayer a block)
             dense = held.get(r["trial"], {}).get("dense_layers", 0)
-            for layer, items in enumerate(counts["items"], dense):
+            numbers = held.get(r["trial"], {}).get("layers") or range(
+                dense, dense + len(counts["items"]))
+            for i, (layer, items) in enumerate(zip(numbers,
+                                                   counts["items"])):
                 mean = sum(items) / len(items)
                 print(f"trial {r['trial']}: layer {layer}: {sum(items)} "
                       f"items to held experts, fullest "
                       f"{max(items) / mean if mean else 0.0:.2f}x the mean, "
-                      f"{counts['dropped'][layer - dense]} dropped")
+                      f"{counts['dropped'][i]} dropped")
             said = held.get(r["trial"], {})
             rows = (attrs.get("steps", 0) * len(counts["items"])
                     * said.get("buffer_rows", 0))

@@ -555,3 +555,58 @@ def test_the_held_experts_part_compiles_for_a_v5e(one_chip, shape):
     assert "expert_gating_bwd" in text
     buffers = re.compile(rf"= \w+\[(\d+,)?{n}(,\d+)?\]\S* (transpose|copy)\(")
     assert [line for line in text.splitlines() if buffers.search(line)] == []
+
+
+# (tokens, heads, groups, state width, head width): the one-sublayer cell's
+# Mamba-2 blocks (64 heads of 64 on 8 groups' B and C of 128, one row of 8192
+# tokens), and a length that pads to whole chunks
+DECAYS = [(8192, 64, 8, 128, 64), (1000, 16, 2, 128, 64)]
+
+
+@pytest.mark.parametrize("shape", DECAYS, ids=lambda s: "x".join(map(str, s)))
+def test_the_scalar_decay_rule_s_kernels_compile_for_a_v5e(one_chip, shape):
+    """ops/linear_attention.py's ``ssd_scan_fwd`` and ``ssd_scan_bwd``
+    through Mosaic: a program a (row, group, chunk), a group's eight heads'
+    64-wide values and states as blocks of their own, transposed
+    operands."""
+    from metaopt_tpu.ops.linear_attention import scalar_decay_rule
+
+    t, h, g, n, p = shape
+    on_chip = lambda s, d: jax.ShapeDtypeStruct(  # noqa: E731
+        s, d, sharding=one_chip)
+    args = [on_chip((1, t, g, n), jnp.bfloat16)] * 2 + [
+        on_chip((1, t, h, p), jnp.bfloat16), on_chip((1, t, h), jnp.float32)]
+
+    def loss(q, k, v, g):
+        out = scalar_decay_rule(q, k, v, g, interpret=False)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+        *args).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "ssd_scan_fwd" in text and "ssd_scan_bwd" in text
+
+
+def test_two_matrix_experts_of_an_odd_width_compile_for_a_v5e(one_chip):
+    """models/moe.py's ``_held_experts`` without a gate at the one-sublayer
+    cell's sizes (8192 tokens x top 6, 8 held experts 1856 = 29 x 64 wide
+    on a model 2688 wide): six megablox calls whose every block takes the
+    width whole, the activation's pass and its gradient."""
+    from metaopt_tpu.models import moe
+    from metaopt_tpu.models.lm_layers import ACTIVATIONS
+
+    n, d, f, held = 49152, 2688, 1856, 8
+    on_chip = lambda s, dtype: jax.ShapeDtypeStruct(  # noqa: E731
+        s, dtype, sharding=one_chip)
+
+    def loss(rows, w_up, w_down, items):
+        out = moe._held_experts(rows, w_up, w_down, items, jnp.sum(items),
+                                ACTIVATIONS["relu2"], "megablox", False)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        on_chip((n, d), jnp.bfloat16), on_chip((held, d, f), jnp.bfloat16),
+        on_chip((held, f, d), jnp.bfloat16),
+        on_chip((held,), jnp.int32)).compile().as_text()
+    assert text.count("tpu_custom_call") == 8
+    assert "expert_activation_bwd" in text

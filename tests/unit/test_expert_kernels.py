@@ -206,6 +206,111 @@ def test_on_the_chip_s_route_output_and_gradients_match(on_the_kernels, share,
         assert not np.any(mine[part])
 
 
+# -- experts of two matrices: no gate, an activation of its own ----------------
+
+RELU2 = lambda x: jnp.square(jax.nn.relu(x))  # noqa: E731
+#: a width of no whole lanes, as the one-sublayer cell's 1856 = 29 x 64 is
+ODD = 192
+
+
+@pytest.mark.parametrize("filled", FILLED)
+def test_the_activation_s_pass_and_its_gradient_in_the_filled_rows(filled):
+    """``h = act(u)`` over the ONE product of experts that are not gated,
+    (n, f) at a width of a lane and a half, and its ``jax.vjp``; a tile
+    past the filled rows is not visited."""
+    key = jax.random.PRNGKey(2)
+    u = jax.random.normal(key, (N, ODD)).astype(jnp.bfloat16)
+    d_h = jax.random.normal(jax.random.fold_in(key, 1),
+                            (N, ODD)).astype(jnp.bfloat16)
+    want, back = jax.vjp(RELU2, u.astype(jnp.float32))
+    h = kernels.activation(u, jnp.int32(filled), RELU2, TILE, interpret=True)
+    d_u = kernels.activation_bwd(d_h, u, jnp.int32(filled), RELU2, TILE,
+                                 interpret=True)
+    assert h.shape == d_u.shape == (N, ODD) and h.dtype == d_u.dtype == u.dtype
+    np.testing.assert_array_equal(
+        np.asarray(h[:filled], np.float32),
+        np.asarray(want[:filled].astype(jnp.bfloat16), np.float32))
+    np.testing.assert_allclose(
+        np.asarray(d_u[:filled], np.float32),
+        np.asarray(back(d_h.astype(jnp.float32))[0][:filled]),
+        rtol=1e-2, atol=1e-6)
+    visited = -(-filled // TILE) * TILE
+    for out in (h, d_u):
+        assert np.isnan(np.asarray(out[visited:], np.float32)).all()
+
+
+@pytest.fixture(scope="module")
+def two_matrices_on_the_kernels():
+    """(program's {part}, reference's {part}) of ``dropless_experts``
+    without a gate, squared ReLU, experts ``ODD`` wide: the backend read as
+    the TPU, every Pallas call interpreted, the width one block."""
+    from metaopt_tpu.models import moe
+
+    patch = pytest.MonkeyPatch()
+    patch.setattr(jax, "default_backend", lambda: "tpu")
+    patch.setattr(moe, "_kernels", lambda: types.SimpleNamespace(**{
+        name: functools.partial(getattr(kernels, name), interpret=True)
+        for name in ("gmm", "tgmm", "activation", "activation_bwd")}))
+    ks = jax.random.split(jax.random.PRNGKey(12), 5)
+    x = jax.random.normal(ks[0], (T, D_MODEL))
+    cot = jax.random.normal(ks[1], (T, D_MODEL))
+    first, count = 4, 8
+    up = jax.random.normal(ks[2], (E, D_MODEL, ODD)) * D_MODEL ** -0.5
+    down = jax.random.normal(ks[3], (E, ODD, D_MODEL)) * ODD ** -0.5
+    weights, experts = moe.route_top_k(
+        2.0 * jax.random.normal(ks[4], (T, E)), TOPK)
+    mats = [m[first:first + count] for m in (up, down)]
+
+    def plain(x, w, u, d):
+        y = jnp.zeros_like(x)
+        for e in range(count):
+            we = jnp.sum(jnp.where(experts == first + e, w, 0.0), -1)
+            y = y + we[:, None] * jnp.dot(
+                RELU2(jnp.dot(x, u[e], precision=HI)), d[e], precision=HI)
+        return y
+
+    def both(fn):
+        def loss(x, w, u, d):
+            y = fn(x, w, u, d)
+            return jnp.sum(y * cot), y
+        grads, y = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3),
+                                    has_aux=True))(x, weights, *mats)
+        return dict(zip(TWO, map(np.asarray, (y,) + grads)))
+
+    try:
+        assert moe.grouped_matmul_impl(T * TOPK, D_MODEL, ODD) == "megablox"
+        said = moe.describe_experts(T * TOPK, D_MODEL, ODD, gated=False)
+        return (both(lambda x, w, u, d: moe.dropless_experts(
+            x, w, experts, None, u, d, first, RELU2)[0]), both(plain), said)
+    finally:
+        patch.undo()
+
+
+TWO = ["y", "x", "weights", "up", "down"]
+
+
+@pytest.mark.parametrize("part", TWO)
+def test_two_matrix_experts_on_the_chip_s_route_match(
+        two_matrices_on_the_kernels, part):
+    """Output and every gradient, bfloat16 products against float32: a
+    fiftieth of the reference's norm, a tenth for what passes through the
+    squared ReLU's kink."""
+    mine, ref, _ = two_matrices_on_the_kernels
+    assert mine[part].shape == ref[part].shape
+    assert np.linalg.norm(mine[part] - ref[part]) <= (
+        0.1 if part in ("x", "up") else 0.02) * np.linalg.norm(ref[part])
+
+
+def test_the_span_says_how_an_odd_width_is_tiled(two_matrices_on_the_kernels):
+    said = two_matrices_on_the_kernels[2]
+    assert said["gate_up"] == f"no gate: one product of {ODD} columns"
+    assert said["products"] == "megablox" and said["gating"] == "pallas"
+    assert said["width"].startswith(f"{ODD} is no whole lanes: one block")
+    tiles = said["tiles"]
+    assert tiles["gu"][2] == ODD and tiles["out"][1] == ODD
+    assert tiles["d_w_gu"][2] == ODD and tiles["d_w_down"][1] == ODD
+
+
 def test_the_models_load_without_the_kernels_file():
     """models/lm.py imports models/moe.py (through the description's reader)
     at its top: neither pulls in ops/experts.py (Pallas, megablox) before an expert

@@ -29,6 +29,51 @@ def expert_layer(held, logits_bias=None, seed=3, t=40, activation="relu"):
 GATED = {"top_k": 4, "normalised": True, "scale": 2.5}
 
 
+#: the nemotron_h family's layer at this file's sizes: experts of two
+#: matrices under a squared ReLU, a correction bias in the choice, a shared
+#: expert twice as wide
+TWO_MATRICES = {"top_k": 3, "normalised": True, "scale": 2.5}
+
+
+def _two_matrix_layer(held, seed=3, t=40):
+    """:func:`_expert_layer`'s five for the layer by ``TWO_MATRICES``, its
+    references chipbench/reference/ssd_lm.py's."""
+    from chipbench.reference import ssd_lm as reference
+    from metaopt_tpu.models.lm_layers import PlainFeedForward
+    from metaopt_tpu.models.moe import DroplessMoE, RoutingRule
+
+    layer = lambda held: DroplessMoE(  # noqa: E731
+        D, F, E, TWO_MATRICES["top_k"], held, "relu2",
+        RoutingRule("sigmoid", True, True, TWO_MATRICES["scale"]), 2 * F,
+        gated=False)
+    key = jax.random.PRNGKey(seed)
+    x = jax.random.normal(key, (1, t, D))
+    logits = 2.0 * jax.random.normal(jax.random.fold_in(key, 1), (1, t, E))
+    bias = 0.05 * jax.random.normal(jax.random.fold_in(key, 2), (E,))
+    full = nn.meta.unbox(layer((0, E)).init(key, x, logits, bias)["params"])
+    assert set(full) == {"up", "down", "shared"}          # no gate anywhere
+    assert set(full["shared"]) == {"up", "down"}
+    first, count = held
+    mine = {k: v if k == "shared" else v[first:first + count]
+            for k, v in full.items()}
+    y, state = layer(held).apply({"params": mine}, x, logits, bias,
+                                 mutable=["moe_stats"])
+
+    def ref(p, held):
+        cfg = {**TWO_MATRICES, "experts_held": list(held)}
+        each = {k: {f"e{e:02d}": p[k][e] for e in range(held[1])}
+                for k in ("up", "down")}
+        return reference._experts(
+            "float32", each, x[0],
+            reference.routing_weights(logits[0], bias, cfg), held[0], ()) \
+            + reference._plain("float32", p["shared"]["up"]["kernel"],
+                               p["shared"]["down"]["kernel"], x[0], ())
+
+    alike = PlainFeedForward(D, 2 * F, "relu2").apply(
+        {"params": full["shared"]}, x)[0].astype(jnp.float32)
+    return y[0], state["moe_stats"], ref(mine, held), ref(full, (0, E)), alike
+
+
 def _expert_layer(held, logits_bias=None, seed=3, t=40, activation="relu",
                   gated=False):
     """:func:`expert_layer`'s four and what every share computes alike (a
@@ -37,6 +82,8 @@ def _expert_layer(held, logits_bias=None, seed=3, t=40, activation="relu",
     from metaopt_tpu.models.lm_layers import GatedFeedForward
     from metaopt_tpu.models.moe import DroplessMoE, RoutingRule
 
+    if activation == "relu2":
+        return _two_matrix_layer(held, seed, t)
     act = {"relu": jax.nn.relu, "silu": jax.nn.silu}[activation]
     top_k = GATED["top_k"] if gated else TOPK
     layer = lambda held: DroplessMoE(  # noqa: E731
@@ -92,16 +139,23 @@ def test_a_share_gives_its_own_experts_part(held):
 @pytest.mark.parametrize("shares, activation, gated", [
     (SHARES, "relu", False),
     ([(first, 2) for first in range(0, E, 2)], "silu", False),
-    ([(first, 2) for first in range(0, E, 2)], "silu", True)],
+    ([(first, 2) for first in range(0, E, 2)], "silu", True),
+    ([(first, 2) for first in range(0, E, 2)], "relu2", False),
+    ([(first, 8) for first in (0, 8)], "relu2", False)],
     ids=["four-shares-relu", "eight-shares-silu",
-         "eight-shares-sigmoid-top4-shared"])
+         "eight-shares-sigmoid-top4-shared",
+         "eight-shares-two-matrices-bias-shared",
+         "two-shares-two-matrices-bias-shared"])
 def test_the_shares_add_up_to_the_uncut_layer(shares, activation, gated):
     """16 experts, top 3, four shares of 4 (gated ReLU) or eight of 2
     (gated SiLU): the partial outputs sum to what the uncut reference
     gives for the whole layer. And the laguna family's layer (sigmoid
     scores normalised over all the chosen, times 2.5, top 4, beside a
     shared expert): the eight shares' routed parts plus the shared expert,
-    which every share computes alike, counted once."""
+    which every share computes alike, counted once. And the nemotron_h
+    family's (experts of two matrices under a squared ReLU, no gate, top 3
+    of score + a correction bias, a shared expert twice as wide): eight
+    shares of two experts, and two of eight."""
     parts = [_expert_layer(held, activation=activation, gated=gated)
              for held in shares]
     total = sum(p[0] for p in parts) - (len(parts) - 1) * parts[0][4]

@@ -4,7 +4,7 @@ every operation the program writes has a layer (``layer_of``) and a
 direction (``direction``), in the forward, its second run under remat and
 the backward.
 
-The steps are the benchmark's seven model kinds at their rehearsal
+The steps are the benchmark's eight model kinds at their rehearsal
 sizes, lowered and not compiled. Each is lowered twice: as this backend
 routes it, and for the TPU with ``jax.default_backend`` answering "tpu", so
 that the Pallas kernels are on the path (lowering a kernel for the TPU needs
@@ -34,6 +34,8 @@ KINDS = {
     "latent-decoder": ("kanana-2-30b-a3b-ep8", "mla_lm_config"),
     "state-space-decoder": ("phi-4-mini-flash-vp8", "ssm_lm_config"),
     "gated-decoder": ("laguna-xs2-33b-a3b-ep8", "gated_lm_config"),
+    "one-sublayer-decoder": ("nemotron-3-nano-30b-a3b-ep16",
+                             "ssd_lm_config"),
 }
 #: no operation of the device: a literal, a function's end, and remat's own
 #: barrier around a block's kept values (jax names it ``.../remat2``)
@@ -185,7 +187,7 @@ def test_every_operation_of_a_train_step_has_a_layer(lowered_steps, kind,
     ("2017-base", False), ("pattern-decoder", True),
     ("selected-attention-decoder", True), ("hybrid-decoder", True),
     ("latent-decoder", True), ("state-space-decoder", True),
-    ("gated-decoder", True)])
+    ("gated-decoder", True), ("one-sublayer-decoder", True)])
 def test_a_step_has_operations_in_every_direction_it_runs(lowered_steps,
                                                          kind, remat):
     """Forward, backward and update in every step; the forward's second
@@ -201,7 +203,11 @@ def test_a_step_has_operations_in_every_direction_it_runs(lowered_steps,
     assert {"norm", "residual", "loss"} <= by_direction["forward"]
     assert {"norm", "loss"} <= by_direction["backward"]
     if remat:
-        assert {"norm", "residual"} <= by_direction["forward.again"]
+        assert "norm" in by_direction["forward.again"]
+        # a block of one sublayer ends in its one sum, which no backward
+        # pass reads: its second run makes none
+        assert ("residual" in by_direction["forward.again"]) \
+            == (kind != "one-sublayer-decoder")
         # the loss, the embedding and the head are outside the blocks
         assert not {"loss", "embed", "readout_xent", "optimizer"} \
             & by_direction["forward.again"]
@@ -226,7 +232,13 @@ def test_a_step_has_operations_in_every_direction_it_runs(lowered_steps,
     # two full and three window layers, one call each at its own head count
     ("gated-decoder", "flash_fwd", 5, 0),
     ("gated-decoder", "flash_bwd", 0, 5),
+    # four Mamba-2 blocks and one attention block of the nine
+    ("one-sublayer-decoder", "ssd_scan_fwd", 4, 0),
+    ("one-sublayer-decoder", "ssd_scan_bwd", 0, 4),
+    ("one-sublayer-decoder", "flash_fwd", 1, 0),
+    ("one-sublayer-decoder", "flash_bwd", 0, 1),
     # the embedding's gradient rule: one table, written once
+    ("one-sublayer-decoder", "embed_rows_bwd", 0, 1),
     ("pattern-decoder", "embed_rows_bwd", 0, 1),
     ("selected-attention-decoder", "embed_rows_bwd", 0, 1),
     ("hybrid-decoder", "embed_rows_bwd", 0, 1),
@@ -245,7 +257,7 @@ def test_a_kernel_s_calls_by_direction(lowered_steps, kind, kernel, forward,
                     for d in trace.DIRECTIONS}
     assert by_direction == {"forward": forward, "forward.again": 0,
                             "backward": backward, "update": 0}
-    layer = {"linear": "linear_attention", "select": "ssm",
+    layer = {"linear": "linear_attention", "select": "ssm", "ssd_sc": "ssd",
              "embed_": "embed"}.get(kernel[:6], "attention")
     assert {trace.layer_of(n) for n in calls} == {layer}
 
@@ -278,6 +290,7 @@ def test_the_lookup_and_its_gradient_rule_are_the_embedding_s(lowered_steps,
     ("selected-attention-decoder", "_backward"),
     ("hybrid-decoder", "_bwd_pallas"),
     ("latent-decoder", "_backward"),
+    ("one-sublayer-decoder", "_decay_bwd_pallas"),
 ])
 def test_a_backward_rule_s_helpers_are_backward(lowered_steps, kind, helper):
     """What a kernel's backward rule runs around the kernel (the delta,
@@ -321,6 +334,14 @@ _BLOCK = ("jit(train_step)/transpose(jvp(DecoderOnlyLM))/DecoderOnlyLM."
     ("jit(train_step)/jvp(DecoderOnlyLM)/DecoderOnlyLM._patterned/h1/"
      "linear/linear_attention/norm/norm/rsqrt", "linear_attention",
      "forward"),
+    # a Mamba-2 mixer's gated norm and convolution are the mixer's, its
+    # scan the mixer's part; the one norm of a block of one sublayer
+    (_BLOCK + "h0/ssd/ssd/ssd.core/jit(_decay_bwd_pallas)/ssd_scan_bwd/"
+     "pallas_call", "ssd", "backward"),
+    (_BLOCK + "rematted_computation/h2/ssd/ssd/rsqrt", "ssd",
+     "forward.again"),
+    ("jit(train_step)/jvp(DecoderOnlyLM)/DecoderOnlyLM._patterned/h1/"
+     "norm_post/norm/rsqrt", "norm", "forward"),
     # a kept-output kernel, the index scores': forward only
     ("jit(train_step)/jvp(DecoderOnlyLM)/DecoderOnlyLM._patterned/h3/attn/"
      "attention/indexer/attention.index/jit(_scores_pallas)/index_scores/"
@@ -356,12 +377,13 @@ def test_the_two_rules_on_a_path(op_name, layer, direction):
 
 
 def test_the_layers_are_the_top_level_scopes_and_every_scope_has_one():
-    assert len(trace.SCOPES) == len(set(trace.SCOPES)) == 26
-    assert {"attention.latent", "moe.shared", "ssm.core",
+    assert len(trace.SCOPES) == len(set(trace.SCOPES)) == 28
+    assert {"attention.latent", "moe.shared", "ssm.core", "ssd.core",
             "attention.diff", "attention.gate"} <= set(trace.SCOPES)
     assert set(trace.LAYERS) == {
-        "embed", "attention", "ffn", "moe", "linear_attention", "ssm", "gmu",
-        "readout_xent", "optimizer", "eval", "norm", "residual", "loss"}
+        "embed", "attention", "ffn", "moe", "linear_attention", "ssm", "ssd",
+        "gmu", "readout_xent", "optimizer", "eval", "norm", "residual",
+        "loss"}
     for scope in trace.SCOPES:
         assert trace.layer_of(scope) in trace.LAYERS
         assert trace.layer_of(f"jit(train_step)/jvp({scope})/mul") \
