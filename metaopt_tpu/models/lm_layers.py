@@ -1122,7 +1122,12 @@ class ScalarDecaySpec(_Mixer):
     ``head_dim`` channels, B and C of ``state`` numbers shared by the heads
     of a group (``groups`` of them), a convolution of ``conv`` taps with a
     bias. On a ``tp`` axis the mixer is whole on every device: its one
-    input projection joins z, x, B, C and dt and has no one axis to cut."""
+    input projection joins z, x, B, C and dt and has no one axis to cut.
+    ``describe`` says under ``"hand_over"`` which form the float32 work
+    around the scan takes (ops/ssd_hand_over.hand_over, the one rule):
+    ``"one pass"``, a Pallas call a side and direction, on the Pallas route
+    of one device at widths of whole lanes; ``"passes"``, XLA's,
+    elsewhere."""
 
     heads: int
     head_dim: int
@@ -1142,6 +1147,13 @@ class ScalarDecaySpec(_Mixer):
     def d_inner(self) -> int:
         return self.heads * self.head_dim
 
+    @property
+    def sizes(self):
+        """The sizes as ops/ssd_hand_over.py's rule and calls take them."""
+        from metaopt_tpu.ops.ssd_hand_over import Sizes
+
+        return Sizes(self.heads, self.head_dim, self.groups, self.state)
+
     def mix(self, block, x):
         return ScalarDecayMixer(block.d_model, self, block.eps,
                                 name="ssd")(x), {}
@@ -1160,8 +1172,12 @@ class ScalarDecaySpec(_Mixer):
 
     def describe(self, step, layers, sources):
         from metaopt_tpu.ops.linear_attention import linear_attention_route
+        from metaopt_tpu.ops.ssd_hand_over import hand_over
 
-        return {**linear_attention_route(), "layers": _numbers(layers),
+        route = linear_attention_route()
+        return {**route, "hand_over": hand_over(route["route"], step.mesh,
+                                                self.sizes),
+                "layers": _numbers(layers),
                 "heads": self.heads, "head_dim": self.head_dim,
                 "groups": self.groups, "state": self.state,
                 "conv": self.conv,
@@ -1193,7 +1209,14 @@ class ScalarDecayMixer(nn.Module):
     columns of W_in are multiplied in float32 at matmul precision highest
     (``heads`` columns: the decays exp(dt a) compound over a row), as a
     linear layer's gates are. The three products carry the names of the
-    spec's ``KEPT``."""
+    spec's ``KEPT``. Between the input projection's products and the rule,
+    and between the rule's output and ``out_proj``, the form is
+    ops/ssd_hand_over.hand_over's to say: ``"one pass"`` on the Pallas
+    route of one device (``ssd_operands`` in front, ``ssd_gated_norm``
+    behind, x made again from the product there, every float32 number
+    float32 in the calls and every rounding where it was), ``"passes"``
+    (:meth:`_passes`: XLA's elementwise passes) off the TPU, on a mesh of
+    several devices and at widths that are no whole lanes."""
 
     d_model: int
     spec: ScalarDecaySpec
@@ -1202,7 +1225,10 @@ class ScalarDecayMixer(nn.Module):
     @nn.compact
     @trace.scope("ssd")
     def __call__(self, u):
-        from metaopt_tpu.ops.linear_attention import scalar_decay_rule
+        from metaopt_tpu.ops import ssd_hand_over as sh
+        from metaopt_tpu.ops.linear_attention import (linear_attention_route,
+                                                      scalar_decay_rule)
+        from metaopt_tpu.parallel.mesh import active_mesh
 
         sp, kept = self.spec, self.spec.KEPT
         own = lambda name, init, shape: self.param(  # noqa: E731
@@ -1213,29 +1239,56 @@ class ScalarDecayMixer(nn.Module):
         zxbc = checkpoint_name(jnp.dot(
             u.astype(jnp.bfloat16), w_in[:, :-sp.heads].astype(jnp.bfloat16),
             preferred_element_type=jnp.bfloat16), kept["in"])
-        dt = jax.nn.softplus(checkpoint_name(jnp.dot(
+        dt = checkpoint_name(jnp.dot(
             u.astype(jnp.float32), w_in[:, -sp.heads:],
             precision=jax.lax.Precision.HIGHEST), kept["dt"])
-            + own("dt_bias", _dt_bias_init, (sp.heads,)))
+        dt_bias = own("dt_bias", _dt_bias_init, (sp.heads,))
+        taps = own("conv", _taps_init, (sp.conv, inner + 2 * bc))
+        conv_bias = own("conv_bias", _taps_init, (inner + 2 * bc,))
+        a_log = own("A_log", _head_decay_init, (sp.heads,))
+        skip = own("D", nn.initializers.ones, (sp.heads,))
+        weight = own("norm", nn.initializers.ones, (inner,))
+        sizes = sp.sizes
+        if sh.hand_over(linear_attention_route()["route"], active_mesh(),
+                        sizes) == "one pass":
+            c, b, v, g, z, x = sh.ssd_operands(
+                zxbc, dt, taps, conv_bias, dt_bias, a_log, sizes)
+            normed = sh.ssd_gated_norm(
+                scalar_decay_rule(c, b, v, g), z, x,
+                jax.lax.stop_gradient(zxbc), taps, conv_bias, skip, weight,
+                sizes, self.eps)
+        else:
+            normed = self._passes(zxbc, jax.nn.softplus(dt + dt_bias), taps,
+                                  conv_bias, a_log, skip, weight)
+        return checkpoint_name(nn.Dense(
+            self.d_model, dtype=jnp.bfloat16, name="out_proj",
+            use_bias=False, kernel_init=_pinit(True, (None, None)),
+        )(normed), kept["out"])
+
+    def _passes(self, zxbc, dt, taps, conv_bias, a_log, skip, weight):
+        """The output projection's operand by XLA's elementwise passes
+        around the scan, float32 between the roundings: the form off the
+        Pallas route and on a mesh of several devices, and the oracle of
+        ops/ssd_hand_over.py's calls."""
+        from metaopt_tpu.ops.linear_attention import scalar_decay_rule
+
+        sp = self.spec
+        inner, bc = sp.d_inner, sp.groups * sp.state
         xbc = jax.nn.silu(
-            short_conv(zxbc[..., inner:].astype(jnp.float32),
-                       own("conv", _taps_init, (sp.conv, inner + 2 * bc)))
-            + own("conv_bias", _taps_init, (inner + 2 * bc,)))
+            short_conv(zxbc[..., inner:].astype(jnp.float32), taps)
+            + conv_bias)
         heads = lambda y, n: y.reshape(*y.shape[:2], n, -1)  # noqa: E731
         x = heads(xbc[..., :inner], sp.heads)
         b, c = (heads(xbc[..., inner + i * bc:inner + (i + 1) * bc],
                       sp.groups).astype(jnp.bfloat16) for i in (0, 1))
-        a = -jnp.exp(own("A_log", _head_decay_init, (sp.heads,)))
+        a = -jnp.exp(a_log)
         y = scalar_decay_rule(c, b, (dt[..., None] * x).astype(jnp.bfloat16),
                               dt * a).astype(jnp.float32) \
-            + own("D", nn.initializers.ones, (sp.heads,))[:, None] * x
+            + skip[:, None] * x
         gated = y.reshape(zxbc.shape[:2] + (inner,)) * jax.nn.silu(
             zxbc[..., :inner].astype(jnp.float32))
         grouped = heads(gated, sp.groups)
         normed = (grouped * jax.lax.rsqrt(jnp.mean(
             jnp.square(grouped), axis=-1, keepdims=True) + self.eps)
-        ).reshape(gated.shape) * own("norm", nn.initializers.ones, (inner,))
-        return checkpoint_name(nn.Dense(
-            self.d_model, dtype=jnp.bfloat16, name="out_proj",
-            use_bias=False, kernel_init=_pinit(True, (None, None)),
-        )(normed.astype(jnp.bfloat16)), kept["out"])
+        ).reshape(gated.shape) * weight
+        return normed.astype(jnp.bfloat16)

@@ -450,7 +450,8 @@ def _kernels_form(said: Optional[dict]) -> str:
 
 #: ``ops/latent_attention.py::hand_over``'s answer in words, and
 #: ``ops/grouped_hand_over.py::hand_over``'s (a trace from before it was
-#: said has neither)
+#: said has neither); under ``("ssd", answer)``
+#: ``ops/ssd_hand_over.py::hand_over``'s for the Mamba-2 layers
 _HAND_OVER = {
     None: "", "passes": "",
     "one pass": ", q and k from their products in one pass a direction",
@@ -458,6 +459,9 @@ _HAND_OVER = {
     "in place": (", the kernels reading q after one pass, K, V and out "
                  "where the matmuls leave them, the shared key joined in "
                  "VMEM"),
+    ("ssd", "one pass"): (", the scan's operands from the products and the "
+                          "gated norm from its output in one pass a "
+                          "direction"),
 }
 
 
@@ -542,7 +546,8 @@ def print_routes(recs: List[dict]) -> None:
                           f"with a bias, norm gated over groups of "
                           f"{how['norm_group']}, chunks of {how['chunk']} by "
                           f"{how['route']}, a program a "
-                          f"{how['program']}")
+                          f"{how['program']}" + _HAND_OVER.get(
+                              ("ssd", how.get("hand_over")), ""))
                     continue
                 if kind == "gmu":
                     print(f"trial {r['trial']}: gated memory units "

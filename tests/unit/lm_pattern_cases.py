@@ -80,15 +80,19 @@ def leaf(tree, path):
     return np.asarray(tree, np.float32)
 
 
-def _equations(jaxpr):
-    """Every equation of a jaxpr and of the jaxprs inside its equations."""
+def _equations(jaxpr, kernels=True):
+    """Every equation of a jaxpr and of the jaxprs inside its equations;
+    without ``kernels`` a ``pallas_call``'s body (on-chip memory) is left
+    out."""
     for eqn in jaxpr.eqns:
         yield eqn
+        if not kernels and eqn.primitive.name == "pallas_call":
+            continue
         for value in eqn.params.values():
             for sub in value if isinstance(value, (list, tuple)) else [value]:
                 inner = getattr(sub, "jaxpr", sub)
                 if hasattr(inner, "eqns"):
-                    yield from _equations(inner)
+                    yield from _equations(inner, kernels)
 
 
 def one_device():

@@ -587,6 +587,41 @@ def test_the_scalar_decay_rule_s_kernels_compile_for_a_v5e(one_chip, shape):
     assert "ssd_scan_fwd" in text and "ssd_scan_bwd" in text
 
 
+@pytest.mark.parametrize("shape", DECAYS, ids=lambda s: "x".join(map(str, s)))
+def test_the_mamba2_hand_over_s_calls_compile_for_a_v5e(one_chip, shape):
+    """ops/ssd_hand_over.py's four calls through Mosaic at the cell's
+    shapes (4 taps) and on a length no tile divides: the halo blocks of 16
+    rows over the product, the rows shifted a tap down the sublanes, a
+    head's step spread over its channels on the MXU, the clamped blocks of
+    the front calls' column axis."""
+    from metaopt_tpu.ops import ssd_hand_over as sh
+
+    t, h, g, n, p = shape
+    sz = sh.Sizes(h, p, g, n)
+    wide = sz.inner + 2 * sz.bc
+    on_chip = lambda s, d: jax.ShapeDtypeStruct(  # noqa: E731
+        s, d, sharding=one_chip)
+    f32 = lambda *s: on_chip(s, jnp.float32)  # noqa: E731
+    args = [on_chip((1, t, sz.inner + wide), jnp.bfloat16), f32(1, t, h),
+            f32(4, wide), f32(wide), f32(h), f32(h), f32(h), f32(sz.inner),
+            on_chip((1, t, h, p), jnp.bfloat16)]
+
+    def loss(zxbc, dt, taps, bias, dt_bias, a_log, d, weight, y):
+        c, b, v, decay, z, x = sh.ssd_operands(zxbc, dt, taps, bias, dt_bias,
+                                               a_log, sz)
+        out = sh.ssd_gated_norm(y + v, z, x, jax.lax.stop_gradient(zxbc),
+                                taps, bias, d, weight, sz, 1e-5)
+        return sum(jnp.sum(o.astype(jnp.float32) ** 2)
+                   for o in (out, c, b, decay))
+
+    text = jax.jit(jax.grad(loss, argnums=tuple(range(9)))).lower(
+        *args).compile().as_text()
+    assert text.count("tpu_custom_call") == 4
+    for name in ("ssd_operands", "ssd_operands_bwd", "ssd_gated_norm",
+                 "ssd_gated_norm_bwd"):
+        assert f"%{name}.1 = " in text, name
+
+
 def test_two_matrix_experts_of_an_odd_width_compile_for_a_v5e(one_chip):
     """models/moe.py's ``_held_experts`` without a gate at the one-sublayer
     cell's sizes (8192 tokens x top 6, 8 held experts 1856 = 29 x 64 wide

@@ -312,7 +312,8 @@ def test_what_trial_setup_says_of_the_blocks():
     said = lm_description.describe_pattern(description(), "reference",
                                            tokens=2 * S, seq_len=S)
     assert said["attention_layers"]["ssd"] == {
-        "route": "xla", "chunk": 128, "layers": [0, 2, 4, 7], "heads": SH,
+        "route": "xla", "chunk": 128, "hand_over": "passes",
+        "layers": [0, 2, 4, 7], "heads": SH,
         "head_dim": SP, "groups": SG, "state": SN, "conv": CONV,
         "norm_group": SH * SP // SG,
         "program": "group of 4 heads and chunk",
