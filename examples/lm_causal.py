@@ -120,11 +120,15 @@ def main():
                    "n_layers": a.n_layers, "d_ff": a.d_ff,
                    "n_heads": max(1, a.d_model // 64),
                    "n_experts": a.n_experts, "warmup": a.warmup}
-    loss = train_lm(
-        hparams, tp=a.tp, sp=a.sp, ep=a.ep, seq_len=a.seq_len,
-        steps=a.steps, batch_size=a.batch_size, n_train=a.n_train,
-        **kw,
-    )
+    # under ``hunt --profile-dir D`` the trial's device trace lands beside its
+    # spans, and ``python -m metaopt_tpu.utils.trace D`` prints its step by
+    # layer and the compiler's operations in it; a no-op otherwise
+    with client.profiled():
+        loss = train_lm(
+            hparams, tp=a.tp, sp=a.sp, ep=a.ep, seq_len=a.seq_len,
+            steps=a.steps, batch_size=a.batch_size, n_train=a.n_train,
+            **kw,
+        )
     report_results([{"name": "loss", "type": "objective", "value": loss}])
 
 

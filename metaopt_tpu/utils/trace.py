@@ -15,13 +15,26 @@ variable is set (``hunt --profile-dir``), and never otherwise.
 A device operation carries the scopes it was traced under in its
 ``op_name`` (``jit(train_step)/transpose(jvp(DecoderOnlyLM))/.../h0/attn/
 attention/attention.core/...``). ``SCOPES`` closes over a train step's
-source: every operation the program writes is under one of its twenty-six
-names, and two rules read a path, :func:`layer_of` (which top-level scope
-owns it) and :func:`direction` (forward, the forward's second run under
-remat, backward, update). What carries no name of ``SCOPES`` the compiler
-made.
+source: every operation the program writes is under one of its
+names, and three rules read an operation, all plain string work: of its
+path :func:`layer_of` (which top-level scope owns it) and
+:func:`direction` (forward, the forward's second run under remat, backward,
+update); what carries no name of ``SCOPES`` the compiler made, and of its
+HLO opcode :func:`compiler_kind` says what it is (a copy, a slice, a
+loop's bookkeeping, a fusion under a nameless root).
 
-``python -m metaopt_tpu.utils.trace DIR`` reads what a sweep left under DIR.
+The device side. A profiler trace's file holds the devices' operations
+*and* the compiled programs that ran (utils/trace_device.py reads both by
+field number; importing this module imports neither it nor jax). There a
+fourth rule, ``trace_device.owner_of``, reads the step's own HLO for the
+layer a nameless operation was made for: the one its users agree on, else
+its operands' producers, else nobody.
+
+``python -m metaopt_tpu.utils.trace DIR`` reads what a sweep left under DIR
+(``hunt --profile-dir DIR``): the phases, the routes and, for a trial that
+ran under ``client.profiled()``, its step by layer and direction, the
+compiler's operations in it by kind and by owner and the ten largest;
+``--scope NAME`` lists the operations under one scope instead.
 """
 
 from __future__ import annotations
@@ -96,6 +109,18 @@ SCOPES = ("embed", "attention", "attention.core", "ffn", "readout_xent",
 LAYERS = tuple(s for s in SCOPES if "." not in s)
 #: what :func:`direction` answers
 DIRECTIONS = ("forward", "forward.again", "backward", "update")
+#: what :func:`compiler_kind` answers: what an operation is that carries no
+#: name of ``SCOPES``
+COMPILER_KINDS = ("copy", "slice", "loop", "fusion", "other")
+_KIND_OF = {
+    **dict.fromkeys(("copy", "copy-start", "copy-done", "transpose",
+                     "reshape", "convert"), "copy"),
+    **dict.fromkeys(("slice", "slice-start", "slice-done", "dynamic-slice",
+                     "dynamic-update-slice", "concatenate", "pad"), "slice"),
+    **dict.fromkeys(("while", "conditional", "call", "tuple",
+                     "get-tuple-element"), "loop"),
+    "fusion": "fusion",
+}
 #: spans a train loop makes every step. The ring keeps one whole only if it
 #: has a child (the step that compiled); the others are summed into the
 #: enclosing span's ``attrs["per_step"]`` as ``{name: [count, seconds]}``, so a
@@ -223,6 +248,40 @@ def direction(op_name: str) -> str:
     if "transpose(" in op_name:
         return "backward"
     return "update" if layer_of(op_name) == "optimizer" else "forward"
+
+
+def compiler_kind(opcode: str) -> str:
+    """One of ``COMPILER_KINDS`` for the HLO opcode of an operation that has
+    no layer (``layer_of`` is None: the compiler made it). The table, settled
+    on the opcodes of PR 51's first traced runs on the v5e:
+
+    - ``copy``, a pass that writes a whole array again: as it is (``copy``,
+      the asynchronous pair ``copy-start`` / ``copy-done``), in another
+      order (``transpose``, a ``reshape`` that is not a bitcast) or in
+      another type (``convert``: the float32 experts read as bfloat16);
+    - ``slice``, a move of a part: ``slice``, ``dynamic-slice``,
+      ``dynamic-update-slice``, ``concatenate``, ``pad`` (the asynchronous
+      ``slice-start`` / ``slice-done`` are ``async-start`` / ``async-done``
+      around a ``slice``: ``trace_device.opcode_of`` looks inside);
+    - ``loop``, control and its bookkeeping: ``while``, ``conditional``,
+      ``call``, ``tuple``, ``get-tuple-element`` (a loop's counters run
+      inside fusions of their own and read ``fusion``);
+    - ``fusion``, a fusion whose root carries no name of ``SCOPES``;
+    - ``other``, the rest (a ``broadcast`` that fills a buffer, ``iota``,
+      a ``custom-call`` without a name): the closed list's guard, under 5 %
+      of the nameless time in the recorded steps.
+
+    An opcode is taken as the compiler spells it, or from the front of an
+    instruction's name where a file holds no program (``copy-done.14``,
+    ``slice-start.3``, a renamed ``bitcast_fusion.3``).
+
+    The same limit as :func:`layer_of`'s: a fusion is whatever its root
+    is, so a fused copy under a nameless root reads ``fusion``."""
+    opcode = opcode.lstrip("%")
+    kind = _KIND_OF.get(opcode) or _KIND_OF.get(opcode.partition(".")[0])
+    if kind is None and "fusion" in opcode:
+        kind = "fusion"  # a renamed fusion: ``bitcast_fusion.3``
+    return kind or "other"
 
 
 def spans(name: Optional[str] = None) -> List[dict]:
@@ -416,6 +475,16 @@ def table(recs: List[dict]) -> List[dict]:
 
 
 def main(argv: List[str]) -> int:
+    """``DIR [--scope NAME]``: the phase table and the routes of what a sweep
+    left under DIR and, a device trace it holds (a trial that ran under
+    ``client.profiled()``), that trial's step by layer and direction and the
+    compiler's operations in it; with ``--scope``, the operations under that
+    one scope."""
+    scope = argv[argv.index("--scope") + 1] if "--scope" in argv else None
+    if scope is not None and scope not in SCOPES:
+        print(f"no scope {scope!r}: one of {', '.join(SCOPES)}",
+              file=sys.stderr)
+        return 2
     recs = load(argv[0])
     print(f"{len(recs)} spans under {argv[0]}; self = a span less its "
           "children; share = median self / median worker.trial")
@@ -426,7 +495,32 @@ def main(argv: List[str]) -> int:
         print(f"{r['phase']:<24}{r['trials']:>7}{r['median_s']:>10.3f}"
               f"{r['max_s']:>10.3f}{r['self_median_s']:>10.3f}{share:>8}")
     print_routes(recs)
+    print_device(recs, argv[0], scope)
     return 0
+
+
+def print_device(recs: List[dict], base: str, scope: Optional[str]) -> None:
+    """The device side of every ``profiler.trace`` span under ``base``
+    (utils/trace_device.py reads the file; a directory that was moved is
+    looked for under ``base`` by its last part, the trial's id)."""
+    marks = [r for r in recs if r["name"] == "profiler.trace"]
+    if not marks:
+        return
+    from metaopt_tpu.utils import trace_device
+
+    for r in marks:
+        said = str(r["attrs"].get("dir", ""))
+        where = said if os.path.isdir(said) else os.path.join(
+            base, os.path.basename(said.rstrip("/")))
+        loaded = trace_device.load(where)
+        if loaded is None or not loaded.programs and not loaded.ops:
+            print(f"trial {r['trial']}: no device trace under {where}")
+            continue
+        print(f"trial {r['trial']}: device trace {loaded.path}")
+        if scope is None:
+            trace_device.print_step(trace_device.step_table(loaded))
+        else:
+            trace_device.print_scope(trace_device.scope_table(loaded, scope))
 
 
 def _scores_form(said: dict) -> str:
