@@ -606,7 +606,8 @@ class GatedFeedForward(nn.Module):
 
 @dataclasses.dataclass(frozen=True)
 class LinearSpec(_Mixer):
-    """What a description says of its linear-attention layers."""
+    """What a description says of its linear-attention layers; ``describe``
+    says under ``"hand_over"`` what :func:`_delta_form`, below, answers."""
 
     heads: int              # held here
     of: int                 # the layer's published count
@@ -647,7 +648,9 @@ class LinearSpec(_Mixer):
     def describe(self, step, layers, sources):
         from metaopt_tpu.ops.linear_attention import linear_attention_route
 
-        return {**linear_attention_route(), "layers": _numbers(layers),
+        route = linear_attention_route()
+        return {**route, "layers": _numbers(layers),
+                "hand_over": _delta_form(self, route["route"], step.mesh),
                 "heads": [self.heads, self.of], "key_dim": self.key_dim,
                 "value_dim": self.value_dim, "conv": self.conv}
 
@@ -688,7 +691,11 @@ class LinearAttention(nn.Module):
     Element-wise work, gates and norms in float32; the two gates'
     projections float32 at matmul precision highest, as a router's are. The
     seven projections' products carry the names of the spec's ``KEPT``
-    (identities unless a block's policy asks for them)."""
+    (identities unless a block's policy asks for them). Between the q, k,
+    v products and the rule, and between the rule's output and ``out``,
+    the form is ops/delta_hand_over.hand_over's to say (:func:`_delta_mixed`
+    below asks it): ``"one pass"``, a Pallas call a side and direction
+    around the scan's heads-first door, or ``"passes"``, XLA's."""
 
     d_model: int
     spec: LinearSpec
@@ -697,8 +704,6 @@ class LinearAttention(nn.Module):
     @nn.compact
     @trace.scope("linear_attention")
     def __call__(self, x):
-        from metaopt_tpu.ops.linear_attention import gated_delta_rule
-
         sp, kept = self.spec, self.spec.KEPT
         proj = lambda name, width: nn.DenseGeneral(  # noqa: E731
             (sp.heads, width), axis=-1, dtype=jnp.bfloat16, name=name,
@@ -714,28 +719,23 @@ class LinearAttention(nn.Module):
             name, nn.initializers.variance_scaling(
                 1 / 3, "fan_in", "uniform", in_axis=0, out_axis=(1, 2)),
             (sp.conv, sp.heads, width), (None, "tp", None))
-        mixed = lambda name, width: jax.nn.silu(short_conv(  # noqa: E731
-            checkpoint_name(proj(name, width)(xb), kept[name])
-            .astype(jnp.float32), taps("conv_" + name, width)))
-        unit = lambda y: y * jax.lax.rsqrt(  # noqa: E731
-            jnp.sum(jnp.square(y), axis=-1, keepdims=True) + 1e-6)
         xb, xf = x.astype(jnp.bfloat16), x.astype(jnp.float32)
-        q = unit(mixed("q", sp.key_dim)) * sp.key_dim ** -0.5
-        k = unit(mixed("k", sp.key_dim))
-        v = mixed("v", sp.value_dim)
+        # (a projection's product, its convolution's taps) by name
+        mixed = {name: (checkpoint_name(proj(name, width)(xb), kept[name]),
+                        taps("conv_" + name, width))
+                 for name, width in (("q", sp.key_dim), ("k", sp.key_dim),
+                                     ("v", sp.value_dim))}
         beta = jax.nn.sigmoid(checkpoint_name(gate("b")(xf), kept["b"])) \
             * (2.0 if sp.neg_eigval else 1.0)
         g = -jnp.exp(own("A_log", _decay_init, (sp.heads,), ("tp",))) \
             * jax.nn.softplus(checkpoint_name(gate("a")(xf), kept["a"]) + own(
                 "dt_bias", _dt_bias_init, (sp.heads,), ("tp",)))
-        o = gated_delta_rule(q.astype(jnp.bfloat16), k.astype(jnp.bfloat16),
-                             v.astype(jnp.bfloat16), g, beta)
-        y = RMSNorm(self.eps, name="norm")(o) * jax.nn.silu(checkpoint_name(
-            proj("g", sp.value_dim)(xb), kept["g"]).astype(jnp.float32))
+        y = _delta_mixed(self, mixed, g, beta, checkpoint_name(
+            proj("g", sp.value_dim)(xb), kept["g"]))
         return checkpoint_name(nn.DenseGeneral(
             self.d_model, axis=(-2, -1), dtype=jnp.bfloat16, name="out",
             use_bias=False, kernel_init=_pinit(True, ("tp", None, None)),
-        )(y.astype(jnp.bfloat16)), kept["out"])
+        )(y), kept["out"])
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1292,3 +1292,48 @@ class ScalarDecayMixer(nn.Module):
             jnp.square(grouped), axis=-1, keepdims=True) + self.eps)
         ).reshape(gated.shape) * weight
         return normed.astype(jnp.bfloat16)
+
+
+def _delta_form(spec: LinearSpec, route: str, mesh) -> str:
+    """ops/delta_hand_over.hand_over's answer for a mixer of ``spec``."""
+    from metaopt_tpu.ops.delta_hand_over import Sizes, hand_over
+
+    return hand_over(route, mesh, Sizes(spec.heads, spec.key_dim,
+                                        spec.value_dim, spec.conv))
+
+
+def _delta_mixed(layer: LinearAttention, mixed, g, beta, gate):
+    """The output projection's operand (B, T, H, value_dim) bfloat16 of a
+    gated-delta mixer from ``mixed``, its q, k and v (product, taps) by
+    name, the log decays, the steps and the g product. How is
+    ops/delta_hand_over.hand_over's to say: ``"one pass"``
+    (``delta_operands`` in front of the scan's heads-first door,
+    ``delta_gated_norm`` behind it: every float32 number float32 in the
+    calls and every rounding where it was) on the Pallas route of one
+    device, ``"passes"`` (XLA's element-wise passes around
+    ``gated_delta_rule``, float32 between the roundings) off the TPU, on a
+    mesh of several devices and at a rehearsal's widths; the second is also
+    the tests' oracle for the first. Down here for the reason
+    :func:`_handed_over` is."""
+    from metaopt_tpu.ops import delta_hand_over as dh
+    from metaopt_tpu.ops.linear_attention import (gated_delta_rule,
+                                                  linear_attention_route)
+    from metaopt_tpu.parallel.mesh import active_mesh
+
+    sp = layer.spec
+    if _delta_form(sp, linear_attention_route()["route"],
+                   active_mesh()) == "one pass":
+        (q, tq), (k, tk), (v, tv) = (mixed[n] for n in "qkv")
+        o = dh.gated_delta_rule_heads_first(
+            *dh.delta_operands(q, k, v, tq, tk, tv), g, beta)
+        return dh.delta_gated_norm(o, gate, NormScale(name="norm")(
+            sp.value_dim), layer.eps)
+    q, k, v = (jax.nn.silu(short_conv(p.astype(jnp.float32), t))
+               for p, t in (mixed[n] for n in "qkv"))
+    unit = lambda y: y * jax.lax.rsqrt(  # noqa: E731
+        jnp.sum(jnp.square(y), axis=-1, keepdims=True) + 1e-6)
+    o = gated_delta_rule(
+        (unit(q) * sp.key_dim ** -0.5).astype(jnp.bfloat16),
+        unit(k).astype(jnp.bfloat16), v.astype(jnp.bfloat16), g, beta)
+    return (RMSNorm(layer.eps, name="norm")(o) * jax.nn.silu(
+        gate.astype(jnp.float32))).astype(jnp.bfloat16)

@@ -43,8 +43,8 @@ Both sit under the scope ``linear_attention.core``, forward and backward.
 A length that is no multiple of ``CHUNK`` is padded (a padded token has
 ``g`` 0, ``beta`` 0: it leaves the state alone). The output and the chunks'
 entering states carry names (``REMAT_KEEPS``) by which a rematerialised
-block keeps them, so that its backward pass does not walk
-the chunks forward a second time.
+block keeps them and does not walk the chunks forward again. A second door,
+ops/delta_hand_over.gated_delta_rule_heads_first, takes q, k, v heads first.
 """
 
 from __future__ import annotations

@@ -545,7 +545,8 @@ def _kernels_form(said: Optional[dict]) -> str:
 #: ``ops/latent_attention.py::hand_over``'s answer in words, and
 #: ``ops/grouped_hand_over.py::hand_over``'s (a trace from before it was
 #: said has neither); under ``("ssd", answer)``
-#: ``ops/ssd_hand_over.py::hand_over``'s for the Mamba-2 layers
+#: ``ops/ssd_hand_over.py::hand_over``'s for the Mamba-2 layers, under
+#: ``("linear", answer)`` ``ops/delta_hand_over.py::hand_over``'s
 _HAND_OVER = {
     None: "", "passes": "",
     "one pass": ", q and k from their products in one pass a direction",
@@ -556,6 +557,9 @@ _HAND_OVER = {
     ("ssd", "one pass"): (", the scan's operands from the products and the "
                           "gated norm from its output in one pass a "
                           "direction"),
+    ("linear", "one pass"): (", q, k and v from their products heads first "
+                             "and the gated norm from the scan's output in "
+                             "one pass a direction"),
 }
 
 
@@ -619,7 +623,8 @@ def print_routes(recs: List[dict]) -> None:
                           f"keys {how['key_dim']}, values "
                           f"{how['value_dim']}, convolutions of "
                           f"{how['conv']}, chunks of {how['chunk']} by "
-                          f"{how['route']}")
+                          f"{how['route']}" + _HAND_OVER.get(
+                              ("linear", how.get("hand_over")), ""))
                     continue
                 if kind == "ssm":
                     print(f"trial {r['trial']}: state-space layers "

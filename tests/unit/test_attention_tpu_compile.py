@@ -645,3 +645,43 @@ def test_two_matrix_experts_of_an_odd_width_compile_for_a_v5e(one_chip):
         on_chip((held,), jnp.int32)).compile().as_text()
     assert text.count("tpu_custom_call") == 8
     assert "expert_activation_bwd" in text
+
+
+#: (tokens, heads, key width, value width): the hybrid cell's linear layers;
+#: a length that is no whole tile; narrower heads of whole sublane tiles
+DELTA_SHAPES = [(8192, 15, 96, 192), (1408, 4, 96, 192), (2048, 2, 16, 48)]
+
+
+@pytest.mark.parametrize("shape", DELTA_SHAPES,
+                         ids=lambda s: "x".join(map(str, s)))
+def test_the_gated_delta_hand_over_s_calls_compile_for_a_v5e(one_chip, shape):
+    """ops/delta_hand_over.py's four calls through Mosaic at the cell's
+    shapes (4 taps): blocks of a head whose 96 or 192 columns are the
+    array's own and no whole lanes, the halo blocks of 16 rows before and
+    after a tile, the rows shifted a tap down the sublanes, the norms' sums
+    over part of a register's lanes; and between the products, which the
+    caller has tokens first, and the calls no copy that XLA must make (the
+    turn to heads first is the layout of whatever produces them)."""
+    from metaopt_tpu.ops import delta_hand_over as dh
+
+    t, h, dk, dv = shape
+    on_chip = lambda s, d: jax.ShapeDtypeStruct(  # noqa: E731
+        s, d, sharding=one_chip)
+    product = lambda d: on_chip((1, t, h, d), jnp.bfloat16)  # noqa: E731
+    taps = lambda d: on_chip((4, h, d), jnp.float32)  # noqa: E731
+    args = [product(dk), product(dk), product(dv), taps(dk), taps(dk),
+            taps(dv), product(dv), on_chip((dv,), jnp.float32)]
+
+    def loss(q, k, v, tq, tk, tv, g, scale):
+        qh, kh, vh = dh.delta_operands(q, k, v, tq, tk, tv)
+        o = vh + jnp.sum(qh, -1, keepdims=True) + jnp.sum(kh, -1,
+                                                          keepdims=True)
+        out = dh.delta_gated_norm(o, g, scale, 1e-6)
+        return jnp.sum(out.astype(jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=tuple(range(8)))).lower(
+        *args).compile().as_text()
+    assert text.count("tpu_custom_call") == 4
+    for name in ("delta_operands", "delta_operands_bwd", "delta_gated_norm",
+                 "delta_gated_norm_bwd"):
+        assert f"{name}" in text, name

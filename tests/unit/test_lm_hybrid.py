@@ -833,6 +833,7 @@ def test_train_lm_says_which_layers_are_linear_and_what_a_block_keeps():
         "global-nope": {"route": "reference", "mask": "dense: causal",
                         "hand_over": "passes"},
         "linear": {"route": "xla", "chunk": CHUNK, "layers": [0],
+                   "hand_over": "passes",
                    "heads": [HELD, HEADS], "key_dim": KD, "value_dim": VD,
                    "conv": 4}}
     # this backend states no limit: the products are sized and not kept
