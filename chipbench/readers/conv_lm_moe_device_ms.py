@@ -1,0 +1,14 @@
+"""Device milliseconds a step under the scope ``moe``: the router's
+product (read before attention), top-k, dispatch, the grouped products and
+the combine of every expert layer, forward and backward
+(chipbench/program_trace.py).
+
+``moe_device_ms`` under this name for ``lfm2-24b.steady-8k``: the
+same body (an accepted entry's ``workloads`` list takes a new cell from a
+``benchmark`` PR alone, which folds this copy back into it)."""
+
+from chipbench import program_trace
+
+
+def read(records):
+    return program_trace.scope_ms_a_step(records, "moe", "train_step")

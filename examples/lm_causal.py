@@ -71,6 +71,15 @@ of two matrices under a squared ReLU beside a shared one (``python -m
 chipbench.ssd_lm_config chipbench/configs/nemotron-3-nano-30b-a3b-ep16.json``
 prints NVIDIA-Nemotron-3-Nano-30B-A3B's share of one chip;
 ``--seq-len=8192``).
+One with ``model_type`` ``lfm2_moe`` names, layer by layer
+(``layer_types`` at the published numbers, ``layers_held``), a gated short
+convolution (``conv``: ``conv_L_cache`` taps between two gates, no state
+beyond two tokens) or grouped attention with q/k norms and rotary positions
+(``full_attention``); ``num_dense_layers`` leading SwiGLU layers, then
+``num_experts_per_tok`` of ``num_experts`` SwiGLU experts by sigmoid scores
+with a correction bias (``use_expert_bias``), a tied head (``python -m
+chipbench.conv_lm_config chipbench/configs/lfm2-24b-a2b-ep8.json`` prints
+LFM2-24B-A2B's share of one chip; ``--seq-len=8192``).
 """
 
 import argparse

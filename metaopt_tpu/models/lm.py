@@ -26,14 +26,14 @@ linear             LinearSpec        LinearAttention        linear_attention
 ssm                StateSpaceSpec    StateSpaceMixer        selective_scan
 gmu                MemoryUnitSpec    GatedMemoryUnit        none
 ssd                ScalarDecaySpec   ScalarDecayMixer       linear_attention
+short-conv         ShortConvSpec     ShortConvMixer         short_conv
 window-, global-,  DifferentialSpec  DifferentialAttention  attention, twice
 cross-nope
 gated (ffn)        GatedSpec         GatedFeedForward       none
 routed (ffn)       moe.RoutedSpec    router, DroplessMoE    experts, megablox
                    + ``gated``       (experts of two
                    false             matrices, no gate)
-absent (either)    Absent            none: the sublayer a
-                                     block does not have
+absent (either)    Absent            no sublayer there      none
 =================  ================  =====================  =================
 
 Who names them (models/lm_description.py, the one module that knows a
@@ -46,7 +46,7 @@ layers; ``phi4flash`` ssm, gmu and the differential kinds, gated;
 a head, the plain rule on the whole) and an output gate a layer, gated or
 routed by the layer; ``nemotron_h`` one sublayer a block by its pattern's
 letter: ssd (Mamba-2), global-nope attention, or experts of two matrices
-under a squared ReLU beside a shared one.
+under a squared ReLU; ``lfm2_moe`` short-conv and global-rope, routed.
 
 The specs and modules are models/lm_layers.py's (the routed feed-forward's
 models/moe.py's); what a rematerialised block keeps is

@@ -7,9 +7,11 @@ own keys (``d_model``, ``n_layers`` ...) or a published config's
 (first, count) of the routed experts, ``vocab_held`` = (first, count) of the
 vocabulary's rows, ``heads_held`` = (first, count) of
 ``num_attention_heads`` (every kind of head is built in that proportion;
-``laguna``, whose layers differ in their head counts, takes none) and,
-for ``phi4flash`` and ``nemotron_h``, ``layers_held``: the published numbers
-of the layers this chip holds (a pipeline stage's; not the first n).
+``laguna``, whose layers differ in their head counts, takes none, and
+neither do ``nemotron_h`` and ``lfm2_moe``, whose deployments share no
+heads) and, for ``phi4flash``, ``nemotron_h`` and ``lfm2_moe``,
+``layers_held``: the published numbers of the layers this chip holds (a
+pipeline stage's; not the first n). Eight families have a reader.
 :func:`family_of` says
 whose words a description speaks, and the family's reader (``_FAMILIES``)
 builds each held layer's specs (models/lm_layers.py, models/moe.py)
@@ -28,7 +30,7 @@ from jax.sharding import Mesh
 
 from metaopt_tpu.models.lm_layers import (
     Absent, DifferentialSpec, GatedSpec, GroupedSpec, LatentSpec, LinearSpec,
-    MemoryUnitSpec, Rotary, ScalarDecaySpec, StateSpaceSpec)
+    MemoryUnitSpec, Rotary, ScalarDecaySpec, ShortConvSpec, StateSpaceSpec)
 from metaopt_tpu.models.moe import RoutedSpec, RoutingRule
 from metaopt_tpu.ops.embed import embed_gradient_route
 
@@ -132,15 +134,19 @@ def _own_names(hparams: Dict[str, Any]) -> Dict[str, Any]:
 
 def family_of(h: Dict[str, Any]) -> Optional[str]:
     """The family whose words a description with a layer pattern speaks
-    (a key of ``_FAMILIES``), None for one without a pattern:
-    ``kv_lora_rank`` is the DeepSeek-V3 family's, ``layer_types`` the Olmo
-    hybrid's, ``num_experts`` the Qwen3-MoE family's, the two layouts or
-    ``sa_config`` alone SmallThinker's; ``model_type`` ``phi4flash`` and
-    ``laguna`` name their families themselves (the second speaks the Olmo
-    hybrid's and the Qwen3-MoE family's words at once);
-    ``hybrid_override_pattern`` is ``nemotron_h``'s (which speaks the
-    DeepSeek-V3 family's routing words too, so it is asked first)."""
-    if h.get("model_type") in ("phi4flash", "laguna"):
+    (a key of ``_FAMILIES``, eight of them), None for one without a
+    pattern. ``model_type`` is asked FIRST, for the families that name
+    themselves and speak other families' words besides: ``phi4flash``;
+    ``laguna`` (the Olmo hybrid's ``layer_types`` and the Qwen3-MoE
+    family's ``num_experts`` at once); ``lfm2_moe`` (the same two keys, and
+    a ``layer_types`` entry, ``conv``, that the Olmo hybrid's reader refuses
+    by name). Then the keys, in this order: ``hybrid_override_pattern`` is
+    ``nemotron_h``'s (which speaks the DeepSeek-V3 family's routing words
+    too, so it is asked before them), ``kv_lora_rank`` the DeepSeek-V3
+    family's, ``layer_types`` the Olmo hybrid's, ``num_experts`` the
+    Qwen3-MoE family's, the two layouts or ``sa_config`` alone
+    SmallThinker's."""
+    if h.get("model_type") in ("phi4flash", "laguna", "lfm2_moe"):
         return h["model_type"]
     for key, family in (("hybrid_override_pattern", "nemotron_h"),
                         ("kv_lora_rank", "deepseek_v3"),
@@ -602,12 +608,80 @@ def _nemotron_h(h) -> Pattern:
 _LETTERS = {"M": "a Mamba-2 mixer", "*": "attention", "E": "experts"}
 
 
+def _lfm2_moe(h) -> Pattern:
+    """The ``lfm2_moe`` family's (LFM2, arXiv:2511.23404): the pre-norm
+    block x + mix(rmsnorm(x)) then x + feed(rmsnorm(x)) (eps ``norm_eps``),
+    read at the PUBLISHED numbers (all of ``layer_types``, or
+    ``layers_held``, a pipeline stage's). Layer l's mixer by
+    ``layer_types[l]``: ``conv`` a gated short convolution over the hidden
+    width (``conv_L_cache`` taps; ``conv_bias`` true is refused, the
+    published value is false), ``full_attention`` grouped attention with
+    RMS norms of q and k over a head's width, then rotary positions over
+    the whole head (``rope_parameters.rope_theta``, ``rope_type`` default).
+    Its feed-forward gated by SiLU: ``intermediate_size`` wide for l <
+    ``num_dense_layers``, else top ``num_experts_per_tok`` of
+    ``num_experts`` experts ``moe_intermediate_size`` wide by sigmoid
+    scores, a correction bias in the choice alone (``use_expert_bias``),
+    the chosen scores over their sum + 1e-6 (``norm_topk_prob``) times
+    ``routed_scaling_factor``, the router read after the mixer, no shared
+    expert. The last norm (the family's ``embedding_norm``) at the output;
+    the head tied to the embedding (the family's default). What has no
+    layer here is refused by its name."""
+    types = list(h.get("layer_types") or ())
+    unknown = sorted(set(types) - set(_MIXERS))
+    if unknown or not types:
+        raise ValueError(f"layer_types names {unknown or 'no layer'}; "
+                         f"known: {sorted(_MIXERS)}")
+    numbers = _layers_held(h, len(types))
+    if h.get("conv_bias"):
+        raise ValueError(f"conv_bias {h['conv_bias']!r}: a gated short "
+                         "convolution has no bias here")
+    if "heads_held" in h:
+        raise ValueError(f"heads_held {h['heads_held']!r}: the deployment "
+                         "shares no heads of an lfm2_moe layer")
+    dense = int(h.get("num_dense_layers", 0))
+    if not 0 <= dense <= len(types):
+        raise ValueError(f"num_dense_layers {dense}: the model has "
+                         f"{len(types)} layers")
+    rope_type = (h.get("rope_parameters") or {}).get("rope_type", "default")
+    if rope_type != "default":
+        raise ValueError(f"rope_parameters.rope_type {rope_type!r}: known: "
+                         "'default'")
+    mixers = {
+        "conv": ShortConvSpec(channels=int(h.get("d_model", 512)),
+                              taps=int(h.get("conv_L_cache", 3))),
+        "full_attention": GroupedSpec(
+            **_attention_heads(h), window=None, theta=float(_theta(h)),
+            qk_norm="head", selection=None)}
+    n_experts = int(h.get("moe_num_primary_experts", 0))
+    routed = n_experts and RoutedSpec(
+        n_experts=n_experts,
+        top_k=int(h.get("moe_num_active_primary_experts", 1)),
+        d_ff=_expert_width(h), held=_held(h, "experts_held", n_experts),
+        activation="silu", shared_d_ff=0, rule=RoutingRule(
+            "sigmoid", bias=bool(h.get("use_expert_bias", False)),
+            normalised=bool(h.get("norm_topk_prob", True)),
+            scale=float(h.get("routed_scaling_factor", 1.0)), eps=1e-6),
+        router_after_mixer=True)
+    if not routed and dense < len(types):
+        raise ValueError(f"num_experts {n_experts}: the layers from "
+                         f"num_dense_layers {dense} on route over experts")
+    feed = GatedSpec(int(h.get("d_ff", 2048)), "silu")
+    return _pattern(
+        h, [Layer(n, mixers[types[n]], feed if n < dense else routed)
+            for n in numbers], "rms", "norm_eps", 1e-5, tied=True)
+
+
+#: an ``lfm2_moe`` ``layer_types`` entry -> the layer's mixer
+_MIXERS = ("conv", "full_attention")
+
+
 #: a family's reader: what its layer is, by the family and not by how a
 #: key of its description is spelt
 _FAMILIES = {"layouts": _layouts, "qwen3_moe": _qwen3_moe,
              "olmo_hybrid": _olmo_hybrid, "deepseek_v3": _deepseek_v3,
              "phi4flash": _phi4flash, "laguna": _laguna,
-             "nemotron_h": _nemotron_h}
+             "nemotron_h": _nemotron_h, "lfm2_moe": _lfm2_moe}
 
 
 def describe_pattern(hparams: Dict[str, Any], route: str, tokens: int,

@@ -1337,3 +1337,85 @@ def _delta_mixed(layer: LinearAttention, mixed, g, beta, gate):
         unit(k).astype(jnp.bfloat16), v.astype(jnp.bfloat16), g, beta)
     return (RMSNorm(layer.eps, name="norm")(o) * jax.nn.silu(
         gate.astype(jnp.float32))).astype(jnp.bfloat16)
+
+
+@dataclasses.dataclass(frozen=True)
+class ShortConvSpec(_Mixer):
+    """A gated short convolution as a mixer (the ``lfm2_moe`` family's
+    ``conv`` layers): ``channels`` channels, ``taps`` taps, no bias and no
+    activation. On a ``tp`` axis the mixer is whole on every device: its one
+    input projection joins the three thirds B, C and X, which meet channel
+    by channel, and has no one axis to cut; ``describe`` says so, and which
+    route the core takes (ops/short_conv.short_conv_route, the one rule).
+    Down here for the reason :func:`_handed_over` is."""
+
+    channels: int
+    taps: int
+
+    #: the input projection's product ([B | C | X] before the gates and the
+    #: convolution, which are made again from it) and the output
+    #: projection's
+    KEPT = {"in": "short_conv.in_proj", "out": "short_conv.out_proj"}
+
+    attends = False
+    kind = "short-conv"
+
+    def mix(self, block, x):
+        return ShortConvMixer(block.d_model, self, name="conv")(x), {}
+
+    def kernel_keeps(self):
+        return ()  # the core is one pass over the product: made again
+
+    def products(self, d_model):
+        kept = self.KEPT
+        return [(d_model, {kept["in"]: 2 * 3 * self.channels}),
+                (self.channels, {kept["out"]: 2 * d_model})]
+
+    def describe(self, step, layers, sources):
+        from metaopt_tpu.ops.short_conv import short_conv_route
+
+        return {"route": short_conv_route(step.mesh, self.channels,
+                                          step.seq_len or 0),
+                "layers": _numbers(layers), "channels": self.channels,
+                "taps": self.taps, "tp": "whole on every device"}
+
+
+class ShortConvMixer(nn.Module):
+    """The gated short convolution of the ``lfm2_moe`` family (LFM2,
+    arXiv:2511.23404): [B | C | X] = u W_in, ONE projection d_model -> 3 x
+    ``channels``, the thirds in this order, no bias; y_t = C_t * sum_i
+    taps[i] (B X)_{t - (K - 1) + i}, a causal depthwise convolution of K =
+    ``spec.taps`` taps between two gates read from the layer's own input
+    ((B X) before the row's start = 0; no bias, NO activation); out = y
+    W_out. No state beyond K - 1 tokens, no scan, no positions. The
+    products bfloat16 with float32 accumulation; the gates and the taps'
+    sum float32, one rounding to the output projection's operand (the core,
+    under ``short_conv.core``: ops/short_conv.py's two Pallas calls on one
+    TPU device, their plain twin elsewhere: ``short_conv_route`` is the one
+    rule). The two products carry the names of the spec's ``KEPT``."""
+
+    d_model: int
+    spec: ShortConvSpec
+
+    @nn.compact
+    @trace.scope("short_conv")
+    def __call__(self, u):
+        from metaopt_tpu.ops import short_conv as sc
+        from metaopt_tpu.parallel.mesh import active_mesh
+
+        sp, kept = self.spec, self.spec.KEPT
+        dense = lambda name, n: nn.Dense(  # noqa: E731
+            n, dtype=jnp.bfloat16, name=name, use_bias=False,
+            kernel_init=_pinit(True, (None, None)))
+        bcx = checkpoint_name(dense("in_proj", 3 * sp.channels)(
+            u.astype(jnp.bfloat16)), kept["in"])
+        taps = self.param("conv", with_mesh_partitioning(
+            _taps_init, (None, None)), (sp.taps, sp.channels))
+        if sc.short_conv_route(active_mesh(), sp.channels,
+                               u.shape[1]) == "pallas":
+            y = sc.gated_short_conv(bcx, taps)
+        else:
+            with trace.scope("short_conv.core"):
+                y = sc.gated_short_conv_plain(bcx, taps)
+        return checkpoint_name(dense("out_proj", self.d_model)(y),
+                               kept["out"])

@@ -645,15 +645,15 @@ class RoutingRule:
     """How a token's experts are chosen and weighed from the router's
     logits. ``softmax``: the k largest logits, a softmax over those k.
     ``sigmoid`` (the DeepSeek-V3 family's): scores sigmoid(logits); the k
-    largest of score + bias where the family has a correction bias (it
-    enters the choice alone, never a weight, so no gradient reaches it);
-    the chosen scores over their sum + 1e-20 where ``normalised``, times
-    ``scale``."""
-
+    largest of score + bias where the family has a correction bias (in the
+    choice alone, never a weight: no gradient reaches it); the chosen scores
+    over their sum + ``eps`` (a family's own: 1e-20, ``lfm2_moe``'s 1e-6)
+    where ``normalised``, times ``scale``."""
     scoring: str = "softmax"
     bias: bool = False
     normalised: bool = True
     scale: float = 1.0
+    eps: float = 1e-20
 
 
 def route_top_k(logits, k: int, rule: RoutingRule = RoutingRule(),
@@ -674,7 +674,7 @@ def route_top_k(logits, k: int, rule: RoutingRule = RoutingRule(),
         weights = jnp.take_along_axis(scores, experts, axis=1)
         if rule.normalised:
             weights = weights / (jnp.sum(weights, axis=1, keepdims=True)
-                                 + 1e-20)
+                                 + rule.eps)
         return weights * rule.scale, experts
 
 

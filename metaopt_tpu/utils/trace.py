@@ -89,6 +89,11 @@ SCOPES = ("embed", "attention", "attention.core", "ffn", "readout_xent",
           # scan of the scalar decay rule (ops/linear_attention.py), both
           # directions
           "ssd", "ssd.core",
+          # a gated short convolution as a mixer
+          # (models/lm_layers.ShortConvMixer): all of it, the two projections
+          # among it, and its element-wise core (ops/short_conv.py: two gates
+          # around three taps), both directions
+          "short_conv", "short_conv.core",
           # a gated memory unit (models/lm_layers.GatedMemoryUnit): an earlier
           # layer's scan output gated by this layer's projection
           "gmu",
@@ -647,6 +652,12 @@ def print_routes(recs: List[dict]) -> None:
                           f"{how['route']}, a program a "
                           f"{how['program']}" + _HAND_OVER.get(
                               ("ssd", how.get("hand_over")), ""))
+                    continue
+                if kind == "short-conv":
+                    print(f"trial {r['trial']}: gated short convolutions, "
+                          f"layers {_runs(how['layers'])}: "
+                          f"{how['channels']} channels, {how['taps']} taps "
+                          f"between two gates, the core by {how['route']}")
                     continue
                 if kind == "gmu":
                     print(f"trial {r['trial']}: gated memory units "

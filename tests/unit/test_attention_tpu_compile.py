@@ -685,3 +685,42 @@ def test_the_gated_delta_hand_over_s_calls_compile_for_a_v5e(one_chip, shape):
     for name in ("delta_operands", "delta_operands_bwd", "delta_gated_norm",
                  "delta_gated_norm_bwd"):
         assert f"{name}" in text, name
+
+
+#: (rows, tokens, channels): the short-convolution cell's mixers; a length
+#: that is no whole tile of either direction; one block of columns
+SHORT_CONVS = [(1, 8192, 2048), (2, 1200, 1024), (1, 512, 128)]
+
+
+@pytest.mark.parametrize("shape", SHORT_CONVS,
+                         ids=lambda s: "x".join(map(str, s)))
+def test_the_gated_short_convolution_s_calls_compile_for_a_v5e(one_chip,
+                                                               shape):
+    """ops/short_conv.py's two calls through Mosaic at the cell's shapes (3
+    taps): a tile's block of the product's 3 D columns whole, the halo
+    blocks of 16 rows before and after it, the rows shifted a tap down the
+    sublanes, the taps' sums a tile; and between the input projection's
+    product and the calls, and between them and the output projection's
+    operand, no slice, transpose or copy that XLA must make: the projection
+    and its gradient are the two custom calls' only neighbours."""
+    from metaopt_tpu.ops import short_conv as sc
+
+    b, t, d = shape
+    on_chip = lambda s, dt: jax.ShapeDtypeStruct(  # noqa: E731
+        s, dt, sharding=one_chip)
+
+    def loss(u, w_in, taps, w_out):
+        bcx = jnp.dot(u, w_in, preferred_element_type=jnp.bfloat16)
+        y = sc.gated_short_conv(bcx, taps)
+        return jnp.sum(jnp.dot(
+            y, w_out, preferred_element_type=jnp.float32) ** 2)
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).lower(
+        on_chip((b, t, d), jnp.bfloat16), on_chip((d, 3 * d), jnp.bfloat16),
+        on_chip((3, d), jnp.float32),
+        on_chip((d, d), jnp.bfloat16)).compile().as_text()
+    assert text.count("tpu_custom_call") == 2
+    assert "short_conv_fwd" in text and "short_conv_bwd" in text
+    entry = text[text.index("ENTRY"):]
+    assert " transpose(" not in entry and " slice(" not in entry
+    assert " copy(" not in entry

@@ -74,6 +74,45 @@ def _two_matrix_layer(held, seed=3, t=40):
     return y[0], state["moe_stats"], ref(mine, held), ref(full, (0, E)), alike
 
 
+#: the lfm2_moe family's layer at this file's sizes: gated SiLU experts, top
+#: 4 of score + a correction bias, the chosen scores over their sum + 1e-6,
+#: no shared expert
+LFM2 = {"top_k": 4, "normalised": True, "scale": 1.0, "routing_eps": 1e-6,
+        "use_bias": True}
+
+
+def _lfm2_layer(held, seed=3, t=40):
+    """:func:`_expert_layer`'s five for the layer by ``LFM2``, its
+    references chipbench/reference/conv_lm.py's."""
+    from chipbench.reference import conv_lm as reference
+    from metaopt_tpu.models.moe import DroplessMoE, RoutingRule
+
+    layer = lambda held: DroplessMoE(  # noqa: E731
+        D, F, E, LFM2["top_k"], held, "silu",
+        RoutingRule("sigmoid", True, True, 1.0, eps=1e-6), 0)
+    key = jax.random.PRNGKey(seed)
+    x = jax.random.normal(key, (1, t, D))
+    logits = 2.0 * jax.random.normal(jax.random.fold_in(key, 1), (1, t, E))
+    bias = 0.05 * jax.random.normal(jax.random.fold_in(key, 2), (E,))
+    full = nn.meta.unbox(layer((0, E)).init(key, x, logits, bias)["params"])
+    assert set(full) == {"gate", "up", "down"}            # no shared expert
+    first, count = held
+    mine = {k: v[first:first + count] for k, v in full.items()}
+    y, state = layer(held).apply({"params": mine}, x, logits, bias,
+                                 mutable=["moe_stats"])
+
+    def ref(p, held):
+        cfg = {**LFM2, "experts_held": list(held)}
+        each = {k: {f"e{e:02d}": p[k][e] for e in range(held[1])}
+                for k in ("gate", "up", "down")}
+        return reference._experts(
+            "float32", each, x[0],
+            reference.routing_weights(logits[0], bias, cfg), held[0],
+            jax.nn.silu)
+
+    return y[0], state["moe_stats"], ref(mine, held), ref(full, (0, E)), 0.0
+
+
 def _expert_layer(held, logits_bias=None, seed=3, t=40, activation="relu",
                   gated=False):
     """:func:`expert_layer`'s four and what every share computes alike (a
@@ -84,6 +123,8 @@ def _expert_layer(held, logits_bias=None, seed=3, t=40, activation="relu",
 
     if activation == "relu2":
         return _two_matrix_layer(held, seed, t)
+    if activation == "lfm2":
+        return _lfm2_layer(held, seed, t)
     act = {"relu": jax.nn.relu, "silu": jax.nn.silu}[activation]
     top_k = GATED["top_k"] if gated else TOPK
     layer = lambda held: DroplessMoE(  # noqa: E731
@@ -141,11 +182,13 @@ def test_a_share_gives_its_own_experts_part(held):
     ([(first, 2) for first in range(0, E, 2)], "silu", False),
     ([(first, 2) for first in range(0, E, 2)], "silu", True),
     ([(first, 2) for first in range(0, E, 2)], "relu2", False),
-    ([(first, 8) for first in (0, 8)], "relu2", False)],
+    ([(first, 8) for first in (0, 8)], "relu2", False),
+    ([(first, 2) for first in range(0, E, 2)], "lfm2", False)],
     ids=["four-shares-relu", "eight-shares-silu",
          "eight-shares-sigmoid-top4-shared",
          "eight-shares-two-matrices-bias-shared",
-         "two-shares-two-matrices-bias-shared"])
+         "two-shares-two-matrices-bias-shared",
+         "eight-shares-silu-bias-top4-eps-no-shared"])
 def test_the_shares_add_up_to_the_uncut_layer(shares, activation, gated):
     """16 experts, top 3, four shares of 4 (gated ReLU) or eight of 2
     (gated SiLU): the partial outputs sum to what the uncut reference
@@ -155,7 +198,9 @@ def test_the_shares_add_up_to_the_uncut_layer(shares, activation, gated):
     which every share computes alike, counted once. And the nemotron_h
     family's (experts of two matrices under a squared ReLU, no gate, top 3
     of score + a correction bias, a shared expert twice as wide): eight
-    shares of two experts, and two of eight."""
+    shares of two experts, and two of eight. And the lfm2_moe family's
+    (gated SiLU experts, top 4 of score + a correction bias, the chosen
+    scores over their sum + 1e-6, no shared expert): eight shares of two."""
     parts = [_expert_layer(held, activation=activation, gated=gated)
              for held in shares]
     total = sum(p[0] for p in parts) - (len(parts) - 1) * parts[0][4]
@@ -167,6 +212,51 @@ def test_the_shares_add_up_to_the_uncut_layer(shares, activation, gated):
     # a share's own part is the reference's for that share
     assert all(np.linalg.norm(p[0] - p[2]) <= 0.02 * np.linalg.norm(uncut)
                for p in parts)
+
+
+def test_the_lfm2_family_s_rule_top_4_of_64_by_score_and_bias():
+    """The 4 largest of s + b choose; the weights are s / (sum of the four
+    s + 1e-6), the bias in neither; written out here in numpy."""
+    from metaopt_tpu.models.moe import RoutingRule, route_top_k
+
+    key = jax.random.PRNGKey(7)
+    logits = 2.0 * jax.random.normal(key, (50, 64))
+    bias = 0.3 * jax.random.normal(jax.random.fold_in(key, 1), (64,))
+    rule = RoutingRule("sigmoid", bias=True, normalised=True, scale=1.0,
+                       eps=1e-6)
+    weights, experts = route_top_k(logits, 4, rule, bias)
+    s = 1.0 / (1.0 + np.exp(-np.asarray(logits, np.float64)))
+    order = np.argsort(-(s + np.asarray(bias, np.float64)), axis=1,
+                       kind="stable")[:, :4]
+    assert (np.sort(order, axis=1) == np.sort(np.asarray(experts),
+                                              axis=1)).all()
+    chosen = np.take_along_axis(s, np.asarray(experts), axis=1)
+    np.testing.assert_allclose(
+        weights, chosen / (chosen.sum(axis=1, keepdims=True) + 1e-6),
+        rtol=2e-6)
+    # the bias moved some choices and is in no weight
+    plain = np.argsort(-s, axis=1, kind="stable")[:, :4]
+    assert (np.sort(plain, axis=1) != np.sort(order, axis=1)).any()
+
+
+@pytest.mark.parametrize("eps", [None, 1e-6, 0.5])
+def test_the_rule_s_epsilon_is_a_field_whose_default_is_the_old_literal(eps):
+    """``RoutingRule.eps`` defaults to 1e-20, which every standing family
+    keeps; a family's own is read where the literal stood (0.5 shows)."""
+    from metaopt_tpu.models.moe import RoutingRule, route_top_k
+
+    assert RoutingRule().eps == 1e-20
+    assert RoutingRule("sigmoid", True, True, 2.5) \
+        == RoutingRule("sigmoid", True, True, 2.5, 1e-20)
+    rule = RoutingRule("sigmoid") if eps is None \
+        else RoutingRule("sigmoid", eps=eps)
+    logits = jax.random.normal(jax.random.PRNGKey(1), (9, E))
+    weights, experts = route_top_k(logits, 3, rule)
+    chosen = np.take_along_axis(np.asarray(jax.nn.sigmoid(logits)),
+                                np.asarray(experts), axis=1)
+    np.testing.assert_allclose(
+        weights, chosen / (chosen.sum(axis=1, keepdims=True)
+                           + (1e-20 if eps is None else eps)), rtol=2e-6)
 
 
 @pytest.mark.parametrize("held", [(0, 4), (4, 4), (0, 16)])

@@ -216,12 +216,12 @@ def _under(names, scope):
     # latent layers' (and ``moe.shared``): tests/unit/test_lm_latent.py
     # its state-space, memory-unit and differential layers':
     # tests/unit/test_trace_layers.py; the gate on a grouped layer's output:
-    # tests/unit/test_lm_gated.py; its Mamba-2 mixers':
-    # tests/unit/test_trace_layers.py
+    # tests/unit/test_lm_gated.py; its Mamba-2 mixers' and its gated short
+    # convolutions': tests/unit/test_trace_layers.py
     and s not in ("attention.index", "attention.select", "linear_attention",
                   "linear_attention.core", "attention.latent", "ssm",
                   "ssm.core", "gmu", "attention.diff", "attention.gate",
-                  "ssd", "ssd.core")])
+                  "ssd", "ssd.core", "short_conv", "short_conv.core")])
 def test_every_scope_names_ops_of_the_train_step(lowered_op_names, scope):
     assert _under(lowered_op_names, scope), scope
 

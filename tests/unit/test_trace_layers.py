@@ -4,7 +4,7 @@ every operation the program writes has a layer (``layer_of``) and a
 direction (``direction``), in the forward, its second run under remat and
 the backward.
 
-The steps are the benchmark's eight model kinds at their rehearsal
+The steps are the benchmark's nine model kinds at their rehearsal
 sizes, lowered and not compiled. Each is lowered twice: as this backend
 routes it, and for the TPU with ``jax.default_backend`` answering "tpu", so
 that the Pallas kernels are on the path (lowering a kernel for the TPU needs
@@ -36,6 +36,7 @@ KINDS = {
     "gated-decoder": ("laguna-xs2-33b-a3b-ep8", "gated_lm_config"),
     "one-sublayer-decoder": ("nemotron-3-nano-30b-a3b-ep16",
                              "ssd_lm_config"),
+    "short-conv-decoder": ("lfm2-24b-a2b-ep8", "conv_lm_config"),
 }
 #: no operation of the device: a literal, a function's end, and remat's own
 #: barrier around a block's kept values (jax names it ``.../remat2``)
@@ -187,7 +188,8 @@ def test_every_operation_of_a_train_step_has_a_layer(lowered_steps, kind,
     ("2017-base", False), ("pattern-decoder", True),
     ("selected-attention-decoder", True), ("hybrid-decoder", True),
     ("latent-decoder", True), ("state-space-decoder", True),
-    ("gated-decoder", True), ("one-sublayer-decoder", True)])
+    ("gated-decoder", True), ("one-sublayer-decoder", True),
+    ("short-conv-decoder", True)])
 def test_a_step_has_operations_in_every_direction_it_runs(lowered_steps,
                                                          kind, remat):
     """Forward, backward and update in every step; the forward's second
@@ -237,6 +239,12 @@ def test_a_step_has_operations_in_every_direction_it_runs(lowered_steps,
     ("one-sublayer-decoder", "ssd_scan_bwd", 0, 4),
     ("one-sublayer-decoder", "flash_fwd", 1, 0),
     ("one-sublayer-decoder", "flash_bwd", 0, 1),
+    # two attention layers of the seven (the five short-convolution
+    # mixers' core is the plain twin at a rehearsal's 64 channels:
+    # tests/unit/test_short_conv_kernel.py has the rule)
+    ("short-conv-decoder", "flash_fwd", 2, 0),
+    ("short-conv-decoder", "flash_bwd", 0, 2),
+    ("short-conv-decoder", "embed_rows_bwd", 0, 1),
     # the embedding's gradient rule: one table, written once
     ("one-sublayer-decoder", "embed_rows_bwd", 0, 1),
     ("pattern-decoder", "embed_rows_bwd", 0, 1),
@@ -283,6 +291,30 @@ def test_the_lookup_and_its_gradient_rule_are_the_embedding_s(lowered_steps,
              if trace.layer_of(n) == "embed"
              and trace.direction(n) == "backward"}
     assert "scatter" in plain and "custom_call" not in plain
+
+
+@pytest.mark.parametrize("route", ["here", "tpu"])
+def test_a_short_convolution_mixer_s_operations_by_scope(lowered_steps,
+                                                        route):
+    """Five mixers: under ``short_conv`` the two projections and the core,
+    under ``short_conv.core`` the gates and the taps alone (no product), in
+    the forward, its second run and the backward; ``short_conv`` is a
+    top-level layer of the partition."""
+    assert "short_conv" in trace.LAYERS and "short_conv.core" in trace.SCOPES
+    ops = [(k, n) for k, n in lowered_steps("short-conv-decoder", route)
+           if trace.layer_of(n) == "short_conv"]
+    core = [(k, n) for k, n in ops if "short_conv.core" in re.split(
+        r"[/()]", n)]
+    assert core and len(core) < len(ops)
+    assert not [k for k, _ in core if k.endswith("dot_general")]
+    products = [n for k, n in ops if k.endswith("dot_general")]
+    assert {trace.direction(n) for n in products} \
+        >= {"forward", "backward"}
+    assert {trace.direction(n) for _, n in core} \
+        == {"forward", "forward.again", "backward"}
+    blocks = {part for _, n in ops for part in n.split("/")
+              if re.fullmatch(r"h\d+", part)}
+    assert blocks == {"h1", "h3", "h4", "h5", "h7"}
 
 
 @pytest.mark.parametrize("kind, helper", [
@@ -367,6 +399,12 @@ _BLOCK = ("jit(train_step)/transpose(jvp(DecoderOnlyLM))/DecoderOnlyLM."
      "update"),
     # an evaluation's function is wholly under its scope (models/resnet.py)
     ("jit(val_error)/eval/ResNet/conv_general_dilated", "eval", "forward"),
+    # a gated short convolution: the core's backward rule names its own
+    # scope; the mixer's projection under the layer alone
+    (_BLOCK + "h3/conv/short_conv/short_conv.core/jit(_backward)/"
+     "short_conv_bwd/pallas_call", "short_conv", "backward"),
+    (_BLOCK + "rematted_computation/h3/conv/short_conv/in_proj/dot_general",
+     "short_conv", "forward.again"),
     # ``attention`` does not match inside another word
     ("jit(train_step)/jvp(Transformer)/dec0/self_attention_like/mul", None,
      "forward"),
@@ -377,13 +415,14 @@ def test_the_two_rules_on_a_path(op_name, layer, direction):
 
 
 def test_the_layers_are_the_top_level_scopes_and_every_scope_has_one():
-    assert len(trace.SCOPES) == len(set(trace.SCOPES)) == 28
+    assert len(trace.SCOPES) == len(set(trace.SCOPES)) == 30
     assert {"attention.latent", "moe.shared", "ssm.core", "ssd.core",
-            "attention.diff", "attention.gate"} <= set(trace.SCOPES)
+            "attention.diff", "attention.gate", "short_conv.core"} \
+        <= set(trace.SCOPES)
     assert set(trace.LAYERS) == {
         "embed", "attention", "ffn", "moe", "linear_attention", "ssm", "ssd",
-        "gmu", "readout_xent", "optimizer", "eval", "norm", "residual",
-        "loss"}
+        "short_conv", "gmu", "readout_xent", "optimizer", "eval", "norm",
+        "residual", "loss"}
     for scope in trace.SCOPES:
         assert trace.layer_of(scope) in trace.LAYERS
         assert trace.layer_of(f"jit(train_step)/jvp({scope})/mul") \
